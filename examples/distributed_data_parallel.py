@@ -24,7 +24,7 @@ def main():
     import numpy as np
     import paddle_tpu as fluid
     if args.cpu:
-        fluid.force_cpu()   # BEFORE any device op (wedged-TPU-safe)
+        fluid.force_cpu()   # BEFORE any device op
     from paddle_tpu import parallel
     from paddle_tpu.models.resnet import resnet_cifar10
 

@@ -26,7 +26,7 @@ def main():
     ap.add_argument("--new-tokens", type=int, default=16)
     args = ap.parse_args()
     if args.cpu:
-        fluid.force_cpu()   # BEFORE any device op (wedged-TPU-safe)
+        fluid.force_cpu()   # BEFORE any device op
 
     cfg = LlamaConfig(vocab_size=256, dim=128, n_layers=4, n_heads=8,
                       n_kv_heads=4, ffn_hidden=256, dtype="float32")

@@ -46,7 +46,7 @@ def measure(amp_level, repeats=4, iters=5, batch=64):
     rng = np.random.RandomState(0)
     feed = {"img": rng.randn(batch, 3, 32, 32).astype(np.float32),
             "label": rng.randint(0, 10, (batch, 1)).astype(np.int64)}
-    exe = fluid.Executor(fluid.TPUPlace())
+    exe = fluid.Executor()
     scope = fluid.Scope()
     with fluid.scope_guard(scope):
         exe.run(startup)
@@ -74,7 +74,7 @@ def measure(amp_level, repeats=4, iters=5, batch=64):
 
 def main():
     if "--cpu" in sys.argv:
-        fluid.force_cpu()   # BEFORE any device op (wedged-TPU-safe)
+        fluid.force_cpu()   # BEFORE any device op
     # the lever ladder: measure each configuration the same way
     base = measure(None)
     o1 = measure("O1")
@@ -91,7 +91,7 @@ def main():
     rng = np.random.RandomState(1)
     feed = {"img": rng.randn(64, 3, 32, 32).astype(np.float32),
             "label": rng.randint(0, 10, (64, 1)).astype(np.int64)}
-    exe = fluid.Executor(fluid.TPUPlace())
+    exe = fluid.Executor()
     scope = fluid.Scope()
     with fluid.scope_guard(scope):
         exe.run(startup_p)

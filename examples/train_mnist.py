@@ -28,7 +28,7 @@ def main():
                     help="force CPUPlace (default: TPUPlace)")
     args = ap.parse_args()
     if args.cpu:
-        fluid.force_cpu()   # BEFORE any device op (wedged-TPU-safe)
+        fluid.force_cpu()   # BEFORE any device op
 
     img = fluid.layers.data(name="img", shape=[784], dtype="float32")
     label = fluid.layers.data(name="label", shape=[1], dtype="int64")

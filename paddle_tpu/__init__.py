@@ -32,10 +32,11 @@ from .core.framework import (                  # noqa: F401
     Program, Block, Variable, Parameter, Operator,
     default_main_program, default_startup_program, program_guard,
     switch_main_program, switch_startup_program, name_scope, get_var)
-from .core.executor import force_cpu           # noqa: F401
+from .core.executor import (                   # noqa: F401
+    force_cpu, enable_compile_cache)
 from .core.executor import (                   # noqa: F401
     Executor, Scope, global_scope, scope_guard, _switch_scope,
-    CPUPlace, TPUPlace, CUDAPlace)
+    Place, CPUPlace, TPUPlace, CUDAPlace)
 from .core.backward import append_backward     # noqa: F401
 from .core.sequence import SequenceBatch, to_sequence_batch  # noqa: F401
 from .core import unique_name                  # noqa: F401

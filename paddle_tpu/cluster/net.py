@@ -383,12 +383,8 @@ def schema_fingerprint():
     protocol version and the jax version (a replica whose jax differs
     would disagree about executables and numerics — refuse at
     handshake, not at the first weird answer)."""
-    try:
-        import jax
-        jax_version = jax.__version__
-    except Exception:               # noqa: BLE001 — handshake-only
-        jax_version = "unknown"
-    return {"proto": PROTO_VERSION, "jax": jax_version}
+    import jax
+    return {"proto": PROTO_VERSION, "jax": jax.__version__}
 
 
 def client_hello(token=None, fingerprint=None):

@@ -227,7 +227,7 @@ class ProgramGradTask:
             return self._built
         from ..core import framework
         from ..core.backward import append_backward
-        from ..core.executor import Executor, Scope, TPUPlace
+        from ..core.executor import Executor, Scope
         from .. import layers
         main, startup = framework.Program(), framework.Program()
         with framework.program_guard(main, startup), \
@@ -240,7 +240,7 @@ class ProgramGradTask:
             loss = layers.mean(layers.square_error_cost(
                 input=pred, label=y))
             params_grads = append_backward(loss)
-        exe = Executor(TPUPlace(), donate_state=False,
+        exe = Executor(donate_state=False,
                        compile_store=self.artifact_dir)
         self._built = {
             "main": main, "loss": loss,

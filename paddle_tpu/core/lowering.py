@@ -335,8 +335,8 @@ def lower_program(program, fetch_names, mode):
                     # across fwd->bwd; BN/activation/pool recompute
                     # from them in the backward. Small residual set =
                     # small HLO, unlike recompute_norms' allow-most
-                    # form (compile-OOM at bench scale, BASELINE
-                    # lever_history_round4).
+                    # form (compile-OOM at bench scale; builder,
+                    # round 4, an earlier installation).
                     policy = jax.checkpoint_policies.\
                         save_only_these_names("conv_out")
                 else:

@@ -14,14 +14,14 @@ traffic (docs/SERVING.md).
 """
 from . import io as fluid_io
 from .core import framework
-from .core.executor import Executor, Scope, TPUPlace, scope_guard
+from .core.executor import Executor, Scope, scope_guard
 
 __all__ = ["Inferencer"]
 
 
 class Inferencer:
     def __init__(self, infer_func, param_path, place=None, parallel=False):
-        self._place = place or TPUPlace()
+        self._place = place
         self.scope = Scope()
         self.startup_program = framework.Program()
         self.inference_program = framework.Program()
@@ -50,7 +50,7 @@ class Inferencer:
         artifact, so the serving process needs no model-building code
         at all. Parameters land in this Inferencer's PRIVATE scope."""
         self = cls.__new__(cls)
-        self._place = place or TPUPlace()
+        self._place = place
         self.scope = Scope()
         self.startup_program = None
         self.exe = Executor(self._place)

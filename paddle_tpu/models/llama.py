@@ -1,6 +1,6 @@
 """Llama-3-style decoder-only LLM — the flagship model.
 
-The stretch config from BASELINE.json: a modern decoder-only LLM built
+The stretch config: a modern decoder-only LLM built
 entirely on the Program IR (embedding → [rms_norm → GQA attention with
 rope + flash/ring kernel → rms_norm → SwiGLU MLP] × L → rms_norm →
 lm_head → softmax_with_cross_entropy), with Megatron-style tensor-
@@ -20,6 +20,7 @@ __all__ = ["LlamaConfig", "LLAMA3_8B", "LLAMA_TINY", "build_llama",
            "build_llama_generator", "build_llama_spec_generator",
            "build_llama_paged_programs", "PagedDecodePrograms",
            "quantize_generator_weights", "stack_generator_weights",
+           "random_int8_generator_weights",
            "save_decode_model", "load_decode_model"]
 
 
@@ -663,6 +664,48 @@ def quantize_generator_weights(scope=None, name="blocks",
     hq, hscale = _q(head, axis=1)
     scope.set(head_name, hq)
     scope.set(head_name + "@scale", hscale.reshape(-1))  # [V]
+
+
+def random_int8_generator_weights(cfg, exe, scope):
+    """Fill ``scope`` with a random int8 serving model for a dense
+    ``cfg`` in the generator layout, created ON the executor's device:
+    one tiny init program per tensor (int8 straight out of
+    uniform_random — no float stage, no multi-GB host transfer, init
+    transients bounded by one tensor). Per-channel scales are the
+    constant 1.6e-4 (the magnitude 0.02-std weights quantize to). For
+    benchmarks and smokes that need full-size weights from a seed, not
+    a trained model."""
+    from ..core import framework
+    hd = cfg.dim // cfg.n_heads
+    L, D, V, F = cfg.n_layers, cfg.dim, cfg.vocab_size, cfg.ffn_hidden
+    int8_shapes = {
+        "blocks.wq": [L, D, cfg.n_heads * hd],
+        "blocks.wk": [L, D, cfg.n_kv_heads * hd],
+        "blocks.wv": [L, D, cfg.n_kv_heads * hd],
+        "blocks.wo": [L, cfg.n_heads * hd, D],
+        "blocks.w_gate": [L, D, F], "blocks.w_up": [L, D, F],
+        "blocks.w_down": [L, F, D], "lm_head": [D, V]}
+
+    def init_one(name, shape, dtype, op_type, **attrs):
+        p = framework.Program()
+        gb = p.global_block()
+        v = gb.create_var(name=name, shape=shape, dtype=dtype,
+                          persistable=True)
+        gb.append_op(type=op_type, inputs={}, outputs={"Out": [v.name]},
+                     attrs={"shape": shape, "dtype": dtype, **attrs})
+        exe.run(p, scope=scope)
+
+    for name, shape in int8_shapes.items():
+        init_one(name, shape, "int8", "uniform_random",
+                 min=-100.0, max=100.0)
+        init_one(name + "@scale",
+                 [V] if name == "lm_head" else [L, 1, shape[-1]],
+                 "float32", "fill_constant", value=1.6e-4)
+    init_one("tok_emb", [V, D], cfg.dtype, "gaussian_random", std=0.02)
+    for name, shape in (("blocks.attn_norm", [L, D]),
+                        ("blocks.mlp_norm", [L, D]),
+                        ("final_norm", [D])):
+        init_one(name, shape, cfg.dtype, "fill_constant", value=1.0)
 
 
 def _tp_spec_table(cfg):

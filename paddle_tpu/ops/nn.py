@@ -58,7 +58,7 @@ def _conv2d(ctx, ins, attrs):
     # ONLY saved residuals — the restrictive inverse of
     # recompute_norms' allow-most policy, whose pinned-everything
     # residual set OOM'd the XLA:TPU compiler at bench scale
-    # (BASELINE lever_history_round4). Tagged only when active: the
+    # (builder, round 4, an earlier installation). Tagged only when active: the
     # name primitive changes the HLO and untouched programs must stay
     # byte-identical to the measured fast path.
     if getattr(ctx.program, "_remat_policy", None) == "save_conv_only":
@@ -263,7 +263,7 @@ def _bn_train_bwd(axes, bshape, eps, res, cts):
     """Hand-derived (textbook) BN backward — round-5 device-time
     profile evidence: autodiff of the one-pass-stats graph compiled to
     ~3 separate activation sweeps per BN (52.9% of the whole ResNet-50
-    step's device time, BASELINE device_time_profile_round5); the
+    step's device time; builder, an earlier installation); the
     canonical form needs one fused (dbias, dscale) reduce sweep over
     (x, dy) plus one elementwise dx pass:
 

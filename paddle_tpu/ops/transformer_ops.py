@@ -98,10 +98,31 @@ def _rope(ctx, ins, attrs):
     return {"Out": [apply_rope(ins["X"][0], attrs.get("base", 10000.0))]}
 
 
+def _mesh_flash_attention(qt, kt, vt, causal, scale, mesh):
+    """flash_attention under a mesh GSPMD partitions. A Mosaic call
+    cannot be partitioned automatically (JAX refuses to lower it), so
+    it is mapped by hand: attention is independent per (batch, head),
+    so [B, H, T, D] splits its batch over 'dp' and its heads over 'tp'
+    with no collective inside. An axis that is absent, or does not
+    divide the dimension, leaves that dimension whole."""
+    def axis(name, dim):
+        n = mesh.axes.get(name, 1)
+        return name if n > 1 and dim % n == 0 else None
+
+    spec = jax.sharding.PartitionSpec(
+        axis("dp", qt.shape[0]), axis("tp", qt.shape[1]), None, None)
+    return jax.shard_map(
+        lambda q, k, v: flash_attention(q, k, v, causal, scale),
+        mesh=mesh.mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)(qt, kt, vt)
+
+
 def attention_core(q, k, v, causal=True, scale=None, allow_ring=True):
     """GQA-aware attention on [B, T, H, D] tensors — repeats kv heads,
     moves heads next to batch, and dispatches to ring attention (mesh
-    has a real 'sp' axis and the caller allows it) or the flash kernel.
+    has a real 'sp' axis and the caller allows it) or the flash kernel
+    (mapped over the mesh by hand unless the caller is already inside
+    a shard_map, as the pipeline schedules are).
     Shared by the multihead_attention op and llama_decoder_stack."""
     if k.shape[2] != q.shape[2]:  # GQA repeat kv heads
         rep = q.shape[2] // k.shape[2]
@@ -118,6 +139,9 @@ def attention_core(q, k, v, causal=True, scale=None, allow_ring=True):
         from ..parallel.ring_attention import ring_attention_sharded
         ot = ring_attention_sharded(qt, kt, vt, mesh, axis="sp",
                                     causal=causal)
+    elif (mesh is not None
+          and not jax.sharding.get_abstract_mesh().manual_axes):
+        ot = _mesh_flash_attention(qt, kt, vt, causal, scale, mesh)
     else:
         ot = flash_attention(qt, kt, vt, causal, scale)
     return jnp.transpose(ot, (0, 2, 1, 3))
@@ -411,15 +435,13 @@ def _llama_generate(ctx, ins, attrs):
     b, t_prompt = tokens.shape
     total = t_prompt + max_new
 
-    # In this round's measured environment each lax.scan iteration costs
-    # ~2.3 ms of loop overhead, so an L-layer inner scan bills ~L*2.3 ms
-    # to EVERY decoded token. unroll_layers replicates the (small) block
-    # body L times instead — one loop level total (the token scan) —
-    # and decode_unroll>1 further replicates the token-step body to
-    # amortize the outer loop the same way. Both trade compile time for
-    # iteration overhead; the decode program is small enough to afford
-    # it (unlike the train stack, where full unroll blew the remote
-    # compile budget — BASELINE.json unrolled_layers_note).
+    # Every lax.scan iteration costs loop overhead, and an L-layer inner
+    # scan bills it L times to EVERY decoded token. unroll_layers
+    # replicates the (small) block body L times instead — one loop
+    # level total (the token scan) — and decode_unroll>1 further
+    # replicates the token-step body to amortize the outer loop the
+    # same way. Both trade compile time for iteration overhead. What an
+    # iteration costs is not re-measured on this installation.
     unroll_layers = bool(attrs.get("unroll_layers", False))
     decode_unroll = max(1, int(attrs.get("decode_unroll", 1)))
     kv_int8 = bool(attrs.get("kv_int8", False))
@@ -932,8 +954,8 @@ class _PagedRunner:
       dense [L, B, kmax, g, hd] cache once, run every step against it
       (a step then costs the same ops as the contiguous cache), and
       scatter the touched pages back once at the end. The decode and
-      speculative step ops use this; per-step page indexing would
-      otherwise dominate the step cost on a host-round-trip backend.
+      speculative step ops use this; what per-step page indexing
+      would cost is not re-measured on this installation.
 
     The dense view holds bitwise the same values the pools do, so both
     forms produce identical numerics. int8 ``<Slot>Scale`` companions
@@ -1323,8 +1345,8 @@ def _llama_decoder_stack(ctx, ins, attrs):
     n_layers = params["Wq"].shape[0]
     if pp <= 1:
         # scan_unroll replicates k layer bodies per scan iteration:
-        # fewer loop iterations (each ~2.3 ms overhead in this round's
-        # measured environment) at the cost of a k-times-larger
+        # fewer loop iterations (their overhead is not re-measured on
+        # this installation) at the cost of a k-times-larger
         # executable to compile
         out, _ = jax.lax.scan(
             lambda h, p: (blk(p, h), None), x, params,

@@ -231,7 +231,8 @@ class ParallelExecutor:
         return fetches
 
     # ------------------------------------------------------------------
-    def compiled_stats(self, fetch_list, feed=None, top_k=10):
+    def compiled_stats(self, fetch_list, feed=None, top_k=10,
+                       include_hlo=False):
         """Measured multichip compile evidence: AOT-lowers exactly the
         sharded executable ``run`` would dispatch (same shardings, same
         lowering) and reports XLA's numbers (flops / bytes_accessed /
@@ -246,7 +247,9 @@ class ParallelExecutor:
         environments can't measure collective BANDWIDTH, but the
         compiled module proves which collectives a given sharding
         induces (reference: ParallelExecutor's NCCL AllReduce op
-        handles, paddle/fluid/framework/details/)."""
+        handles, paddle/fluid/framework/details/).
+        ``include_hlo=True`` keeps the optimized module text under
+        ``hlo_text`` (megabytes)."""
         from ..core.executor import compiled_cost_stats
         fetch_names, state_rw, state_ro, feed_vals = \
             self._prepare(feed or {}, fetch_list)
@@ -257,7 +260,9 @@ class ParallelExecutor:
                 step_arg(1, self.program.random_seed)).compile()
         stats = compiled_cost_stats(compiled, top_k, include_hlo=True)
         stats["mesh"] = dict(self.mesh.axes)
-        hlo_text = stats.pop("hlo_text", None)
+        hlo_text = stats.get("hlo_text")
+        if not include_hlo:
+            stats.pop("hlo_text", None)
         if hlo_text is None:
             # n_kernels == -1: the optimized module text was unavailable.
             # Leaving "collectives" out (rather than {}) lets consumers —

@@ -15,10 +15,7 @@ memory_optimization pass would.
 """
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map                      # jax >= 0.8
-except ImportError:                                # pragma: no cover
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["gpipe", "one_f_one_b"]
@@ -85,17 +82,10 @@ def gpipe(stage_fn, mesh, axis="pp", checkpoint_stages=True):
         in_specs = (jax.tree_util.tree_map(lambda _: param_spec,
                                            stacked_params),
                     P(None, "dp") if "dp" in other_axes else P())
-        kw = {"check_vma": False}
-        try:
-            sm = shard_map(
-                per_group, mesh=mesh.mesh, in_specs=in_specs,
-                out_specs=P(None, "dp") if "dp" in other_axes else P(),
-                **kw)
-        except TypeError:      # older jax spells it check_rep
-            sm = shard_map(
-                per_group, mesh=mesh.mesh, in_specs=in_specs,
-                out_specs=P(None, "dp") if "dp" in other_axes else P(),
-                check_rep=False)
+        sm = shard_map(
+            per_group, mesh=mesh.mesh, in_specs=in_specs,
+            out_specs=P(None, "dp") if "dp" in other_axes else P(),
+            check_vma=False)
         return sm(stacked_params, micro)
 
     return pipelined
@@ -284,13 +274,10 @@ def one_f_one_b(stage_fn, loss_fn, mesh, axis="pp", loss_params=False,
             out_specs = out_specs + (lspecs,)
         if return_dx:
             out_specs = out_specs + (data_spec,)
-        kw = dict(mesh=mesh.mesh,
-                  in_specs=(pspecs, lspecs, data_spec, data_spec),
-                  out_specs=out_specs)
-        try:
-            sm = shard_map(per_group, check_vma=False, **kw)
-        except TypeError:                      # older jax: check_rep
-            sm = shard_map(per_group, check_rep=False, **kw)
+        sm = shard_map(
+            per_group, mesh=mesh.mesh,
+            in_specs=(pspecs, lspecs, data_spec, data_spec),
+            out_specs=out_specs, check_vma=False)
         return sm(stacked_params, lparams, micro_x, micro_y)
 
     return step

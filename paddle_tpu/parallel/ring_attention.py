@@ -18,7 +18,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..ops.pallas_attention import attention_with_lse
@@ -86,11 +85,11 @@ def ring_attention_sharded(q, k, v, mesh, axis="sp", causal=True,
                            scale=None):
     """Global entry: q,k,v [B, H, T, D] with T sharded over ``axis``."""
     spec = P(None, None, axis, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_attention, axis_name=axis, causal=causal,
                           scale=scale),
         mesh=mesh.mesh if hasattr(mesh, "mesh") else mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        check_rep=False)
+        check_vma=False)
     return fn(q, k, v)

@@ -4,7 +4,7 @@ Every recovery path in the resilience subsystem — crash-safe
 checkpoints, retrying execution, the NaN sentinel — is only as good as
 its tests, and none of the underlying faults (SIGKILL mid-write, a
 flaky network reader, a numerically divergent step, a dropped PJRT
-tunnel) occur naturally in CI. This module makes them occur ON DEMAND
+connection) occur naturally in CI. This module makes them occur ON DEMAND
 and DETERMINISTICALLY: a fault is armed with a fire index and a fire
 count, instrumented framework code calls :func:`fires` at its
 injection point, and exactly the configured calls fire. TensorFlow's

@@ -46,7 +46,7 @@ import time
 
 import numpy as np
 
-from ..core.executor import CPUPlace, Executor, global_scope
+from ..core.executor import Executor, global_scope
 from ..resilience import faultinject as _faultinject
 from ..resilience.retry import RetryPolicy, default_policy, with_retries
 from .batching import (QueueFullError, RequestTimeoutError,
@@ -300,7 +300,10 @@ class DecodeEngine:
     (``build_llama_generator`` startup, a trained+stacked scope, or a
     ``quantize_generator_weights``'d one; draft weights under
     ``draft.*`` when ``draft_cfg`` — see models/llama.py
-    copy_weights_as_draft). The engine never initializes weights."""
+    copy_weights_as_draft). The engine never initializes weights.
+    ``place=None`` dispatches on the process's default device — the
+    chip where there is one; the KV pools are created on that default
+    device whatever ``place`` says."""
 
     def __init__(self, cfg, scope=None, place=None, config=None,
                  draft_cfg=None, auto_start=True, optimize=True,
@@ -375,7 +378,7 @@ class DecodeEngine:
         # step executable the first one compiled instead of paying XLA
         # again (io/artifact_store.py; None defers to
         # PADDLE_TPU_ARTIFACT_DIR)
-        self.exe = Executor(place or CPUPlace(),
+        self.exe = Executor(place,
                             retry_policy=RetryPolicy(max_attempts=1),
                             donate_state=False,
                             compile_store=compile_store)

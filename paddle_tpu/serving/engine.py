@@ -41,7 +41,7 @@ import time
 
 import numpy as np
 
-from ..core.executor import CPUPlace, Executor, Scope, global_scope
+from ..core.executor import Executor, Scope, global_scope
 from ..resilience import faultinject as _faultinject
 from ..resilience.retry import (RetryPolicy, TransientDeviceError,
                                 default_policy, with_retries)
@@ -178,7 +178,7 @@ class ServingEngine:
         # process (or an export-time seeding pass) already persisted
         # them: the zero-compile cold start. None defers to
         # PADDLE_TPU_ARTIFACT_DIR; False disables outright.
-        self.exe = Executor(place or CPUPlace(),
+        self.exe = Executor(place,
                             retry_policy=RetryPolicy(max_attempts=1),
                             donate_state=False,
                             compile_store=compile_store)
@@ -227,7 +227,7 @@ class ServingEngine:
         from .. import io as fluid_io
         from ..io.artifact_store import EMBEDDED_DIRNAME
         scope = Scope()
-        exe = Executor(place or CPUPlace())
+        exe = Executor(place)
         # the target scope is passed explicitly — a guard swap of the
         # process-global scope here would race the worker threads of
         # every other live engine (a canary rebuild under traffic

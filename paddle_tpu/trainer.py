@@ -20,7 +20,7 @@ import numpy as np
 from . import io as fluid_io
 from . import optimizer as optimizer_mod
 from .core import framework
-from .core.executor import Executor, Scope, TPUPlace, scope_guard
+from .core.executor import Executor, Scope, scope_guard
 from .data_feeder import DataFeeder
 from .resilience import checkpoint as _ckpt
 from .resilience import faultinject
@@ -100,7 +100,7 @@ class Trainer:
 
     def __init__(self, train_func, optimizer_func, param_path=None,
                  place=None, parallel=False, checkpoint_config=None):
-        self._place = place or TPUPlace()
+        self._place = place
         self._parallel = parallel
         self._stop = False
         self._checkpoint_cfg = checkpoint_config

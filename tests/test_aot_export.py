@@ -32,7 +32,7 @@ def _train_and_save(tmp_path):
         loss = fluid.layers.mean(
             fluid.layers.cross_entropy(prob, y))
         fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
-    exe = fluid.Executor(fluid.TPUPlace())
+    exe = fluid.Executor()
     exe.run(startup)
     rng = np.random.RandomState(0)
     for _ in range(3):
@@ -105,7 +105,6 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 import importlib.util, sys
 import numpy as np
 import jax
-jax.config.update("jax_platforms", "cpu")
 spec = importlib.util.spec_from_file_location("aot", {aot_path!r})
 aot = importlib.util.module_from_spec(spec)
 spec.loader.exec_module(aot)
@@ -138,7 +137,7 @@ def test_aot_generator_export_roundtrip(tmp_path):
                                      append_batch_size=False)
             out = build_llama_generator(LLAMA_TINY, toks,
                                         max_new_tokens=5)
-        exe = fluid.Executor(fluid.TPUPlace())
+        exe = fluid.Executor()
         exe.run(startup_p)
         pv = np.random.RandomState(0).randint(
             0, LLAMA_TINY.vocab_size, (2, 6)).astype(np.int64)
@@ -179,7 +178,7 @@ def test_aot_exports_sequence_program(tmp_path):
          for n in (5, 3, 7)])
     with fluid.scope_guard(scope):
         main, startup, prob = _seq_model()
-        exe = fluid.Executor(fluid.TPUPlace())
+        exe = fluid.Executor()
         exe.run(startup)
         ref = exe.run(main, feed={"words": sb}, fetch_list=[prob],
                       mode="test")[0]
@@ -215,7 +214,7 @@ def test_aot_sequence_predictor_feed_forms(tmp_path):
          for n in (4, 2)])
     with fluid.scope_guard(scope):
         main, startup, prob = _seq_model()
-        exe = fluid.Executor(fluid.TPUPlace())
+        exe = fluid.Executor()
         exe.run(startup)
         fluid.io.save_inference_model(d, ["words"], [prob], exe, main)
     pred = load_compiled_predictor(d)
@@ -244,7 +243,7 @@ def test_aot_exports_two_level_lod_program(tmp_path):
             sent = fluid.layers.sequence_pool(x, "sum")
             doc = fluid.layers.sequence_pool(sent, "sum")
             out = fluid.layers.fc(doc, size=2)
-        exe = fluid.Executor(fluid.TPUPlace())
+        exe = fluid.Executor()
         exe.run(startup)
         ref = exe.run(main, feed={"x": sb}, fetch_list=[out],
                       mode="test")[0]
@@ -278,7 +277,7 @@ def test_aot_exports_llama_generator(tmp_path):
             out = build_llama_generator(cfg, ptok, max_new_tokens=new,
                                         quantize=quant)
         scope = fluid.Scope()
-        exe = fluid.Executor(fluid.TPUPlace())
+        exe = fluid.Executor()
         with fluid.scope_guard(scope):
             exe.run(startup)
             if quant:

@@ -146,3 +146,39 @@ def test_pallas_kernels_interpret_match_reference():
                     err_msg=f"d{name} causal={causal}")
     finally:
         pa._FORCE_INTERPRET = False
+
+
+def test_kernels_under_a_mesh_are_mapped_by_hand():
+    """A Mosaic call cannot be partitioned by GSPMD (JAX refuses to
+    lower it), so under a mesh attention_core maps the flash kernels
+    over dp/tp with shard_map. Through the interpreter, on a dp2 x tp2
+    mesh with sharded inputs: same output and dq as with no mesh, and
+    the kernel gate that a ragged last block used to slip past."""
+    import paddle_tpu.ops.pallas_attention as pa
+    from paddle_tpu.ops.transformer_ops import attention_core
+    from paddle_tpu.parallel.mesh import mesh_scope
+    rng = np.random.RandomState(5)
+    q, k, v = (jnp.asarray(rng.randn(2, 128, 2, 128) * 0.5, jnp.float32)
+               for _ in range(3))                       # [B, T, H, D]
+    assert pa._kernel_shapes_ok(jnp.zeros((1, 1, 256, 128)),
+                                jnp.zeros((1, 1, 256, 128)))
+    assert not pa._kernel_shapes_ok(jnp.zeros((1, 1, 192, 128)),
+                                    jnp.zeros((1, 1, 192, 128)))
+
+    def out_and_dq(q, k, v):
+        return jax.value_and_grad(
+            lambda q: attention_core(q, k, v).sum() * 0.01)(q)
+
+    mesh = make_mesh({"dp": 2, "tp": 2})
+    pa._FORCE_INTERPRET = True
+    try:
+        want = out_and_dq(q, k, v)
+        with mesh_scope(mesh):
+            sh = mesh.sharding("dp", None, "tp", None)
+            got = jax.jit(out_and_dq)(*(jax.device_put(a, sh)
+                                        for a in (q, k, v)))
+    finally:
+        pa._FORCE_INTERPRET = False
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)

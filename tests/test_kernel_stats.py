@@ -24,7 +24,7 @@ def _small_train_stats(top_k=10):
         loss = fluid.layers.mean(fluid.layers.softmax_with_cross_entropy(
             fluid.layers.fc(h, size=10), y))
         fluid.optimizer.Adam(learning_rate=0.01).minimize(loss)
-    exe = fluid.Executor(fluid.TPUPlace())
+    exe = fluid.Executor()
     exe.run(startup_p)
     feed = {"x": np.zeros((4, 64), np.float32),
             "y": np.zeros((4, 1), np.int64)}

@@ -456,7 +456,7 @@ def test_unrolled_decode_matches_scan_decode():
                 out = build_llama_generator(CFG, toks,
                                             max_new_tokens=NEW, **kw)
             gen_p.random_seed = startup_p.random_seed = 7
-            exe = fluid.Executor(fluid.TPUPlace())
+            exe = fluid.Executor()
             exe.run(startup_p)
             pv = np.random.RandomState(0).randint(
                 0, CFG.vocab_size, (2, PROMPT)).astype(np.int64)

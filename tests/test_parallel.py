@@ -38,6 +38,14 @@ def test_eight_devices_present():
     assert len(jax.devices()) == 8
 
 
+def test_mesh_larger_than_the_default_backend_is_an_error():
+    """A mesh is laid over jax.devices() and nothing else: no other
+    backend's devices stand in for missing ones."""
+    assert make_mesh({"dp": -1}).size() == 8
+    with pytest.raises(ValueError, match="cannot be laid out over 8"):
+        make_mesh({"dp": 4, "tp": 4})
+
+
 def test_data_parallel_trains():
     loss = build_model()
     fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
@@ -149,7 +157,7 @@ def test_quantized_all_reduce_close_to_exact():
     """EQuARX-style int8 gradient allreduce (parallel/collectives.py):
     ~1e-2 relative error vs the exact psum on a dp mesh."""
     import jax
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from paddle_tpu import parallel
     from paddle_tpu.parallel import collectives as C

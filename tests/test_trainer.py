@@ -155,8 +155,7 @@ class TestProfilerAverage:
         a trace written by the profiler session parses without error —
         on the CPU backend there may be no device plane, which must
         report gracefully, not crash. (The TPU path is exercised by
-        tools/device_profile.py on the real chip; BASELINE
-        device_time_profile_round5 holds its output.)"""
+        tools/device_profile.py on the real chip.)"""
         assert fluid.profiler.device_kernel_profile(
             str(tmp_path / "missing")) is None
         main, startup = fluid.Program(), fluid.Program()

@@ -9,14 +9,12 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"      # host-side tool
 
 import numpy as np                                         # noqa: E402
 
 
 def main(n_samples=20000, batch=128, img_elems=3072):
-    import jax
-    jax.config.update("jax_platforms", "cpu")
     from paddle_tpu.io import recordio
     from paddle_tpu.io.batcher import FixedBatcher, write_fixed
     from paddle_tpu import reader as rdr

@@ -22,8 +22,8 @@ sweep billed at f32. dY of layer L IS dX of layer L+1: each boundary
 gradient is written once and read once — both passes are counted, one
 on each side.
 
-This floor is what the measured step (BASELINE resnet_gap_analysis,
-~37-42 GB) must be compared against: measured/floor <= ~1.3x means the
+This floor is what the measured step (~37-42 GB; builder, an earlier
+installation) must be compared against: measured/floor <= ~1.3x means the
 bytes-bound conclusion is real, not a stopping excuse. Reference
 counterpart of the question: the per-op CUDA kernels of
 /root/reference/paddle/fluid/operators/conv_cudnn_op.cu.cc make every
@@ -36,12 +36,11 @@ import json
 import os
 import sys
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"      # analytic tool, no chip
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 import jax                                                   # noqa: E402
-jax.config.update("jax_platforms", "cpu")
 
 import numpy as np                                           # noqa: E402
 
