@@ -86,7 +86,8 @@ def main():
               "here — compare the BYTES column instead; the speedups "
               "are TPU numbers, see docs/PERFORMANCE.md)")
 
-    # profile the winner: chrome trace lands in ./prof/host_timeline.json
+    # profile the winner: the trace (device and host spans, one clock)
+    # lands under ./prof/plugins/profile/<time>/
     main_p, startup_p, loss = build("O2")
     rng = np.random.RandomState(1)
     feed = {"img": rng.randn(64, 3, 32, 32).astype(np.float32),
@@ -100,8 +101,9 @@ def main():
             for i in range(3):
                 with fluid.profiler.record_event(f"step{i}"):
                     exe.run(main_p, feed=feed, fetch_list=[loss])
-    print("chrome trace: ./prof/host_timeline.json "
-          "(load in chrome://tracing or Perfetto)")
+    print("trace: ./prof/plugins/profile/<time>/*.trace.json.gz "
+          "(load in chrome://tracing or Perfetto; the .xplane.pb "
+          "beside it is TensorBoard's)")
 
 
 if __name__ == "__main__":

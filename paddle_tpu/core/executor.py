@@ -8,7 +8,6 @@ fetch-set) — JAX itself re-specializes on feed shapes — and donates the
 read-write state so parameter updates are in-place in HBM.
 """
 import os
-import time
 import warnings
 
 import numpy as np
@@ -18,6 +17,7 @@ import jax.numpy as jnp
 
 from . import framework
 from .lowering import lower_program, written_names
+from ..profiler import record_event
 from ..resilience import faultinject as _faultinject
 from ..resilience.retry import (TransientDeviceError, default_policy,
                                 with_retries)
@@ -269,6 +269,13 @@ class Executor:
         full pass pipeline and raises VerifyError on any error-level
         diagnostic; "0"/False disables."""
         program = program or framework.default_main_program()
+        with record_event("pt:executor/run", program=program.uid,
+                          step=self._step + 1, repeats=repeats):
+            return self._run(program, feed, fetch_list, scope,
+                             return_numpy, mode, repeats, validate)
+
+    def _run(self, program, feed, fetch_list, scope, return_numpy, mode,
+             repeats, validate):
         if not 1 <= repeats <= 32:
             # an unroll, deliberately: a lax.scan over sub-steps would
             # keep the executable O(1) in k at the price of a while-loop
@@ -328,10 +335,6 @@ class Executor:
                                   fn, args)
                if self._store is not None else None)
 
-        from .. import profiler
-        prof = profiler.profiling_active()
-        t0 = time.perf_counter() if prof else 0.0
-
         def _dispatch():
             # deterministic transient-fault point (resilience/
             # faultinject.py "device_error") — raises BEFORE the
@@ -350,18 +353,14 @@ class Executor:
                 return fn(*args)
 
         policy = self._retry_policy or default_policy()
-        new_state, fetches = with_retries(
-            _dispatch, policy=policy,
-            on_retry=lambda exc, n, delay: warnings.warn(
-                f"transient device error on dispatch (failure {n}): "
-                f"{exc}; retrying in {delay:.3g}s", stacklevel=3))
-        if prof:
-            # dispatch slice for the chrome timeline (async: this is
-            # host-side enqueue time; device time is in the XLA trace)
-            profiler.add_timeline_event(
-                f"dispatch step {first_step}", t0, time.perf_counter(),
-                args={"repeats": repeats,
-                      "program": f"uid={program.uid}"})
+        # async: the span is host-side enqueue time; the device's time
+        # is on the trace's own device lines, on the same clock
+        with record_event("pt:executor/dispatch"):
+            new_state, fetches = with_retries(
+                _dispatch, policy=policy,
+                on_retry=lambda exc, n, delay: warnings.warn(
+                    f"transient device error on dispatch (failure {n}): "
+                    f"{exc}; retrying in {delay:.3g}s", stacklevel=3))
 
         # write the scope FIRST: state_rw was donated (its old buffers
         # are already deleted), so if the guard raises and the scope

@@ -23,6 +23,7 @@ from ..core import framework
 from ..core.executor import (Executor, global_scope, make_stepped,
                              step_arg, check_nan_guard)
 from ..core.lowering import lower_program, written_names
+from ..profiler import record_event
 from .mesh import make_mesh, DeviceMesh, mesh_scope
 
 # GSPMD collective opcodes in optimized HLO. Each collective counts
@@ -201,9 +202,17 @@ class ParallelExecutor:
     # ------------------------------------------------------------------
     def run(self, fetch_list, feed=None, feed_dict=None, return_numpy=True):
         feed = feed if feed is not None else (feed_dict or {})
+        with record_event("pt:pexecutor/run", program=self.program.uid,
+                          step=self._step + 1):
+            return self._run(fetch_list, feed, return_numpy)
+
+    def _run(self, fetch_list, feed, return_numpy):
         program = self.program
-        fetch_names, state_rw, state_ro, feed_vals = \
-            self._prepare(feed, fetch_list)
+        # its own span: _prepare pulls a device-resident feed to the
+        # host and back (jnp.asarray(np.asarray(v))) on every step
+        with record_event("pt:pexecutor/prepare"):
+            fetch_names, state_rw, state_ro, feed_vals = \
+                self._prepare(feed, fetch_list)
 
         key = (program.uid, program.version, tuple(fetch_names))
         fn = self._cache.get(key)
@@ -214,7 +223,7 @@ class ParallelExecutor:
 
         self._step += 1
 
-        with mesh_scope(self.mesh):
+        with mesh_scope(self.mesh), record_event("pt:pexecutor/dispatch"):
             new_state, fetches = fn(state_rw, state_ro, feed_vals,
                                     step_arg(self._step,
                                              program.random_seed))
@@ -275,6 +284,20 @@ class ParallelExecutor:
             coll[m.group(1)] = coll.get(m.group(1), 0) + 1
         stats["collectives"] = coll
         return stats
+
+    # ------------------------------------------------------------------
+    def compile_counts(self):
+        """``{cache_key: n_shape_specializations}`` with ``Executor.
+        compile_counts``'s meaning: how many XLA executables stand
+        behind each lowered program (jax.jit re-specializes per
+        feed-shape signature). Keys are ``(program_uid,
+        program_version, fetch_names)``."""
+        return {k: int(fn._cache_size()) for k, fn in self._cache.items()}
+
+    def total_compiles(self):
+        """Total XLA executables cached across every lowered program —
+        the scalar a no-recompile check compares."""
+        return sum(self.compile_counts().values())
 
     @property
     def device_count(self):

@@ -40,6 +40,7 @@ steady state. Metrics add TTFT/TPOT windows and token counters —
 tools/servebench.py --decode turns them into
 ``llama_decode_serving_tok_s``.
 """
+import itertools
 import os
 import threading
 import time
@@ -47,6 +48,7 @@ import time
 import numpy as np
 
 from ..core.executor import Executor, global_scope
+from ..profiler import record_event
 from ..resilience import faultinject as _faultinject
 from ..resilience.retry import RetryPolicy, default_policy, with_retries
 from .batching import (QueueFullError, RequestTimeoutError,
@@ -86,7 +88,22 @@ _DECODE_COUNTERS = (
     "shed_batch_total", "evictions_total",
     "brownout_engage_total", "brownout_revert_total",
     "brownout_cap_max_new_total", "brownout_spec_off_total",
-    "brownout_chunk_defer_total")
+    "brownout_chunk_defer_total",
+    # the worker's own clock (PR 24), float seconds where the name ends
+    # in _s_total. Every instant of the worker's life goes to loop_busy
+    # (the loop's body) or loop_idle (the wait with nothing to do);
+    # the dispatch sums lie inside busy, each from the call to its
+    # tokens on the host, so busy - dispatches is the host time between
+    # dispatches. prefill_total counts REQUESTS, prefill_dispatch_total
+    # dispatches; real against padded (prefill_batch x bucket) prompt
+    # tokens is what a padded prefill wastes; queue_wait is admission
+    # instant - enqueued_at, summed over the requests prefill_total
+    # counts. The pt:engine/* spans share these boundaries.
+    "loop_busy_s_total", "loop_idle_s_total",
+    "decode_dispatch_s_total", "chunk_dispatch_s_total",
+    "prefill_dispatch_s_total", "prefill_dispatch_total",
+    "prefill_tokens_total", "prefill_padded_tokens_total",
+    "queue_wait_s_total")
 
 # priority rank -> the per-class shed counter it lands in
 _SHED_BY_RANK = {rank: f"shed_{name}_total"
@@ -181,15 +198,18 @@ class DecodeRequest:
     first-writer-wins (the worker and the watchdog can race, exactly
     as in batching.PendingResult). ``result()`` returns the generated
     tokens as a 1-D int64 array (prompt not included; ends at eos_id
-    inclusive when one was emitted)."""
+    inclusive when one was emitted). ``seq`` is the engine's sequence
+    number for the request: the ``req`` attribute of its trace spans."""
 
     __slots__ = ("prompt", "max_new", "deadline", "enqueued_at",
                  "ttft_s", "slo", "prefill_only", "handoff_state",
-                 "_event", "_result", "_error", "_settle_lock",
+                 "seq", "_event", "_result", "_error", "_settle_lock",
                  "_callbacks")
 
     def __init__(self, prompt, max_new, deadline, enqueued_at,
-                 slo=None, prefill_only=False, handoff_state=None):
+                 slo=None, prefill_only=False, handoff_state=None,
+                 seq=None):
+        self.seq = seq
         self.prompt = prompt
         self.max_new = max_new
         self.deadline = deadline
@@ -408,6 +428,7 @@ class DecodeEngine:
         # chaos hook: per-engine ungraceful worker kill (cluster chaos
         # targets one replica; the global fault point cannot)
         self._crash = threading.Event()
+        self._seq = itertools.count(1)       # DecodeRequest.seq
         if auto_start:
             self.start()
 
@@ -566,6 +587,16 @@ class DecodeEngine:
                 f"slo must be an SLOClass (serving.sched), got "
                 f"{type(slo).__name__}")
         prompt = np.asarray(prompt, dtype=np.int64).reshape(-1)
+        max_new = (self.config.max_new_tokens if max_new is None
+                   else int(max_new))
+        seq = next(self._seq)
+        with record_event("pt:engine/submit", req=seq,
+                          prompt_len=prompt.size, max_new=max_new):
+            return self._submit(seq, prompt, max_new, timeout, slo,
+                                prefill_only, queued_for_s)
+
+    def _submit(self, seq, prompt, max_new, timeout, slo, prefill_only,
+                queued_for_s):
         if prompt.size < 1:
             raise ValueError("prompt must hold at least one token")
         if prompt.size > self.config.prompt_buckets[-1]:
@@ -573,8 +604,6 @@ class DecodeEngine:
             raise BucketError(
                 f"prompt of {prompt.size} tokens exceeds the largest "
                 f"declared bucket {self.config.prompt_buckets[-1]}")
-        max_new = (self.config.max_new_tokens if max_new is None
-                   else int(max_new))
         if not 1 <= max_new <= self.config.max_new_tokens:
             raise ValueError(
                 f"max_new must be in [1, {self.config.max_new_tokens}]"
@@ -608,7 +637,7 @@ class DecodeEngine:
             prompt=prompt, max_new=max_new,
             deadline=None if timeout is None else now + float(timeout),
             enqueued_at=now - max(0.0, float(queued_for_s)),
-            slo=slo, prefill_only=prefill_only)
+            slo=slo, prefill_only=prefill_only, seq=seq)
         victim = None
         with self._cv:
             if self._closed:
@@ -704,7 +733,8 @@ class DecodeEngine:
         req = DecodeRequest(
             prompt=prompt, max_new=max_new,
             deadline=None if timeout is None else now + float(timeout),
-            enqueued_at=now, slo=slo, handoff_state=state)
+            enqueued_at=now, slo=slo, handoff_state=state,
+            seq=next(self._seq))
         req.ttft_s = state.get("ttft_s")
         self.metrics.incr("requests_total")
         if state.get("done"):
@@ -983,32 +1013,39 @@ class DecodeEngine:
                 return
             self.slots[idx] = None
             self.allocator.free(slot.pages)
+        with record_event("pt:engine/retire", req=slot.req.seq,
+                          tokens=len(slot.emitted)):
+            self._settle(slot, error, draining)
+        with self._cv:
+            self._cv.notify_all()
+
+    def _settle(self, slot, error, draining):
+        """A retired slot's bookkeeping and its request's settlement,
+        done-callbacks included (they run on this thread)."""
         now = time.monotonic()
         if error is not None:
             slot.req.set_error(error)
-        else:
-            n = len(slot.emitted)
-            if n > 1 and slot.first_token_at is not None:
-                tpot = (now - slot.first_token_at) / (n - 1)
-                self.metrics.observe_window("tpot_s", tpot)
-                slo = slot.req.slo
-                if slo is not None:
-                    if slo.tpot_target_s is not None:
-                        self.metrics.incr(
-                            "slo_tpot_met"
-                            if tpot <= slo.tpot_target_s
-                            else "slo_tpot_violated")
-                    self.metrics.observe_window(
-                        f"{slo.name}.tpot_s", tpot)
-            self.metrics.observe_latency(now - slot.req.enqueued_at)
-            self.metrics.incr("responses_total")
-            self.metrics.incr("retired_total")
-            if draining:
-                self.metrics.incr("drained_total")
-            slot.req.set_result(
-                np.asarray(slot.emitted, dtype=np.int64))
-        with self._cv:
-            self._cv.notify_all()
+            return
+        n = len(slot.emitted)
+        if n > 1 and slot.first_token_at is not None:
+            tpot = (now - slot.first_token_at) / (n - 1)
+            self.metrics.observe_window("tpot_s", tpot)
+            slo = slot.req.slo
+            if slo is not None:
+                if slo.tpot_target_s is not None:
+                    self.metrics.incr(
+                        "slo_tpot_met"
+                        if tpot <= slo.tpot_target_s
+                        else "slo_tpot_violated")
+                self.metrics.observe_window(
+                    f"{slo.name}.tpot_s", tpot)
+        self.metrics.observe_latency(now - slot.req.enqueued_at)
+        self.metrics.incr("responses_total")
+        self.metrics.incr("retired_total")
+        if draining:
+            self.metrics.incr("drained_total")
+        slot.req.set_result(
+            np.asarray(slot.emitted, dtype=np.int64))
 
     def _is_chunk_path(self, r):
         """Long prompts go through the chunked-prefill path when the
@@ -1136,13 +1173,20 @@ class DecodeEngine:
                                                     lens, tables)
                 return nxt
 
+            dispatch = record_event(
+                "pt:engine/prefill_dispatch", bucket=bucket,
+                rows=len(granted),
+                req=" ".join(str(r.seq) for r, _ in granted))
+            admitted_at = time.monotonic()
             try:
-                nxt = with_retries(
-                    _prefill_dispatch, policy=policy,
-                    deadline=min(deadlines) if deadlines else None,
-                    on_retry=lambda exc, n, delay:
-                        self.metrics.incr("retries_total"))
+                with dispatch:
+                    nxt = with_retries(
+                        _prefill_dispatch, policy=policy,
+                        deadline=min(deadlines) if deadlines else None,
+                        on_retry=lambda exc, n, delay:
+                            self.metrics.incr("retries_total"))
             except BaseException as exc:     # noqa: BLE001 — forwarded
+                self._tick(prefill_dispatch_s_total=dispatch.seconds)
                 with self._slots_lock:
                     for _, pages in granted:
                         self.allocator.free(pages)
@@ -1154,6 +1198,13 @@ class DecodeEngine:
                     r.set_error(exc)
                 continue
             self.breaker.record_success()
+            self._tick(
+                prefill_dispatch_total=1,
+                prefill_dispatch_s_total=dispatch.seconds,
+                prefill_tokens_total=int(lens[:len(granted)].sum()),
+                prefill_padded_tokens_total=pb * bucket,
+                queue_wait_s_total=sum(admitted_at - r.enqueued_at
+                                       for r, _ in granted))
             for j, (r, pages) in enumerate(granted):
                 self._install_first_token(r, pages, tables[j],
                                           int(nxt[j]), free[j])
@@ -1304,6 +1355,7 @@ class DecodeEngine:
         table[:len(pages)] = pages
         with self._slots_lock:
             self._chunk_jobs[idx] = _ChunkJob(r, pages, table)
+        self._tick(queue_wait_s_total=time.monotonic() - r.enqueued_at)
         return True
 
     def _fail_chunk_job(self, idx, exc):
@@ -1362,13 +1414,17 @@ class DecodeEngine:
                 return self._run_chunk_program(tokens, lens, offs,
                                                table)
 
+            dispatch = record_event("pt:engine/chunk_dispatch",
+                                    req=r.seq, offset=job.off)
             try:
-                nxt = with_retries(
-                    _chunk_dispatch, policy=policy,
-                    deadline=r.deadline,
-                    on_retry=lambda exc, n, delay:
-                        self.metrics.incr("retries_total"))
+                with dispatch:
+                    nxt = with_retries(
+                        _chunk_dispatch, policy=policy,
+                        deadline=r.deadline,
+                        on_retry=lambda exc, n, delay:
+                            self.metrics.incr("retries_total"))
             except BaseException as exc:  # noqa: BLE001 — forwarded
+                self._tick(chunk_dispatch_s_total=dispatch.seconds)
                 if self.breaker.record_failure():
                     self.metrics.incr("breaker_open_total")
                     self.health.to(HealthState.DEGRADED)
@@ -1377,7 +1433,8 @@ class DecodeEngine:
                 progressed = True
                 continue
             self.breaker.record_success()
-            self.metrics.incr("chunk_prefill_total")
+            self._tick(chunk_prefill_total=1,
+                       chunk_dispatch_s_total=dispatch.seconds)
             job.off += int(sl.size)
             progressed = True
             if job.off >= r.prompt.size:
@@ -1441,16 +1498,21 @@ class DecodeEngine:
                 return self._run_decode_program(toks, pos, table)
             return self._run_spec_program(toks, prev, pos, table)
 
+        dispatch = record_event("pt:engine/decode_dispatch",
+                                rows=len(active), spec=int(use_spec))
         try:
-            result = with_retries(
-                _step_dispatch, policy=policy, deadline=batch_deadline,
-                on_retry=lambda exc, n, delay:
-                    self.metrics.incr("retries_total"))
+            with dispatch:
+                result = with_retries(
+                    _step_dispatch, policy=policy,
+                    deadline=batch_deadline,
+                    on_retry=lambda exc, n, delay:
+                        self.metrics.incr("retries_total"))
             if not use_spec:
                 out = result
             else:
                 emitted, accepted = result
         except BaseException as exc:     # noqa: BLE001 — forwarded
+            self._tick(decode_dispatch_s_total=dispatch.seconds)
             if self.breaker.record_failure():
                 self.metrics.incr("breaker_open_total")
                 self.health.to(HealthState.DEGRADED)
@@ -1461,7 +1523,8 @@ class DecodeEngine:
         self.breaker.record_success()
         if self.health.state == HealthState.DEGRADED:
             self.health.to(HealthState.READY)
-        self.metrics.incr("decode_batches_total")
+        self._tick(decode_batches_total=1,
+                   decode_dispatch_s_total=dispatch.seconds)
         draining = self._closed and not self._stop.is_set()
         eos = c.eos_id
         n_new = 0
@@ -1509,8 +1572,20 @@ class DecodeEngine:
         return row[:room], done
 
     # -- worker / watchdog -----------------------------------------------
+    def _tick(self, clock="loop_busy_s_total", **deltas):
+        """Worker thread only. Credits the time since the last tick to
+        ``clock`` and adds ``deltas``, under one lock: a snapshot then
+        holds a dispatch's count, its seconds and the busy time up to
+        its end together, and busy + idle is the worker's life with
+        nothing left out."""
+        now = time.perf_counter()
+        deltas[clock] = now - self._clock
+        self._clock = now
+        self.metrics.incr_many(deltas)
+
     def _worker_loop(self):
         policy = self.config.retry_policy or default_policy()
+        self._clock = time.perf_counter()
         while not self._stop.is_set():
             # the crash point is consumed only while this engine has
             # work: fires() advances a process-global clock, so an IDLE
@@ -1521,18 +1596,29 @@ class DecodeEngine:
                     self._has_work()
                     and _faultinject.fires("serving_worker_crash")):
                 return   # models SIGKILL — the watchdog's job
-            self.health.beat()
-            moved = self._update_brownout()
-            swept = self._sweep_expired()
-            admitted = self._admit(policy)
-            chunked = self._step_chunks(policy)
-            stepped = self._step(policy)
+            with record_event("pt:engine/loop"):
+                self.health.beat()
+                moved = self._update_brownout()
+                swept = self._sweep_expired()
+                with record_event(
+                        "pt:engine/admit", queued=len(self._queue),
+                        free=sum(s is None for s in self.slots)):
+                    admitted = self._admit(policy)
+                chunked = self._step_chunks(policy)
+                with record_event(
+                        "pt:engine/step",
+                        rows=sum(s is not None for s in self.slots)):
+                    stepped = self._step(policy)
+            self._tick()
             if self._closed and not self._has_work():
                 break    # drain complete
             if not (admitted or chunked or stepped or swept or moved):
                 with self._cv:
                     if not self._queue and not self._closed:
-                        self._cv.wait(0.02)
+                        with record_event("pt:engine/idle"):
+                            self._cv.wait(0.02)
+                        self._tick("loop_idle_s_total")
+        self._tick()
         for req in self._take_pending():
             req.set_error(ServerClosedError("engine closed"))
 

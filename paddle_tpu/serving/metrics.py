@@ -64,6 +64,17 @@ class ServingMetrics:
                                f"of {sorted(self._counters)}")
             self._counters[name] += n
 
+    def incr_many(self, deltas):
+        """Several counters under ONE lock acquisition, so that a
+        snapshot never holds a count without the seconds that belong
+        to it. Nothing is added if any name is unknown."""
+        with self._lock:
+            unknown = [n for n in deltas if n not in self._counters]
+            if unknown:
+                raise KeyError(f"unknown serving counter(s) {unknown}")
+            for name, n in deltas.items():
+                self._counters[name] += n
+
     def observe_batch(self, n_rows, bucket_rows, batch_latency_s):
         """One executed micro-batch: real rows, padded bucket capacity,
         and the worker-side batch service time."""
