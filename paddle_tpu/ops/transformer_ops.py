@@ -947,15 +947,23 @@ class _PagedRunner:
 
     - ``forward(h, k_pages, v_pages, table, pos0, t_len)`` — operate
       directly on the [L, n_pages, page_size, g, hd] page pools
-      through ``table`` [B, max_pages] (prefill: one big window, one
-      gather/scatter amortized over the whole prompt).
-    - ``gather``/``forward_dense``/``scatter`` — hoist the pool→dense
-      gather OUT of a multi-step loop: gather each row's pages to a
-      dense [L, B, kmax, g, hd] cache once, run every step against it
-      (a step then costs the same ops as the contiguous cache), and
-      scatter the touched pages back once at the end. The decode and
-      speculative step ops use this; what per-step page indexing
-      would cost is not re-measured on this installation.
+      through ``table`` [B, max_pages]: each layer writes the window's
+      K,V at ``[layer, page, offset]`` and attends over the row's
+      pages of that layer (both prefill ops).
+    - ``gather``/``forward_dense``/``scatter`` — gather each row's
+      pages to a dense [L, B, kmax, g, hd] cache once a dispatch, run
+      every step against it (each layer writes ``[layer, row, q_pos]``
+      and attends over ``kd[layer]``), and scatter the pages back
+      once at the end. The decode and speculative step ops use this.
+
+    In both, the layer scan CARRIES the whole [L, ...] K and V arrays
+    beside ``h`` and scans over (weights, layer index): a scan's
+    ``ys`` is a fresh buffer that cannot alias its ``xs``, so caches
+    passed that way are rebuilt whole on every call — on every token,
+    inside the decode op's step loop. Carried, they alias from the
+    dispatch's gather to its scatter, and a step touches the rows it
+    writes and the bytes attention reads
+    (tests/test_paged_cache_inplace.py holds both to it).
 
     The dense view holds bitwise the same values the pools do, so both
     forms produce identical numerics. int8 ``<Slot>Scale`` companions
@@ -1000,14 +1008,18 @@ class _PagedRunner:
 
     def _stack_forward(self, h, k_caches, v_caches, q_pos, t_len,
                        attend_write):
-        """Layer scan shared by both forms; ``attend_write(q, k, v,
-        kc, vc) -> (out, kc2, vc2)`` owns the cache update + attend."""
-        def block_step(p, h, kc, vc):
+        """Layer scan shared by both forms. The whole [L, ...] caches
+        ride in the carry and the layer index in ``xs``;
+        ``attend_write(q, k, v, kc, vc, layer) -> (out, kc2, vc2)``
+        owns layer ``layer``'s cache update + attend."""
+        def layer(carry, xs):
+            h, kc, vc = carry
+            p, lyr = xs
             caches = {}
 
             def attend(q, k, v):
                 out, caches["k"], caches["v"] = attend_write(
-                    q, k, v, kc, vc)
+                    q, k, v, kc, vc, lyr)
                 return out
 
             h = decoder_block(p, h, n_heads=self.n_heads,
@@ -1015,16 +1027,12 @@ class _PagedRunner:
                               eps=self.eps, pos=q_pos,
                               attend_fn=attend,
                               moe_top_k=self.moe_top_k)
-            return h, caches["k"], caches["v"]
+            return (h, caches["k"], caches["v"]), None
 
-        def layer(carry, xs):
-            h = carry
-            p, kc, vc = xs
-            h, kc, vc = block_step(p, h, kc, vc)
-            return h, (kc, vc)
-
-        h, (k_caches, v_caches) = jax.lax.scan(
-            layer, h, (self.params, k_caches, v_caches))
+        (h, k_caches, v_caches), _ = jax.lax.scan(
+            layer, (h, k_caches, v_caches),
+            (self.params,
+             jnp.arange(k_caches.shape[0], dtype=jnp.int32)))
         return h, k_caches, v_caches
 
     # -- paged form (prefill) --------------------------------------------
@@ -1033,13 +1041,13 @@ class _PagedRunner:
         kmax = table.shape[1] * self.page_size
         q_pos = pos0[:, None] + jnp.arange(t_len, dtype=jnp.int32)[None]
 
-        def attend_write(q, k, v, kp, vp):
+        def attend_write(q, k, v, kp, vp, lyr):
             pg = jnp.take_along_axis(table, q_pos // self.page_size,
                                      axis=1)
-            kp2 = kp.at[pg, q_pos % self.page_size].set(k)
-            vp2 = vp.at[pg, q_pos % self.page_size].set(v)
-            k_all = kp2[table].reshape(b, kmax, self.n_kv, self.hd)
-            v_all = vp2[table].reshape(b, kmax, self.n_kv, self.hd)
+            kp2 = kp.at[lyr, pg, q_pos % self.page_size].set(k)
+            vp2 = vp.at[lyr, pg, q_pos % self.page_size].set(v)
+            k_all = kp2[lyr, table].reshape(b, kmax, self.n_kv, self.hd)
+            v_all = vp2[lyr, table].reshape(b, kmax, self.n_kv, self.hd)
             return (self._attend_math(q, k_all, v_all, q_pos, t_len),
                     kp2, vp2)
 
@@ -1071,11 +1079,11 @@ class _PagedRunner:
         rows = jnp.arange(b)
         q_pos = pos0[:, None] + jnp.arange(t_len, dtype=jnp.int32)[None]
 
-        def attend_write(q, k, v, kd, vd):
-            kd2 = kd.at[rows[:, None], q_pos].set(k)
-            vd2 = vd.at[rows[:, None], q_pos].set(v)
-            return (self._attend_math(q, kd2, vd2, q_pos, t_len),
-                    kd2, vd2)
+        def attend_write(q, k, v, kd, vd, lyr):
+            kd2 = kd.at[lyr, rows[:, None], q_pos].set(k)
+            vd2 = vd.at[lyr, rows[:, None], q_pos].set(v)
+            return (self._attend_math(q, kd2[lyr], vd2[lyr], q_pos,
+                                      t_len), kd2, vd2)
 
         return self._stack_forward(h, k_dense, v_dense, q_pos, t_len,
                                    attend_write)
@@ -1215,8 +1223,8 @@ def _llama_paged_decode(ctx, ins, attrs):
         page_size=attrs["page_size"], head_scale=head_scale)
     steps = max(1, int(attrs.get("steps", 1)))
 
-    # dense form: pool -> dense gather once, ``steps`` cheap steps,
-    # one scatter back — not per step (see _PagedRunner)
+    # dense form: pool -> dense gather once, ``steps`` steps that
+    # carry the dense caches in place, one scatter back (_PagedRunner)
     kd, vd = run.gather(kp, table), run.gather(vp, table)
 
     def step(carry, _):

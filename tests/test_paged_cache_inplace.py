@@ -1,0 +1,275 @@
+"""The paged ops keep their KV caches in place.
+
+``_PagedRunner``'s layer scan carries the whole [L, ...] K and V arrays
+and updates them where they lie. The form it replaced passed them as the
+scan's ``xs`` and rebuilt them as its ``ys``: a fresh buffer a call, so
+the decode op copied its whole dense cache on every token (PERF.md
+section 6, PR 25). Two guards:
+
+- structure: no cache- or pool-shaped array is an ``xs`` or ``ys`` of a
+  scan in any of the four ops' jaxprs, and the compiled module holds no
+  whole-cache ``copy`` inside a loop;
+- bit parity: the replaced form, frozen below as ``_XsYsRunner``, gives
+  the same tokens and the same pools, bit for bit.
+"""
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from paddle_tpu.ops import transformer_ops as T
+
+L, LD = 3, 2                      # target / draft depth
+D, NH, NKV, HD, F, V = 32, 4, 2, 8, 64, 50
+B, PS, MP, NP = 4, 4, 5, 13       # slots, page size, pages a row, pool
+KMAX = PS * MP
+ATTRS = dict(n_heads=NH, n_kv_heads=NKV, draft_n_heads=NH,
+             draft_n_kv_heads=NKV, rope_base=10000.0, epsilon=1e-5,
+             page_size=PS, gamma=3)
+
+# rows of unequal length; row 0 crosses from its 2nd to its 3rd page
+# inside a 4-step dispatch (positions 7, 8, 9, 10); row 2 is an inactive
+# slot: token 0, position 1, the all-null table
+TABLE = np.array([[1, 2, 3, 0, 0], [4, 5, 6, 7, 0], [0, 0, 0, 0, 0],
+                  [8, 9, 10, 11, 12]], np.int32)
+POS = np.array([7, 13, 1, 2], np.int32)
+TOK = np.array([5, 17, 0, 33], np.int32)
+PREV = np.array([9, 2, 0, 41], np.int32)
+
+
+class _XsYsRunner(T._PagedRunner):
+    """The form this repo ran until PR 25, kept as the plain reference:
+    the layer scan takes each layer's cache as ``xs`` and stacks the
+    updated caches as ``ys``."""
+
+    def _stack_forward(self, h, k_caches, v_caches, q_pos, t_len,
+                       attend_write):
+        def layer(h, xs):
+            p, kc, vc = xs
+            caches = {}
+
+            def attend(q, k, v):
+                out, caches["k"], caches["v"] = attend_write(
+                    q, k, v, kc, vc)
+                return out
+
+            h = T.decoder_block(p, h, n_heads=self.n_heads,
+                                n_kv=self.n_kv, base=self.base,
+                                eps=self.eps, pos=q_pos, attend_fn=attend,
+                                moe_top_k=self.moe_top_k)
+            return h, (caches["k"], caches["v"])
+
+        h, (k_caches, v_caches) = jax.lax.scan(
+            layer, h, (self.params, k_caches, v_caches))
+        return h, k_caches, v_caches
+
+    def forward(self, h, k_pages, v_pages, table, pos0, t_len):
+        b, kmax = h.shape[0], table.shape[1] * self.page_size
+        q_pos = pos0[:, None] + jnp.arange(t_len, dtype=jnp.int32)[None]
+
+        def attend_write(q, k, v, kp, vp):
+            pg = jnp.take_along_axis(table, q_pos // self.page_size, axis=1)
+            kp2 = kp.at[pg, q_pos % self.page_size].set(k)
+            vp2 = vp.at[pg, q_pos % self.page_size].set(v)
+            k_all = kp2[table].reshape(b, kmax, self.n_kv, self.hd)
+            v_all = vp2[table].reshape(b, kmax, self.n_kv, self.hd)
+            return self._attend_math(q, k_all, v_all, q_pos, t_len), kp2, vp2
+
+        return self._stack_forward(h, k_pages, v_pages, q_pos, t_len,
+                                   attend_write)
+
+    def forward_dense(self, h, k_dense, v_dense, pos0, t_len):
+        rows = jnp.arange(h.shape[0])
+        q_pos = pos0[:, None] + jnp.arange(t_len, dtype=jnp.int32)[None]
+
+        def attend_write(q, k, v, kd, vd):
+            kd2 = kd.at[rows[:, None], q_pos].set(k)
+            vd2 = vd.at[rows[:, None], q_pos].set(v)
+            return self._attend_math(q, kd2, vd2, q_pos, t_len), kd2, vd2
+
+        return self._stack_forward(h, k_dense, v_dense, q_pos, t_len,
+                                   attend_write)
+
+
+def _model(key, n_layers, prefix="", quant=False):
+    """One toy model's op inputs: bf16, or int8 with ``<Slot>Scale``."""
+    shapes = {"Wq": (D, NH * HD), "Wk": (D, NKV * HD), "Wv": (D, NKV * HD),
+              "Wo": (NH * HD, D), "WGate": (D, F), "WUp": (D, F),
+              "WDown": (F, D)}
+    keys = iter(jax.random.split(key, 16))
+    ins = {}
+    for slot, (m, n) in shapes.items():
+        w = jax.random.normal(next(keys), (n_layers, m, n)) * 0.2
+        if quant:
+            scale = jnp.max(jnp.abs(w), axis=1, keepdims=True) / 127.0
+            ins[prefix + slot] = jnp.round(w / scale).astype(jnp.int8)
+            ins[prefix + slot + "Scale"] = scale.astype(jnp.float32)
+        else:
+            ins[prefix + slot] = w.astype(jnp.bfloat16)
+    ones = jnp.ones((n_layers, D), jnp.bfloat16)
+    ins[prefix + "AttnNorm"] = ins[prefix + "MlpNorm"] = ones
+    ins[prefix + "Emb"] = jax.random.normal(
+        next(keys), (V, D)).astype(jnp.bfloat16)
+    ins[prefix + "FinalNorm"] = jnp.ones((D,), jnp.bfloat16)
+    head = jax.random.normal(next(keys), (D, V)) * 0.2
+    if quant:
+        hs = jnp.max(jnp.abs(head), axis=0) / 127.0
+        ins[prefix + "LmHead"] = jnp.round(head / hs).astype(jnp.int8)
+        ins[prefix + "LmHeadScale"] = hs.astype(jnp.float32)
+    else:
+        ins[prefix + "LmHead"] = head.astype(jnp.bfloat16)
+    pools = jax.random.normal(next(keys), (2, n_layers, NP, PS, NKV, HD))
+    pages = "DraftKPages DraftVPages" if prefix else "KPages VPages"
+    for name, pool in zip(pages.split(), pools.astype(jnp.bfloat16)):
+        ins[name] = pool
+    return ins
+
+
+def _case(op_name, steps=4, quant=False):
+    """(op, inputs, attrs) of one paged op at the toy shapes."""
+    ins = _model(jax.random.PRNGKey(0), L, quant=quant)
+    ins["Table"] = jnp.asarray(TABLE)
+    attrs = dict(ATTRS, steps=steps)
+    if op_name == "llama_paged_decode":
+        ins.update(Tokens=jnp.asarray(TOK), Positions=jnp.asarray(POS))
+    elif op_name == "llama_paged_spec_step":
+        ins.update(_model(jax.random.PRNGKey(1), LD, prefix="Draft"))
+        ins.update(Tokens=jnp.asarray(TOK), Prev=jnp.asarray(PREV),
+                   Positions=jnp.asarray(POS))
+    else:
+        width = 6                 # a window that crosses a page boundary
+        toks = jax.random.randint(jax.random.PRNGKey(2), (B, width), 0, V)
+        ins.update(Tokens=toks, Lens=jnp.asarray([6, 3, 1, 5], jnp.int32))
+        if op_name == "llama_paged_prefill_chunk":
+            ins["Offsets"] = jnp.asarray([3, 10, 0, 6], jnp.int32)
+    return getattr(T, "_" + op_name), ins, attrs
+
+
+def _jit(op, attrs):
+    """The op as one jitted function of its inputs."""
+    def fn(ins):
+        out = op(None, {k: [v] for k, v in ins.items()}, attrs)
+        return {k: v[0] for k, v in out.items()}
+
+    return jax.jit(fn)
+
+
+PAGED_OPS = ("llama_paged_decode", "llama_paged_prefill",
+             "llama_paged_prefill_chunk", "llama_paged_spec_step")
+
+
+def _cache_shapes(ins):
+    """Every pool shape among the inputs, and its dense view's."""
+    shapes = set()
+    for name, x in ins.items():
+        if name.endswith("Pages"):
+            shapes.add(tuple(x.shape))
+            shapes.add((x.shape[0], B, KMAX) + tuple(x.shape[-2:]))
+    return shapes
+
+
+def _scans(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _scans(sub)
+
+
+def _scan_cache_use(closed_jaxpr, shapes):
+    """(names of scans with a cache as xs or ys, number that carry one)."""
+    streamed, carried = [], 0
+    for eqn in _scans(closed_jaxpr.jaxpr):
+        nc, nk = eqn.params["num_consts"], eqn.params["num_carry"]
+        xs_ys = eqn.invars[nc + nk:] + eqn.outvars[nk:]
+        if any(tuple(v.aval.shape) in shapes for v in xs_ys):
+            streamed.append(str(eqn.source_info.name_stack) or "scan")
+        carried += any(tuple(v.aval.shape) in shapes
+                       for v in eqn.invars[nc:nc + nk])
+    return streamed, carried
+
+
+def _loop_copies(hlo_text, shapes):
+    """``copy`` instructions over a cache shape in any computation a
+    ``while`` reaches."""
+    comps = {}
+    for block in re.split(r"\n(?=(?:ENTRY )?%?[\w.\-]+ \(.*\) -> .*\{\n)",
+                          hlo_text):
+        m = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(", block)
+        if m:
+            comps[m.group(1)] = block
+    called = re.compile(
+        r"(?:body|condition|calls|to_apply)=%?([\w.\-]+)")
+    todo = [n for blk in comps.values()
+            for n in re.findall(r"body=%?([\w.\-]+)", blk)]
+    reached = set()
+    while todo:
+        name = todo.pop()
+        if name in reached or name not in comps:
+            continue
+        reached.add(name)
+        todo += called.findall(comps[name])
+    dims = {",".join(map(str, s)) for s in shapes}
+    found = []
+    for name in reached:
+        for line in comps[name].splitlines():
+            m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* "
+                         r"copy\(", line)
+            if m and m.group(1) in dims:
+                found.append(line.strip()[:120])
+    return found
+
+
+def _structure(op, ins, attrs):
+    """(scans streaming a cache, scans carrying one, in-loop copies)."""
+    shapes = _cache_shapes(ins)
+    fn = _jit(op, attrs)
+    streamed, carried = _scan_cache_use(jax.make_jaxpr(fn)(ins), shapes)
+    copies = _loop_copies(fn.lower(ins).compile().as_text(), shapes)
+    return streamed, carried, copies
+
+
+@pytest.mark.parametrize("op_name", PAGED_OPS)
+def test_caches_are_carried_not_streamed(op_name):
+    streamed, carried, copies = _structure(*_case(op_name))
+    assert not streamed, (
+        f"{op_name}: a scan takes or returns a KV cache as xs/ys, which "
+        f"copies the whole cache on every call: {streamed}")
+    assert carried, f"{op_name}: no scan carries a KV cache"
+    assert not copies, f"{op_name}: whole-cache copy inside a loop: {copies}"
+
+
+def test_structure_check_sees_the_replaced_form(monkeypatch):
+    """The detector is not vacuous: the frozen xs/ys form trips both
+    halves of it, on this backend too."""
+    monkeypatch.setattr(T, "_PagedRunner", _XsYsRunner)
+    streamed, _, copies = _structure(*_case("llama_paged_decode"))
+    assert streamed and copies
+
+
+@pytest.mark.parametrize("op_name,steps,quant", [
+    ("llama_paged_decode", 1, False), ("llama_paged_decode", 4, False),
+    ("llama_paged_decode", 1, True), ("llama_paged_decode", 4, True),
+    ("llama_paged_prefill", 1, False), ("llama_paged_prefill", 1, True),
+    ("llama_paged_prefill_chunk", 1, False),
+    ("llama_paged_spec_step", 1, False)])
+def test_bit_parity_with_the_replaced_form(op_name, steps, quant,
+                                           monkeypatch):
+    op, ins, attrs = _case(op_name, steps=steps, quant=quant)
+    new = _jit(op, attrs)(ins)
+    # from here ``_make_paged_runner`` builds the frozen reference
+    monkeypatch.setattr(T, "_PagedRunner", _XsYsRunner)
+    old = _jit(op, attrs)(ins)
+    assert sorted(new) == sorted(old)
+    for name in sorted(new):
+        a, b = np.asarray(new[name]), np.asarray(old[name])
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        assert np.array_equal(a.view(np.uint8), b.view(np.uint8)), (
+            f"{op_name} steps={steps} quant={quant}: {name} differs")
+    if op_name == "llama_paged_decode":
+        assert new["OutTokens"].shape == (B, steps)
+        # the dispatch wrote: the pools are not what went in
+        assert not np.array_equal(np.asarray(new["KPagesOut"], np.float32),
+                                  np.asarray(ins["KPages"], np.float32))
