@@ -379,7 +379,7 @@ def phase_server(cfg):
                 f"{alone.tolist()} vs {outs[again].tolist()}"
             engine.assert_no_recompiles()
             assert engine.exe.place.device.platform == chip
-            found = _platforms([engine._kp, engine._vp]
+            found = _platforms(list(engine._pools)
                                + _scope_arrays(scope))
             assert found == {chip}, \
                 f"pools/weights live on {found}, not on {chip}"

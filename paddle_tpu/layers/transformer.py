@@ -10,6 +10,7 @@ __all__ = ["rms_norm", "rope", "multihead_attention", "silu", "moe_ffn",
            "llama_spec_generate", "llama_paged_prefill",
            "llama_paged_prefill_chunk",
            "llama_paged_decode", "llama_paged_spec_step",
+           "block_paged_op",
            "fused_head_cross_entropy", "llama_stack_1f1b_loss"]
 
 
@@ -731,3 +732,62 @@ def silu(x, name=None):
     helper.append_op(type="silu", inputs={"X": [x.name]},
                      outputs={"Out": [out.name]})
     return out
+
+
+def block_paged_op(kind, feeds, pools, *, params, lead_params, attrs,
+                   vocab_size, dtype, steps=1, name="blocks",
+                   lead_name="lead", emb_name="tok_emb",
+                   final_norm_name="final_norm", head_name="lm_head"):
+    """One paged step program of a model whose block kinds are attributes
+    (ops/transformer_ops.py block_paged_*; models/latent_moe.py builds
+    them). ``kind``: ``prefill`` | ``prefill_chunk`` | ``decode``;
+    ``feeds``: the op's data inputs by slot (Tokens, Lens, Offsets,
+    Positions, Table); ``pools``: the cache pools; ``params`` /
+    ``lead_params``: slot -> (suffix, shape, dtype) of the stacked
+    layers' and the leading dense layers' parameters, named
+    ``{name}.{suffix}`` / ``{lead_name}.{suffix}``. Returns (tokens,
+    pools_out, logits, picks, stats)."""
+    helper = LayerHelper("block_paged_" + kind, name=name)
+    ninit = init_mod.Normal(0.0, 0.02)
+
+    def make(pname, shape, pdtype, init=ninit):
+        return helper.create_parameter(
+            ParamAttr(name=pname, initializer=init), list(shape), pdtype)
+
+    dim = params["AttnNorm"][1][-1]
+    inputs = {"Emb": [make(emb_name, [vocab_size, dim], dtype).name],
+              "FinalNorm": [make(final_norm_name, [dim], dtype,
+                                 init_mod.Constant(1.0)).name],
+              "LmHead": [make(head_name, [dim, vocab_size], dtype).name]}
+    for prefix, scope_name, table in (("", name, params),
+                                      ("Lead", lead_name, lead_params)):
+        for slot, (suffix, shape, pdtype) in table.items():
+            inputs[prefix + slot] = [make(f"{scope_name}.{suffix}", shape,
+                                          pdtype).name]
+    inputs.update({slot: [v.name] for slot, v in feeds.items()})
+    inputs["Pools"] = [p.name for p in pools]
+    tokens = feeds["Tokens"]
+    b = tokens.shape[0]
+    shape = [b, int(steps)] if kind == "decode" else [b]
+    out = helper.create_variable_for_type_inference(tokens.dtype,
+                                                    shape=shape)
+    logits = helper.create_variable_for_type_inference(
+        "float32", shape=shape + [vocab_size])
+    stats = helper.create_variable_for_type_inference("int32", shape=[5])
+    picks = helper.create_variable_for_type_inference(
+        "int32", shape=shape + [params["MoeRouter"][1][0],
+                                int(attrs["moe_top_k"])])
+    pools_out = [helper.create_variable_for_type_inference(
+        p.dtype, shape=p.shape) for p in pools]
+    attrs = dict(attrs)
+    if kind == "decode":
+        attrs["steps"] = int(steps)
+    helper.append_op(
+        type="block_paged_" + kind, inputs=inputs,
+        outputs={"OutTokens" if kind == "decode" else "NextTok":
+                 [out.name], "Logits": [logits.name],
+                 "Picks": [picks.name],
+                 "PoolsOut": [p.name for p in pools_out],
+                 "Stats": [stats.name]},
+        attrs=attrs)
+    return out, pools_out, logits, picks, stats

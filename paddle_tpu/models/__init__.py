@@ -8,6 +8,7 @@ from . import stacked_dynamic_lstm  # noqa: F401
 from . import machine_translation   # noqa: F401
 from . import transformer     # noqa: F401
 from . import llama           # noqa: F401
+from . import latent_moe      # noqa: F401
 from . import word2vec        # noqa: F401
 from . import recommender     # noqa: F401
 from . import ctr             # noqa: F401

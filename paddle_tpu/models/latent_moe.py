@@ -1,0 +1,241 @@
+"""Decoder-only models of the latent-attention / routed-experts /
+hyper-connection kind (Xing4.0-29B-A4B's block), for serving.
+
+The block is ops/transformer_ops.py ``block_forward`` at these kinds:
+multi-head latent attention whose cache holds one ``[kv_rank +
+rope_dim]`` entry a token a layer (DeepSeek-V2, arXiv:2405.04434) with
+YaRN-scaled rotary positions; sigmoid-scored routed experts with a
+selection bias and a shared expert, drop-free (ops/moe.py); the residual
+stream widened to ``n_streams`` and mixed by manifold-constrained
+hyper-connections (arXiv:2512.24880). The first ``n_dense_layers`` have
+a dense SwiGLU in the experts' place.
+
+Serving only: ``build_paged_programs`` gives DecodeEngine the prefill,
+chunk and decode programs; there is no training graph, no fused
+generator and no speculative form (the published multi-token-prediction
+layer is left out).
+"""
+from dataclasses import dataclass
+
+from .. import layers
+from ..layers import transformer as tfl
+from ..ops.transformer_ops import PAGED_STATS, yarn_inv_freq, yarn_mscale
+from .llama import PagedDecodePrograms, prefill_buckets_reached
+
+__all__ = ["LatentMoEConfig", "LATENT_MOE_TINY"]
+
+
+@dataclass
+class LatentMoEConfig:
+    name: str = "latent-moe"
+    vocab_size: int = 131072
+    dim: int = 3584
+    n_layers: int = 40
+    n_dense_layers: int = 2
+    n_heads: int = 32
+    q_rank: int = 768
+    kv_rank: int = 512
+    nope_dim: int = 128
+    rope_dim: int = 64
+    v_dim: int = 128
+    ffn_hidden: int = 9216           # the leading dense layers' SwiGLU
+    n_experts: int = 64              # routed experts held
+    moe_top_k: int = 4
+    expert_hidden: int = 1024
+    n_shared: int = 1
+    route_scale: float = 2.0
+    n_streams: int = 4               # hc_mult
+    sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp: tuple = (-30.0, 30.0)
+    norm_eps: float = 1e-6
+    rope_base: float = 10000.0
+    rope_factor: float = 64.0
+    rope_original_max: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale_all_dim: float = 1.0
+    dtype: str = "bfloat16"
+
+    @property
+    def entry_dim(self):
+        """Values a token leaves in a layer's cache."""
+        return self.kv_rank + self.rope_dim
+
+    def cache_spec(self):
+        """A token's cache entries in one layer: [(shape, dtype)]."""
+        return [((self.entry_dim,), self.dtype)]
+
+    def softmax_scale(self):
+        m = yarn_mscale(self.rope_factor, self.rope_mscale_all_dim)
+        return (self.nope_dim + self.rope_dim) ** -0.5 * m * m
+
+    def block_attrs(self, page_size):
+        return {
+            "n_heads": self.n_heads, "epsilon": self.norm_eps,
+            "attention": "latent", "ffn": "routed", "residual": "mhc",
+            "moe_top_k": self.moe_top_k, "scoring": "sigmoid",
+            "route_scale": self.route_scale, "kv_rank": self.kv_rank,
+            "rope_dim": self.rope_dim, "nope_dim": self.nope_dim,
+            "v_dim": self.v_dim,
+            "rope_inv_freq": [float(x) for x in yarn_inv_freq(
+                self.rope_dim, self.rope_base, self.rope_factor,
+                self.rope_original_max, self.rope_beta_fast,
+                self.rope_beta_slow)],
+            "softmax_scale": self.softmax_scale(),
+            "n_streams": self.n_streams,
+            "sinkhorn_iters": self.sinkhorn_iters, "hc_eps": self.hc_eps,
+            "hc_clamp": [float(x) for x in self.hc_clamp],
+            "page_size": int(page_size)}
+
+    def layer_params(self, n_layers, routed):
+        """slot -> (suffix, shape, dtype) of ``n_layers`` stacked layers
+        with a routed (else dense) feed-forward. The router, its bias and
+        the hyper-connection coefficients are float32 whatever ``dtype``
+        is."""
+        L, D, H, n = n_layers, self.dim, self.n_heads, self.n_streams
+        dt, mix = self.dtype, 2 * n + n * n
+        out = {
+            "AttnNorm": ("attn_norm", [L, D], dt),
+            "MlpNorm": ("mlp_norm", [L, D], dt),
+            "Wqa": ("wqa", [L, D, self.q_rank], dt),
+            "QNorm": ("q_norm", [L, self.q_rank], dt),
+            "Wqb": ("wqb", [L, self.q_rank,
+                            H * (self.nope_dim + self.rope_dim)], dt),
+            "Wkva": ("wkva", [L, D, self.entry_dim], dt),
+            "KvNorm": ("kv_norm", [L, self.kv_rank], dt),
+            "Wkvb": ("wkvb", [L, self.kv_rank,
+                              H * (self.nope_dim + self.v_dim)], dt),
+            "Wo": ("wo", [L, H * self.v_dim, D], dt)}
+        for which in ("Attn", "Mlp"):
+            low = which.lower()
+            out["Hc" + which + "Phi"] = (f"hc_{low}_phi", [L, n * D, mix],
+                                         "float32")
+            out["Hc" + which + "Alpha"] = (f"hc_{low}_alpha", [L, 3],
+                                           "float32")
+            out["Hc" + which + "Bias"] = (f"hc_{low}_bias", [L, mix],
+                                          "float32")
+        if not routed:
+            F = self.ffn_hidden
+            out.update(WGate=("w_gate", [L, D, F], dt),
+                       WUp=("w_up", [L, D, F], dt),
+                       WDown=("w_down", [L, F, D], dt))
+            return out
+        E, F, S = self.n_experts, self.expert_hidden, \
+            self.n_shared * self.expert_hidden
+        out.update(MoeRouter=("moe_router", [L, D, E], "float32"),
+                   MoeBias=("moe_bias", [L, E], "float32"),
+                   MoeWGate=("moe_w_gate", [L, E, D, F], dt),
+                   MoeWUp=("moe_w_up", [L, E, D, F], dt),
+                   MoeWDown=("moe_w_down", [L, E, F, D], dt))
+        if S:
+            out.update(ShWGate=("sh_w_gate", [L, D, S], dt),
+                       ShWUp=("sh_w_up", [L, D, S], dt),
+                       ShWDown=("sh_w_down", [L, S, D], dt))
+        return out
+
+    def param_shapes(self):
+        """Every parameter the programs read from the scope: name ->
+        (shape, dtype). ``blocks.*`` are the routed layers, stacked;
+        ``lead.*`` the leading dense ones."""
+        out = {"tok_emb": ([self.vocab_size, self.dim], self.dtype),
+               "final_norm": ([self.dim], self.dtype),
+               "lm_head": ([self.dim, self.vocab_size], self.dtype)}
+        for scope_name, n, routed in (
+                ("lead", self.n_dense_layers, False),
+                ("blocks", self.n_layers - self.n_dense_layers, True)):
+            if n:
+                for suffix, shape, dt in self.layer_params(
+                        n, routed).values():
+                    out[f"{scope_name}.{suffix}"] = (shape, dt)
+        return out
+
+    def build_paged_programs(self, *, max_batch, page_size, n_pages,
+                             pages_per_seq, prompt_buckets,
+                             decode_block=1, prefill_batch=1,
+                             quantize=False, draft_cfg=None, gamma=4,
+                             chunk_size=None):
+        """The paged step programs DecodeEngine runs for this model: as
+        models/llama.py build_llama_paged_programs, over ONE pool of
+        ``[n_layers, n_pages, page_size, kv_rank + rope_dim]``, each
+        program also returning its float32 logits, the experts its routed
+        layers picked for the tokens those logits belong to, and
+        PAGED_STATS. The
+        scope must already hold ``param_shapes()``."""
+        if draft_cfg is not None:
+            raise NotImplementedError(
+                f"{self.name}: latent-attention models have no "
+                "speculative paged form (the multi-token-prediction "
+                "layer is not built); drop draft_cfg")
+        if quantize:
+            raise NotImplementedError(
+                f"{self.name}: served in {self.dtype} as published; the "
+                "int8 path (qmat) covers the dense Llama block only; "
+                "drop quantize")
+        if self.n_layers <= self.n_dense_layers:
+            raise ValueError("no routed layer after the dense ones")
+        from ..core import framework
+        pool_shape = [self.n_layers, n_pages, page_size, self.entry_dim]
+        common = dict(
+            params=self.layer_params(
+                self.n_layers - self.n_dense_layers, True),
+            lead_params=(self.layer_params(self.n_dense_layers, False)
+                         if self.n_dense_layers else {}),
+            attrs=self.block_attrs(page_size), vocab_size=self.vocab_size,
+            dtype=self.dtype)
+
+        def bundle(kind, prefix, batch, feeds, steps=1):
+            """One program: ``feeds`` are (slot, feed name, shape, dtype)
+            of its data inputs, in feed order; the pool follows."""
+            main = framework.Program()
+            with framework.program_guard(main, framework.Program()), \
+                    framework.unique_name.guard():
+                data = {slot: layers.data(
+                    name=f"{prefix}_{fname}", shape=list(shape),
+                    dtype=dt, append_batch_size=False)
+                    for slot, fname, shape, dt in feeds}
+                pool = layers.data(name=f"{prefix}_pool",
+                                   shape=pool_shape, dtype=self.dtype,
+                                   append_batch_size=False)
+                out, pools_out, logits, picks, stats = tfl.block_paged_op(
+                    kind, data, [pool], steps=steps, **common)
+            return {"program": main.clone(for_test=True),
+                    "feeds": tuple(f"{prefix}_{f[1]}" for f in feeds)
+                    + (f"{prefix}_pool",),
+                    "fetch": [out] + pools_out + [logits, picks, stats],
+                    "extras": ("logits", "picks", "stats")}
+
+        pb = max(1, int(prefill_batch))
+        table = lambda b: ("Table", "table", [b, pages_per_seq], "int32")
+        prefill = {
+            bucket: bundle("prefill", "pp", pb, [
+                ("Tokens", "tokens", [pb, bucket], "int64"),
+                ("Lens", "lens", [pb], "int32"), table(pb)])
+            for bucket in prefill_buckets_reached(prompt_buckets,
+                                                  chunk_size)}
+        decode = bundle("decode", "dc", max_batch, [
+            ("Tokens", "tokens", [max_batch], "int64"),
+            ("Positions", "positions", [max_batch], "int32"),
+            table(max_batch)], steps=decode_block)
+        chunk = None
+        if chunk_size is not None:
+            cs = int(chunk_size)
+            if cs < 1:
+                raise ValueError(f"chunk_size must be >= 1, got {cs}")
+            chunk = bundle("prefill_chunk", "ck", 1, [
+                ("Tokens", "tokens", [1, cs], "int64"),
+                ("Lens", "lens", [1], "int32"),
+                ("Offsets", "offsets", [1], "int32"), table(1)])
+        return PagedDecodePrograms(
+            self, None, page_size, pages_per_seq, n_pages, max_batch,
+            prefill, decode, None, [(pool_shape, self.dtype)], None,
+            chunk=chunk, chunk_size=None if chunk is None else cs,
+            stats=PAGED_STATS)
+
+
+LATENT_MOE_TINY = LatentMoEConfig(
+    name="latent-moe-tiny", vocab_size=96, dim=32, n_layers=3,
+    n_dense_layers=1, n_heads=4, q_rank=24, kv_rank=16, nope_dim=8,
+    rope_dim=8, v_dim=8, ffn_hidden=64, n_experts=8, moe_top_k=2,
+    expert_hidden=16, n_shared=1, n_streams=4, sinkhorn_iters=20,
+    rope_original_max=16, dtype="float32")

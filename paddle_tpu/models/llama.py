@@ -42,6 +42,17 @@ class LlamaConfig:
     moe_capacity_factor: float = 2.0
     moe_aux_weight: float = 0.01
 
+    def cache_spec(self):
+        """A token's cache entries in one layer, [(shape, dtype)]: a K
+        and a V of [kv_heads, head_dim]."""
+        entry = (self.n_kv_heads, self.dim // self.n_heads)
+        return [(entry, self.dtype), (entry, self.dtype)]
+
+    def build_paged_programs(self, **geometry):
+        """What DecodeEngine asks of a model: its paged step programs
+        (build_llama_paged_programs)."""
+        return build_llama_paged_programs(self, **geometry)
+
 
 LLAMA3_8B = LlamaConfig()
 LLAMA_TINY = LlamaConfig(vocab_size=256, dim=64, n_layers=2, n_heads=4,
@@ -351,14 +362,19 @@ class PagedDecodePrograms:
 
     ``prefill`` maps bucket length -> a bundle dict with the program,
     feed var names, and fetch vars; ``decode``/``spec`` are single
-    bundles. ``kv_shape`` (and ``draft_kv_shape`` when spec) are the
-    [L, n_pages, page_size, n_kv, head_dim] pool shapes the engine
-    allocates host-side and round-trips through every dispatch."""
+    bundles. A bundle's feeds end with the model's cache pools and its
+    fetches are (token outputs, the pools, then what ``extras`` names:
+    ``logits``, ``picks``, ``stats``); ``pools`` says which pools those
+    are where not the target's (``draft``, ``both``). ``pool_specs`` (and ``draft_pool_specs``
+    when spec) are the (shape, dtype) of each pool the engine allocates
+    and round-trips through every dispatch: ``[L, n_pages, page_size]``
+    followed by one entry of the model's ``cache_spec()``. ``stats``
+    names the counters a ``stats`` fetch holds, in its order."""
 
     def __init__(self, cfg, draft_cfg, page_size, pages_per_seq,
-                 n_pages, max_batch, prefill, decode, spec, kv_shape,
-                 draft_kv_shape, kv_dtype, draft_kv_dtype,
-                 draft_prefill=None, chunk=None, chunk_size=None):
+                 n_pages, max_batch, prefill, decode, spec, pool_specs,
+                 draft_pool_specs, draft_prefill=None, chunk=None,
+                 chunk_size=None, stats=()):
         self.cfg = cfg
         self.draft_cfg = draft_cfg
         self.page_size = page_size
@@ -372,10 +388,27 @@ class PagedDecodePrograms:
         self.spec = spec
         self.chunk = chunk              # chunked-prefill bundle or None
         self.chunk_size = chunk_size
-        self.kv_shape = kv_shape
-        self.draft_kv_shape = draft_kv_shape
-        self.kv_dtype = kv_dtype
-        self.draft_kv_dtype = draft_kv_dtype
+        self.pool_specs = pool_specs
+        self.draft_pool_specs = draft_pool_specs
+        self.stats = tuple(stats)
+
+
+def prefill_buckets_reached(prompt_buckets, chunk_size):
+    """The buckets a whole-prompt prefill can land in: all of them, or,
+    with chunked prefill, those that some prompt of at most
+    ``chunk_size`` tokens pads to (longer prompts go in slices through
+    the chunk program, so a program for a larger bucket would be built,
+    compiled and warmed for no request)."""
+    buckets = sorted(set(int(b) for b in prompt_buckets))
+    if chunk_size is None:
+        return buckets
+    return [b for i, b in enumerate(buckets)
+            if i == 0 or buckets[i - 1] < int(chunk_size)]
+
+
+def _pool_specs(cfg, n_pages, page_size):
+    return [([cfg.n_layers, n_pages, page_size] + list(entry), dtype)
+            for entry, dtype in cfg.cache_spec()]
 
 
 def build_llama_paged_programs(cfg, *, max_batch, page_size, n_pages,
@@ -396,8 +429,9 @@ def build_llama_paged_programs(cfg, *, max_batch, page_size, n_pages,
     if cfg.moe_experts > 0 or (draft_cfg is not None
                                and draft_cfg.moe_experts > 0):
         raise NotImplementedError(
-            "the paged decode engine serves dense configs; route MoE "
-            "serving through build_llama_generator")
+            "the Llama paged programs are dense: softmax-routed experts "
+            "under GQA have no paged form (models/latent_moe.py has the "
+            "routed form the engine serves)")
     if draft_cfg is not None and quantize:
         raise NotImplementedError(
             "speculative paged decoding is float-only (same design-out "
@@ -407,8 +441,8 @@ def build_llama_paged_programs(cfg, *, max_batch, page_size, n_pages,
             f"target and draft must share a vocabulary: "
             f"{cfg.vocab_size} vs {draft_cfg.vocab_size}")
     from ..core import framework
-    hd = cfg.dim // cfg.n_heads
-    kv_shape = [cfg.n_layers, n_pages, page_size, cfg.n_kv_heads, hd]
+    pool_specs = _pool_specs(cfg, n_pages, page_size)
+    kv_shape = pool_specs[0][0]
     common = dict(vocab_size=cfg.vocab_size, dim=cfg.dim,
                   n_layers=cfg.n_layers, n_heads=cfg.n_heads,
                   n_kv_heads=cfg.n_kv_heads, ffn_hidden=cfg.ffn_hidden,
@@ -421,7 +455,8 @@ def build_llama_paged_programs(cfg, *, max_batch, page_size, n_pages,
 
     prefill = {}
     pb = max(1, int(prefill_batch))
-    for bucket in sorted(set(int(b) for b in prompt_buckets)):
+    buckets = prefill_buckets_reached(prompt_buckets, chunk_size)
+    for bucket in buckets:
         main = framework.Program()
         with framework.program_guard(main, framework.Program()), \
                 framework.unique_name.guard():
@@ -485,15 +520,14 @@ def build_llama_paged_programs(cfg, *, max_batch, page_size, n_pages,
 
     spec = None
     draft_prefill = None
-    draft_kv_shape = None
+    draft_pool_specs = None
     if draft_cfg is not None:
-        d_hd = draft_cfg.dim // draft_cfg.n_heads
-        draft_kv_shape = [draft_cfg.n_layers, n_pages, page_size,
-                          draft_cfg.n_kv_heads, d_hd]
+        draft_pool_specs = _pool_specs(draft_cfg, n_pages, page_size)
+        draft_kv_shape = draft_pool_specs[0][0]
         # the draft prefills its own paged cache over the same prompt
         # (and the same page indices — one table serves both pools)
         draft_prefill = {}
-        for bucket in sorted(set(int(b) for b in prompt_buckets)):
+        for bucket in buckets:
             main = framework.Program()
             with framework.program_guard(main, framework.Program()), \
                     framework.unique_name.guard():
@@ -518,7 +552,7 @@ def build_llama_paged_programs(cfg, *, max_batch, page_size, n_pages,
                 "program": main.clone(for_test=True),
                 "feeds": ("dp_tokens", "dp_lens", "dp_table",
                           "dp_kpages", "dp_vpages"),
-                "fetch": [nxt, kp_out, vp_out]}
+                "fetch": [nxt, kp_out, vp_out], "pools": "draft"}
         main = framework.Program()
         with framework.program_guard(main, framework.Program()), \
                 framework.unique_name.guard():
@@ -550,12 +584,11 @@ def build_llama_paged_programs(cfg, *, max_batch, page_size, n_pages,
                 "feeds": ("sp_tokens", "sp_prev", "sp_positions",
                           "sp_table", "sp_kpages", "sp_vpages",
                           "sp_draft_kpages", "sp_draft_vpages"),
-                "fetch": list(outs)}
+                "fetch": list(outs), "pools": "both"}
 
     return PagedDecodePrograms(
         cfg, draft_cfg, page_size, pages_per_seq, n_pages, max_batch,
-        prefill, decode, spec, kv_shape, draft_kv_shape,
-        cfg.dtype, None if draft_cfg is None else draft_cfg.dtype,
+        prefill, decode, spec, pool_specs, draft_pool_specs,
         draft_prefill=draft_prefill, chunk=chunk,
         chunk_size=None if chunk is None else int(chunk_size))
 

@@ -17,8 +17,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.registry import register_op
 
-__all__ = ["top_k_gating", "moe_apply", "moe_apply_no_drop",
-           "moe_apply_no_drop_q"]
+__all__ = ["top_k_gating", "moe_apply", "moe_route", "moe_load",
+           "moe_apply_sorted", "moe_apply_no_drop", "moe_apply_no_drop_q"]
 
 
 def _ep_constraint(x, spec):
@@ -97,21 +97,87 @@ def moe_apply(xt, wg, w_gate, w_up, w_down, top_k, cap_factor):
     return out, aux
 
 
-def moe_apply_no_drop(xt, wg, w_gate, w_up, w_down, top_k):
-    """Inference-form MoE: exact top-k routing with NO capacity drops.
-    Training capacity makes a token's output depend on which OTHER
-    tokens competed for its experts — under KV-cache decoding that
-    would make cached and recomputed logits diverge, so eval/serving
-    uses the drop-free form (every expert evaluates every token, the
-    combine mask keeps its top-k — E x FLOPs, the standard small-batch
-    serving trade)."""
-    w = _topk_combine(_router_probs(xt, wg), top_k)          # [T, E]
+def moe_route(xt, wg, top_k, scoring="softmax", bias=None, scale=1.0):
+    """Exact top-k routing of flat tokens xt [T, D], in float32 whatever
+    the model's dtype (the products at "highest" precision: a bf16 pass
+    over the router reorders near-ties). Returns (experts [T, K] int32,
+    gates [T, K] float32).
+
+    ``softmax``: the K largest probabilities, renormalised.
+    ``sigmoid``: scores ``sigmoid(x Wg)``; the K largest of
+    ``scores + bias`` are picked (``bias`` steers selection only), their
+    own scores are renormalised and multiplied by ``scale``."""
+    logits = jnp.dot(xt.astype(jnp.float32), wg.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    if scoring == "softmax":
+        gates, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+        return idx, scale * gates / jnp.maximum(
+            jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
+    if scoring != "sigmoid":
+        raise ValueError(f"unknown router scoring {scoring!r}")
+    scores = jax.nn.sigmoid(logits)
+    picked = scores if bias is None else scores + bias.astype(jnp.float32)
+    idx = jax.lax.top_k(picked, top_k)[1]
+    gates = jnp.take_along_axis(scores, idx, axis=-1)
+    return idx, scale * gates / (
+        jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+
+
+def moe_load(idx, n_experts, valid=None):
+    """Tokens each expert was given, [E] int32; ``valid`` [T] bool leaves
+    padding and inactive rows out of the count (they are still computed:
+    shapes are static)."""
+    hits = jax.nn.one_hot(idx, n_experts, dtype=jnp.int32).sum(axis=1)
+    if valid is not None:
+        hits = hits * valid.astype(jnp.int32)[:, None]
+    return hits.sum(axis=0)
+
+
+def moe_apply_sorted(xt, idx, gates, w_gate, w_up, w_down, layer=None):
+    """The drop-free expert layer: the T x K token-expert pairs sorted by
+    expert, one grouped matmul a projection over the experts held (the
+    leading dimension of the weights; ``jax.lax.ragged_dot``: on the TPU
+    a Mosaic kernel that reads an expert's weights only where a pair
+    reached it), then the gates applied and the pairs summed back in
+    token order. Every pair is computed, so a token's output depends on
+    no other token. Returns [T, D] in xt's dtype.
+
+    With ``layer`` (a traced index) the weights are a model's stacked
+    [L, E, ...] experts, taken as L x E groups of which only layer
+    ``layer``'s are non-empty: slicing the layer out first would copy all
+    its experts on every call (3.2 ms against 0.4 for the matmul itself
+    at 64 rows: PERF.md section 6, PR 27), and empty groups cost
+    nothing."""
+    t, k = idx.shape
+    e = w_gate.shape[-3]
+    flat = idx.reshape(t * k)
+    order = jnp.argsort(flat, stable=True)
+    sizes = moe_load(idx, e)
+    if layer is not None:
+        n_layers = w_gate.shape[0]
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((n_layers * e,), jnp.int32), sizes, (layer * e,))
+        w_gate, w_up, w_down = (w.reshape((n_layers * e,) + w.shape[2:])
+                                for w in (w_gate, w_up, w_down))
+    xs = xt[order // k]
     cdt = xt.dtype
-    gate_h = jnp.einsum("td,edh->teh", xt, w_gate)
-    up_h = jnp.einsum("td,edh->teh", xt, w_up)
-    h = (gate_h * jax.nn.sigmoid(gate_h)) * up_h
-    expert_out = jnp.einsum("teh,ehd->ted", h, w_down)
-    return jnp.einsum("te,ted->td", w.astype(cdt), expert_out)
+    gate_h = jax.lax.ragged_dot(xs, w_gate, sizes)
+    up_h = jax.lax.ragged_dot(xs, w_up, sizes)
+    h = ((gate_h * jax.nn.sigmoid(gate_h)) * up_h).astype(cdt)
+    ys = jax.lax.ragged_dot(h, w_down, sizes,
+                            preferred_element_type=jnp.float32)
+    pairs = ys[jnp.argsort(order)].reshape(t, k, -1)
+    return jnp.sum(pairs * gates[..., None], axis=1).astype(cdt)
+
+
+def moe_apply_no_drop(xt, wg, w_gate, w_up, w_down, top_k):
+    """Inference-form MoE: exact top-k softmax routing with NO capacity
+    drops. Training capacity makes a token's output depend on which
+    OTHER tokens competed for its experts — under KV-cache decoding
+    that would make cached and recomputed logits diverge, so
+    eval/serving routes drop-free (moe_route + moe_apply_sorted)."""
+    idx, gates = moe_route(xt, wg, top_k)
+    return moe_apply_sorted(xt, idx, gates, w_gate, w_up, w_down)
 
 
 def _topk_combine(probs, top_k):

@@ -7,6 +7,8 @@ fusion_lstm_op.cc etc. are the CUDA-era analogues): the hot path is one
 op the compiler can schedule as a unit, instead of a softmax/matmul
 chain.
 """
+import math
+
 import numpy as np
 
 import jax
@@ -35,14 +37,16 @@ def _rms_norm(ctx, ins, attrs):
                                 attrs.get("epsilon", 1e-6))]}
 
 
-def apply_rope_at(x, positions, base=10000.0):
+def apply_rope_at(x, positions, base=10000.0, inv_freq=None):
     """x: [B, T, H, D]; positions: [T] absolute positions shared by the
     batch, or [B, T] per-row positions (the continuous-batching decode
     engine schedules rows at unrelated sequence offsets). Positions may
     be traced values — unlike apply_rope's table slicing, nothing here
-    depends on them being static."""
+    depends on them being static. ``inv_freq`` [D/2] replaces the plain
+    ``base ** (-2i / D)`` (yarn_inv_freq)."""
     b, t, h, d = x.shape
-    inv = 1.0 / (base ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    inv = (1.0 / (base ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+           if inv_freq is None else jnp.asarray(inv_freq, jnp.float32))
     freqs = positions.astype(jnp.float32)[..., None] * inv  # [(B,)T, D/2]
     if freqs.ndim == 2:
         cos = jnp.cos(freqs)[None, :, None, :]
@@ -61,6 +65,33 @@ def apply_rope(x, base=10000.0, position_offset=0):
     style). Same math as apply_rope_at at positions offset..offset+T."""
     t = x.shape[1]
     return apply_rope_at(x, position_offset + jnp.arange(t), base)
+
+
+def yarn_inv_freq(dim, base, factor, original_max, beta_fast=32.0,
+                  beta_slow=1.0):
+    """YaRN's inverse frequencies for a ``dim``-wide rotary part (Peng et
+    al., arXiv:2309.00071, as DeepseekV3YarnRotaryEmbedding computes
+    them): pairs that turn more than ``beta_fast`` times over the
+    original context keep ``base ** (-2i / dim)``, pairs that turn less
+    than ``beta_slow`` times are divided by ``factor``, a linear ramp
+    between. numpy, so that it is a constant of the program."""
+    plain = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def pair_of(turns):
+        return dim * math.log(original_max / (turns * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / max(high - low, 0.001), 0.0, 1.0)
+    return (plain / factor * ramp + plain * (1.0 - ramp)).astype(
+        np.float32)
+
+
+def yarn_mscale(factor, mscale=1.0):
+    """YaRN's attention temperature ``0.1 * mscale * ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
 def warp_logits(logits, temperature, top_k=0, top_p=1.0):
@@ -168,6 +199,7 @@ _STACK_SLOTS = ("AttnNorm", "Wq", "Wk", "Wv", "Wo",
                 "MlpNorm", "WGate", "WUp", "WDown")
 _MATMUL_SLOTS = ("Wq", "Wk", "Wv", "Wo", "WGate", "WUp", "WDown")
 _MOE_SLOTS = ("MoeRouter", "MoeWGate", "MoeWUp", "MoeWDown")
+_EXPERT_SLOTS = ("MoeWGate", "MoeWUp", "MoeWDown")
 
 
 def qmat(x, p, slot, cdt=None):
@@ -215,47 +247,211 @@ def _reject_quant_scales(ins, op_name):
             "trained scope (models.llama.quantize_generator_weights).")
 
 
+class BlockKinds:
+    """What one decoder block is made of, as data of the model: its kind
+    of attention (``gqa`` | ``latent``), of feed-forward (``swiglu`` |
+    ``routed``, the latter with a shared expert where the parameters
+    hold one) and of residual path (``plain`` | ``mhc``), with the sizes
+    each kind needs. ``block_forward`` is the one definition that reads
+    it; training, the generator and the paged programs derive from that.
+
+    ``latent`` (multi-head latent attention, DeepSeek-V2,
+    arXiv:2405.04434): ``kv_rank`` normalised latent + ``rope_dim``
+    rotated key a token are the cache's entry; ``nope_dim``/``v_dim``
+    are a head's expanded key and value widths; ``rope_inv_freq`` and
+    ``softmax_scale`` carry YaRN. ``routed``: ``scoring`` and
+    ``route_scale`` as ops/moe.py moe_route. ``mhc`` (manifold-
+    constrained hyper-connections, arXiv:2512.24880): ``n_streams``
+    residual streams mixed by per-token matrices, the stream-to-stream
+    one made doubly stochastic by ``sinkhorn_iters`` rounds."""
+
+    def __init__(self, *, n_heads, n_kv=None, base=10000.0, eps=1e-6,
+                 attention="gqa", ffn="swiglu", residual="plain",
+                 moe_top_k=2, scoring="softmax", route_scale=1.0,
+                 kv_rank=0, rope_dim=0, nope_dim=0, v_dim=0,
+                 rope_inv_freq=None, softmax_scale=None, n_streams=1,
+                 sinkhorn_iters=0, hc_eps=1e-6, hc_clamp=(-30.0, 30.0)):
+        for kind, table in ((attention, _ATTENTION), (ffn, _FFN),
+                            (residual, _RESIDUAL)):
+            if kind not in table:
+                raise ValueError(f"unknown block kind {kind!r}; have "
+                                 f"{sorted(table)}")
+        self.n_heads, self.n_kv = n_heads, n_kv or n_heads
+        self.base, self.eps = base, eps
+        self.attention, self.ffn, self.residual = attention, ffn, residual
+        self.moe_top_k, self.scoring = moe_top_k, scoring
+        self.route_scale = route_scale
+        self.kv_rank, self.rope_dim = kv_rank, rope_dim
+        self.nope_dim, self.v_dim = nope_dim, v_dim
+        self.rope_inv_freq = rope_inv_freq
+        self.softmax_scale = softmax_scale
+        self.n_streams, self.sinkhorn_iters = n_streams, sinkhorn_iters
+        self.hc_eps, self.hc_clamp = hc_eps, tuple(hc_clamp)
+
+
+def _gqa_attention(kinds, p, u, pos, attend_fn):
+    """Roped grouped-query projections; ``attend_fn(q, (k, v))`` owns the
+    attention and any cache."""
+    b, t, _ = u.shape
+    hd = p["Wq"].shape[-1] // kinds.n_heads
+    q = apply_rope_at(qmat(u, p, "Wq").reshape(b, t, kinds.n_heads, hd),
+                      pos, kinds.base)
+    k = apply_rope_at(qmat(u, p, "Wk").reshape(b, t, kinds.n_kv, hd),
+                      pos, kinds.base)
+    v = qmat(u, p, "Wv").reshape(b, t, kinds.n_kv, hd)
+    return qmat(attend_fn(q, (k, v)), p, "Wo")
+
+
+def _latent_attention(kinds, p, u, pos, attend_fn):
+    """Latent attention's projections: the query through its low-rank
+    pair, the key/value side down to ONE ``[kv_rank | rope_dim]`` entry a
+    token (normalised latent, rotated key shared by all heads), which is
+    all a cache holds. ``attend_fn((q_nope, q_pe), (entry,))`` attends,
+    expanded or absorbed, and returns [b, t, heads * v_dim]."""
+    b, t, _ = u.shape
+    r = kinds.kv_rank
+    q = (rms_normalize(u @ p["Wqa"], p["QNorm"], kinds.eps)
+         @ p["Wqb"]).reshape(b, t, kinds.n_heads, -1)
+    q_nope, q_pe = q[..., :kinds.nope_dim], q[..., kinds.nope_dim:]
+    ckv = u @ p["Wkva"]
+    c = rms_normalize(ckv[..., :r], p["KvNorm"], kinds.eps)
+
+    def rotate(x):      # published pairs are interleaved: de-interleave
+        x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+        return apply_rope_at(x, pos, inv_freq=kinds.rope_inv_freq)
+
+    k_pe = rotate(ckv[..., None, r:])[:, :, 0]
+    entry = jnp.concatenate([c, k_pe], axis=-1)
+    return attend_fn((q_nope, rotate(q_pe)), (entry,)) @ p["Wo"]
+
+
+def _swiglu(p, x, gate="WGate", up="WUp", down="WDown"):
+    g = qmat(x, p, gate)
+    return qmat((g * jax.nn.sigmoid(g)) * qmat(x, p, up), p, down)
+
+
+def _swiglu_ffn(kinds, p, u, valid):
+    return _swiglu(p, u), None
+
+
+def _routed_ffn(kinds, p, u, valid):
+    """Drop-free routed experts (ops/moe.py), plus the shared expert
+    where ``p`` holds one. Also returns (the call's load, [E] int32 over
+    the ``valid`` tokens; the experts picked, [b, t, K])."""
+    from . import moe
+    b, t, d = u.shape
+    xt = u.reshape(b * t, d)
+    if p.get("MoeWGateScale") is not None:          # W8A8 expert stacks
+        return moe.moe_apply_no_drop_q(
+            xt, p["MoeRouter"], p["MoeWGate"], p["MoeWUp"], p["MoeWDown"],
+            {"gate": p["MoeWGateScale"], "up": p["MoeWUpScale"],
+             "down": p["MoeWDownScale"]},
+            kinds.moe_top_k).reshape(b, t, d), None
+    with jax.named_scope("moe/route"):
+        idx, gates = moe.moe_route(
+            xt, p["MoeRouter"], kinds.moe_top_k, kinds.scoring,
+            p.get("MoeBias"), kinds.route_scale)
+        load = moe.moe_load(idx, p["MoeWGate"].shape[-3],
+                            None if valid is None else valid.reshape(-1))
+    with jax.named_scope("moe/experts"):
+        # p["ExpertsOf"]: the expert stacks are the whole model's and
+        # this is the layer to take (_PagedRunner._stack_forward)
+        out = moe.moe_apply_sorted(xt, idx, gates, p["MoeWGate"],
+                                   p["MoeWUp"], p["MoeWDown"],
+                                   layer=p.get("ExpertsOf"))
+    if p.get("ShWGate") is not None:
+        with jax.named_scope("moe/shared"):
+            out = out + _swiglu(p, xt, "ShWGate", "ShWUp", "ShWDown")
+    return out.reshape(b, t, d), (load, idx.reshape(b, t, -1))
+
+
+def _plain_residual(kinds, p, which, x, sublayer):
+    return x + sublayer(rms_normalize(x, p[which + "Norm"], kinds.eps))
+
+
+def sinkhorn_knopp(m, iters, eps):
+    """``iters`` rounds of row then column normalisation of the positive
+    matrices m [..., n, n]: towards doubly stochastic."""
+    for _ in range(iters):
+        m = m / (jnp.sum(m, axis=-1, keepdims=True) + eps)
+        m = m / (jnp.sum(m, axis=-2, keepdims=True) + eps)
+    return m
+
+
+def _mhc_residual(kinds, p, which, x, sublayer):
+    """x [b, t, n, D]: the sublayer reads ``Hpre x``, its output goes
+    back through ``Hpost`` and the streams are mixed by ``Hres``; all
+    three are functions of the token's normalised streams, computed in
+    float32 at "highest" precision."""
+    b, t, n, d = x.shape
+    hi = jax.lax.Precision.HIGHEST
+    with jax.named_scope("mhc/mix"):
+        xf = x.astype(jnp.float32)
+        z = jnp.dot(rms_normalize(xf.reshape(b, t, n * d), None, kinds.eps),
+                    p["Hc" + which + "Phi"], precision=hi)
+        alpha, bias = p["Hc" + which + "Alpha"], p["Hc" + which + "Bias"]
+        pre = jax.nn.sigmoid(alpha[0] * z[..., :n] + bias[:n])
+        post = 2.0 * jax.nn.sigmoid(alpha[1] * z[..., n:2 * n]
+                                    + bias[n:2 * n])
+        res = alpha[2] * z[..., 2 * n:] + bias[2 * n:]
+        res = sinkhorn_knopp(
+            jnp.exp(jnp.clip(res, *kinds.hc_clamp)).reshape(b, t, n, n),
+            kinds.sinkhorn_iters, kinds.hc_eps)
+        u = jnp.einsum("btn,btnd->btd", pre, xf,
+                       precision=hi).astype(x.dtype)
+    y = sublayer(rms_normalize(u, p[which + "Norm"], kinds.eps))
+    with jax.named_scope("mhc/mix"):
+        out = jnp.einsum("btij,btjd->btid", res, xf, precision=hi) \
+            + post[..., None] * y.astype(jnp.float32)[:, :, None, :]
+    return out.astype(x.dtype)
+
+
+_ATTENTION = {"gqa": _gqa_attention, "latent": _latent_attention}
+_FFN = {"swiglu": _swiglu_ffn, "routed": _routed_ffn}
+_RESIDUAL = {"plain": _plain_residual, "mhc": _mhc_residual}
+
+
+def block_forward(kinds, p, x, pos, attend_fn, ffn=None, valid=None):
+    """One decoder block, the single copy of the block math: residual(
+    attention) then residual(feed-forward), each kind looked up in
+    ``kinds``. ``ffn`` overrides the feed-forward kind for this layer (a
+    model's leading dense layers); ``attend_fn(q, entries) -> out`` gets
+    the positioned queries and the token's cache entries and owns the
+    attention and any cache side effects. Returns (x, routing): a routed
+    layer's (per-expert load over the ``valid`` tokens, experts picked
+    [b, t, K]), else None."""
+    residual = _RESIDUAL[kinds.residual]
+    routing = []
+
+    def feed_forward(u):
+        out, info = _FFN[ffn or kinds.ffn](kinds, p, u, valid)
+        routing.append(info)
+        return out
+
+    x = residual(kinds, p, "Attn", x, lambda u: _ATTENTION[
+        kinds.attention](kinds, p, u, pos, attend_fn))
+    x = residual(kinds, p, "Mlp", x, feed_forward)
+    return x, routing[0]
+
+
 def decoder_block(p, h, *, n_heads, n_kv, base, eps, pos, attend_fn,
                   moe_top_k=2):
-    """One Llama decoder block — the single copy of the block math
-    shared by training (llama_decoder_stack) and generation
-    (llama_generate): rms_norm → roped QKV at ``pos`` → ``attend_fn``
-    → residual → rms_norm → SwiGLU → residual.
+    """One Llama decoder block — block_forward at the Llama kinds, shared
+    by training (llama_decoder_stack) and generation (llama_generate):
+    rms_norm → roped QKV at ``pos`` → ``attend_fn`` → residual →
+    rms_norm → SwiGLU (or, where ``p`` holds a router, the drop-free
+    routed form: the capacity-competition of the training form would
+    make cached decode depend on the rest of the batch) → residual.
 
     attend_fn(q, k, v) -> [b, t, n_heads*hd] gets the roped q/k and raw
     v ([b, t, heads, hd]) and owns the attention (and any KV-cache
     side effects)."""
-    b, t, _ = h.shape
-    hd = p["Wq"].shape[-1] // n_heads
-    pre = rms_normalize(h, p["AttnNorm"], eps)
-    q = apply_rope_at(qmat(pre, p, "Wq").reshape(b, t, n_heads, hd),
-                      pos, base)
-    k = apply_rope_at(qmat(pre, p, "Wk").reshape(b, t, n_kv, hd),
-                      pos, base)
-    v = qmat(pre, p, "Wv").reshape(b, t, n_kv, hd)
-    h = h + qmat(attend_fn(q, k, v), p, "Wo")
-    pre2 = rms_normalize(h, p["MlpNorm"], eps)
-    if p.get("MoeRouter") is not None:
-        # inference-form MoE: drop-free exact top-k (ops/moe.py) — the
-        # capacity-competition of the training form would make cached
-        # decode depend on the rest of the batch
-        from .moe import moe_apply_no_drop, moe_apply_no_drop_q
-        d_model = h.shape[-1]
-        xt = pre2.reshape(b * t, d_model)
-        if p.get("MoeWGateScale") is not None:      # W8A8 expert stacks
-            out = moe_apply_no_drop_q(
-                xt, p["MoeRouter"], p["MoeWGate"], p["MoeWUp"],
-                p["MoeWDown"],
-                {"gate": p["MoeWGateScale"], "up": p["MoeWUpScale"],
-                 "down": p["MoeWDownScale"]}, moe_top_k)
-        else:
-            out = moe_apply_no_drop(xt, p["MoeRouter"], p["MoeWGate"],
-                                    p["MoeWUp"], p["MoeWDown"],
-                                    moe_top_k)
-        return h + out.reshape(b, t, d_model)
-    g = qmat(pre2, p, "WGate")
-    u = qmat(pre2, p, "WUp")
-    return h + qmat((g * jax.nn.sigmoid(g)) * u, p, "WDown")
+    kinds = BlockKinds(
+        n_heads=n_heads, n_kv=n_kv, base=base, eps=eps,
+        moe_top_k=moe_top_k,
+        ffn="swiglu" if p.get("MoeRouter") is None else "routed")
+    return block_forward(kinds, p, h, pos,
+                         lambda q, kv: attend_fn(q, *kv))[0]
 
 
 def make_flash_block(n_heads, n_kv, base, eps, remat=True):
@@ -941,37 +1137,61 @@ def _llama_spec_generate(ctx, ins, attrs):
 # shape never changes, and cross-row coupling does not exist.
 # ---------------------------------------------------------------------
 
+# every name the engine's counters take from a paged program's ``Stats``
+# output, in its order (serving/decode_engine.py ticks them; docs/
+# SERVING.md, "Metrics reference"). The last three are counted by decode
+# dispatches alone: a prefill touches every expert and would hide what a
+# step must read.
+PAGED_STATS = ("moe_assignments_total", "moe_max_load_total",
+               "moe_decode_expert_calls_total",
+               "moe_decode_experts_touched_total",
+               "latent_tokens_read_total")
+
+# keys a prefill window expands at a time (latent attention): scores of
+# [heads, window, _KEY_BLOCK] float32, never of the whole cache
+_KEY_BLOCK = 2048
+
+
 class _PagedRunner:
     """Paged twin of _make_cached_runner, closed over one model's
-    stacked weights. Two execution forms over the SAME math:
+    stacked weights and its ``BlockKinds``. A model's cache is a tuple
+    of pools ``[L, n_pages, page_size, *entry]``, one per entry a token
+    leaves in a layer (GQA: K and V ``[g, hd]``; latent attention: one
+    ``[kv_rank + rope_dim]``). Two execution forms over the SAME math:
 
-    - ``forward(h, k_pages, v_pages, table, pos0, t_len)`` — operate
-      directly on the [L, n_pages, page_size, g, hd] page pools
-      through ``table`` [B, max_pages]: each layer writes the window's
-      K,V at ``[layer, page, offset]`` and attends over the row's
-      pages of that layer (both prefill ops).
+    - ``forward(h, *pools, table, pos0, t_len)`` — operate directly on
+      the page pools through ``table`` [B, max_pages]: each layer
+      writes the window's entries at ``[layer, page, offset]`` and
+      attends over the row's pages of that layer (both prefill ops).
     - ``gather``/``forward_dense``/``scatter`` — gather each row's
-      pages to a dense [L, B, kmax, g, hd] cache once a dispatch, run
+      pages to a dense [L, B, kmax, *entry] cache once a dispatch, run
       every step against it (each layer writes ``[layer, row, q_pos]``
-      and attends over ``kd[layer]``), and scatter the pages back
+      and attends over ``dense[layer]``), and scatter the pages back
       once at the end. The decode and speculative step ops use this.
 
-    In both, the layer scan CARRIES the whole [L, ...] K and V arrays
-    beside ``h`` and scans over (weights, layer index): a scan's
-    ``ys`` is a fresh buffer that cannot alias its ``xs``, so caches
-    passed that way are rebuilt whole on every call — on every token,
-    inside the decode op's step loop. Carried, they alias from the
-    dispatch's gather to its scatter, and a step touches the rows it
-    writes and the bytes attention reads
-    (tests/test_paged_cache_inplace.py holds both to it).
+    In both, the layer scan CARRIES the whole [L, ...] caches beside
+    ``h`` and scans over (weights, layer index): a scan's ``ys`` is a
+    fresh buffer that cannot alias its ``xs``, so caches passed that
+    way are rebuilt whole on every call — on every token, inside the
+    decode op's step loop. Carried, they alias from the dispatch's
+    gather to its scatter, and a step touches the rows it writes and
+    the bytes attention reads (tests/test_paged_cache_inplace.py holds
+    both to it). ``lead`` are the parameters of the model's leading
+    layers whose feed-forward is dense (``lead_ffn``): they run before
+    the scan, on the first layers of the same caches.
 
-    The dense view holds bitwise the same values the pools do, so both
-    forms produce identical numerics. int8 ``<Slot>Scale`` companions
-    ride along in ``params`` exactly as in the contiguous runner
-    (qmat)."""
+    Latent attention attends two ways over its one cache: a prefill
+    window EXPANDS the latents it can see into per-head keys and
+    values, a block of keys at a time; a decode step ABSORBS the
+    expansion into its query and its output and reads the latents as
+    they lie. The dense view holds bitwise the same values the pools
+    do, so both forms see identical caches. int8 ``<Slot>Scale``
+    companions ride along in ``params`` exactly as in the contiguous
+    runner (qmat)."""
 
     def __init__(self, params, emb_w, fnorm, head, *, n_heads, n_kv,
-                 base, eps, page_size, head_scale=None, moe_top_k=2):
+                 base, eps, page_size, head_scale=None, moe_top_k=2,
+                 kinds=None, lead=None):
         self.params = params
         self.emb_w = emb_w
         self.fnorm = fnorm
@@ -983,8 +1203,25 @@ class _PagedRunner:
         self.eps = eps
         self.page_size = page_size
         self.moe_top_k = moe_top_k
-        self.hd = params["Wq"].shape[-1] // n_heads
-        self.rep = n_heads // n_kv
+        self.kinds = kinds or BlockKinds(
+            n_heads=n_heads, n_kv=n_kv, base=base, eps=eps,
+            moe_top_k=moe_top_k)
+        self.lead = lead
+        self.valid = None       # [B, T] bool: the tokens Stats counts
+        self.pick_at = None     # [B]: the window position Picks reports
+        self._loads = []        # the last forward's routed loads [n, E]
+        self.picks = None       # and its picks at pick_at, [n, B, K]
+        if self.kinds.attention == "gqa":
+            self.hd = params["Wq"].shape[-1] // n_heads
+            self.rep = n_heads // n_kv
+
+    def embed(self, tokens):
+        """Token rows of the embedding; under ``mhc`` every residual
+        stream starts as a copy of them."""
+        h = self.emb_w[tokens]
+        if self.kinds.residual == "mhc":
+            h = jnp.repeat(h[..., None, :], self.kinds.n_streams, axis=-2)
+        return h
 
     def _attend_math(self, q, k_all, v_all, q_pos, t_len):
         """GQA attention of a [B, t_len] query window against dense
@@ -1006,103 +1243,248 @@ class _PagedRunner:
         return out.astype(q.dtype).reshape(
             b, t_len, self.n_heads * self.hd)
 
-    def _stack_forward(self, h, k_caches, v_caches, q_pos, t_len,
-                       attend_write):
-        """Layer scan shared by both forms. The whole [L, ...] caches
-        ride in the carry and the layer index in ``xs``;
-        ``attend_write(q, k, v, kc, vc, layer) -> (out, kc2, vc2)``
-        owns layer ``layer``'s cache update + attend."""
-        def layer(carry, xs):
-            h, kc, vc = carry
-            p, lyr = xs
-            caches = {}
+    def _kv_up(self, p):
+        """A layer's latent -> per-head [key | value] expansion,
+        [kv_rank, heads, nope_dim + v_dim]."""
+        k = self.kinds
+        return p["Wkvb"].reshape(k.kv_rank, k.n_heads,
+                                 k.nope_dim + k.v_dim)
 
-            def attend(q, k, v):
-                out, caches["k"], caches["v"] = attend_write(
-                    q, k, v, kc, vc, lyr)
+    def _latent_expanded(self, p, q, read_block, n_blocks, kb, q_pos):
+        """Latent attention of a prefill window, expanded: each block of
+        ``kb`` cache positions (``read_block(i) -> [B, kb, entry]``) is
+        expanded to per-head keys and values and folded into a running
+        softmax, so no [heads, window, kmax] array is ever live. Only
+        the blocks that hold a position some query may see are visited.
+        Block 0 holds position 0, which every query sees, so the running
+        maximum is real before any wholly masked block meets it."""
+        k = self.kinds
+        q_nope, q_pe = q
+        b, t = q_pos.shape
+        w_up = self._kv_up(p)
+        f32 = jnp.float32
+
+        def fold(i, carry):
+            m, l, acc = carry
+            blk = read_block(i)
+            kv = jnp.einsum("bkr,rhd->bkhd", blk[..., :k.kv_rank], w_up)
+            s = (jnp.einsum("bqhd,bkhd->bhqk", q_nope,
+                            kv[..., :k.nope_dim],
+                            preferred_element_type=f32)
+                 + jnp.einsum("bqhd,bkd->bhqk", q_pe,
+                              blk[..., k.kv_rank:],
+                              preferred_element_type=f32)) \
+                * k.softmax_scale
+            k_pos = i * kb + jnp.arange(kb, dtype=jnp.int32)
+            s = jnp.where((k_pos[None, None] <= q_pos[:, :, None])[:, None],
+                          s, -1e30)
+            m2 = jnp.maximum(m, jnp.max(s, axis=-1))
+            w = jnp.exp(s - m2[..., None])
+            a = jnp.exp(m - m2)
+            acc = acc * a[..., None] + jnp.einsum(
+                "bhqk,bkhd->bhqd", w.astype(kv.dtype),
+                kv[..., k.nope_dim:], preferred_element_type=f32)
+            return m2, l * a + jnp.sum(w, axis=-1), acc
+
+        with jax.named_scope("mla/expand"):
+            init = (jnp.full((b, k.n_heads, t), -1e30, f32),
+                    jnp.zeros((b, k.n_heads, t), f32),
+                    jnp.zeros((b, k.n_heads, t, k.v_dim), f32))
+            seen = jnp.minimum(jnp.max(q_pos) // kb + 1, n_blocks)
+            _, l, acc = jax.lax.fori_loop(0, seen, fold, init)
+            out = jnp.moveaxis(acc / l[..., None], 1, 2)
+        return out.astype(q_nope.dtype).reshape(b, t,
+                                                k.n_heads * k.v_dim)
+
+    def _latent_absorbed(self, p, q, view, q_pos):
+        """Latent attention of decode steps, absorbed: the key half of
+        the expansion moves into the query (``q_nope Wk^T``, then one
+        [kv_rank + rope_dim]-wide product with the cache as it lies),
+        the value half onto the attended latent. Same mathematics as
+        _latent_expanded; the cache is read once and never expanded."""
+        k = self.kinds
+        q_nope, q_pe = q
+        b, t = q_pos.shape
+        w_up = self._kv_up(p)
+        f32 = jnp.float32
+        with jax.named_scope("mla/absorb"):
+            q_abs = jnp.einsum("bqhd,rhd->bqhr", q_nope,
+                               w_up[..., :k.nope_dim])
+            s = jnp.einsum("bqhc,bkc->bhqk",
+                           jnp.concatenate([q_abs, q_pe], axis=-1), view,
+                           preferred_element_type=f32) * k.softmax_scale
+            mask = (jnp.arange(view.shape[1], dtype=jnp.int32)[None, None]
+                    <= q_pos[:, :, None])
+            w = jax.nn.softmax(jnp.where(mask[:, None], s, -1e30), axis=-1)
+            # over the whole entry: slicing the latent out of the view
+            # would copy it; the rotated key's columns are dropped after
+            o_lat = jnp.einsum("bhqk,bkc->bqhc", w.astype(view.dtype),
+                               view, preferred_element_type=f32)
+            out = jnp.einsum("bqhr,rhd->bqhd",
+                             o_lat[..., :k.kv_rank].astype(view.dtype),
+                             w_up[..., k.nope_dim:])
+        return out.reshape(b, t, k.n_heads * k.v_dim)
+
+    def _stack_forward(self, h, pools, q_pos, attend_write):
+        """The layers, shared by both forms: the leading layers one by
+        one, then the scan over the stacked ones. The whole [L, ...]
+        caches ride in the carry and the layer index in ``xs``;
+        ``attend_write(p, q, entries, pools, layer) -> (out, pools2)``
+        owns layer ``layer``'s cache update + attend."""
+        self._loads, self.picks = [], None
+
+        def layer(h, pools, p, lyr, ffn=None):
+            held = {}
+
+            def attend(q, entries):
+                out, held["pools"] = attend_write(p, q, entries, pools,
+                                                  lyr)
                 return out
 
-            h = decoder_block(p, h, n_heads=self.n_heads,
-                              n_kv=self.n_kv, base=self.base,
-                              eps=self.eps, pos=q_pos,
-                              attend_fn=attend,
-                              moe_top_k=self.moe_top_k)
-            return (h, caches["k"], caches["v"]), None
+            h, routing = block_forward(self.kinds, p, h, q_pos, attend,
+                                       ffn=ffn, valid=self.valid)
+            if routing is not None:         # (load, picks at pick_at)
+                at = (jnp.zeros((h.shape[0],), jnp.int32)
+                      if self.pick_at is None else self.pick_at)
+                routing = (routing[0],
+                           routing[1][jnp.arange(h.shape[0]), at])
+            return h, held["pools"], routing
 
-        (h, k_caches, v_caches), _ = jax.lax.scan(
-            layer, (h, k_caches, v_caches),
-            (self.params,
-             jnp.arange(k_caches.shape[0], dtype=jnp.int32)))
-        return h, k_caches, v_caches
+        n_lead = 0
+        if self.lead is not None:
+            n_lead = jax.tree_util.tree_leaves(self.lead)[0].shape[0]
+            for i in range(n_lead):
+                h, pools, _ = layer(
+                    h, pools, {s: w[i] for s, w in self.lead.items()},
+                    i, ffn="swiglu")
+
+        # the routed experts' stacks stay whole outside the scan's xs: a
+        # scan slices its xs, and a slice that feeds the grouped matmul
+        # is a copy of every expert of the layer, on every call
+        held = {s: w for s, w in self.params.items()
+                if s in _EXPERT_SLOTS and self.kinds.ffn == "routed"
+                and s + "Scale" not in self.params}
+        sliced = {s: w for s, w in self.params.items() if s not in held}
+
+        def scanned(carry, xs):
+            p = dict(xs[0], **held)
+            if held:
+                p["ExpertsOf"] = xs[1]
+            h, pools, routing = layer(*carry, p, xs[1] + n_lead)
+            return (h, pools), routing
+
+        n = pools[0].shape[0] - n_lead
+        (h, pools), routing = jax.lax.scan(
+            scanned, (h, pools),
+            (sliced, jnp.arange(n, dtype=jnp.int32)))
+        if routing is not None:
+            self._loads, self.picks = routing
+        return h, pools
 
     # -- paged form (prefill) --------------------------------------------
-    def forward(self, h, k_pages, v_pages, table, pos0, t_len):
+    def forward(self, h, *pools_table_pos0_len):
+        *pools, table, pos0, t_len = pools_table_pos0_len
         b = h.shape[0]
-        kmax = table.shape[1] * self.page_size
+        ps = self.page_size
+        kmax = table.shape[1] * ps
         q_pos = pos0[:, None] + jnp.arange(t_len, dtype=jnp.int32)[None]
+        # latent attention reads its pages a block of keys at a time
+        ppb = max(1, min(_KEY_BLOCK // ps, table.shape[1]))
+        n_blocks = -(-table.shape[1] // ppb)
+        blocks = jnp.pad(table, ((0, 0),
+                                 (0, n_blocks * ppb - table.shape[1])))
 
-        def attend_write(q, k, v, kp, vp, lyr):
-            pg = jnp.take_along_axis(table, q_pos // self.page_size,
-                                     axis=1)
-            kp2 = kp.at[lyr, pg, q_pos % self.page_size].set(k)
-            vp2 = vp.at[lyr, pg, q_pos % self.page_size].set(v)
-            k_all = kp2[lyr, table].reshape(b, kmax, self.n_kv, self.hd)
-            v_all = vp2[lyr, table].reshape(b, kmax, self.n_kv, self.hd)
-            return (self._attend_math(q, k_all, v_all, q_pos, t_len),
-                    kp2, vp2)
+        def attend_write(p, q, entries, pools, lyr):
+            pg = jnp.take_along_axis(table, q_pos // ps, axis=1)
+            pools = tuple(pl.at[lyr, pg, q_pos % ps].set(e)
+                          for pl, e in zip(pools, entries))
+            if self.kinds.attention == "latent":
+                def read_block(i):
+                    tb = jax.lax.dynamic_slice_in_dim(blocks, i * ppb,
+                                                      ppb, axis=1)
+                    return pools[0][lyr, tb].reshape(b, ppb * ps, -1)
 
-        return self._stack_forward(h, k_pages, v_pages, q_pos, t_len,
-                                   attend_write)
+                return (self._latent_expanded(p, q, read_block, n_blocks,
+                                              ppb * ps, q_pos), pools)
+            views = [pl[lyr, table].reshape((b, kmax) + pl.shape[3:])
+                     for pl in pools]
+            return self._attend_math(q, *views, q_pos, t_len), pools
+
+        h, pools = self._stack_forward(h, tuple(pools), q_pos,
+                                       attend_write)
+        return (h,) + tuple(pools)
 
     # -- dense form (decode / spec loops) --------------------------------
     def gather(self, pages, table):
-        """[L, P, ps, g, hd] pools -> dense [L, B, kmax, g, hd] view of
+        """[L, P, ps, *entry] pool -> dense [L, B, kmax, *entry] view of
         each row's pages, in table order."""
         lyr, b = pages.shape[0], table.shape[0]
         return pages[:, table].reshape(
-            lyr, b, table.shape[1] * self.page_size, pages.shape[-2],
-            pages.shape[-1])
+            (lyr, b, table.shape[1] * self.page_size) + pages.shape[3:])
 
     def scatter(self, pages, dense, table):
         """Write the dense view back through the table. Rows' real
         pages are disjoint by construction; every null-table entry
         (inactive slots, unallocated tails) collides harmlessly on
         page 0, which nothing ever reads."""
-        lyr, b, kmax = dense.shape[0], dense.shape[1], dense.shape[2]
-        mp = table.shape[1]
+        lyr, b = dense.shape[0], dense.shape[1]
         return pages.at[:, table].set(
-            dense.reshape(lyr, b, mp, self.page_size,
-                          dense.shape[-2], dense.shape[-1]))
+            dense.reshape((lyr, b, table.shape[1], self.page_size)
+                          + dense.shape[3:]))
 
-    def forward_dense(self, h, k_dense, v_dense, pos0, t_len):
-        b = h.shape[0]
-        rows = jnp.arange(b)
+    def forward_dense(self, h, *dense_pos0_len):
+        *dense, pos0, t_len = dense_pos0_len
+        rows = jnp.arange(h.shape[0])
         q_pos = pos0[:, None] + jnp.arange(t_len, dtype=jnp.int32)[None]
 
-        def attend_write(q, k, v, kd, vd, lyr):
-            kd2 = kd.at[lyr, rows[:, None], q_pos].set(k)
-            vd2 = vd.at[lyr, rows[:, None], q_pos].set(v)
-            return (self._attend_math(q, kd2[lyr], vd2[lyr], q_pos,
-                                      t_len), kd2, vd2)
+        def attend_write(p, q, entries, dense, lyr):
+            dense = tuple(d.at[lyr, rows[:, None], q_pos].set(e)
+                          for d, e in zip(dense, entries))
+            if self.kinds.attention == "latent":
+                return (self._latent_absorbed(p, q, dense[0][lyr], q_pos),
+                        dense)
+            return (self._attend_math(q, *(d[lyr] for d in dense), q_pos,
+                                      t_len), dense)
 
-        return self._stack_forward(h, k_dense, v_dense, q_pos, t_len,
-                                   attend_write)
+        h, dense = self._stack_forward(h, tuple(dense), q_pos,
+                                       attend_write)
+        return (h,) + tuple(dense)
 
     def logits_of(self, hl):
+        if self.kinds.residual == "mhc":      # the streams leave summed
+            hl = jnp.sum(hl.astype(jnp.float32), axis=-2).astype(hl.dtype)
         hn = rms_normalize(hl, self.fnorm, self.eps)
         if self.head_scale is None:
             return (hn @ self.head).astype(jnp.float32)
         return qmat(hn, {"W": self.head, "WScale": self.head_scale},
                     "W", cdt=jnp.float32)
 
+    def stats(self, decode, positions=None):
+        """The last forward's counters as PAGED_STATS orders them, int32
+        [5]: token-expert pairs and the fullest expert's tokens, summed
+        over its routed layers; for a decode step also its expert-layer
+        calls x experts held, the experts among them that a token
+        reached, and the cache positions its active rows attended."""
+        out = [jnp.int32(0)] * len(PAGED_STATS)
+        if len(self._loads):
+            loads = self._loads                           # [layers, E]
+            out[0] = jnp.sum(loads)
+            out[1] = jnp.sum(jnp.max(loads, axis=-1))
+            if decode:
+                out[2] = jnp.int32(loads.shape[0] * loads.shape[1])
+                out[3] = jnp.sum(loads > 0)
+        if decode and self.kinds.attention == "latent":
+            out[4] = jnp.sum(jnp.where(self.valid[:, 0], positions + 1, 0))
+        return jnp.stack([jnp.asarray(x, jnp.int32) for x in out])
+
 
 def _make_paged_runner(params, emb_w, fnorm, head, *, n_heads, n_kv,
                        base, eps, page_size, head_scale=None,
-                       moe_top_k=2):
+                       moe_top_k=2, kinds=None, lead=None):
     return _PagedRunner(params, emb_w, fnorm, head, n_heads=n_heads,
                         n_kv=n_kv, base=base, eps=eps,
                         page_size=page_size, head_scale=head_scale,
-                        moe_top_k=moe_top_k)
+                        moe_top_k=moe_top_k, kinds=kinds, lead=lead)
 
 
 def _paged_model_inputs(ins, prefix=""):
@@ -1120,6 +1502,64 @@ def _paged_model_inputs(ins, prefix=""):
             ins[prefix + "LmHead"][0], head_scale)
 
 
+def _llama_runner(ins, attrs):
+    params, emb_w, fnorm, head, head_scale = _paged_model_inputs(ins)
+    return _make_paged_runner(
+        params, emb_w, fnorm, head, n_heads=attrs["n_heads"],
+        n_kv=attrs.get("n_kv_heads", attrs["n_heads"]),
+        base=attrs.get("rope_base", 10000.0),
+        eps=attrs.get("epsilon", 1e-6),
+        page_size=attrs["page_size"], head_scale=head_scale)
+
+
+def _paged_prefill(run, tokens, lens, offsets, table, pools):
+    """A window of each row's prompt into its pages: the body of every
+    paged prefill op. Returns (next token [B], its float32 logits
+    [B, V], the pools); ``run.picks`` then holds the routed layers'
+    picks at each row's last real token."""
+    b, t = tokens.shape
+    run.pick_at = lens - 1
+    # what Stats counts: real tokens of rows that own a real first page
+    run.valid = (jnp.arange(t, dtype=jnp.int32)[None] < lens[:, None]) \
+        & (table[:, :1] > 0)
+    h, *pools = run.forward(run.embed(tokens), *pools, table, offsets, t)
+    logits = run.logits_of(h[jnp.arange(b), lens - 1])
+    return jnp.argmax(logits, axis=-1).astype(tokens.dtype), logits, pools
+
+
+def _paged_decode(run, tok, pos, table, pools, steps, extras=False):
+    """``steps`` greedy steps of every slot against the dense view: the
+    body of every paged decode op. Returns (tokens [B, steps], pools)
+    and, with ``extras``, each step's float32 logits [B, steps, V], its
+    routed picks [B, steps, routed layers, K] and the dispatch's Stats."""
+    # dense form: pool -> dense gather once, ``steps`` steps that
+    # carry the dense caches in place, one scatter back (_PagedRunner)
+    dense = tuple(run.gather(pl, table) for pl in pools)
+    run.valid = table[:, :1] > 0        # a live row owns a real first page
+
+    def step(carry, _):
+        tok, pos, dense, stats = carry
+        h, *dense = run.forward_dense(run.embed(tok[:, None]), *dense,
+                                      pos, 1)
+        logits = run.logits_of(h[:, 0])
+        nxt = jnp.argmax(logits, axis=-1).astype(tok.dtype)
+        if not extras:
+            return (nxt, pos + 1, tuple(dense), stats), nxt
+        return ((nxt, pos + 1, tuple(dense),
+                 stats + run.stats(True, pos)),
+                (nxt, logits, jnp.moveaxis(run.picks, 0, 1)))
+
+    stats0 = jnp.zeros((len(PAGED_STATS),), jnp.int32) if extras else None
+    (_, _, dense, stats), ys = jax.lax.scan(
+        step, (tok, pos.astype(jnp.int32), dense, stats0), None,
+        length=steps)
+    pools = [run.scatter(pl, d, table) for pl, d in zip(pools, dense)]
+    if not extras:
+        return jnp.moveaxis(ys, 0, 1), pools
+    return (jnp.moveaxis(ys[0], 0, 1), pools, jnp.moveaxis(ys[1], 0, 1),
+            jnp.moveaxis(ys[2], 0, 1), stats)
+
+
 @register_op("llama_paged_prefill")
 def _llama_paged_prefill(ctx, ins, attrs):
     """Prefill one (or a few) prompt(s) into paged-KV slots and emit
@@ -1132,22 +1572,10 @@ def _llama_paged_prefill(ctx, ins, attrs):
     [L, n_pages, page_size, g, hd]. Outputs NextTok [B] plus the
     updated pools."""
     tokens = ins["Tokens"][0]
-    lens = ins["Lens"][0]
-    table = ins["Table"][0]
-    kp, vp = ins["KPages"][0], ins["VPages"][0]
-    params, emb_w, fnorm, head, head_scale = _paged_model_inputs(ins)
-    run = _make_paged_runner(
-        params, emb_w, fnorm, head, n_heads=attrs["n_heads"],
-        n_kv=attrs.get("n_kv_heads", attrs["n_heads"]),
-        base=attrs.get("rope_base", 10000.0),
-        eps=attrs.get("epsilon", 1e-6),
-        page_size=attrs["page_size"], head_scale=head_scale)
-    b = tokens.shape[0]
-    h = emb_w[tokens]
-    h, kp, vp = run.forward(h, kp, vp, table,
-                            jnp.zeros((b,), jnp.int32), tokens.shape[1])
-    last = h[jnp.arange(b), lens - 1]
-    nxt = jnp.argmax(run.logits_of(last), axis=-1).astype(tokens.dtype)
+    nxt, _, (kp, vp) = _paged_prefill(
+        _llama_runner(ins, attrs), tokens, ins["Lens"][0],
+        jnp.zeros((tokens.shape[0],), jnp.int32), ins["Table"][0],
+        (ins["KPages"][0], ins["VPages"][0]))
     return {"NextTok": [nxt], "KPagesOut": [kp], "VPagesOut": [vp]}
 
 
@@ -1178,23 +1606,10 @@ def _llama_paged_prefill_chunk(ctx, ins, attrs):
     NextTok [B] is the greedy token after the last REAL slice
     position — meaningful only on a prompt's final chunk (earlier
     chunks' callers discard it)."""
-    tokens = ins["Tokens"][0]
-    lens = ins["Lens"][0]
-    offsets = ins["Offsets"][0].astype(jnp.int32)
-    table = ins["Table"][0]
-    kp, vp = ins["KPages"][0], ins["VPages"][0]
-    params, emb_w, fnorm, head, head_scale = _paged_model_inputs(ins)
-    run = _make_paged_runner(
-        params, emb_w, fnorm, head, n_heads=attrs["n_heads"],
-        n_kv=attrs.get("n_kv_heads", attrs["n_heads"]),
-        base=attrs.get("rope_base", 10000.0),
-        eps=attrs.get("epsilon", 1e-6),
-        page_size=attrs["page_size"], head_scale=head_scale)
-    b = tokens.shape[0]
-    h = emb_w[tokens]
-    h, kp, vp = run.forward(h, kp, vp, table, offsets, tokens.shape[1])
-    last = h[jnp.arange(b), lens - 1]
-    nxt = jnp.argmax(run.logits_of(last), axis=-1).astype(tokens.dtype)
+    nxt, _, (kp, vp) = _paged_prefill(
+        _llama_runner(ins, attrs), ins["Tokens"][0], ins["Lens"][0],
+        ins["Offsets"][0].astype(jnp.int32), ins["Table"][0],
+        (ins["KPages"][0], ins["VPages"][0]))
     return {"NextTok": [nxt], "KPagesOut": [kp], "VPagesOut": [vp]}
 
 
@@ -1210,36 +1625,97 @@ def _llama_paged_decode(ctx, ins, attrs):
     1, and an all-null table; their outputs are garbage the engine
     discards, and their writes land on the null page. OutTokens
     [B, steps]."""
-    tok = ins["Tokens"][0]
-    pos = ins["Positions"][0]
-    table = ins["Table"][0]
-    kp, vp = ins["KPages"][0], ins["VPages"][0]
-    params, emb_w, fnorm, head, head_scale = _paged_model_inputs(ins)
-    run = _make_paged_runner(
-        params, emb_w, fnorm, head, n_heads=attrs["n_heads"],
-        n_kv=attrs.get("n_kv_heads", attrs["n_heads"]),
-        base=attrs.get("rope_base", 10000.0),
-        eps=attrs.get("epsilon", 1e-6),
-        page_size=attrs["page_size"], head_scale=head_scale)
-    steps = max(1, int(attrs.get("steps", 1)))
+    toks, (kp, vp) = _paged_decode(
+        _llama_runner(ins, attrs), ins["Tokens"][0], ins["Positions"][0],
+        ins["Table"][0], (ins["KPages"][0], ins["VPages"][0]),
+        max(1, int(attrs.get("steps", 1))))
+    return {"OutTokens": [toks], "KPagesOut": [kp], "VPagesOut": [vp]}
 
-    # dense form: pool -> dense gather once, ``steps`` steps that
-    # carry the dense caches in place, one scatter back (_PagedRunner)
-    kd, vd = run.gather(kp, table), run.gather(vp, table)
 
-    def step(carry, _):
-        tok, pos, kd, vd = carry
-        h = emb_w[tok][:, None, :]
-        h, kd, vd = run.forward_dense(h, kd, vd, pos, 1)
-        nxt = jnp.argmax(run.logits_of(h[:, 0]),
-                         axis=-1).astype(tok.dtype)
-        return (nxt, pos + 1, kd, vd), nxt
+# ---------------------------------------------------------------------
+# The same three programs for a model whose block kinds are attributes
+# (models/latent_moe.py): one list of pools, whatever a token's cache
+# entries are; the float32 logits behind each emitted token and the
+# dispatch's counters (PAGED_STATS) beside the tokens.
+# ---------------------------------------------------------------------
 
-    (_, _, kd, vd), toks = jax.lax.scan(
-        step, (tok, pos.astype(jnp.int32), kd, vd), None, length=steps)
-    return {"OutTokens": [jnp.moveaxis(toks, 0, 1)],
-            "KPagesOut": [run.scatter(kp, kd, table)],
-            "VPagesOut": [run.scatter(vp, vd, table)]}
+_BLOCK_SLOTS = (
+    "AttnNorm", "MlpNorm", "Wqa", "QNorm", "Wqb", "Wkva", "KvNorm", "Wkvb",
+    "Wo", "WGate", "WUp", "WDown", "MoeRouter", "MoeBias", "MoeWGate",
+    "MoeWUp", "MoeWDown", "ShWGate", "ShWUp", "ShWDown", "HcAttnPhi",
+    "HcAttnAlpha", "HcAttnBias", "HcMlpPhi", "HcMlpAlpha", "HcMlpBias")
+
+
+def _block_runner(ins, attrs):
+    """The runner of a block_paged_* op: the stacked layers' parameters
+    by slot, the leading dense layers' under ``Lead<Slot>``, and the
+    kinds from the attributes."""
+    kinds = BlockKinds(
+        n_heads=attrs["n_heads"], eps=attrs["epsilon"],
+        attention=attrs["attention"], ffn=attrs["ffn"],
+        residual=attrs["residual"], moe_top_k=attrs["moe_top_k"],
+        scoring=attrs["scoring"], route_scale=attrs["route_scale"],
+        kv_rank=attrs["kv_rank"], rope_dim=attrs["rope_dim"],
+        nope_dim=attrs["nope_dim"], v_dim=attrs["v_dim"],
+        rope_inv_freq=np.asarray(attrs["rope_inv_freq"], np.float32),
+        softmax_scale=attrs["softmax_scale"],
+        n_streams=attrs["n_streams"],
+        sinkhorn_iters=attrs["sinkhorn_iters"], hc_eps=attrs["hc_eps"],
+        hc_clamp=attrs["hc_clamp"])
+    params = {s: ins[s][0] for s in _BLOCK_SLOTS if s in ins}
+    lead = {s: ins["Lead" + s][0] for s in _BLOCK_SLOTS
+            if "Lead" + s in ins}
+    return _make_paged_runner(
+        params, ins["Emb"][0], ins["FinalNorm"][0], ins["LmHead"][0],
+        n_heads=kinds.n_heads, n_kv=kinds.n_kv, base=kinds.base,
+        eps=kinds.eps, page_size=attrs["page_size"],
+        moe_top_k=kinds.moe_top_k, kinds=kinds, lead=lead or None)
+
+
+def _block_prefill_outputs(run, nxt, logits, pools):
+    return {"NextTok": [nxt], "Logits": [logits],
+            "Picks": [jnp.moveaxis(run.picks, 0, 1)],
+            "PoolsOut": list(pools), "Stats": [run.stats(False)]}
+
+
+@register_op("block_paged_prefill")
+def _block_paged_prefill(ctx, ins, attrs):
+    """llama_paged_prefill for a model of any block kinds: Pools is the
+    list of its cache pools; also Logits [B, V] float32 (the logits
+    NextTok is the argmax of), Picks [B, routed layers, K] (the experts
+    each routed layer picked for the row's last real token: routing is
+    discrete, and whoever compares Logits with a reference has to know
+    it) and Stats (PAGED_STATS)."""
+    run = _block_runner(ins, attrs)
+    tokens = ins["Tokens"][0]
+    return _block_prefill_outputs(run, *_paged_prefill(
+        run, tokens, ins["Lens"][0],
+        jnp.zeros((tokens.shape[0],), jnp.int32), ins["Table"][0],
+        ins["Pools"]))
+
+
+@register_op("block_paged_prefill_chunk")
+def _block_paged_prefill_chunk(ctx, ins, attrs):
+    """llama_paged_prefill_chunk for a model of any block kinds (see
+    block_paged_prefill)."""
+    run = _block_runner(ins, attrs)
+    return _block_prefill_outputs(run, *_paged_prefill(
+        run, ins["Tokens"][0], ins["Lens"][0],
+        ins["Offsets"][0].astype(jnp.int32), ins["Table"][0],
+        ins["Pools"]))
+
+
+@register_op("block_paged_decode")
+def _block_paged_decode(ctx, ins, attrs):
+    """llama_paged_decode for a model of any block kinds: also Logits
+    [B, steps, V] float32, Picks [B, steps, routed layers, K] and Stats
+    summed over the steps."""
+    toks, pools, logits, picks, stats = _paged_decode(
+        _block_runner(ins, attrs), ins["Tokens"][0], ins["Positions"][0],
+        ins["Table"][0], ins["Pools"],
+        max(1, int(attrs.get("steps", 1))), extras=True)
+    return {"OutTokens": [toks], "Logits": [logits], "Picks": [picks],
+            "PoolsOut": list(pools), "Stats": [stats]}
 
 
 @register_op("llama_paged_spec_step")
@@ -1401,7 +1877,6 @@ def _llama_decoder_stack(ctx, ins, attrs):
 # the slots each op actually declares, so one shared rule covers the
 # whole prefill/chunk/decode/spec family.
 # ---------------------------------------------------------------------
-import math  # noqa: E402
 
 from ..analysis.numcheck import NumInfo, num_first  # noqa: E402
 from ..core.registry import register_numerics  # noqa: E402
@@ -1421,6 +1896,19 @@ def _num_paged_kv(op, ins, attrs):
     return out
 
 
+def _num_block_paged(op, ins, attrs):
+    count = NumInfo(0.0, math.inf, finite=True, confident=True)
+    pool = num_first(ins, "Pools")
+    unknown = NumInfo(-math.inf, math.inf, finite=pool.finite,
+                      confident=pool.confident)
+    return {"NextTok": [count], "OutTokens": [count], "Picks": [count],
+            "Stats": [count], "Logits": [unknown],
+            "PoolsOut": [unknown] * len(ins.get("Pools", ()))}
+
+
+for _op in ("block_paged_prefill", "block_paged_prefill_chunk",
+            "block_paged_decode"):
+    register_numerics(_op)(_num_block_paged)
 register_numerics("llama_paged_prefill")(_num_paged_kv)
 register_numerics("llama_paged_prefill_chunk")(_num_paged_kv)
 register_numerics("llama_paged_decode")(_num_paged_kv)

@@ -103,7 +103,17 @@ _DECODE_COUNTERS = (
     "decode_dispatch_s_total", "chunk_dispatch_s_total",
     "prefill_dispatch_s_total", "prefill_dispatch_total",
     "prefill_tokens_total", "prefill_padded_tokens_total",
-    "queue_wait_s_total")
+    "queue_wait_s_total",
+    # summed on the device by the programs of a model with routed
+    # experts or a latent cache, and returned beside a dispatch's tokens
+    # (ops/transformer_ops.py PAGED_STATS); 0 for a dense GQA model.
+    # Token-expert pairs and the fullest expert's tokens, per routed-
+    # layer call, over every dispatch; over decode dispatches alone:
+    # routed-layer calls x experts held, the experts of those that a
+    # token reached, and the cache positions the active rows attended.
+    "moe_assignments_total", "moe_max_load_total",
+    "moe_decode_expert_calls_total", "moe_decode_experts_touched_total",
+    "latent_tokens_read_total")
 
 # priority rank -> the per-class shed counter it lands in
 _SHED_BY_RANK = {rank: f"shed_{name}_total"
@@ -315,9 +325,15 @@ class _ChunkJob:
 
 
 class DecodeEngine:
-    """Continuous-batching decode server for one dense Llama-family
-    config. ``scope`` must already hold the generator-layout weights
-    (``build_llama_generator`` startup, a trained+stacked scope, or a
+    """Continuous-batching decode server for one model. A model is a
+    config with ``build_paged_programs(**geometry)``: the prefill, chunk
+    and step programs and the specification of its cache pools
+    (models/llama.py PagedDecodePrograms; LlamaConfig and
+    models/latent_moe.py LatentMoEConfig are the two there are). The
+    engine owns the pools, the page tables and the slots, and knows
+    nothing else of the model. ``scope`` must already hold the weights
+    the programs name (for Llama the generator layout:
+    ``build_llama_generator`` startup, a trained+stacked scope, or a
     ``quantize_generator_weights``'d one; draft weights under
     ``draft.*`` when ``draft_cfg`` — see models/llama.py
     copy_weights_as_draft). The engine never initializes weights.
@@ -328,7 +344,6 @@ class DecodeEngine:
     def __init__(self, cfg, scope=None, place=None, config=None,
                  draft_cfg=None, auto_start=True, optimize=True,
                  compile_store=None):
-        from ..models.llama import build_llama_paged_programs
         self.cfg = cfg
         self.draft_cfg = draft_cfg
         self.config = config or DecodeConfig()
@@ -364,8 +379,8 @@ class DecodeEngine:
             self._bo_max_new_cap = int(
                 bo_kw.pop("max_new_cap", self._bo_max_new_cap))
             self.brownout = BrownoutController(**bo_kw)
-        self.programs = build_llama_paged_programs(
-            cfg, max_batch=c.max_batch, page_size=c.page_size,
+        self.programs = cfg.build_paged_programs(
+            max_batch=c.max_batch, page_size=c.page_size,
             n_pages=n_pages, pages_per_seq=self.pages_per_seq,
             prompt_buckets=c.prompt_buckets,
             decode_block=c.decode_block,
@@ -382,14 +397,18 @@ class DecodeEngine:
         if optimize:
             self._optimize_programs()
         import jax.numpy as jnp
-        self._kp = jnp.zeros(tuple(self.programs.kv_shape), cfg.dtype)
-        self._vp = jnp.zeros(tuple(self.programs.kv_shape), cfg.dtype)
-        self._dkp = self._dvp = None
-        if draft_cfg is not None:
-            self._dkp = jnp.zeros(tuple(self.programs.draft_kv_shape),
-                                  draft_cfg.dtype)
-            self._dvp = jnp.zeros(tuple(self.programs.draft_kv_shape),
-                                  draft_cfg.dtype)
+        # the cache pools, as the model's programs specify them; every
+        # dispatch takes them in and hands them back
+        self._pools = [jnp.zeros(tuple(shape), dtype)
+                       for shape, dtype in self.programs.pool_specs]
+        self._draft_pools = [
+            jnp.zeros(tuple(shape), dtype)
+            for shape, dtype in self.programs.draft_pool_specs or ()]
+        # program label -> what its last dispatch returned beside tokens,
+        # pools and stats, by name (``logits``, ``picks``), where the
+        # model's programs return such: left on the device, for whoever
+        # compares them with a reference
+        self.kept = {}
         # all retries surface at the serving layer (counted); the inner
         # executor must not also retry. donate_state=False: pool
         # replicas share one weight scope (see ServingEngine).
@@ -715,7 +734,7 @@ class DecodeEngine:
                 or state.get("kind") != "kv_handoff" \
                 or not all(key in state for key in
                            ("prompt", "max_new", "pos", "cur", "prev",
-                            "emitted", "pages", "page_size", "k", "v")):
+                            "emitted", "pages", "page_size", "cache")):
             raise ServingError(
                 "import_handoff needs the blob a prefill_only request "
                 "resolved with (dict with kind='kv_handoff')")
@@ -746,13 +765,13 @@ class DecodeEngine:
             self.metrics.incr("retired_total")
             req.set_result(np.asarray(emitted, dtype=np.int64))
             return req
-        k = np.asarray(state["k"])
+        n_src = self._handoff_cache(state)[0].shape[1]
         if self.allocator.pages_for(prompt.size + max_new) \
                 > self.allocator.usable_pages \
-                or k.shape[1] > self.allocator.usable_pages:
+                or n_src > self.allocator.usable_pages:
             self.metrics.incr("shed_total")
             raise PagesExhaustedError(
-                f"handoff state needs {k.shape[1]} pages but the pool "
+                f"handoff state needs {n_src} pages but the pool "
                 f"only has {self.allocator.usable_pages}")
         if not self.breaker.admits():
             self.metrics.incr("breaker_shed_total")
@@ -863,56 +882,67 @@ class DecodeEngine:
 
     # scope is passed explicitly to every run — scope_guard swaps a
     # process-global, which would race other live engines' threads
-    def _run_prefill_program(self, bucket, tokens, lens, table):
-        b = self.programs.prefill[bucket]
-        nxt, self._kp, self._vp = self.exe.run(
-            b["program"],
-            feed=self._bundle_feed(
-                b, (tokens, lens, table, self._kp, self._vp)),
+    def _run_program(self, label, b, arrays):
+        """One dispatch of bundle ``b``: ``arrays`` then the pools it
+        names (the target's; ``draft``: the draft's; ``both``: one after
+        the other) in, the pools rebound to what comes back. Returns the
+        token outputs as numpy; a ``stats`` fetch ticks the counters it
+        names, any other extra stays on the device under
+        ``kept[label]``."""
+        which = b.get("pools", "target")
+        pools = ([] if which == "draft" else self._pools) \
+            + ([] if which == "target" else self._draft_pools)
+        outs = self.exe.run(
+            b["program"], feed=self._bundle_feed(b, (*arrays, *pools)),
             fetch_list=b["fetch"], mode="test", return_numpy=False,
             scope=self.scope)
-        return np.asarray(nxt)
+        # poll for the dispatch's end, do not block on it: a worker
+        # blocked in the fetch is woken 2.5-3 ms late on the chip's
+        # host in most processes (the "slow mode" of PERF.md section 2:
+        # 9 of 14 runs of one cell, none of 6 when polling; PR 27). The
+        # worker has nothing else to do until the tokens are there, and
+        # sleep(0) hands the interpreter to whichever thread wants it.
+        while not outs[0].is_ready():
+            time.sleep(0)
+        extras = b.get("extras", ())
+        n_head = len(outs) - len(pools) - len(extras)
+        back = list(outs[n_head:n_head + len(pools)])
+        if which != "draft":
+            self._pools, back = (back[:len(self._pools)],
+                                 back[len(self._pools):])
+        if which != "target":
+            self._draft_pools = back
+        kept = dict(zip(extras, outs[n_head + len(pools):]))
+        if "stats" in kept:
+            self.metrics.incr_many(dict(zip(
+                self.programs.stats,
+                (int(x) for x in np.asarray(kept.pop("stats"))))))
+        if kept:
+            self.kept[label] = kept
+        return [np.asarray(x) for x in outs[:n_head]]
+
+    def _run_prefill_program(self, bucket, tokens, lens, table):
+        return self._run_program(
+            f"prefill_{bucket}", self.programs.prefill[bucket],
+            (tokens, lens, table))[0]
 
     def _run_draft_prefill_program(self, bucket, tokens, lens, table):
-        b = self.programs.draft_prefill[bucket]
-        _, self._dkp, self._dvp = self.exe.run(
-            b["program"],
-            feed=self._bundle_feed(
-                b, (tokens, lens, table, self._dkp, self._dvp)),
-            fetch_list=b["fetch"], mode="test", return_numpy=False,
-            scope=self.scope)
+        self._run_program(
+            f"draft_prefill_{bucket}",
+            self.programs.draft_prefill[bucket], (tokens, lens, table))
 
     def _run_chunk_program(self, tokens, lens, offsets, table):
-        b = self.programs.chunk
-        nxt, self._kp, self._vp = self.exe.run(
-            b["program"],
-            feed=self._bundle_feed(
-                b, (tokens, lens, offsets, table, self._kp, self._vp)),
-            fetch_list=b["fetch"], mode="test", return_numpy=False,
-            scope=self.scope)
-        return np.asarray(nxt)
+        return self._run_program("chunk", self.programs.chunk,
+                                 (tokens, lens, offsets, table))[0]
 
     def _run_decode_program(self, tokens, positions, table):
-        b = self.programs.decode
-        out, self._kp, self._vp = self.exe.run(
-            b["program"],
-            feed=self._bundle_feed(
-                b, (tokens, positions, table, self._kp, self._vp)),
-            fetch_list=b["fetch"], mode="test", return_numpy=False,
-            scope=self.scope)
-        return np.asarray(out)
+        return self._run_program("decode", self.programs.decode,
+                                 (tokens, positions, table))[0]
 
     def _run_spec_program(self, tokens, prev, positions, table):
-        b = self.programs.spec
-        (emitted, accepted, self._kp, self._vp, self._dkp,
-         self._dvp) = self.exe.run(
-            b["program"],
-            feed=self._bundle_feed(
-                b, (tokens, prev, positions, table, self._kp,
-                    self._vp, self._dkp, self._dvp)),
-            fetch_list=b["fetch"], mode="test", return_numpy=False,
-            scope=self.scope)
-        return np.asarray(emitted), np.asarray(accepted)
+        emitted, accepted = self._run_program(
+            "spec", self.programs.spec, (tokens, prev, positions, table))
+        return emitted, accepted
 
     # -- internal: scheduler ---------------------------------------------
     def _pages_needed(self, prompt_len, max_new):
@@ -1259,8 +1289,7 @@ class DecodeEngine:
         with self._slots_lock:
             alloc_state = self.allocator.export_state(pages)
         idxs = np.asarray(pages, np.int64)
-        k = np.asarray(self._kp)[:, idxs]
-        v = np.asarray(self._vp)[:, idxs]
+        cache = [np.asarray(pool)[:, idxs] for pool in self._pools]
         with self._slots_lock:
             self.allocator.free(pages)
         eos = self.config.eos_id
@@ -1268,8 +1297,7 @@ class DecodeEngine:
         if done:
             # a finished request needs no KV — the importer resolves it
             # without a decode slot, so don't ship dead pages
-            k = k[:, :0]
-            v = v[:, :0]
+            cache = [x[:, :0] for x in cache]
             alloc_state = {"pages": [], "page_size":
                            alloc_state["page_size"]}
         state = {"kind": "kv_handoff",
@@ -1281,7 +1309,7 @@ class DecodeEngine:
                  "emitted": [int(first)],
                  "pages": alloc_state["pages"],
                  "page_size": alloc_state["page_size"],
-                 "k": k, "v": v,
+                 "cache": cache,
                  "done": bool(done),
                  "ttft_s": r.ttft_s}
         self.metrics.incr("handoff_export_total")
@@ -1292,6 +1320,19 @@ class DecodeEngine:
         with self._cv:
             self._cv.notify_all()
 
+    def _handoff_cache(self, state):
+        """The page contents a handoff blob carries, one array a pool of
+        this engine's model, checked against the pools' entry shapes (a
+        blob of another model's cache would scatter nonsense)."""
+        cache = [np.asarray(x) for x in state["cache"]]
+        want = [tuple(p.shape[2:]) for p in self._pools]
+        got = [tuple(x.shape[2:]) for x in cache]
+        if got != want:
+            raise ServingError(
+                f"handoff cache entries {got} do not match this "
+                f"engine's pools {want}")
+        return cache
+
     def _admit_handoff(self, r, idx):
         """Install an imported handoff blob into slot ``idx``: fresh
         pages, an exact value copy of the exported page contents into
@@ -1300,9 +1341,8 @@ class DecodeEngine:
         a decode slot resuming at the handed-off position. Returns
         False (request requeued at the front) on page exhaustion."""
         state = r.handoff_state
-        k = np.asarray(state["k"])
-        v = np.asarray(state["v"])
-        n_src = int(k.shape[1])
+        cache = self._handoff_cache(state)
+        n_src = int(cache[0].shape[1])
         try:
             with self._slots_lock:
                 pages = self.allocator.import_alloc(
@@ -1315,10 +1355,8 @@ class DecodeEngine:
             return False
         import jax.numpy as jnp
         rows = np.asarray(pages[:n_src], np.int64)
-        self._kp = self._kp.at[:, rows].set(
-            jnp.asarray(k, self._kp.dtype))
-        self._vp = self._vp.at[:, rows].set(
-            jnp.asarray(v, self._vp.dtype))
+        self._pools = [pool.at[:, rows].set(jnp.asarray(x, pool.dtype))
+                       for pool, x in zip(self._pools, cache)]
         table = np.zeros((self.pages_per_seq,), np.int32)
         table[:len(pages)] = pages
         emitted = [int(t) for t in state["emitted"]]
@@ -1434,14 +1472,20 @@ class DecodeEngine:
                 continue
             self.breaker.record_success()
             self._tick(chunk_prefill_total=1,
-                       chunk_dispatch_s_total=dispatch.seconds)
+                       chunk_dispatch_s_total=dispatch.seconds,
+                       prefill_tokens_total=int(sl.size),
+                       prefill_padded_tokens_total=cs)
             job.off += int(sl.size)
             progressed = True
             if job.off >= r.prompt.size:
+                # close() or the watchdog may have taken the job (and
+                # freed its pages) while this slice ran: install only a
+                # job that is still this engine's
                 with self._slots_lock:
-                    self._chunk_jobs.pop(idx, None)
-                self._install_first_token(r, job.pages, job.table,
-                                          int(nxt[0]), idx)
+                    live = self._chunk_jobs.pop(idx, None) is job
+                if live:
+                    self._install_first_token(r, job.pages, job.table,
+                                              int(nxt[0]), idx)
         return progressed
 
     def _active(self):

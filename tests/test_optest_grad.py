@@ -656,6 +656,11 @@ NONDIFF = {
                                  "tests/test_slo_sched.py)",
     "llama_paged_spec_step": "serving step emits int tokens "
                              "(per-row draft-and-verify)",
+    "block_paged_prefill": "serving step emits int tokens (logits vs "
+                           "the float32 reference pinned in "
+                           "tests/test_latent_moe.py)",
+    "block_paged_prefill_chunk": "serving step emits int tokens",
+    "block_paged_decode": "serving step emits int tokens",
     # optimizer-fusion plumbing (transpiler/fuse_optimizer.py): runs
     # POST-backward on grads/params — never on the loss tape; exact
     # fused-vs-unfused updates pinned in tests/test_fuse_optimizer.py

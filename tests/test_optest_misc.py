@@ -452,6 +452,9 @@ WAIVED = {
     "llama_paged_prefill_chunk": "tests/test_slo_sched.py",
     "llama_paged_decode": "tests/test_decode_serving.py",
     "llama_paged_spec_step": "tests/test_decode_serving.py",
+    "block_paged_prefill": "tests/test_latent_moe.py",
+    "block_paged_prefill_chunk": "tests/test_latent_moe.py",
+    "block_paged_decode": "tests/test_latent_moe.py",
     "fused_head_cross_entropy": "tests/test_fused_loss.py",
     "llama_stack_1f1b_loss": "tests/test_llama_pp.py",
     "while": "tests/test_sequence.py",

@@ -241,6 +241,63 @@ def test_caches_are_carried_not_streamed(op_name):
     assert not copies, f"{op_name}: whole-cache copy inside a loop: {copies}"
 
 
+def _latent_case(op_name):
+    """(op, inputs, attrs) of one block_paged_* op at LATENT_MOE_TINY:
+    latent attention over ONE pool, routed experts, four residual
+    streams, a leading dense layer before the scan."""
+    from paddle_tpu.models.latent_moe import LATENT_MOE_TINY as cfg
+    shapes = cfg.param_shapes()
+    keys = jax.random.split(jax.random.PRNGKey(3), len(shapes))
+    w = {name: (0.2 * jax.random.normal(k, shape)).astype(dt)
+         for k, (name, (shape, dt)) in zip(keys, sorted(shapes.items()))}
+    ins = {"Emb": w["tok_emb"], "FinalNorm": w["final_norm"],
+           "LmHead": w["lm_head"], "Table": jnp.asarray(TABLE)}
+    for prefix, scope, n, routed in (
+            ("Lead", "lead", cfg.n_dense_layers, False),
+            ("", "blocks", cfg.n_layers - cfg.n_dense_layers, True)):
+        for slot, (suffix, _, _) in cfg.layer_params(n, routed).items():
+            ins[prefix + slot] = w[f"{scope}.{suffix}"]
+    ins["Pools"] = jax.random.normal(
+        jax.random.PRNGKey(4), (cfg.n_layers, NP, PS, cfg.entry_dim))
+    if op_name == "block_paged_decode":
+        ins.update(Tokens=jnp.asarray(TOK), Positions=jnp.asarray(POS))
+    else:
+        toks = jax.random.randint(jax.random.PRNGKey(2), (B, 6), 0,
+                                  cfg.vocab_size)
+        ins.update(Tokens=toks, Lens=jnp.asarray([6, 3, 1, 5], jnp.int32))
+        if op_name == "block_paged_prefill_chunk":
+            ins["Offsets"] = jnp.asarray([3, 10, 0, 6], jnp.int32)
+    return (getattr(T, "_" + op_name), ins,
+            dict(cfg.block_attrs(PS), steps=4))
+
+
+@pytest.mark.parametrize("op_name", [
+    "block_paged_decode", "block_paged_prefill",
+    "block_paged_prefill_chunk"])
+def test_latent_cache_is_carried_not_streamed(op_name):
+    """The same two guards for the model with one [L, pages, page, 576]-
+    kind pool: no pool- or dense-view-shaped xs/ys, no whole-cache copy
+    in a loop (the expanded attention's key-block loop among them)."""
+    op, ins, attrs = _latent_case(op_name)
+    pool = ins["Pools"]
+    shapes = {tuple(pool.shape),
+              (pool.shape[0], B, KMAX) + tuple(pool.shape[3:])}
+
+    def fn(ins):
+        out = op(None, {k: [v] for k, v in ins.items()}, attrs)
+        return {k: v[0] for k, v in out.items()}
+
+    fn = jax.jit(fn)
+    streamed, carried = _scan_cache_use(jax.make_jaxpr(fn)(ins), shapes)
+    copies = _loop_copies(fn.lower(ins).compile().as_text(), shapes)
+    assert not streamed, (op_name, streamed)
+    assert carried, f"{op_name}: no scan carries the latent cache"
+    assert not copies, f"{op_name}: whole-cache copy in a loop: {copies}"
+    # nor are the experts' stacks sliced by the scan: they ride whole
+    expert_stacks = {tuple(ins[s].shape) for s in T._EXPERT_SLOTS}
+    assert not _scan_cache_use(jax.make_jaxpr(fn)(ins), expert_stacks)[0]
+
+
 def test_structure_check_sees_the_replaced_form(monkeypatch):
     """The detector is not vacuous: the frozen xs/ys form trips both
     halves of it, on this backend too."""
