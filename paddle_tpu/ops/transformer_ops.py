@@ -1163,18 +1163,22 @@ class _PagedRunner:
       the page pools through ``table`` [B, max_pages]: each layer
       writes the window's entries at ``[layer, page, offset]`` and
       attends over the row's pages of that layer (both prefill ops).
-    - ``gather``/``forward_dense``/``scatter`` — gather each row's
+    - ``gather``/``forward_dense``/``write_back`` — gather each row's
       pages to a dense [L, B, kmax, *entry] cache once a dispatch, run
       every step against it (each layer writes ``[layer, row, q_pos]``
-      and attends over ``dense[layer]``), and scatter the pages back
-      once at the end. The decode and speculative step ops use this.
+      and attends over ``dense[layer]``), and at the end write back to
+      the pools the entries the steps wrote, a few positions a row, and
+      nothing else: the rest of the view is what the pools already
+      hold. The decode and speculative step ops use this. Page 0 is the
+      null page: the writes of inactive slots and of unallocated tails
+      land there, in no defined order.
 
     In both, the layer scan CARRIES the whole [L, ...] caches beside
     ``h`` and scans over (weights, layer index): a scan's ``ys`` is a
     fresh buffer that cannot alias its ``xs``, so caches passed that
     way are rebuilt whole on every call — on every token, inside the
     decode op's step loop. Carried, they alias from the dispatch's
-    gather to its scatter, and a step touches the rows it writes and
+    gather to its write-back, and a step touches the rows it writes and
     the bytes attention reads (tests/test_paged_cache_inplace.py holds
     both to it). ``lead`` are the parameters of the model's leading
     layers whose feed-forward is dense (``lead_ffn``): they run before
@@ -1422,15 +1426,36 @@ class _PagedRunner:
         return pages[:, table].reshape(
             (lyr, b, table.shape[1] * self.page_size) + pages.shape[3:])
 
-    def scatter(self, pages, dense, table):
-        """Write the dense view back through the table. Rows' real
+    def write_back(self, pages, dense, table, pos0, n):
+        """The pool with the ``n`` positions from ``pos0`` [B] of every
+        row copied out of the dense view: all that the dispatch's steps
+        wrote, so all in which the view differs from the pool it was
+        gathered from. The [L, B, n, *entry] entries go to ``[:,
+        table[row, p // page_size], p % page_size]``, the addressing
+        ``forward`` writes with. A position at or beyond ``kmax`` is
+        dropped, as ``forward_dense`` dropped its write. Rows' real
         pages are disjoint by construction; every null-table entry
-        (inactive slots, unallocated tails) collides harmlessly on
-        page 0, which nothing ever reads."""
-        lyr, b = dense.shape[0], dense.shape[1]
-        return pages.at[:, table].set(
-            dense.reshape((lyr, b, table.shape[1], self.page_size)
-                          + dense.shape[3:]))
+        (inactive slots, unallocated tails) collides harmlessly on page
+        0, the null page: it holds garbage, and nothing reads it
+        unmasked."""
+        ps = self.page_size
+        kmax = table.shape[1] * ps
+        q_pos = pos0[:, None] + jnp.arange(n, dtype=jnp.int32)[None]
+        at = jnp.minimum(q_pos, kmax - 1)
+        # one index an entry, the layer's too: a slice across the layers
+        # has XLA re-lay the whole view with its layers innermost
+        lyr = jnp.arange(pages.shape[0])[:, None, None]
+        rows = jnp.arange(table.shape[0])[None, :, None]
+        entries = dense[lyr, rows, at[None]]
+        # an undonated pool is copied before it is written: not before
+        # the entries are out and the view is dead, or both are live
+        pages, entries = jax.lax.optimization_barrier((pages, entries))
+        # beyond kmax: a page past the pool's last, which the set drops
+        pg = jnp.where(q_pos < kmax,
+                       jnp.take_along_axis(table, at // ps, axis=1),
+                       pages.shape[1])
+        return pages.at[lyr, pg[None], (q_pos % ps)[None]].set(
+            entries, mode="drop")
 
     def forward_dense(self, h, *dense_pos0_len):
         *dense, pos0, t_len = dense_pos0_len
@@ -1532,8 +1557,9 @@ def _paged_decode(run, tok, pos, table, pools, steps, extras=False):
     body of every paged decode op. Returns (tokens [B, steps], pools)
     and, with ``extras``, each step's float32 logits [B, steps, V], its
     routed picks [B, steps, routed layers, K] and the dispatch's Stats."""
-    # dense form: pool -> dense gather once, ``steps`` steps that
-    # carry the dense caches in place, one scatter back (_PagedRunner)
+    # dense form: pool -> dense gather once, ``steps`` steps that carry
+    # the dense caches in place, their entries written back (_PagedRunner)
+    pos = pos.astype(jnp.int32)
     dense = tuple(run.gather(pl, table) for pl in pools)
     run.valid = table[:, :1] > 0        # a live row owns a real first page
 
@@ -1551,9 +1577,9 @@ def _paged_decode(run, tok, pos, table, pools, steps, extras=False):
 
     stats0 = jnp.zeros((len(PAGED_STATS),), jnp.int32) if extras else None
     (_, _, dense, stats), ys = jax.lax.scan(
-        step, (tok, pos.astype(jnp.int32), dense, stats0), None,
-        length=steps)
-    pools = [run.scatter(pl, d, table) for pl, d in zip(pools, dense)]
+        step, (tok, pos, dense, stats0), None, length=steps)
+    pools = [run.write_back(pl, d, table, pos, steps)
+             for pl, d in zip(pools, dense)]
     if not extras:
         return jnp.moveaxis(ys, 0, 1), pools
     return (jnp.moveaxis(ys[0], 0, 1), pools, jnp.moveaxis(ys[1], 0, 1),
@@ -1761,7 +1787,8 @@ def _llama_paged_spec_step(ctx, ins, attrs):
         eps=attrs.get("draft_epsilon", attrs.get("epsilon", 1e-6)),
         page_size=page_size, head_scale=d_hscale)
 
-    # dense form for the whole round (one gather/scatter per pool)
+    # dense form for the whole round (one gather and one write-back of
+    # the round's entries per pool)
     dkd, dvd = d_run.gather(dkp, table), d_run.gather(dvp, table)
     tkd, tvd = t_run.gather(tkp, table), t_run.gather(tvp, table)
 
@@ -1791,10 +1818,14 @@ def _llama_paged_spec_step(ctx, ins, attrs):
     match = (D == G[:, :gamma]).astype(jnp.int32)
     m = jnp.sum(jnp.cumprod(match, axis=1), axis=1)
     return {"Emitted": [G], "Accepted": [(m + 1).astype(jnp.int32)],
-            "KPagesOut": [t_run.scatter(tkp, tkd, table)],
-            "VPagesOut": [t_run.scatter(tvp, tvd, table)],
-            "DraftKPagesOut": [d_run.scatter(dkp, dkd, table)],
-            "DraftVPagesOut": [d_run.scatter(dvp, dvd, table)]}
+            "KPagesOut": [t_run.write_back(tkp, tkd, table, pos,
+                                           gamma + 1)],
+            "VPagesOut": [t_run.write_back(tvp, tvd, table, pos,
+                                           gamma + 1)],
+            "DraftKPagesOut": [d_run.write_back(dkp, dkd, table, pos - 1,
+                                                gamma + 1)],
+            "DraftVPagesOut": [d_run.write_back(dvp, dvd, table, pos - 1,
+                                                gamma + 1)]}
 
 
 @register_op("llama_decoder_stack")

@@ -11,6 +11,12 @@ section 6, PR 25). Two guards:
   whole-cache ``copy`` inside a loop;
 - bit parity: the replaced form, frozen below as ``_XsYsRunner``, gives
   the same tokens and the same pools, bit for bit.
+
+A dense-form dispatch writes back the entries it wrote, not its whole
+dense view (PERF.md section 6, PR 28). The same two guards: no write
+into a pool outside the step loop has an update of the view's size, and
+the whole-view scatter, frozen below as ``_WholeViewRunner``, gives the
+same tokens and the same pools on every page but the null one.
 """
 import re
 
@@ -37,6 +43,10 @@ TABLE = np.array([[1, 2, 3, 0, 0], [4, 5, 6, 7, 0], [0, 0, 0, 0, 0],
 POS = np.array([7, 13, 1, 2], np.int32)
 TOK = np.array([5, 17, 0, 33], np.int32)
 PREV = np.array([9, 2, 0, 41], np.int32)
+# the same rows with row 3 at the end of its table: its positions run
+# past KMAX (18, 19, then 20 and up, which are dropped). Row 1 runs off
+# its allocated pages onto a null entry of its table (position 16).
+POS_END = np.array([7, 13, 1, 18], np.int32)
 
 
 class _XsYsRunner(T._PagedRunner):
@@ -93,6 +103,18 @@ class _XsYsRunner(T._PagedRunner):
                                    attend_write)
 
 
+class _WholeViewRunner(T._PagedRunner):
+    """The write-back this repo ran until PR 28, kept as the plain
+    reference: the whole dense view goes back through the table, every
+    null-table entry colliding on page 0."""
+
+    def write_back(self, pages, dense, table, pos0, n):
+        lyr, b = dense.shape[0], dense.shape[1]
+        return pages.at[:, table].set(
+            dense.reshape((lyr, b, table.shape[1], self.page_size)
+                          + dense.shape[3:]))
+
+
 def _model(key, n_layers, prefix="", quant=False):
     """One toy model's op inputs: bf16, or int8 with ``<Slot>Scale``."""
     shapes = {"Wq": (D, NH * HD), "Wk": (D, NKV * HD), "Wv": (D, NKV * HD),
@@ -127,17 +149,18 @@ def _model(key, n_layers, prefix="", quant=False):
     return ins
 
 
-def _case(op_name, steps=4, quant=False):
+def _case(op_name, steps=4, quant=False, pos=POS):
     """(op, inputs, attrs) of one paged op at the toy shapes."""
     ins = _model(jax.random.PRNGKey(0), L, quant=quant)
     ins["Table"] = jnp.asarray(TABLE)
     attrs = dict(ATTRS, steps=steps)
     if op_name == "llama_paged_decode":
-        ins.update(Tokens=jnp.asarray(TOK), Positions=jnp.asarray(POS))
+        ins.update(Tokens=jnp.asarray(TOK), Positions=jnp.asarray(pos))
     elif op_name == "llama_paged_spec_step":
-        ins.update(_model(jax.random.PRNGKey(1), LD, prefix="Draft"))
+        ins.update(_model(jax.random.PRNGKey(1), LD, prefix="Draft",
+                          quant=quant))
         ins.update(Tokens=jnp.asarray(TOK), Prev=jnp.asarray(PREV),
-                   Positions=jnp.asarray(POS))
+                   Positions=jnp.asarray(pos))
     else:
         width = 6                 # a window that crosses a page boundary
         toks = jax.random.randint(jax.random.PRNGKey(2), (B, width), 0, V)
@@ -241,7 +264,7 @@ def test_caches_are_carried_not_streamed(op_name):
     assert not copies, f"{op_name}: whole-cache copy inside a loop: {copies}"
 
 
-def _latent_case(op_name):
+def _latent_case(op_name, steps=4, pos=POS):
     """(op, inputs, attrs) of one block_paged_* op at LATENT_MOE_TINY:
     latent attention over ONE pool, routed experts, four residual
     streams, a leading dense layer before the scan."""
@@ -260,7 +283,7 @@ def _latent_case(op_name):
     ins["Pools"] = jax.random.normal(
         jax.random.PRNGKey(4), (cfg.n_layers, NP, PS, cfg.entry_dim))
     if op_name == "block_paged_decode":
-        ins.update(Tokens=jnp.asarray(TOK), Positions=jnp.asarray(POS))
+        ins.update(Tokens=jnp.asarray(TOK), Positions=jnp.asarray(pos))
     else:
         toks = jax.random.randint(jax.random.PRNGKey(2), (B, 6), 0,
                                   cfg.vocab_size)
@@ -268,7 +291,7 @@ def _latent_case(op_name):
         if op_name == "block_paged_prefill_chunk":
             ins["Offsets"] = jnp.asarray([3, 10, 0, 6], jnp.int32)
     return (getattr(T, "_" + op_name), ins,
-            dict(cfg.block_attrs(PS), steps=4))
+            dict(cfg.block_attrs(PS), steps=steps))
 
 
 @pytest.mark.parametrize("op_name", [
@@ -330,3 +353,147 @@ def test_bit_parity_with_the_replaced_form(op_name, steps, quant,
         # the dispatch wrote: the pools are not what went in
         assert not np.array_equal(np.asarray(new["KPagesOut"], np.float32),
                                   np.asarray(ins["KPages"], np.float32))
+
+
+# ---------------------------------------------------------------------
+# The write-back of a dense-form dispatch (PR 28)
+# ---------------------------------------------------------------------
+
+def _pool_writes(jaxpr, pools):
+    """The update shape of every scatter or dynamic-update-slice into a
+    pool-shaped array outside any loop: ``jaxpr`` is searched through
+    its calls, not into a scan or a while."""
+    found = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name in ("scan", "while"):
+            continue
+        if (name.startswith("scatter") or name == "dynamic_update_slice") \
+                and tuple(eqn.invars[0].aval.shape) in pools:
+            update = eqn.invars[2 if name.startswith("scatter") else 1]
+            found.append(tuple(update.aval.shape))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pool_writes(sub, pools)
+    return found
+
+
+def _n_written(op_name, steps=4):
+    """Positions a row a dense-form dispatch writes."""
+    return ATTRS["gamma"] + 1 if op_name == "llama_paged_spec_step" \
+        else steps
+
+
+def _pool_inputs(ins):
+    return [k for k in ins if k.endswith("Pages") or k == "Pools"]
+
+
+def _dense_op_pools(op_name, ins):
+    """{pool shape: (entries a dispatch writes, entries of its view)}
+    of a decode or speculative op's inputs."""
+    n = _n_written(op_name)
+    out = {}
+    for x in (ins[name] for name in _pool_inputs(ins)):
+        entry = int(np.prod(x.shape[3:]))
+        out[tuple(x.shape)] = (x.shape[0] * B * n * entry,
+                               x.shape[0] * B * KMAX * entry)
+    return out
+
+
+def _write_back_sizes(op, ins, attrs, op_name):
+    """(pool writes of the size a dispatch writes, of any other size,
+    the pools there are): the sizes are element counts of the update."""
+    pools = _dense_op_pools(op_name, ins)
+    jaxpr = jax.make_jaxpr(_jit(op, attrs))(ins).jaxpr
+    written, other = [], []
+    for shape in _pool_writes(jaxpr, set(pools)):
+        size = int(np.prod(shape))
+        (written if size in {w for w, _ in pools.values()}
+         else other).append(shape)
+    return written, other, pools
+
+
+def _dense_case(op_name, **kw):
+    if op_name == "block_paged_decode":
+        kw.pop("quant", None)
+        return _latent_case(op_name, **kw)
+    return _case(op_name, **kw)
+
+
+@pytest.mark.parametrize("op_name", [
+    "llama_paged_decode", "llama_paged_spec_step", "block_paged_decode"])
+def test_write_back_holds_the_entries_written(op_name):
+    """Outside the step loop every write into a pool has an update of
+    [L, B, n, *entry], the entries the dispatch wrote: none of the dense
+    view's size, and one for each pool."""
+    op, ins, attrs = _dense_case(op_name)
+    written, other, _ = _write_back_sizes(op, ins, attrs, op_name)
+    assert not other, f"{op_name}: a pool write of another size: {other}"
+    assert len(written) == len(_pool_inputs(ins)), (op_name, written)
+
+
+def test_write_back_check_sees_the_whole_view_form(monkeypatch):
+    """The detector is not vacuous: the frozen whole-view scatter trips
+    it with an update as large as the dense view."""
+    monkeypatch.setattr(T, "_PagedRunner", _WholeViewRunner)
+    op, ins, attrs = _case("llama_paged_decode")
+    written, other, pools = _write_back_sizes(op, ins, attrs,
+                                              "llama_paged_decode")
+    assert not written
+    assert len(other) == 2          # the K pool's and the V pool's
+    assert {int(np.prod(s)) for s in other} == {
+        view for _, view in pools.values()}
+
+
+@pytest.mark.parametrize("op_name,steps,quant", [
+    ("llama_paged_decode", 1, False), ("llama_paged_decode", 4, False),
+    ("llama_paged_decode", 1, True), ("llama_paged_decode", 4, True),
+    ("llama_paged_spec_step", 1, False),
+    ("llama_paged_spec_step", 1, True),
+    ("block_paged_decode", 1, False), ("block_paged_decode", 4, False)])
+def test_write_back_parity_with_the_whole_view_scatter(op_name, steps,
+                                                       quant, monkeypatch):
+    """Tokens (and whatever else the op returns) bit for bit, and every
+    pool bit for bit on pages 1 and up. Inside the case: rows of unequal
+    length; row 0 crossing a page boundary within the dispatch; row 1
+    running onto a null entry of its table; row 2 an inactive slot on
+    the all-null table; row 3 running past KMAX, where its writes are
+    dropped and do not come back into its last page."""
+    op, ins, attrs = _dense_case(op_name, steps=steps, quant=quant,
+                                 pos=POS_END)
+    new = _jit(op, attrs)(ins)
+    monkeypatch.setattr(T, "_PagedRunner", _WholeViewRunner)
+    old = _jit(op, attrs)(ins)
+    assert sorted(new) == sorted(old)
+    for name in sorted(new):
+        a, b = np.asarray(new[name]), np.asarray(old[name])
+        assert a.dtype == b.dtype and a.shape == b.shape, name
+        if name[:-3] in _pool_inputs(ins):      # <pool>Out: pages 1 and up
+            a, b = a[:, 1:], b[:, 1:]
+        assert np.array_equal(a.view(np.uint8), b.view(np.uint8)), (
+            f"{op_name} steps={steps} quant={quant}: {name} differs")
+    n = _n_written(op_name, steps)
+    for src in _pool_inputs(ins):
+        name = src + "Out"
+        got = np.asarray(new[name]).astype(np.float32)
+        was = np.asarray(ins[src]).astype(np.float32)
+        first = POS_END - src.startswith("Draft")       # draft: pos - 1
+        # row 3's last page: the positions inside KMAX are written, the
+        # head of the page (where a clamped table lookup would put
+        # positions KMAX and up) is as it was
+        last = TABLE[3, -1]
+        head = first[3] % PS
+        assert np.array_equal(got[:, last, :head], was[:, last, :head]), name
+        if first[3] + n > KMAX:
+            assert not np.array_equal(got[:, last, head:],
+                                      was[:, last, head:]), name
+        # row 0 wrote on both sides of its page boundary when it crossed
+        if first[0] + n > 8:
+            for page, off in ((TABLE[0, 1], 3), (TABLE[0, 2], 0)):
+                assert not np.array_equal(got[:, page, off],
+                                          was[:, page, off]), name
+        # pages 1 and up that no live position of the dispatch reaches
+        # are as they went in: the inactive slot touched none of them
+        touched = {TABLE[r, p // PS] for r in (0, 1, 3)
+                   for p in range(first[r], min(first[r] + n, KMAX))}
+        for page in set(range(1, NP)) - touched:
+            assert np.array_equal(got[:, page], was[:, page]), (name, page)
