@@ -119,8 +119,7 @@ TRANSPORTS = {
         "servers": (("cluster/net_worker.py", "ReplicaServer"),),
     },
     "train": {
-        "clients": (("cluster/train_fabric.py", None),
-                    ("cluster/net_worker.py", "provision_from_remote")),
+        "clients": (("cluster/train_fabric.py", None),),
         "servers": (("cluster/train_worker.py", None),),
     },
 }

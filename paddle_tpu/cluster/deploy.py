@@ -1,19 +1,18 @@
 """Versioned deployments — canary traffic shifting, numerics-gated
-promotion, instant zero-compile rollback.
+promotion, instant rollback.
 
-The serving tier can cold-start any replica with zero XLA compiles
-from the artifact store (io/artifact_store.py) and restart replicas
-under load without losing a request (pool.rolling_restart), but those
-are mechanisms; this module is the POLICY that closes the deployment
-loop: *ship, observe, revert*.
+The serving tier can restart replicas under load without losing a
+request (pool.rolling_restart), but that is a mechanism; this module
+is the POLICY that closes the deployment loop: *ship, observe,
+revert*.
 
 A **version** is an immutable, nameable deployment unit — a
 ``save_inference_model`` directory plus everything embedded in it:
-the ``__artifacts__`` compiled-executable snapshot, the params
-manifest sha256, and the monotonically stamped ``model_version`` from
-``__meta__.json``. :class:`DeploymentManager` lets one
-:class:`~paddle_tpu.cluster.pool.ReplicaPool` serve two versions side
-by side and walks a candidate through the production gauntlet:
+the params manifest sha256 and the monotonically stamped
+``model_version`` from ``__meta__.json``. :class:`DeploymentManager`
+lets one :class:`~paddle_tpu.cluster.pool.ReplicaPool` serve two
+versions side by side and walks a candidate through the production
+gauntlet:
 
 1. **dark deploy** — k replicas are drained and converted to the
    canary's factory (the PR-7 zero-loss restart choreography, so no
@@ -32,9 +31,8 @@ by side and walks a candidate through the production gauntlet:
 4. **auto-reject + instant rollback** — any gate failure repoints the
    router weights to the incumbent (instant: the very next request
    draw cannot pick the canary) and rolls the canary replicas back to
-   the incumbent's factory; the artifact store guarantees the re-warm
-   performs ZERO compiles, and the drain-based restart guarantees
-   zero lost requests.
+   the incumbent's factory; the drain-based restart guarantees zero
+   lost requests.
 
 Chaos coverage: the ``serving_canary_regression`` fault point
 (resilience/faultinject.py) perturbs the canary's golden-set outputs
@@ -209,11 +207,11 @@ class ModelVersion:
 
     ``factory`` is the zero-arg engine factory the pool rebuilds
     replicas from; ``model_dir`` (optional but recommended) pins the
-    identity — the params-manifest sha256, the ``__artifacts__``
-    snapshot, and the export's ``model_version`` stamp are read from
-    it. ``eval_fn`` (feed-dict → list of fetch arrays) overrides the
-    default golden-set evaluation path — scriptable fakes use it to
-    unit-test the gate without real engines."""
+    identity — the params-manifest sha256 and the export's
+    ``model_version`` stamp are read from it. ``eval_fn`` (feed-dict
+    → list of fetch arrays) overrides the default golden-set
+    evaluation path — scriptable fakes use it to unit-test the gate
+    without real engines."""
 
     def __init__(self, name, factory, model_dir=None, eval_fn=None,
                  golden=None):
@@ -225,11 +223,9 @@ class ModelVersion:
         self._golden = golden
         self.params_sha = None
         self.model_version = None
-        self.has_artifacts = False
         if self.model_dir is not None:
             import json
             from ..io import PARAMS_MANIFEST
-            from ..io.artifact_store import EMBEDDED_DIRNAME
             try:
                 with open(os.path.join(self.model_dir,
                                        PARAMS_MANIFEST)) as f:
@@ -243,8 +239,6 @@ class ModelVersion:
                         "model_version")
             except (OSError, ValueError):
                 pass
-            self.has_artifacts = os.path.isdir(
-                os.path.join(self.model_dir, EMBEDDED_DIRNAME))
 
     def golden(self):
         """The recorded golden-request set ``(feeds, outputs)`` —
@@ -264,8 +258,7 @@ class ModelVersion:
     def snapshot(self):
         return {"name": self.name, "model_dir": self.model_dir,
                 "params_sha": self.params_sha,
-                "model_version": self.model_version,
-                "has_artifacts": self.has_artifacts}
+                "model_version": self.model_version}
 
     def __repr__(self):
         return (f"ModelVersion({self.name!r}, "
@@ -309,7 +302,7 @@ class DeploymentManager:
         """Name a version. Either ``factory`` (zero-arg → started
         engine) or ``model_dir`` (a ``save_inference_model`` export —
         the factory becomes ``ServingEngine.from_saved_model`` over
-        it, picking up embedded buckets + artifact store)."""
+        it, picking up the embedded buckets)."""
         if factory is None:
             if model_dir is None:
                 raise DeploymentError(
@@ -524,11 +517,9 @@ class DeploymentManager:
         FIRST (the next candidate draw cannot pick the canary — the
         data-plane rollback is one dict swap), then the canary
         replicas drain and rebuild back onto the incumbent's factory.
-        With the incumbent's artifact store embedded in its saved
-        model, the re-warm performs zero XLA compiles
-        (``rewarm_compiles`` in the report is the proof), and the
-        drain guarantees the canary's in-flight requests finish —
-        rollback loses nothing."""
+        ``rewarm_compiles`` in the report counts what the re-warm
+        compiled, and the drain guarantees the canary's in-flight
+        requests finish — rollback loses nothing."""
         incumbent = self.version(self._require_incumbent())
         t0 = time.monotonic()
         self.router.set_weights({incumbent.name: 1.0})
@@ -633,8 +624,7 @@ class DeploymentManager:
 
 
 def _sum_compiles(rewarm):
-    """Total compiles across a restart report's rewarm entries — the
-    number the zero-compile rollback guarantee pins to 0."""
+    """Total compiles across a restart report's rewarm entries."""
     total = 0
     for rep in (rewarm or {}).values():
         if isinstance(rep, dict):

@@ -8,8 +8,9 @@ magic + version + CRC32, restricted unpickling, handshake auth). A
 fresh host needs nothing but this module and a saved-model dir — and
 with the ``fetch_manifest`` / ``fetch_artifact`` verbs it does not even
 need the dir: a peer can provision itself over the wire
-(:func:`provision_from_remote`), ``__artifacts__`` blobs included, so
-the new replica warms with ZERO XLA compiles and no shared filesystem.
+(:func:`provision_from_remote`), the serving manifest included, so the
+new replica warms the exporter's buckets with no shared filesystem. An
+"artifact" on this wire is one file of the model directory.
 
 Wire verbs (after the hello/welcome handshake)::
 
@@ -44,12 +45,36 @@ import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
 
-from ..io.artifact_store import dir_manifest
 from . import net
 
 __all__ = ["ReplicaServer", "provision_from_remote"]
 
 _HANDSHAKE_TIMEOUT_S = 10.0
+
+
+def dir_manifest(root):
+    """Integrity manifest of a directory tree for wire transfer:
+    ``{relpath: {"sha256": hex, "bytes": n}}`` over every regular file
+    under ``root``. Quarantined evidence (io/aot.py) is skipped — a
+    provisioned host should start from the clean model directory, not
+    somebody's postmortem. This is the catalog the ``fetch_manifest``
+    verb serves and ``provision_from_remote`` verifies against, file
+    by file."""
+    root = os.path.abspath(root)
+    out = {}
+    for dirpath, dirnames, filenames in os.walk(root):
+        dirnames[:] = sorted(d for d in dirnames if d != "quarantine")
+        for fname in sorted(filenames):
+            full = os.path.join(dirpath, fname)
+            rel = os.path.relpath(full, root)
+            try:
+                with open(full, "rb") as f:
+                    blob = f.read()
+            except OSError:
+                continue        # racing a deletion — skip
+            out[rel] = {"sha256": net.hash_blob(blob),
+                        "bytes": len(blob)}
+    return out
 
 
 class ReplicaServer:
@@ -59,8 +84,8 @@ class ReplicaServer:
     ``token=None`` uses the shared-env default. ``engine_kw`` forwards
     ServingConfig knobs exactly like ProcessReplica does. The engine
     is built (and warmed, unless ``warmup=False``) at construction, so
-    ``.warmup_report`` answers the zero-compile question before the
-    first client connects.
+    ``.warmup_report`` says what was compiled before the first client
+    connects.
 
     ``engine=`` serves a pre-built engine instead (a DecodeEngine for
     disaggregated decode serving: submit feeds are prompt arrays, the
@@ -114,9 +139,7 @@ class ReplicaServer:
         return f"{self.host}:{self.port}"
 
     def total_compiles(self):
-        """XLA compiles this server's engine has performed — the
-        remote-provisioning gate reads 0 here when the model dir
-        carried a seeded ``__artifacts__`` store."""
+        """XLA executables this server's engine holds."""
         return self.engine.exe.total_compiles()
 
     def _incr(self, key, n=1):
@@ -358,11 +381,11 @@ class ReplicaServer:
 def provision_from_remote(addr, dest_dir, token=None, timeout=120.0):
     """Materialize a saved-model directory from a running
     :class:`ReplicaServer` — no shared filesystem: fetch the file
-    manifest, then every file (``__artifacts__`` blobs and the warmup
-    manifest included) over ``fetch_artifact``, each verified against
-    its sha256 before it touches disk. Returns a report dict; a fresh
-    ``ReplicaServer(dest_dir)`` afterwards warms the exporter's bucket
-    set with zero XLA compiles."""
+    manifest, then every file (the serving manifest included) over
+    ``fetch_artifact``, each verified against its sha256 before it
+    touches disk. Returns a report dict; a fresh
+    ``ReplicaServer(dest_dir)`` afterwards warms exactly the
+    exporter's bucket set."""
     t0 = time.monotonic()
     deadline = None if timeout is None else t0 + float(timeout)
     sock, _welcome = net.open_conn(addr, token=token,

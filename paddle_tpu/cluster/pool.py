@@ -16,7 +16,7 @@ The pool is the control plane the Router (data plane) reads:
   them elsewhere meanwhile.
 - **scaling** — ``scale_up()`` adds warmed replicas; ``scale_down()``
   drains and removes them (finish what they admitted, take nothing
-  new) — traffic-spike response once artifact warmup is fast.
+  new) — the traffic-spike response.
 - **rolling restart** — ``rolling_restart()`` is the zero-downtime
   deploy: one replica at a time is flagged ``restarting`` (the router
   stops picking it), drained via the engine's own
@@ -234,9 +234,7 @@ class ReplicaPool:
         return {"restarted": restarted,
                 "min_ready_observed": min_ready,
                 "ready_after": self.ready_count(),
-                # per-replica rewarm reports: with a compiled-artifact
-                # store behind the factory these show compiles: 0 —
-                # restart cost is loading, not XLA
+                # per-replica warmup reports of the rebuilt engines
                 "rewarm": rewarm,
                 "wall_s": round(time.monotonic() - t0, 3)}
 

@@ -164,10 +164,7 @@ class InProcessReplica(Replica):
         onto a NEW factory first — that is how a canary deploy (and
         its rollback) converts a drained replica to another model
         version in place, keeping the pool's membership stable. The
-        warmup report is stashed on ``last_rebuild_report`` — with a
-        compiled-artifact store behind the factory's engines it shows
-        ``compiles: 0``, the proof that restart cost is load-bound,
-        not compile-bound."""
+        warmup report is stashed on ``last_rebuild_report``."""
         if factory is not None:
             self._factory = factory
         self._engine = self._factory()

@@ -11,11 +11,9 @@ hosts through a step-synchronized loop, and every failure mode is a
 
 - **heartbeat-missed / straggler-deadline** → the worker is evicted
   and the step is retried at reduced world size (elastic down);
-- **rejoin / replacement** → a host cold-provisions its compiled
-  ``__artifacts__`` over the wire from any live peer (PR 11
-  ``provision_from_remote`` — zero XLA compiles), catches up from the
-  last committed state, and is folded back into the shard assignment
-  (elastic up);
+- **rejoin / replacement** → a host rebuilds the task from its wire
+  spec, catches up from the last committed state, and is folded back
+  into the shard assignment (elastic up);
 - **coordinator crash** → workers park at the barrier under a
   deadline; a new coordinator resumes from the last committed
   checkpoint serial and the run continues.
@@ -56,8 +54,8 @@ handshake)::
      "state": {...}, "sha": hex}
         -> {"type": "train_committed", "id": n, "ok": bool,
             "sha": worker_sha}
-    {"type": "stats"/"ping"/"fetch_manifest"/"fetch_artifact"/"bye"}
-        — identical to the serving fabric (provisioning included).
+    {"type": "stats"/"ping"}
+        — identical to the serving fabric.
 
 Fault points (``resilience/faultinject.py``): the worker-side step
 handler checks ``trainer_crash_at_step`` (hard death) and
@@ -186,41 +184,34 @@ class LinRegTask:
 class ProgramGradTask:
     """A real fluid train program split pserver-style: the worker runs
     forward + ``append_backward`` and fetches per-shard gradient sums
-    through the Executor (artifact store attached, so a provisioned
-    host replays the compiled step with ZERO XLA compiles); the
-    coordinator applies the SGD update in deterministic host numpy.
+    through the Executor; the coordinator applies the SGD update in
+    deterministic host numpy.
 
     The program — data → fc(tanh) → fc → square_error_cost → mean —
-    is rebuilt from the spec on every host; the PR 9 canonical
-    program hash makes the artifact keys match across processes, which
-    is what cold wire-provisioning relies on."""
+    is rebuilt from the spec on every host."""
 
     kind = "program"
 
     def __init__(self, dim=8, hidden=8, rows_per_shard=4, lr=0.05,
-                 seed=0, artifact_dir=None):
+                 seed=0):
         self.dim = int(dim)
         self.hidden = int(hidden)
         self.rows_per_shard = int(rows_per_shard)
         self.lr = float(lr)
         self.seed = int(seed)
-        self.artifact_dir = artifact_dir
         self._built = None      # lazy: the coordinator never compiles
 
     def spec(self):
-        # artifact_dir is deliberately host-local (CLI/ctor), never
-        # part of the wire spec — the math is shared, the cache is not
         return {"kind": self.kind, "dim": self.dim,
                 "hidden": self.hidden,
                 "rows_per_shard": self.rows_per_shard,
                 "lr": self.lr, "seed": self.seed}
 
     @classmethod
-    def from_spec(cls, spec, artifact_dir=None):
+    def from_spec(cls, spec):
         return cls(dim=spec.get("dim", 8), hidden=spec.get("hidden", 8),
                    rows_per_shard=spec.get("rows_per_shard", 4),
-                   lr=spec.get("lr", 0.05), seed=spec.get("seed", 0),
-                   artifact_dir=artifact_dir)
+                   lr=spec.get("lr", 0.05), seed=spec.get("seed", 0))
 
     def _build(self):
         if self._built is not None:
@@ -240,8 +231,7 @@ class ProgramGradTask:
             loss = layers.mean(layers.square_error_cost(
                 input=pred, label=y))
             params_grads = append_backward(loss)
-        exe = Executor(donate_state=False,
-                       compile_store=self.artifact_dir)
+        exe = Executor(donate_state=False)
         self._built = {
             "main": main, "loss": loss,
             "params_grads": [(p.name, g) for p, g in params_grads],
@@ -301,7 +291,7 @@ class ProgramGradTask:
 _TASK_KINDS = {"linreg": LinRegTask, "program": ProgramGradTask}
 
 
-def task_from_spec(spec, artifact_dir=None):
+def task_from_spec(spec):
     """Rebuild a task from its wire spec (the worker side of
     ``train_configure``). Raises :class:`TrainTaskError` on anything
     malformed — a typed refusal, never an import or KeyError."""
@@ -312,8 +302,6 @@ def task_from_spec(spec, artifact_dir=None):
         raise TrainTaskError(
             f"unknown task kind {spec['kind']!r}; "
             f"known: {sorted(_TASK_KINDS)}")
-    if cls is ProgramGradTask:
-        return cls.from_spec(spec, artifact_dir=artifact_dir)
     return cls.from_spec(spec)
 
 

@@ -7,13 +7,11 @@ serves gradient computation to a
 is deliberately passive and (almost) stateless: the coordinator sends
 the authoritative params with EVERY ``train_step``, so a worker that
 died and came back — or a brand-new replacement host — needs nothing
-but this entrypoint, the task spec (re-sent on ``train_configure``),
-and, for compiled tasks, an ``__artifacts__`` store it can
-cold-provision over the wire from any live peer
-(``net_worker.provision_from_remote`` — zero XLA compiles). The only
-state a worker retains is the last COMMITTED ``(step, sha)`` it
-verified, which is exactly what a parked worker needs to answer a new
-coordinator's catch-up commit after the old coordinator died.
+but this entrypoint and the task spec (re-sent on
+``train_configure``). The only state a worker retains is the last
+COMMITTED ``(step, sha)`` it verified, which is exactly what a parked
+worker needs to answer a new coordinator's catch-up commit after the
+old coordinator died.
 
 Wire verbs (after the hello/welcome handshake; see
 ``train_fabric`` for the frame schemas)::
@@ -26,10 +24,6 @@ Wire verbs (after the hello/welcome handshake; see
                       leader's sha (followers-verify half of the
                       commit barrier); remember (step, sha)
     stats/ping        ops plane + heartbeat
-    fetch_manifest /  serve this worker's artifact dir so a PEER can
-    fetch_artifact    provision itself over the wire (same
-                      path-confined, checksummed protocol as serving)
-    bye               close this connection (server stays up)
 
 Parking: a worker whose coordinator vanished simply keeps listening —
 ``stats()`` reports ``coordinator_age_s`` so operators can see the
@@ -49,7 +43,6 @@ the coordinator's straggler deadline must evict us).
 Run in-process (tests) or as a host entrypoint::
 
     python -m paddle_tpu.cluster.train_worker --port 7731 \
-        [--artifact-dir DIR] [--provision-from HOST:PORT] \
         [--park-deadline 60] [--hard-exit]
 """
 import argparse
@@ -75,18 +68,13 @@ class TrainWorkerServer:
     """Serve gradient computation over TCP for one training host.
 
     ``port=0`` picks a free port (read it back from ``.port``).
-    ``artifact_dir`` doubles as the compile cache for program tasks
-    AND the directory served to provisioning peers. ``hard_exit=True``
-    makes an injected ``trainer_crash_at_step`` call ``os._exit`` —
-    subprocess drills want the SIGKILL shape; in-process tests get an
-    abrupt socket teardown instead."""
+    ``hard_exit=True`` makes an injected ``trainer_crash_at_step``
+    call ``os._exit`` — subprocess drills want the SIGKILL shape;
+    in-process tests get an abrupt socket teardown instead."""
 
     def __init__(self, host="127.0.0.1", port=0, token=None,
-                 name=None, artifact_dir=None, hard_exit=False,
-                 backlog=16):
+                 name=None, hard_exit=False, backlog=16):
         self._token = token
-        self.artifact_dir = (os.path.abspath(artifact_dir)
-                             if artifact_dir else None)
         self.hard_exit = bool(hard_exit)
         self._task = None
         self._task_spec = None
@@ -103,8 +91,7 @@ class TrainWorkerServer:
                           "protocol_errors_total": 0,
                           "steps_total": 0,
                           "commits_total": 0,
-                          "commit_mismatches_total": 0,
-                          "artifacts_served_total": 0}
+                          "commit_mismatches_total": 0}
         self._listener = socket.socket(socket.AF_INET,
                                        socket.SOCK_STREAM)
         self._listener.setsockopt(socket.SOL_SOCKET,
@@ -123,9 +110,8 @@ class TrainWorkerServer:
         return f"{self.host}:{self.port}"
 
     def total_compiles(self):
-        """XLA compiles this worker's task has performed — 0 for pure
-        tasks and for program tasks warmed from a provisioned
-        ``__artifacts__`` store (the elastic-rejoin gate)."""
+        """XLA executables this worker's task holds — 0 for pure
+        tasks."""
         with self._task_lock:
             task = self._task
         return task.total_compiles() if task is not None else 0
@@ -187,7 +173,7 @@ class TrainWorkerServer:
                   "stats": self.stats()})
             while not self._closed.is_set():
                 msg = net.recv_frame(sock)
-                if msg is None or msg.get("type") == "bye":
+                if msg is None:
                     return
                 self._last_contact = time.monotonic()
                 self._dispatch(msg, send)
@@ -223,10 +209,6 @@ class TrainWorkerServer:
             # 'stats' because it also wants the worker's step serial
             elif kind == "ping":
                 send({"type": "pong", "id": req_id})
-            elif kind == "fetch_manifest":
-                self._handle_manifest(req_id, send)
-            elif kind == "fetch_artifact":
-                self._send_artifact(req_id, msg.get("path"), send)
             else:
                 send({"type": "error", "id": req_id,
                       "error": ("ServingError",
@@ -241,8 +223,7 @@ class TrainWorkerServer:
         spec = msg.get("task")
         with self._task_lock:
             if spec != self._task_spec:
-                self._task = task_from_spec(
-                    spec, artifact_dir=self.artifact_dir)
+                self._task = task_from_spec(spec)
                 self._task_spec = spec
             task = self._task
         send({"type": "train_configured", "id": req_id,
@@ -312,45 +293,6 @@ class TrainWorkerServer:
         send({"type": "train_committed", "id": req_id, "ok": ok,
               "sha": ours})
 
-    def _handle_manifest(self, req_id, send):
-        if self.artifact_dir is None \
-                or not os.path.isdir(self.artifact_dir):
-            send({"type": "manifest", "id": req_id, "value": {}})
-            return
-        from ..io.artifact_store import dir_manifest
-        send({"type": "manifest", "id": req_id,
-              "value": dir_manifest(self.artifact_dir)})
-
-    def _send_artifact(self, req_id, relpath, send):
-        """One file of the artifact dir, path-confined and
-        checksummed — lets a replacement worker provision its compile
-        cache from this live peer."""
-        try:
-            if self.artifact_dir is None:
-                raise ValueError(
-                    f"worker {self.name} has no artifact dir to serve")
-            if not isinstance(relpath, str) or os.path.isabs(relpath):
-                raise ValueError(f"artifact path must be relative, "
-                                 f"got {relpath!r}")
-            root = os.path.realpath(self.artifact_dir)
-            full = os.path.realpath(os.path.join(root, relpath))
-            if not (full + os.sep).startswith(root + os.sep) \
-                    and full != root:
-                raise ValueError(
-                    f"artifact path {relpath!r} escapes the "
-                    "artifact dir")
-            with open(full, "rb") as f:
-                blob = f.read()
-        except (OSError, ValueError) as exc:
-            send({"type": "error", "id": req_id,
-                  "error": net.wire_error(
-                      exc if isinstance(exc, ValueError)
-                      else ValueError(str(exc)))})
-            return
-        self._incr("artifacts_served_total")
-        send({"type": "artifact", "id": req_id, "path": relpath,
-              "blob": blob, "sha256": net.hash_blob(blob)})
-
     # -- introspection / lifecycle ---------------------------------------
     def stats(self):
         with self._task_lock:
@@ -412,13 +354,6 @@ def main(argv=None):
                     "coordinator over TCP")
     ap.add_argument("--host", default="0.0.0.0")
     ap.add_argument("--port", type=int, default=7731)
-    ap.add_argument("--artifact-dir", default=None,
-                    help="compile cache for program tasks; also "
-                         "served to provisioning peers")
-    ap.add_argument("--provision-from", default=None, metavar="ADDR",
-                    help="cold-provision --artifact-dir over the wire "
-                         "from a live peer worker before serving "
-                         "(zero XLA compiles afterwards)")
     ap.add_argument("--park-deadline", type=float, default=None,
                     metavar="S",
                     help="exit status 3 when no coordinator has "
@@ -437,18 +372,8 @@ def main(argv=None):
     # racecheck: ok(global-mutation) — ditto: entrypoint-owned process,
     # called once before the first device op
     fluid.force_cpu()
-    if args.provision_from:
-        if not args.artifact_dir:
-            ap.error("--provision-from requires --artifact-dir")
-        from .net_worker import provision_from_remote
-        report = provision_from_remote(args.provision_from,
-                                       args.artifact_dir)
-        print(f"provisioned {report['files']} files "
-              f"({report['bytes']} bytes) from {args.provision_from} "
-              f"in {report['wall_s']}s", flush=True)
     server = TrainWorkerServer(
-        host=args.host, port=args.port,
-        artifact_dir=args.artifact_dir, hard_exit=args.hard_exit)
+        host=args.host, port=args.port, hard_exit=args.hard_exit)
     print(f"train worker ready on {server.addr} "
           f"(compiles={server.total_compiles()})", flush=True)
     try:

@@ -217,25 +217,10 @@ class Executor:
     reference paddle/fluid/framework/executor.cc)."""
 
     def __init__(self, place=None, retry_policy=None,
-                 donate_state=True, compile_store=None):
+                 donate_state=True):
         self.place = place or Place()
         self._cache = {}
         self._validated = set()
-        # persistent compiled-artifact store (io/artifact_store.py):
-        # an ArtifactStore, a directory path, None (defer to
-        # PADDLE_TPU_ARTIFACT_DIR), or False (off even with the env
-        # var). When active, run() loads executables by content hash
-        # instead of compiling on a hit, and persists what it had to
-        # compile — the zero-compile cold-start path for serving
-        # replicas.
-        from ..io.artifact_store import resolve_store
-        self._store = resolve_store(compile_store)
-        self._store_fns = {}     # artifact key -> loaded executable
-        self._store_new = {}     # ("artifact", key) -> 1 per AOT compile
-        self._akey_cache = {}    # per-dispatch key memo
-        self._prog_repr = {}     # (uid, version, fetch) -> canonical repr
-        self._store_warned = False
-        self._fp = None          # library fingerprint, resolved lazily
         # PADDLE_TPU_OPTIMIZE: (program uid, fetch names) -> (source
         # version, optimized clone) — the DCE/CSE'd twin actually
         # lowered when the opt-in hook is on
@@ -327,13 +312,6 @@ class Executor:
 
         args = (state_rw, state_ro, feed_vals,
                 step_arg(first_step, program.random_seed))
-        # artifact store: a content-hash hit dispatches a loaded
-        # executable (ZERO XLA compiles — compile_counts does not
-        # grow); a miss AOT-compiles through fn (counted) and persists
-        # the executable for the next process. None → plain jit path.
-        art = (self._artifact_for(program, mode, fetch_names, repeats,
-                                  fn, args)
-               if self._store is not None else None)
 
         def _dispatch():
             # deterministic transient-fault point (resilience/
@@ -348,8 +326,6 @@ class Executor:
                 raise TransientDeviceError(
                     "injected transient device error (UNAVAILABLE)")
             with jax.default_device(self.place.device):
-                if art is not None:
-                    return art(*args)
                 return fn(*args)
 
         policy = self._retry_policy or default_policy()
@@ -376,100 +352,6 @@ class Executor:
             # data/lengths leaves while keeping the container
             fetches = jax.tree_util.tree_map(np.asarray, fetches)
         return fetches
-
-    # ------------------------------------------------------------------
-    def _fingerprint(self):
-        if self._fp is None:
-            from ..io.artifact_store import library_fingerprint
-            self._fp = library_fingerprint(self.place.device.platform)
-        return self._fp
-
-    def _artifact_for(self, program, mode, fetch_names, repeats, fn,
-                      args):
-        """Store-backed executable for this dispatch: an in-memory
-        hit, a verified disk load (zero XLA compiles), or a fresh AOT
-        compile persisted for the next process. Returns None on any
-        failure — the ordinary jit path runs, so the store can degrade
-        but never break a dispatch."""
-        try:
-            from ..io.artifact_store import arg_signature, artifact_key
-            sig = arg_signature(args)
-            ckey = (program.uid, program.version, mode,
-                    tuple(fetch_names), repeats, sig)
-            akey = self._akey_cache.get(ckey)
-            if akey is None:
-                pkey = (program.uid, program.version,
-                        tuple(sorted(fetch_names)))
-                prepr = self._prog_repr.get(pkey)
-                if prepr is None:
-                    from ..io.artifact_store import \
-                        canonical_program_repr
-                    prepr = canonical_program_repr(program, fetch_names)
-                    self._prog_repr[pkey] = prepr
-                akey = artifact_key(prepr, mode, fetch_names, repeats,
-                                    self._donate_state, sig,
-                                    self._fingerprint())
-                self._akey_cache[ckey] = akey
-            art = self._store_fns.get(akey)
-            if art is None:
-                art = self._store.load(akey)
-            if art is None:
-                art = self._compile_and_persist(fn, args, akey, mode,
-                                                fetch_names)
-            if art is not None:
-                self._store_fns[akey] = art
-                if len(self._store_fns) > 512:   # mutate-and-run bound
-                    self._store_fns.pop(next(iter(self._store_fns)))
-            return art
-        except Exception as e:        # noqa: BLE001 — degrade, never block
-            try:
-                self._store._incr("bypass_total")
-            except Exception:         # noqa: BLE001
-                pass
-            if not self._store_warned:
-                self._store_warned = True
-                warnings.warn(
-                    f"artifact store bypassed ({type(e).__name__}: "
-                    f"{e}); dispatching through the ordinary compile "
-                    "path", stacklevel=3)
-            return None
-
-    def _compile_and_persist(self, fn, args, akey, mode, fetch_names):
-        """The store-miss path: ONE ahead-of-time XLA compile of
-        exactly the executable fn would have jit-compiled (same trace,
-        same donation), counted in compile_counts under a synthetic
-        ("artifact", key) entry so warmup/no-recompile introspection
-        sees it, then persisted — compiled executable + a portable
-        jax.export module — for every later process."""
-        from ..io.artifact_store import _LoadedArtifact
-        compiled = fn.lower(*args).compile()
-        self._store_new[("artifact", akey)] = 1
-
-        def exporter():
-            from jax import export as jexport
-            specs = jax.tree_util.tree_map(
-                lambda x: jax.ShapeDtypeStruct(
-                    np.shape(x),
-                    getattr(x, "dtype", None) or np.asarray(x).dtype),
-                args)
-            return jexport.export(fn)(*specs).serialize()
-
-        self._store.save(
-            akey, compiled, self._fingerprint(), exporter=exporter,
-            meta={"mode": mode, "fetch": list(fetch_names),
-                  "donate": self._donate_state})
-        return _LoadedArtifact(compiled, "fresh", akey)
-
-    def store_stats(self):
-        """The artifact store's counter snapshot (plus how many loaded
-        executables this executor holds), or None when no store is
-        configured — surfaced by the serving engines under
-        stats()["artifact_store"]."""
-        if self._store is None:
-            return None
-        snap = self._store.stats()
-        snap["loaded_executables"] = len(self._store_fns)
-        return snap
 
     # ------------------------------------------------------------------
     def _maybe_optimize(self, program, fetch_list):
@@ -650,13 +532,7 @@ class Executor:
         executables stand behind each lowered program (jax.jit
         re-specializes per feed-shape signature, so each declared
         serving bucket contributes exactly one)."""
-        out = {k: int(fn._cache_size()) for k, fn in self._cache.items()}
-        # store-miss AOT compiles: one synthetic ("artifact", key)
-        # entry each, so warmup counts and the no-recompile pin see
-        # them. Store HITS deliberately appear nowhere — that absence
-        # is the provable zero-compile cold start.
-        out.update(self._store_new)
-        return out
+        return {k: int(fn._cache_size()) for k, fn in self._cache.items()}
 
     def total_compiles(self):
         """Total XLA executables currently cached across every lowered
@@ -677,10 +553,6 @@ class Executor:
     def close(self):
         self._cache.clear()
         self._opt_cache.clear()
-        self._store_fns.clear()
-        self._store_new.clear()
-        self._akey_cache.clear()
-        self._prog_repr.clear()
 
 
 def compiled_cost_stats(compiled, top_k=10, include_hlo=False):

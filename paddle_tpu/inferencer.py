@@ -27,7 +27,6 @@ class Inferencer:
         self.inference_program = framework.Program()
         self.feed_names = None      # fixed by from_inference_model only
         self.serving_manifest = {}  # populated by from_inference_model
-        self.artifact_dir = None    # embedded compiled-artifact store
         with framework.program_guard(self.inference_program,
                                      self.startup_program), \
                 framework.unique_name.guard():
@@ -63,14 +62,6 @@ class Inferencer:
         # serving geometry the exporter persisted (bucket manifest,
         # decode max_batch) — serve() warms exactly these buckets
         self.serving_manifest = fluid_io.load_serving_manifest(dirname)
-        # compiled-artifact store embedded at export time
-        # (save_inference_model(artifact_store=True)) — serve() hands
-        # it to every engine it builds, so replica warmup loads the
-        # exporter's executables instead of compiling them
-        import os
-        from .io.artifact_store import EMBEDDED_DIRNAME
-        embedded = os.path.join(dirname, EMBEDDED_DIRNAME)
-        self.artifact_dir = embedded if os.path.isdir(embedded) else None
         return self
 
     # the saved-model loader under the name the serving docs use; the
@@ -88,8 +79,7 @@ class Inferencer:
 
     def serve(self, buckets=None, config=None, auto_start=True,
               warmup=False, replicas=1, policy="health_aware",
-              max_cluster_queue=None, compile_store=None,
-              remotes=None, net_token=None):
+              max_cluster_queue=None, remotes=None, net_token=None):
         """Wrap this model in a :class:`~paddle_tpu.serving.ServingEngine`
         (batched concurrent inference over pre-compiled shape buckets,
         plus the hardening layer: health states, watchdog, circuit
@@ -111,14 +101,6 @@ class Inferencer:
         routing, crash revival, and ``pool.rolling_restart()`` for
         zero-downtime redeploys (docs/SERVING.md "Running a replica
         pool").
-
-        ``compile_store`` (default: the saved model's embedded
-        ``__artifacts__`` store when one was exported, else
-        ``PADDLE_TPU_ARTIFACT_DIR``) hands every engine the persistent
-        compiled-artifact store, so replica warmups — including every
-        ``rolling_restart()`` rebuild — LOAD their bucket executables
-        instead of compiling them (docs/PERFORMANCE.md "Cold starts
-        and the artifact store").
 
         ``remotes=["host:port", ...]`` routes to ALREADY-RUNNING
         :class:`~paddle_tpu.cluster.ReplicaServer` hosts instead of
@@ -142,15 +124,12 @@ class Inferencer:
         manifest = getattr(self, "serving_manifest", None) or {}
         if buckets is None and manifest.get("buckets"):
             buckets = BucketSpec.from_manifest(manifest["buckets"])
-        if compile_store is None:
-            compile_store = getattr(self, "artifact_dir", None)
 
         def factory():
             return ServingEngine(self.inference_program, feed_names,
                                  self.fetch_vars, scope=self.scope,
                                  place=self._place, buckets=buckets,
-                                 config=config, auto_start=auto_start,
-                                 compile_store=compile_store)
+                                 config=config, auto_start=auto_start)
 
         if int(replicas) > 1:
             from .cluster import serve_cluster
@@ -164,8 +143,7 @@ class Inferencer:
 
     def serve_decode(self, cfg, config=None, draft_cfg=None,
                      auto_start=True, warmup=False, replicas=1,
-                     policy="health_aware", max_cluster_queue=None,
-                     compile_store=None):
+                     policy="health_aware", max_cluster_queue=None):
         """Wrap this Inferencer's scope in a continuous-batching
         :class:`~paddle_tpu.serving.DecodeEngine` (docs/SERVING.md
         "Continuous decode batching"). The scope must hold the
@@ -177,18 +155,14 @@ class Inferencer:
         no-recompile contract already armed. ``replicas=N`` returns a
         balanced cluster Router over N decode engines sharing this
         scope, exactly as :meth:`serve` does for the bucketed
-        engine. ``compile_store`` hands every engine the persistent
-        compiled-artifact store (default PADDLE_TPU_ARTIFACT_DIR) so a
-        rebuilt or scaled-up replica loads its step executables
-        instead of compiling them."""
+        engine."""
         from .serving import DecodeEngine
 
         def factory():
             return DecodeEngine(cfg, scope=self.scope,
                                 place=self._place, config=config,
                                 draft_cfg=draft_cfg,
-                                auto_start=auto_start,
-                                compile_store=compile_store)
+                                auto_start=auto_start)
 
         if int(replicas) > 1:
             from .cluster import serve_cluster

@@ -23,11 +23,9 @@ __all__ = ["save_vars", "save_params", "save_persistables", "load_vars",
            "save_checkpoint", "load_checkpoint",
            "get_inference_program", "CompiledPredictor",
            "load_compiled_predictor", "is_parameter", "is_persistable",
-           "get_parameter_value", "get_parameter_value_by_name",
-           "ArtifactStore"]
+           "get_parameter_value", "get_parameter_value_by_name"]
 
 from .aot import CompiledPredictor, load_compiled_predictor  # noqa: F401,E402
-from .artifact_store import ArtifactStore  # noqa: F401,E402
 
 
 def is_parameter(var):
@@ -206,7 +204,7 @@ def save_inference_model(dirname, feeded_var_names, target_vars, executor,
                          main_program=None, model_filename=None,
                          params_filename=None, export_for_deployment=True,
                          serving_buckets=None, decode_max_batch=None,
-                         artifact_store=None, model_version=None):
+                         model_version=None):
     """Prunes the program to the inference slice and saves graph + params
     (reference python/paddle/fluid/io.py save_inference_model).
 
@@ -217,19 +215,6 @@ def save_inference_model(dirname, feeded_var_names, target_vars, executor,
     exactly the exporter's bucket signatures instead of guessing —
     the fast-scale-out half of the replica-pool story
     (docs/SERVING.md "Running a replica pool").
-
-    ``artifact_store`` pre-seeds a persistent compiled-artifact store
-    with the executables for the exporter's bucket set, so those
-    buckets ship WITH their compiled code and a fresh replica's
-    ``warmup()`` performs zero XLA compiles (io/artifact_store.py;
-    docs/PERFORMANCE.md "Cold starts and the artifact store"):
-    ``True`` embeds the store in the saved-model dir itself
-    (``__artifacts__/`` — the dir alone provisions a new replica
-    host), or pass a path / ``ArtifactStore`` for a shared store.
-    Seeding replays exactly the ``from_saved_model`` + ``warmup()``
-    path a replica takes, so the stored keys match by construction; a
-    seeding failure degrades to a normal (compile-at-warmup) artifact
-    with a warning, never a failed save.
 
     Every export is stamped with a monotonically increasing
     ``model_version`` in ``__meta__.json`` (auto-bumped from any
@@ -303,38 +288,7 @@ def save_inference_model(dirname, feeded_var_names, target_vars, executor,
             warnings.warn(
                 f"AOT export skipped ({type(e).__name__}: {e}); the "
                 "saved model still loads via load_inference_model")
-    if artifact_store:
-        try:
-            _seed_artifact_store(dirname, artifact_store)
-        except Exception as e:                    # noqa: BLE001
-            import warnings
-            warnings.warn(
-                f"artifact-store seeding skipped ({type(e).__name__}: "
-                f"{e}); replicas will compile at warmup instead of "
-                "loading")
     return inference_program
-
-
-def _seed_artifact_store(dirname, artifact_store):
-    """Warm the compiled-artifact store with the exporter's bucket set
-    by replaying the exact load path a replica takes —
-    ``ServingEngine.from_saved_model`` + ``warmup()`` — so the
-    persisted keys match a future replica's lookups by construction
-    (same pruned program, same optimize pipeline, same buckets)."""
-    from ..serving.engine import ServingEngine
-    from .artifact_store import EMBEDDED_DIRNAME, resolve_store
-    if artifact_store is True:
-        store = resolve_store(os.path.join(dirname, EMBEDDED_DIRNAME))
-    else:
-        store = resolve_store(artifact_store)
-    eng = ServingEngine.from_saved_model(
-        dirname, compile_store=store, auto_start=False)
-    try:
-        report = eng.warmup()
-        report["store"] = eng.exe.store_stats()
-        return report
-    finally:
-        eng.close()
 
 
 def load_serving_manifest(dirname):

@@ -342,8 +342,7 @@ class DecodeEngine:
     device whatever ``place`` says."""
 
     def __init__(self, cfg, scope=None, place=None, config=None,
-                 draft_cfg=None, auto_start=True, optimize=True,
-                 compile_store=None):
+                 draft_cfg=None, auto_start=True, optimize=True):
         self.cfg = cfg
         self.draft_cfg = draft_cfg
         self.config = config or DecodeConfig()
@@ -412,15 +411,9 @@ class DecodeEngine:
         # all retries surface at the serving layer (counted); the inner
         # executor must not also retry. donate_state=False: pool
         # replicas share one weight scope (see ServingEngine).
-        # compile_store: persistent compiled-artifact store — a second
-        # decode replica (or a rolling-restart rebuild) loads every
-        # step executable the first one compiled instead of paying XLA
-        # again (io/artifact_store.py; None defers to
-        # PADDLE_TPU_ARTIFACT_DIR)
         self.exe = Executor(place,
                             retry_policy=RetryPolicy(max_attempts=1),
-                            donate_state=False,
-                            compile_store=compile_store)
+                            donate_state=False)
         self.metrics = ServingMetrics(extra_counters=_DECODE_COUNTERS)
         self.health = HealthMonitor()
         self.breaker = CircuitBreaker(
@@ -830,7 +823,6 @@ class DecodeEngine:
         snap["brownout"] = (None if self.brownout is None
                             else self.brownout.snapshot())
         snap["optimize"] = self.optimize_reports or None
-        snap["artifact_store"] = self.exe.store_stats()
         return snap
 
     # -- internal: program rewrites --------------------------------------

@@ -134,7 +134,7 @@ class ServingEngine:
 
     def __init__(self, program, feed_names, fetch_list, scope=None,
                  place=None, buckets=None, config=None, auto_start=True,
-                 optimize=True, compile_store=None, model_version=None):
+                 optimize=True, model_version=None):
         self.feed_names = list(feed_names)
         self.fetch_list = list(fetch_list)
         # deployment identity from the export's __meta__.json (None
@@ -172,16 +172,9 @@ class ServingEngine:
         # donate_state=False: replicas of a cluster pool share one
         # read-only parameter scope — a donated (hence deleted) state
         # buffer in one replica would be a dangling buffer in the rest
-        # compile_store: persistent compiled-artifact store
-        # (io/artifact_store.py) — warmup() then LOADS this engine's
-        # bucket executables instead of compiling them when a peer
-        # process (or an export-time seeding pass) already persisted
-        # them: the zero-compile cold start. None defers to
-        # PADDLE_TPU_ARTIFACT_DIR; False disables outright.
         self.exe = Executor(place,
                             retry_policy=RetryPolicy(max_attempts=1),
-                            donate_state=False,
-                            compile_store=compile_store)
+                            donate_state=False)
         self.metrics = ServingMetrics()
         self.batcher = MicroBatcher(
             max_batch_size=self.buckets.max_batch,
@@ -216,16 +209,8 @@ class ServingEngine:
         serving_buckets=...)``) and the caller passes no ``buckets``,
         the exported BucketSpec is used — ``warmup()`` then
         pre-compiles exactly the bucket signatures the exporter saw,
-        instead of guessing (the replica scale-out path).
-
-        When the artifact carries an embedded compiled-artifact store
-        (``save_inference_model(..., artifact_store=True)`` writes
-        ``__artifacts__/`` beside the params) and the caller passes no
-        ``compile_store``, that store is used — warmup() then performs
-        ZERO XLA compiles: the saved-model dir alone carries
-        everything a fresh replica host needs."""
+        instead of guessing (the replica scale-out path)."""
         from .. import io as fluid_io
-        from ..io.artifact_store import EMBEDDED_DIRNAME
         scope = Scope()
         exe = Executor(place)
         # the target scope is passed explicitly — a guard swap of the
@@ -239,10 +224,6 @@ class ServingEngine:
             if manifest.get("buckets"):
                 kw["buckets"] = BucketSpec.from_manifest(
                     manifest["buckets"])
-        if kw.get("compile_store") is None:
-            embedded = os.path.join(dirname, EMBEDDED_DIRNAME)
-            if os.path.isdir(embedded):
-                kw["compile_store"] = embedded
         if kw.get("model_version") is None:
             try:
                 with open(os.path.join(dirname, "__meta__.json")) as f:
@@ -491,7 +472,6 @@ class ServingEngine:
                             if self.optimize_report is not None
                             else None)
         snap["breaker"] = self.breaker.snapshot()
-        snap["artifact_store"] = self.exe.store_stats()
         open_sigs = {str(sig): br.snapshot()
                      for sig, br in self._sig_breakers.items()
                      if br.state != CircuitBreaker.CLOSED}

@@ -292,3 +292,52 @@ def test_aot_exports_llama_generator(tmp_path):
         got = np.asarray(pred.run({"ptok": prompt})[0])
         np.testing.assert_array_equal(got, want)
         assert got.shape == (2, prompt_len + new)
+
+
+# ---------------------------------------------------------------------
+# params.npz sha256 manifest (CompiledPredictor verification)
+# ---------------------------------------------------------------------
+
+def _export_model(tmp_path):
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[8], dtype="float32")
+        y = fluid.layers.fc(input=x, size=4, act="softmax")
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(startup)
+    model_dir = str(tmp_path / "model")
+    fluid.io.save_inference_model(model_dir, ["x"], [y], exe,
+                                  main_program=main)
+    return model_dir
+
+
+def test_compiled_predictor_verifies_params_manifest(tmp_path):
+    from paddle_tpu.io import PARAMS_MANIFEST
+    model_dir = _export_model(tmp_path)
+    assert os.path.exists(os.path.join(model_dir, PARAMS_MANIFEST))
+    pred = fluid.io.load_compiled_predictor(model_dir)   # clean: loads
+    out = pred.run({"x": np.zeros((2, 8), np.float32)})
+    assert out[0].shape == (2, 4)
+
+
+def test_compiled_predictor_quarantines_corrupt_params(tmp_path):
+    from paddle_tpu.resilience.checkpoint import ChecksumMismatch
+    model_dir = _export_model(tmp_path)
+    ppath = os.path.join(model_dir, "params.npz")
+    with open(ppath, "r+b") as f:
+        f.seek(30)
+        f.write(b"\x00" * 16)                  # torn copy / bit rot
+    with pytest.raises(ChecksumMismatch, match="sha256 mismatch"):
+        fluid.io.load_compiled_predictor(model_dir)
+    assert not os.path.exists(ppath)           # moved, not deleted
+    qdir = os.path.join(model_dir, "quarantine")
+    assert os.path.isdir(qdir) and os.listdir(qdir)
+
+
+def test_compiled_predictor_legacy_artifact_loads_unchecked(tmp_path):
+    from paddle_tpu.io import PARAMS_MANIFEST
+    model_dir = _export_model(tmp_path)
+    os.remove(os.path.join(model_dir, PARAMS_MANIFEST))  # old export
+    pred = fluid.io.load_compiled_predictor(model_dir)
+    assert pred.run({"x": np.zeros((1, 8), np.float32)})[0].shape == \
+        (1, 4)

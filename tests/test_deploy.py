@@ -583,7 +583,6 @@ def test_model_version_surfaces_in_engine_stats(tmp_path):
     mv = ModelVersion("v42", factory=lambda: None, model_dir=model_dir)
     assert mv.model_version == 42
     assert mv.params_sha
-    assert not mv.has_artifacts          # no store in this export
     assert mv.snapshot()["model_version"] == 42
 
 

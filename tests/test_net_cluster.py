@@ -19,10 +19,10 @@ What is pinned here:
   reader loop fails everything pending however it dies (the
   ProcessReplica audit, regression-tested on both transports);
 * **loopback end-to-end** — a ReplicaServer serving a saved-model dir
-  answers bit-exact with a lone engine, cold-starts with ZERO XLA
-  compiles from an artifact-seeded dir, and provisions a fresh host
-  over nothing but the socket (``fetch_manifest``/``fetch_artifact``,
-  sha256-verified);
+  answers bit-exact with a lone engine, warms exactly the exporter's
+  bucket signatures from the serving manifest, and provisions a fresh
+  host over nothing but the socket (``fetch_manifest``/
+  ``fetch_artifact``, sha256-verified);
 * **partition tolerance** — a partitioned remote degrades to excluded
   (typed errors only, zero lost requests) and rejoins within one
   membership refresh of the partition healing.
@@ -49,6 +49,7 @@ from paddle_tpu.cluster import (FrameError, HandshakeError, Membership,
                                 ReplicaServer, Router,
                                 provision_from_remote, serve_remotes)
 from paddle_tpu.cluster import net
+from paddle_tpu.cluster.net_worker import dir_manifest
 from paddle_tpu.cluster.replica import ProcessReplica
 from paddle_tpu.resilience import faultinject
 from paddle_tpu.serving import (BucketSpec, QueueFullError,
@@ -568,8 +569,8 @@ def test_membership_refresh_thread_runs():
 
 @pytest.fixture(scope="module")
 def saved_model(tmp_path_factory):
-    """A tiny exported classifier with serving buckets AND a seeded
-    embedded artifact store, plus a lone-engine reference output."""
+    """A tiny exported classifier with serving buckets, plus a
+    lone-engine reference output."""
     fluid.force_cpu()
     tmp = tmp_path_factory.mktemp("netmodel")
     main, startup = fluid.Program(), fluid.Program()
@@ -585,8 +586,7 @@ def saved_model(tmp_path_factory):
         exe.run(startup)
         fluid.io.save_inference_model(
             model_dir, ["x"], [pred], exe, main_program=infer,
-            serving_buckets=BucketSpec(batch_sizes=(1, 2)),
-            artifact_store=True)
+            serving_buckets=BucketSpec(batch_sizes=(1, 2)))
     eng = ServingEngine.from_saved_model(model_dir,
                                          place=fluid.CPUPlace())
     feed = {"x": np.arange(8, dtype=np.float32).reshape(1, 8)}
@@ -604,13 +604,13 @@ def loopback_server(saved_model):
     server.close()
 
 
-def test_server_cold_starts_with_zero_compiles(loopback_server):
-    """Acceptance pin: a fresh ReplicaServer provisioned from only a
-    saved-model dir warms the exporter's bucket set with zero XLA
-    compiles."""
-    assert loopback_server.total_compiles() == 0
-    assert loopback_server.warmup_report["compiles"] == 0
-    assert loopback_server.warmup_report["signatures"] == 2
+def test_server_warms_the_exporters_buckets(loopback_server):
+    """Acceptance pin: a fresh ReplicaServer built from only a
+    saved-model dir warms exactly the exporter's 2 bucket signatures,
+    read from the serving manifest: one executable each."""
+    assert loopback_server.engine.buckets.batch_sizes == (1, 2)
+    assert loopback_server.warmup_report == {"signatures": 2,
+                                             "compiles": 2}
 
 
 def test_loopback_bit_exact_vs_lone_engine(saved_model,
@@ -728,15 +728,15 @@ def test_provision_from_remote_over_the_wire(saved_model,
                                              loopback_server,
                                              tmp_path):
     """No shared filesystem: a fresh host materializes the model dir
-    (artifacts included) over fetch_manifest/fetch_artifact, then
-    cold-starts with zero XLA compiles, bit-exact."""
+    over fetch_manifest/fetch_artifact, every file matching the
+    server's manifest sha256, then serves bit-exact."""
     dest = str(tmp_path / "provisioned")
     report = provision_from_remote(loopback_server.addr, dest)
-    assert report["files"] >= 3 and report["bytes"] > 0
-    assert os.path.isdir(os.path.join(dest, "__artifacts__"))
+    manifest = dir_manifest(saved_model["dir"])
+    assert report["files"] == len(manifest) >= 3 and report["bytes"] > 0
+    assert dir_manifest(dest) == manifest      # every file, by sha256
     fresh = ReplicaServer(dest, name="provisioned")
     try:
-        assert fresh.total_compiles() == 0
         rep = RemoteReplica(fresh.addr)
         try:
             out = rep.submit(saved_model["feed"],

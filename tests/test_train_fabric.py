@@ -21,9 +21,9 @@ What is pinned here:
   (a BaseException — recovery code cannot swallow it), workers park,
   and a NEW coordinator over the same checkpoint dir resumes from the
   last committed serial to sha parity with an uninterrupted run;
-* **the compiled tier provisions over the wire** — a ProgramGradTask
-  replacement worker fetches a live peer's ``__artifacts__`` and joins
-  with ZERO XLA compiles (the elastic-up gate);
+* **the compiled tier scales up mid-run** — a ProgramGradTask
+  replacement worker rebuilds the program from the wire spec and joins
+  without moving the loss curve (the elastic-up gate);
 * **ops plane** — per-worker rows (last_step, step-time percentiles,
   heartbeat age, evictions/rejoins) and ServingMetrics.merge(label=)
   namespacing so per-worker counters never collide.
@@ -102,10 +102,8 @@ def test_task_spec_roundtrip_and_typed_refusals():
     clone = task_from_spec(task.spec())
     assert isinstance(clone, LinRegTask)
     assert clone.spec() == task.spec()
-    prog = task_from_spec(ProgramGradTask(seed=2).spec(),
-                          artifact_dir="/tmp/nowhere")
+    prog = task_from_spec(ProgramGradTask(seed=2).spec())
     assert isinstance(prog, ProgramGradTask)
-    assert prog.artifact_dir == "/tmp/nowhere"   # host-local, not wire
     with pytest.raises(TrainTaskError):
         task_from_spec({"no": "kind"})
     with pytest.raises(TrainTaskError):
@@ -336,7 +334,7 @@ def test_membership_heartbeat_counts_eviction_and_rejoin(tmp_path):
 
 
 def test_worker_server_stats_surface(tmp_path):
-    w = TrainWorkerServer(artifact_dir=str(tmp_path / "af"))
+    w = TrainWorkerServer()
     client = WorkerClient(w.addr)
     client.configure(LinRegTask(seed=1).spec())
     reply = client.rpc({"type": "stats"})
@@ -353,27 +351,33 @@ def test_worker_server_stats_surface(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# compiled tier: provisioning gate
+# compiled tier: elastic-up gate
 # ---------------------------------------------------------------------------
 
 
-def test_program_task_replacement_provisions_zero_compiles(tmp_path):
+def test_program_task_replacement_joins_mid_run(tmp_path):
     """The elastic-up gate for the compiled tier: a replacement worker
-    wire-provisions a live peer's ``__artifacts__`` and serves real
-    program gradients with total_compiles() == 0."""
-    from paddle_tpu.cluster.net_worker import provision_from_remote
-    wa = TrainWorkerServer(artifact_dir=str(tmp_path / "a"))
+    admitted mid-run rebuilds the program from the wire spec, serves
+    real program gradients, and leaves the coordinator's losses and
+    commits where an uninterrupted single-worker run puts them."""
+    wr = TrainWorkerServer()
+    ref = TrainCoordinator(ProgramGradTask(seed=1), [wr.addr],
+                           str(tmp_path / "ref"),
+                           commit_interval=3, n_shards=2)
+    ref.run(6)
+    ref_losses, ref_commits = ref.losses(), ref.commits()
+    _teardown(ref, [wr])
+
+    wa = TrainWorkerServer()
     co = TrainCoordinator(ProgramGradTask(seed=1), [wa.addr],
                           str(tmp_path / "ckpts"),
                           commit_interval=3, n_shards=2)
     co.run(3)
-    assert wa.total_compiles() >= 1     # the peer paid the compile
-    report = provision_from_remote(wa.addr, str(tmp_path / "c"))
-    assert report["files"] >= 1
-    wc = TrainWorkerServer(artifact_dir=str(tmp_path / "c"))
+    wc = TrainWorkerServer()
     co.admit(wc.addr)
     co.run(3)
     assert wc.last_step == 6
-    assert wc.total_compiles() == 0, \
-        "provisioned replacement recompiled — artifact store missed"
+    assert wc.total_compiles() >= 1     # it ran the program itself
+    assert co.losses() == ref_losses
+    assert co.commits() == ref_commits
     _teardown(co, [wa, wc])

@@ -225,109 +225,15 @@ else
 fi
 echo "selfcheck: replica-pool router smoke passed"
 
-# ---- stage 8: compiled-artifact store (zero-compile cold start) ------
-# The persistent artifact store's gate (docs/PERFORMANCE.md "Cold
-# starts and the artifact store"): export a model with an embedded
-# seeded store, then a FRESH subprocess builds a serving engine from
-# nothing but the saved-model dir — total_compiles() must stay ZERO
-# through warmup of the exporter's full bucket set and outputs must be
-# bit-exact vs the seeding process's reference. servebench --cold-start
-# additionally records the storeless-vs-warm warmup speedup (>=2x
-# gate; typically >10x on this box).
-rm -rf "$OUT/coldstart"
-if python - "$OUT/coldstart" > "$OUT/coldstart_seed.log" 2>&1 <<'EOF8A'
-import sys, os
-import numpy as np
-import paddle_tpu as fluid
-from paddle_tpu.models import zoo
-from paddle_tpu import serving
-
-fluid.force_cpu()
-model_dir = os.path.join(sys.argv[1], "model")
-zp = zoo.build_zoo_program("mnist_mlp")
-scope = fluid.Scope()
-exe = fluid.Executor(fluid.CPUPlace())
-with fluid.scope_guard(scope):
-    exe.run(zp.startup)
-    fluid.io.save_inference_model(
-        model_dir, zp.feed_names, zp.fetch_list, exe,
-        main_program=zp.main,
-        serving_buckets=serving.BucketSpec(batch_sizes=(1, 2, 4)),
-        artifact_store=True)
-eng = serving.ServingEngine.from_saved_model(
-    model_dir, compile_store=False, auto_start=False)
-rng = np.random.RandomState(0)
-feed = {"img": rng.randn(2, 784).astype(np.float32),
-        "label": np.zeros((2, 1), np.int64)}
-from paddle_tpu.core.executor import scope_guard
-with scope_guard(eng.scope):
-    out = eng.exe.run(eng.program, feed=feed,
-                      fetch_list=eng.fetch_list, mode="test")
-np.save(os.path.join(sys.argv[1], "ref.npy"), np.asarray(out[0]))
-eng.close()
-print("seeded:", sorted(os.listdir(os.path.join(model_dir,
-                                                "__artifacts__"))))
-EOF8A
-then
-    echo "ok   artifact-store export+seed ($(tail -1 "$OUT/coldstart_seed.log"))"
-else
-    echo "FAIL artifact-store export+seed — see $OUT/coldstart_seed.log" >&2
-    exit 1
-fi
-if python - "$OUT/coldstart" > "$OUT/coldstart_load.log" 2>&1 <<'EOF8B'
-import sys, os
-import numpy as np
-import paddle_tpu as fluid
-from paddle_tpu import serving
-
-fluid.force_cpu()
-model_dir = os.path.join(sys.argv[1], "model")
-eng = serving.ServingEngine.from_saved_model(model_dir, auto_start=False)
-warm = eng.warmup()
-assert eng.exe.total_compiles() == 0, \
-    f"fresh replica compiled: {eng.exe.compile_counts()}"
-st = eng.exe.store_stats()
-assert st["misses_total"] == 0 and st["hits_total"] > 0, st
-rng = np.random.RandomState(0)
-feed = {"img": rng.randn(2, 784).astype(np.float32),
-        "label": np.zeros((2, 1), np.int64)}
-from paddle_tpu.core.executor import scope_guard
-with scope_guard(eng.scope):
-    out = eng.exe.run(eng.program, feed=feed,
-                      fetch_list=eng.fetch_list, mode="test")
-ref = np.load(os.path.join(sys.argv[1], "ref.npy"))
-assert np.array_equal(ref, np.asarray(out[0])), \
-    "store-loaded outputs diverged from the exporter's reference"
-eng.close()
-print(f"zero compiles across {warm['signatures']} bucket signatures, "
-      f"{st['hits_total']} store hits, bit-exact")
-EOF8B
-then
-    echo "ok   artifact-store fresh-process load ($(tail -1 "$OUT/coldstart_load.log"))"
-else
-    echo "FAIL artifact-store fresh-process load — see $OUT/coldstart_load.log" >&2
-    exit 1
-fi
-if python tools/servebench.py --cold-start --model mnist_mlp \
-        --assert-speedup 2.0 --out "$OUT/servebench_coldstart.json" \
-        > "$OUT/servebench_coldstart.log" 2>&1; then
-    echo "ok   servebench --cold-start ($(tail -1 "$OUT/servebench_coldstart.log"))"
-else
-    echo "FAIL servebench --cold-start — see $OUT/servebench_coldstart.log /" \
-         "servebench_coldstart.json" >&2
-    exit 1
-fi
-echo "selfcheck: artifact-store cold-start gate passed"
+# (stage 8, the compiled-artifact store's gate, went with the store.)
 
 # ---- stage 9: cross-host serving fabric (sockets + partitions) -------
 # The network fabric's gate (docs/DISTRIBUTED.md "Serving across
 # hosts"): servebench --remote 2 stands up loopback ReplicaServers
-# from one exported dir and exits 1 unless (a) a fresh server
-# provisioned from the saved-model dir warms with ZERO XLA compiles,
-# (b) a second server provisioned purely OVER THE SOCKET
-# (fetch_manifest/fetch_artifact, sha256-verified) also warms with
-# zero compiles, and (c) the socket pool serves every request within
-# float tolerance of a local engine. Then the partition chaos drill:
+# from one exported dir — one of them provisioned purely OVER THE
+# SOCKET (fetch_manifest/fetch_artifact, sha256-verified) — and exits
+# 1 unless the socket pool serves every request within float
+# tolerance of a local engine. Then the partition chaos drill:
 # net_partition + net_frame_drop armed mid-load must lose ZERO
 # requests (typed errors only), open and re-close the per-connection
 # breakers, and rejoin the partitioned replicas within one membership
@@ -356,14 +262,12 @@ echo "selfcheck: cross-host serving fabric gate passed"
 
 # ---- stage 10: versioned-deployment canary drill ---------------------
 # The deployment loop's gate (docs/SERVING.md "Deploying a new
-# version"): servebench --canary exports two artifact-store versions,
-# dark-deploys v2 behind router weights, proves the golden-set
-# numerics gate ACCEPTS a faithful canary (zero re-warm compiles),
-# then arms serving_canary_regression and exits 1 unless the staged
-# promotion auto-REJECTS on the in-flight numerics resample and rolls
-# back to v1 with zero lost requests, zero typed errors, and ZERO
-# compiles on the restarted replicas (rollback rides the embedded
-# artifact store). Records serving_rollback_s.
+# version"): servebench --canary exports two versions, dark-deploys
+# v2 behind router weights, proves the golden-set numerics gate
+# ACCEPTS a faithful canary, then arms serving_canary_regression and
+# exits 1 unless the staged promotion auto-REJECTS on the in-flight
+# numerics resample and rolls back to v1 with zero lost requests and
+# zero typed errors. Records serving_rollback_s.
 if python tools/servebench.py --canary --requests 48 \
         --concurrency 8 --out "$OUT/servebench_canary.json" \
         > "$OUT/servebench_canary.log" 2>&1; then
@@ -453,9 +357,8 @@ echo "selfcheck: static numerics gate passed"
 # The training fabric's gate (docs/DISTRIBUTED.md "Training across
 # hosts"): trainbench --chaos runs REAL subprocess workers and fires
 # all four trainer fault points against one run — a hard worker crash
-# (os._exit mid-step) with an elastic replacement that cold-provisions
-# its artifacts over the wire (--task program: total_compiles must be
-# ZERO), a straggler evicted typed at the deadline and rejoined after
+# (os._exit mid-step) with an elastic replacement folded back in, a
+# straggler evicted typed at the deadline and rejoined after
 # healing, a two-call net partition, and a coordinator crash resumed
 # by a NEW coordinator from the last committed serial. PASS requires
 # the chaos run's committed (serial, sha) sequence to EQUAL the

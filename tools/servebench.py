@@ -1356,10 +1356,10 @@ def cluster_main(args):
 def canary_main(args):
     """--canary: the versioned-deployment drill (selfcheck stage 10).
 
-    Exports the bench model twice (v1/v2, identical weights, embedded
-    artifact stores, monotone model_version stamps), serves v1 from a
-    replica pool under sustained client load, records a golden set,
-    then walks the full deployment gauntlet:
+    Exports the bench model twice (v1/v2, identical weights, monotone
+    model_version stamps), serves v1 from a replica pool under
+    sustained client load, records a golden set, then walks the full
+    deployment gauntlet:
 
     1. dark-deploy v2 as a canary (zero traffic) — the clean
        pre-traffic numerics gate must PASS (the weights are
@@ -1370,12 +1370,11 @@ def canary_main(args):
        stage's in-flight numerics re-sample must AUTO-REJECT and roll
        back;
     4. assert the rollback contract: zero lost requests across the
-       whole drill, zero XLA compiles on the re-warmed incumbent
-       replicas, weights instantly repointed, post-rollback traffic
+       whole drill, weights instantly repointed, post-rollback traffic
        all-success.
 
     BENCH record: ``serving_rollback_s`` — weight repoint + canary
-    drain + zero-compile rebuild, wall-clock."""
+    drain + rebuild, wall-clock."""
     import shutil
     import tempfile
     import threading
@@ -1402,7 +1401,7 @@ def canary_main(args):
                 fluid.io.save_inference_model(
                     dirname, zp.feed_names, fetch_names, exe,
                     main_program=infer, serving_buckets=buckets,
-                    artifact_store=True, model_version=mv)
+                    model_version=mv)
 
         replicas = max(2, args.cluster or 2)
         router = cluster.serve_cluster(
@@ -1416,9 +1415,6 @@ def canary_main(args):
             failures.append(
                 f"model_version stamps wrong: v1={v1.model_version} "
                 f"v2={v2.model_version} (expected 1, 2)")
-        if not (v1.has_artifacts and v2.has_artifacts):
-            failures.append("exports are missing their embedded "
-                            "artifact stores")
         mgr.set_incumbent("v1")
         mgr.record_golden(feeds[:8])
 
@@ -1455,11 +1451,6 @@ def canary_main(args):
             failures.append(
                 "clean canary (identical weights) was rejected: "
                 f"{deploy.get('numerics', {}).get('worst')}")
-        if deploy.get("rewarm_compiles"):
-            failures.append(
-                f"canary conversion compiled "
-                f"{deploy['rewarm_compiles']} executables — the v2 "
-                "artifact store should make it zero")
 
         # ---- 2. per-version metrics separation at 50/50 ------------
         status_mid = None
@@ -1502,11 +1493,6 @@ def canary_main(args):
         stop.set()
         for t in clients:
             t.join(30.0)
-        if rollback.get("rewarm_compiles"):
-            failures.append(
-                f"rollback re-warm compiled "
-                f"{rollback['rewarm_compiles']} executables — the "
-                "incumbent artifact store must make it ZERO")
         weights = router.weights()
         if weights != {"v1": 1.0}:
             failures.append(
@@ -1520,11 +1506,6 @@ def canary_main(args):
         for name in rollback.get("replicas", []):
             for r in router.pool.replicas():
                 if r.name == name and hasattr(r, "engine"):
-                    n = r.engine.exe.total_compiles()
-                    if n:
-                        failures.append(
-                            f"re-warmed incumbent {name} shows "
-                            f"{n} compiles (expected 0)")
                     if r.engine.model_version != 1:
                         failures.append(
                             f"re-warmed incumbent {name} serves "
@@ -1597,8 +1578,8 @@ def canary_main(args):
 
 
 def _export_remote_model(args, workdir):
-    """Export the bench model with serving buckets + a seeded embedded
-    artifact store — the dir a remote host provisions from."""
+    """Export the bench model with serving buckets — the dir a remote
+    host provisions from."""
     zp, infer, fetch, per_row, scope, feeds = _setup(args)
     model_dir = os.path.join(workdir, "model")
     exe = fluid.Executor(fluid.CPUPlace())
@@ -1611,8 +1592,7 @@ def _export_remote_model(args, workdir):
             else [v.name for v in fetch],
             exe, main_program=infer,
             serving_buckets=serving.BucketSpec(
-                batch_sizes=_bucket_sizes(args.max_batch)),
-            artifact_store=True)
+                batch_sizes=_bucket_sizes(args.max_batch)))
     return model_dir, feeds, per_row
 
 
@@ -1620,10 +1600,10 @@ def remote_main(args):
     """--remote N: the cross-host serving fabric on loopback sockets —
     N ReplicaServers provisioned from one exported dir, a
     socket-backed pool behind the stock Router, closed-loop QPS
-    (``serving_remote_qps``), plus the cold-provision gate: a fresh
+    (``serving_remote_qps``), plus the provisioning gate: a fresh
     server stood up from the saved-model dir (and another provisioned
-    purely OVER THE WIRE) must warm with ZERO XLA compiles and answer
-    bit-exact (docs/DISTRIBUTED.md "Serving across hosts")."""
+    purely OVER THE WIRE) must answer within float tolerance of a
+    lone local engine (docs/DISTRIBUTED.md "Serving across hosts")."""
     import os as _os
     import shutil
     import tempfile
@@ -1652,11 +1632,6 @@ def remote_main(args):
         first = cluster.ReplicaServer(model_dir, name="remote-0")
         cold_provision_s = time.perf_counter() - t0
         servers.append(first)
-        if first.total_compiles() != 0:
-            failures.append(
-                f"cold-provisioned server compiled "
-                f"{first.total_compiles()} executables — expected "
-                "ZERO (artifact store miss)")
 
         # ---- wire provision: socket -> fresh dir -> serving socket --
         wire_dir = _os.path.join(workdir, "wire_provisioned")
@@ -1666,10 +1641,6 @@ def remote_main(args):
         wire = cluster.ReplicaServer(wire_dir, name="remote-1")
         wire_provision_s = time.perf_counter() - t0
         servers.append(wire)
-        if wire.total_compiles() != 0:
-            failures.append(
-                f"wire-provisioned server compiled "
-                f"{wire.total_compiles()} executables — expected ZERO")
         for _ in range(max(2, int(args.remote)) - 2):
             servers.append(cluster.ReplicaServer(model_dir))
 
@@ -1747,7 +1718,7 @@ def remote_main(args):
               f"{remote_rps:.0f} req/s, cold provision "
               f"{cold_provision_s:.2f}s, wire provision "
               f"{wire_provision_s:.2f}s "
-              f"({wire_report['files']} files, 0 compiles), "
+              f"({wire_report['files']} files), "
               f"{mismatches} mismatches")
     if failures:
         for f in failures:
@@ -2540,202 +2511,6 @@ def overload_main(args):
     return 0
 
 
-def cold_start_main(args):
-    """--cold-start: engine construction+warmup wall-clock, storeless
-    vs cold (empty artifact store — compiles AND seeds) vs warm
-    (seeded store — loads only). The warm replica must perform ZERO
-    XLA compiles and return bit-exact outputs vs the storeless engine;
-    the BENCH records are ``serving_cold_start_s`` (warm wall-clock)
-    and ``serving_cold_start_speedup`` (storeless / warm — the
-    autoscaling spin-up win). ``--decode`` measures the decode engine
-    the same way (``llama_decode_cold_start_*``)."""
-    import shutil
-    import tempfile
-
-    workdir = tempfile.mkdtemp(prefix="coldstart_")
-    try:
-        if args.decode:
-            report, failures = _cold_start_decode(args, workdir)
-        else:
-            report, failures = _cold_start_classifier(args, workdir)
-    finally:
-        shutil.rmtree(workdir, ignore_errors=True)
-
-    text = json.dumps(report, indent=2)
-    if args.out:
-        with open(args.out, "w") as f:
-            f.write(text + "\n")
-    if args.json:
-        print(text)
-    else:
-        r = report
-        print(f"servebench --cold-start{' --decode' if args.decode else ''} "
-              f"{r['model']}: storeless {r['storeless_warmup_s']}s, "
-              f"cold(seed) {r['cold_seed_s']}s, "
-              f"warm {r['warm_warmup_s']}s "
-              f"({r['cold_start_speedup']}x), "
-              f"{r['warm_compiles']} warm compiles, "
-              f"bitexact={r['bitexact']}")
-    for f in failures:
-        print(f"servebench --cold-start: {f}", file=sys.stderr)
-    if failures:
-        return 1
-    if args.assert_speedup is not None and \
-            report["cold_start_speedup"] < args.assert_speedup:
-        print(f"servebench --cold-start: speedup "
-              f"{report['cold_start_speedup']}x below the "
-              f"--assert-speedup {args.assert_speedup}x floor",
-              file=sys.stderr)
-        return 1
-    return 0
-
-
-def _cold_start_records(prefix, storeless_s, cold_s, warm_s, extra):
-    speedup = round(storeless_s / warm_s, 2) if warm_s > 0 else None
-    base = {"unit": None, "backend": "cpu",
-            "storeless_warmup_s": round(storeless_s, 3),
-            "cold_seed_s": round(cold_s, 3),
-            "warm_warmup_s": round(warm_s, 3)}
-    base.update(extra)
-    recs = [dict(base, metric=f"{prefix}_cold_start_s",
-                 value=round(warm_s, 3), unit="s"),
-            dict(base, metric=f"{prefix}_cold_start_speedup",
-                 value=speedup, unit="x")]
-    return recs, speedup
-
-
-def _cold_start_classifier(args, workdir):
-    zp, infer, fetch, per_row, scope, feeds = _setup(args)
-    model_dir = os.path.join(workdir, "model")
-    store_dir = os.path.join(workdir, "store")
-    startup_exe = fluid.Executor(fluid.CPUPlace())
-    # racecheck: ok(global-mutation) — driver-thread export before any
-    # serving thread starts; bench-private scope
-    with fluid.scope_guard(scope):
-        fluid.io.save_inference_model(
-            model_dir, zp.feed_names,
-            fetch if isinstance(fetch[0], str)
-            else [v.name for v in fetch],
-            startup_exe, main_program=zp.main,
-            serving_buckets=serving.BucketSpec(
-                batch_sizes=_bucket_sizes(args.max_batch)))
-
-    def build(compile_store):
-        t0 = time.perf_counter()
-        eng = serving.ServingEngine.from_saved_model(
-            model_dir, compile_store=compile_store, auto_start=False)
-        warm = eng.warmup()
-        return eng, warm, time.perf_counter() - t0
-
-    failures = []
-    ref_eng, _, storeless_s = build(False)          # today's cost
-    cold_eng, cold_warm, cold_s = build(store_dir)  # compiles + seeds
-    warm_eng, warm_warm, warm_s = build(store_dir)  # loads only
-    warm_compiles = warm_eng.exe.total_compiles()
-    if warm_compiles != 0:
-        failures.append(
-            f"warm replica compiled {warm_compiles} executables — "
-            f"expected ZERO ({warm_eng.exe.compile_counts()})")
-    # bit-exactness: the warm engine's executables came off disk; its
-    # rows must equal the storeless engine's bit for bit
-    bitexact = True
-    from paddle_tpu.core.executor import scope_guard as _sg
-    for feed in feeds[:8]:
-        # racecheck: ok(run-without-scope, global-mutation) — parity
-        # probe in the driver thread while engines are quiesced; each
-        # guard binds that engine's own scope
-        with _sg(ref_eng.scope):
-            a = ref_eng.exe.run(ref_eng.program, feed=feed,
-                                fetch_list=ref_eng.fetch_list,
-                                mode="test")
-        # racecheck: ok(run-without-scope, global-mutation) — ditto
-        with _sg(warm_eng.scope):
-            b = warm_eng.exe.run(warm_eng.program, feed=feed,
-                                 fetch_list=warm_eng.fetch_list,
-                                 mode="test")
-        for x, y in zip(a, b):
-            if not np.array_equal(np.asarray(x), np.asarray(y)):
-                bitexact = False
-    if not bitexact:
-        failures.append("store-loaded outputs diverged from the "
-                        "storeless engine (must be bit-exact)")
-    store_stats = warm_eng.exe.store_stats()
-    for eng in (ref_eng, cold_eng, warm_eng):
-        eng.close()
-    recs, speedup = _cold_start_records(
-        "serving", storeless_s, cold_s, warm_s,
-        {"model": args.model, "signatures": warm_warm["signatures"],
-         "store_hits": store_stats["hits_total"]})
-    report = {"model": args.model, "mode": "classifier",
-              "storeless_warmup_s": round(storeless_s, 3),
-              "cold_seed_s": round(cold_s, 3),
-              "warm_warmup_s": round(warm_s, 3),
-              "cold_start_speedup": speedup,
-              "warm_compiles": warm_compiles,
-              "cold_warmup": cold_warm, "warm_warmup": warm_warm,
-              "bitexact": bitexact,
-              "artifact_store": store_stats,
-              "bench_records": recs}
-    return report, failures
-
-
-def _cold_start_decode(args, workdir):
-    from paddle_tpu import serving
-
-    args.requests = min(args.requests, 4)
-    cfg, buckets, scope, exe, gen, prompts = _decode_model(args)
-    store_dir = os.path.join(workdir, "store")
-
-    def build(compile_store):
-        t0 = time.perf_counter()
-        eng = serving.DecodeEngine(
-            cfg, scope=scope, place=fluid.CPUPlace(),
-            config=_decode_config(args, buckets),
-            compile_store=compile_store, auto_start=False)
-        warm = eng.warmup()
-        return eng, warm, time.perf_counter() - t0
-
-    failures = []
-    ref_eng, _, storeless_s = build(False)
-    cold_eng, cold_warm, cold_s = build(store_dir)
-    warm_eng, warm_warm, warm_s = build(store_dir)
-    warm_compiles = warm_eng.exe.total_compiles()
-    if warm_compiles != 0:
-        failures.append(
-            f"warm decode replica compiled {warm_compiles} "
-            f"executables — expected ZERO "
-            f"({warm_eng.exe.compile_counts()})")
-    bitexact = True
-    ref_eng.start()
-    warm_eng.start()
-    for p in prompts[:2]:
-        a = np.asarray(ref_eng.generate(p, max_new=args.max_new))
-        b = np.asarray(warm_eng.generate(p, max_new=args.max_new))
-        if not np.array_equal(a, b):
-            bitexact = False
-    if not bitexact:
-        failures.append("store-loaded decode tokens diverged from the "
-                        "storeless engine (must be bit-exact)")
-    store_stats = warm_eng.exe.store_stats()
-    for eng in (ref_eng, cold_eng, warm_eng):
-        eng.close()
-    recs, speedup = _cold_start_records(
-        "llama_decode", storeless_s, cold_s, warm_s,
-        {"model": "llama_tiny", "programs": warm_warm["programs"],
-         "store_hits": store_stats["hits_total"]})
-    report = {"model": "llama_tiny", "mode": "decode",
-              "storeless_warmup_s": round(storeless_s, 3),
-              "cold_seed_s": round(cold_s, 3),
-              "warm_warmup_s": round(warm_s, 3),
-              "cold_start_speedup": speedup,
-              "warm_compiles": warm_compiles,
-              "cold_warmup": cold_warm, "warm_warmup": warm_warm,
-              "bitexact": bitexact,
-              "artifact_store": store_stats,
-              "bench_records": recs}
-    return report, failures
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser(
         description="serving load benchmark: batched vs single-request")
@@ -2752,11 +2527,6 @@ def main(argv=None):
     ap.add_argument("--chaos", action="store_true",
                     help="fault-injection drill instead of the "
                          "speedup race (selfcheck stage 4)")
-    ap.add_argument("--cold-start", action="store_true",
-                    help="artifact-store cold-start benchmark: "
-                         "construction+warmup storeless vs warm "
-                         "(zero-compile) replica; with --decode, the "
-                         "decode engine (selfcheck stage 8)")
     ap.add_argument("--decode", action="store_true",
                     help="continuous-batching decode benchmark on a "
                          "tiny llama (selfcheck stage 6)")
@@ -2807,12 +2577,12 @@ def main(argv=None):
     ap.add_argument("--remote", type=int, default=0,
                     help="N>0: drive N loopback ReplicaServers over "
                     "the socket fabric (serving_remote_qps + the "
-                    "zero-compile cold/wire provisioning gates); "
+                    "wire provisioning gate); "
                     "with --chaos, the partition drill instead")
     ap.add_argument("--canary", action="store_true",
                     help="versioned-deployment drill: canary traffic "
                          "shifting, numerics-gated promotion, instant "
-                         "zero-compile rollback (selfcheck stage 10)")
+                         "rollback (selfcheck stage 10)")
     ap.add_argument("--rolling-restart", action="store_true",
                     help="with --cluster: roll-restart every replica "
                          "under sustained mixed load and assert zero "
@@ -2847,8 +2617,6 @@ def main(argv=None):
     if args.max_batch is None:
         args.max_batch = 16 if args.decode else 8
 
-    if args.cold_start:
-        return cold_start_main(args)
     if args.canary:
         return canary_main(args)
     if args.chaos and args.remote:

@@ -13,9 +13,8 @@ all four trainer fault points fired against one run —
 1. ``trainer_crash_at_step`` (env-armed, ``--hard-exit``: the worker
    takes an ``os._exit`` mid-step — the SIGKILL shape), the
    coordinator evicts and retries at reduced world size, and a
-   REPLACEMENT worker cold-provisions its ``__artifacts__`` over the
-   wire from a live peer (``--task program``: total_compiles must be
-   0) and is folded back in (elastic up, ``train_elastic_resume_s``);
+   REPLACEMENT worker is folded back in (elastic up,
+   ``train_elastic_resume_s``);
 2. ``trainer_straggle`` (env-armed stall past the coordinator's
    straggler deadline): evicted typed, REJOINS after the stall heals
    (``train_recover_s``);
@@ -66,9 +65,7 @@ def _reference_run(kind, steps, commit_interval, n_shards):
     from paddle_tpu.cluster.train_fabric import TrainCoordinator
     from paddle_tpu.cluster.train_worker import TrainWorkerServer
     d = tempfile.mkdtemp(prefix="trainbench_ref_")
-    w = TrainWorkerServer(
-        artifact_dir=tempfile.mkdtemp(prefix="trainbench_ref_af_")
-        if kind == "program" else None)
+    w = TrainWorkerServer()
     co = TrainCoordinator(_task(kind), [w.addr], d,
                           commit_interval=commit_interval,
                           n_shards=n_shards)
@@ -86,15 +83,10 @@ def _free_port():
         return s.getsockname()[1]
 
 
-def _spawn_worker(port, artifact_dir=None, provision_from=None,
-                  faults=None, straggle_s=None, hard_exit=False):
+def _spawn_worker(port, faults=None, straggle_s=None, hard_exit=False):
     """Launch a real subprocess worker; block until its ready line."""
     cmd = [sys.executable, "-m", "paddle_tpu.cluster.train_worker",
            "--host", "127.0.0.1", "--port", str(port)]
-    if artifact_dir:
-        cmd += ["--artifact-dir", artifact_dir]
-    if provision_from:
-        cmd += ["--provision-from", provision_from]
     if hard_exit:
         cmd += ["--hard-exit"]
     env = dict(os.environ)
@@ -172,20 +164,14 @@ def chaos_main(args):
                                              commit_interval, n_shards)
 
     ckpt_dir = tempfile.mkdtemp(prefix="trainbench_chaos_")
-    afs = {n: tempfile.mkdtemp(prefix=f"trainbench_{n}_")
-           for n in ("w1", "w2", "w3")}
     # w1 dies hard on its 3rd served step; w2 straggles once later
     w1 = _spawn_worker(_free_port(),
-                       artifact_dir=afs["w1"] if kind == "program"
-                       else None,
                        faults="trainer_crash_at_step@2",
                        hard_exit=True)
     # w2's 11th handled step stalls: steps 1-6 plus the crash retry
     # are 7 handles in phase 1, 2 more after w3 joins — index 10
     # lands inside phase 2's window, after the warmup deadline drops
     w2 = _spawn_worker(_free_port(),
-                       artifact_dir=afs["w2"] if kind == "program"
-                       else None,
                        faults="trainer_straggle@10", straggle_s=3.0)
     w1_addr = None
     w2_addr = None
@@ -209,10 +195,7 @@ def chaos_main(args):
             failures.append("w1's hard crash never evicted it")
         w3_port = _free_port()
         t0 = time.monotonic()
-        w3 = _spawn_worker(
-            w3_port,
-            artifact_dir=afs["w3"] if kind == "program" else None,
-            provision_from=w2_addr if kind == "program" else None)
+        w3 = _spawn_worker(w3_port)
         procs.append(w3)
         w3_addr = f"127.0.0.1:{w3_port}"
         w3_client = co.admit(w3_addr)
@@ -225,8 +208,7 @@ def chaos_main(args):
         # --- phase 2: straggler evict + rejoin ----------------------
         print("phase 2: trainer_straggle past the deadline ...",
               flush=True)
-        # every program is warm now (and w3 provisioned, so no
-        # compile ever re-raises the bar): a 3s stall against a 1.5s
+        # every program is warm now: a 3s stall against a 1.5s
         # deadline is an unambiguous straggler
         co.step_deadline_s = 1.5
         evict_before = co.evictions_total
@@ -295,16 +277,6 @@ def chaos_main(args):
                     f"loss curve diverged at step {step}: "
                     f"{loss} vs {ref}")
                 break
-        # the replacement provisioned with zero compiles
-        if kind == "program":
-            for c in co2.live_workers():
-                if c.name == w3_addr:
-                    c.refresh()     # a stats heartbeat fills the cache
-                    compiles = c.stats().get("total_compiles")
-                    if compiles != 0:
-                        failures.append(
-                            f"replacement worker recompiled: "
-                            f"total_compiles={compiles}")
         snap = co2.stats()
         co2.close()
     finally:
