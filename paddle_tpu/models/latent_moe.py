@@ -152,9 +152,8 @@ class LatentMoEConfig:
 
     def build_paged_programs(self, *, max_batch, page_size, n_pages,
                              pages_per_seq, prompt_buckets,
-                             decode_block=1, prefill_batch=1,
-                             quantize=False, draft_cfg=None, gamma=4,
-                             chunk_size=None):
+                             decode_block=1, quantize=False,
+                             draft_cfg=None, gamma=4, chunk_size=None):
         """The paged step programs DecodeEngine runs for this model: as
         models/llama.py build_llama_paged_programs, over ONE pool of
         ``[n_layers, n_pages, page_size, kv_rank + rope_dim]``, each
@@ -184,7 +183,7 @@ class LatentMoEConfig:
             attrs=self.block_attrs(page_size), vocab_size=self.vocab_size,
             dtype=self.dtype)
 
-        def bundle(kind, prefix, batch, feeds, steps=1):
+        def bundle(kind, prefix, feeds, steps=1):
             """One program: ``feeds`` are (slot, feed name, shape, dtype)
             of its data inputs, in feed order; the pool follows."""
             main = framework.Program()
@@ -205,15 +204,14 @@ class LatentMoEConfig:
                     "fetch": [out] + pools_out + [logits, picks, stats],
                     "extras": ("logits", "picks", "stats")}
 
-        pb = max(1, int(prefill_batch))
         table = lambda b: ("Table", "table", [b, pages_per_seq], "int32")
         prefill = {
-            bucket: bundle("prefill", "pp", pb, [
-                ("Tokens", "tokens", [pb, bucket], "int64"),
-                ("Lens", "lens", [pb], "int32"), table(pb)])
+            bucket: bundle("prefill", "pp", [
+                ("Tokens", "tokens", [1, bucket], "int64"),
+                ("Lens", "lens", [1], "int32"), table(1)])
             for bucket in prefill_buckets_reached(prompt_buckets,
                                                   chunk_size)}
-        decode = bundle("decode", "dc", max_batch, [
+        decode = bundle("decode", "dc", [
             ("Tokens", "tokens", [max_batch], "int64"),
             ("Positions", "positions", [max_batch], "int32"),
             table(max_batch)], steps=decode_block)
@@ -222,7 +220,7 @@ class LatentMoEConfig:
             cs = int(chunk_size)
             if cs < 1:
                 raise ValueError(f"chunk_size must be >= 1, got {cs}")
-            chunk = bundle("prefill_chunk", "ck", 1, [
+            chunk = bundle("prefill_chunk", "ck", [
                 ("Tokens", "tokens", [1, cs], "int64"),
                 ("Lens", "lens", [1], "int32"),
                 ("Offsets", "offsets", [1], "int32"), table(1)])

@@ -413,9 +413,9 @@ def _pool_specs(cfg, n_pages, page_size):
 
 def build_llama_paged_programs(cfg, *, max_batch, page_size, n_pages,
                                pages_per_seq, prompt_buckets,
-                               decode_block=1, prefill_batch=1,
-                               quantize=False, draft_cfg=None,
-                               gamma=4, chunk_size=None):
+                               decode_block=1, quantize=False,
+                               draft_cfg=None, gamma=4,
+                               chunk_size=None):
     """Builds the paged-KV step programs for ``cfg`` (dense configs
     only): prefill-into-slot per prompt bucket, a ``decode_block``-step
     decode program, and (with ``draft_cfg``) a speculative-round
@@ -454,15 +454,14 @@ def build_llama_paged_programs(cfg, *, max_batch, page_size, n_pages,
                            append_batch_size=False)
 
     prefill = {}
-    pb = max(1, int(prefill_batch))
     buckets = prefill_buckets_reached(prompt_buckets, chunk_size)
     for bucket in buckets:
         main = framework.Program()
         with framework.program_guard(main, framework.Program()), \
                 framework.unique_name.guard():
-            tokens = _data("pp_tokens", [pb, bucket], "int64")
-            lens = _data("pp_lens", [pb], "int32")
-            table = _data("pp_table", [pb, pages_per_seq], "int32")
+            tokens = _data("pp_tokens", [1, bucket], "int64")
+            lens = _data("pp_lens", [1], "int32")
+            table = _data("pp_table", [1, pages_per_seq], "int32")
             kp = _data("pp_kpages", kv_shape, cfg.dtype)
             vp = _data("pp_vpages", kv_shape, cfg.dtype)
             nxt, kp_out, vp_out = tfl.llama_paged_prefill(
@@ -531,9 +530,9 @@ def build_llama_paged_programs(cfg, *, max_batch, page_size, n_pages,
             main = framework.Program()
             with framework.program_guard(main, framework.Program()), \
                     framework.unique_name.guard():
-                tokens = _data("dp_tokens", [pb, bucket], "int64")
-                lens = _data("dp_lens", [pb], "int32")
-                table = _data("dp_table", [pb, pages_per_seq], "int32")
+                tokens = _data("dp_tokens", [1, bucket], "int64")
+                lens = _data("dp_lens", [1], "int32")
+                table = _data("dp_table", [1, pages_per_seq], "int32")
                 kp = _data("dp_kpages", draft_kv_shape, draft_cfg.dtype)
                 vp = _data("dp_vpages", draft_kv_shape, draft_cfg.dtype)
                 nxt, kp_out, vp_out = tfl.llama_paged_prefill(

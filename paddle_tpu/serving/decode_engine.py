@@ -95,10 +95,11 @@ _DECODE_COUNTERS = (
     # the dispatch sums lie inside busy, each from the call to its
     # tokens on the host, so busy - dispatches is the host time between
     # dispatches. prefill_total counts REQUESTS, prefill_dispatch_total
-    # dispatches; real against padded (prefill_batch x bucket) prompt
-    # tokens is what a padded prefill wastes; queue_wait is admission
-    # instant - enqueued_at, summed over the requests prefill_total
-    # counts. The pt:engine/* spans share these boundaries.
+    # dispatches (one a whole-prompt request); real against padded
+    # (``bucket`` a dispatch) prompt tokens is what the bucket's length
+    # wastes; queue_wait is each request's own dispatch instant -
+    # enqueued_at, summed over the requests prefill_total counts. The
+    # pt:engine/* spans share these boundaries.
     "loop_busy_s_total", "loop_idle_s_total",
     "decode_dispatch_s_total", "chunk_dispatch_s_total",
     "prefill_dispatch_s_total", "prefill_dispatch_total",
@@ -136,7 +137,10 @@ class DecodeConfig:
     values overcommit and admission waits for pages);
     ``decode_block`` tokens generated per decode dispatch (the
     dispatch-overhead amortizer; admission/retirement happen at block
-    boundaries); ``gamma`` draft tokens per speculative round.
+    boundaries); ``prefill_batch`` the most same-bucket requests one
+    admission pass takes from the queue together (it shapes no program:
+    every prefill program has one row, and a group is dispatched
+    request by request); ``gamma`` draft tokens per speculative round.
 
     Traffic: ``eos_id`` retires a sequence early (None = generate to
     max_new); ``max_queue`` admission bound; ``default_timeout_s``
@@ -382,8 +386,7 @@ class DecodeEngine:
             max_batch=c.max_batch, page_size=c.page_size,
             n_pages=n_pages, pages_per_seq=self.pages_per_seq,
             prompt_buckets=c.prompt_buckets,
-            decode_block=c.decode_block,
-            prefill_batch=c.prefill_batch, quantize=c.quantize,
+            decode_block=c.decode_block, quantize=c.quantize,
             draft_cfg=draft_cfg, gamma=c.gamma,
             chunk_size=c.chunk_size)
         # graph rewrites on every step program (analysis/optimize.py,
@@ -507,24 +510,21 @@ class DecodeEngine:
 
     # -- warmup ----------------------------------------------------------
     def warmup(self):
-        """Pre-compile every step executable (each prefill bucket, the
-        decode step, the spec step) with null-page dummy dispatches,
-        then snapshot compile counts for assert_no_recompiles(). The
-        steady state after this never compiles, no matter how requests
-        churn."""
+        """Pre-compile every step executable (each prefill bucket's
+        single-row program, the decode step, the spec step) with
+        null-page dummy dispatches, then snapshot compile counts for
+        assert_no_recompiles(). The steady state after this never
+        compiles, no matter how requests churn."""
         n = 0
-        pb = self.config.prefill_batch
+        row = (np.ones((1,), np.int32),
+               np.zeros((1, self.pages_per_seq), np.int32))
         for bucket in sorted(self.programs.prefill):
             self._run_prefill_program(
-                bucket, np.zeros((pb, bucket), np.int64),
-                np.ones((pb,), np.int32),
-                np.zeros((pb, self.pages_per_seq), np.int32))
+                bucket, np.zeros((1, bucket), np.int64), *row)
             n += 1
             if self.draft_cfg is not None:
                 self._run_draft_prefill_program(
-                    bucket, np.zeros((pb, bucket), np.int64),
-                    np.ones((pb,), np.int32),
-                    np.zeros((pb, self.pages_per_seq), np.int32))
+                    bucket, np.zeros((1, bucket), np.int64), *row)
                 n += 1
         if self.programs.chunk is not None:
             cs = self.programs.chunk_size
@@ -914,14 +914,23 @@ class DecodeEngine:
         return [np.asarray(x) for x in outs[:n_head]]
 
     def _run_prefill_program(self, bucket, tokens, lens, table):
-        return self._run_program(
-            f"prefill_{bucket}", self.programs.prefill[bucket],
-            (tokens, lens, table))[0]
+        """The bucket's single-row program once for each row given, in
+        order: the ``[rows]`` next tokens. ``kept`` holds what the last
+        row's dispatch left."""
+        return self._run_rows(f"prefill_{bucket}",
+                              self.programs.prefill[bucket],
+                              tokens, lens, table)
 
     def _run_draft_prefill_program(self, bucket, tokens, lens, table):
-        self._run_program(
-            f"draft_prefill_{bucket}",
-            self.programs.draft_prefill[bucket], (tokens, lens, table))
+        self._run_rows(f"draft_prefill_{bucket}",
+                       self.programs.draft_prefill[bucket],
+                       tokens, lens, table)
+
+    def _run_rows(self, label, b, tokens, lens, table):
+        return np.concatenate([
+            self._run_program(label, b, (tokens[i:i + 1], lens[i:i + 1],
+                                         table[i:i + 1]))[0]
+            for i in range(len(tokens))])
 
     def _run_chunk_program(self, tokens, lens, offsets, table):
         return self._run_program("chunk", self.programs.chunk,
@@ -1087,14 +1096,16 @@ class DecodeEngine:
         per-token budget). The head of the order then picks its path:
         handoff import (pages + an eager KV copy, no dispatch),
         chunked prefill (reserve a slot + pages now; the slices run in
-        _step_chunks), or whole-prompt prefill — up to
-        ``prefill_batch`` same-bucket requests per DISPATCH (one
-        dispatch per request would make admission cost rival the fused
-        baseline). Rows are independent inside the prefill program, so
-        grouping never couples request numerics (same contract as the
-        decode step). Transient page exhaustion leaves requests queued
-        (retirement frees pages and wakes admission); a terminal
-        prefill failure fails only that dispatch's requests."""
+        _step_chunks), or whole-prompt prefill: a group of up to
+        ``prefill_batch`` same-bucket requests leaves the queue
+        together and is dispatched REQUEST BY REQUEST, in the group's
+        order, through the bucket's one single-row program
+        (_prefill_request). Each first token is installed as its own
+        dispatch returns, nothing waits to fill a dispatch, and a
+        request runs the same executable alone or in company.
+        Transient page exhaustion leaves requests queued (retirement
+        frees pages and wakes admission); a terminal prefill failure
+        fails only that dispatch's request."""
         admitted = False
         while True:
             with self._slots_lock:
@@ -1175,63 +1186,58 @@ class DecodeEngine:
                         "circuit breaker open — prefill shed; back "
                         f"off {self.config.breaker_cooldown_s}s"))
                 continue
-            pb = self.config.prefill_batch
-            tokens = np.zeros((pb, bucket), np.int64)
-            lens = np.ones((pb,), np.int32)
-            tables = np.zeros((pb, self.pages_per_seq), np.int32)
-            for j, (r, pages) in enumerate(granted):
-                tokens[j, :r.prompt.size] = r.prompt
-                lens[j] = r.prompt.size
-                tables[j, :len(pages)] = pages
-            deadlines = [r.deadline for r, _ in granted
-                         if r.deadline is not None]
-
-            def _prefill_dispatch():
-                self._maybe_inject_fault()
-                nxt = self._run_prefill_program(bucket, tokens, lens,
-                                                tables)
-                if self.draft_cfg is not None:
-                    self._run_draft_prefill_program(bucket, tokens,
-                                                    lens, tables)
-                return nxt
-
-            dispatch = record_event(
-                "pt:engine/prefill_dispatch", bucket=bucket,
-                rows=len(granted),
-                req=" ".join(str(r.seq) for r, _ in granted))
-            admitted_at = time.monotonic()
-            try:
-                with dispatch:
-                    nxt = with_retries(
-                        _prefill_dispatch, policy=policy,
-                        deadline=min(deadlines) if deadlines else None,
-                        on_retry=lambda exc, n, delay:
-                            self.metrics.incr("retries_total"))
-            except BaseException as exc:     # noqa: BLE001 — forwarded
-                self._tick(prefill_dispatch_s_total=dispatch.seconds)
-                with self._slots_lock:
-                    for _, pages in granted:
-                        self.allocator.free(pages)
-                if self.breaker.record_failure():
-                    self.metrics.incr("breaker_open_total")
-                    self.health.to(HealthState.DEGRADED)
-                self.metrics.incr("errors_total", len(granted))
-                for r, _ in granted:
-                    r.set_error(exc)
-                continue
-            self.breaker.record_success()
-            self._tick(
-                prefill_dispatch_total=1,
-                prefill_dispatch_s_total=dispatch.seconds,
-                prefill_tokens_total=int(lens[:len(granted)].sum()),
-                prefill_padded_tokens_total=pb * bucket,
-                queue_wait_s_total=sum(admitted_at - r.enqueued_at
-                                       for r, _ in granted))
-            for j, (r, pages) in enumerate(granted):
-                self._install_first_token(r, pages, tables[j],
-                                          int(nxt[j]), free[j])
-            admitted = True
+            for (r, pages), idx in zip(granted, free):
+                admitted |= self._prefill_request(policy, bucket, r,
+                                                  pages, idx)
         return admitted
+
+    def _prefill_request(self, policy, bucket, r, pages, idx):
+        """One whole-prompt request's own dispatch of its bucket's
+        single-row program (the draft's behind it), and its first token
+        installed as that dispatch returns: True. A terminal failure
+        frees the pages and fails this request alone: False."""
+        tokens = np.zeros((1, bucket), np.int64)
+        tokens[0, :r.prompt.size] = r.prompt
+        lens = np.asarray([r.prompt.size], np.int32)
+        table = np.zeros((1, self.pages_per_seq), np.int32)
+        table[0, :len(pages)] = pages
+
+        def _prefill_dispatch():
+            self._maybe_inject_fault()
+            nxt = self._run_prefill_program(bucket, tokens, lens, table)
+            if self.draft_cfg is not None:
+                self._run_draft_prefill_program(bucket, tokens, lens,
+                                                table)
+            return nxt
+
+        dispatch = record_event("pt:engine/prefill_dispatch",
+                                bucket=bucket, rows=1, req=r.seq)
+        admitted_at = time.monotonic()
+        try:
+            with dispatch:
+                nxt = with_retries(
+                    _prefill_dispatch, policy=policy,
+                    deadline=r.deadline,
+                    on_retry=lambda exc, n, delay:
+                        self.metrics.incr("retries_total"))
+        except BaseException as exc:     # noqa: BLE001 — forwarded
+            self._tick(prefill_dispatch_s_total=dispatch.seconds)
+            with self._slots_lock:
+                self.allocator.free(pages)
+            if self.breaker.record_failure():
+                self.metrics.incr("breaker_open_total")
+                self.health.to(HealthState.DEGRADED)
+            self.metrics.incr("errors_total")
+            r.set_error(exc)
+            return False
+        self.breaker.record_success()
+        self._tick(prefill_dispatch_total=1,
+                   prefill_dispatch_s_total=dispatch.seconds,
+                   prefill_tokens_total=int(r.prompt.size),
+                   prefill_padded_tokens_total=bucket,
+                   queue_wait_s_total=admitted_at - r.enqueued_at)
+        self._install_first_token(r, pages, table[0], int(nxt[0]), idx)
+        return True
 
     def _score_ttft(self, r):
         """SLO attainment bookkeeping for a freshly prefilled request:

@@ -17,6 +17,7 @@ The two contracts everything else hangs off:
 """
 import time
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -205,6 +206,189 @@ def test_churn_no_recompiles_and_bit_identical(engine):
     assert st["responses_total"] >= 2 * len(prompts)
     assert st["ttft_s"]["count"] >= 2 * len(prompts)
     assert st["pages_in_use"] == 0       # everything retired and freed
+
+
+# ---------------------------------------------------------------------
+# single-row prefill programs: a group leaves the queue together and is
+# dispatched request by request
+# ---------------------------------------------------------------------
+
+def _group_engine(scope, **over):
+    """A cold engine with no worker, so that whatever is queued before
+    ``start()`` is taken by ONE admission pass (up to prefill_batch)."""
+    conf = dict(max_batch=4, prompt_buckets=(4, 8), max_new_tokens=8,
+                page_size=8, decode_block=4, prefill_batch=4,
+                default_timeout_s=120.0)
+    draft_cfg = over.pop("draft_cfg", None)
+    conf.update(over)
+    return DecodeEngine(CFG, scope=scope, place=fluid.CPUPlace(),
+                        draft_cfg=draft_cfg, config=DecodeConfig(**conf),
+                        auto_start=False)
+
+
+def _queue_together(eng, prompts, **kw):
+    """Stop the worker, queue ``prompts``, start it again: the requests."""
+    eng._stop.set()
+    if eng._worker is not None:
+        eng._worker.join(10.0)
+    reqs = [eng.submit(p, timeout=120, **kw) for p in prompts]
+    eng.start()
+    return reqs
+
+
+def _pool_bytes(pools):
+    return [np.asarray(p).view(np.uint8) for p in pools]
+
+
+@pytest.fixture(scope="module")
+def group_engine(served_scope):
+    eng = _group_engine(served_scope[0])
+    eng.warm = eng.warmup()
+    yield eng
+    eng.close()
+
+
+@pytest.mark.parametrize("bucket", [4, 8])
+@pytest.mark.parametrize("size", [1, 2, 3, 4])
+def test_groups_of_every_size_run_the_warmed_programs(group_engine, size,
+                                                      bucket):
+    """Two prefill programs and the decode program, whatever the group:
+    1 to 4 requests of either bucket compile nothing, and each pays one
+    dispatch of its bucket's length."""
+    eng = group_engine
+    assert eng.warm["programs"] == 3
+    compiles = eng.exe.total_compiles()
+    before = eng.stats()
+    rng = np.random.RandomState(10 * bucket + size)
+    prompts = _prompts(size, rng, lo=bucket // 2 + 1, hi=bucket)
+    reqs = _queue_together(eng, prompts, max_new=3)
+    assert all(r.result(120).size == 3 for r in reqs)
+    eng.assert_no_recompiles()
+    after = eng.stats()
+    assert eng.exe.total_compiles() == compiles == after["compiles_now"]
+    assert after["prefill_dispatch_total"] \
+        - before["prefill_dispatch_total"] == size
+    assert after["prefill_padded_tokens_total"] \
+        - before["prefill_padded_tokens_total"] == size * bucket
+
+
+def test_a_request_among_three_peers_is_the_request_alone(served_scope):
+    """Queued third of four same-bucket requests, a prompt returns the
+    tokens it returns alone and leaves, byte for byte, the pages it
+    leaves alone: there is one executable a bucket, whatever shares the
+    queue."""
+    scope = served_scope[0]
+    rng = np.random.RandomState(11)
+    peers = _prompts(3, rng, lo=5, hi=8)
+    probe = rng.randint(0, CFG.vocab_size, (7,)).astype(np.int64)
+    mix = peers[:2] + [probe] + peers[2:]
+    out = {}
+    for name, prompts, at in (("alone", [probe], 0), ("mixed", mix, 2)):
+        handoff = _group_engine(scope)
+        tokens = _group_engine(scope)
+        try:
+            # prefill_only resolves with the page CONTENTS the prefill
+            # left (fresh pools on both sides: unwritten entries are 0)
+            blobs = [handoff.submit(p, max_new=4, prefill_only=True)
+                     for p in prompts]
+            reqs = [tokens.submit(p, max_new=6) for p in prompts]
+            handoff.start(), tokens.start()
+            out[name] = (blobs[at].result(120), reqs[at].result(120))
+            assert tokens.stats()["prefill_dispatch_total"] \
+                == len(prompts)
+        finally:
+            handoff.close(), tokens.close()
+    (blob_a, tok_a), (blob_m, tok_m) = out["alone"], out["mixed"]
+    np.testing.assert_array_equal(tok_a, tok_m)
+    assert blob_a["emitted"] == blob_m["emitted"] == [int(tok_a[0])]
+    assert len(blob_a["cache"]) == 2
+    for a, m in zip(blob_a["cache"], blob_m["cache"]):
+        assert np.asarray(a).any()
+        np.testing.assert_array_equal(np.asarray(a).view(np.uint8),
+                                      np.asarray(m).view(np.uint8))
+
+
+@pytest.mark.parametrize("rows", [2, 4])
+def test_run_prefill_program_takes_rows_one_dispatch_each(served_scope,
+                                                          rows):
+    """_run_prefill_program with k rows IS k calls of one row: the same
+    next tokens, the same pools, and ``kept`` from the last row."""
+    eng = _group_engine(served_scope[0])
+    try:
+        rng = np.random.RandomState(12)
+        tokens = np.zeros((rows, 8), np.int64)
+        lens = rng.randint(3, 9, (rows,)).astype(np.int32)
+        table = np.zeros((rows, eng.pages_per_seq), np.int32)
+        for i in range(rows):
+            tokens[i, :lens[i]] = rng.randint(0, CFG.vocab_size, lens[i])
+            table[i, :2] = (1 + 2 * i, 2 + 2 * i)
+        runs = eng.exe.compile_counts()
+        together = eng._run_prefill_program(8, tokens, lens, table)
+        assert together.shape == (rows,)
+        pools = _pool_bytes(eng._pools)
+        eng._pools = [jnp.zeros_like(p) for p in eng._pools]
+        one_by_one = [eng._run_prefill_program(
+            8, tokens[i:i + 1], lens[i:i + 1], table[i:i + 1])
+            for i in range(rows)]
+        assert all(t.shape == (1,) for t in one_by_one)
+        np.testing.assert_array_equal(together,
+                                      np.concatenate(one_by_one))
+        for a, b in zip(pools, _pool_bytes(eng._pools)):
+            assert a.any()
+            np.testing.assert_array_equal(a, b)
+        # one executable served every row of both forms
+        assert len(eng.exe.compile_counts()) == len(runs) + 1
+    finally:
+        eng.close()
+
+
+def test_spec_group_fills_the_draft_pool_row_by_row(served_scope):
+    """A speculative engine prefills target and draft request by
+    request: after a group of three the draft's pools (and the
+    target's) hold, byte for byte, what each request's own two
+    dispatches leave."""
+    scope = served_scope[0]
+    with fluid.scope_guard(scope):
+        copy_weights_as_draft(scope)
+    eng = _group_engine(scope, draft_cfg=CFG, prompt_buckets=(8,),
+                        max_new_tokens=6, gamma=3)
+    try:
+        assert eng.warmup()["programs"] == 4   # 2 prefills, decode, spec
+        rng = np.random.RandomState(13)
+        prompts = _prompts(3, rng, lo=3, hi=8)
+        # max_new=1 retires at the first token: no speculative round
+        # writes behind the prefills
+        reqs = _queue_together(eng, prompts, max_new=1)
+        first = [int(r.result(120)[0]) for r in reqs]
+        eng._stop.set()
+        eng._worker.join(10.0)
+        eng.assert_no_recompiles()
+        assert eng.stats()["prefill_dispatch_total"] == 3
+        target, draft = (_pool_bytes(eng._pools),
+                         _pool_bytes(eng._draft_pools))
+        assert all(d[:, 1:].any() for d in draft)
+        # each request alone, into zeroed pools, at the pages a fresh
+        # allocator granted it (1.., in order)
+        eng._pools = [jnp.zeros_like(p) for p in eng._pools]
+        eng._draft_pools = [jnp.zeros_like(p) for p in eng._draft_pools]
+        page = 1
+        for i, p in enumerate(prompts):
+            tokens = np.zeros((1, 8), np.int64)
+            tokens[0, :p.size] = p
+            need = eng._pages_needed(p.size, 1)
+            table = np.zeros((1, eng.pages_per_seq), np.int32)
+            table[0, :need] = page + np.arange(need)
+            page += need
+            lens = np.asarray([p.size], np.int32)
+            assert int(eng._run_prefill_program(
+                8, tokens, lens, table)[0]) == first[i]
+            eng._run_draft_prefill_program(8, tokens, lens, table)
+        # page 0 is the null page: the warm-up's dummy rows wrote there
+        for a, b in zip(draft + target, _pool_bytes(
+                eng._draft_pools + eng._pools)):
+            np.testing.assert_array_equal(a[:, 1:], b[:, 1:])
+    finally:
+        eng.close()
 
 
 def test_submit_validation(engine):

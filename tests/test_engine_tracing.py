@@ -126,13 +126,14 @@ def test_loop_clock_partitions_the_workers_life(scope):
 # ---------------------------------------------------------------------
 
 @pytest.mark.parametrize("lengths,prefill_batch,dispatches,padded", [
-    # one at a time: every dispatch pays prefill_batch rows of its bucket
-    ([3], 2, 1, 2 * 4),
-    ([5], 2, 1, 2 * 8),
-    # queued together: same-bucket prompts share a dispatch, two by two
-    ([2, 3, 4, 4], 2, 2, 2 * (2 * 4)),
-    ([3, 7, 4, 8, 6], 2, 3, 2 * 4 + 2 * (2 * 8)),
-    ([5, 6, 7, 8], 4, 1, 4 * 8),
+    # a dispatch carries one request and pays its bucket's length
+    ([3], 2, 1, 4),
+    ([5], 2, 1, 8),
+    # queued together: a group leaves the queue together, and still each
+    # request is a dispatch of its own
+    ([2, 3, 4, 4], 2, 4, 4 * 4),
+    ([3, 7, 4, 8, 6], 2, 5, 2 * 4 + 3 * 8),
+    ([5, 6, 7, 8], 4, 4, 4 * 8),
 ])
 def test_prefill_accounting_is_exact(scope, lengths, prefill_batch,
                                      dispatches, padded):
@@ -159,6 +160,51 @@ def test_prefill_accounting_is_exact(scope, lengths, prefill_batch,
     assert d["queue_wait_s_total"] / d["prefill_total"] >= 0.05
     assert d["queue_wait_s_total"] / d["prefill_total"] < 30.0
     assert [r.seq for r in reqs] == list(range(1, len(lengths) + 1))
+
+
+def test_the_first_of_a_group_has_its_token_before_the_last_is_dispatched(
+        scope, tmp_path):
+    """Four same-bucket requests leave the queue as one group and are
+    dispatched one by one: four single-row spans in the group's order,
+    the first request settled (it asked for one token) before the
+    second's dispatch starts, and first tokens as far apart as the
+    dispatches between them take."""
+    eng = make_engine(scope, auto_start=False, prefill_batch=4)
+    eng.warmup()
+    rng = np.random.RandomState(4)
+    reqs = [eng.submit(rng.randint(0, CFG.vocab_size, (n,)),
+                       max_new=1 if i == 0 else 2)
+            for i, n in enumerate((5, 8, 6, 7))]
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.start()
+        for r in reqs:
+            r.result(60.0)
+        eng.close()
+    finally:
+        jax.profiler.stop_trace()
+    events = pt_events(str(tmp_path))
+    spans = sorted((e for e in events
+                    if e[0] == "pt:engine/prefill_dispatch"),
+                   key=lambda e: e[1])
+    assert [e[3]["req"] for e in spans] == [r.seq for r in reqs]
+    assert all(e[3]["rows"] == 1 and e[3]["bucket"] == 8 for e in spans)
+    admits = [a for a in events if a[0] == "pt:engine/admit"
+              and a[1] <= spans[0][1] and spans[0][2] <= a[2]]
+    assert len(admits) == 1                    # one pass took all four
+    assert all(admits[0][1] <= e[1] and e[2] <= admits[0][2]
+               for e in spans)
+    for a, b in zip(spans, spans[1:]):
+        assert a[2] <= b[1]                    # one after the other
+    retired = [e for e in events if e[0] == "pt:engine/retire"
+               and e[3]["req"] == reqs[0].seq]
+    assert len(retired) == 1
+    assert spans[0][2] <= retired[0][1] and retired[0][2] <= spans[1][1]
+    # the instant of each first token, on the clock ttft_s is taken from
+    first = [r.enqueued_at + r.ttft_s for r in reqs]
+    assert first == sorted(first)
+    later = sum(e[2] - e[1] for e in spans[1:]) / 1e9
+    assert first[-1] - first[0] >= later > 0
 
 
 def test_chunked_prefill_counts_its_queue_wait_and_dispatch_time(scope):

@@ -2535,8 +2535,8 @@ def main(argv=None):
     ap.add_argument("--decode-block", type=int, default=16,
                     help="decode steps per dispatch (--decode)")
     ap.add_argument("--prefill-batch", type=int, default=8,
-                    help="same-bucket prompts prefilled per dispatch "
-                         "(--decode)")
+                    help="same-bucket prompts one admission pass takes "
+                         "together, each its own dispatch (--decode)")
     ap.add_argument("--spec", action="store_true",
                     help="speculative engine mode, perfect draft "
                          "(--decode)")
