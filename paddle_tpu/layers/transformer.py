@@ -747,6 +747,7 @@ def block_paged_op(kind, feeds, pools, *, params, lead_params, attrs,
     layers' and the leading dense layers' parameters, named
     ``{name}.{suffix}`` / ``{lead_name}.{suffix}``. Returns (tokens,
     pools_out, logits, picks, stats)."""
+    from ..ops.transformer_ops import PAGED_STATS
     helper = LayerHelper("block_paged_" + kind, name=name)
     ninit = init_mod.Normal(0.0, 0.02)
 
@@ -773,7 +774,8 @@ def block_paged_op(kind, feeds, pools, *, params, lead_params, attrs,
                                                     shape=shape)
     logits = helper.create_variable_for_type_inference(
         "float32", shape=shape + [vocab_size])
-    stats = helper.create_variable_for_type_inference("int32", shape=[5])
+    stats = helper.create_variable_for_type_inference(
+        "int32", shape=[len(PAGED_STATS)])
     picks = helper.create_variable_for_type_inference(
         "int32", shape=shape + [params["MoeRouter"][1][0],
                                 int(attrs["moe_top_k"])])

@@ -1,19 +1,30 @@
-"""Decoder-only models of the latent-attention / routed-experts /
-hyper-connection kind (Xing4.0-29B-A4B's block), for serving.
+"""Decoder-only models whose block is latent attention with routed
+experts, for serving: Xing4.0-29B-A4B's block (hyper-connection
+residuals, every expert held) and DeepSeek-V3's (plain residuals, a
+group-limited router, and a share of the experts) are both values of
+``LatentMoEConfig``.
 
 The block is ops/transformer_ops.py ``block_forward`` at these kinds:
 multi-head latent attention whose cache holds one ``[kv_rank +
 rope_dim]`` entry a token a layer (DeepSeek-V2, arXiv:2405.04434) with
 YaRN-scaled rotary positions; sigmoid-scored routed experts with a
-selection bias and a shared expert, drop-free (ops/moe.py); the residual
-stream widened to ``n_streams`` and mixed by manifold-constrained
-hyper-connections (arXiv:2512.24880). The first ``n_dense_layers`` have
-a dense SwiGLU in the experts' place.
+selection bias, optionally group-limited (DeepSeek-V3, arXiv:2412.19437),
+and a shared expert, drop-free (ops/moe.py); the residual path either
+plain (``x + F(norm(x))``) or widened to ``n_streams`` and mixed by
+manifold-constrained hyper-connections (arXiv:2512.24880). The first
+``n_dense_layers`` have a dense SwiGLU in the experts' place.
+
+A model may be ONE CHIP'S SHARE of an expert-parallel deployment: the
+router stays ``router_width`` wide and every token picks ``moe_top_k``
+of all of them, while the chip holds ``n_experts`` of them from
+``experts_first`` on and computes their part of the sum. What the absent
+experts would add is left out (no exchange is run, nothing stands in for
+the other chips), and that partial result goes on to the next layer.
 
 Serving only: ``build_paged_programs`` gives DecodeEngine the prefill,
 chunk and decode programs; there is no training graph, no fused
 generator and no speculative form (the published multi-token-prediction
-layer is left out).
+layers are left out).
 """
 from dataclasses import dataclass
 
@@ -22,7 +33,7 @@ from ..layers import transformer as tfl
 from ..ops.transformer_ops import PAGED_STATS, yarn_inv_freq, yarn_mscale
 from .llama import PagedDecodePrograms, prefill_buckets_reached
 
-__all__ = ["LatentMoEConfig", "LATENT_MOE_TINY"]
+__all__ = ["LatentMoEConfig", "LATENT_MOE_TINY", "LATENT_SHARE_TINY"]
 
 
 @dataclass
@@ -40,10 +51,15 @@ class LatentMoEConfig:
     v_dim: int = 128
     ffn_hidden: int = 9216           # the leading dense layers' SwiGLU
     n_experts: int = 64              # routed experts held
+    router_width: int = None         # experts routed over (None: those held)
+    experts_first: int = 0           # the first expert held
+    n_group: int = 1                 # group-limited selection (moe_route)
+    topk_group: int = 1
     moe_top_k: int = 4
     expert_hidden: int = 1024
     n_shared: int = 1
     route_scale: float = 2.0
+    residual: str = "mhc"            # or "plain": one stream, no Hc*
     n_streams: int = 4               # hc_mult
     sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
@@ -56,6 +72,22 @@ class LatentMoEConfig:
     rope_beta_slow: float = 1.0
     rope_mscale_all_dim: float = 1.0
     dtype: str = "bfloat16"
+
+    def __post_init__(self):
+        if self.router_width is None:
+            self.router_width = self.n_experts
+        if not 0 <= self.experts_first \
+                <= self.router_width - self.n_experts:
+            raise ValueError(
+                f"{self.name}: experts {self.experts_first} to "
+                f"{self.experts_first + self.n_experts - 1} are not "
+                f"among a router's {self.router_width}")
+        if self.router_width % self.n_group \
+                or not 1 <= self.topk_group <= self.n_group:
+            raise ValueError(
+                f"{self.name}: {self.router_width} experts do not make "
+                f"{self.n_group} equal groups of which "
+                f"{self.topk_group} are kept")
 
     @property
     def entry_dim(self):
@@ -73,9 +105,12 @@ class LatentMoEConfig:
     def block_attrs(self, page_size):
         return {
             "n_heads": self.n_heads, "epsilon": self.norm_eps,
-            "attention": "latent", "ffn": "routed", "residual": "mhc",
+            "attention": "latent", "ffn": "routed",
+            "residual": self.residual,
             "moe_top_k": self.moe_top_k, "scoring": "sigmoid",
-            "route_scale": self.route_scale, "kv_rank": self.kv_rank,
+            "route_scale": self.route_scale, "n_group": self.n_group,
+            "topk_group": self.topk_group,
+            "experts_first": self.experts_first, "kv_rank": self.kv_rank,
             "rope_dim": self.rope_dim, "nope_dim": self.nope_dim,
             "v_dim": self.v_dim,
             "rope_inv_freq": [float(x) for x in yarn_inv_freq(
@@ -90,9 +125,10 @@ class LatentMoEConfig:
 
     def layer_params(self, n_layers, routed):
         """slot -> (suffix, shape, dtype) of ``n_layers`` stacked layers
-        with a routed (else dense) feed-forward. The router, its bias and
-        the hyper-connection coefficients are float32 whatever ``dtype``
-        is."""
+        with a routed (else dense) feed-forward. The router (as wide as
+        ``router_width``, beside ``n_experts`` held experts), its bias
+        and the hyper-connection coefficients (``mhc`` alone) are float32
+        whatever ``dtype`` is."""
         L, D, H, n = n_layers, self.dim, self.n_heads, self.n_streams
         dt, mix = self.dtype, 2 * n + n * n
         out = {
@@ -107,7 +143,7 @@ class LatentMoEConfig:
             "Wkvb": ("wkvb", [L, self.kv_rank,
                               H * (self.nope_dim + self.v_dim)], dt),
             "Wo": ("wo", [L, H * self.v_dim, D], dt)}
-        for which in ("Attn", "Mlp"):
+        for which in ("Attn", "Mlp") if self.residual == "mhc" else ():
             low = which.lower()
             out["Hc" + which + "Phi"] = (f"hc_{low}_phi", [L, n * D, mix],
                                          "float32")
@@ -123,8 +159,9 @@ class LatentMoEConfig:
             return out
         E, F, S = self.n_experts, self.expert_hidden, \
             self.n_shared * self.expert_hidden
-        out.update(MoeRouter=("moe_router", [L, D, E], "float32"),
-                   MoeBias=("moe_bias", [L, E], "float32"),
+        R = self.router_width
+        out.update(MoeRouter=("moe_router", [L, D, R], "float32"),
+                   MoeBias=("moe_bias", [L, R], "float32"),
                    MoeWGate=("moe_w_gate", [L, E, D, F], dt),
                    MoeWUp=("moe_w_up", [L, E, D, F], dt),
                    MoeWDown=("moe_w_down", [L, E, F, D], dt))
@@ -237,3 +274,13 @@ LATENT_MOE_TINY = LatentMoEConfig(
     rope_dim=8, v_dim=8, ffn_hidden=64, n_experts=8, moe_top_k=2,
     expert_hidden=16, n_shared=1, n_streams=4, sinkhorn_iters=20,
     rope_original_max=16, dtype="float32")
+
+# one chip's share of a layer split over four: a 16-wide router in 4 groups
+# of which 2 are kept, 3 experts a token, experts 4-7 held; plain residuals
+LATENT_SHARE_TINY = LatentMoEConfig(
+    name="latent-share-tiny", vocab_size=96, dim=32, n_layers=3,
+    n_dense_layers=1, n_heads=4, q_rank=24, kv_rank=16, nope_dim=8,
+    rope_dim=8, v_dim=8, ffn_hidden=64, n_experts=4, router_width=16,
+    experts_first=4, n_group=4, topk_group=2, moe_top_k=3,
+    expert_hidden=16, n_shared=1, residual="plain", n_streams=1,
+    sinkhorn_iters=0, rope_original_max=16, dtype="float32")

@@ -97,16 +97,23 @@ def moe_apply(xt, wg, w_gate, w_up, w_down, top_k, cap_factor):
     return out, aux
 
 
-def moe_route(xt, wg, top_k, scoring="softmax", bias=None, scale=1.0):
-    """Exact top-k routing of flat tokens xt [T, D], in float32 whatever
-    the model's dtype (the products at "highest" precision: a bf16 pass
-    over the router reorders near-ties). Returns (experts [T, K] int32,
-    gates [T, K] float32).
+def moe_route(xt, wg, top_k, scoring="softmax", bias=None, scale=1.0,
+              n_group=1, topk_group=1):
+    """Exact top-k routing of flat tokens xt [T, D] over the router's
+    whole width E (``wg`` [D, E], however many of those experts are held
+    here), in float32 whatever the model's dtype (the products at
+    "highest" precision: a bf16 pass over the router reorders near-ties).
+    Returns (experts [T, K] int32, gates [T, K] float32).
 
     ``softmax``: the K largest probabilities, renormalised.
     ``sigmoid``: scores ``sigmoid(x Wg)``; the K largest of
     ``scores + bias`` are picked (``bias`` steers selection only), their
-    own scores are renormalised and multiplied by ``scale``."""
+    own scores are renormalised and multiplied by ``scale``. With
+    ``n_group`` > 1 the selection is group-limited (DeepSeek-V3,
+    arXiv:2412.19437): the experts are ``n_group`` equal runs, a group
+    scores the sum of its two largest ``scores + bias``, the
+    ``topk_group`` best groups are kept and the others' selection scores
+    set to 0 before the K largest are taken."""
     logits = jnp.dot(xt.astype(jnp.float32), wg.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     if scoring == "softmax":
@@ -117,23 +124,33 @@ def moe_route(xt, wg, top_k, scoring="softmax", bias=None, scale=1.0):
         raise ValueError(f"unknown router scoring {scoring!r}")
     scores = jax.nn.sigmoid(logits)
     picked = scores if bias is None else scores + bias.astype(jnp.float32)
+    if n_group > 1:
+        t, e = picked.shape
+        grouped = picked.reshape(t, n_group, e // n_group)
+        best = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        kept = jax.nn.one_hot(jax.lax.top_k(best, topk_group)[1], n_group,
+                              dtype=jnp.bool_).any(axis=1)
+        picked = jnp.where(kept[..., None], grouped, 0.0).reshape(t, e)
     idx = jax.lax.top_k(picked, top_k)[1]
     gates = jnp.take_along_axis(scores, idx, axis=-1)
     return idx, scale * gates / (
         jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
 
 
-def moe_load(idx, n_experts, valid=None):
-    """Tokens each expert was given, [E] int32; ``valid`` [T] bool leaves
-    padding and inactive rows out of the count (they are still computed:
-    shapes are static)."""
-    hits = jax.nn.one_hot(idx, n_experts, dtype=jnp.int32).sum(axis=1)
+def moe_load(idx, n_experts, valid=None, first=0):
+    """Tokens each of the ``n_experts`` experts from ``first`` on was
+    given, [n_experts] int32; picks outside that range count nowhere.
+    ``valid`` [T] bool leaves padding and inactive rows out of the count
+    (they are still computed: shapes are static)."""
+    hits = jax.nn.one_hot(idx - first, n_experts,
+                          dtype=jnp.int32).sum(axis=1)
     if valid is not None:
         hits = hits * valid.astype(jnp.int32)[:, None]
     return hits.sum(axis=0)
 
 
-def moe_apply_sorted(xt, idx, gates, w_gate, w_up, w_down, layer=None):
+def moe_apply_sorted(xt, idx, gates, w_gate, w_up, w_down, layer=None,
+                     held=None):
     """The drop-free expert layer: the T x K token-expert pairs sorted by
     expert, one grouped matmul a projection over the experts held (the
     leading dimension of the weights; ``jax.lax.ragged_dot``: on the TPU
@@ -147,27 +164,69 @@ def moe_apply_sorted(xt, idx, gates, w_gate, w_up, w_down, layer=None):
     ``layer``'s are non-empty: slicing the layer out first would copy all
     its experts on every call (3.2 ms against 0.4 for the matmul itself
     at 64 rows: PERF.md section 6, PR 27), and empty groups cost
-    nothing."""
+    nothing.
+
+    With ``held`` = (first, width) the weights are one chip's share of an
+    expert-parallel layer: ``idx`` runs over a router ``width`` wide and
+    the E experts held are ``first .. first + E - 1``. A pair routed to
+    an absent expert sorts behind the last held group and is neither
+    gathered nor multiplied: what the absent experts would add is
+    another chip's to compute, and is left out here. Only the leading
+    rows of the sorted order go through the matmuls: four times an even
+    router's share of the pairs, and where the router sends this chip
+    more than that, all of them, so no pair to a held expert is ever
+    dropped."""
     t, k = idx.shape
     e = w_gate.shape[-3]
-    flat = idx.reshape(t * k)
-    order = jnp.argsort(flat, stable=True)
-    sizes = moe_load(idx, e)
+    local = idx
+    if held is not None:
+        first, width = held
+        local = jnp.where((idx >= first) & (idx < first + e),
+                          idx - first, e)
+    order = jnp.argsort(local.reshape(t * k), stable=True)
+    sizes = moe_load(local, e)
+    n_held = jnp.sum(sizes)
     if layer is not None:
         n_layers = w_gate.shape[0]
         sizes = jax.lax.dynamic_update_slice(
             jnp.zeros((n_layers * e,), jnp.int32), sizes, (layer * e,))
         w_gate, w_up, w_down = (w.reshape((n_layers * e,) + w.shape[2:])
                                 for w in (w_gate, w_up, w_down))
-    xs = xt[order // k]
     cdt = xt.dtype
-    gate_h = jax.lax.ragged_dot(xs, w_gate, sizes)
-    up_h = jax.lax.ragged_dot(xs, w_up, sizes)
-    h = ((gate_h * jax.nn.sigmoid(gate_h)) * up_h).astype(cdt)
-    ys = jax.lax.ragged_dot(h, w_down, sizes,
-                            preferred_element_type=jnp.float32)
-    pairs = ys[jnp.argsort(order)].reshape(t, k, -1)
-    return jnp.sum(pairs * gates[..., None], axis=1).astype(cdt)
+
+    def grouped(pairs):
+        """The sorted pairs ``pairs`` through their experts, float32."""
+        xs = xt[pairs // k]
+        gate_h = jax.lax.ragged_dot(xs, w_gate, sizes)
+        up_h = jax.lax.ragged_dot(xs, w_up, sizes)
+        h = ((gate_h * jax.nn.sigmoid(gate_h)) * up_h).astype(cdt)
+        return jax.lax.ragged_dot(h, w_down, sizes,
+                                  preferred_element_type=jnp.float32)
+
+    if held is None:
+        pairs = grouped(order)[jnp.argsort(order)].reshape(t, k, -1)
+        return jnp.sum(pairs * gates[..., None], axis=1).astype(cdt)
+
+    def leading(rows):
+        """The first ``rows`` sorted pairs, gated and summed by token; a
+        row behind the last held group counts for nothing (the kernel
+        leaves it unwritten). The sum is one [T, rows] x [rows, D]
+        product: a scatter-add goes row by row on the chip, half a
+        microsecond each (PERF.md section 6, PR 31)."""
+        pairs = order[:rows]
+        live = jnp.arange(rows) < n_held
+        ys = jnp.where(live[:, None], grouped(pairs), 0.0)
+        g = jnp.where(live, gates.reshape(t * k)[pairs], 0.0)
+        to_token = jnp.where(
+            (pairs // k)[None] == jnp.arange(t)[:, None], g[None], 0.0)
+        return jnp.dot(to_token, ys,
+                       precision=jax.lax.Precision.HIGHEST).astype(cdt)
+
+    few = -(-4 * t * k * e // width // 8) * 8
+    if few >= t * k:
+        return leading(t * k)
+    return jax.lax.cond(n_held <= few, lambda: leading(few),
+                        lambda: leading(t * k))
 
 
 def moe_apply_no_drop(xt, wg, w_gate, w_up, w_down, top_k):

@@ -252,22 +252,31 @@ class BlockKinds:
     of attention (``gqa`` | ``latent``), of feed-forward (``swiglu`` |
     ``routed``, the latter with a shared expert where the parameters
     hold one) and of residual path (``plain`` | ``mhc``), with the sizes
-    each kind needs. ``block_forward`` is the one definition that reads
-    it; training, the generator and the paged programs derive from that.
+    each kind needs. Any combination is a block: Xing4.0 is latent +
+    routed + mhc, DeepSeek-V3 latent + routed + plain, Llama and Mistral
+    gqa + swiglu + plain. ``block_forward`` is the one definition that
+    reads it; training, the generator and the paged programs derive from
+    that.
 
     ``latent`` (multi-head latent attention, DeepSeek-V2,
     arXiv:2405.04434): ``kv_rank`` normalised latent + ``rope_dim``
     rotated key a token are the cache's entry; ``nope_dim``/``v_dim``
     are a head's expanded key and value widths; ``rope_inv_freq`` and
-    ``softmax_scale`` carry YaRN. ``routed``: ``scoring`` and
-    ``route_scale`` as ops/moe.py moe_route. ``mhc`` (manifold-
-    constrained hyper-connections, arXiv:2512.24880): ``n_streams``
-    residual streams mixed by per-token matrices, the stream-to-stream
-    one made doubly stochastic by ``sinkhorn_iters`` rounds."""
+    ``softmax_scale`` carry YaRN. ``routed``: ``scoring``,
+    ``route_scale``, ``n_group`` and ``topk_group`` as ops/moe.py
+    moe_route, over the router's whole width (its parameter's); the
+    experts held are the expert parameters' leading dimension, from
+    ``experts_first`` on: fewer than the router's width where the block
+    is one chip's share of an expert-parallel layer (moe_apply_sorted).
+    ``mhc`` (manifold-constrained hyper-connections, arXiv:2512.24880):
+    ``n_streams`` residual streams mixed by per-token matrices, the
+    stream-to-stream one made doubly stochastic by ``sinkhorn_iters``
+    rounds; ``plain`` has one stream and no such parameters."""
 
     def __init__(self, *, n_heads, n_kv=None, base=10000.0, eps=1e-6,
                  attention="gqa", ffn="swiglu", residual="plain",
                  moe_top_k=2, scoring="softmax", route_scale=1.0,
+                 n_group=1, topk_group=1, experts_first=0,
                  kv_rank=0, rope_dim=0, nope_dim=0, v_dim=0,
                  rope_inv_freq=None, softmax_scale=None, n_streams=1,
                  sinkhorn_iters=0, hc_eps=1e-6, hc_clamp=(-30.0, 30.0)):
@@ -281,6 +290,8 @@ class BlockKinds:
         self.attention, self.ffn, self.residual = attention, ffn, residual
         self.moe_top_k, self.scoring = moe_top_k, scoring
         self.route_scale = route_scale
+        self.n_group, self.topk_group = n_group, topk_group
+        self.experts_first = experts_first
         self.kv_rank, self.rope_dim = kv_rank, rope_dim
         self.nope_dim, self.v_dim = nope_dim, v_dim
         self.rope_inv_freq = rope_inv_freq
@@ -336,8 +347,9 @@ def _swiglu_ffn(kinds, p, u, valid):
 
 def _routed_ffn(kinds, p, u, valid):
     """Drop-free routed experts (ops/moe.py), plus the shared expert
-    where ``p`` holds one. Also returns (the call's load, [E] int32 over
-    the ``valid`` tokens; the experts picked, [b, t, K])."""
+    where ``p`` holds one. Also returns (the held experts' load in this
+    call, [E] int32 over the ``valid`` tokens; the experts picked over
+    the router's whole width, [b, t, K])."""
     from . import moe
     b, t, d = u.shape
     xt = u.reshape(b * t, d)
@@ -347,18 +359,23 @@ def _routed_ffn(kinds, p, u, valid):
             {"gate": p["MoeWGateScale"], "up": p["MoeWUpScale"],
              "down": p["MoeWDownScale"]},
             kinds.moe_top_k).reshape(b, t, d), None
+    n_held, width = p["MoeWGate"].shape[-3], p["MoeRouter"].shape[-1]
+    # a share of the layer: fewer experts than the router is wide
+    held = None if n_held == width else (kinds.experts_first, width)
     with jax.named_scope("moe/route"):
         idx, gates = moe.moe_route(
             xt, p["MoeRouter"], kinds.moe_top_k, kinds.scoring,
-            p.get("MoeBias"), kinds.route_scale)
-        load = moe.moe_load(idx, p["MoeWGate"].shape[-3],
-                            None if valid is None else valid.reshape(-1))
+            p.get("MoeBias"), kinds.route_scale, kinds.n_group,
+            kinds.topk_group)
+        load = moe.moe_load(idx, n_held,
+                            None if valid is None else valid.reshape(-1),
+                            kinds.experts_first)
     with jax.named_scope("moe/experts"):
         # p["ExpertsOf"]: the expert stacks are the whole model's and
         # this is the layer to take (_PagedRunner._stack_forward)
         out = moe.moe_apply_sorted(xt, idx, gates, p["MoeWGate"],
                                    p["MoeWUp"], p["MoeWDown"],
-                                   layer=p.get("ExpertsOf"))
+                                   layer=p.get("ExpertsOf"), held=held)
     if p.get("ShWGate") is not None:
         with jax.named_scope("moe/shared"):
             out = out + _swiglu(p, xt, "ShWGate", "ShWUp", "ShWDown")
@@ -1139,17 +1156,23 @@ def _llama_spec_generate(ctx, ins, attrs):
 
 # every name the engine's counters take from a paged program's ``Stats``
 # output, in its order (serving/decode_engine.py ticks them; docs/
-# SERVING.md, "Metrics reference"). The last three are counted by decode
-# dispatches alone: a prefill touches every expert and would hide what a
-# step must read.
+# SERVING.md, "Metrics reference"). Assignments are counted over the
+# router's whole width, everything else over the experts held, which are
+# fewer where the model is one chip's share of an expert-parallel layer.
+# The decode ones are counted by decode dispatches alone: a prefill
+# touches every expert and would hide what a step must read.
 PAGED_STATS = ("moe_assignments_total", "moe_max_load_total",
                "moe_decode_expert_calls_total",
                "moe_decode_experts_touched_total",
-               "latent_tokens_read_total")
+               "latent_tokens_read_total", "moe_held_assignments_total")
 
 # keys a prefill window expands at a time (latent attention): scores of
-# [heads, window, _KEY_BLOCK] float32, never of the whole cache
+# [heads, window, keys] float32, never of the whole cache. At most
+# _KEY_BLOCK keys, and fewer where heads x window is so large that one
+# score pass would pass _SCORE_BYTES (128 heads over a 1,024-token
+# window: 1,024 keys; 32 heads over 2,048: all 2,048)
 _KEY_BLOCK = 2048
+_SCORE_BYTES = 2 ** 29
 
 
 class _PagedRunner:
@@ -1213,6 +1236,9 @@ class _PagedRunner:
         self.lead = lead
         self.valid = None       # [B, T] bool: the tokens Stats counts
         self.pick_at = None     # [B]: the window position Picks reports
+        self.seen = None        # positions a prefill window can see at
+                                # most, where known: latent attention
+                                # reads no page beyond them
         self._loads = []        # the last forward's routed loads [n, E]
         self.picks = None       # and its picks at pick_at, [n, B, K]
         if self.kinds.attention == "gqa":
@@ -1393,10 +1419,14 @@ class _PagedRunner:
         kmax = table.shape[1] * ps
         q_pos = pos0[:, None] + jnp.arange(t_len, dtype=jnp.int32)[None]
         # latent attention reads its pages a block of keys at a time
-        ppb = max(1, min(_KEY_BLOCK // ps, table.shape[1]))
-        n_blocks = -(-table.shape[1] // ppb)
-        blocks = jnp.pad(table, ((0, 0),
-                                 (0, n_blocks * ppb - table.shape[1])))
+        n_read = table.shape[1] if self.seen is None \
+            else min(table.shape[1], -(-self.seen // ps))
+        keys = min(_KEY_BLOCK,
+                   _SCORE_BYTES // (4 * self.n_heads * b * t_len))
+        ppb = max(1, min(keys // ps, n_read))
+        n_blocks = -(-n_read // ppb)
+        blocks = jnp.pad(table[:, :n_read],
+                         ((0, 0), (0, n_blocks * ppb - n_read)))
 
         def attend_write(p, q, entries, pools, lyr):
             pg = jnp.take_along_axis(table, q_pos // ps, axis=1)
@@ -1485,16 +1515,20 @@ class _PagedRunner:
                     "W", cdt=jnp.float32)
 
     def stats(self, decode, positions=None):
-        """The last forward's counters as PAGED_STATS orders them, int32
-        [5]: token-expert pairs and the fullest expert's tokens, summed
-        over its routed layers; for a decode step also its expert-layer
-        calls x experts held, the experts among them that a token
-        reached, and the cache positions its active rows attended."""
+        """The last forward's counters as PAGED_STATS orders them, int32:
+        token-expert pairs over the router's whole width, the fullest
+        held expert's tokens and the pairs that fell on held experts,
+        each summed over its routed layers; for a decode step also its
+        expert-layer calls x experts held, the experts among them that a
+        token reached, and the cache positions its active rows
+        attended."""
         out = [jnp.int32(0)] * len(PAGED_STATS)
         if len(self._loads):
-            loads = self._loads                           # [layers, E]
-            out[0] = jnp.sum(loads)
+            loads = self._loads                      # [layers, E held]
+            out[0] = (jnp.sum(self.valid) * self.kinds.moe_top_k
+                      * loads.shape[0])
             out[1] = jnp.sum(jnp.max(loads, axis=-1))
+            out[5] = jnp.sum(loads)
             if decode:
                 out[2] = jnp.int32(loads.shape[0] * loads.shape[1])
                 out[3] = jnp.sum(loads > 0)
@@ -1539,7 +1573,8 @@ def _llama_runner(ins, attrs):
 
 def _paged_prefill(run, tokens, lens, offsets, table, pools):
     """A window of each row's prompt into its pages: the body of every
-    paged prefill op. Returns (next token [B], its float32 logits
+    paged prefill op; ``offsets`` None: the window is the whole prompt,
+    from position 0. Returns (next token [B], its float32 logits
     [B, V], the pools); ``run.picks`` then holds the routed layers'
     picks at each row's last real token."""
     b, t = tokens.shape
@@ -1547,6 +1582,8 @@ def _paged_prefill(run, tokens, lens, offsets, table, pools):
     # what Stats counts: real tokens of rows that own a real first page
     run.valid = (jnp.arange(t, dtype=jnp.int32)[None] < lens[:, None]) \
         & (table[:, :1] > 0)
+    if offsets is None:         # and sees its own window, no further
+        run.seen, offsets = t, jnp.zeros((b,), jnp.int32)
     h, *pools = run.forward(run.embed(tokens), *pools, table, offsets, t)
     logits = run.logits_of(h[jnp.arange(b), lens - 1])
     return jnp.argmax(logits, axis=-1).astype(tokens.dtype), logits, pools
@@ -1599,9 +1636,8 @@ def _llama_paged_prefill(ctx, ins, attrs):
     updated pools."""
     tokens = ins["Tokens"][0]
     nxt, _, (kp, vp) = _paged_prefill(
-        _llama_runner(ins, attrs), tokens, ins["Lens"][0],
-        jnp.zeros((tokens.shape[0],), jnp.int32), ins["Table"][0],
-        (ins["KPages"][0], ins["VPages"][0]))
+        _llama_runner(ins, attrs), tokens, ins["Lens"][0], None,
+        ins["Table"][0], (ins["KPages"][0], ins["VPages"][0]))
     return {"NextTok": [nxt], "KPagesOut": [kp], "VPagesOut": [vp]}
 
 
@@ -1681,7 +1717,8 @@ def _block_runner(ins, attrs):
         attention=attrs["attention"], ffn=attrs["ffn"],
         residual=attrs["residual"], moe_top_k=attrs["moe_top_k"],
         scoring=attrs["scoring"], route_scale=attrs["route_scale"],
-        kv_rank=attrs["kv_rank"], rope_dim=attrs["rope_dim"],
+        n_group=attrs["n_group"], topk_group=attrs["topk_group"],
+        experts_first=attrs["experts_first"], kv_rank=attrs["kv_rank"], rope_dim=attrs["rope_dim"],
         nope_dim=attrs["nope_dim"], v_dim=attrs["v_dim"],
         rope_inv_freq=np.asarray(attrs["rope_inv_freq"], np.float32),
         softmax_scale=attrs["softmax_scale"],
@@ -1715,9 +1752,7 @@ def _block_paged_prefill(ctx, ins, attrs):
     run = _block_runner(ins, attrs)
     tokens = ins["Tokens"][0]
     return _block_prefill_outputs(run, *_paged_prefill(
-        run, tokens, ins["Lens"][0],
-        jnp.zeros((tokens.shape[0],), jnp.int32), ins["Table"][0],
-        ins["Pools"]))
+        run, tokens, ins["Lens"][0], None, ins["Table"][0], ins["Pools"]))
 
 
 @register_op("block_paged_prefill_chunk")

@@ -108,13 +108,22 @@ _DECODE_COUNTERS = (
     # summed on the device by the programs of a model with routed
     # experts or a latent cache, and returned beside a dispatch's tokens
     # (ops/transformer_ops.py PAGED_STATS); 0 for a dense GQA model.
-    # Token-expert pairs and the fullest expert's tokens, per routed-
-    # layer call, over every dispatch; over decode dispatches alone:
-    # routed-layer calls x experts held, the experts of those that a
-    # token reached, and the cache positions the active rows attended.
+    # Token-expert pairs over the router's whole width, those of them
+    # that fell on the experts held here (all, unless the model is one
+    # chip's share of an expert-parallel layer) and the fullest held
+    # expert's tokens, per routed-layer call, over every dispatch; over
+    # decode dispatches alone: routed-layer calls x experts held, the
+    # experts of those that a token reached, and the cache positions the
+    # active rows attended.
     "moe_assignments_total", "moe_max_load_total",
     "moe_decode_expert_calls_total", "moe_decode_experts_touched_total",
-    "latent_tokens_read_total")
+    "latent_tokens_read_total", "moe_held_assignments_total")
+
+# how long after a program's end the worker keeps polling before it reads
+# the tokens and counters whose host copies set out with the program: the
+# copies were measured to land 0.23-0.3 ms behind it (PERF.md section 6,
+# PR 31)
+_HOST_COPY_S = 4e-4
 
 # priority rank -> the per-class shed counter it lands in
 _SHED_BY_RANK = {rank: f"shed_{name}_total"
@@ -888,6 +897,16 @@ class DecodeEngine:
             b["program"], feed=self._bundle_feed(b, (*arrays, *pools)),
             fetch_list=b["fetch"], mode="test", return_numpy=False,
             scope=self.scope)
+        extras = b.get("extras", ())
+        n_head = len(outs) - len(pools) - len(extras)
+        kept = dict(zip(extras, outs[n_head + len(pools):]))
+        # what the host reads back, the tokens and the counters, sets out
+        # for the host behind the program, not when the worker asks: a
+        # fetch asked for afterwards is a blocking wait of its own, 0.5 ms
+        # each in a process that wakes on time (PERF.md section 6, PR 31)
+        for x in list(outs[:n_head]) + [kept[k] for k in kept
+                                        if k == "stats"]:
+            x.copy_to_host_async()
         # poll for the dispatch's end, do not block on it: a worker
         # blocked in the fetch is woken 2.5-3 ms late on the chip's
         # host in most processes (the "slow mode" of PERF.md section 2:
@@ -896,15 +915,17 @@ class DecodeEngine:
         # sleep(0) hands the interpreter to whichever thread wants it.
         while not outs[0].is_ready():
             time.sleep(0)
-        extras = b.get("extras", ())
-        n_head = len(outs) - len(pools) - len(extras)
+        # the host copies land some 0.3 ms behind the program; to wait
+        # for them inside np.asarray would be a blocking wait again
+        landed = time.perf_counter() + _HOST_COPY_S
+        while time.perf_counter() < landed:
+            time.sleep(0)
         back = list(outs[n_head:n_head + len(pools)])
         if which != "draft":
             self._pools, back = (back[:len(self._pools)],
                                  back[len(self._pools):])
         if which != "target":
             self._draft_pools = back
-        kept = dict(zip(extras, outs[n_head + len(pools):]))
         if "stats" in kept:
             self.metrics.incr_many(dict(zip(
                 self.programs.stats,
