@@ -240,12 +240,22 @@ class Executor:
 
     # ------------------------------------------------------------------
     def run(self, program=None, feed=None, fetch_list=None, scope=None,
-            return_numpy=True, mode=None, repeats=1, validate=None):
+            return_numpy=True, mode=None, repeats=1, validate=None,
+            donate_feeds=()):
         """``repeats`` > 1 runs that many train steps in ONE device
         dispatch on the same feed (rng advances per sub-step exactly as
         separate calls would); fetches are the LAST sub-step's. Not
         compatible with NaN-guard mode (the guard reports per
         dispatch).
+
+        What a dispatch donates: the persistables the program writes
+        (unless ``donate_state=False``), and the feeds ``donate_feeds``
+        names, which the caller gives up: pass those as device arrays
+        and hold them no longer, the call deletes each one XLA could
+        alias to a fetch of its shape (the first named to the first
+        fetched, so name them in fetch order) and the fetch is the same
+        buffer written in place. No other feed is donated, and the
+        persistables a program only reads (the weights) never are.
 
         ``validate`` gates the static verifier (analysis/) run once per
         newly-compiled program, BEFORE lowering: None reads
@@ -257,10 +267,11 @@ class Executor:
         with record_event("pt:executor/run", program=program.uid,
                           step=self._step + 1, repeats=repeats):
             return self._run(program, feed, fetch_list, scope,
-                             return_numpy, mode, repeats, validate)
+                             return_numpy, mode, repeats, validate,
+                             tuple(donate_feeds))
 
     def _run(self, program, feed, fetch_list, scope, return_numpy, mode,
-             repeats, validate):
+             repeats, validate, donate_feeds):
         if not 1 <= repeats <= 32:
             # an unroll, deliberately: a lax.scan over sub-steps would
             # keep the executable O(1) in k at the price of a while-loop
@@ -290,7 +301,7 @@ class Executor:
             self._prepare(program, feed, fetch_list, scope, mode)
 
         key = (program.uid, program.version, mode, tuple(fetch_names),
-               repeats)
+               repeats, donate_feeds)
         fn = self._cache.get(key)
         if fn is None:
             # evict executables for older versions of this program so a
@@ -299,19 +310,17 @@ class Executor:
                      if k[0] == program.uid and k[1] != program.version]
             for k in stale:
                 del self._cache[k]
-            step_fn = lower_program(program, fetch_names, mode)
-            fn = jax.jit(make_stepped(step_fn, repeats),
-                         donate_argnums=(0,) if self._donate_state
-                         else ())
-            fn.step_fn = step_fn     # keeps NaN-guard labels reachable
+            fn = self._jit(program, fetch_names, mode, repeats,
+                           donate_feeds, self._donate_state)
             self._cache[key] = fn
 
         self._step += 1
         first_step = self._step
         self._step += repeats - 1
 
-        args = (state_rw, state_ro, feed_vals,
-                step_arg(first_step, program.random_seed))
+        args = self._jit_args(state_rw, state_ro, feed_vals,
+                              step_arg(first_step, program.random_seed),
+                              donate_feeds)
 
         def _dispatch():
             # deterministic transient-fault point (resilience/
@@ -352,6 +361,42 @@ class Executor:
             # data/lengths leaves while keeping the container
             fetches = jax.tree_util.tree_map(np.asarray, fetches)
         return fetches
+
+    # ------------------------------------------------------------------
+    @staticmethod
+    def _jit(program, fetch_names, mode, repeats, donate_feeds,
+             donate_state):
+        """The jitted step of ``program``: (written state, read state,
+        feeds, [step, seed]) and, where the caller gives feeds up, a
+        fifth argument that holds those alone, in the order named, so
+        that they are donated and the feeds beside them are not."""
+        step_fn = lower_program(program, fetch_names, mode)
+        stepped = make_stepped(step_fn, repeats)
+        donate = (0,) if donate_state else ()
+        if donate_feeds:
+            kept_feeds = stepped
+
+            def stepped(rw, ro, feed, step_seed, given):
+                return kept_feeds(
+                    rw, ro, dict(feed, **dict(zip(donate_feeds, given))),
+                    step_seed)
+            donate += (4,)
+        fn = jax.jit(stepped, donate_argnums=donate)
+        fn.step_fn = step_fn         # keeps NaN-guard labels reachable
+        return fn
+
+    @staticmethod
+    def _jit_args(state_rw, state_ro, feed_vals, step_seed, donate_feeds):
+        """``_jit``'s arguments: the feeds given up leave ``feed_vals``
+        for a list of their own."""
+        if not donate_feeds:
+            return state_rw, state_ro, feed_vals, step_seed
+        missing = [n for n in donate_feeds if n not in feed_vals]
+        if missing:
+            raise KeyError(f"donate_feeds names {missing}, which the "
+                           f"feed does not hold")
+        given = [feed_vals.pop(n) for n in donate_feeds]
+        return state_rw, state_ro, feed_vals, step_seed, given
 
     # ------------------------------------------------------------------
     def _maybe_optimize(self, program, fetch_list):
@@ -479,12 +524,14 @@ class Executor:
     # ------------------------------------------------------------------
     def compiled_stats(self, program=None, feed=None, fetch_list=None,
                        scope=None, mode=None, repeats=1, top_k=10,
-                       include_hlo=False):
+                       include_hlo=False, donate_feeds=()):
         """Measured (not inferred) compile-time evidence for a step:
         AOT-lowers exactly the executable ``run`` would use for this
         (program, feed, fetch, repeats) and reports XLA's own numbers —
         {'flops', 'bytes_accessed', 'n_kernels', 'peak_memory_bytes',
-        'generated_code_size_bytes'}. ``n_kernels`` counts non-trivial
+        'aliased_bytes', 'generated_code_size_bytes'}, with the feeds
+        ``donate_feeds`` names given up as ``run`` would. ``n_kernels``
+        counts non-trivial
         instructions in the optimized HLO entry computation (fusions,
         convolutions, custom calls, loops...) — each is roughly one
         kernel launch per step, the quantity a per-kernel-overhead
@@ -511,10 +558,12 @@ class Executor:
         fetch_names, mode, state_rw, state_ro, feed_vals = \
             self._prepare(program, feed, fetch_list, scope, mode,
                           strict=False)
-        step_fn = lower_program(program, fetch_names, mode)
-        fn = jax.jit(make_stepped(step_fn, repeats), donate_argnums=(0,))
-        compiled = fn.lower(state_rw, state_ro, feed_vals,
-                            step_arg(1, program.random_seed)).compile()
+        donate_feeds = tuple(donate_feeds)
+        fn = self._jit(program, fetch_names, mode, repeats, donate_feeds,
+                       True)
+        compiled = fn.lower(*self._jit_args(
+            state_rw, state_ro, feed_vals,
+            step_arg(1, program.random_seed), donate_feeds)).compile()
         return compiled_cost_stats(compiled, top_k, include_hlo)
 
     # ------------------------------------------------------------------
@@ -523,7 +572,8 @@ class Executor:
     # bucket, steady-state traffic must not grow these numbers)
     def compile_cache_keys(self):
         """Snapshot of lowered-program cache keys, each
-        ``(program_uid, program_version, mode, fetch_names, repeats)``
+        ``(program_uid, program_version, mode, fetch_names, repeats,
+        donate_feeds)``
         — one entry per distinct lowered step function."""
         return sorted(self._cache)
 
@@ -560,9 +610,10 @@ def compiled_cost_stats(compiled, top_k=10, include_hlo=False):
     used by Executor.compiled_stats and ParallelExecutor.compiled_stats
     so the two cannot drift when jax's cost_analysis shape changes.
     Returns {'flops','bytes_accessed'[,'peak_memory_bytes',
-    'generated_code_size_bytes'],'n_kernels'[,'kernel_histogram',
-    'top_kernels']}; n_kernels is -1 when the optimized module text is
-    unavailable. include_hlo=True additionally returns the module text
+    'aliased_bytes','generated_code_size_bytes'],'n_kernels'
+    [,'kernel_histogram','top_kernels']}; aliased_bytes is what the
+    outputs share with donated arguments (written in place); n_kernels
+    is -1 when the optimized module text is unavailable. include_hlo=True additionally returns the module text
     under 'hlo_text' (megabytes — callers that serialize the stats,
     like bench.py's KSTATS record, must leave it off)."""
     cost = compiled.cost_analysis()
@@ -575,6 +626,8 @@ def compiled_cost_stats(compiled, top_k=10, include_hlo=False):
             + getattr(mem, "argument_size_in_bytes", 0)
             + getattr(mem, "output_size_in_bytes", 0)
             - getattr(mem, "alias_size_in_bytes", 0))
+        stats["aliased_bytes"] = int(
+            getattr(mem, "alias_size_in_bytes", 0))
         stats["generated_code_size_bytes"] = int(
             getattr(mem, "generated_code_size_in_bytes", 0))
     except Exception:

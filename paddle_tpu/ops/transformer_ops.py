@@ -1477,8 +1477,11 @@ class _PagedRunner:
         lyr = jnp.arange(pages.shape[0])[:, None, None]
         rows = jnp.arange(table.shape[0])[None, :, None]
         entries = dense[lyr, rows, at[None]]
-        # an undonated pool is copied before it is written: not before
-        # the entries are out and the view is dead, or both are live
+        # the pool is not written (nor, where the chip keeps it in
+        # another layout, re-laid) before the entries are out and the
+        # view is dead, or both are live: with the pools donated the
+        # latent decode program's footprint still reads 12.69 GB with
+        # this barrier and 14.78 without (PERF.md section 6, PR 32)
         pages, entries = jax.lax.optimization_barrier((pages, entries))
         # beyond kmax: a page past the pool's last, which the set drops
         pg = jnp.where(q_pos < kmax,

@@ -31,7 +31,7 @@ from .batching import (MicroBatcher, PendingResult, QueueFullError,  # noqa: F40
                        ServingError)
 from .buckets import BucketError, BucketSpec                         # noqa: F401
 from .decode_engine import (DecodeConfig, DecodeEngine,              # noqa: F401
-                            DecodeRequest)
+                            DecodeRequest, PoolsLostError)
 from .engine import ServingConfig, ServingEngine                     # noqa: F401
 from .health import (CircuitBreaker, HealthMonitor, HealthState,     # noqa: F401
                      ServiceUnavailableError, WorkerDiedError)
@@ -47,7 +47,8 @@ __all__ = ["AdmissionController", "BrownoutController", "BucketError",
            "DecodeEngine", "DecodeRequest", "FIFOScheduler",
            "HealthMonitor", "HealthState", "MicroBatcher",
            "PRIORITIES", "PageAllocator", "PagesExhaustedError",
-           "PendingResult", "QueueFullError", "RequestTimeoutError",
+           "PendingResult", "PoolsLostError", "QueueFullError",
+           "RequestTimeoutError",
            "RetryBudget", "RetryBudgetExhaustedError", "SLOClass",
            "SLOScheduler", "ServerClosedError",
            "ServiceUnavailableError", "ServingError", "ServingConfig",

@@ -62,7 +62,8 @@ from .metrics import ServingMetrics
 from .overload import BrownoutController
 from .sched import get_scheduler, priority_rank, PRIORITIES
 
-__all__ = ["DecodeConfig", "DecodeRequest", "DecodeEngine"]
+__all__ = ["DecodeConfig", "DecodeRequest", "DecodeEngine",
+           "PoolsLostError"]
 
 _DECODE_COUNTERS = (
     "prefill_total", "decode_batches_total", "generated_tokens_total",
@@ -117,7 +118,13 @@ _DECODE_COUNTERS = (
     # active rows attended.
     "moe_assignments_total", "moe_max_load_total",
     "moe_decode_expert_calls_total", "moe_decode_experts_touched_total",
-    "latent_tokens_read_total", "moe_held_assignments_total")
+    "latent_tokens_read_total", "moe_held_assignments_total",
+    # the pools are donated to every dispatch (PR 32): consumed ticks
+    # once a dispatch whose fed pools all came back deleted, so it equals
+    # the sum of the dispatch counters unless JAX dropped a donation as
+    # unusable; lost ticks once each time the engine found a pool of its
+    # own deleted and replaced them all with zeroed ones
+    "pools_consumed_total", "pools_lost_total")
 
 # how long after a program's end the worker keeps polling before it reads
 # the tokens and counters whose host copies set out with the program: the
@@ -132,6 +139,14 @@ _SHED_BY_RANK = {rank: f"shed_{name}_total"
 
 def _env_float(name, default):
     return float(os.environ.get(name, default))
+
+
+class PoolsLostError(ServingError):
+    """The cache pools were consumed by a dispatch that did not hand
+    them back (or were deleted under the engine), so every sequence that
+    held pages lost its cache. The engine has zeroed pools again and
+    serves the next request; this one must be submitted anew. Never
+    retried as it stands: there is no cache to continue on."""
 
 
 class DecodeConfig:
@@ -344,7 +359,20 @@ class DecodeEngine:
     (models/llama.py PagedDecodePrograms; LlamaConfig and
     models/latent_moe.py LatentMoEConfig are the two there are). The
     engine owns the pools, the page tables and the slots, and knows
-    nothing else of the model. ``scope`` must already hold the weights
+    nothing else of the model.
+
+    **The pools are the engine's alone and are donated to every
+    dispatch**: a program takes ``_pools`` (``_draft_pools``) in, XLA
+    writes the new entries into those very buffers, and the engine
+    rebinds both lists to what comes back; the arrays it fed are deleted
+    by then, so nobody else may hold one across a dispatch. The weights
+    are read state of the scope, shared by every engine over it, and are
+    never donated. A pool that is found deleted (a dispatch failed after
+    the executable took it, or someone put a consumed array back) is
+    LOST: ``_replace_lost_pools`` zeroes all pools, fails every slot and
+    chunk job that holds pages with ``PoolsLostError`` and counts
+    ``pools_lost_total``; no sequence continues on a zeroed cache and
+    that dispatch is not retried. ``scope`` must already hold the weights
     the programs name (for Llama the generator layout:
     ``build_llama_generator`` startup, a trained+stacked scope, or a
     ``quantize_generator_weights``'d one; draft weights under
@@ -407,25 +435,21 @@ class DecodeEngine:
         self.optimize_reports = {}
         if optimize:
             self._optimize_programs()
-        import jax.numpy as jnp
-        # the cache pools, as the model's programs specify them; every
-        # dispatch takes them in and hands them back
-        self._pools = [jnp.zeros(tuple(shape), dtype)
-                       for shape, dtype in self.programs.pool_specs]
-        self._draft_pools = [
-            jnp.zeros(tuple(shape), dtype)
-            for shape, dtype in self.programs.draft_pool_specs or ()]
+        # the cache pools, as the model's programs specify them: donated
+        # to every dispatch, and rebound to what it hands back
+        self._pools, self._draft_pools = self._zeroed_pools()
         # program label -> what its last dispatch returned beside tokens,
         # pools and stats, by name (``logits``, ``picks``), where the
         # model's programs return such: left on the device, for whoever
         # compares them with a reference
         self.kept = {}
         # all retries surface at the serving layer (counted); the inner
-        # executor must not also retry. donate_state=False: pool
-        # replicas share one weight scope (see ServingEngine).
+        # executor must not also retry. The programs run mode="test" and
+        # write no persistable, so the executor has no state to donate:
+        # the weights are read state, shared by every engine over this
+        # scope, and only the pools are given up (_run_program)
         self.exe = Executor(place,
-                            retry_policy=RetryPolicy(max_attempts=1),
-                            donate_state=False)
+                            retry_policy=RetryPolicy(max_attempts=1))
         self.metrics = ServingMetrics(extra_counters=_DECODE_COUNTERS)
         self.health = HealthMonitor()
         self.breaker = CircuitBreaker(
@@ -462,14 +486,19 @@ class DecodeEngine:
         worker + watchdog threads."""
         if self._worker is not None and self._worker.is_alive():
             return self
+        # no worker, not a dead one, while the new one is made: a live
+        # watchdog that found the stopped thread once ``_stop`` is clear
+        # would declare a death and fail what is queued for the new one
+        self._worker = None
         self._stop.clear()
         self._crash.clear()
         self._worker_death_seen = False
         self.health.beat()
-        self._worker = threading.Thread(
+        worker = threading.Thread(
             target=self._worker_loop, name="paddle-tpu-decode-worker",
             daemon=True)
-        self._worker.start()
+        worker.start()
+        self._worker = worker
         if self._watchdog is None or not self._watchdog.is_alive():
             self._watchdog_stop.clear()
             self._watchdog = threading.Thread(
@@ -835,6 +864,21 @@ class DecodeEngine:
         return snap
 
     # -- internal: program rewrites --------------------------------------
+    def _bundles(self):
+        """Every program bundle the engine dispatches, under the label
+        its dispatch method gives ``_run_program``."""
+        bundles = {f"prefill_{bucket}": b
+                   for bucket, b in self.programs.prefill.items()}
+        bundles.update(
+            (f"draft_prefill_{bucket}", b)
+            for bucket, b in (self.programs.draft_prefill or {}).items())
+        bundles["decode"] = self.programs.decode
+        if self.programs.chunk is not None:
+            bundles["chunk"] = self.programs.chunk
+        if self.programs.spec is not None:
+            bundles["spec"] = self.programs.spec
+        return bundles
+
     def _optimize_programs(self):
         """Runs the rewrite pipeline (Program.optimize) over every
         step-program bundle, keyed like the dispatch methods name
@@ -843,18 +887,7 @@ class DecodeEngine:
         nowhere; fetch Variables are resolved by NAME because they
         belong to the pre-clone builder program."""
         import warnings
-        bundles = {}
-        for bucket, b in self.programs.prefill.items():
-            bundles[f"prefill_{bucket}"] = b
-        if self.programs.draft_prefill:
-            for bucket, b in self.programs.draft_prefill.items():
-                bundles[f"draft_prefill_{bucket}"] = b
-        bundles["decode"] = self.programs.decode
-        if self.programs.chunk is not None:
-            bundles["chunk"] = self.programs.chunk
-        if self.programs.spec is not None:
-            bundles["spec"] = self.programs.spec
-        for label, b in bundles.items():
+        for label, b in self._bundles().items():
             try:
                 report = b["program"].optimize(
                     fetch_list=[v.name if hasattr(v, "name") else v
@@ -881,22 +914,85 @@ class DecodeEngine:
     def _bundle_feed(self, bundle, arrays):
         return dict(zip(bundle["feeds"], arrays))
 
+    def _zeroed_pools(self):
+        """(pools, draft pools) of ``programs.pool_specs``, zeroed."""
+        import jax.numpy as jnp
+        return tuple(
+            [jnp.zeros(tuple(shape), dtype) for shape, dtype in specs or ()]
+            for specs in (self.programs.pool_specs,
+                          self.programs.draft_pool_specs))
+
+    def _replace_lost_pools(self):
+        """The one rule for a consumed pool. Where any pool of this
+        engine is deleted, the cache is lost: ALL pools become zeroed
+        ones of ``programs.pool_specs``, every slot and chunk job that
+        holds pages fails with ``PoolsLostError`` and frees them (no
+        sequence continues on a zeroed cache), ``pools_lost_total``
+        ticks, and the error is returned for the dispatch at hand to
+        raise: it is not retryable. None where the pools are live, and
+        where nothing held a page (the replacement is silent, but
+        counted)."""
+        if not any(p.is_deleted()
+                   for p in (*self._pools, *self._draft_pools)):
+            return None
+        self._pools, self._draft_pools = self._zeroed_pools()
+        self.metrics.incr("pools_lost_total")
+        with self._slots_lock:
+            held = [i for i, slot in enumerate(self.slots)
+                    if slot is not None]
+            jobs = sorted(self._chunk_jobs)
+        if not held and not jobs:
+            return None
+        lost = PoolsLostError(
+            "the cache pools were consumed by a dispatch that did not "
+            "hand them back; every sequence that held pages lost its "
+            "cache and is failed (the engine has zeroed pools again: "
+            "submit the request anew)")
+        self.metrics.incr("errors_total", len(held) + len(jobs))
+        for i in held:
+            self._retire(i, error=lost)
+        for i in jobs:
+            self._fail_chunk_job(i, lost)
+        return lost
+
     # scope is passed explicitly to every run — scope_guard swaps a
     # process-global, which would race other live engines' threads
     def _run_program(self, label, b, arrays):
         """One dispatch of bundle ``b``: ``arrays`` then the pools it
         names (the target's; ``draft``: the draft's; ``both``: one after
-        the other) in, the pools rebound to what comes back. Returns the
-        token outputs as numpy; a ``stats`` fetch ticks the counters it
-        names, any other extra stays on the device under
-        ``kept[label]``."""
+        the other) in, DONATED (``Executor.run(donate_feeds=...)``): the
+        program writes into the buffers it was fed, the arrays fed are
+        deleted (``pools_consumed_total`` counts the dispatches where
+        they were), and the pools are rebound to what comes back.
+        Returns the token outputs as numpy; a ``stats`` fetch ticks the
+        counters it names, any other extra stays on the device under
+        ``kept[label]``. A dispatch that finds a pool deleted on entry,
+        or fails once its pools are gone, goes by
+        ``_replace_lost_pools``."""
+        lost = self._replace_lost_pools()
+        if lost is not None:
+            raise lost
+        try:
+            return self._dispatch(label, b, arrays)
+        except BaseException as exc:     # noqa: BLE001 — reraised
+            lost = self._replace_lost_pools()
+            if lost is None:
+                raise
+            raise lost from exc
+
+    def _pools_of(self, b):
+        """The pools bundle ``b`` takes in and hands back, in its order."""
         which = b.get("pools", "target")
-        pools = ([] if which == "draft" else self._pools) \
+        return ([] if which == "draft" else self._pools) \
             + ([] if which == "target" else self._draft_pools)
+
+    def _dispatch(self, label, b, arrays):
+        pools = self._pools_of(b)
         outs = self.exe.run(
             b["program"], feed=self._bundle_feed(b, (*arrays, *pools)),
             fetch_list=b["fetch"], mode="test", return_numpy=False,
-            scope=self.scope)
+            scope=self.scope, donate_feeds=b["feeds"][len(arrays):])
+        consumed = all(p.is_deleted() for p in pools)
         extras = b.get("extras", ())
         n_head = len(outs) - len(pools) - len(extras)
         kept = dict(zip(extras, outs[n_head + len(pools):]))
@@ -920,7 +1016,11 @@ class DecodeEngine:
         landed = time.perf_counter() + _HOST_COPY_S
         while time.perf_counter() < landed:
             time.sleep(0)
+        # the tokens first: a program that failed on the device raises
+        # here, while the engine still holds the consumed pools it fed
+        head = [np.asarray(x) for x in outs[:n_head]]
         back = list(outs[n_head:n_head + len(pools)])
+        which = b.get("pools", "target")
         if which != "draft":
             self._pools, back = (back[:len(self._pools)],
                                  back[len(self._pools):])
@@ -932,7 +1032,11 @@ class DecodeEngine:
                 (int(x) for x in np.asarray(kept.pop("stats"))))))
         if kept:
             self.kept[label] = kept
-        return [np.asarray(x) for x in outs[:n_head]]
+        # ticked as the dispatch returns, beside its caller's own count:
+        # a snapshot finds the two equal, not one dispatch apart
+        if consumed:
+            self.metrics.incr("pools_consumed_total")
+        return head
 
     def _run_prefill_program(self, bucket, tokens, lens, table):
         """The bucket's single-row program once for each row given, in
@@ -1416,14 +1520,17 @@ class DecodeEngine:
         return True
 
     def _fail_chunk_job(self, idx, exc):
+        """Fail the chunk job at ``idx`` and free its pages: False where
+        close(), the watchdog or a lost pool settled it already."""
         with self._slots_lock:
             job = self._chunk_jobs.pop(idx, None)
             if job is None:
-                return
+                return False
             self.allocator.free(job.pages)
         job.req.set_error(exc)
         with self._cv:
             self._cv.notify_all()
+        return True
 
     def _step_chunks(self, policy):
         """One chunk dispatch per in-flight chunked prefill — chunk
@@ -1485,8 +1592,8 @@ class DecodeEngine:
                 if self.breaker.record_failure():
                     self.metrics.incr("breaker_open_total")
                     self.health.to(HealthState.DEGRADED)
-                self.metrics.incr("errors_total")
-                self._fail_chunk_job(idx, exc)
+                if self._fail_chunk_job(idx, exc):
+                    self.metrics.incr("errors_total")
                 progressed = True
                 continue
             self.breaker.record_success()
@@ -1579,6 +1686,8 @@ class DecodeEngine:
             if self.breaker.record_failure():
                 self.metrics.incr("breaker_open_total")
                 self.health.to(HealthState.DEGRADED)
+            # slots a lost pool took are settled and counted already
+            active = self._active()
             self.metrics.incr("errors_total", len(active))
             for i, _ in active:
                 self._retire(i, error=exc)

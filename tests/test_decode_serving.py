@@ -26,10 +26,13 @@ from paddle_tpu.models.llama import (LlamaConfig, build_llama_generator,
                                      copy_weights_as_draft,
                                      quantize_generator_weights)
 from paddle_tpu.resilience import faultinject
+from paddle_tpu.resilience.retry import RetryPolicy, TransientDeviceError
 from paddle_tpu.serving import (BucketError, DecodeConfig, DecodeEngine,
                                 PageAllocator, PagesExhaustedError,
-                                QueueFullError, RequestTimeoutError,
-                                WorkerDiedError)
+                                PoolsLostError, QueueFullError,
+                                RequestTimeoutError, WorkerDiedError)
+
+from pool_donation import aliased_bytes, check_dispatch_donates
 
 pytestmark = pytest.mark.serving
 
@@ -630,6 +633,272 @@ def test_worker_crash_zero_lost_requests(served_scope):
     finally:
         faultinject.disarm()
         eng.close()
+
+
+# ---------------------------------------------------------------------
+# the pools are donated to every dispatch; a consumed pool is lost
+# ---------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def donating_engines(served_scope):
+    """Every program an engine has, over two engines (a speculative
+    engine has no chunk program): neither has a worker."""
+    scope = served_scope[0]
+    with fluid.scope_guard(scope):
+        copy_weights_as_draft(scope)
+    engines = {"chunked": _group_engine(scope, chunk_size=4),
+               "spec": _group_engine(scope, draft_cfg=CFG, gamma=3)}
+    yield engines
+    for eng in engines.values():
+        eng.close()
+
+
+@pytest.mark.parametrize("which,label", [
+    ("chunked", "prefill_4"), ("chunked", "chunk"), ("chunked", "decode"),
+    ("spec", "prefill_4"), ("spec", "prefill_8"),
+    ("spec", "draft_prefill_4"), ("spec", "draft_prefill_8"),
+    ("spec", "decode"), ("spec", "spec")])
+def test_every_program_consumes_the_pools_it_is_fed(donating_engines,
+                                                    which, label):
+    """Each program's dispatch deletes the pools it was fed, leaves the
+    engine live ones, and gives the tokens and pool bytes of the same
+    lowered program under a jit that donates nothing; XLA aliases all of
+    the pools' bytes."""
+    eng = donating_engines[which]
+    if which == "chunked":
+        # bucket 8 is beyond chunk_size 4: those prompts go in slices
+        assert sorted(eng._bundles()) == ["chunk", "decode", "prefill_4"]
+    else:
+        assert len(eng._bundles()) == 6
+    b, arrays, fed = check_dispatch_donates(eng, label, CFG.vocab_size)
+    pools = eng._pools_of(b)
+    assert len(pools) == len(fed)
+    assert aliased_bytes(eng, b, arrays, pools) \
+        >= sum(p.nbytes for p in pools)
+    assert not any(p.is_deleted() for p in pools)    # a lowering only
+
+
+def _fail_once_consumed(eng, monkeypatch, program, exc):
+    """``eng``'s next dispatch of ``program`` runs, consumes its pools
+    and THEN raises ``exc``: once. Returns the list of calls it saw."""
+    run, calls = eng.exe.run, []
+
+    def failing(prog, *args, **kw):
+        outs = run(prog, *args, **kw)
+        if prog is program and not calls:
+            calls.append(prog)
+            raise exc
+        return outs
+
+    monkeypatch.setattr(eng.exe, "run", failing)
+    return calls
+
+
+def test_a_dispatch_that_fails_with_its_pools_consumed_loses_them(
+        served_scope, engine, monkeypatch):
+    """The decode dispatch raises after the executable took the pools: a
+    TRANSIENT error, which the policy would retry. Every live slot fails
+    with PoolsLostError, nothing is retried, the pools are zeroed ones of
+    the right shapes, and the next request gets a fresh engine's tokens."""
+    eng = _group_engine(served_scope[0], retry_policy=RetryPolicy(
+        max_attempts=3, initial_backoff=0.0))
+    try:
+        eng.warmup()
+        rng = np.random.RandomState(21)
+        prompts = _prompts(3, rng, lo=3, hi=8)
+        want = [engine.generate(p, max_new=6, timeout=120) for p in prompts]
+        calls = _fail_once_consumed(
+            eng, monkeypatch, eng.programs.decode["program"],
+            TransientDeviceError("UNAVAILABLE: lost after the launch"))
+        before = eng.stats()
+        reqs = _queue_together(eng, prompts, max_new=6)
+        for r in reqs:
+            with pytest.raises(PoolsLostError) as err:
+                r.result(120)
+            assert isinstance(err.value.__cause__, TransientDeviceError)
+        assert len(calls) == 1
+        after = eng.stats()
+        assert after["pools_lost_total"] - before["pools_lost_total"] == 1
+        assert after["retries_total"] == before["retries_total"]
+        assert after["errors_total"] - before["errors_total"] == 3
+        assert after["decode_batches_total"] == before["decode_batches_total"]
+        assert eng.allocator.in_use == 0 and eng._active() == []
+        assert [(tuple(p.shape), p.dtype) for p in eng._pools] \
+            == [(tuple(shape), np.dtype(dtype))
+                for shape, dtype in eng.programs.pool_specs]
+        # the engine serves on, from a cache that holds nothing stale
+        for p, w in zip(prompts, want):
+            np.testing.assert_array_equal(
+                eng.generate(p, max_new=6, timeout=120), w)
+        eng.assert_no_recompiles()
+        assert eng.stats()["pools_lost_total"] \
+            - before["pools_lost_total"] == 1
+    finally:
+        eng.close()
+
+
+def test_a_prefill_that_loses_the_pools_fails_the_slots_that_held_pages(
+        served_scope, engine, monkeypatch):
+    """A prefill's failure after consumption is not that request's alone:
+    the slots that were decoding lose their cache with it. The pools are
+    found zeroed right after the failed dispatch, not at the next one."""
+    eng = _group_engine(served_scope[0], prefill_batch=1)
+    try:
+        eng.warmup()
+        rng = np.random.RandomState(22)
+        first, second = _prompts(2, rng, lo=5, hi=8)
+        calls, decoding = [], []
+        run = eng.exe.run
+        prefill = eng.programs.prefill[8]["program"]
+
+        def failing(prog, *args, **kw):
+            outs = run(prog, *args, **kw)
+            calls.append(prog)
+            if prog is prefill and calls.count(prefill) == 2:
+                decoding.extend(slot.req for _, slot in eng._active())
+                raise RuntimeError("INTERNAL: the program failed")
+            return outs
+
+        monkeypatch.setattr(eng.exe, "run", failing)
+        reqs = _queue_together(eng, [first, second], max_new=8)
+        for r in reqs:
+            with pytest.raises(PoolsLostError):
+                r.result(120)
+        assert decoding == reqs[:1]        # the first held a slot by then
+        stats = eng.stats()
+        assert stats["pools_lost_total"] == 1
+        assert eng.allocator.in_use == 0
+        assert not any(p.is_deleted() for p in eng._pools)
+        np.testing.assert_array_equal(
+            eng.generate(first, max_new=6, timeout=120),
+            engine.generate(first, max_new=6, timeout=120))
+    finally:
+        eng.close()
+
+
+def test_a_consumed_pool_put_back_is_replaced_in_silence_when_none_is_live(
+        served_scope):
+    """Someone keeps the arrays an engine held, dispatches, and puts them
+    back: with no slot live the next dispatch starts from zeroed pools,
+    counted, and raises nothing."""
+    eng = _group_engine(served_scope[0])
+    try:
+        kept = list(eng._pools)
+        _, arrays, _ = check_dispatch_donates(eng, "decode", CFG.vocab_size)
+        assert not any(p.is_deleted() for p in kept)   # noise was fed
+        eng._run_decode_program(*arrays)
+        eng._pools[:] = [eng._pools[0], kept[1]]
+        eng._run_decode_program(*arrays)               # consumes kept[1]
+        eng._pools[:] = kept
+        assert eng.stats()["pools_lost_total"] == 0
+        out = eng._run_decode_program(*arrays)
+        assert eng.stats()["pools_lost_total"] == 1
+        fresh = _group_engine(served_scope[0])
+        try:
+            np.testing.assert_array_equal(
+                out, fresh._run_decode_program(*arrays))
+            for a, b in zip(_pool_bytes(eng._pools),
+                            _pool_bytes(fresh._pools)):
+                np.testing.assert_array_equal(a, b)
+        finally:
+            fresh.close()
+    finally:
+        eng.close()
+
+
+def test_an_injected_device_error_still_retries_with_the_pools_intact(
+        served_scope, engine):
+    """serving_device_error fires BEFORE Executor.run: the pools were not
+    taken, the retry finds them, and nothing is lost."""
+    eng = _group_engine(served_scope[0], retry_policy=RetryPolicy(
+        max_attempts=3, initial_backoff=0.0))
+    try:
+        eng.warmup()
+        rng = np.random.RandomState(23)
+        prompts = _prompts(2, rng, lo=3, hi=8)
+        reqs = _queue_together(eng, prompts[:1], max_new=6)
+        reqs[0].result(120)
+        pools = _pool_bytes(eng._pools)
+        faultinject.arm("serving_device_error", at=1, times=2)
+        try:
+            got = eng.generate(prompts[1], max_new=6, timeout=120)
+        finally:
+            faultinject.disarm()
+        stats = eng.stats()
+        assert stats["retries_total"] == 2
+        assert stats["pools_lost_total"] == 0 and stats["errors_total"] == 0
+        np.testing.assert_array_equal(
+            got, engine.generate(prompts[1], max_new=6, timeout=120))
+        # what the first request left is still in the pools' other pages
+        assert any((a != 0).any() for a in pools)
+    finally:
+        faultinject.disarm()
+        eng.close()
+
+
+@pytest.mark.parametrize("which", ["chunked", "spec"])
+def test_pools_consumed_equals_the_dispatches_after_a_mixed_run(
+        served_scope, which):
+    """Every dispatch consumed its pools: the counter is the sum of the
+    dispatch counters (a speculative engine's prefill is two dispatches,
+    the target's and the draft's), so a donation JAX dropped would show
+    as a shortfall."""
+    scope = served_scope[0]
+    with fluid.scope_guard(scope):
+        copy_weights_as_draft(scope)
+    over = dict(chunk_size=4) if which == "chunked" \
+        else dict(draft_cfg=CFG, gamma=3)
+    eng = _group_engine(scope, **over)
+    try:
+        warm = eng.warmup()
+        before = eng.stats()
+        assert before["pools_consumed_total"] == warm["programs"]
+        eng.start()
+        rng = np.random.RandomState(24)
+        reqs = [eng.submit(p, max_new=int(rng.randint(1, 7)), timeout=120)
+                for p in _prompts(9, rng, lo=2, hi=8)]
+        for r in reqs:
+            r.result(120)
+        after = eng.stats()
+        delta = {k: after[k] - before[k] for k in after
+                 if k.endswith("_total")}
+        dispatches = (delta["decode_batches_total"]
+                      + delta["chunk_prefill_total"]
+                      + delta["prefill_dispatch_total"]
+                      * (2 if which == "spec" else 1))
+        assert delta["decode_batches_total"] > 0
+        assert delta["prefill_dispatch_total"] > 0
+        assert (delta["chunk_prefill_total"] > 0) == (which == "chunked")
+        assert delta["pools_consumed_total"] == dispatches
+        assert after["pools_lost_total"] == 0
+        eng.assert_no_recompiles()
+    finally:
+        eng.close()
+
+
+def test_two_engines_over_one_scope_keep_their_weights(served_scope):
+    """Each engine owns its pools and gives them up; the weights are the
+    scope's, shared, and no dispatch of either engine deletes one."""
+    scope = served_scope[0]
+    a, b = (_group_engine(scope).start() for _ in range(2))
+    try:
+        rng = np.random.RandomState(25)
+        prompts = _prompts(3, rng, lo=3, hi=8)
+        first = [a.generate(p, max_new=6, timeout=120) for p in prompts]
+        second = [b.generate(p, max_new=6, timeout=120) for p in prompts]
+        again = [a.generate(p, max_new=6, timeout=120) for p in prompts]
+        for x, y, z in zip(first, second, again):
+            np.testing.assert_array_equal(x, y)
+            np.testing.assert_array_equal(x, z)
+        names = [n for n in a.programs.decode["program"].global_block().vars
+                 if scope.find_var(n) is not None]
+        assert names and not any(
+            scope.find_var(n).is_deleted() for n in names)
+        assert a.stats()["pools_lost_total"] == 0
+        assert b.stats()["pools_lost_total"] == 0
+    finally:
+        a.close()
+        b.close()
 
 
 # ---------------------------------------------------------------------

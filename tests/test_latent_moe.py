@@ -19,9 +19,11 @@ from paddle_tpu.models.llama import LLAMA_TINY
 from paddle_tpu.ops import moe
 from paddle_tpu.ops import transformer_ops as T
 from paddle_tpu.serving.batching import ServingError
-from paddle_tpu.serving.decode_engine import DecodeConfig, DecodeEngine
+from paddle_tpu.serving.decode_engine import (DecodeConfig, DecodeEngine,
+                                              PoolsLostError)
 
 from benchmark.reference import latent_moe_mhc as ref
+from pool_donation import aliased_bytes, check_dispatch_donates
 
 REL_L2_F32 = 1e-4
 PS, MP = 4, 8                      # page size, pages a row
@@ -366,6 +368,68 @@ def test_handoff_carries_the_one_pool_cache(engine):
             other.import_handoff(bad)
     finally:
         other.close()
+
+
+@pytest.mark.parametrize("label", ["prefill_8", "chunk", "decode"])
+def test_every_program_consumes_the_pool_it_is_fed(label):
+    """The one latent pool is donated to each program the engine has:
+    the array fed is deleted, tokens and pool bytes are the undonated
+    program's, and XLA aliases the whole pool."""
+    eng = make_engine()
+    eng.close()
+    assert sorted(eng._bundles()) == ["chunk", "decode", "prefill_8"]
+    b, arrays, fed = check_dispatch_donates(eng, label, CFG.vocab_size)
+    assert len(fed) == 1
+    assert aliased_bytes(eng, b, arrays, eng._pools) >= eng._pools[0].nbytes
+    # what the program returns beside tokens and pool stays on the device
+    assert set(eng.kept[label]) == {"logits", "picks"}
+
+
+def test_a_chunk_that_loses_the_pool_fails_the_job_and_the_live_slot(
+        monkeypatch):
+    """The second slice of a chunked prefill raises once the pool is
+    consumed: the chunk job and the slot decoding beside it both fail
+    with PoolsLostError, their pages come back, and the engine serves
+    the same prompts again with a fresh engine's tokens."""
+    eng = make_engine()
+    try:
+        eng.warmup()
+        rng = np.random.RandomState(9)
+        short, long_ = (rng.randint(0, CFG.vocab_size, n) for n in (6, 21))
+        want = [eng.generate(p, max_new=8) for p in (short, long_)]
+        eng._stop.set()
+        eng._worker.join(10.0)
+        run, chunk, calls = eng.exe.run, eng.programs.chunk["program"], []
+
+        def failing(prog, *args, **kw):
+            outs = run(prog, *args, **kw)
+            calls.append(prog)
+            if prog is chunk and calls.count(chunk) == 2:
+                raise RuntimeError("INTERNAL: the program failed")
+            return outs
+
+        monkeypatch.setattr(eng.exe, "run", failing)
+        before = eng.stats()
+        reqs = [eng.submit(p, max_new=8) for p in (short, long_)]
+        eng.start()
+        for r in reqs:
+            with pytest.raises(PoolsLostError):
+                r.result(120)
+        after = eng.stats()
+        assert after["pools_lost_total"] - before["pools_lost_total"] == 1
+        assert after["errors_total"] - before["errors_total"] == 2
+        assert eng.allocator.in_use == 0 and not eng._chunk_jobs
+        assert not np.asarray(eng._pools[0]).any()
+        for p, w in zip((short, long_), want):
+            assert np.array_equal(eng.generate(p, max_new=8), w)
+        eng.assert_no_recompiles()
+        # the counter ticks as a dispatch RETURNS: the failed one, which
+        # consumed its pool, is in neither count
+        assert after["pools_consumed_total"] == (
+            after["decode_batches_total"] + after["prefill_dispatch_total"]
+            + after["chunk_prefill_total"] + 3)         # the warm-up's
+    finally:
+        eng.close()
 
 
 @pytest.mark.parametrize("kw,match", [
