@@ -737,7 +737,8 @@ def silu(x, name=None):
 def block_paged_op(kind, feeds, pools, *, params, lead_params, attrs,
                    vocab_size, dtype, steps=1, name="blocks",
                    lead_name="lead", emb_name="tok_emb",
-                   final_norm_name="final_norm", head_name="lm_head"):
+                   final_norm_name="final_norm", head_name="lm_head",
+                   stacks=(), stats=None):
     """One paged step program of a model whose block kinds are attributes
     (ops/transformer_ops.py block_paged_*; models/latent_moe.py builds
     them). ``kind``: ``prefill`` | ``prefill_chunk`` | ``decode``;
@@ -745,8 +746,12 @@ def block_paged_op(kind, feeds, pools, *, params, lead_params, attrs,
     Positions, Table); ``pools``: the cache pools; ``params`` /
     ``lead_params``: slot -> (suffix, shape, dtype) of the stacked
     layers' and the leading dense layers' parameters, named
-    ``{name}.{suffix}`` / ``{lead_name}.{suffix}``. Returns (tokens,
-    pools_out, logits, picks, stats)."""
+    ``{name}.{suffix}`` / ``{lead_name}.{suffix}``. A model that mixes
+    attention kinds has no ``params`` but ``stacks``: (slot prefix, scope
+    name, table) of each kind's stacked layers, in ``attrs["attn_kinds"]``'
+    order, and ``RingTable`` among its feeds; ``stats`` names its Stats
+    (PAGED_STATS where None). Returns (tokens, pools_out, logits, picks,
+    stats)."""
     from ..ops.transformer_ops import PAGED_STATS
     helper = LayerHelper("block_paged_" + kind, name=name)
     ninit = init_mod.Normal(0.0, 0.02)
@@ -755,13 +760,16 @@ def block_paged_op(kind, feeds, pools, *, params, lead_params, attrs,
         return helper.create_parameter(
             ParamAttr(name=pname, initializer=init), list(shape), pdtype)
 
-    dim = params["AttnNorm"][1][-1]
+    tables = [("", name, params), ("Lead", lead_name, lead_params)] \
+        + list(stacks)
+    dim = next(t["AttnNorm"][1][-1] for _, _, t in tables if t)
+    n_routed = sum(t["MoeRouter"][1][0] for _, _, t in tables
+                   if "MoeRouter" in t)
     inputs = {"Emb": [make(emb_name, [vocab_size, dim], dtype).name],
               "FinalNorm": [make(final_norm_name, [dim], dtype,
                                  init_mod.Constant(1.0)).name],
               "LmHead": [make(head_name, [dim, vocab_size], dtype).name]}
-    for prefix, scope_name, table in (("", name, params),
-                                      ("Lead", lead_name, lead_params)):
+    for prefix, scope_name, table in tables:
         for slot, (suffix, shape, pdtype) in table.items():
             inputs[prefix + slot] = [make(f"{scope_name}.{suffix}", shape,
                                           pdtype).name]
@@ -775,10 +783,9 @@ def block_paged_op(kind, feeds, pools, *, params, lead_params, attrs,
     logits = helper.create_variable_for_type_inference(
         "float32", shape=shape + [vocab_size])
     stats = helper.create_variable_for_type_inference(
-        "int32", shape=[len(PAGED_STATS)])
+        "int32", shape=[len(PAGED_STATS if stats is None else stats)])
     picks = helper.create_variable_for_type_inference(
-        "int32", shape=shape + [params["MoeRouter"][1][0],
-                                int(attrs["moe_top_k"])])
+        "int32", shape=shape + [n_routed, int(attrs["moe_top_k"])])
     pools_out = [helper.create_variable_for_type_inference(
         p.dtype, shape=p.shape) for p in pools]
     attrs = dict(attrs)

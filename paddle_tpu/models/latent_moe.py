@@ -33,7 +33,8 @@ from ..layers import transformer as tfl
 from ..ops.transformer_ops import PAGED_STATS, yarn_inv_freq, yarn_mscale
 from .llama import PagedDecodePrograms, prefill_buckets_reached
 
-__all__ = ["LatentMoEConfig", "LATENT_MOE_TINY", "LATENT_SHARE_TINY"]
+__all__ = ["LatentMoEConfig", "LATENT_MOE_TINY", "LATENT_SHARE_TINY",
+           "build_block_programs"]
 
 
 @dataclass
@@ -210,62 +211,86 @@ class LatentMoEConfig:
                 "drop quantize")
         if self.n_layers <= self.n_dense_layers:
             raise ValueError("no routed layer after the dense ones")
-        from ..core import framework
         pool_shape = [self.n_layers, n_pages, page_size, self.entry_dim]
-        common = dict(
-            params=self.layer_params(
-                self.n_layers - self.n_dense_layers, True),
-            lead_params=(self.layer_params(self.n_dense_layers, False)
-                         if self.n_dense_layers else {}),
-            attrs=self.block_attrs(page_size), vocab_size=self.vocab_size,
-            dtype=self.dtype)
+        return build_block_programs(
+            self, pool_specs=[(pool_shape, self.dtype)],
+            common=dict(
+                params=self.layer_params(
+                    self.n_layers - self.n_dense_layers, True),
+                lead_params=(self.layer_params(self.n_dense_layers, False)
+                             if self.n_dense_layers else {}),
+                attrs=self.block_attrs(page_size),
+                vocab_size=self.vocab_size, dtype=self.dtype),
+            max_batch=max_batch, page_size=page_size, n_pages=n_pages,
+            pages_per_seq=pages_per_seq, prompt_buckets=prompt_buckets,
+            decode_block=decode_block, chunk_size=chunk_size)
 
-        def bundle(kind, prefix, feeds, steps=1):
-            """One program: ``feeds`` are (slot, feed name, shape, dtype)
-            of its data inputs, in feed order; the pool follows."""
-            main = framework.Program()
-            with framework.program_guard(main, framework.Program()), \
-                    framework.unique_name.guard():
-                data = {slot: layers.data(
-                    name=f"{prefix}_{fname}", shape=list(shape),
-                    dtype=dt, append_batch_size=False)
-                    for slot, fname, shape, dt in feeds}
-                pool = layers.data(name=f"{prefix}_pool",
-                                   shape=pool_shape, dtype=self.dtype,
-                                   append_batch_size=False)
-                out, pools_out, logits, picks, stats = tfl.block_paged_op(
-                    kind, data, [pool], steps=steps, **common)
-            return {"program": main.clone(for_test=True),
-                    "feeds": tuple(f"{prefix}_{f[1]}" for f in feeds)
-                    + (f"{prefix}_pool",),
-                    "fetch": [out] + pools_out + [logits, picks, stats],
-                    "extras": ("logits", "picks", "stats")}
 
-        table = lambda b: ("Table", "table", [b, pages_per_seq], "int32")
-        prefill = {
-            bucket: bundle("prefill", "pp", [
-                ("Tokens", "tokens", [1, bucket], "int64"),
-                ("Lens", "lens", [1], "int32"), table(1)])
-            for bucket in prefill_buckets_reached(prompt_buckets,
-                                                  chunk_size)}
-        decode = bundle("decode", "dc", [
-            ("Tokens", "tokens", [max_batch], "int64"),
-            ("Positions", "positions", [max_batch], "int32"),
-            table(max_batch)], steps=decode_block)
-        chunk = None
-        if chunk_size is not None:
-            cs = int(chunk_size)
-            if cs < 1:
-                raise ValueError(f"chunk_size must be >= 1, got {cs}")
-            chunk = bundle("prefill_chunk", "ck", [
-                ("Tokens", "tokens", [1, cs], "int64"),
-                ("Lens", "lens", [1], "int32"),
-                ("Offsets", "offsets", [1], "int32"), table(1)])
-        return PagedDecodePrograms(
-            self, None, page_size, pages_per_seq, n_pages, max_batch,
-            prefill, decode, None, [(pool_shape, self.dtype)], None,
-            chunk=chunk, chunk_size=None if chunk is None else cs,
-            stats=PAGED_STATS)
+def build_block_programs(cfg, *, pool_specs, common, max_batch, page_size,
+                         n_pages, pages_per_seq, prompt_buckets,
+                         decode_block, chunk_size, ring=None,
+                         stats=PAGED_STATS):
+    """The prefill, chunk and decode programs of a model whose block
+    kinds are attributes (layers/transformer.py block_paged_op with
+    ``common``), over the pools ``pool_specs``. ``ring`` (a model with
+    window attention layers: models/hybrid_moe.py) is the window kinds'
+    cache as PagedDecodePrograms carries it; every program then takes a
+    second table, [rows, ring["pages_per_seq"]], behind the first."""
+    from ..core import framework
+
+    def bundle(kind, prefix, feeds, steps=1):
+        """One program: ``feeds`` are (slot, feed name, shape, dtype)
+        of its data inputs, in feed order; the pools follow."""
+        main = framework.Program()
+        with framework.program_guard(main, framework.Program()), \
+                framework.unique_name.guard():
+            data = {slot: layers.data(
+                name=f"{prefix}_{fname}", shape=list(shape),
+                dtype=dt, append_batch_size=False)
+                for slot, fname, shape, dt in feeds}
+            pools = [layers.data(
+                name=f"{prefix}_pool" + (str(i) if i else ""),
+                shape=shape, dtype=dt, append_batch_size=False)
+                for i, (shape, dt) in enumerate(pool_specs)]
+            out, pools_out, logits, picks, st = tfl.block_paged_op(
+                kind, data, pools, steps=steps, stats=stats, **common)
+        return {"program": main.clone(for_test=True),
+                "feeds": tuple(f"{prefix}_{f[1]}" for f in feeds)
+                + tuple(p.name for p in pools),
+                "fetch": [out] + pools_out + [logits, picks, st],
+                "extras": ("logits", "picks", "stats")}
+
+    def tables(b):
+        out = [("Table", "table", [b, pages_per_seq], "int32")]
+        if ring is not None:
+            out.append(("RingTable", "ring_table",
+                        [b, ring["pages_per_seq"]], "int32"))
+        return out
+
+    prefill = {
+        bucket: bundle("prefill", "pp", [
+            ("Tokens", "tokens", [1, bucket], "int64"),
+            ("Lens", "lens", [1], "int32"), *tables(1)])
+        for bucket in prefill_buckets_reached(prompt_buckets,
+                                              chunk_size)}
+    decode = bundle("decode", "dc", [
+        ("Tokens", "tokens", [max_batch], "int64"),
+        ("Positions", "positions", [max_batch], "int32"),
+        *tables(max_batch)], steps=decode_block)
+    chunk = None
+    if chunk_size is not None:
+        cs = int(chunk_size)
+        if cs < 1:
+            raise ValueError(f"chunk_size must be >= 1, got {cs}")
+        chunk = bundle("prefill_chunk", "ck", [
+            ("Tokens", "tokens", [1, cs], "int64"),
+            ("Lens", "lens", [1], "int32"),
+            ("Offsets", "offsets", [1], "int32"), *tables(1)])
+    return PagedDecodePrograms(
+        cfg, None, page_size, pages_per_seq, n_pages, max_batch,
+        prefill, decode, None, list(pool_specs), None,
+        chunk=chunk, chunk_size=None if chunk is None else cs,
+        stats=stats, ring=ring)
 
 
 LATENT_MOE_TINY = LatentMoEConfig(

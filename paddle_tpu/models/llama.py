@@ -369,12 +369,21 @@ class PagedDecodePrograms:
     when spec) are the (shape, dtype) of each pool the engine allocates
     and round-trips through every dispatch: ``[L, n_pages, page_size]``
     followed by one entry of the model's ``cache_spec()``. ``stats``
-    names the counters a ``stats`` fetch holds, in its order."""
+    names the counters a ``stats`` fetch holds, in its order.
+
+    Every pool is of the ``sequence`` cache kind (pages for as long as
+    the request lives, ``pages_per_seq`` a row, one table) unless
+    ``ring`` says otherwise: ``{"window": w, "pages_per_seq": pages a
+    row's ring has, "n_pages": the ring pools' pages, null page
+    included, "pools": the indices in pool_specs of the pools that are
+    rings}``, the ``window`` cache kind of a model with window attention
+    layers. Every program of such a model takes the rows' ring table
+    behind their page table."""
 
     def __init__(self, cfg, draft_cfg, page_size, pages_per_seq,
                  n_pages, max_batch, prefill, decode, spec, pool_specs,
                  draft_pool_specs, draft_prefill=None, chunk=None,
-                 chunk_size=None, stats=()):
+                 chunk_size=None, stats=(), ring=None):
         self.cfg = cfg
         self.draft_cfg = draft_cfg
         self.page_size = page_size
@@ -391,6 +400,7 @@ class PagedDecodePrograms:
         self.pool_specs = pool_specs
         self.draft_pool_specs = draft_pool_specs
         self.stats = tuple(stats)
+        self.ring = ring
 
 
 def prefill_buckets_reached(prompt_buckets, chunk_size):

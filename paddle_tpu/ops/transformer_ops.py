@@ -7,6 +7,10 @@ fusion_lstm_op.cc etc. are the CUDA-era analogues): the hot path is one
 op the compiler can schedule as a unit, instead of a softmax/matmul
 chain.
 """
+import contextlib
+import copy
+import functools
+import itertools
 import math
 
 import numpy as np
@@ -271,7 +275,24 @@ class BlockKinds:
     ``mhc`` (manifold-constrained hyper-connections, arXiv:2512.24880):
     ``n_streams`` residual streams mixed by per-token matrices, the
     stream-to-stream one made doubly stochastic by ``sinkhorn_iters``
-    rounds; ``plain`` has one stream and no such parameters."""
+    rounds; ``plain`` has one stream and no such parameters.
+
+    ``gqa`` takes a key width ``key_dim`` beside a value width ``v_dim``
+    (None / 0: both the query projection's width over the heads), of
+    which the first ``rotary_dim`` are rotated (None: all of them), and a
+    ``value_scale`` on the values. A stack may MIX KINDS OF ATTENTION
+    LAYER: ``attn_kinds`` is then a tuple of dicts, one a kind, each with
+    its ``name`` (the ``attn/<name>`` and ``cache/<name>`` scopes of a
+    trace), ``n_kv``, rotary ``base``, ``window`` (None: every earlier
+    position is seen; w: the query and the w - 1 before it), ``sink``
+    (whether the layer's parameters hold a learned scalar a head,
+    ``Sink``, that the softmax counts as one more column of its
+    denominator), ``stack`` (the prefix of the op's slots that hold this
+    kind's stacked layers) and ``pools`` (which of the model's cache
+    pools are this kind's: a kind with a window keeps a RING of the last
+    positions, a kind without keeps the whole sequence), and
+    ``layer_kinds`` names each layer's kind by its index there.
+    ``of(k)`` is this object at kind ``k``."""
 
     def __init__(self, *, n_heads, n_kv=None, base=10000.0, eps=1e-6,
                  attention="gqa", ffn="swiglu", residual="plain",
@@ -279,7 +300,9 @@ class BlockKinds:
                  n_group=1, topk_group=1, experts_first=0,
                  kv_rank=0, rope_dim=0, nope_dim=0, v_dim=0,
                  rope_inv_freq=None, softmax_scale=None, n_streams=1,
-                 sinkhorn_iters=0, hc_eps=1e-6, hc_clamp=(-30.0, 30.0)):
+                 sinkhorn_iters=0, hc_eps=1e-6, hc_clamp=(-30.0, 30.0),
+                 key_dim=None, rotary_dim=None, value_scale=1.0,
+                 attn_kinds=None, layer_kinds=None):
         for kind, table in ((attention, _ATTENTION), (ffn, _FFN),
                             (residual, _RESIDUAL)):
             if kind not in table:
@@ -298,18 +321,44 @@ class BlockKinds:
         self.softmax_scale = softmax_scale
         self.n_streams, self.sinkhorn_iters = n_streams, sinkhorn_iters
         self.hc_eps, self.hc_clamp = hc_eps, tuple(hc_clamp)
+        self.key_dim, self.rotary_dim = key_dim, rotary_dim
+        self.value_scale = value_scale
+        self.window, self.sink = None, False
+        self.attn_kinds = None if attn_kinds is None \
+            else tuple(dict(k) for k in attn_kinds)
+        self.layer_kinds = None if layer_kinds is None \
+            else tuple(int(k) for k in layer_kinds)
+
+    def of(self, k):
+        """These kinds with attention kind ``k``'s own values in place."""
+        kind = self.attn_kinds[k]
+        out = copy.copy(self)
+        out.n_kv, out.base = kind["n_kv"], kind["base"]
+        out.window, out.sink = kind["window"], kind["sink"]
+        return out
 
 
 def _gqa_attention(kinds, p, u, pos, attend_fn):
-    """Roped grouped-query projections; ``attend_fn(q, (k, v))`` owns the
-    attention and any cache."""
+    """Grouped-query projections, the first ``rotary_dim`` widths of every
+    query and key head rotated (all of them where None), the values times
+    ``value_scale``; ``attend_fn(q, (k, v))`` owns the attention and any
+    cache."""
     b, t, _ = u.shape
-    hd = p["Wq"].shape[-1] // kinds.n_heads
-    q = apply_rope_at(qmat(u, p, "Wq").reshape(b, t, kinds.n_heads, hd),
-                      pos, kinds.base)
-    k = apply_rope_at(qmat(u, p, "Wk").reshape(b, t, kinds.n_kv, hd),
-                      pos, kinds.base)
-    v = qmat(u, p, "Wv").reshape(b, t, kinds.n_kv, hd)
+    hd = kinds.key_dim or p["Wq"].shape[-1] // kinds.n_heads
+    rd = kinds.rotary_dim
+
+    def rotate(x):
+        if rd is None or rd == hd:
+            return apply_rope_at(x, pos, kinds.base)
+        return jnp.concatenate(
+            [apply_rope_at(x[..., :rd], pos, kinds.base), x[..., rd:]],
+            axis=-1)
+
+    q = rotate(qmat(u, p, "Wq").reshape(b, t, kinds.n_heads, hd))
+    k = rotate(qmat(u, p, "Wk").reshape(b, t, kinds.n_kv, hd))
+    v = qmat(u, p, "Wv").reshape(b, t, kinds.n_kv, -1)
+    if kinds.value_scale != 1.0:
+        v = v * jnp.asarray(kinds.value_scale, v.dtype)
     return qmat(attend_fn(q, (k, v)), p, "Wo")
 
 
@@ -1165,6 +1214,13 @@ PAGED_STATS = ("moe_assignments_total", "moe_max_load_total",
                "moe_decode_expert_calls_total",
                "moe_decode_experts_touched_total",
                "latent_tokens_read_total", "moe_held_assignments_total")
+# what a model that mixes kinds of attention layer counts besides, over
+# decode steps alone: the cache positions its active rows attended, summed
+# over the layers that keep the whole sequence and over the layers with a
+# window (layers x rows x positions, so entry bytes x these is what the
+# steps had to read of the cache)
+HYBRID_STATS = PAGED_STATS + ("attn_full_positions_total",
+                              "attn_window_positions_total")
 
 # keys a prefill window expands at a time (latent attention): scores of
 # [heads, window, keys] float32, never of the whole cache. At most
@@ -1214,11 +1270,39 @@ class _PagedRunner:
     they lie. The dense view holds bitwise the same values the pools
     do, so both forms see identical caches. int8 ``<Slot>Scale``
     companions ride along in ``params`` exactly as in the contiguous
-    runner (qmat)."""
+    runner (qmat).
+
+    A CACHE KIND is how long a layer's entries live. ``sequence``: as
+    long as the request, a page for every ``page_size`` positions,
+    reached through ``table`` [B, pages_per_seq]. ``window`` (a layer
+    that attends the query and the ``w - 1`` positions before it): a RING
+    of ``ring_table.shape[1]`` pages a row, position ``p`` at page ``(p //
+    page_size) % ring pages``, offset ``p % page_size``, so a row holds
+    its last ring's worth of positions and no more. A model whose
+    ``BlockKinds`` mix attention kinds (``attn_kinds`` / ``layer_kinds``)
+    has, for each kind, its own pools ``[layers of the kind, pages,
+    page_size, heads * width]`` (the kind's own head count; an entry lies
+    FLAT in its page: 4 heads of 192 are 6 lane tiles, where ``[4, 192]``
+    had the chip re-lay the whole pool on its way into and out of every
+    program, 11 of a decode program's 83 ms: PERF.md section 6, PR 33),
+    its own parameter stack (``stacks[prefix]``, shapes differ between
+    kinds) and its table; ``_stack_forward`` walks the pattern a RUN of
+    same-kind layers at a time: one scan a run of window layers, and the
+    layers that keep the whole sequence by their own number. A
+    prefill window through a window layer attends its own keys and the
+    ``w - 1`` before them (read from the ring BEFORE it is written), in
+    bands of two w-blocks a query block, and leaves its last real
+    positions in the ring; through a sequence layer it folds the row's
+    pages a block of keys at a time under a running softmax, so neither
+    ever holds [heads, window, kmax] scores. The dense form gathers the
+    window kinds' rings stacked, [layers, B, ring, ...], and of the layers
+    that keep the whole sequence a view EACH, [B, g, kmax, d] with heads
+    before positions (``gather_layers``): a step reads its layer's view
+    where it lies, and those layers are taken by number, not by a scan."""
 
     def __init__(self, params, emb_w, fnorm, head, *, n_heads, n_kv,
                  base, eps, page_size, head_scale=None, moe_top_k=2,
-                 kinds=None, lead=None):
+                 kinds=None, lead=None, stacks=None):
         self.params = params
         self.emb_w = emb_w
         self.fnorm = fnorm
@@ -1234,6 +1318,9 @@ class _PagedRunner:
             n_heads=n_heads, n_kv=n_kv, base=base, eps=eps,
             moe_top_k=moe_top_k)
         self.lead = lead
+        self.stacks = stacks    # attention kind's prefix -> its layers
+        self.ring_table = None  # [B, ring pages]: the window kinds' table
+        self.lens = None        # [B]: a prefill window's real tokens
         self.valid = None       # [B, T] bool: the tokens Stats counts
         self.pick_at = None     # [B]: the window position Picks reports
         self.seen = None        # positions a prefill window can see at
@@ -1242,7 +1329,8 @@ class _PagedRunner:
         self._loads = []        # the last forward's routed loads [n, E]
         self.picks = None       # and its picks at pick_at, [n, B, K]
         if self.kinds.attention == "gqa":
-            self.hd = params["Wq"].shape[-1] // n_heads
+            self.hd = self.kinds.key_dim \
+                or params["Wq"].shape[-1] // n_heads
             self.rep = n_heads // n_kv
 
     def embed(self, tokens):
@@ -1272,6 +1360,144 @@ class _PagedRunner:
                          v_all.astype(jnp.float32))
         return out.astype(q.dtype).reshape(
             b, t_len, self.n_heads * self.hd)
+
+    def _attend_masked(self, q, k_all, v_all, q_pos, k_pos=None,
+                       window=None, sink=None, head_major=False):
+        """GQA attention of queries q [B, T, heads, kd] at ``q_pos``
+        [B, T] over keys [B, K, g, kd] and values [B, K, g, vd]
+        (``head_major``: [B, g, K, *]) in the cache's type, accumulated
+        in float32. Key j of row b is position ``k_pos[b, j]`` (None: j;
+        negative: no key there); a query sees a key at or before itself
+        and, with ``window``, fewer than ``window`` positions back.
+        ``sink`` [heads]: one more column of the softmax's denominator a
+        head, that adds to nothing else."""
+        b, t = q_pos.shape
+        g, n_keys = (k_all.shape[1], k_all.shape[2]) if head_major \
+            else (k_all.shape[2], k_all.shape[1])
+        keys = "bgkd" if head_major else "bkgd"
+        f32 = jnp.float32
+        qg = q.reshape(b, t, g, self.n_heads // g, q.shape[-1])
+        kp = jnp.arange(n_keys, dtype=jnp.int32)[None] \
+            if k_pos is None else k_pos
+        back = q_pos[:, :, None] - kp[:, None, :]            # [B, T, K]
+        mask = (back >= 0) & (kp[:, None, :] >= 0)
+        if window is not None:
+            mask = mask & (back < window)
+        s = jnp.einsum(f"bqgrd,{keys}->bgrqk", qg, k_all,
+                       preferred_element_type=f32) * q.shape[-1] ** -0.5
+        s = jnp.where(mask[:, None, None], s, -1e30)
+        m = jnp.max(s, axis=-1, keepdims=True)
+        if sink is not None:
+            sk = sink.astype(f32).reshape(1, g, -1, 1, 1)
+            m = jnp.maximum(m, sk)
+        e = jnp.exp(s - m)
+        l = jnp.sum(e, axis=-1, keepdims=True)
+        if sink is not None:
+            l = l + jnp.exp(sk - m)
+        out = jnp.einsum(f"bgrqk,{keys}->bqgrd",
+                         (e / l).astype(v_all.dtype), v_all,
+                         preferred_element_type=f32)
+        return out.astype(q.dtype).reshape(b, t, -1)
+
+    def _gqa_blocked(self, q, read_block, n_blocks, kb, q_pos, sink=None):
+        """GQA attention of a prefill window over a whole-sequence cache,
+        a block of ``kb`` positions at a time (``read_block(i) -> (keys
+        [B, kb, g, kd], values [B, kb, g, vd])``) under a running softmax:
+        _latent_expanded's fold without the expansion. Only the blocks
+        that hold a position some query may see are visited."""
+        b, t = q_pos.shape
+        f32 = jnp.float32
+        scale = q.shape[-1] ** -0.5
+
+        def fold(i, carry):
+            m, l, acc = carry
+            kblk, vblk = read_block(i)
+            g = kblk.shape[2]
+            qg = q.reshape(b, t, g, self.n_heads // g, q.shape[-1])
+            s = jnp.einsum("bqgrd,bkgd->bgrqk", qg, kblk,
+                           preferred_element_type=f32) * scale
+            k_pos = i * kb + jnp.arange(kb, dtype=jnp.int32)
+            s = jnp.where((k_pos[None, None] <= q_pos[:, :, None])
+                          [:, None, None], s, -1e30)
+            m2 = jnp.maximum(m, jnp.max(s, axis=-1))
+            w = jnp.exp(s - m2[..., None])
+            a = jnp.exp(m - m2)
+            acc = acc * a[..., None] + jnp.einsum(
+                "bgrqk,bkgd->bgrqd", w.astype(vblk.dtype), vblk,
+                preferred_element_type=f32)
+            return m2, l * a + jnp.sum(w, axis=-1), acc
+
+        k0, v0 = jax.eval_shape(read_block, 0)
+        g, r = k0.shape[2], self.n_heads // k0.shape[2]
+        init = (jnp.full((b, g, r, t), -1e30, f32),
+                jnp.zeros((b, g, r, t), f32),
+                jnp.zeros((b, g, r, t, v0.shape[-1]), f32))
+        seen = jnp.minimum(jnp.max(q_pos) // kb + 1, n_blocks)
+        m, l, acc = jax.lax.fori_loop(0, seen, fold, init)
+        if sink is not None:
+            sk = sink.astype(f32).reshape(1, g, r, 1)
+            m2 = jnp.maximum(m, sk)
+            a = jnp.exp(m - m2)
+            acc, l = acc * a[..., None], l * a + jnp.exp(sk - m2)
+        out = jnp.moveaxis(acc / l[..., None], 3, 1)   # [B, T, g, r, vd]
+        return out.astype(q.dtype).reshape(b, t, -1)
+
+    def _ring_pages(self, pos):
+        """(page, offset) of positions ``pos`` [B, n] in the rows' rings."""
+        ps, n_ring = self.page_size, self.ring_table.shape[1]
+        return (jnp.take_along_axis(self.ring_table, (pos // ps) % n_ring,
+                                    axis=1), pos % ps)
+
+    def _window_prefill(self, q, entries, rings, lyr, q_pos, window,
+                        sink):
+        """A prefill window through a window layer: (out, the rings
+        written). The window's own keys and values ``entries`` and the
+        ``window`` positions before its first, which the ring holds (read
+        before anything is written), are cut into blocks of ``window``
+        positions, and a block of queries attends its own block and the
+        one before it: no other key can lie inside its band. Then the
+        last ring's worth of the row's REAL positions (``lens``) goes
+        into the ring; a padding position is never written, it would
+        take the place of a real one."""
+        b, t = q_pos.shape
+        w = window
+        pos0 = q_pos[:, 0]
+        prev = pos0[:, None] - w + jnp.arange(w, dtype=jnp.int32)[None]
+        pg, off = self._ring_pages(jnp.maximum(prev, 0))
+        nb = -(-t // w)
+
+        def banded(x, before=None):
+            """[B, t, ...] (behind ``before`` [B, w, ...]) -> the blocks
+            [B * nb, w or 2w, ...]."""
+            x = jnp.pad(x, [(0, 0), (0, nb * w - x.shape[1])]
+                        + [(0, 0)] * (x.ndim - 2))
+            if before is None:
+                return x.reshape((b * nb, w) + x.shape[2:])
+            x = jnp.concatenate([before, x], axis=1).reshape(
+                (b, nb + 1, w) + x.shape[2:])
+            return jnp.concatenate([x[:, :-1], x[:, 1:]], axis=2).reshape(
+                (b * nb, 2 * w) + x.shape[3:])
+
+        at = pos0[:, None] + jnp.arange(nb * w, dtype=jnp.int32)[None]
+        keys, values = (
+            banded(e, ring[lyr, pg, off].reshape((b, w) + e.shape[2:]))
+            for e, ring in zip(entries, rings))
+        out = self._attend_masked(
+            banded(q), keys, values, at.reshape(b * nb, w),
+            k_pos=banded(at, prev), window=w, sink=sink)
+        out = out.reshape(b, nb * w, -1)[:, :t]
+        # the ring: the last ``ring`` real positions of the window
+        n_ring = self.ring_table.shape[1] * self.page_size
+        j = self.lens[:, None] - n_ring \
+            + jnp.arange(n_ring, dtype=jnp.int32)[None]
+        pg, off = self._ring_pages(pos0[:, None] + j)
+        src = jnp.clip(j, 0, t - 1)
+        rows = jnp.arange(b)[:, None]
+        rings = tuple(
+            ring.at[lyr, jnp.where(j >= 0, pg, ring.shape[1]), off].set(
+                e[rows, src].reshape(b, n_ring, -1), mode="drop")
+            for e, ring in zip(entries, rings))
+        return out, rings
 
     def _kv_up(self, p):
         """A layer's latent -> per-head [key | value] expansion,
@@ -1357,21 +1583,25 @@ class _PagedRunner:
 
     def _stack_forward(self, h, pools, q_pos, attend_write):
         """The layers, shared by both forms: the leading layers one by
-        one, then the scan over the stacked ones. The whole [L, ...]
-        caches ride in the carry and the layer index in ``xs``;
-        ``attend_write(p, q, entries, pools, layer) -> (out, pools2)``
-        owns layer ``layer``'s cache update + attend."""
+        one, then the scan over the stacked ones (where the model mixes
+        attention kinds: a scan over each run of same-kind layers, in
+        the pattern's order). The whole [L, ...] caches ride in the carry
+        and the layer index in ``xs``; ``attend_write(p, q, entries,
+        pools, layer, kind) -> (out, pools2)`` owns the cache update +
+        attend of layer ``layer`` of its kind's pools (``kind`` None:
+        the model has one kind)."""
         self._loads, self.picks = [], None
 
-        def layer(h, pools, p, lyr, ffn=None):
+        def layer(h, pools, p, lyr, ffn=None, kind=None):
             held = {}
 
             def attend(q, entries):
                 out, held["pools"] = attend_write(p, q, entries, pools,
-                                                  lyr)
+                                                  lyr, kind)
                 return out
 
-            h, routing = block_forward(self.kinds, p, h, q_pos, attend,
+            kinds = self.kinds if kind is None else self.kinds.of(kind)
+            h, routing = block_forward(kinds, p, h, q_pos, attend,
                                        ffn=ffn, valid=self.valid)
             if routing is not None:         # (load, picks at pick_at)
                 at = (jnp.zeros((h.shape[0],), jnp.int32)
@@ -1380,35 +1610,95 @@ class _PagedRunner:
                            routing[1][jnp.arange(h.shape[0]), at])
             return h, held["pools"], routing
 
+        lk = self.kinds.layer_kinds
         n_lead = 0
+        # layers of each kind already run: the next one's place in its
+        # kind's pools
+        done = [0] * len(self.kinds.attn_kinds or ())
         if self.lead is not None:
             n_lead = jax.tree_util.tree_leaves(self.lead)[0].shape[0]
             for i in range(n_lead):
+                kind = None if lk is None else lk[i]
                 h, pools, _ = layer(
                     h, pools, {s: w[i] for s, w in self.lead.items()},
-                    i, ffn="swiglu")
+                    i if kind is None else done[kind], ffn="swiglu",
+                    kind=kind)
+                if kind is not None:
+                    done[kind] += 1
 
-        # the routed experts' stacks stay whole outside the scan's xs: a
-        # scan slices its xs, and a slice that feeds the grouped matmul
-        # is a copy of every expert of the layer, on every call
-        held = {s: w for s, w in self.params.items()
-                if s in _EXPERT_SLOTS and self.kinds.ffn == "routed"
-                and s + "Scale" not in self.params}
-        sliced = {s: w for s, w in self.params.items() if s not in held}
+        def split(params):
+            """(held, sliced): the routed experts' stacks stay whole
+            outside the scan's xs: a scan slices its xs, and a slice that
+            feeds the grouped matmul is a copy of every expert of the
+            layer, on every call."""
+            held = {s: w for s, w in params.items()
+                    if s in _EXPERT_SLOTS and self.kinds.ffn == "routed"
+                    and s + "Scale" not in params}
+            return held, {s: w for s, w in params.items()
+                          if s not in held}
 
-        def scanned(carry, xs):
-            p = dict(xs[0], **held)
-            if held:
-                p["ExpertsOf"] = xs[1]
-            h, pools, routing = layer(*carry, p, xs[1] + n_lead)
-            return (h, pools), routing
+        if lk is None:
+            held, sliced = split(self.params)
 
-        n = pools[0].shape[0] - n_lead
-        (h, pools), routing = jax.lax.scan(
-            scanned, (h, pools),
-            (sliced, jnp.arange(n, dtype=jnp.int32)))
-        if routing is not None:
-            self._loads, self.picks = routing
+            def scanned(carry, xs):
+                p = dict(xs[0], **held)
+                if held:
+                    p["ExpertsOf"] = xs[1]
+                h, pools, routing = layer(*carry, p, xs[1] + n_lead)
+                return (h, pools), routing
+
+            n = pools[0].shape[0] - n_lead
+            (h, pools), routing = jax.lax.scan(
+                scanned, (h, pools),
+                (sliced, jnp.arange(n, dtype=jnp.int32)))
+            if routing is not None:
+                self._loads, self.picks = routing
+            return h, pools
+
+        # runs of same-kind layers: (kind, first layer of the run in its
+        # kind's stack, layers). A run's scan takes layer j of the stack
+        # inside its body, which is what a scan does with its xs; slicing
+        # the run out of the stack beforehand would copy its weights
+        first, nxt, routings = list(done), [0] * len(done), []
+        for kind, run in itertools.groupby(lk[n_lead:]):
+            count = len(list(run))
+            start, nxt[kind] = nxt[kind], nxt[kind] + count
+            spec = self.kinds.attn_kinds[kind]
+            held, sliced = split(self.stacks[spec["stack"]])
+            if spec["window"] is None:
+                # a layer that keeps the whole sequence is taken by its
+                # own number, not a scan's: the dense form then holds a
+                # view a layer and reads it where it lies, where a
+                # traced index would copy 17,472 positions x 24 rows of
+                # keys and values out of a stacked view on every step
+                # (9 of a 31.6 ms step: PERF.md section 6, PR 33). Such
+                # layers are one in six of the pattern, so this is no
+                # loop over the stack
+                for j in range(start, start + count):
+                    p = dict({s: w[j] for s, w in sliced.items()}, **held)
+                    if held:
+                        p["ExpertsOf"] = jnp.int32(j)
+                    h, pools, routing = layer(h, pools, p, j + first[kind],
+                                              kind=kind)
+                    routings.append(None if routing is None else tuple(
+                        r[None] for r in routing))
+                continue
+
+            def scanned(carry, j, kind=kind, held=held, sliced=sliced):
+                p = dict({s: w[j] for s, w in sliced.items()}, **held)
+                if held:
+                    p["ExpertsOf"] = j
+                h, pools, routing = layer(*carry, p, j + first[kind],
+                                          kind=kind)
+                return (h, pools), routing
+
+            (h, pools), routing = jax.lax.scan(
+                scanned, (h, pools),
+                jnp.arange(start, start + count, dtype=jnp.int32))
+            routings.append(routing)
+        if all(r is not None for r in routings):
+            self._loads, self.picks = (
+                jnp.concatenate([r[i] for r in routings]) for i in (0, 1))
         return h, pools
 
     # -- paged form (prefill) --------------------------------------------
@@ -1428,7 +1718,40 @@ class _PagedRunner:
         blocks = jnp.pad(table[:, :n_read],
                          ((0, 0), (0, n_blocks * ppb - n_read)))
 
-        def attend_write(p, q, entries, pools, lyr):
+        def attend_kind(p, q, entries, pools, lyr, kind):
+            """A layer of one of several attention kinds: its own pools."""
+            spec = self.kinds.attn_kinds[kind]
+            mine = [pools[i] for i in spec["pools"]]
+            sink = p["Sink"] if spec["sink"] else None
+            if spec["window"] is not None:
+                with jax.named_scope("attn/" + spec["name"]):
+                    out, mine = self._window_prefill(
+                        q, entries, mine, lyr, q_pos, spec["window"], sink)
+            else:
+                with jax.named_scope("attn/" + spec["name"]):
+                    pg = jnp.take_along_axis(table, q_pos // ps, axis=1)
+                    mine = [pl.at[lyr, pg, q_pos % ps].set(
+                        e.reshape(b, t_len, -1))
+                        for pl, e in zip(mine, entries)]
+
+                    def read_block(i):
+                        tb = jax.lax.dynamic_slice_in_dim(
+                            blocks, i * ppb, ppb, axis=1)
+                        return tuple(
+                            pl[lyr, tb].reshape((b, ppb * ps)
+                                                + e.shape[2:])
+                            for pl, e in zip(mine, entries))
+
+                    out = self._gqa_blocked(q, read_block, n_blocks,
+                                            ppb * ps, q_pos, sink)
+            pools = list(pools)
+            for i, pl in zip(spec["pools"], mine):
+                pools[i] = pl
+            return out, tuple(pools)
+
+        def attend_write(p, q, entries, pools, lyr, kind=None):
+            if kind is not None:
+                return attend_kind(p, q, entries, pools, lyr, kind)
             pg = jnp.take_along_axis(table, q_pos // ps, axis=1)
             pools = tuple(pl.at[lyr, pg, q_pos % ps].set(e)
                           for pl, e in zip(pools, entries))
@@ -1449,6 +1772,26 @@ class _PagedRunner:
         return (h,) + tuple(pools)
 
     # -- dense form (decode / spec loops) --------------------------------
+    def pool_view(self, i, table):
+        """How the dense form sees pool ``i``: (its table, the pair of
+        methods that gather its view and write it back, a named scope
+        for the two, ``cache/<the kind's name>``, where the model has
+        several kinds, so that a trace tells them apart). A model with one
+        kind of layer: one stacked view [L, B, kmax, ...]; a window
+        kind: the rows' rings, stacked; the sequence kind of a model
+        that has both: a view a layer, heads before positions."""
+        for spec in self.kinds.attn_kinds or ():
+            if i in spec["pools"]:
+                scope = jax.named_scope("cache/" + spec["name"])
+                if spec["window"] is not None:
+                    return (self.ring_table,
+                            (self.gather, self.write_back_ring), scope)
+                return (table, (functools.partial(
+                    self.gather_layers, heads=spec["n_kv"]),
+                    self.write_back_layers), scope)
+        return (table, (self.gather, self.write_back),
+                contextlib.nullcontext())
+
     def gather(self, pages, table):
         """[L, P, ps, *entry] pool -> dense [L, B, kmax, *entry] view of
         each row's pages, in table order."""
@@ -1490,12 +1833,100 @@ class _PagedRunner:
         return pages.at[lyr, pg[None], (q_pos % ps)[None]].set(
             entries, mode="drop")
 
+    def gather_layers(self, pages, table, heads):
+        """[L, P, ps, g * d] pool -> a view a layer, each [B, g, kmax, d]:
+        every row's pages in table order, heads before positions, which
+        is how a step's products want them, so that a step reads its
+        layer's view where it lies: no slice out of a stacked view, no
+        re-layout (the transposition is made here, once a dispatch)."""
+        b = table.shape[0]
+        return tuple(
+            jnp.moveaxis(pages[lyr, table].reshape(
+                b, table.shape[1] * self.page_size, heads, -1), 1, 2)
+            for lyr in range(pages.shape[0]))
+
+    def write_back_layers(self, pages, dense, table, pos0, n):
+        """write_back for the views ``gather_layers`` made."""
+        ps = self.page_size
+        kmax = table.shape[1] * ps
+        q_pos = pos0[:, None] + jnp.arange(n, dtype=jnp.int32)[None]
+        at = jnp.minimum(q_pos, kmax - 1)
+        rows = jnp.arange(table.shape[0])[:, None]
+        entries = jnp.stack([d[rows, :, at].reshape(at.shape + (-1,))
+                             for d in dense])
+        pages, entries = jax.lax.optimization_barrier((pages, entries))
+        pg = jnp.where(q_pos < kmax,
+                       jnp.take_along_axis(table, at // ps, axis=1),
+                       pages.shape[1])
+        lyr = jnp.arange(pages.shape[0])[:, None, None]
+        return pages.at[lyr, pg[None], (q_pos % ps)[None]].set(
+            entries, mode="drop")
+
+    def write_back_ring(self, pages, dense, table, pos0, n):
+        """write_back for a window kind's pool, view and ring ``table``:
+        position p lies at ``p % ring`` of the view and in page ``(p //
+        page_size) % table.shape[1]`` of the row's ring, and no position
+        is beyond it."""
+        ps = self.page_size
+        q_pos = pos0[:, None] + jnp.arange(n, dtype=jnp.int32)[None]
+        lyr = jnp.arange(pages.shape[0])[:, None, None]
+        rows = jnp.arange(table.shape[0])[None, :, None]
+        entries = dense[lyr, rows, (q_pos % (table.shape[1] * ps))[None]]
+        pages, entries = jax.lax.optimization_barrier((pages, entries))
+        pg = jnp.take_along_axis(table, (q_pos // ps) % table.shape[1],
+                                 axis=1)
+        return pages.at[lyr, pg[None], (q_pos % ps)[None]].set(entries)
+
     def forward_dense(self, h, *dense_pos0_len):
         *dense, pos0, t_len = dense_pos0_len
         rows = jnp.arange(h.shape[0])
         q_pos = pos0[:, None] + jnp.arange(t_len, dtype=jnp.int32)[None]
 
-        def attend_write(p, q, entries, dense, lyr):
+        def attend_kind(p, q, entries, dense, lyr, kind):
+            """A layer of one of several attention kinds: its own views.
+            A window kind's view is the row's ring, position p at ``p %
+            ring``; index i then holds the last position at or before
+            the window's last query that falls there."""
+            spec = self.kinds.attn_kinds[kind]
+            sink = p["Sink"] if spec["sink"] else None
+            mine = [dense[i] for i in spec["pools"]]
+            if spec["window"] is None:
+                with jax.named_scope("attn/" + spec["name"]):
+                    # a view a layer [B, g, kmax, d] (gather_layers);
+                    # ``lyr`` is the layer's own number (_stack_forward)
+                    mine = [d[:lyr] + (d[lyr].at[
+                        rows[:, None], :, q_pos].set(e, mode="drop"),)
+                        + d[lyr + 1:] for d, e in zip(mine, entries)]
+                    out = self._attend_masked(
+                        q, mine[0][lyr], mine[1][lyr], q_pos, sink=sink,
+                        head_major=True)
+            else:
+                with jax.named_scope("attn/" + spec["name"]):
+                    ring = mine[0].shape[2]
+                    if ring < spec["window"] + t_len - 1:
+                        raise ValueError(
+                            f"a ring of {ring} positions cannot hold a "
+                            f"window of {spec['window']} behind {t_len} "
+                            "queries")
+                    mine = [d.at[lyr, rows[:, None], q_pos % ring].set(
+                        e.reshape(e.shape[:2] + (-1,)))
+                        for d, e in zip(mine, entries)]
+                    last = q_pos[:, -1:]
+                    k_pos = last - (last - jnp.arange(
+                        ring, dtype=jnp.int32)[None]) % ring
+                    out = self._attend_masked(
+                        q, *(d[lyr].reshape(d.shape[1:3]
+                                            + (spec["n_kv"], -1))
+                             for d in mine), q_pos, k_pos=k_pos,
+                        window=spec["window"], sink=sink)
+            dense = list(dense)
+            for i, d in zip(spec["pools"], mine):
+                dense[i] = d
+            return out, tuple(dense)
+
+        def attend_write(p, q, entries, dense, lyr, kind=None):
+            if kind is not None:
+                return attend_kind(p, q, entries, dense, lyr, kind)
             dense = tuple(d.at[lyr, rows[:, None], q_pos].set(e)
                           for d, e in zip(dense, entries))
             if self.kinds.attention == "latent":
@@ -1525,7 +1956,17 @@ class _PagedRunner:
         expert-layer calls x experts held, the experts among them that a
         token reached, and the cache positions its active rows
         attended."""
-        out = [jnp.int32(0)] * len(PAGED_STATS)
+        hybrid = self.kinds.layer_kinds is not None
+        out = [jnp.int32(0)] * len(HYBRID_STATS if hybrid else PAGED_STATS)
+        if decode and hybrid:
+            n = jnp.where(self.valid[:, 0], positions + 1, 0)
+            for k, spec in enumerate(self.kinds.attn_kinds):
+                layers = self.kinds.layer_kinds.count(k)
+                if spec["window"] is None:
+                    out[6] = out[6] + layers * jnp.sum(n)
+                else:
+                    out[7] = out[7] + layers * jnp.sum(
+                        jnp.minimum(n, spec["window"]))
         if len(self._loads):
             loads = self._loads                      # [layers, E held]
             out[0] = (jnp.sum(self.valid) * self.kinds.moe_top_k
@@ -1542,11 +1983,12 @@ class _PagedRunner:
 
 def _make_paged_runner(params, emb_w, fnorm, head, *, n_heads, n_kv,
                        base, eps, page_size, head_scale=None,
-                       moe_top_k=2, kinds=None, lead=None):
+                       moe_top_k=2, kinds=None, lead=None, stacks=None):
     return _PagedRunner(params, emb_w, fnorm, head, n_heads=n_heads,
                         n_kv=n_kv, base=base, eps=eps,
                         page_size=page_size, head_scale=head_scale,
-                        moe_top_k=moe_top_k, kinds=kinds, lead=lead)
+                        moe_top_k=moe_top_k, kinds=kinds, lead=lead,
+                        stacks=stacks)
 
 
 def _paged_model_inputs(ins, prefix=""):
@@ -1581,7 +2023,7 @@ def _paged_prefill(run, tokens, lens, offsets, table, pools):
     [B, V], the pools); ``run.picks`` then holds the routed layers'
     picks at each row's last real token."""
     b, t = tokens.shape
-    run.pick_at = lens - 1
+    run.lens, run.pick_at = lens, lens - 1
     # what Stats counts: real tokens of rows that own a real first page
     run.valid = (jnp.arange(t, dtype=jnp.int32)[None] < lens[:, None]) \
         & (table[:, :1] > 0)
@@ -1600,7 +2042,12 @@ def _paged_decode(run, tok, pos, table, pools, steps, extras=False):
     # dense form: pool -> dense gather once, ``steps`` steps that carry
     # the dense caches in place, their entries written back (_PagedRunner)
     pos = pos.astype(jnp.int32)
-    dense = tuple(run.gather(pl, table) for pl in pools)
+    views = [run.pool_view(i, table) for i in range(len(pools))]
+    dense = []
+    for pl, (tb, (gather, _), scope) in zip(pools, views):
+        with scope:
+            dense.append(gather(pl, tb))
+    dense = tuple(dense)
     run.valid = table[:, :1] > 0        # a live row owns a real first page
 
     def step(carry, _):
@@ -1615,11 +2062,14 @@ def _paged_decode(run, tok, pos, table, pools, steps, extras=False):
                  stats + run.stats(True, pos)),
                 (nxt, logits, jnp.moveaxis(run.picks, 0, 1)))
 
-    stats0 = jnp.zeros((len(PAGED_STATS),), jnp.int32) if extras else None
+    stats0 = jnp.zeros_like(run.stats(False)) if extras else None
     (_, _, dense, stats), ys = jax.lax.scan(
         step, (tok, pos, dense, stats0), None, length=steps)
-    pools = [run.write_back(pl, d, table, pos, steps)
-             for pl, d in zip(pools, dense)]
+    back = []
+    for pl, d, (tb, (_, write_back), scope) in zip(pools, dense, views):
+        with scope:
+            back.append(write_back(pl, d, tb, pos, steps))
+    pools = back
     if not extras:
         return jnp.moveaxis(ys, 0, 1), pools
     return (jnp.moveaxis(ys[0], 0, 1), pools, jnp.moveaxis(ys[1], 0, 1),
@@ -1708,13 +2158,16 @@ _BLOCK_SLOTS = (
     "AttnNorm", "MlpNorm", "Wqa", "QNorm", "Wqb", "Wkva", "KvNorm", "Wkvb",
     "Wo", "WGate", "WUp", "WDown", "MoeRouter", "MoeBias", "MoeWGate",
     "MoeWUp", "MoeWDown", "ShWGate", "ShWUp", "ShWDown", "HcAttnPhi",
-    "HcAttnAlpha", "HcAttnBias", "HcMlpPhi", "HcMlpAlpha", "HcMlpBias")
+    "HcAttnAlpha", "HcAttnBias", "HcMlpPhi", "HcMlpAlpha", "HcMlpBias",
+    "Wq", "Wk", "Wv", "Sink")
 
 
 def _block_runner(ins, attrs):
     """The runner of a block_paged_* op: the stacked layers' parameters
     by slot, the leading dense layers' under ``Lead<Slot>``, and the
-    kinds from the attributes."""
+    kinds from the attributes; where these mix attention kinds
+    (``attn_kinds``), each kind's stacked layers under ``<its
+    stack><Slot>`` and the window kinds' table under ``RingTable``."""
     kinds = BlockKinds(
         n_heads=attrs["n_heads"], eps=attrs["epsilon"],
         attention=attrs["attention"], ffn=attrs["ffn"],
@@ -1727,15 +2180,26 @@ def _block_runner(ins, attrs):
         softmax_scale=attrs["softmax_scale"],
         n_streams=attrs["n_streams"],
         sinkhorn_iters=attrs["sinkhorn_iters"], hc_eps=attrs["hc_eps"],
-        hc_clamp=attrs["hc_clamp"])
+        hc_clamp=attrs["hc_clamp"], key_dim=attrs.get("key_dim"),
+        rotary_dim=attrs.get("rotary_dim"),
+        value_scale=attrs.get("value_scale", 1.0),
+        attn_kinds=attrs.get("attn_kinds"),
+        layer_kinds=attrs.get("layer_kinds"))
     params = {s: ins[s][0] for s in _BLOCK_SLOTS if s in ins}
     lead = {s: ins["Lead" + s][0] for s in _BLOCK_SLOTS
             if "Lead" + s in ins}
-    return _make_paged_runner(
+    stacks = {k["stack"]: {s: ins[k["stack"] + s][0] for s in _BLOCK_SLOTS
+                           if k["stack"] + s in ins}
+              for k in kinds.attn_kinds or ()}
+    run = _make_paged_runner(
         params, ins["Emb"][0], ins["FinalNorm"][0], ins["LmHead"][0],
         n_heads=kinds.n_heads, n_kv=kinds.n_kv, base=kinds.base,
         eps=kinds.eps, page_size=attrs["page_size"],
-        moe_top_k=kinds.moe_top_k, kinds=kinds, lead=lead or None)
+        moe_top_k=kinds.moe_top_k, kinds=kinds, lead=lead or None,
+        stacks=stacks or None)
+    if "RingTable" in ins:
+        run.ring_table = ins["RingTable"][0]
+    return run
 
 
 def _block_prefill_outputs(run, nxt, logits, pools):

@@ -124,7 +124,21 @@ _DECODE_COUNTERS = (
     # the sum of the dispatch counters unless JAX dropped a donation as
     # unusable; lost ticks once each time the engine found a pool of its
     # own deleted and replaced them all with zeroed ones
-    "pools_consumed_total", "pools_lost_total")
+    "pools_consumed_total", "pools_lost_total",
+    # a model with window attention layers (PR 33) has caches of two
+    # kinds, and counts on the device, over decode steps, the positions
+    # its active rows attended in the layers of each (HYBRID_STATS:
+    # layers x rows x positions). The engine's own: each turn of a ring
+    # page (a window page whose positions all fell out of the window and
+    # were overwritten where they lay), and, once a decode dispatch over
+    # the active slots, the bytes of cache they hold (pages of every
+    # kind, whole) and the positions resident in them: bytes over
+    # positions is what a cached token costs (30,720 B in the cell's
+    # model were every layer kept whole). Every model ticks the last
+    # two; the first three stay 0 for a model with one cache kind.
+    "attn_full_positions_total", "attn_window_positions_total",
+    "window_pages_recycled_total",
+    "cache_bytes_held_total", "cache_positions_resident_total")
 
 # how long after a program's end the worker keeps polling before it reads
 # the tokens and counters whose host copies set out with the program: the
@@ -321,13 +335,14 @@ class _Slot:
     """One active decode slot: the request, its page set / table row,
     and the per-sequence scheduler state."""
 
-    __slots__ = ("req", "pages", "table", "pos", "cur", "prev",
+    __slots__ = ("req", "pages", "ring", "table", "pos", "cur", "prev",
                  "emitted", "first_token_at")
 
     def __init__(self, req, pages, table, pos, cur, prev, emitted,
-                 first_token_at):
+                 first_token_at, ring=None):
         self.req = req
-        self.pages = pages
+        self.pages = pages            # of the ``sequence`` kind
+        self.ring = ring              # the ``window`` kind's, or None
         self.table = table            # np int32 [pages_per_seq]
         self.pos = pos                # cache length (cur not cached yet)
         self.cur = cur                # last emitted token
@@ -343,11 +358,12 @@ class _ChunkJob:
     chunk installs it), so free-slot accounting and the decode batch
     never see a half-prefilled sequence."""
 
-    __slots__ = ("req", "pages", "table", "off")
+    __slots__ = ("req", "pages", "ring", "table", "off")
 
-    def __init__(self, req, pages, table, off=0):
+    def __init__(self, req, pages, table, off=0, ring=None):
         self.req = req
         self.pages = pages
+        self.ring = ring              # the ``window`` kind's, or None
         self.table = table            # np int32 [pages_per_seq]
         self.off = off                # prompt tokens prefilled so far
 
@@ -360,6 +376,17 @@ class DecodeEngine:
     models/latent_moe.py LatentMoEConfig are the two there are). The
     engine owns the pools, the page tables and the slots, and knows
     nothing else of the model.
+
+    A model's pools are of the ``sequence`` cache kind (pages for as long
+    as the request lives) unless its programs say otherwise
+    (``programs.ring``): the ``window`` kind is a ring of
+    ``ring["pages_per_seq"]`` pages a request, whatever its length
+    (kv_pages.py). One ``PageAllocator`` serves both; a slot or chunk job
+    holds pages of each kind its model has (``pages``, ``ring``) and they
+    are granted together and freed together: admission, retirement,
+    shedding and the handoff blob cover both, and every program takes the
+    rows' table of each kind. A model with one kind has one table and
+    today's programs.
 
     **The pools are the engine's alone and are donated to every
     dispatch**: a program takes ``_pools`` (``_draft_pools``) in, XLA
@@ -381,6 +408,8 @@ class DecodeEngine:
     ``place=None`` dispatches on the process's default device — the
     chip where there is one; the KV pools are created on that default
     device whatever ``place`` says."""
+
+    RING = "window"         # the second cache kind's name in the allocator
 
     def __init__(self, cfg, scope=None, place=None, config=None,
                  draft_cfg=None, auto_start=True, optimize=True):
@@ -426,6 +455,19 @@ class DecodeEngine:
             decode_block=c.decode_block, quantize=c.quantize,
             draft_cfg=draft_cfg, gamma=c.gamma,
             chunk_size=c.chunk_size)
+        # a second cache kind, where the model has window layers: a ring
+        # of pages a request, its own pool of page ids
+        self.ring = self.programs.ring
+        if self.ring is not None:
+            self.allocator.add_kind(self.RING, self.ring["n_pages"])
+        # bytes a page of each kind holds, over all the kind's pools
+        self._page_bytes = {PageAllocator.SEQUENCE: 0, self.RING: 0}
+        for i, (shape, dtype) in enumerate(self.programs.pool_specs):
+            kind = self.RING if self.ring is not None \
+                and i in self.ring["pools"] else PageAllocator.SEQUENCE
+            self._page_bytes[kind] += int(
+                np.prod([shape[0]] + list(shape[2:]))
+                * np.dtype(dtype).itemsize)
         # graph rewrites on every step program (analysis/optimize.py,
         # proven bit-exact by optcheck): the bundles are private
         # clones, so optimizing in place is safe, and each program's
@@ -555,21 +597,23 @@ class DecodeEngine:
         compiles, no matter how requests churn."""
         n = 0
         row = (np.ones((1,), np.int32),
-               np.zeros((1, self.pages_per_seq), np.int32))
+               np.zeros((1, self.pages_per_seq), np.int32),
+               self._ring_rows([None]))
         for bucket in sorted(self.programs.prefill):
             self._run_prefill_program(
                 bucket, np.zeros((1, bucket), np.int64), *row)
             n += 1
             if self.draft_cfg is not None:
                 self._run_draft_prefill_program(
-                    bucket, np.zeros((1, bucket), np.int64), *row)
+                    bucket, np.zeros((1, bucket), np.int64), *row[:2])
                 n += 1
         if self.programs.chunk is not None:
             cs = self.programs.chunk_size
             self._run_chunk_program(
                 np.zeros((1, cs), np.int64), np.ones((1,), np.int32),
                 np.zeros((1,), np.int32),
-                np.zeros((1, self.pages_per_seq), np.int32))
+                np.zeros((1, self.pages_per_seq), np.int32),
+                self._ring_rows([None]))
             n += 1
         # the PLAIN decode program warms even for speculative engines:
         # brownout level 2 (spec_off) switches a live engine to it,
@@ -578,7 +622,8 @@ class DecodeEngine:
             np.zeros((self.config.max_batch,), np.int64),
             np.ones((self.config.max_batch,), np.int32),
             np.zeros((self.config.max_batch, self.pages_per_seq),
-                     np.int32))
+                     np.int32),
+            self._ring_rows([None] * self.config.max_batch))
         n += 1
         if self.draft_cfg is not None:
             self._run_spec_program(
@@ -675,6 +720,13 @@ class DecodeEngine:
                 f" pages but the pool only has "
                 f"{self.allocator.usable_pages} — grow n_pages or "
                 "shorten the request")
+        if self.ring is not None and self.ring["pages_per_seq"] \
+                > self.allocator.usable_of(self.RING):
+            self.metrics.incr("shed_total")
+            raise PagesExhaustedError(
+                f"request needs a ring of {self.ring['pages_per_seq']} "
+                "window pages but the pool only has "
+                f"{self.allocator.usable_of(self.RING)}")
         if not self.breaker.admits():
             self.metrics.incr("breaker_shed_total")
             raise ServiceUnavailableError(
@@ -856,6 +908,11 @@ class DecodeEngine:
         snap["max_batch"] = self.config.max_batch
         snap["pages_in_use"] = self.allocator.in_use
         snap["pages_available"] = self.allocator.available
+        if self.ring is not None:
+            snap["window_pages_in_use"] = self.allocator.in_use_of(
+                self.RING)
+            snap["window_pages_available"] = self.allocator.available_of(
+                self.RING)
         snap["health_state"] = self.health.state
         snap["breaker"] = self.breaker.snapshot()
         snap["brownout"] = (None if self.brownout is None
@@ -1038,32 +1095,54 @@ class DecodeEngine:
             self.metrics.incr("pools_consumed_total")
         return head
 
-    def _run_prefill_program(self, bucket, tokens, lens, table):
+    def _ring_rows(self, rings):
+        """The rows' ring tables [rows, ring pages] for a program of a
+        model with a ``window`` cache kind (a row without a ring: the
+        null page); None for a model with one kind, whose programs take
+        one table."""
+        if self.ring is None:
+            return None
+        out = np.zeros((len(rings), self.ring["pages_per_seq"]), np.int32)
+        for i, ring in enumerate(rings):
+            if ring is not None:
+                out[i] = ring
+        return out
+
+    @staticmethod
+    def _tables(table, ring):
+        return (table,) if ring is None else (table, ring)
+
+    def _run_prefill_program(self, bucket, tokens, lens, table, ring=None):
         """The bucket's single-row program once for each row given, in
         order: the ``[rows]`` next tokens. ``kept`` holds what the last
-        row's dispatch left."""
+        row's dispatch left. ``ring``: the rows' ring tables, where the
+        model has them (``_ring_rows``)."""
         return self._run_rows(f"prefill_{bucket}",
                               self.programs.prefill[bucket],
-                              tokens, lens, table)
+                              tokens, lens, table, ring)
 
     def _run_draft_prefill_program(self, bucket, tokens, lens, table):
         self._run_rows(f"draft_prefill_{bucket}",
                        self.programs.draft_prefill[bucket],
                        tokens, lens, table)
 
-    def _run_rows(self, label, b, tokens, lens, table):
+    def _run_rows(self, label, b, tokens, lens, table, ring=None):
         return np.concatenate([
-            self._run_program(label, b, (tokens[i:i + 1], lens[i:i + 1],
-                                         table[i:i + 1]))[0]
+            self._run_program(
+                label, b, (tokens[i:i + 1], lens[i:i + 1]) + self._tables(
+                    table[i:i + 1],
+                    None if ring is None else ring[i:i + 1]))[0]
             for i in range(len(tokens))])
 
-    def _run_chunk_program(self, tokens, lens, offsets, table):
-        return self._run_program("chunk", self.programs.chunk,
-                                 (tokens, lens, offsets, table))[0]
+    def _run_chunk_program(self, tokens, lens, offsets, table, ring=None):
+        return self._run_program(
+            "chunk", self.programs.chunk,
+            (tokens, lens, offsets) + self._tables(table, ring))[0]
 
-    def _run_decode_program(self, tokens, positions, table):
-        return self._run_program("decode", self.programs.decode,
-                                 (tokens, positions, table))[0]
+    def _run_decode_program(self, tokens, positions, table, ring=None):
+        return self._run_program(
+            "decode", self.programs.decode,
+            (tokens, positions) + self._tables(table, ring))[0]
 
     def _run_spec_program(self, tokens, prev, positions, table):
         emitted, accepted = self._run_program(
@@ -1077,6 +1156,26 @@ class DecodeEngine:
         slack = c.decode_block + (c.gamma + 1 if self.draft_cfg else 0)
         return self.allocator.pages_for(
             max(bucket, prompt_len + max_new + slack))
+
+    def _alloc(self, n_pages):
+        """``n_pages`` pages of the ``sequence`` kind and, where the
+        model has one, a ring of the ``window`` kind: both or, with
+        PagesExhaustedError, neither. Under ``_slots_lock``."""
+        pages = self.allocator.alloc(n_pages)
+        if self.ring is None:
+            return pages, None
+        try:
+            return pages, self.allocator.alloc(
+                self.ring["pages_per_seq"], self.RING)
+        except PagesExhaustedError:
+            self.allocator.free(pages)
+            raise
+
+    def _free(self, pages, ring):
+        """Both kinds of a request's pages back. Under ``_slots_lock``."""
+        self.allocator.free(pages)
+        if ring is not None:
+            self.allocator.free(ring, self.RING)
 
     def _bucket_for(self, prompt_len):
         for b in self.config.prompt_buckets:
@@ -1134,12 +1233,12 @@ class DecodeEngine:
             for i, slot in enumerate(self.slots):
                 if slot is not None:
                     pending.append(slot.req)
-                    self.allocator.free(slot.pages)
+                    self._free(slot.pages, slot.ring)
                     self.slots[i] = None
             jobs, self._chunk_jobs = dict(self._chunk_jobs), {}
             for job in jobs.values():
                 pending.append(job.req)
-                self.allocator.free(job.pages)
+                self._free(job.pages, job.ring)
         return pending
 
     def _sweep_expired(self):
@@ -1168,7 +1267,7 @@ class DecodeEngine:
             if slot is None:      # already failed by close()/watchdog
                 return
             self.slots[idx] = None
-            self.allocator.free(slot.pages)
+            self._free(slot.pages, slot.ring)
         with record_event("pt:engine/retire", req=slot.req.seq,
                           tokens=len(slot.emitted)):
             self._settle(slot, error, draining)
@@ -1287,7 +1386,7 @@ class DecodeEngine:
                     continue
                 try:
                     with self._slots_lock:
-                        pages = self.allocator.alloc(
+                        pages = self._alloc(
                             self._pages_needed(r.prompt.size,
                                                r.max_new))
                 except PagesExhaustedError:
@@ -1304,7 +1403,7 @@ class DecodeEngine:
             if not self.breaker.allow():
                 with self._slots_lock:
                     for _, pages in granted:
-                        self.allocator.free(pages)
+                        self._free(*pages)
                 self.metrics.incr("breaker_shed_total", len(granted))
                 for r, _ in granted:
                     r.set_error(ServiceUnavailableError(
@@ -1320,7 +1419,10 @@ class DecodeEngine:
         """One whole-prompt request's own dispatch of its bucket's
         single-row program (the draft's behind it), and its first token
         installed as that dispatch returns: True. A terminal failure
-        frees the pages and fails this request alone: False."""
+        frees the pages and fails this request alone: False. ``pages``:
+        (the request's pages, its ring or None), as ``_alloc`` gave them."""
+        pages, ring = pages
+        ring_table = self._ring_rows([ring])
         tokens = np.zeros((1, bucket), np.int64)
         tokens[0, :r.prompt.size] = r.prompt
         lens = np.asarray([r.prompt.size], np.int32)
@@ -1329,7 +1431,8 @@ class DecodeEngine:
 
         def _prefill_dispatch():
             self._maybe_inject_fault()
-            nxt = self._run_prefill_program(bucket, tokens, lens, table)
+            nxt = self._run_prefill_program(bucket, tokens, lens, table,
+                                            ring_table)
             if self.draft_cfg is not None:
                 self._run_draft_prefill_program(bucket, tokens, lens,
                                                 table)
@@ -1348,7 +1451,7 @@ class DecodeEngine:
         except BaseException as exc:     # noqa: BLE001 — forwarded
             self._tick(prefill_dispatch_s_total=dispatch.seconds)
             with self._slots_lock:
-                self.allocator.free(pages)
+                self._free(pages, ring)
             if self.breaker.record_failure():
                 self.metrics.incr("breaker_open_total")
                 self.health.to(HealthState.DEGRADED)
@@ -1361,7 +1464,8 @@ class DecodeEngine:
                    prefill_tokens_total=int(r.prompt.size),
                    prefill_padded_tokens_total=bucket,
                    queue_wait_s_total=admitted_at - r.enqueued_at)
-        self._install_first_token(r, pages, table[0], int(nxt[0]), idx)
+        self._install_first_token(r, pages, table[0], int(nxt[0]), idx,
+                                  ring)
         return True
 
     def _score_ttft(self, r):
@@ -1377,7 +1481,8 @@ class DecodeEngine:
                               else "slo_ttft_violated")
         self.metrics.observe_window(f"{slo.name}.ttft_s", r.ttft_s)
 
-    def _install_first_token(self, r, pages, table, first, idx):
+    def _install_first_token(self, r, pages, table, first, idx,
+                             ring=None):
         """Post-prefill bookkeeping shared by whole-prompt admission
         and the final chunk of a chunked prefill: TTFT accounting,
         then either a decode slot install or — for ``prefill_only``
@@ -1389,32 +1494,41 @@ class DecodeEngine:
         self._score_ttft(r)
         self.metrics.incr("prefill_total")
         self.metrics.incr("generated_tokens_total")
+        if ring is not None:
+            self._count_recycled(0, r.prompt.size)
         if r.prefill_only:
-            self._export_handoff(r, pages, first)
+            self._export_handoff(r, pages, first, ring)
             return
         with self._slots_lock:
             self.slots[idx] = _Slot(
                 r, pages, table, pos=r.prompt.size, cur=first,
                 prev=int(r.prompt[-1]), emitted=[first],
-                first_token_at=now)
+                first_token_at=now, ring=ring)
         eos = self.config.eos_id
         if (eos is not None and first == eos) or r.max_new == 1:
             self._retire(idx, draining=self._closed
                          and not self._stop.is_set())
 
-    def _export_handoff(self, r, pages, first):
+    def _export_handoff(self, r, pages, first, ring=None):
         """Resolve a ``prefill_only`` request with the KV handoff
         blob: the filled page CONTENTS in table order (sequence
-        position p lives at blob page ``p // page_size``), the prompt,
-        and the tokens generated so far. Pages are freed here — the
+        position p lives at blob page ``p // page_size``; in a pool of
+        the ``window`` kind at the ring's page ``(p // page_size) % ring
+        pages``, ``ring_pages`` in the blob), the prompt, and the tokens
+        generated so far. Pages are freed here — the
         blob owns the KV state now; import allocates fresh pages on
         the destination, so the handoff is location-independent."""
         with self._slots_lock:
             alloc_state = self.allocator.export_state(pages)
+            ring_state = None if ring is None else \
+                self.allocator.export_state(ring, self.RING)
         idxs = np.asarray(pages, np.int64)
-        cache = [np.asarray(pool)[:, idxs] for pool in self._pools]
+        rings = () if ring is None else self.ring["pools"]
+        cache = [np.asarray(pool)[:, np.asarray(ring, np.int64)
+                                  if i in rings else idxs]
+                 for i, pool in enumerate(self._pools)]
         with self._slots_lock:
-            self.allocator.free(pages)
+            self._free(pages, ring)
         eos = self.config.eos_id
         done = (eos is not None and first == eos) or r.max_new == 1
         if done:
@@ -1435,6 +1549,8 @@ class DecodeEngine:
                  "cache": cache,
                  "done": bool(done),
                  "ttft_s": r.ttft_s}
+        if ring_state is not None:
+            state["ring_pages"] = [] if done else ring_state["pages"]
         self.metrics.incr("handoff_export_total")
         self.metrics.observe_latency(time.monotonic() - r.enqueued_at)
         self.metrics.incr("responses_total")
@@ -1466,11 +1582,26 @@ class DecodeEngine:
         state = r.handoff_state
         cache = self._handoff_cache(state)
         n_src = int(cache[0].shape[1])
+        if (self.ring is None) != ("ring_pages" not in state):
+            raise ServingError(
+                "handoff blob and this engine's model differ in their "
+                "cache kinds (a ring of window pages on one side alone)")
+        ring = None
         try:
             with self._slots_lock:
                 pages = self.allocator.import_alloc(
                     state,
                     total=self._pages_needed(r.prompt.size, r.max_new))
+                if self.ring is not None:
+                    try:
+                        ring = self.allocator.import_alloc(
+                            {"pages": state["ring_pages"],
+                             "page_size": state["page_size"]},
+                            total=self.ring["pages_per_seq"],
+                            kind=self.RING)
+                    except PagesExhaustedError:
+                        self.allocator.free(pages)
+                        raise
         except PagesExhaustedError:
             self.metrics.incr("page_wait_total")
             with self._qlock:
@@ -1478,8 +1609,12 @@ class DecodeEngine:
             return False
         import jax.numpy as jnp
         rows = np.asarray(pages[:n_src], np.int64)
-        self._pools = [pool.at[:, rows].set(jnp.asarray(x, pool.dtype))
-                       for pool, x in zip(self._pools, cache)]
+        ring_rows = None if ring is None else np.asarray(ring, np.int64)
+        self._pools = [
+            pool.at[:, ring_rows if ring is not None
+                    and i in self.ring["pools"] else rows].set(
+                jnp.asarray(x, pool.dtype))
+            for i, (pool, x) in enumerate(zip(self._pools, cache))]
         table = np.zeros((self.pages_per_seq,), np.int32)
         table[:len(pages)] = pages
         emitted = [int(t) for t in state["emitted"]]
@@ -1488,7 +1623,7 @@ class DecodeEngine:
                 r, pages, table, pos=int(state["pos"]),
                 cur=int(state["cur"]), prev=int(state["prev"]),
                 emitted=emitted,
-                first_token_at=time.monotonic())
+                first_token_at=time.monotonic(), ring=ring)
         self.metrics.incr("handoff_import_total")
         eos = self.config.eos_id
         if (eos is not None and emitted and emitted[-1] == eos) \
@@ -1505,7 +1640,7 @@ class DecodeEngine:
         page exhaustion."""
         try:
             with self._slots_lock:
-                pages = self.allocator.alloc(
+                pages, ring = self._alloc(
                     self._pages_needed(r.prompt.size, r.max_new))
         except PagesExhaustedError:
             self.metrics.incr("page_wait_total")
@@ -1515,7 +1650,7 @@ class DecodeEngine:
         table = np.zeros((self.pages_per_seq,), np.int32)
         table[:len(pages)] = pages
         with self._slots_lock:
-            self._chunk_jobs[idx] = _ChunkJob(r, pages, table)
+            self._chunk_jobs[idx] = _ChunkJob(r, pages, table, ring=ring)
         self._tick(queue_wait_s_total=time.monotonic() - r.enqueued_at)
         return True
 
@@ -1526,7 +1661,7 @@ class DecodeEngine:
             job = self._chunk_jobs.pop(idx, None)
             if job is None:
                 return False
-            self.allocator.free(job.pages)
+            self._free(job.pages, job.ring)
         job.req.set_error(exc)
         with self._cv:
             self._cv.notify_all()
@@ -1572,11 +1707,12 @@ class DecodeEngine:
             lens = np.asarray([sl.size], np.int32)
             offs = np.asarray([job.off], np.int32)
             table = job.table.reshape(1, -1)
+            ring_table = self._ring_rows([job.ring])
 
             def _chunk_dispatch():
                 self._maybe_inject_fault()
                 return self._run_chunk_program(tokens, lens, offs,
-                                               table)
+                                               table, ring_table)
 
             dispatch = record_event("pt:engine/chunk_dispatch",
                                     req=r.seq, offset=job.off)
@@ -1611,7 +1747,7 @@ class DecodeEngine:
                     live = self._chunk_jobs.pop(idx, None) is job
                 if live:
                     self._install_first_token(r, job.pages, job.table,
-                                              int(nxt[0]), idx)
+                                              int(nxt[0]), idx, job.ring)
         return progressed
 
     def _active(self):
@@ -1647,6 +1783,8 @@ class DecodeEngine:
             prev[i] = slot.prev
             pos[i] = slot.pos
             table[i] = slot.table
+        ring_table = self._ring_rows(
+            [None if s is None else s.ring for s in self.slots])
         deadlines = [s.req.deadline for _, s in active
                      if s.req.deadline is not None]
         batch_deadline = min(deadlines) if deadlines else None
@@ -1665,7 +1803,8 @@ class DecodeEngine:
         def _step_dispatch():
             self._maybe_inject_fault()
             if not use_spec:
-                return self._run_decode_program(toks, pos, table)
+                return self._run_decode_program(toks, pos, table,
+                                                ring_table)
             return self._run_spec_program(toks, prev, pos, table)
 
         dispatch = record_event("pt:engine/decode_dispatch",
@@ -1695,8 +1834,17 @@ class DecodeEngine:
         self.breaker.record_success()
         if self.health.state == HealthState.DEGRADED:
             self.health.to(HealthState.READY)
+        # what the active slots held through this dispatch: pages of
+        # every kind, whole, and the positions resident in them
         self._tick(decode_batches_total=1,
-                   decode_dispatch_s_total=dispatch.seconds)
+                   decode_dispatch_s_total=dispatch.seconds,
+                   cache_bytes_held_total=sum(
+                       self._held_bytes(s) for _, s in active),
+                   cache_positions_resident_total=sum(
+                       s.pos for _, s in active))
+        if self.ring is not None:
+            for _, slot in active:
+                self._count_recycled(slot.pos, slot.pos + c.decode_block)
         draining = self._closed and not self._stop.is_set()
         eos = c.eos_id
         n_new = 0
@@ -1729,6 +1877,23 @@ class DecodeEngine:
                     self._retire(i, draining=draining)
         self.metrics.incr("generated_tokens_total", n_new)
         return True
+
+    def _held_bytes(self, slot):
+        """Bytes of cache a slot's pages hold, every kind, pages whole."""
+        held = len(slot.pages) * self._page_bytes[PageAllocator.SEQUENCE]
+        if slot.ring is not None:
+            held += len(slot.ring) * self._page_bytes[self.RING]
+        return held
+
+    def _count_recycled(self, pos0, pos1):
+        """A request's positions ``pos0 .. pos1 - 1`` were written: count
+        the ring pages that were entered anew and had held an earlier
+        page's worth of positions (``window_pages_recycled_total``)."""
+        ps, n = self.config.page_size, self.ring["pages_per_seq"]
+        first, last = -(-pos0 // ps), (pos1 - 1) // ps  # entered anew
+        turns = max(0, last - max(first, n) + 1)
+        if turns:
+            self.metrics.incr("window_pages_recycled_total", turns)
 
     def _truncate(self, slot, row):
         """The slice of freshly generated ``row`` this slot actually

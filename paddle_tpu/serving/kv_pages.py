@@ -18,6 +18,22 @@ retired request's pages is bit-identical to running it alone); the
 allocator only enforces the integer invariants (no double alloc, no
 double free, exhaustion is a typed shed).
 
+A model may keep caches of more than one KIND, and a kind is how long
+an entry lives. ``sequence``, the kind every model has: a page for every
+``page_size`` positions of the request, held until it retires. ``window``
+(a model with sliding-window attention layers, models/hybrid_moe.py): a
+RING of a few pages a request, position p in ring page ``(p //
+page_size) % ring pages``, so a request holds its window's worth of
+pages however long it grows, and a position that falls out of the window
+is overwritten where it lies (the engine counts each such turn of a ring
+page in ``window_pages_recycled_total``). The pools of two kinds have
+pages of different shapes (other layers, other head counts), so each
+kind has its own page ids, its own null page 0 and its own free list,
+under ONE allocator that keeps the integer invariants for each:
+``add_kind`` declares one, and ``alloc`` / ``free`` / the capacity
+queries take ``kind=`` (the ``sequence`` kind where it is not given, so
+a model with one kind never names it).
+
 Pure host-side integers: no jax, no numpy, trivially unit-testable.
 """
 from .batching import QueueFullError
@@ -38,32 +54,68 @@ class PageAllocator:
     Page 0 is the reserved null page and is never handed out; the
     usable pool is pages 1..n_pages-1. ``alloc`` returns pages in
     ascending order (determinism for tests), ``free`` returns them.
+    ``n_pages`` are the ``sequence`` kind's; ``add_kind`` declares a
+    further cache kind with a pool of its own (see the module's
+    docstring). ``n_pages``, ``usable_pages``, ``available`` and
+    ``in_use`` speak of the ``sequence`` kind, ``*_of(kind)`` of any.
     """
 
+    SEQUENCE = "sequence"
+
     def __init__(self, n_pages, page_size):
+        if page_size < 1:
+            raise ValueError(f"page_size must be >= 1, got {page_size}")
+        self.page_size = int(page_size)
+        self._n_pages, self._free_of = {}, {}
+        self.add_kind(self.SEQUENCE, n_pages)
+
+    def add_kind(self, kind, n_pages):
+        """Declare cache kind ``kind`` with ``n_pages`` pages of its own
+        (page 0 its null page)."""
+        if kind in self._n_pages:
+            raise ValueError(f"cache kind {kind!r} is declared already")
         if n_pages < 2:
             raise ValueError(
                 f"n_pages must be >= 2 (page 0 is the reserved null "
                 f"page), got {n_pages}")
-        if page_size < 1:
-            raise ValueError(f"page_size must be >= 1, got {page_size}")
-        self.n_pages = int(n_pages)
-        self.page_size = int(page_size)
-        self._free = set(range(1, self.n_pages))
+        self._n_pages[kind] = int(n_pages)
+        self._free_of[kind] = set(range(1, int(n_pages)))
+
+    @property
+    def kinds(self):
+        return tuple(self._n_pages)
+
+    @property
+    def n_pages(self):
+        return self._n_pages[self.SEQUENCE]
+
+    @property
+    def _free(self):
+        return self._free_of[self.SEQUENCE]
 
     # -- capacity queries ------------------------------------------------
+    def usable_of(self, kind):
+        """Total allocatable pages of ``kind`` (its pool minus the null
+        page)."""
+        return self._n_pages[kind] - 1
+
+    def available_of(self, kind):
+        return len(self._free_of[kind])
+
+    def in_use_of(self, kind):
+        return self.usable_of(kind) - len(self._free_of[kind])
+
     @property
     def usable_pages(self):
-        """Total allocatable pages (the pool minus the null page)."""
-        return self.n_pages - 1
+        return self.usable_of(self.SEQUENCE)
 
     @property
     def available(self):
-        return len(self._free)
+        return self.available_of(self.SEQUENCE)
 
     @property
     def in_use(self):
-        return self.usable_pages - len(self._free)
+        return self.in_use_of(self.SEQUENCE)
 
     def pages_for(self, n_positions):
         """Pages needed to cover ``n_positions`` sequence positions."""
@@ -73,36 +125,38 @@ class PageAllocator:
         return -(-int(n_positions) // self.page_size)
 
     # -- alloc / free ----------------------------------------------------
-    def alloc(self, n):
-        """Allocate ``n`` pages or raise PagesExhaustedError (leaving
-        the pool untouched — no partial grants)."""
+    def alloc(self, n, kind=SEQUENCE):
+        """Allocate ``n`` pages of ``kind`` or raise PagesExhaustedError
+        (leaving the pool untouched — no partial grants)."""
         n = int(n)
+        free = self._free_of[kind]
         if n < 1:
             raise ValueError(f"alloc needs n >= 1, got {n}")
-        if n > len(self._free):
+        if n > len(free):
             raise PagesExhaustedError(
-                f"KV page pool exhausted: need {n} pages, "
-                f"{len(self._free)}/{self.usable_pages} free — load "
+                f"KV page pool exhausted: need {n} {kind} pages, "
+                f"{len(free)}/{self.usable_of(kind)} free — load "
                 "shed, retry with backoff (or grow n_pages)")
-        got = sorted(self._free)[:n]
-        self._free.difference_update(got)
+        got = sorted(free)[:n]
+        free.difference_update(got)
         return got
 
-    def free(self, pages):
-        """Return pages to the pool. Double-free and null-page returns
-        are invariant violations and raise."""
+    def free(self, pages, kind=SEQUENCE):
+        """Return pages of ``kind`` to its pool. Double-free and
+        null-page returns are invariant violations and raise."""
         pages = list(pages)
+        free = self._free_of[kind]
         for p in pages:
-            if not 1 <= p < self.n_pages:
+            if not 1 <= p < self._n_pages[kind]:
                 raise ValueError(
                     f"free of page {p} outside the usable pool "
-                    f"[1, {self.n_pages})")
-            if p in self._free:
-                raise ValueError(f"double free of page {p}")
-        self._free.update(pages)
+                    f"[1, {self._n_pages[kind]}) of {kind} pages")
+            if p in free:
+                raise ValueError(f"double free of {kind} page {p}")
+        free.update(pages)
 
     # -- KV handoff hooks ------------------------------------------------
-    def export_state(self, pages):
+    def export_state(self, pages, kind=SEQUENCE):
         """Bookkeeping half of a KV handoff export: validate that
         every page is a live allocation of THIS pool (exporting a
         freed or out-of-range page would ship garbage the length mask
@@ -112,16 +166,16 @@ class PageAllocator:
         location-independent."""
         pages = [int(p) for p in pages]
         for p in pages:
-            if not 1 <= p < self.n_pages:
+            if not 1 <= p < self._n_pages[kind]:
                 raise ValueError(
                     f"cannot export page {p}: outside the usable "
-                    f"pool [1, {self.n_pages})")
-            if p in self._free:
+                    f"pool [1, {self._n_pages[kind]})")
+            if p in self._free_of[kind]:
                 raise ValueError(
                     f"cannot export page {p}: not a live allocation")
         return {"pages": pages, "page_size": self.page_size}
 
-    def import_alloc(self, state, total=None):
+    def import_alloc(self, state, total=None, kind=SEQUENCE):
         """Allocation half of a KV handoff import: check geometry
         compatibility (a page_size mismatch would silently misalign
         every position past the first page) and allocate fresh local
@@ -135,4 +189,4 @@ class PageAllocator:
         n = len(state["pages"])
         if total is not None:
             n = max(n, int(total))
-        return self.alloc(n)
+        return self.alloc(n, kind)
