@@ -30,8 +30,10 @@ from dataclasses import dataclass
 
 from .. import layers
 from ..layers import transformer as tfl
-from ..ops.transformer_ops import PAGED_STATS, yarn_inv_freq, yarn_mscale
-from .llama import PagedDecodePrograms, prefill_buckets_reached
+from ..ops.transformer_ops import (PAGED_STATS, whole_tiles, yarn_inv_freq,
+                                   yarn_mscale)
+from .llama import (PagedDecodePrograms, cache_pool_specs,
+                    prefill_buckets_reached)
 
 __all__ = ["LatentMoEConfig", "LATENT_MOE_TINY", "LATENT_SHARE_TINY",
            "build_block_programs"]
@@ -95,9 +97,19 @@ class LatentMoEConfig:
         """Values a token leaves in a layer's cache."""
         return self.kv_rank + self.rope_dim
 
+    @property
+    def stored_dim(self):
+        """The width the cache keeps an entry at: whole lane tiles,
+        ``[latent | rotated key | zeros]`` (512 + 64 is stored 640 wide).
+        A pool 576 wide is 4.5 tiles: the chip re-laid all of it three
+        times a decode program and twice a prefill (PERF.md section 6,
+        PR 34). The weights keep their published shapes."""
+        return whole_tiles(self.entry_dim)
+
     def cache_spec(self):
-        """A token's cache entries in one layer: [(shape, dtype)]."""
-        return [((self.entry_dim,), self.dtype)]
+        """A token's cache entries in one layer as the pools store them:
+        [(shape, dtype)]."""
+        return [((self.stored_dim,), self.dtype)]
 
     def softmax_scale(self):
         m = yarn_mscale(self.rope_factor, self.rope_mscale_all_dim)
@@ -194,7 +206,7 @@ class LatentMoEConfig:
                              draft_cfg=None, gamma=4, chunk_size=None):
         """The paged step programs DecodeEngine runs for this model: as
         models/llama.py build_llama_paged_programs, over ONE pool of
-        ``[n_layers, n_pages, page_size, kv_rank + rope_dim]``, each
+        ``[n_layers, n_pages, page_size, stored_dim]``, each
         program also returning its float32 logits, the experts its routed
         layers picked for the tokens those logits belong to, and
         PAGED_STATS. The
@@ -211,9 +223,8 @@ class LatentMoEConfig:
                 "drop quantize")
         if self.n_layers <= self.n_dense_layers:
             raise ValueError("no routed layer after the dense ones")
-        pool_shape = [self.n_layers, n_pages, page_size, self.entry_dim]
         return build_block_programs(
-            self, pool_specs=[(pool_shape, self.dtype)],
+            self, pool_specs=cache_pool_specs(self, n_pages, page_size),
             common=dict(
                 params=self.layer_params(
                     self.n_layers - self.n_dense_layers, True),

@@ -416,7 +416,9 @@ def prefill_buckets_reached(prompt_buckets, chunk_size):
             if i == 0 or buckets[i - 1] < int(chunk_size)]
 
 
-def _pool_specs(cfg, n_pages, page_size):
+def cache_pool_specs(cfg, n_pages, page_size):
+    """(shape, dtype) of each cache pool of ``cfg``: ``[layers, n_pages,
+    page_size]`` before each entry of its ``cache_spec()``."""
     return [([cfg.n_layers, n_pages, page_size] + list(entry), dtype)
             for entry, dtype in cfg.cache_spec()]
 
@@ -451,7 +453,7 @@ def build_llama_paged_programs(cfg, *, max_batch, page_size, n_pages,
             f"target and draft must share a vocabulary: "
             f"{cfg.vocab_size} vs {draft_cfg.vocab_size}")
     from ..core import framework
-    pool_specs = _pool_specs(cfg, n_pages, page_size)
+    pool_specs = cache_pool_specs(cfg, n_pages, page_size)
     kv_shape = pool_specs[0][0]
     common = dict(vocab_size=cfg.vocab_size, dim=cfg.dim,
                   n_layers=cfg.n_layers, n_heads=cfg.n_heads,
@@ -531,7 +533,7 @@ def build_llama_paged_programs(cfg, *, max_batch, page_size, n_pages,
     draft_prefill = None
     draft_pool_specs = None
     if draft_cfg is not None:
-        draft_pool_specs = _pool_specs(draft_cfg, n_pages, page_size)
+        draft_pool_specs = cache_pool_specs(draft_cfg, n_pages, page_size)
         draft_kv_shape = draft_pool_specs[0][0]
         # the draft prefills its own paged cache over the same prompt
         # (and the same page indices — one table serves both pools)

@@ -1230,13 +1230,42 @@ HYBRID_STATS = PAGED_STATS + ("attn_full_positions_total",
 _KEY_BLOCK = 2048
 _SCORE_BYTES = 2 ** 29
 
+# widths of the chip's minor-axis tile: a pool whose entry is not a whole
+# number of them is handed to every program with another axis innermost
+# and re-laid, whole, on its way to the gather and to the output (PERF.md
+# section 6, PR 33 and PR 34)
+_LANE_TILE = 128
+
+
+def whole_tiles(width):
+    """The width a cache stores a ``width``-wide entry at: the next whole
+    number of lane tiles (a width that is one already: itself)."""
+    return -(-int(width) // _LANE_TILE) * _LANE_TILE
+
+
+def _as_stored(entry, pool):
+    """``entry`` [..., width] as ``pool`` [..., stored width] keeps it:
+    zeros behind it where the pool is wider."""
+    pad = pool.shape[-1] - entry.shape[-1]
+    if not pad:
+        return entry
+    return jnp.pad(entry, [(0, 0)] * (entry.ndim - 1) + [(0, pad)])
+
 
 class _PagedRunner:
     """Paged twin of _make_cached_runner, closed over one model's
     stacked weights and its ``BlockKinds``. A model's cache is a tuple
     of pools ``[L, n_pages, page_size, *entry]``, one per entry a token
     leaves in a layer (GQA: K and V ``[g, hd]``; latent attention: one
-    ``[kv_rank + rope_dim]``). Two execution forms over the SAME math:
+    ``[kv_rank + rope_dim]``). AN ENTRY IS STORED AT WHOLE LANE TILES: a
+    pool whose minor width is not a multiple of 128 is handed to every
+    program with its page axis innermost and re-laid, whole, for the
+    gather, the scatter and the output, so latent attention's 576 values
+    are stored 640 wide, zeros behind them (the model's ``cache_spec()``
+    says so; both forms write an entry at the width of the pool they are
+    given and attention multiplies the pad by zeros), as the hybrid
+    model's heads lie flat in their page, below: one rule, PERF.md
+    section 6, PR 33 and PR 34. Two execution forms over the SAME math:
 
     - ``forward(h, *pools, table, pos0, t_len)`` — operate directly on
       the page pools through ``table`` [B, max_pages]: each layer
@@ -1528,7 +1557,7 @@ class _PagedRunner:
                             kv[..., :k.nope_dim],
                             preferred_element_type=f32)
                  + jnp.einsum("bqhd,bkd->bhqk", q_pe,
-                              blk[..., k.kv_rank:],
+                              blk[..., k.kv_rank:k.kv_rank + k.rope_dim],
                               preferred_element_type=f32)) \
                 * k.softmax_scale
             k_pos = i * kb + jnp.arange(kb, dtype=jnp.int32)
@@ -1557,7 +1586,9 @@ class _PagedRunner:
         the expansion moves into the query (``q_nope Wk^T``, then one
         [kv_rank + rope_dim]-wide product with the cache as it lies),
         the value half onto the attended latent. Same mathematics as
-        _latent_expanded; the cache is read once and never expanded."""
+        _latent_expanded; the cache is read once and never expanded.
+        Where the view is stored wider than the entry (whole lane tiles)
+        the query gets zeros against the view's zeros."""
         k = self.kinds
         q_nope, q_pe = q
         b, t = q_pos.shape
@@ -1566,14 +1597,14 @@ class _PagedRunner:
         with jax.named_scope("mla/absorb"):
             q_abs = jnp.einsum("bqhd,rhd->bqhr", q_nope,
                                w_up[..., :k.nope_dim])
-            s = jnp.einsum("bqhc,bkc->bhqk",
-                           jnp.concatenate([q_abs, q_pe], axis=-1), view,
-                           preferred_element_type=f32) * k.softmax_scale
+            s = jnp.einsum("bqhc,bkc->bhqk", _as_stored(
+                jnp.concatenate([q_abs, q_pe], axis=-1), view), view,
+                preferred_element_type=f32) * k.softmax_scale
             mask = (jnp.arange(view.shape[1], dtype=jnp.int32)[None, None]
                     <= q_pos[:, :, None])
             w = jax.nn.softmax(jnp.where(mask[:, None], s, -1e30), axis=-1)
-            # over the whole entry: slicing the latent out of the view
-            # would copy it; the rotated key's columns are dropped after
+            # over the whole stored entry: slicing the latent out of the
+            # view would copy it; the other columns are dropped after
             o_lat = jnp.einsum("bhqk,bkc->bqhc", w.astype(view.dtype),
                                view, preferred_element_type=f32)
             out = jnp.einsum("bqhr,rhd->bqhd",
@@ -1753,7 +1784,7 @@ class _PagedRunner:
             if kind is not None:
                 return attend_kind(p, q, entries, pools, lyr, kind)
             pg = jnp.take_along_axis(table, q_pos // ps, axis=1)
-            pools = tuple(pl.at[lyr, pg, q_pos % ps].set(e)
+            pools = tuple(pl.at[lyr, pg, q_pos % ps].set(_as_stored(e, pl))
                           for pl, e in zip(pools, entries))
             if self.kinds.attention == "latent":
                 def read_block(i):
@@ -1823,8 +1854,11 @@ class _PagedRunner:
         # the pool is not written (nor, where the chip keeps it in
         # another layout, re-laid) before the entries are out and the
         # view is dead, or both are live: with the pools donated the
-        # latent decode program's footprint still reads 12.69 GB with
-        # this barrier and 14.78 without (PERF.md section 6, PR 32)
+        # latent decode program's footprint read 12.69 GB with this
+        # barrier and 14.78 without while its pool was 576 wide (PERF.md
+        # section 6, PR 32); at whole lane tiles XLA's memory analysis
+        # reads 12.77 GB either way (PR 34); it stays for any pool the
+        # chip does re-lay
         pages, entries = jax.lax.optimization_barrier((pages, entries))
         # beyond kmax: a page past the pool's last, which the set drops
         pg = jnp.where(q_pos < kmax,
@@ -1927,8 +1961,8 @@ class _PagedRunner:
         def attend_write(p, q, entries, dense, lyr, kind=None):
             if kind is not None:
                 return attend_kind(p, q, entries, dense, lyr, kind)
-            dense = tuple(d.at[lyr, rows[:, None], q_pos].set(e)
-                          for d, e in zip(dense, entries))
+            dense = tuple(d.at[lyr, rows[:, None], q_pos].set(
+                _as_stored(e, d)) for d, e in zip(dense, entries))
             if self.kinds.attention == "latent":
                 return (self._latent_absorbed(p, q, dense[0][lyr], q_pos),
                         dense)

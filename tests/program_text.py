@@ -1,0 +1,60 @@
+"""The text of a DecodeEngine program's executable, for tests that pin
+programs a change must leave alone (test_paged_programs_pinned.py).
+
+A bundle of ``build_paged_programs`` is lowered as the engine dispatches
+it (core/executor.py ``Executor._jit``: the pools a fifth, donated
+argument) from the shapes its program declares, no weight made."""
+import hashlib
+import re
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.core.executor import make_stepped
+from paddle_tpu.core.lowering import lower_program
+
+
+def lower_bundle(bundle, n_pools):
+    """``jax.stages.Lowered`` of one program bundle."""
+    gb = bundle["program"].global_block()
+
+    def abstract(name):
+        v = gb.vars[name]
+        return jax.ShapeDtypeStruct(tuple(v.shape), jnp.dtype(v.dtype))
+
+    fetch = [v if isinstance(v, str) else v.name for v in bundle["fetch"]]
+    stepped = make_stepped(lower_program(bundle["program"], fetch, "test"))
+    pools = list(bundle["feeds"][-n_pools:])
+
+    def fn(rw, ro, feed, step_seed, given):
+        return stepped(rw, ro, dict(feed, **dict(zip(pools, given))),
+                       step_seed)
+
+    ro = {n: abstract(n) for n, v in sorted(gb.vars.items())
+          if v.persistable}
+    feeds = {n: abstract(n) for n in bundle["feeds"][:-n_pools]}
+    return jax.jit(fn, donate_argnums=(4,)).lower(
+        {}, ro, feeds, jax.ShapeDtypeStruct((2,), jnp.uint32),
+        [abstract(n) for n in pools])
+
+
+def fingerprint(lowered):
+    """(sha256 of the StableHLO text the program lowers to, instructions
+    of the optimized module): the first is what the program asks for,
+    whatever the backend makes of it; the second what this backend's
+    compiler left."""
+    text = lowered.as_text()
+    optimized = lowered.compile().as_text()
+    count = len(re.findall(r"^\s+(?:ROOT )?%?[\w.\-]+ = ", optimized,
+                           re.M))
+    return hashlib.sha256(text.encode()).hexdigest()[:16], count
+
+
+def bundles_of(programs):
+    """label -> bundle of every target-model program of a
+    PagedDecodePrograms."""
+    out = {f"prefill_{b}": v for b, v in sorted(programs.prefill.items())}
+    out["decode"] = programs.decode
+    if programs.chunk is not None:
+        out["chunk"] = programs.chunk
+    return out

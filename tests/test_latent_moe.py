@@ -24,6 +24,7 @@ from paddle_tpu.serving.decode_engine import (DecodeConfig, DecodeEngine,
 
 from benchmark.reference import latent_moe_mhc as ref
 from pool_donation import aliased_bytes, check_dispatch_donates
+import stored_width
 
 REL_L2_F32 = 1e-4
 PS, MP = 4, 8                      # page size, pages a row
@@ -318,7 +319,7 @@ def test_engine_keeps_whole_prompt_programs_only_where_reachable(engine):
     assert sorted(engine.programs.prefill) == [8]
     assert engine.programs.chunk_size == 8
     assert [tuple(p.shape) for p in engine._pools] == [
-        (CFG.n_layers, engine.allocator.n_pages, PS, CFG.entry_dim)]
+        (CFG.n_layers, engine.allocator.n_pages, PS, CFG.stored_dim)]
 
 
 def test_engine_tokens_are_the_references_alone_and_co_scheduled(engine):
@@ -358,7 +359,7 @@ def test_handoff_carries_the_one_pool_cache(engine):
     want = engine.generate(prompt, max_new=5)
     blob = engine.submit(prompt, max_new=5, prefill_only=True).result(60)
     assert blob["kind"] == "kv_handoff" and len(blob["cache"]) == 1
-    assert blob["cache"][0].shape[2:] == (PS, CFG.entry_dim)
+    assert blob["cache"][0].shape[2:] == (PS, CFG.stored_dim)
     other = make_engine()
     try:
         got = other.import_handoff(blob).result(60)
@@ -368,6 +369,36 @@ def test_handoff_carries_the_one_pool_cache(engine):
             other.import_handoff(bad)
     finally:
         other.close()
+
+
+# -- an entry is stored at whole lane tiles -------------------------------
+
+@pytest.mark.parametrize("kv_rank,rope_dim,stored", [
+    (16, 8, 128),           # this file's model: 24 wide, one tile
+    (512, 64, 640),         # the published widths: 4.5 tiles -> 5
+    (120, 8, 128),          # a whole tile already: left alone
+    (448, 64, 512),
+    (128, 1, 256)])
+def test_an_entry_is_stored_at_the_next_whole_lane_tile(kv_rank, rope_dim,
+                                                        stored):
+    from dataclasses import replace
+    cfg = replace(CFG, kv_rank=kv_rank, rope_dim=rope_dim)
+    assert cfg.entry_dim == kv_rank + rope_dim
+    assert cfg.stored_dim == stored
+    assert cfg.cache_spec() == [((stored,), cfg.dtype)]
+    # the weights keep their published shapes: the pad is the cache's
+    shape, _ = cfg.param_shapes()["blocks.wkva"]
+    assert shape[-1] == cfg.entry_dim
+
+
+@pytest.mark.parametrize("form", ["whole", "chunked"])
+def test_the_padded_entry_changes_no_bit_of_logits_picks_or_cache(form):
+    stored_width.check_padding_changes_no_bit(run_op, CFG, form, PS, MP)
+
+
+def test_an_engine_stores_padded_what_it_would_store_unpadded(monkeypatch):
+    """Whole-prompt and chunk programs, under hyper-connections."""
+    stored_width.check_engines_agree(make_engine, monkeypatch, CFG, PS)
 
 
 @pytest.mark.parametrize("label", ["prefill_8", "chunk", "decode"])

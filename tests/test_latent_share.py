@@ -19,6 +19,7 @@ from paddle_tpu.ops import transformer_ops as T
 from paddle_tpu.serving.decode_engine import DecodeConfig, DecodeEngine
 
 from benchmark.reference import latent_moe_share as ref
+import stored_width
 
 REL_L2_F32 = 1e-4
 PS, MP = 4, 8                      # page size, pages a row
@@ -448,17 +449,35 @@ def test_the_key_block_shrinks_where_heads_times_window_is_large(
 
 # -- the engine ---------------------------------------------------------------
 
-@pytest.fixture(scope="module")
-def engine():
+def make_engine():
     scope = fluid.Scope()
     for name, value in W.items():
         scope.set(name, value)
-    eng = DecodeEngine(CFG, scope=scope, config=DecodeConfig(
+    return DecodeEngine(CFG, scope=scope, config=DecodeConfig(
         max_batch=3, prompt_buckets=(8, 32), max_new_tokens=8,
         page_size=PS, decode_block=2, prefill_batch=1))
+
+
+@pytest.fixture(scope="module")
+def engine():
+    eng = make_engine()
     eng.warmup()
     yield eng
     eng.close()
+
+
+@pytest.mark.parametrize("form", ["whole", "chunked"])
+def test_the_padded_entry_changes_no_bit_of_logits_picks_or_cache(form):
+    """Under the plain residual path (test_latent_moe.py: under
+    hyper-connections)."""
+    assert (CFG.entry_dim, CFG.stored_dim) == (24, 128)
+    stored_width.check_padding_changes_no_bit(run_op, CFG, form, PS, MP)
+
+
+def test_an_engine_stores_padded_what_it_would_store_unpadded(monkeypatch):
+    """Whole-prompt programs alone (this engine has no chunk program),
+    under the plain residual path."""
+    stored_width.check_engines_agree(make_engine, monkeypatch, CFG, PS)
 
 
 def test_engine_tokens_are_the_references_alone_and_co_scheduled(engine):

@@ -1,0 +1,45 @@
+"""The paged programs of the models whose cache entries are whole lane
+tiles already (the dense Llama block's ``[kv_heads, head_dim]``, the
+hybrid model's flat ``heads * width``) are what they were before latent
+attention's entry was padded (PERF.md section 6, PR 34): the pad is made
+only where a pool is wider than the entry written into it, so a program
+whose pools are as wide as its entries lowers to the same text.
+
+PINNED was taken on PR 33's tree (tests/program_text.py ``fingerprint``
+of each program at GEOMETRY: the sha256 of the StableHLO text, the
+instructions of the module the CPU compiler leaves) and read the same on
+PR 34's. A PR that means to change one of these programs takes the new
+values from this test's failure message."""
+import pytest
+
+from paddle_tpu.models.hybrid_moe import HYBRID_MOE_TINY
+from paddle_tpu.models.llama import LLAMA_TINY
+
+import program_text
+
+GEOMETRY = dict(max_batch=3, page_size=4, n_pages=40, pages_per_seq=8,
+                prompt_buckets=(8, 16), decode_block=2, chunk_size=8)
+MODELS = {"llama": LLAMA_TINY, "hybrid": HYBRID_MOE_TINY}
+PINNED = {
+    "llama/prefill_8": ("937237aae36f26bc", 601),
+    "llama/decode": ("b9a509496a2957b9", 767),
+    "llama/chunk": ("4c190e5db1d62819", 636),
+    "hybrid/prefill_8": ("8e4666aeda4ea03d", 3164),
+    "hybrid/decode": ("e38b4808a0f48f6f", 3146),
+    "hybrid/chunk": ("7a045869e84f4200", 3400),
+}
+
+
+@pytest.fixture(scope="module")
+def programs():
+    return {name: cfg.build_paged_programs(**GEOMETRY)
+            for name, cfg in MODELS.items()}
+
+
+@pytest.mark.parametrize("which", sorted(PINNED))
+def test_a_program_over_whole_tile_entries_is_what_it_was(programs, which):
+    model, label = which.split("/")
+    progs = programs[model]
+    got = program_text.fingerprint(program_text.lower_bundle(
+        program_text.bundles_of(progs)[label], len(progs.pool_specs)))
+    assert got == PINNED[which], (which, got)
