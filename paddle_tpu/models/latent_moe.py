@@ -30,8 +30,8 @@ from dataclasses import dataclass
 
 from .. import layers
 from ..layers import transformer as tfl
-from ..ops.transformer_ops import (PAGED_STATS, whole_tiles, yarn_inv_freq,
-                                   yarn_mscale)
+from ..ops.transformer_ops import (PAGED_STATS, decode_in_place,
+                                   whole_tiles, yarn_inv_freq, yarn_mscale)
 from .llama import (PagedDecodePrograms, cache_pool_specs,
                     prefill_buckets_reached)
 
@@ -288,6 +288,9 @@ def build_block_programs(cfg, *, pool_specs, common, max_batch, page_size,
         ("Tokens", "tokens", [max_batch], "int64"),
         ("Positions", "positions", [max_batch], "int32"),
         *tables(max_batch)], steps=decode_block)
+    decode["in_place"] = decode_in_place(
+        common["attrs"]["attention"], common["attrs"].get("attn_kinds"),
+        [shape for shape, _ in pool_specs])
     chunk = None
     if chunk_size is not None:
         cs = int(chunk_size)

@@ -13,6 +13,7 @@ from jax.sharding import PartitionSpec as P
 
 from .. import layers
 from ..layers import transformer as tfl
+from ..ops.transformer_ops import decode_in_place
 from ..param_attr import ParamAttr
 from .. import initializer as init_mod
 
@@ -365,7 +366,9 @@ class PagedDecodePrograms:
     bundles. A bundle's feeds end with the model's cache pools and its
     fetches are (token outputs, the pools, then what ``extras`` names:
     ``logits``, ``picks``, ``stats``); ``pools`` says which pools those
-    are where not the target's (``draft``, ``both``). ``pool_specs`` (and ``draft_pool_specs``
+    are where not the target's (``draft``, ``both``); the decode
+    bundle's ``in_place`` says which form its steps were built on
+    (ops/transformer_ops.py decode_in_place). ``pool_specs`` (and ``draft_pool_specs``
     when spec) are the (shape, dtype) of each pool the engine allocates
     and round-trips through every dispatch: ``[L, n_pages, page_size]``
     followed by one entry of the model's ``cache_spec()``. ``stats``
@@ -499,7 +502,9 @@ def build_llama_paged_programs(cfg, *, max_batch, page_size, n_pages,
     decode = {"program": main.clone(for_test=True),
               "feeds": ("dc_tokens", "dc_positions", "dc_table",
                         "dc_kpages", "dc_vpages"),
-              "fetch": [out, kp_out, vp_out]}
+              "fetch": [out, kp_out, vp_out],
+              "in_place": decode_in_place(
+                  "gqa", None, [shape for shape, _ in pool_specs])}
 
     chunk = None
     if chunk_size is not None:

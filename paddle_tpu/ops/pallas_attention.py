@@ -322,6 +322,144 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, scale, causal,
 
 
 # ---------------------------------------------------------------------------
+# paged GQA decode: one query a row against the row's pages, where they lie
+# ---------------------------------------------------------------------------
+
+# positions a block of keys holds (whole pages: 8 of Mistral's 16). Fixed
+# here, never derived from a batch: a row's blocks, and so the order its
+# softmax is folded in, are the same whatever rows it shares a dispatch
+# with
+PAGED_BLOCK_KEYS = 128
+
+
+def paged_gqa_usable(k_shape, v_shape):
+    """The gate of ``paged_gqa_decode``: the backend runs Pallas kernels
+    (``flash_attention``'s own gate), and the pools are two ``[L, pages,
+    page_size, g, hd]`` of one shape, a head a whole number of lane
+    tiles."""
+    return (_use_pallas() and len(k_shape) == 5
+            and tuple(k_shape) == tuple(v_shape) and k_shape[-1] % 128 == 0)
+
+
+def _paged_gqa_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, v_hbm,
+                      o_ref, k_buf, v_buf, sems, *, scale, rep):
+    """Every row of the batch, one after the other: a row's pages are
+    copied to VMEM a block of ``ppb`` at a time, the next block (the next
+    row's first, at a row's end) in flight while this one is folded under
+    a running softmax. A page of a layer lies ``[page_size, g, hd]``, heads
+    inside positions, so a block is read FLAT, ``[positions x g, hd]``:
+    every query head meets every kv head's keys in one product and the
+    columns of the other groups are masked with the positions past the
+    row's length. The g-fold product is the price of leaving the pools as
+    they are stored; it is the MXU's, which a decode step leaves idle."""
+    n_rows, n_heads, hd = q_ref.shape
+    _, ppb, ps, g, _ = k_buf.shape
+    pps = table_ref.shape[1]
+    bk = ppb * ps
+    lyr = layer_ref[0]
+
+    def block_copies(row, blk, slot, act):
+        """Start or wait for the copies of the pages of block ``blk`` of
+        ``row`` that hold a position the row attends."""
+        for p in range(ppb):
+            page_no = blk * ppb + p
+
+            @pl.when(page_no * ps < len_ref[row])
+            def _():
+                page = table_ref[row, jnp.minimum(page_no, pps - 1)]
+                for i, (hbm, buf) in enumerate(((k_hbm, k_buf),
+                                                (v_hbm, v_buf))):
+                    act(pltpu.make_async_copy(
+                        hbm.at[lyr, page], buf.at[slot, p],
+                        sems.at[i, slot]))
+
+    # a page that is not copied leaves what its place in the buffer held,
+    # under a weight of exactly 0.0: that has to be a number
+    v_buf[...] = jnp.zeros_like(v_buf)
+    block_copies(0, 0, 0, lambda c: c.start())
+
+    def row_body(row, slot):
+        length = len_ref[row]
+        n_blocks = lax.div(length + bk - 1, bk)
+        q = q_ref[row]                                      # [heads, hd]
+
+        def block_body(blk, carry):
+            m, l, acc, slot = carry
+            last = blk + 1 == n_blocks
+            nxt_row = jnp.where(last, row + 1, row)
+            nxt_blk = jnp.where(last, 0, blk + 1)
+
+            @pl.when(nxt_row < n_rows)
+            def _():
+                block_copies(jnp.minimum(nxt_row, n_rows - 1), nxt_blk,
+                             1 - slot, lambda c: c.start())
+
+            block_copies(row, blk, slot, lambda c: c.wait())
+            k = k_buf[slot].reshape(bk * g, hd)
+            v = v_buf[slot].reshape(bk * g, hd)
+            s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+            # column c of the block: position c // g, kv head c % g
+            cols = lax.broadcasted_iota(jnp.int32, (1, bk * g), 1)
+            heads = lax.broadcasted_iota(jnp.int32, (n_heads, 1), 0)
+            seen = (lax.rem(cols, g) == lax.div(heads, rep)) \
+                & (lax.div(cols, g) < length - blk * bk)
+            s = jnp.where(seen, s, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            e = jnp.exp(s - m_new)
+            corr = jnp.exp(m - m_new)
+            l = corr * l + jnp.sum(e, axis=1, keepdims=True)
+            acc = corr * acc + lax.dot_general(
+                e.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return m_new, l, acc, 1 - slot
+
+        m, l, acc, slot = lax.fori_loop(
+            0, n_blocks, block_body,
+            (jnp.full((n_heads, 1), NEG_INF, jnp.float32),
+             jnp.zeros((n_heads, 1), jnp.float32),
+             jnp.zeros((n_heads, hd), jnp.float32), slot))
+        o_ref[row] = (acc / l).astype(o_ref.dtype)
+        return slot
+
+    lax.fori_loop(0, n_rows, row_body, 0)
+
+
+def paged_gqa_decode(q, k_pool, v_pool, layer, table, lengths):
+    """One decode step's attention of layer ``layer`` read off the cache
+    pools as they are stored. q [B, heads, hd]; k_pool, v_pool [L, pages,
+    page_size, g, hd] (left in HBM, never copied whole); table [B,
+    pages_per_seq] int32; lengths [B] int32 (held to 1 and to the table's
+    positions): row b attends the first ``lengths[b]`` positions of its
+    pages ``table[b]`` and nothing else, so its result depends on those
+    pages and that length alone (float32 scores and accumulator, products
+    in the cache's type). Returns [B, heads, hd] in q's type."""
+    n_rows, n_heads, hd = q.shape
+    _, _, ps, g, _ = k_pool.shape
+    lengths = jnp.clip(lengths, 1, table.shape[1] * ps)
+    kernel = functools.partial(_paged_gqa_kernel, scale=hd ** -0.5,
+                               rep=n_heads // g)
+    buf = pltpu.VMEM((2, max(1, PAGED_BLOCK_KEYS // ps), ps, g, hd),
+                     k_pool.dtype)
+    return _pcall(
+        kernel,
+        name="paged_gqa_decode",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(1,),
+            in_specs=[pl.BlockSpec((n_rows, n_heads, hd),
+                                   lambda i, *_: (0, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((n_rows, n_heads, hd),
+                                   lambda i, *_: (0, 0, 0)),
+            scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2))]),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), table.astype(jnp.int32),
+      lengths.astype(jnp.int32), q, k_pool, v_pool)
+
+
+# ---------------------------------------------------------------------------
 # jax reference path (CPU tests, backward, and lse building block)
 # ---------------------------------------------------------------------------
 

@@ -19,7 +19,8 @@ import jax
 import jax.numpy as jnp
 
 from ..core.registry import register_op
-from .pallas_attention import flash_attention
+from .pallas_attention import (flash_attention, paged_gqa_decode,
+                               paged_gqa_usable)
 
 
 def rms_normalize(x, scale=None, eps=1e-6):
@@ -1265,7 +1266,7 @@ class _PagedRunner:
     says so; both forms write an entry at the width of the pool they are
     given and attention multiplies the pad by zeros), as the hybrid
     model's heads lie flat in their page, below: one rule, PERF.md
-    section 6, PR 33 and PR 34. Two execution forms over the SAME math:
+    section 6, PR 33 and PR 34. Three execution forms over the SAME math:
 
     - ``forward(h, *pools, table, pos0, t_len)`` — operate directly on
       the page pools through ``table`` [B, max_pages]: each layer
@@ -1277,11 +1278,30 @@ class _PagedRunner:
       and attends over ``dense[layer]``), and at the end write back to
       the pools the entries the steps wrote, a few positions a row, and
       nothing else: the rest of the view is what the pools already
-      hold. The decode and speculative step ops use this. Page 0 is the
-      null page: the writes of inactive slots and of unallocated tails
-      land there, in no defined order.
+      hold. The speculative step op uses this, and the decode ops of
+      every model the next form does not take.
+    - ``forward_in_place(h, *pools, table, pos)`` — a decode step
+      against the pools themselves: each layer writes the step's entry
+      into its page and a Pallas kernel attends the row's pages where
+      they lie, to the row's own length (pallas_attention.py
+      ``paged_gqa_decode``). No view, no gather, no write-back: the view
+      cost Mistral's decode program 26.6 of its 71.68 ms (PERF.md
+      section 6, PR 37). WHICH FORM A DECODE OP TAKES is read off what
+      it is given (``decode_in_place``): this one where the model has one
+      kind of plain GQA layer, its K and V pools are of one shape with
+      heads of whole lane tiles, and the backend runs the kernel (the
+      chip; the tests' interpreter hook); the dense form everywhere
+      else: latent attention, a model that mixes attention kinds,
+      narrow heads, every backend that is not the chip. The three cache
+      forms want three kernels; the dense form goes when its last caller
+      has one (ROADMAP.md, Speed 1).
 
-    In both, the layer scan CARRIES the whole [L, ...] caches beside
+    Page 0 is the null page: the writes of inactive slots and of
+    unallocated tails land there, in no defined order (in place, two
+    rows that both run onto null entries also READ each other's writes
+    there; the engine lets no live row do that).
+
+    In all three, the layer scan CARRIES the whole [L, ...] caches beside
     ``h`` and scans over (weights, layer index): a scan's ``ys`` is a
     fresh buffer that cannot alias its ``xs``, so caches passed that
     way are rebuilt whole on every call — on every token, inside the
@@ -1973,6 +1993,35 @@ class _PagedRunner:
                                        attend_write)
         return (h,) + tuple(dense)
 
+    # -- in-place form (a decode step of plain GQA pools on the chip) ----
+    def forward_in_place(self, h, *pools_table_pos):
+        """One decode step against the pools themselves: each layer
+        writes the step's K and V at ``[layer, table[row, pos // page_size],
+        pos % page_size]`` (the addressing ``forward`` and ``write_back``
+        use; a position at or beyond ``kmax`` is dropped, a null table
+        entry lands on page 0) and attends the row's pages where they lie,
+        to the row's own length, ``pos + 1`` and ``kmax`` at most
+        (pallas_attention.paged_gqa_decode)."""
+        *pools, table, pos = pools_table_pos
+        ps = self.page_size
+        kmax = table.shape[1] * ps
+        at = jnp.minimum(pos, kmax - 1)
+        pg = jnp.where(pos < kmax,
+                       jnp.take_along_axis(table, (at // ps)[:, None],
+                                           axis=1)[:, 0],
+                       pools[0].shape[1])
+        lens = jnp.minimum(pos + 1, kmax)
+
+        def attend_write(p, q, entries, pools, lyr, kind=None):
+            pools = tuple(pl.at[lyr, pg, pos % ps].set(e[:, 0], mode="drop")
+                          for pl, e in zip(pools, entries))
+            out = paged_gqa_decode(q[:, 0], *pools, lyr, table, lens)
+            return out.reshape(out.shape[0], 1, -1), pools
+
+        h, pools = self._stack_forward(h, tuple(pools), pos[:, None],
+                                       attend_write)
+        return (h,) + tuple(pools)
+
     def logits_of(self, hl):
         if self.kinds.residual == "mhc":      # the streams leave summed
             hl = jnp.sum(hl.astype(jnp.float32), axis=-2).astype(hl.dtype)
@@ -2013,6 +2062,18 @@ class _PagedRunner:
         if decode and self.kinds.attention == "latent":
             out[4] = jnp.sum(jnp.where(self.valid[:, 0], positions + 1, 0))
         return jnp.stack([jnp.asarray(x, jnp.int32) for x in out])
+
+
+def decode_in_place(attention, attn_kinds, pool_shapes):
+    """Whether a decode op of a model with these block kinds, over pools
+    of these shapes, runs its steps against the pools themselves
+    (``_PagedRunner.forward_in_place``) and not against a dense view:
+    read off what the op is given, by ``_paged_decode`` where it lowers
+    and by whoever builds its program and wants to know which form that
+    is. One kind of plain GQA layer, K and V pools of one shape with
+    whole-tile heads, and a backend that runs the Pallas kernel."""
+    return (attention == "gqa" and attn_kinds is None
+            and len(pool_shapes) == 2 and paged_gqa_usable(*pool_shapes))
 
 
 def _make_paged_runner(params, emb_w, fnorm, head, *, n_heads, n_kv,
@@ -2069,41 +2130,59 @@ def _paged_prefill(run, tokens, lens, offsets, table, pools):
 
 
 def _paged_decode(run, tok, pos, table, pools, steps, extras=False):
-    """``steps`` greedy steps of every slot against the dense view: the
-    body of every paged decode op. Returns (tokens [B, steps], pools)
-    and, with ``extras``, each step's float32 logits [B, steps, V], its
-    routed picks [B, steps, routed layers, K] and the dispatch's Stats."""
-    # dense form: pool -> dense gather once, ``steps`` steps that carry
-    # the dense caches in place, their entries written back (_PagedRunner)
+    """``steps`` greedy steps of every slot: the body of every paged
+    decode op. Returns (tokens [B, steps], pools) and, with ``extras``,
+    each step's float32 logits [B, steps, V], its routed picks [B, steps,
+    routed layers, K] and the dispatch's Stats."""
     pos = pos.astype(jnp.int32)
-    views = [run.pool_view(i, table) for i in range(len(pools))]
-    dense = []
-    for pl, (tb, (gather, _), scope) in zip(pools, views):
-        with scope:
-            dense.append(gather(pl, tb))
-    dense = tuple(dense)
+    in_place = decode_in_place(run.kinds.attention, run.kinds.attn_kinds,
+                               [pl.shape for pl in pools])
+    if in_place:
+        # in-place form: the steps carry the pools themselves, each layer
+        # writes its entry into its page and attends the pages where they
+        # lie; what comes back is what was carried
+        cache = tuple(pools)
+
+        def forward(h, cache, pos):
+            return run.forward_in_place(h, *cache, table, pos)
+    else:
+        # dense form: pool -> dense gather once, ``steps`` steps that
+        # carry the dense caches in place, their entries written back
+        # (_PagedRunner)
+        views = [run.pool_view(i, table) for i in range(len(pools))]
+        cache = []
+        for pl, (tb, (gather, _), scope) in zip(pools, views):
+            with scope:
+                cache.append(gather(pl, tb))
+        cache = tuple(cache)
+
+        def forward(h, cache, pos):
+            return run.forward_dense(h, *cache, pos, 1)
+
     run.valid = table[:, :1] > 0        # a live row owns a real first page
 
     def step(carry, _):
-        tok, pos, dense, stats = carry
-        h, *dense = run.forward_dense(run.embed(tok[:, None]), *dense,
-                                      pos, 1)
+        tok, pos, cache, stats = carry
+        h, *cache = forward(run.embed(tok[:, None]), cache, pos)
         logits = run.logits_of(h[:, 0])
         nxt = jnp.argmax(logits, axis=-1).astype(tok.dtype)
         if not extras:
-            return (nxt, pos + 1, tuple(dense), stats), nxt
-        return ((nxt, pos + 1, tuple(dense),
+            return (nxt, pos + 1, tuple(cache), stats), nxt
+        return ((nxt, pos + 1, tuple(cache),
                  stats + run.stats(True, pos)),
                 (nxt, logits, jnp.moveaxis(run.picks, 0, 1)))
 
     stats0 = jnp.zeros_like(run.stats(False)) if extras else None
-    (_, _, dense, stats), ys = jax.lax.scan(
-        step, (tok, pos, dense, stats0), None, length=steps)
-    back = []
-    for pl, d, (tb, (_, write_back), scope) in zip(pools, dense, views):
-        with scope:
-            back.append(write_back(pl, d, tb, pos, steps))
-    pools = back
+    (_, _, cache, stats), ys = jax.lax.scan(
+        step, (tok, pos, cache, stats0), None, length=steps)
+    if in_place:
+        pools = list(cache)
+    else:
+        back = []
+        for pl, d, (tb, (_, write_back), scope) in zip(pools, cache, views):
+            with scope:
+                back.append(write_back(pl, d, tb, pos, steps))
+        pools = back
     if not extras:
         return jnp.moveaxis(ys, 0, 1), pools
     return (jnp.moveaxis(ys[0], 0, 1), pools, jnp.moveaxis(ys[1], 0, 1),

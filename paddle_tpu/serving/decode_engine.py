@@ -125,6 +125,12 @@ _DECODE_COUNTERS = (
     # unusable; lost ticks once each time the engine found a pool of its
     # own deleted and replaced them all with zeroed ones
     "pools_consumed_total", "pools_lost_total",
+    # ticked beside decode_batches_total for every decode dispatch whose
+    # program runs its steps against the pools themselves and not a dense
+    # view of them (the decode bundle's ``in_place``: plain GQA pools on a
+    # backend with the paged kernel); its share of decode_batches_total
+    # is how often that form engages
+    "decode_in_place_total",
     # a model with window attention layers (PR 33) has caches of two
     # kinds, and counts on the device, over decode steps, the positions
     # its active rows attended in the layers of each (HYBRID_STATS:
@@ -1837,6 +1843,9 @@ class DecodeEngine:
         # what the active slots held through this dispatch: pages of
         # every kind, whole, and the positions resident in them
         self._tick(decode_batches_total=1,
+                   decode_in_place_total=int(
+                       not use_spec
+                       and self.programs.decode.get("in_place", False)),
                    decode_dispatch_s_total=dispatch.seconds,
                    cache_bytes_held_total=sum(
                        self._held_bytes(s) for _, s in active),

@@ -14,13 +14,16 @@ from paddle_tpu.core.executor import make_stepped
 from paddle_tpu.core.lowering import lower_program
 
 
-def lower_bundle(bundle, n_pools):
-    """``jax.stages.Lowered`` of one program bundle."""
+def lower_bundle(bundle, n_pools, sharding=None):
+    """``jax.stages.Lowered`` of one program bundle; ``sharding``: where
+    every argument lies (a described chip's: the lowering is then for
+    that chip's compiler)."""
     gb = bundle["program"].global_block()
 
     def abstract(name):
         v = gb.vars[name]
-        return jax.ShapeDtypeStruct(tuple(v.shape), jnp.dtype(v.dtype))
+        return jax.ShapeDtypeStruct(tuple(v.shape), jnp.dtype(v.dtype),
+                                    sharding=sharding)
 
     fetch = [v if isinstance(v, str) else v.name for v in bundle["fetch"]]
     stepped = make_stepped(lower_program(bundle["program"], fetch, "test"))
@@ -34,7 +37,8 @@ def lower_bundle(bundle, n_pools):
           if v.persistable}
     feeds = {n: abstract(n) for n in bundle["feeds"][:-n_pools]}
     return jax.jit(fn, donate_argnums=(4,)).lower(
-        {}, ro, feeds, jax.ShapeDtypeStruct((2,), jnp.uint32),
+        {}, ro, feeds,
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=sharding),
         [abstract(n) for n in pools])
 
 

@@ -1,0 +1,290 @@
+"""A GQA decode step attends its pages where they lie.
+
+On a backend that runs the Pallas kernels (the chip; here the hook
+``pallas_attention._FORCE_INTERPRET``) a decode op over plain GQA pools
+takes the in-place form (PERF.md section 6, PR 37): the steps carry the
+pools themselves, each layer writes its entry into its page and
+``paged_gqa_decode`` folds the row's pages, to the row's own length,
+under a running softmax. Held here, on test_paged_cache_inplace.py's
+rows (unequal lengths, a row that crosses a page inside a 4-step
+dispatch, the inactive slot, a row that runs past ``kmax``, a row that
+runs onto a null table entry) at a head 128 wide:
+
+- the kernel against ``_attend_math`` over the gathered view, a case a
+  row;
+- a row alone against the same row among peers, bit for bit;
+- a whole ``llama_paged_decode`` dispatch in both forms: the same tokens
+  and the same pools on every page but the null one;
+- structure: no array of the dense view's shape, no pool as a scan's
+  ``xs`` / ``ys``;
+- the gate: latent, hybrid and narrow-head models build the dense form,
+  and the engine's ``decode_in_place_total`` says which it dispatched.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as fluid
+from benchmark.builders.serve import make_generator_weights
+from paddle_tpu.models.hybrid_moe import HYBRID_MOE_TINY
+from paddle_tpu.models.latent_moe import LATENT_MOE_TINY
+from paddle_tpu.models.llama import LLAMA_TINY, LlamaConfig
+from paddle_tpu.ops import pallas_attention as pa
+from paddle_tpu.ops import transformer_ops as T
+from paddle_tpu.serving import DecodeConfig, DecodeEngine
+
+import test_paged_cache_inplace as rows
+from test_paged_cache_inplace import (B, KMAX, MP, NP, POS, POS_END, PS,
+                                      TABLE, TOK)
+
+L, D, NH, NKV, HD, F, V = 3, 64, 4, 2, 128, 64, 50
+ATTRS = dict(n_heads=NH, n_kv_heads=NKV, rope_base=10000.0, epsilon=1e-5,
+             page_size=PS)
+
+
+@pytest.fixture
+def kernel_on(monkeypatch):
+    """The Pallas kernels through the interpreter, a block two pages long
+    so that rows of these lengths fold several blocks."""
+    monkeypatch.setattr(pa, "_FORCE_INTERPRET", True)
+    monkeypatch.setattr(pa, "PAGED_BLOCK_KEYS", 2 * PS)
+
+
+def _pools(dtype, seed=0):
+    k, v = jax.random.normal(jax.random.PRNGKey(seed),
+                             (2, L, NP, PS, NKV, HD))
+    return k.astype(dtype), v.astype(dtype)
+
+
+def _view_attention(q, k_pool, v_pool, layer, table, lengths):
+    """``_attend_math`` over the gathered view: the dense form's own
+    attention of one query a row at position ``length - 1``."""
+    run = T._PagedRunner({"Wq": jnp.zeros((L, D, NH * HD))}, None, None,
+                         None, n_heads=NH, n_kv=NKV, base=1e4, eps=1e-5,
+                         page_size=PS)
+    views = [run.gather(pool, table)[layer] for pool in (k_pool, v_pool)]
+    return run._attend_math(q[:, None], *views, lengths[:, None] - 1,
+                            1)[:, 0].reshape(q.shape)
+
+
+# (table row, length attended): the rows of TABLE at the lengths a 4-step
+# dispatch from POS / POS_END gives them, as ``forward_in_place`` hands
+# them over (position + 1, ``kmax`` at most)
+CASES = {
+    "before_its_page_boundary": (0, 8),      # ends exactly on a page
+    "crossed_into_its_third_page": (0, 9),
+    "two_blocks_and_a_tail": (1, 14),
+    "onto_a_null_table_entry": (1, 17),      # position 16: page 0
+    "inactive_slot": (2, 2),                 # position 1, null table
+    "one_position": (3, 1),
+    "short_row": (3, 3),
+    "all_of_its_table": (3, KMAX),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_kernel_against_the_gathered_view(case, dtype, kernel_on):
+    """Every row of a batch at once, this case's row among them (peers of
+    other lengths before and behind it), against the dense form's
+    attention. float32 pools: the same math in another order; bf16: the
+    weights are rounded to the cache's type before they meet the
+    values."""
+    row, length = CASES[case]
+    k_pool, v_pool = _pools(dtype)
+    q = jax.random.normal(jax.random.PRNGKey(5), (B, NH, HD)).astype(dtype)
+    lengths = np.array([5, 12, 2, 7], np.int32)
+    lengths[row] = length
+    args = (q, k_pool, v_pool, jnp.int32(1), jnp.asarray(TABLE),
+            jnp.asarray(lengths))
+    got = np.asarray(jax.jit(pa.paged_gqa_decode)(*args), np.float32)
+    want = np.asarray(_view_attention(*args), np.float32)
+    tol = 2e-2 if dtype == "bfloat16" else 2e-6
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+
+
+def test_a_row_alone_is_the_row_among_peers(kernel_on):
+    """Bit for bit: a row's blocks and the order its softmax is folded in
+    are fixed by the program, so neither the batch's longest row nor what
+    another row left in the buffers reaches its result."""
+    k_pool, v_pool = _pools("bfloat16")
+    q = jax.random.normal(jax.random.PRNGKey(6),
+                          (B, NH, HD)).astype(jnp.bfloat16)
+    lengths = np.array([9, 17, 2, KMAX], np.int32)
+    among = np.asarray(jax.jit(pa.paged_gqa_decode)(
+        q, k_pool, v_pool, jnp.int32(2), jnp.asarray(TABLE),
+        jnp.asarray(lengths)))
+    for row in (0, 1, 3):
+        alone = np.asarray(jax.jit(pa.paged_gqa_decode)(
+            q[row:row + 1], k_pool, v_pool, jnp.int32(2),
+            jnp.asarray(TABLE[row:row + 1]),
+            jnp.asarray(lengths[row:row + 1])))
+        assert np.array_equal(alone[0].view(np.uint16),
+                              among[row].view(np.uint16)), row
+
+
+def _decode_case(dtype, pos, steps=4):
+    """(op, inputs, attrs) of ``llama_paged_decode`` at the toy shapes,
+    a head 128 wide."""
+    shapes = {"Wq": (D, NH * HD), "Wk": (D, NKV * HD), "Wv": (D, NKV * HD),
+              "Wo": (NH * HD, D), "WGate": (D, F), "WUp": (D, F),
+              "WDown": (F, D)}
+    keys = iter(jax.random.split(jax.random.PRNGKey(0), 16))
+    ins = {slot: (jax.random.normal(next(keys), (L, m, n)) * 0.2
+                  ).astype(dtype) for slot, (m, n) in shapes.items()}
+    ins["AttnNorm"] = ins["MlpNorm"] = jnp.ones((L, D), dtype)
+    ins["Emb"] = jax.random.normal(next(keys), (V, D)).astype(dtype)
+    ins["FinalNorm"] = jnp.ones((D,), dtype)
+    ins["LmHead"] = (jax.random.normal(next(keys), (D, V)) * 0.2
+                     ).astype(dtype)
+    ins["KPages"], ins["VPages"] = _pools(dtype, seed=7)
+    ins.update(Table=jnp.asarray(TABLE), Tokens=jnp.asarray(TOK),
+               Positions=jnp.asarray(pos))
+    return T._llama_paged_decode, ins, dict(ATTRS, steps=steps)
+
+
+@pytest.mark.parametrize("pos", [POS, POS_END], ids=["pos", "pos_end"])
+def test_a_dispatch_in_both_forms(pos, monkeypatch):
+    """Four steps of every row, dense form then in-place form, float32
+    (the two forms are then the same sums in another order; at bf16 the
+    kernel rounds its weights to the cache's type where ``_attend_math``
+    does not, and this toy's argmax does not survive that). The same
+    tokens, and the same pools on pages 1 and up. Outside the comparison,
+    as in the dense form "in no defined order": the null page, and the
+    last token of the two rows whose fourth position is on it (row 1 at
+    16, the inactive row 2 at 4: in place they meet on one offset of one
+    page, in a view each had a copy)."""
+    op, ins, attrs = _decode_case("float32", pos)
+    assert not T.decode_in_place("gqa", None, [ins["KPages"].shape] * 2)
+    dense = rows._jit(op, attrs)(ins)
+    monkeypatch.setattr(pa, "_FORCE_INTERPRET", True)
+    monkeypatch.setattr(pa, "PAGED_BLOCK_KEYS", 2 * PS)
+    assert T.decode_in_place("gqa", None, [ins["KPages"].shape] * 2)
+    in_place = rows._jit(op, attrs)(ins)
+    got, want = (np.asarray(x["OutTokens"]) for x in (in_place, dense))
+    assert got.shape == (B, 4)
+    assert np.array_equal(got[:, :3], want[:, :3])
+    assert np.array_equal(got[[0, 3], 3], want[[0, 3], 3])
+    for name in ("KPagesOut", "VPagesOut"):
+        a, b = (np.asarray(x[name])[:, 1:] for x in (in_place, dense))
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+        # and the dispatch wrote: positions inside kmax of the live rows,
+        # nothing else (row 3 past kmax: dropped, its last page's head as
+        # it was)
+        was = np.asarray(ins[name[:-3]])[:, 1:]
+        changed = {int(p) + 1 for p in np.nonzero(
+            (a != was).any(axis=(0, 2, 3, 4)))[0]}
+        touched = {int(TABLE[r, p // PS]) for r in (0, 1, 3)
+                   for p in range(pos[r], min(pos[r] + 4, KMAX))} - {0}
+        assert changed == touched, (name, changed, touched)
+        if pos[3] + 4 > KMAX:
+            head = pos[3] % PS
+            assert np.array_equal(a[:, TABLE[3, -1] - 1, :head],
+                                  was[:, TABLE[3, -1] - 1, :head])
+
+
+def _all_shapes(jaxpr, found=None):
+    """The shape of every value in ``jaxpr`` and the jaxprs inside it."""
+    found = set() if found is None else found
+    for v in jaxpr.invars + jaxpr.constvars:
+        found.add(tuple(getattr(v.aval, "shape", ())))
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            found.add(tuple(getattr(v.aval, "shape", ())))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _all_shapes(sub, found)
+    return found
+
+
+# the dense view of a [L, NP, PS, NKV, HD] pool: stacked, a layer's, as
+# gathered, and heads before positions
+VIEW_SHAPES = {(L, B, KMAX, NKV, HD), (B, KMAX, NKV, HD),
+               (1, B, KMAX, NKV, HD), (L, B, MP, PS, NKV, HD),
+               (B, NKV, KMAX, HD)}
+
+
+def test_the_in_place_op_holds_no_view(monkeypatch):
+    """No array of the dense view's shape anywhere in the op, the pools
+    carried by its scans and never their ``xs`` or ``ys``; and the
+    detector sees the dense form, which has both a view and a carry."""
+    op, ins, attrs = _decode_case("bfloat16", POS)
+    pool = {tuple(ins["KPages"].shape)}
+    dense = jax.make_jaxpr(rows._jit(op, attrs))(ins)
+    assert _all_shapes(dense.jaxpr) & VIEW_SHAPES
+    monkeypatch.setattr(pa, "_FORCE_INTERPRET", True)
+    in_place = jax.make_jaxpr(rows._jit(op, attrs))(ins)
+    assert not _all_shapes(in_place.jaxpr) & VIEW_SHAPES
+    streamed, carried = rows._scan_cache_use(in_place, pool)
+    assert not streamed, streamed
+    assert carried == 2         # the step scan and the layer scan
+    # one kernel instance a program: inside the layer scan
+    text = str(in_place)
+    assert text.count("name=paged_gqa_decode") == 1, text.count(
+        "paged_gqa_decode")
+
+
+WIDE = LlamaConfig(vocab_size=64, dim=256, n_layers=2, n_heads=2,
+                   n_kv_heads=1, ffn_hidden=64, dtype="float32")
+assert WIDE.dim // WIDE.n_heads == 128
+GEOMETRY = dict(max_batch=3, page_size=4, n_pages=40, pages_per_seq=8,
+                prompt_buckets=(8, 16), decode_block=2, chunk_size=8)
+
+
+@pytest.mark.parametrize("hook", [False, True], ids=["off", "on"])
+def test_the_gate_reads_the_model_and_the_backend(hook, monkeypatch):
+    """One kind of plain GQA layer with whole-tile heads, on a backend
+    that runs the kernel: everything else builds the dense form."""
+    monkeypatch.setattr(pa, "_FORCE_INTERPRET", hook)
+    built = {name: cfg.build_paged_programs(**GEOMETRY).decode["in_place"]
+             for name, cfg in (("wide", WIDE), ("narrow", LLAMA_TINY),
+                               ("latent", LATENT_MOE_TINY),
+                               ("hybrid", HYBRID_MOE_TINY))}
+    assert built == {"wide": hook, "narrow": False, "latent": False,
+                     "hybrid": False}
+    pool = (2, 40, 4, 1, 128)
+    assert T.decode_in_place("gqa", None, [pool, pool]) is hook
+    for attention, kinds, pools in (
+            ("latent", None, [(2, 40, 4, 640)]),
+            ("gqa", ({"name": "full"},), [pool, pool]),
+            ("gqa", None, [pool, (2, 40, 4, 1, 256)]),      # key != value
+            ("gqa", None, [(2, 40, 4, 2, 64)] * 2),         # half a tile
+            ("gqa", None, [(2, 40, 4, 128)] * 2)):          # flat entries
+        assert not T.decode_in_place(attention, kinds, pools)
+
+
+def _wide_scope():
+    scope = fluid.Scope()
+    for name, value in make_generator_weights(WIDE, 11, False).items():
+        scope.set(name, value)
+    return scope
+
+
+@pytest.mark.serving
+@pytest.mark.parametrize("hook", [False, True], ids=["off", "on"])
+def test_the_engine_counts_its_in_place_dispatches(hook, monkeypatch):
+    """``decode_in_place_total`` equals ``decode_batches_total`` for an
+    engine built where the kernel runs and stays 0 where it does not."""
+    monkeypatch.setattr(pa, "_FORCE_INTERPRET", hook)
+    engine = DecodeEngine(
+        WIDE, scope=_wide_scope(), place=fluid.CPUPlace(),
+        config=DecodeConfig(max_batch=3, prompt_buckets=(8,),
+                            max_new_tokens=6, page_size=4, decode_block=2,
+                            default_timeout_s=120.0))
+    try:
+        assert engine.programs.decode["in_place"] is hook
+        engine.warmup()
+        rng = np.random.RandomState(3)
+        prompts = [rng.randint(0, WIDE.vocab_size, (n,)).astype(np.int64)
+                   for n in (3, 7, 5, 2)]
+        requests = [engine.submit(p, max_new=6, timeout=120)
+                    for p in prompts]
+        tokens = [r.result(120) for r in requests]
+        stats = engine.stats()
+    finally:
+        engine.close()
+    assert stats["decode_batches_total"] > 0
+    assert stats["decode_in_place_total"] == (
+        stats["decode_batches_total"] if hook else 0)
+    assert stats["pools_lost_total"] == 0
+    assert all(len(t) == 6 for t in tokens)
