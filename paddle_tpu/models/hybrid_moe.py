@@ -201,7 +201,8 @@ class HybridMoEConfig:
                 "without a speculative form; drop draft_cfg / quantize")
         ring_pages = self.ring_pages(page_size)
         ring = {"window": self.window, "pages_per_seq": ring_pages,
-                "n_pages": max_batch * ring_pages + 1, "pools": (2, 3)}
+                "n_pages": max_batch * ring_pages + 1, "pools": (2, 3),
+                "table": ("RingTable", "ring_table")}
         pool_specs = []
         for kind, pages in ((FULL, n_pages), (WINDOW, ring["n_pages"])):
             n = max(1, self.layers_of(kind))
@@ -221,7 +222,8 @@ class HybridMoEConfig:
                 vocab_size=self.vocab_size, dtype=self.dtype),
             max_batch=max_batch, page_size=page_size, n_pages=n_pages,
             pages_per_seq=pages_per_seq, prompt_buckets=prompt_buckets,
-            decode_block=decode_block, chunk_size=chunk_size, ring=ring,
+            decode_block=decode_block, chunk_size=chunk_size,
+            kinds={"window": ring},
             stats=HYBRID_STATS)
 
 

@@ -239,14 +239,17 @@ class LatentMoEConfig:
 
 def build_block_programs(cfg, *, pool_specs, common, max_batch, page_size,
                          n_pages, pages_per_seq, prompt_buckets,
-                         decode_block, chunk_size, ring=None,
+                         decode_block, chunk_size, kinds=None,
                          stats=PAGED_STATS):
     """The prefill, chunk and decode programs of a model whose block
     kinds are attributes (layers/transformer.py block_paged_op with
-    ``common``), over the pools ``pool_specs``. ``ring`` (a model with
-    window attention layers: models/hybrid_moe.py) is the window kinds'
-    cache as PagedDecodePrograms carries it; every program then takes a
-    second table, [rows, ring["pages_per_seq"]], behind the first."""
+    ``common``), over the pools ``pool_specs``. ``kinds``: the model's
+    cache kinds beyond ``sequence`` as PagedDecodePrograms carries them,
+    name -> spec, in the order of their tables (``window``:
+    models/hybrid_moe.py; ``state``: models/hybrid_ssm.py); every program
+    then takes a table a kind, [rows, spec["pages_per_seq"]], behind the
+    page table, under the op's slot and the feed name ``spec["table"]``
+    gives."""
     from ..core import framework
 
     def bundle(kind, prefix, feeds, steps=1):
@@ -272,11 +275,9 @@ def build_block_programs(cfg, *, pool_specs, common, max_batch, page_size,
                 "extras": ("logits", "picks", "stats")}
 
     def tables(b):
-        out = [("Table", "table", [b, pages_per_seq], "int32")]
-        if ring is not None:
-            out.append(("RingTable", "ring_table",
-                        [b, ring["pages_per_seq"]], "int32"))
-        return out
+        return [("Table", "table", [b, pages_per_seq], "int32")] + [
+            (*spec["table"], [b, spec["pages_per_seq"]], "int32")
+            for spec in (kinds or {}).values()]
 
     prefill = {
         bucket: bundle("prefill", "pp", [
@@ -304,7 +305,7 @@ def build_block_programs(cfg, *, pool_specs, common, max_batch, page_size,
         cfg, None, page_size, pages_per_seq, n_pages, max_batch,
         prefill, decode, None, list(pool_specs), None,
         chunk=chunk, chunk_size=None if chunk is None else cs,
-        stats=stats, ring=ring)
+        stats=stats, kinds=kinds)
 
 
 LATENT_MOE_TINY = LatentMoEConfig(

@@ -376,17 +376,22 @@ class PagedDecodePrograms:
 
     Every pool is of the ``sequence`` cache kind (pages for as long as
     the request lives, ``pages_per_seq`` a row, one table) unless
-    ``ring`` says otherwise: ``{"window": w, "pages_per_seq": pages a
-    row's ring has, "n_pages": the ring pools' pages, null page
-    included, "pools": the indices in pool_specs of the pools that are
-    rings}``, the ``window`` cache kind of a model with window attention
-    layers. Every program of such a model takes the rows' ring table
-    behind their page table."""
+    ``kinds`` says otherwise: cache kind -> ``{"pages_per_seq": pages a
+    row holds of it, "n_pages": the kind's pages, null page included,
+    "pools": the indices in pool_specs of the kind's pools, "table": the
+    (op slot, feed name) of the rows' table of it}``, by the name the
+    allocator knows the kind by and in the order of the tables, which
+    every program of such a model takes behind the page table. ``window``
+    (a model with window attention layers) is a RING of pages a row
+    (``"window"``: w); ``state`` (a model with state-space layers) ONE
+    entry a request whatever its length (``pages_per_seq`` 1; ``"unit"``:
+    what the engine's gauges call its pages, "entries"). The engine
+    reads a kind from here and nowhere else."""
 
     def __init__(self, cfg, draft_cfg, page_size, pages_per_seq,
                  n_pages, max_batch, prefill, decode, spec, pool_specs,
                  draft_pool_specs, draft_prefill=None, chunk=None,
-                 chunk_size=None, stats=(), ring=None):
+                 chunk_size=None, stats=(), kinds=None):
         self.cfg = cfg
         self.draft_cfg = draft_cfg
         self.page_size = page_size
@@ -403,7 +408,7 @@ class PagedDecodePrograms:
         self.pool_specs = pool_specs
         self.draft_pool_specs = draft_pool_specs
         self.stats = tuple(stats)
-        self.ring = ring
+        self.kinds = dict(kinds or {})
 
 
 def prefill_buckets_reached(prompt_buckets, chunk_size):

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.registry import register_op
+from . import ssm
 from .pallas_attention import (flash_attention, paged_gqa_decode,
                                paged_gqa_usable)
 
@@ -293,7 +294,16 @@ class BlockKinds:
     pools are this kind's: a kind with a window keeps a RING of the last
     positions, a kind without keeps the whole sequence), and
     ``layer_kinds`` names each layer's kind by its index there.
-    ``of(k)`` is this object at kind ``k``."""
+    ``of(k)`` is this object at kind ``k``.
+
+    A kind of layer may have ANOTHER MIXER than attention: ``"mixer":
+    "ssm"`` in its dict is the selective state-space mixer (ops/ssm.py),
+    whose cache is no list of positions but ONE entry a sequence, the
+    ``state`` cache kind: its ``pools`` are the recurrent state ``[layers,
+    entries, N, C]`` float32 and the convolution's tail ``[layers,
+    entries, (k - 1) * C]``, reached through a third table, [rows, 1]
+    (``StateTable``). ``rotary_dim`` 0: the ``gqa`` kinds rotate nothing
+    (the state layers carry the order)."""
 
     def __init__(self, *, n_heads, n_kv=None, base=10000.0, eps=1e-6,
                  attention="gqa", ffn="swiglu", residual="plain",
@@ -336,6 +346,7 @@ class BlockKinds:
         out = copy.copy(self)
         out.n_kv, out.base = kind["n_kv"], kind["base"]
         out.window, out.sink = kind["window"], kind["sink"]
+        out.attention = kind.get("mixer", self.attention)
         return out
 
 
@@ -349,6 +360,8 @@ def _gqa_attention(kinds, p, u, pos, attend_fn):
     rd = kinds.rotary_dim
 
     def rotate(x):
+        if rd == 0:
+            return x
         if rd is None or rd == hd:
             return apply_rope_at(x, pos, kinds.base)
         return jnp.concatenate(
@@ -384,6 +397,22 @@ def _latent_attention(kinds, p, u, pos, attend_fn):
     k_pe = rotate(ckv[..., None, r:])[:, :, 0]
     entry = jnp.concatenate([c, k_pe], axis=-1)
     return attend_fn((q_nope, rotate(q_pe)), (entry,)) @ p["Wo"]
+
+
+def _is_ssm(spec):
+    """Whether a kind of layer (an ``attn_kinds`` dict) has the selective
+    state-space mixer, and so a cache of the ``state`` kind."""
+    return spec.get("mixer") == "ssm"
+
+
+def _ssm_mixer(kinds, p, u, pos, attend_fn):
+    """The selective state-space mixer's projections: ``[z | g] = W_in u``,
+    out ``= W_out (y * silu(g))``. ``attend_fn(z, ())`` owns what lies
+    between, the convolution and the recurrence over z (ops/ssm.py), and
+    the state a sequence carries from one call to the next."""
+    zg = qmat(u, p, "WIn")
+    z, g = jnp.split(zg, 2, axis=-1)
+    return qmat(attend_fn(z, ()) * jax.nn.silu(g), p, "WOut")
 
 
 def _swiglu(p, x, gate="WGate", up="WUp", down="WDown"):
@@ -473,7 +502,8 @@ def _mhc_residual(kinds, p, which, x, sublayer):
     return out.astype(x.dtype)
 
 
-_ATTENTION = {"gqa": _gqa_attention, "latent": _latent_attention}
+_ATTENTION = {"gqa": _gqa_attention, "latent": _latent_attention,
+              "ssm": _ssm_mixer}
 _FFN = {"swiglu": _swiglu_ffn, "routed": _routed_ffn}
 _RESIDUAL = {"plain": _plain_residual, "mhc": _mhc_residual}
 
@@ -1222,6 +1252,11 @@ PAGED_STATS = ("moe_assignments_total", "moe_max_load_total",
 # steps had to read of the cache)
 HYBRID_STATS = PAGED_STATS + ("attn_full_positions_total",
                               "attn_window_positions_total")
+# and a model some of whose layers are state-space mixers: the states its
+# active rows updated over decode steps (layers x rows), and the real
+# positions its prefill windows scanned (layers x positions)
+SSM_STATS = HYBRID_STATS + ("ssm_state_updates_total",
+                            "ssm_prefill_positions_total")
 
 # keys a prefill window expands at a time (latent attention): scores of
 # [heads, window, keys] float32, never of the whole cache. At most
@@ -1347,7 +1382,23 @@ class _PagedRunner:
     window kinds' rings stacked, [layers, B, ring, ...], and of the layers
     that keep the whole sequence a view EACH, [B, g, kmax, d] with heads
     before positions (``gather_layers``): a step reads its layer's view
-    where it lies, and those layers are taken by number, not by a scan."""
+    where it lies, and those layers are taken by number, not by a scan.
+
+    ``state`` (a layer whose mixer is the selective state-space one,
+    ops/ssm.py): ONE entry a row for the row's life, reached through
+    ``state_table`` [B, 1]; the kind's two pools are the recurrent state
+    ``[layers, entries, N, C]`` float32 and the convolution's tail
+    ``[layers, entries, (k - 1) * C]``, the minor axis whole lane tiles as
+    everywhere. Entry 0 is the null entry. UNLIKE A PAGE, AN ENTRY IS NOT
+    PROTECTED BY THE LENGTH MASK: whatever it holds is the row's past. So
+    a prefill window that starts a row (``fresh``: the whole-prompt op; a
+    chunk whose offset is 0) starts from zeros and does not look at the
+    entry, scans the row's real positions alone (ssm.window) and writes
+    the state after position ``lens - 1``; a decode step reads, updates
+    and writes the live rows' entries where they lie, in the entries'
+    order (``_state_step``: the dense form has no view of this kind), and
+    a row that is not live writes nothing at all. A run of such layers is
+    one scan."""
 
     def __init__(self, params, emb_w, fnorm, head, *, n_heads, n_kv,
                  base, eps, page_size, head_scale=None, moe_top_k=2,
@@ -1369,6 +1420,9 @@ class _PagedRunner:
         self.lead = lead
         self.stacks = stacks    # attention kind's prefix -> its layers
         self.ring_table = None  # [B, ring pages]: the window kinds' table
+        self.state_table = None  # [B, 1]: the state kinds' entry a row
+        self.fresh = False      # a prefill window from position 0: the
+                                # rows' states start from zeros, unread
         self.lens = None        # [B]: a prefill window's real tokens
         self.valid = None       # [B, T] bool: the tokens Stats counts
         self.pick_at = None     # [B]: the window position Picks reports
@@ -1548,6 +1602,69 @@ class _PagedRunner:
             for e, ring in zip(entries, rings))
         return out, rings
 
+    def _state_prefill(self, p, z, mine, lyr, pos0, spec):
+        """A prefill window through a state-space layer: (y, the kind's
+        pools written). The rows' state and tail come from their entries
+        where the window continues a row (``pos0 > 0``) and are zeros
+        where it starts one; ``fresh`` (every row starts): the entries are
+        not read at all."""
+        b = z.shape[0]
+        s_pool, t_pool = mine
+        entry = self.state_table[:, 0]
+        with jax.named_scope("cache/" + spec["name"]):
+            if self.fresh:
+                state0 = jnp.zeros((b,) + s_pool.shape[2:], s_pool.dtype)
+                tail0 = jnp.zeros((b, t_pool.shape[2]), t_pool.dtype)
+            else:
+                goes_on = pos0 > 0
+                state0 = jnp.where(goes_on[:, None, None],
+                                   s_pool[lyr, entry], 0)
+                tail0 = jnp.where(goes_on[:, None], t_pool[lyr, entry], 0)
+        y, state, tail = ssm.window(
+            p, z, state0, tail0.reshape(b, -1, z.shape[-1]), self.lens,
+            self.kinds.eps)
+        with jax.named_scope("cache/" + spec["name"]):
+            s_pool = s_pool.at[lyr, entry].set(state.astype(s_pool.dtype))
+            t_pool = t_pool.at[lyr, entry].set(
+                tail.reshape(b, -1).astype(t_pool.dtype))
+        return y, [s_pool, t_pool]
+
+    def _state_step(self, p, z, mine, lyr, spec):
+        """A decode step through a state-space layer, run IN THE ENTRIES'
+        ORDER against the kind's pools themselves: (y [B, 1, C], the
+        pools written). The step's inputs, a row each and small, are
+        scattered to their rows' entries, every entry of the layer is
+        stepped where it lies, and the live rows' outputs are gathered
+        back: the states, which are most of what a step moves, are
+        neither gathered to the rows nor scattered back (a view of them
+        cost 6.5 GB of copies a dispatch at 128 rows: PERF.md section 6,
+        PR 39). An entry that no live row holds (the null entry, a free
+        one, a chunk job's between its chunks) keeps what it held, and a
+        row that is not live gets zeros."""
+        s_pool, t_pool = mine
+        n = s_pool.shape[1]
+        live = self.valid[:, 0]
+        entry = self.state_table[:, 0]
+        with jax.named_scope("cache/" + spec["name"]):
+            at = jnp.where(live, entry, n)          # not live: dropped
+            z_e = jnp.zeros((n, z.shape[-1]), z.dtype).at[at].set(
+                z[:, 0], mode="drop")
+            held = jnp.zeros((n,), bool).at[at].set(True, mode="drop")
+            state0, tail0 = s_pool[lyr], t_pool[lyr]
+        y_e, state, tail = ssm.step(
+            p, z_e, state0, tail0.reshape(n, -1, z.shape[-1]),
+            self.kinds.eps)
+        with jax.named_scope("cache/" + spec["name"]):
+            s_pool = s_pool.at[lyr].set(jnp.where(
+                held[:, None, None], state.astype(s_pool.dtype), state0))
+            t_pool = t_pool.at[lyr].set(jnp.where(
+                held[:, None], tail.reshape(n, -1).astype(t_pool.dtype),
+                tail0))
+            # a row that is not live reads no entry either: whatever the
+            # null entry holds cannot reach the null PAGE through it
+            y = jnp.where(live[:, None], y_e[entry], 0)
+        return y[:, None], [s_pool, t_pool]
+
     def _kv_up(self, p):
         """A layer's latent -> per-head [key | value] expansion,
         [kv_rank, heads, nope_dim + v_dim]."""
@@ -1716,7 +1833,7 @@ class _PagedRunner:
             start, nxt[kind] = nxt[kind], nxt[kind] + count
             spec = self.kinds.attn_kinds[kind]
             held, sliced = split(self.stacks[spec["stack"]])
-            if spec["window"] is None:
+            if spec["window"] is None and not _is_ssm(spec):
                 # a layer that keeps the whole sequence is taken by its
                 # own number, not a scan's: the dense form then holds a
                 # view a layer and reads it where it lies, where a
@@ -1774,7 +1891,10 @@ class _PagedRunner:
             spec = self.kinds.attn_kinds[kind]
             mine = [pools[i] for i in spec["pools"]]
             sink = p["Sink"] if spec["sink"] else None
-            if spec["window"] is not None:
+            if _is_ssm(spec):
+                out, mine = self._state_prefill(p, q, mine, lyr, pos0,
+                                                spec)
+            elif spec["window"] is not None:
                 with jax.named_scope("attn/" + spec["name"]):
                     out, mine = self._window_prefill(
                         q, entries, mine, lyr, q_pos, spec["window"], sink)
@@ -1834,6 +1954,10 @@ class _PagedRunner:
         for spec in self.kinds.attn_kinds or ():
             if i in spec["pools"]:
                 scope = jax.named_scope("cache/" + spec["name"])
+                if _is_ssm(spec):
+                    # no view: the steps run against the pool (_state_step)
+                    return (None, (lambda pool, _: pool,
+                                   lambda pool, seen, *_: seen), scope)
                 if spec["window"] is not None:
                     return (self.ring_table,
                             (self.gather, self.write_back_ring), scope)
@@ -1944,7 +2068,9 @@ class _PagedRunner:
             spec = self.kinds.attn_kinds[kind]
             sink = p["Sink"] if spec["sink"] else None
             mine = [dense[i] for i in spec["pools"]]
-            if spec["window"] is None:
+            if _is_ssm(spec):
+                out, mine = self._state_step(p, q, mine, lyr, spec)
+            elif spec["window"] is None:
                 with jax.named_scope("attn/" + spec["name"]):
                     # a view a layer [B, g, kmax, d] (gather_layers);
                     # ``lyr`` is the layer's own number (_stack_forward)
@@ -2038,13 +2164,24 @@ class _PagedRunner:
         each summed over its routed layers; for a decode step also its
         expert-layer calls x experts held, the experts among them that a
         token reached, and the cache positions its active rows
-        attended."""
+        attended (HYBRID_STATS: by cache kind); a model with state-space
+        layers (SSM_STATS) also the states a decode step updated and the
+        real positions a prefill window scanned."""
         hybrid = self.kinds.layer_kinds is not None
-        out = [jnp.int32(0)] * len(HYBRID_STATS if hybrid else PAGED_STATS)
+        names = stats_names(self.kinds)
+        out = [jnp.int32(0)] * len(names)
+        for k, spec in enumerate(self.kinds.attn_kinds or ()):
+            if _is_ssm(spec):
+                at = names.index("ssm_state_updates_total" if decode
+                                 else "ssm_prefill_positions_total")
+                out[at] = out[at] + self.kinds.layer_kinds.count(k) \
+                    * jnp.sum(self.valid)
         if decode and hybrid:
             n = jnp.where(self.valid[:, 0], positions + 1, 0)
             for k, spec in enumerate(self.kinds.attn_kinds):
                 layers = self.kinds.layer_kinds.count(k)
+                if _is_ssm(spec):
+                    continue
                 if spec["window"] is None:
                     out[6] = out[6] + layers * jnp.sum(n)
                 else:
@@ -2062,6 +2199,17 @@ class _PagedRunner:
         if decode and self.kinds.attention == "latent":
             out[4] = jnp.sum(jnp.where(self.valid[:, 0], positions + 1, 0))
         return jnp.stack([jnp.asarray(x, jnp.int32) for x in out])
+
+
+def stats_names(kinds):
+    """The counters a program of a model of these kinds returns, in its
+    order: PAGED_STATS, HYBRID_STATS where it mixes kinds of layer,
+    SSM_STATS where some of those are state-space mixers."""
+    if kinds.layer_kinds is None:
+        return PAGED_STATS
+    if any(_is_ssm(k) for k in kinds.attn_kinds):
+        return SSM_STATS
+    return HYBRID_STATS
 
 
 def decode_in_place(attention, attn_kinds, pool_shapes):
@@ -2122,6 +2270,7 @@ def _paged_prefill(run, tokens, lens, offsets, table, pools):
     # what Stats counts: real tokens of rows that own a real first page
     run.valid = (jnp.arange(t, dtype=jnp.int32)[None] < lens[:, None]) \
         & (table[:, :1] > 0)
+    run.fresh = offsets is None
     if offsets is None:         # and sees its own window, no further
         run.seen, offsets = t, jnp.zeros((b,), jnp.int32)
     h, *pools = run.forward(run.embed(tokens), *pools, table, offsets, t)
@@ -2170,7 +2319,7 @@ def _paged_decode(run, tok, pos, table, pools, steps, extras=False):
             return (nxt, pos + 1, tuple(cache), stats), nxt
         return ((nxt, pos + 1, tuple(cache),
                  stats + run.stats(True, pos)),
-                (nxt, logits, jnp.moveaxis(run.picks, 0, 1)))
+                (nxt, logits, _picks_of(run, tok.shape[0])))
 
     stats0 = jnp.zeros_like(run.stats(False)) if extras else None
     (_, _, cache, stats), ys = jax.lax.scan(
@@ -2272,7 +2421,8 @@ _BLOCK_SLOTS = (
     "Wo", "WGate", "WUp", "WDown", "MoeRouter", "MoeBias", "MoeWGate",
     "MoeWUp", "MoeWDown", "ShWGate", "ShWUp", "ShWDown", "HcAttnPhi",
     "HcAttnAlpha", "HcAttnBias", "HcMlpPhi", "HcMlpAlpha", "HcMlpBias",
-    "Wq", "Wk", "Wv", "Sink")
+    "Wq", "Wk", "Wv", "Sink", "WIn", "ConvW", "ConvB", "WX", "DtNorm",
+    "BNorm", "CNorm", "WDt", "DtBias", "ALog", "D", "WOut")
 
 
 def _block_runner(ins, attrs):
@@ -2312,12 +2462,22 @@ def _block_runner(ins, attrs):
         stacks=stacks or None)
     if "RingTable" in ins:
         run.ring_table = ins["RingTable"][0]
+    if "StateTable" in ins:
+        run.state_table = ins["StateTable"][0]
     return run
+
+
+def _picks_of(run, rows):
+    """The routed layers' picks of the last forward, rows first; [rows, 0,
+    K] for a model without a routed layer."""
+    if run.picks is None:
+        return jnp.zeros((rows, 0, run.kinds.moe_top_k), jnp.int32)
+    return jnp.moveaxis(run.picks, 0, 1)
 
 
 def _block_prefill_outputs(run, nxt, logits, pools):
     return {"NextTok": [nxt], "Logits": [logits],
-            "Picks": [jnp.moveaxis(run.picks, 0, 1)],
+            "Picks": [_picks_of(run, nxt.shape[0])],
             "PoolsOut": list(pools), "Stats": [run.stats(False)]}
 
 

@@ -144,7 +144,19 @@ _DECODE_COUNTERS = (
     # two; the first three stay 0 for a model with one cache kind.
     "attn_full_positions_total", "attn_window_positions_total",
     "window_pages_recycled_total",
-    "cache_bytes_held_total", "cache_positions_resident_total")
+    "cache_bytes_held_total", "cache_positions_resident_total",
+    # a model with state-space layers (PR 39) keeps ONE cache entry a
+    # request in each of them, the ``state`` kind, and counts on the
+    # device the states its active rows updated over decode steps and the
+    # real positions its prefill windows scanned (SSM_STATS: layers x
+    # rows | positions). The engine's own: the dispatches that started a
+    # request's state from zeros (a whole-prompt prefill, a prompt's first
+    # chunk: it equals the requests started, and a request whose entry
+    # was not reset would continue its predecessor's), and, beside
+    # cache_bytes_held_total (which counts them too), the bytes of the
+    # state entries the active slots held. 0 for a model without the kind.
+    "ssm_state_updates_total", "ssm_prefill_positions_total",
+    "state_resets_total", "state_bytes_held_total")
 
 # how long after a program's end the worker keeps polling before it reads
 # the tokens and counters whose host copies set out with the program: the
@@ -341,14 +353,13 @@ class _Slot:
     """One active decode slot: the request, its page set / table row,
     and the per-sequence scheduler state."""
 
-    __slots__ = ("req", "pages", "ring", "table", "pos", "cur", "prev",
+    __slots__ = ("req", "held", "table", "pos", "cur", "prev",
                  "emitted", "first_token_at")
 
-    def __init__(self, req, pages, table, pos, cur, prev, emitted,
-                 first_token_at, ring=None):
+    def __init__(self, req, held, table, pos, cur, prev, emitted,
+                 first_token_at):
         self.req = req
-        self.pages = pages            # of the ``sequence`` kind
-        self.ring = ring              # the ``window`` kind's, or None
+        self.held = held              # cache kind -> its pages (_alloc)
         self.table = table            # np int32 [pages_per_seq]
         self.pos = pos                # cache length (cur not cached yet)
         self.cur = cur                # last emitted token
@@ -364,12 +375,11 @@ class _ChunkJob:
     chunk installs it), so free-slot accounting and the decode batch
     never see a half-prefilled sequence."""
 
-    __slots__ = ("req", "pages", "ring", "table", "off")
+    __slots__ = ("req", "held", "table", "off")
 
-    def __init__(self, req, pages, table, off=0, ring=None):
+    def __init__(self, req, held, table, off=0):
         self.req = req
-        self.pages = pages
-        self.ring = ring              # the ``window`` kind's, or None
+        self.held = held              # cache kind -> its pages (_alloc)
         self.table = table            # np int32 [pages_per_seq]
         self.off = off                # prompt tokens prefilled so far
 
@@ -385,14 +395,23 @@ class DecodeEngine:
 
     A model's pools are of the ``sequence`` cache kind (pages for as long
     as the request lives) unless its programs say otherwise
-    (``programs.ring``): the ``window`` kind is a ring of
-    ``ring["pages_per_seq"]`` pages a request, whatever its length
-    (kv_pages.py). One ``PageAllocator`` serves both; a slot or chunk job
-    holds pages of each kind its model has (``pages``, ``ring``) and they
-    are granted together and freed together: admission, retirement,
-    shedding and the handoff blob cover both, and every program takes the
-    rows' table of each kind. A model with one kind has one table and
-    today's programs.
+    (``programs.kinds``): the ``window`` kind is a ring of
+    ``pages_per_seq`` pages a request, whatever its length, and the
+    ``state`` kind ONE entry a request, which no position indexes
+    (kv_pages.py). One ``PageAllocator`` serves them all; a slot or chunk
+    job holds a MAPPING, cache kind -> its pages of that kind (``held``),
+    granted together and freed together: admission, retirement, shedding
+    and the handoff blob cover every kind the model has, and every
+    program takes the rows' table of each kind, in ``programs.kinds``'
+    order behind the page table. A model with one kind has one table and
+    today's programs. The engine knows a kind by what ``programs.kinds``
+    says of it and by nothing else, with two exceptions that are the
+    kinds' own meaning: a ring's turns are counted
+    (``window_pages_recycled_total``), and a dispatch that starts a
+    request where the model has the ``state`` kind counts a reset
+    (``state_resets_total``): the programs start such a request's state
+    from zeros, since an entry, unlike a page, is not hidden by the
+    length mask.
 
     **The pools are the engine's alone and are donated to every
     dispatch**: a program takes ``_pools`` (``_draft_pools``) in, XLA
@@ -415,7 +434,8 @@ class DecodeEngine:
     chip where there is one; the KV pools are created on that default
     device whatever ``place`` says."""
 
-    RING = "window"         # the second cache kind's name in the allocator
+    RING = "window"         # cache kinds beyond ``sequence``, as the
+    STATE = "state"         # allocator and ``programs.kinds`` name them
 
     def __init__(self, cfg, scope=None, place=None, config=None,
                  draft_cfg=None, auto_start=True, optimize=True):
@@ -461,16 +481,24 @@ class DecodeEngine:
             decode_block=c.decode_block, quantize=c.quantize,
             draft_cfg=draft_cfg, gamma=c.gamma,
             chunk_size=c.chunk_size)
-        # a second cache kind, where the model has window layers: a ring
-        # of pages a request, its own pool of page ids
-        self.ring = self.programs.ring
-        if self.ring is not None:
-            self.allocator.add_kind(self.RING, self.ring["n_pages"])
-        # bytes a page of each kind holds, over all the kind's pools
-        self._page_bytes = {PageAllocator.SEQUENCE: 0, self.RING: 0}
-        for i, (shape, dtype) in enumerate(self.programs.pool_specs):
-            kind = self.RING if self.ring is not None \
-                and i in self.ring["pools"] else PageAllocator.SEQUENCE
+        # further cache kinds, where the model has them (window layers: a
+        # ring of pages a request; state-space layers: an entry a
+        # request), each with its own pool of page ids
+        self.kinds = dict(self.programs.kinds)
+        # the ``window`` kind's spec by a name of its own, beside
+        # ``_ring_rows``: what benchmark/builders/serve_hybrid.py reads
+        self.ring = self.kinds.get(self.RING)
+        for kind, spec in self.kinds.items():
+            self.allocator.add_kind(kind, spec["n_pages"])
+        # which kind each pool is of, and the bytes a page of each kind
+        # holds, over all the kind's pools
+        self._pool_kind = [
+            next((k for k, spec in self.kinds.items()
+                  if i in spec["pools"]), PageAllocator.SEQUENCE)
+            for i in range(len(self.programs.pool_specs))]
+        self._page_bytes = dict.fromkeys(self.allocator.kinds, 0)
+        for kind, (shape, dtype) in zip(self._pool_kind,
+                                        self.programs.pool_specs):
             self._page_bytes[kind] += int(
                 np.prod([shape[0]] + list(shape[2:]))
                 * np.dtype(dtype).itemsize)
@@ -604,7 +632,7 @@ class DecodeEngine:
         n = 0
         row = (np.ones((1,), np.int32),
                np.zeros((1, self.pages_per_seq), np.int32),
-               self._ring_rows([None]))
+               *self._kind_tables([None]))
         for bucket in sorted(self.programs.prefill):
             self._run_prefill_program(
                 bucket, np.zeros((1, bucket), np.int64), *row)
@@ -619,7 +647,7 @@ class DecodeEngine:
                 np.zeros((1, cs), np.int64), np.ones((1,), np.int32),
                 np.zeros((1,), np.int32),
                 np.zeros((1, self.pages_per_seq), np.int32),
-                self._ring_rows([None]))
+                *self._kind_tables([None]))
             n += 1
         # the PLAIN decode program warms even for speculative engines:
         # brownout level 2 (spec_off) switches a live engine to it,
@@ -629,7 +657,7 @@ class DecodeEngine:
             np.ones((self.config.max_batch,), np.int32),
             np.zeros((self.config.max_batch, self.pages_per_seq),
                      np.int32),
-            self._ring_rows([None] * self.config.max_batch))
+            *self._kind_tables([None] * self.config.max_batch))
         n += 1
         if self.draft_cfg is not None:
             self._run_spec_program(
@@ -726,13 +754,13 @@ class DecodeEngine:
                 f" pages but the pool only has "
                 f"{self.allocator.usable_pages} — grow n_pages or "
                 "shorten the request")
-        if self.ring is not None and self.ring["pages_per_seq"] \
-                > self.allocator.usable_of(self.RING):
-            self.metrics.incr("shed_total")
-            raise PagesExhaustedError(
-                f"request needs a ring of {self.ring['pages_per_seq']} "
-                "window pages but the pool only has "
-                f"{self.allocator.usable_of(self.RING)}")
+        for kind, spec in self.kinds.items():
+            if spec["pages_per_seq"] > self.allocator.usable_of(kind):
+                self.metrics.incr("shed_total")
+                raise PagesExhaustedError(
+                    f"request needs {spec['pages_per_seq']} {kind} pages "
+                    "but the pool only has "
+                    f"{self.allocator.usable_of(kind)}")
         if not self.breaker.admits():
             self.metrics.incr("breaker_shed_total")
             raise ServiceUnavailableError(
@@ -832,6 +860,11 @@ class DecodeEngine:
                 f"handoff page_size {state['page_size']} != this "
                 f"engine's {self.config.page_size} — prefill and "
                 "decode replicas must share the page geometry")
+        if set(state.get("kinds", {})) != set(self.kinds):
+            raise ServingError(
+                "handoff blob and this engine's model differ in their "
+                f"cache kinds ({sorted(state.get('kinds', {}))} against "
+                f"{sorted(self.kinds)} beside the sequence kind)")
         prompt = np.asarray(state["prompt"], np.int64).reshape(-1)
         max_new = int(state["max_new"])
         emitted = [int(t) for t in state["emitted"]]
@@ -914,11 +947,12 @@ class DecodeEngine:
         snap["max_batch"] = self.config.max_batch
         snap["pages_in_use"] = self.allocator.in_use
         snap["pages_available"] = self.allocator.available
-        if self.ring is not None:
-            snap["window_pages_in_use"] = self.allocator.in_use_of(
-                self.RING)
-            snap["window_pages_available"] = self.allocator.available_of(
-                self.RING)
+        for kind, spec in self.kinds.items():
+            unit = spec.get("unit", "pages")     # window_pages_in_use,
+            snap[f"{kind}_{unit}_in_use"] = \
+                self.allocator.in_use_of(kind)   # state_entries_in_use
+            snap[f"{kind}_{unit}_available"] = \
+                self.allocator.available_of(kind)
         snap["health_state"] = self.health.state
         snap["breaker"] = self.breaker.snapshot()
         snap["brownout"] = (None if self.brownout is None
@@ -1101,54 +1135,62 @@ class DecodeEngine:
             self.metrics.incr("pools_consumed_total")
         return head
 
+    def _kind_tables(self, helds):
+        """The rows' tables of every cache kind beyond ``sequence``, in
+        ``programs.kinds``' order, each [rows, the kind's pages a request]:
+        what a program takes behind the page table. ``helds``: a row's
+        ``held`` mapping, or None for a row that holds nothing (the null
+        page). () for a model with one kind, whose programs take one
+        table."""
+        out = []
+        for kind, spec in self.kinds.items():
+            t = np.zeros((len(helds), spec["pages_per_seq"]), np.int32)
+            for i, held in enumerate(helds):
+                if held is not None:
+                    t[i] = held[kind]
+            out.append(t)
+        return tuple(out)
+
     def _ring_rows(self, rings):
-        """The rows' ring tables [rows, ring pages] for a program of a
-        model with a ``window`` cache kind (a row without a ring: the
-        null page); None for a model with one kind, whose programs take
-        one table."""
+        """The ``window`` kind's table alone, from the rows' rings (a
+        model with that kind beside ``sequence`` and no other); None for a
+        model without it."""
         if self.ring is None:
             return None
-        out = np.zeros((len(rings), self.ring["pages_per_seq"]), np.int32)
-        for i, ring in enumerate(rings):
-            if ring is not None:
-                out[i] = ring
-        return out
+        return self._kind_tables([None if ring is None
+                                  else {self.RING: ring}
+                                  for ring in rings])[0]
 
-    @staticmethod
-    def _tables(table, ring):
-        return (table,) if ring is None else (table, ring)
-
-    def _run_prefill_program(self, bucket, tokens, lens, table, ring=None):
+    def _run_prefill_program(self, bucket, tokens, lens, table, *kinds):
         """The bucket's single-row program once for each row given, in
         order: the ``[rows]`` next tokens. ``kept`` holds what the last
-        row's dispatch left. ``ring``: the rows' ring tables, where the
-        model has them (``_ring_rows``)."""
+        row's dispatch left. ``kinds``: the rows' tables of the model's
+        further cache kinds (``_kind_tables``)."""
         return self._run_rows(f"prefill_{bucket}",
                               self.programs.prefill[bucket],
-                              tokens, lens, table, ring)
+                              tokens, lens, table, *kinds)
 
     def _run_draft_prefill_program(self, bucket, tokens, lens, table):
         self._run_rows(f"draft_prefill_{bucket}",
                        self.programs.draft_prefill[bucket],
                        tokens, lens, table)
 
-    def _run_rows(self, label, b, tokens, lens, table, ring=None):
+    def _run_rows(self, label, b, tokens, lens, table, *kinds):
         return np.concatenate([
             self._run_program(
-                label, b, (tokens[i:i + 1], lens[i:i + 1]) + self._tables(
-                    table[i:i + 1],
-                    None if ring is None else ring[i:i + 1]))[0]
+                label, b, (tokens[i:i + 1], lens[i:i + 1], table[i:i + 1])
+                + tuple(t[i:i + 1] for t in kinds))[0]
             for i in range(len(tokens))])
 
-    def _run_chunk_program(self, tokens, lens, offsets, table, ring=None):
+    def _run_chunk_program(self, tokens, lens, offsets, table, *kinds):
         return self._run_program(
             "chunk", self.programs.chunk,
-            (tokens, lens, offsets) + self._tables(table, ring))[0]
+            (tokens, lens, offsets, table) + kinds)[0]
 
-    def _run_decode_program(self, tokens, positions, table, ring=None):
+    def _run_decode_program(self, tokens, positions, table, *kinds):
         return self._run_program(
             "decode", self.programs.decode,
-            (tokens, positions) + self._tables(table, ring))[0]
+            (tokens, positions, table) + kinds)[0]
 
     def _run_spec_program(self, tokens, prev, positions, table):
         emitted, accepted = self._run_program(
@@ -1163,25 +1205,28 @@ class DecodeEngine:
         return self.allocator.pages_for(
             max(bucket, prompt_len + max_new + slack))
 
-    def _alloc(self, n_pages):
-        """``n_pages`` pages of the ``sequence`` kind and, where the
-        model has one, a ring of the ``window`` kind: both or, with
-        PagesExhaustedError, neither. Under ``_slots_lock``."""
-        pages = self.allocator.alloc(n_pages)
-        if self.ring is None:
-            return pages, None
+    def _alloc(self, n_pages, grant=None):
+        """What a request holds, cache kind -> pages: ``n_pages`` of the
+        ``sequence`` kind and of every further kind the model has the
+        kind's pages a request (a ring; a state entry): all of them or,
+        with PagesExhaustedError, none. ``grant(kind, n)`` allocates
+        (``allocator.alloc`` where None). Under ``_slots_lock``."""
+        grant = grant or (lambda kind, n: self.allocator.alloc(n, kind))
+        held = {}
         try:
-            return pages, self.allocator.alloc(
-                self.ring["pages_per_seq"], self.RING)
+            held[PageAllocator.SEQUENCE] = grant(PageAllocator.SEQUENCE,
+                                                 n_pages)
+            for kind, spec in self.kinds.items():
+                held[kind] = grant(kind, spec["pages_per_seq"])
         except PagesExhaustedError:
-            self.allocator.free(pages)
+            self._free(held)
             raise
+        return held
 
-    def _free(self, pages, ring):
-        """Both kinds of a request's pages back. Under ``_slots_lock``."""
-        self.allocator.free(pages)
-        if ring is not None:
-            self.allocator.free(ring, self.RING)
+    def _free(self, held):
+        """A request's pages of every kind back. Under ``_slots_lock``."""
+        for kind, pages in held.items():
+            self.allocator.free(pages, kind)
 
     def _bucket_for(self, prompt_len):
         for b in self.config.prompt_buckets:
@@ -1239,12 +1284,12 @@ class DecodeEngine:
             for i, slot in enumerate(self.slots):
                 if slot is not None:
                     pending.append(slot.req)
-                    self._free(slot.pages, slot.ring)
+                    self._free(slot.held)
                     self.slots[i] = None
             jobs, self._chunk_jobs = dict(self._chunk_jobs), {}
             for job in jobs.values():
                 pending.append(job.req)
-                self._free(job.pages, job.ring)
+                self._free(job.held)
         return pending
 
     def _sweep_expired(self):
@@ -1273,7 +1318,7 @@ class DecodeEngine:
             if slot is None:      # already failed by close()/watchdog
                 return
             self.slots[idx] = None
-            self._free(slot.pages, slot.ring)
+            self._free(slot.held)
         with record_event("pt:engine/retire", req=slot.req.seq,
                           tokens=len(slot.emitted)):
             self._settle(slot, error, draining)
@@ -1384,7 +1429,7 @@ class DecodeEngine:
                 admitted = True
                 continue
             bucket, group = plan[1], plan[2]
-            granted = []       # (req, pages) actually prefilling now
+            granted = []       # (req, held) actually prefilling now
             starved = []
             for j, r in enumerate(group):
                 if starved:
@@ -1408,8 +1453,8 @@ class DecodeEngine:
             self.metrics.set_queue_depth(len(self._queue))
             if not self.breaker.allow():
                 with self._slots_lock:
-                    for _, pages in granted:
-                        self._free(*pages)
+                    for _, held in granted:
+                        self._free(held)
                 self.metrics.incr("breaker_shed_total", len(granted))
                 for r, _ in granted:
                     r.set_error(ServiceUnavailableError(
@@ -1421,14 +1466,14 @@ class DecodeEngine:
                                                   pages, idx)
         return admitted
 
-    def _prefill_request(self, policy, bucket, r, pages, idx):
+    def _prefill_request(self, policy, bucket, r, held, idx):
         """One whole-prompt request's own dispatch of its bucket's
         single-row program (the draft's behind it), and its first token
         installed as that dispatch returns: True. A terminal failure
-        frees the pages and fails this request alone: False. ``pages``:
-        (the request's pages, its ring or None), as ``_alloc`` gave them."""
-        pages, ring = pages
-        ring_table = self._ring_rows([ring])
+        frees the pages and fails this request alone: False. ``held``:
+        the request's pages by cache kind, as ``_alloc`` gave them."""
+        pages = held[PageAllocator.SEQUENCE]
+        kind_tables = self._kind_tables([held])
         tokens = np.zeros((1, bucket), np.int64)
         tokens[0, :r.prompt.size] = r.prompt
         lens = np.asarray([r.prompt.size], np.int32)
@@ -1438,7 +1483,7 @@ class DecodeEngine:
         def _prefill_dispatch():
             self._maybe_inject_fault()
             nxt = self._run_prefill_program(bucket, tokens, lens, table,
-                                            ring_table)
+                                            *kind_tables)
             if self.draft_cfg is not None:
                 self._run_draft_prefill_program(bucket, tokens, lens,
                                                 table)
@@ -1457,7 +1502,7 @@ class DecodeEngine:
         except BaseException as exc:     # noqa: BLE001 — forwarded
             self._tick(prefill_dispatch_s_total=dispatch.seconds)
             with self._slots_lock:
-                self._free(pages, ring)
+                self._free(held)
             if self.breaker.record_failure():
                 self.metrics.incr("breaker_open_total")
                 self.health.to(HealthState.DEGRADED)
@@ -1469,9 +1514,9 @@ class DecodeEngine:
                    prefill_dispatch_s_total=dispatch.seconds,
                    prefill_tokens_total=int(r.prompt.size),
                    prefill_padded_tokens_total=bucket,
+                   state_resets_total=int(self.STATE in held),
                    queue_wait_s_total=admitted_at - r.enqueued_at)
-        self._install_first_token(r, pages, table[0], int(nxt[0]), idx,
-                                  ring)
+        self._install_first_token(r, held, table[0], int(nxt[0]), idx)
         return True
 
     def _score_ttft(self, r):
@@ -1487,8 +1532,7 @@ class DecodeEngine:
                               else "slo_ttft_violated")
         self.metrics.observe_window(f"{slo.name}.ttft_s", r.ttft_s)
 
-    def _install_first_token(self, r, pages, table, first, idx,
-                             ring=None):
+    def _install_first_token(self, r, held, table, first, idx):
         """Post-prefill bookkeeping shared by whole-prompt admission
         and the final chunk of a chunked prefill: TTFT accounting,
         then either a decode slot install or — for ``prefill_only``
@@ -1500,41 +1544,40 @@ class DecodeEngine:
         self._score_ttft(r)
         self.metrics.incr("prefill_total")
         self.metrics.incr("generated_tokens_total")
-        if ring is not None:
+        if self.RING in held:
             self._count_recycled(0, r.prompt.size)
         if r.prefill_only:
-            self._export_handoff(r, pages, first, ring)
+            self._export_handoff(r, held, first)
             return
         with self._slots_lock:
             self.slots[idx] = _Slot(
-                r, pages, table, pos=r.prompt.size, cur=first,
+                r, held, table, pos=r.prompt.size, cur=first,
                 prev=int(r.prompt[-1]), emitted=[first],
-                first_token_at=now, ring=ring)
+                first_token_at=now)
         eos = self.config.eos_id
         if (eos is not None and first == eos) or r.max_new == 1:
             self._retire(idx, draining=self._closed
                          and not self._stop.is_set())
 
-    def _export_handoff(self, r, pages, first, ring=None):
+    def _export_handoff(self, r, held, first):
         """Resolve a ``prefill_only`` request with the KV handoff
         blob: the filled page CONTENTS in table order (sequence
         position p lives at blob page ``p // page_size``; in a pool of
         the ``window`` kind at the ring's page ``(p // page_size) % ring
-        pages``, ``ring_pages`` in the blob), the prompt, and the tokens
-        generated so far. Pages are freed here — the
+        pages``; a pool of the ``state`` kind gives the request's one
+        entry), the exporter's pages of each further kind under ``kinds``
+        in the blob, the prompt, and the tokens generated so far. Pages
+        are freed here — the
         blob owns the KV state now; import allocates fresh pages on
         the destination, so the handoff is location-independent."""
         with self._slots_lock:
-            alloc_state = self.allocator.export_state(pages)
-            ring_state = None if ring is None else \
-                self.allocator.export_state(ring, self.RING)
-        idxs = np.asarray(pages, np.int64)
-        rings = () if ring is None else self.ring["pools"]
-        cache = [np.asarray(pool)[:, np.asarray(ring, np.int64)
-                                  if i in rings else idxs]
-                 for i, pool in enumerate(self._pools)]
+            exported = {kind: self.allocator.export_state(pages, kind)
+                        for kind, pages in held.items()}
+        alloc_state = exported.pop(PageAllocator.SEQUENCE)
+        cache = [np.asarray(pool)[:, np.asarray(held[kind], np.int64)]
+                 for kind, pool in zip(self._pool_kind, self._pools)]
         with self._slots_lock:
-            self._free(pages, ring)
+            self._free(held)
         eos = self.config.eos_id
         done = (eos is not None and first == eos) or r.max_new == 1
         if done:
@@ -1555,8 +1598,9 @@ class DecodeEngine:
                  "cache": cache,
                  "done": bool(done),
                  "ttft_s": r.ttft_s}
-        if ring_state is not None:
-            state["ring_pages"] = [] if done else ring_state["pages"]
+        if exported:
+            state["kinds"] = {kind: [] if done else st["pages"]
+                              for kind, st in exported.items()}
         self.metrics.incr("handoff_export_total")
         self.metrics.observe_latency(time.monotonic() - r.enqueued_at)
         self.metrics.incr("responses_total")
@@ -1588,48 +1632,40 @@ class DecodeEngine:
         state = r.handoff_state
         cache = self._handoff_cache(state)
         n_src = int(cache[0].shape[1])
-        if (self.ring is None) != ("ring_pages" not in state):
-            raise ServingError(
-                "handoff blob and this engine's model differ in their "
-                "cache kinds (a ring of window pages on one side alone)")
-        ring = None
+        theirs = state.get("kinds", {})
+
+        def grant(kind, n):
+            pages = state["pages"] if kind == PageAllocator.SEQUENCE \
+                else theirs[kind]
+            return self.allocator.import_alloc(
+                {"pages": pages, "page_size": state["page_size"]},
+                total=n, kind=kind)
+
         try:
             with self._slots_lock:
-                pages = self.allocator.import_alloc(
-                    state,
-                    total=self._pages_needed(r.prompt.size, r.max_new))
-                if self.ring is not None:
-                    try:
-                        ring = self.allocator.import_alloc(
-                            {"pages": state["ring_pages"],
-                             "page_size": state["page_size"]},
-                            total=self.ring["pages_per_seq"],
-                            kind=self.RING)
-                    except PagesExhaustedError:
-                        self.allocator.free(pages)
-                        raise
+                held = self._alloc(
+                    self._pages_needed(r.prompt.size, r.max_new), grant)
         except PagesExhaustedError:
             self.metrics.incr("page_wait_total")
             with self._qlock:
                 self._queue.insert(0, r)
             return False
         import jax.numpy as jnp
-        rows = np.asarray(pages[:n_src], np.int64)
-        ring_rows = None if ring is None else np.asarray(ring, np.int64)
+        pages = held[PageAllocator.SEQUENCE]
+        rows = {kind: np.asarray(
+            got[:n_src] if kind == PageAllocator.SEQUENCE else got,
+            np.int64) for kind, got in held.items()}
         self._pools = [
-            pool.at[:, ring_rows if ring is not None
-                    and i in self.ring["pools"] else rows].set(
-                jnp.asarray(x, pool.dtype))
-            for i, (pool, x) in enumerate(zip(self._pools, cache))]
+            pool.at[:, rows[kind]].set(jnp.asarray(x, pool.dtype))
+            for kind, pool, x in zip(self._pool_kind, self._pools, cache)]
         table = np.zeros((self.pages_per_seq,), np.int32)
         table[:len(pages)] = pages
         emitted = [int(t) for t in state["emitted"]]
         with self._slots_lock:
             self.slots[idx] = _Slot(
-                r, pages, table, pos=int(state["pos"]),
+                r, held, table, pos=int(state["pos"]),
                 cur=int(state["cur"]), prev=int(state["prev"]),
-                emitted=emitted,
-                first_token_at=time.monotonic(), ring=ring)
+                emitted=emitted, first_token_at=time.monotonic())
         self.metrics.incr("handoff_import_total")
         eos = self.config.eos_id
         if (eos is not None and emitted and emitted[-1] == eos) \
@@ -1646,17 +1682,18 @@ class DecodeEngine:
         page exhaustion."""
         try:
             with self._slots_lock:
-                pages, ring = self._alloc(
+                held = self._alloc(
                     self._pages_needed(r.prompt.size, r.max_new))
         except PagesExhaustedError:
             self.metrics.incr("page_wait_total")
             with self._qlock:
                 self._queue.insert(0, r)
             return False
+        pages = held[PageAllocator.SEQUENCE]
         table = np.zeros((self.pages_per_seq,), np.int32)
         table[:len(pages)] = pages
         with self._slots_lock:
-            self._chunk_jobs[idx] = _ChunkJob(r, pages, table, ring=ring)
+            self._chunk_jobs[idx] = _ChunkJob(r, held, table)
         self._tick(queue_wait_s_total=time.monotonic() - r.enqueued_at)
         return True
 
@@ -1667,7 +1704,7 @@ class DecodeEngine:
             job = self._chunk_jobs.pop(idx, None)
             if job is None:
                 return False
-            self._free(job.pages, job.ring)
+            self._free(job.held)
         job.req.set_error(exc)
         with self._cv:
             self._cv.notify_all()
@@ -1713,12 +1750,12 @@ class DecodeEngine:
             lens = np.asarray([sl.size], np.int32)
             offs = np.asarray([job.off], np.int32)
             table = job.table.reshape(1, -1)
-            ring_table = self._ring_rows([job.ring])
+            kind_tables = self._kind_tables([job.held])
 
             def _chunk_dispatch():
                 self._maybe_inject_fault()
                 return self._run_chunk_program(tokens, lens, offs,
-                                               table, ring_table)
+                                               table, *kind_tables)
 
             dispatch = record_event("pt:engine/chunk_dispatch",
                                     req=r.seq, offset=job.off)
@@ -1742,7 +1779,9 @@ class DecodeEngine:
             self._tick(chunk_prefill_total=1,
                        chunk_dispatch_s_total=dispatch.seconds,
                        prefill_tokens_total=int(sl.size),
-                       prefill_padded_tokens_total=cs)
+                       prefill_padded_tokens_total=cs,
+                       state_resets_total=int(self.STATE in job.held
+                                              and job.off == 0))
             job.off += int(sl.size)
             progressed = True
             if job.off >= r.prompt.size:
@@ -1752,8 +1791,8 @@ class DecodeEngine:
                 with self._slots_lock:
                     live = self._chunk_jobs.pop(idx, None) is job
                 if live:
-                    self._install_first_token(r, job.pages, job.table,
-                                              int(nxt[0]), idx, job.ring)
+                    self._install_first_token(r, job.held, job.table,
+                                              int(nxt[0]), idx)
         return progressed
 
     def _active(self):
@@ -1789,8 +1828,8 @@ class DecodeEngine:
             prev[i] = slot.prev
             pos[i] = slot.pos
             table[i] = slot.table
-        ring_table = self._ring_rows(
-            [None if s is None else s.ring for s in self.slots])
+        kind_tables = self._kind_tables(
+            [None if s is None else s.held for s in self.slots])
         deadlines = [s.req.deadline for _, s in active
                      if s.req.deadline is not None]
         batch_deadline = min(deadlines) if deadlines else None
@@ -1810,7 +1849,7 @@ class DecodeEngine:
             self._maybe_inject_fault()
             if not use_spec:
                 return self._run_decode_program(toks, pos, table,
-                                                ring_table)
+                                                *kind_tables)
             return self._run_spec_program(toks, prev, pos, table)
 
         dispatch = record_event("pt:engine/decode_dispatch",
@@ -1849,9 +1888,11 @@ class DecodeEngine:
                    decode_dispatch_s_total=dispatch.seconds,
                    cache_bytes_held_total=sum(
                        self._held_bytes(s) for _, s in active),
+                   state_bytes_held_total=sum(
+                       self._held_bytes(s, self.STATE) for _, s in active),
                    cache_positions_resident_total=sum(
                        s.pos for _, s in active))
-        if self.ring is not None:
+        if self.RING in self.kinds:
             for _, slot in active:
                 self._count_recycled(slot.pos, slot.pos + c.decode_block)
         draining = self._closed and not self._stop.is_set()
@@ -1887,18 +1928,19 @@ class DecodeEngine:
         self.metrics.incr("generated_tokens_total", n_new)
         return True
 
-    def _held_bytes(self, slot):
-        """Bytes of cache a slot's pages hold, every kind, pages whole."""
-        held = len(slot.pages) * self._page_bytes[PageAllocator.SEQUENCE]
-        if slot.ring is not None:
-            held += len(slot.ring) * self._page_bytes[self.RING]
-        return held
+    def _held_bytes(self, slot, only=None):
+        """Bytes of cache a slot's pages hold, pages whole: of every kind,
+        or of the kind ``only``."""
+        return sum(len(pages) * self._page_bytes[kind]
+                   for kind, pages in slot.held.items()
+                   if only is None or kind == only)
 
     def _count_recycled(self, pos0, pos1):
         """A request's positions ``pos0 .. pos1 - 1`` were written: count
         the ring pages that were entered anew and had held an earlier
         page's worth of positions (``window_pages_recycled_total``)."""
-        ps, n = self.config.page_size, self.ring["pages_per_seq"]
+        ps = self.config.page_size
+        n = self.kinds[self.RING]["pages_per_seq"]
         first, last = -(-pos0 // ps), (pos1 - 1) // ps  # entered anew
         turns = max(0, last - max(first, n) + 1)
         if turns:

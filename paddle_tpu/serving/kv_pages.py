@@ -26,7 +26,16 @@ RING of a few pages a request, position p in ring page ``(p //
 page_size) % ring pages``, so a request holds its window's worth of
 pages however long it grows, and a position that falls out of the window
 is overwritten where it lies (the engine counts each such turn of a ring
-page in ``window_pages_recycled_total``). The pools of two kinds have
+page in ``window_pages_recycled_total``). ``state`` (a model with
+state-space layers, models/hybrid_ssm.py): ONE entry a request for the
+request's life, whatever its length: the layer's recurrent state, which no
+position indexes; a "page" of this kind is an entry, and page 0 the null
+entry that rows which are not live read and write. UNLIKE A PAGE OF THE
+OTHER KINDS, A REUSED ENTRY'S OLD CONTENTS ARE OBSERVABLE: no length mask
+hides them, so it is the programs that start a request (a whole-prompt
+prefill, a prompt's first chunk) which begin from zeros without reading
+the entry, and the engine counts those dispatches
+(``state_resets_total``). The pools of two kinds have
 pages of different shapes (other layers, other head counts), so each
 kind has its own page ids, its own null page 0 and its own free list,
 under ONE allocator that keeps the integer invariants for each:

@@ -300,7 +300,7 @@ def test_a_model_with_one_cache_kind_keeps_its_pools_and_its_one_table(
     programs = cfg.build_paged_programs(
         max_batch=3, page_size=4, n_pages=31, pages_per_seq=10,
         prompt_buckets=(8, 16), decode_block=2, chunk_size=8)
-    assert programs.ring is None and len(programs.pool_specs) == n_pools
+    assert programs.kinds == {} and len(programs.pool_specs) == n_pools
     assert all(shape[:3] == [cfg.n_layers, 31, 4]
                for shape, _ in programs.pool_specs)
     for bundle, data in ((programs.decode, 3), (programs.chunk, 4),
@@ -439,7 +439,8 @@ def test_engine_logits_are_the_references_whole_chunked_and_decoded(scope):
 def test_a_window_layers_pages_are_a_ring_whatever_the_length(engine):
     a = engine.allocator
     assert engine.ring == {"window": 4, "pages_per_seq": 2,
-                           "n_pages": 3 * 2 + 1, "pools": (2, 3)}
+                           "n_pages": 3 * 2 + 1, "pools": (2, 3),
+                           "table": ("RingTable", "ring_table")}
     assert a.kinds == ("sequence", "window") and a.usable_of("window") == 6
     before = engine.stats()
     out = engine.generate(LONG, max_new=8)
@@ -522,7 +523,7 @@ def test_a_shed_or_failed_request_frees_both_kinds(scope):
 def test_the_handoff_blob_round_trips_both_kinds(engine, scope):
     want = engine.generate(LONG, max_new=8)
     blob = engine.submit(LONG, max_new=8, prefill_only=True).result(60)
-    assert len(blob["ring_pages"]) == 2 and len(blob["cache"]) == 4
+    assert len(blob["kinds"]["window"]) == 2 and len(blob["cache"]) == 4
     assert [x.shape[1] for x in blob["cache"]] == [
         len(blob["pages"])] * 2 + [2, 2]
     a = engine.allocator

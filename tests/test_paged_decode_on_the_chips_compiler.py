@@ -10,6 +10,7 @@ PR 34). The describing call is made inside a fixture and in this file
 alone: one process at a time may load the TPU's library.
 """
 import json
+import math
 import os
 import re
 
@@ -87,3 +88,63 @@ def test_the_decode_program_copies_no_pool(one_chip, mistral, monkeypatch):
     assert memory.alias_size_in_bytes >= 2 * pool_bytes
     # what the program holds beside its arguments: no third pool
     assert memory.temp_size_in_bytes < pool_bytes
+
+
+# -- the state cache kind's programs (models/hybrid_ssm.py) ---------------
+
+@pytest.fixture(scope="module")
+def jamba():
+    """The programs of benchmark/configs/ai21-jamba2-3b.json at its
+    ``builder.engine`` (128 slots of 5,120 + 2,048 positions in pages of
+    64, chunks of 2,048, 4 steps), the pages sized as DecodeEngine sizes
+    them."""
+    from benchmark.builders.serve_ssm import model_config
+    with open(os.path.join(HERE, os.pardir, "benchmark", "configs",
+                           "ai21-jamba2-3b.json")) as f:
+        config = json.load(f)
+    e = config["builder"]["engine"]
+    per_seq = -(-(e["prompt_buckets"][-1] + e["max_new_tokens"]
+                  + e["decode_block"]) // e["page_size"])
+    return model_config(config).build_paged_programs(
+        max_batch=e["max_batch"], page_size=e["page_size"],
+        n_pages=e["max_batch"] * per_seq + 1, pages_per_seq=per_seq,
+        prompt_buckets=tuple(e["prompt_buckets"]),
+        decode_block=e["decode_block"], chunk_size=e["chunk_size"])
+
+
+def _hlo_type(shape, dtype):
+    return {"float32": "f32", "bfloat16": "bf16"}[dtype] \
+        + "[" + ",".join(map(str, shape)) + "]"
+
+
+@pytest.mark.parametrize("label", ["decode", "chunk", "prefill_512"])
+def test_no_program_re_lays_or_copies_a_state_pool(one_chip, jamba, label):
+    """The state pools lie with whole lane tiles on their minor axis
+    ([.., 16, 5120] float32, [.., 15360] bf16), so the chip takes them as
+    they are stored: no ``copy`` of a pool of any kind in any program, all
+    four aliased from the donated inputs to the outputs; the decode
+    program has no view of the states (its steps run against the pool),
+    and a prefill holds nothing of [T, N, C] (671 MB a layer at 2,048
+    positions): the scan carries one state from position to position."""
+    (s_shape, _), (t_shape, _) = jamba.pool_specs[2:]
+    assert s_shape[2:] == [16, 5120] and t_shape[2:] == [3 * 5120]
+    compiled = program_text.lower_bundle(
+        program_text.bundles_of(jamba)[label], 4,
+        sharding=one_chip).compile()
+    text = compiled.as_text()
+    for shape, dtype in jamba.pool_specs:
+        pool = _hlo_type(shape, dtype)
+        assert pool in text
+        assert not re.findall(re.escape(pool) + r"\S* copy\(", text), pool
+    pools = sum(math.prod(s) * (4 if dt == "float32" else 2)
+                for s, dt in jamba.pool_specs)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= pools
+    if label == "decode":
+        # the attention layers' dense view (0.95 GB) and no state view
+        rows = _hlo_type([s_shape[0], jamba.max_batch] + s_shape[2:],
+                         "float32")
+        assert rows not in text
+        assert memory.temp_size_in_bytes < 1.3e9
+    else:
+        assert memory.temp_size_in_bytes < 0.5e9 < 2048 * 16 * 5120 * 4
