@@ -330,6 +330,11 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, scale, causal,
 # softmax is folded in, are the same whatever rows it shares a dispatch
 # with
 PAGED_BLOCK_KEYS = 128
+# and a block of flat entries (8 pages of 64), by the chip: one block is
+# in flight while one is folded, and MiMo's 24 rows of 17,408 positions
+# read 495 GB/s at 128 positions a block (0.33 MB), 692 at 256, 735 at 512
+# and no more at 1,024 or 2,048 (PERF.md section 6, PR 42)
+PAGED_FLAT_BLOCK_KEYS = 512
 
 
 def paged_gqa_usable(k_shape, v_shape):
@@ -341,19 +346,17 @@ def paged_gqa_usable(k_shape, v_shape):
             and tuple(k_shape) == tuple(v_shape) and k_shape[-1] % 128 == 0)
 
 
-def _paged_gqa_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, v_hbm,
-                      o_ref, k_buf, v_buf, sems, *, scale, rep):
-    """Every row of the batch, one after the other: a row's pages are
-    copied to VMEM a block of ``ppb`` at a time, the next block (the next
-    row's first, at a row's end) in flight while this one is folded under
-    a running softmax. A page of a layer lies ``[page_size, g, hd]``, heads
-    inside positions, so a block is read FLAT, ``[positions x g, hd]``:
-    every query head meets every kv head's keys in one product and the
-    columns of the other groups are masked with the positions past the
-    row's length. The g-fold product is the price of leaving the pools as
-    they are stored; it is the MXU's, which a decode step leaves idle."""
-    n_rows, n_heads, hd = q_ref.shape
-    _, ppb, ps, g, _ = k_buf.shape
+def _paged_rows_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, v_hbm,
+                       o_ref, k_buf, v_buf, sems, *, fold):
+    """The schedule of the paged decode kernels. Every row of the batch,
+    one after the other: a row's pages are copied to VMEM a block of
+    ``ppb`` at a time, only those that hold a position the row attends,
+    the next block (the next row's first, at a row's end) in flight while
+    ``fold(q, k_buf, v_buf, slot, length, blk, (m, l, acc)) -> (m, l,
+    acc)`` takes this one into the row's running softmax (float32
+    maximum, denominator and accumulator)."""
+    n_rows, n_heads, _ = q_ref.shape
+    ppb, ps = k_buf.shape[1:3]
     pps = table_ref.shape[1]
     bk = ppb * ps
     lyr = layer_ref[0]
@@ -381,10 +384,10 @@ def _paged_gqa_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, v_hbm,
     def row_body(row, slot):
         length = len_ref[row]
         n_blocks = lax.div(length + bk - 1, bk)
-        q = q_ref[row]                                      # [heads, hd]
+        q = q_ref[row]
 
         def block_body(blk, carry):
-            m, l, acc, slot = carry
+            *folded, slot = carry
             last = blk + 1 == n_blocks
             nxt_row = jnp.where(last, row + 1, row)
             nxt_blk = jnp.where(last, 0, blk + 1)
@@ -395,34 +398,80 @@ def _paged_gqa_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, v_hbm,
                              1 - slot, lambda c: c.start())
 
             block_copies(row, blk, slot, lambda c: c.wait())
-            k = k_buf[slot].reshape(bk * g, hd)
-            v = v_buf[slot].reshape(bk * g, hd)
-            s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-            # column c of the block: position c // g, kv head c % g
-            cols = lax.broadcasted_iota(jnp.int32, (1, bk * g), 1)
-            heads = lax.broadcasted_iota(jnp.int32, (n_heads, 1), 0)
-            seen = (lax.rem(cols, g) == lax.div(heads, rep)) \
-                & (lax.div(cols, g) < length - blk * bk)
-            s = jnp.where(seen, s, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-            e = jnp.exp(s - m_new)
-            corr = jnp.exp(m - m_new)
-            l = corr * l + jnp.sum(e, axis=1, keepdims=True)
-            acc = corr * acc + lax.dot_general(
-                e.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            return m_new, l, acc, 1 - slot
+            return (*fold(q, k_buf, v_buf, slot, length, blk, folded),
+                    1 - slot)
 
         m, l, acc, slot = lax.fori_loop(
             0, n_blocks, block_body,
             (jnp.full((n_heads, 1), NEG_INF, jnp.float32),
              jnp.zeros((n_heads, 1), jnp.float32),
-             jnp.zeros((n_heads, hd), jnp.float32), slot))
+             jnp.zeros((n_heads, o_ref.shape[-1]), jnp.float32), slot))
         o_ref[row] = (acc / l).astype(o_ref.dtype)
         return slot
 
     lax.fori_loop(0, n_rows, row_body, 0)
+
+
+def _fold_heads(q, k_buf, v_buf, slot, length, blk, carry, *, scale, rep):
+    """A block of pages that lie ``[page_size, g, hd]``, heads inside
+    positions, read FLAT, ``[positions x g, hd]``: every query head meets
+    every kv head's keys in one product and the columns of the other
+    groups are masked with the positions past the row's length. The g-fold
+    product is the price of leaving the pools as they are stored; it is
+    the MXU's, which a decode step leaves idle."""
+    m, l, acc = carry
+    n_heads, hd = q.shape
+    _, ppb, ps, g, _ = k_buf.shape
+    bk = ppb * ps
+    k = k_buf[slot].reshape(bk * g, hd)
+    v = v_buf[slot].reshape(bk * g, hd)
+    s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * scale
+    # column c of the block: position c // g, kv head c % g
+    cols = lax.broadcasted_iota(jnp.int32, (1, bk * g), 1)
+    heads = lax.broadcasted_iota(jnp.int32, (n_heads, 1), 0)
+    seen = (lax.rem(cols, g) == lax.div(heads, rep)) \
+        & (lax.div(cols, g) < length - blk * bk)
+    s = jnp.where(seen, s, NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    e = jnp.exp(s - m_new)
+    corr = jnp.exp(m - m_new)
+    l = corr * l + jnp.sum(e, axis=1, keepdims=True)
+    acc = corr * acc + lax.dot_general(
+        e.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    return m_new, l, acc
+
+
+def _paged_decode_call(name, fold, q, k_pool, v_pool, layer, table,
+                       lengths, out_width, block_keys):
+    """One program over the batch's rows (``_paged_rows_kernel`` with
+    ``fold``): layer, table and lengths are scalar-prefetch operands, the
+    pools stay in HBM in the layout they are stored in, a block of
+    ``block_keys`` positions of each in VMEM twice. ``lengths`` is
+    held to 1 and to the table's positions."""
+    n_rows, n_heads, _ = q.shape
+    ps = k_pool.shape[2]
+    lengths = jnp.clip(lengths, 1, table.shape[1] * ps)
+    ppb = max(1, block_keys // ps)
+    out = (n_rows, n_heads, out_width)
+    return _pcall(
+        functools.partial(_paged_rows_kernel, fold=fold),
+        name=name,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(1,),
+            in_specs=[pl.BlockSpec(q.shape, lambda i, *_: (0, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(out, lambda i, *_: (0, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, ppb) + pool.shape[2:], pool.dtype)
+                for pool in (k_pool, v_pool)]
+            + [pltpu.SemaphoreType.DMA((2, 2))]),
+        out_shape=jax.ShapeDtypeStruct(out, q.dtype),
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), table.astype(jnp.int32),
+      lengths.astype(jnp.int32), q, k_pool, v_pool)
 
 
 def paged_gqa_decode(q, k_pool, v_pool, layer, table, lengths):
@@ -434,29 +483,75 @@ def paged_gqa_decode(q, k_pool, v_pool, layer, table, lengths):
     pages ``table[b]`` and nothing else, so its result depends on those
     pages and that length alone (float32 scores and accumulator, products
     in the cache's type). Returns [B, heads, hd] in q's type."""
-    n_rows, n_heads, hd = q.shape
-    _, _, ps, g, _ = k_pool.shape
-    lengths = jnp.clip(lengths, 1, table.shape[1] * ps)
-    kernel = functools.partial(_paged_gqa_kernel, scale=hd ** -0.5,
-                               rep=n_heads // g)
-    buf = pltpu.VMEM((2, max(1, PAGED_BLOCK_KEYS // ps), ps, g, hd),
-                     k_pool.dtype)
-    return _pcall(
-        kernel,
-        name="paged_gqa_decode",
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(1,),
-            in_specs=[pl.BlockSpec((n_rows, n_heads, hd),
-                                   lambda i, *_: (0, 0, 0)),
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((n_rows, n_heads, hd),
-                                   lambda i, *_: (0, 0, 0)),
-            scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2))]),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32), table.astype(jnp.int32),
-      lengths.astype(jnp.int32), q, k_pool, v_pool)
+    _, n_heads, hd = q.shape
+    fold = functools.partial(_fold_heads, scale=hd ** -0.5,
+                             rep=n_heads // k_pool.shape[3])
+    return _paged_decode_call("paged_gqa_decode", fold, q, k_pool, v_pool,
+                              layer, table, lengths, hd, PAGED_BLOCK_KEYS)
+
+
+def paged_flat_usable(k_shape, v_shape, n_kv):
+    """The gate of ``paged_flat_decode``: the backend runs Pallas kernels,
+    and the pools are ``[L, pages, page_size, n_kv * dk]`` keys beside
+    ``[.., n_kv * dv]`` values, an entry FLAT in its page: the keys' width
+    whole lane tiles (a head's own need not be: 192), a value head's too."""
+    return (_use_pallas() and len(k_shape) == len(v_shape) == 4
+            and tuple(k_shape[:3]) == tuple(v_shape[:3])
+            and k_shape[3] % 128 == 0 and k_shape[3] % n_kv == 0
+            and v_shape[3] % (128 * n_kv) == 0)
+
+
+def _fold_flat(q, k_buf, v_buf, slot, length, blk, carry, *, scale, g):
+    """A block of pages whose entries lie FLAT, ``[page_size, g * dk]``
+    keys and ``[page_size, g * dv]`` values, against a ZERO-EXPANDED
+    query, [heads, g * dk]: a head's ``dk`` values in its own group's
+    columns and zeros in the others', so ONE product with the block as it
+    lies gives [heads, positions] scores, whatever ``dk`` is in lane tiles
+    (192 is one and a half: a head's slice of a key would be re-laid),
+    with no column of another group to mask. g-fold products of zeros, the
+    MXU's. A group's heads then meet their own ``dv`` columns of the
+    values, a whole-tile slice."""
+    m, l, acc = carry
+    rep = q.shape[0] // g
+    _, ppb, ps, kw = k_buf.shape
+    bk, dv = ppb * ps, v_buf.shape[3] // g
+    s = lax.dot_general(q, k_buf[slot].reshape(bk, kw),
+                        (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * scale
+    cols = lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+    s = jnp.where(cols < length - blk * bk, s, NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    e = jnp.exp(s - m_new)
+    corr = jnp.exp(m - m_new)
+    l = corr * l + jnp.sum(e, axis=1, keepdims=True)
+    acc = corr * acc + jnp.concatenate([
+        lax.dot_general(
+            e[i * rep:(i + 1) * rep].astype(v_buf.dtype),
+            v_buf[slot, :, :, i * dv:(i + 1) * dv].reshape(bk, dv),
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        for i in range(g)])
+    return m_new, l, acc
+
+
+def paged_flat_decode(q, k_pool, v_pool, layer, table, lengths):
+    """``paged_gqa_decode`` for pools whose entries lie flat in their
+    pages, keys and values of unequal widths: q [B, heads, dk]; k_pool [L,
+    pages, page_size, g * dk]; v_pool [.., g * dv] (a model that mixes
+    kinds of layer stores its sequence kind so: _PagedRunner). The same
+    schedule and the same promise: a row's result depends on its pages
+    and its length alone. Scores are scaled by ``dk ** -0.5``. Returns [B,
+    heads, dv] in q's type."""
+    n_rows, n_heads, dk = q.shape
+    g = k_pool.shape[3] // dk
+    own = (jnp.arange(n_heads)[:, None] // (n_heads // g)
+           == jnp.arange(g)[None])                          # [heads, g]
+    expanded = jnp.where(own[None, :, :, None], q[:, :, None], 0).reshape(
+        n_rows, n_heads, g * dk)
+    return _paged_decode_call(
+        "paged_flat_decode", functools.partial(
+            _fold_flat, scale=dk ** -0.5, g=g),
+        expanded, k_pool, v_pool, layer, table, lengths,
+        v_pool.shape[3] // g, PAGED_FLAT_BLOCK_KEYS)
 
 
 # ---------------------------------------------------------------------------

@@ -20,7 +20,8 @@ import jax.numpy as jnp
 
 from ..core.registry import register_op
 from . import ssm
-from .pallas_attention import (flash_attention, paged_gqa_decode,
+from .pallas_attention import (flash_attention, paged_flat_decode,
+                               paged_flat_usable, paged_gqa_decode,
                                paged_gqa_usable)
 
 
@@ -1318,18 +1319,25 @@ class _PagedRunner:
     - ``forward_in_place(h, *pools, table, pos)`` — a decode step
       against the pools themselves: each layer writes the step's entry
       into its page and a Pallas kernel attends the row's pages where
-      they lie, to the row's own length (pallas_attention.py
-      ``paged_gqa_decode``). No view, no gather, no write-back: the view
-      cost Mistral's decode program 26.6 of its 71.68 ms (PERF.md
-      section 6, PR 37). WHICH FORM A DECODE OP TAKES is read off what
-      it is given (``decode_in_place``): this one where the model has one
-      kind of plain GQA layer, its K and V pools are of one shape with
-      heads of whole lane tiles, and the backend runs the kernel (the
-      chip; the tests' interpreter hook); the dense form everywhere
-      else: latent attention, a model that mixes attention kinds,
-      narrow heads, every backend that is not the chip. The three cache
-      forms want three kernels; the dense form goes when its last caller
-      has one (ROADMAP.md, Speed 1).
+      they lie, to the row's own length (``_in_place_step``;
+      pallas_attention.py ``paged_gqa_decode``). No view, no gather, no
+      write-back: the view cost Mistral's decode program 26.6 of its
+      71.68 ms (PERF.md section 6, PR 37). WHICH FORM A DECODE OP TAKES
+      is read off what it is given (``decode_in_place``): this one where
+      the model has one kind of plain GQA layer, its K and V pools are of
+      one shape with heads of whole lane tiles, and the backend runs the
+      kernel (the chip; the tests' interpreter hook). A model that MIXES
+      KINDS OF LAYER is asked KIND BY KIND and runs ``forward_dense``
+      with a form a kind: its kind that keeps the whole sequence, where it
+      has no sink and its entries lie flat at whole lane tiles, the same
+      step against its own pools (``paged_flat_decode``; the pools ride
+      the step scan as the state kind's do, nothing gathered or written
+      back: half of MiMo-V2-Flash's decode program, PERF.md section 6,
+      PR 42), beside a window kind's view of its rings and a state kind's
+      entries. The dense form everywhere else: latent attention, a kind
+      with a sink or narrow entries, the speculative step, every backend
+      that is not the chip. Latent attention wants a third kernel; the
+      dense form goes when its last caller has one (ROADMAP.md, Speed 1).
 
     Page 0 is the null page: the writes of inactive slots and of
     unallocated tails land there, in no defined order (in place, two
@@ -1380,9 +1388,10 @@ class _PagedRunner:
     pages a block of keys at a time under a running softmax, so neither
     ever holds [heads, window, kmax] scores. The dense form gathers the
     window kinds' rings stacked, [layers, B, ring, ...], and of the layers
-    that keep the whole sequence a view EACH, [B, g, kmax, d] with heads
-    before positions (``gather_layers``): a step reads its layer's view
-    where it lies, and those layers are taken by number, not by a scan.
+    that keep the whole sequence, where they are not attended in place, a
+    view EACH, [B, g, kmax, d] with heads before positions
+    (``gather_layers``): a step reads its layer's view where it lies, and
+    those layers are taken by number, not by a scan.
 
     ``state`` (a layer whose mixer is the selective state-space one,
     ops/ssm.py): ONE entry a row for the row's life, reached through
@@ -1419,6 +1428,10 @@ class _PagedRunner:
             moe_top_k=moe_top_k)
         self.lead = lead
         self.stacks = stacks    # attention kind's prefix -> its layers
+        self.table = None       # [B, pages]: the sequence kinds' table,
+        self.in_place = frozenset()  # and the kinds (of a model that
+                                # mixes them) whose decode steps attend
+                                # their pages in place (_paged_decode)
         self.ring_table = None  # [B, ring pages]: the window kinds' table
         self.state_table = None  # [B, 1]: the state kinds' entry a row
         self.fresh = False      # a prefill window from position 0: the
@@ -1951,11 +1964,12 @@ class _PagedRunner:
         kind of layer: one stacked view [L, B, kmax, ...]; a window
         kind: the rows' rings, stacked; the sequence kind of a model
         that has both: a view a layer, heads before positions."""
-        for spec in self.kinds.attn_kinds or ():
+        for k, spec in enumerate(self.kinds.attn_kinds or ()):
             if i in spec["pools"]:
                 scope = jax.named_scope("cache/" + spec["name"])
-                if _is_ssm(spec):
-                    # no view: the steps run against the pool (_state_step)
+                if _is_ssm(spec) or k in self.in_place:
+                    # no view: the steps run against the pool
+                    # (_state_step, _in_place_step)
                     return (None, (lambda pool, _: pool,
                                    lambda pool, seen, *_: seen), scope)
                 if spec["window"] is not None:
@@ -2070,6 +2084,11 @@ class _PagedRunner:
             mine = [dense[i] for i in spec["pools"]]
             if _is_ssm(spec):
                 out, mine = self._state_step(p, q, mine, lyr, spec)
+            elif kind in self.in_place:
+                with jax.named_scope("attn/" + spec["name"]):
+                    out, mine = self._in_place_step(
+                        paged_flat_decode, self.table, pos0,
+                        mine[0].shape[1])(q, entries, mine, lyr)
             elif spec["window"] is None:
                 with jax.named_scope("attn/" + spec["name"]):
                     # a view a layer [B, g, kmax, d] (gather_layers);
@@ -2119,30 +2138,45 @@ class _PagedRunner:
                                        attend_write)
         return (h,) + tuple(dense)
 
-    # -- in-place form (a decode step of plain GQA pools on the chip) ----
-    def forward_in_place(self, h, *pools_table_pos):
-        """One decode step against the pools themselves: each layer
-        writes the step's K and V at ``[layer, table[row, pos // page_size],
-        pos % page_size]`` (the addressing ``forward`` and ``write_back``
-        use; a position at or beyond ``kmax`` is dropped, a null table
-        entry lands on page 0) and attends the row's pages where they lie,
-        to the row's own length, ``pos + 1`` and ``kmax`` at most
-        (pallas_attention.paged_gqa_decode)."""
-        *pools, table, pos = pools_table_pos
+    # -- in-place form (a decode step against the pools, on the chip) ----
+    def _in_place_step(self, kernel, table, pos, n_pages):
+        """``attend(q, entries, pools, layer) -> (out [B, 1, heads * dv],
+        the pools written)``: a decode step at positions ``pos`` [B] of
+        one layer against its K and V pools themselves. The step's entry
+        goes to ``[layer, table[row, pos // page_size], pos % page_size]``
+        (the addressing ``forward`` and ``write_back`` use; a position at
+        or beyond ``kmax`` is dropped, a null table entry lands on page 0)
+        and ``kernel`` (pallas_attention.py) attends the row's pages where
+        they lie, to the row's own length, ``pos + 1`` and ``kmax`` at
+        most."""
         ps = self.page_size
         kmax = table.shape[1] * ps
         at = jnp.minimum(pos, kmax - 1)
         pg = jnp.where(pos < kmax,
                        jnp.take_along_axis(table, (at // ps)[:, None],
                                            axis=1)[:, 0],
-                       pools[0].shape[1])
+                       n_pages)
         lens = jnp.minimum(pos + 1, kmax)
 
-        def attend_write(p, q, entries, pools, lyr, kind=None):
-            pools = tuple(pl.at[lyr, pg, pos % ps].set(e[:, 0], mode="drop")
-                          for pl, e in zip(pools, entries))
-            out = paged_gqa_decode(q[:, 0], *pools, lyr, table, lens)
+        def attend(q, entries, pools, lyr):
+            pools = tuple(pl.at[lyr, pg, pos % ps].set(
+                e[:, 0].reshape((-1,) + pl.shape[3:]), mode="drop")
+                for pl, e in zip(pools, entries))
+            out = kernel(q[:, 0], *pools, lyr, table, lens)
             return out.reshape(out.shape[0], 1, -1), pools
+
+        return attend
+
+    def forward_in_place(self, h, *pools_table_pos):
+        """One decode step of a model with one kind of plain GQA layer
+        against its pools themselves (_in_place_step with
+        pallas_attention.paged_gqa_decode)."""
+        *pools, table, pos = pools_table_pos
+        attend = self._in_place_step(paged_gqa_decode, table, pos,
+                                     pools[0].shape[1])
+
+        def attend_write(p, q, entries, pools, lyr, kind=None):
+            return attend(q, entries, pools, lyr)
 
         h, pools = self._stack_forward(h, tuple(pools), pos[:, None],
                                        attend_write)
@@ -2212,16 +2246,31 @@ def stats_names(kinds):
     return HYBRID_STATS
 
 
-def decode_in_place(attention, attn_kinds, pool_shapes):
+def decode_in_place(attention, attn_kinds, pool_shapes, kind=None):
     """Whether a decode op of a model with these block kinds, over pools
-    of these shapes, runs its steps against the pools themselves
-    (``_PagedRunner.forward_in_place``) and not against a dense view:
-    read off what the op is given, by ``_paged_decode`` where it lowers
-    and by whoever builds its program and wants to know which form that
-    is. One kind of plain GQA layer, K and V pools of one shape with
-    whole-tile heads, and a backend that runs the Pallas kernel."""
-    return (attention == "gqa" and attn_kinds is None
-            and len(pool_shapes) == 2 and paged_gqa_usable(*pool_shapes))
+    of these shapes, runs its steps against the pools themselves and not
+    against a dense view: read off what the op is given, by
+    ``_paged_decode`` where it lowers and by whoever builds its program
+    and wants to know which form that is. A model with one kind of plain
+    GQA layer (``_PagedRunner.forward_in_place``): K and V pools of one
+    shape with whole-tile heads, and a backend that runs the Pallas
+    kernel. A model that mixes kinds of layer is asked KIND BY KIND
+    (``kind`` None: whether any is): in place where the kind keeps the
+    whole sequence, has attention for its mixer and no sink, and its two
+    pools hold their entries flat at whole lane tiles (paged_flat_usable);
+    its other kinds keep their form."""
+    if attention != "gqa":
+        return False
+    if attn_kinds is None:
+        return len(pool_shapes) == 2 and paged_gqa_usable(*pool_shapes)
+    if kind is None:
+        return any(decode_in_place(attention, attn_kinds, pool_shapes, k)
+                   for k in range(len(attn_kinds)))
+    spec = attn_kinds[kind]
+    return (spec["window"] is None and not _is_ssm(spec)
+            and not spec["sink"] and len(spec["pools"]) == 2
+            and paged_flat_usable(*(pool_shapes[i] for i in spec["pools"]),
+                                  spec["n_kv"]))
 
 
 def _make_paged_runner(params, emb_w, fnorm, head, *, n_heads, n_kv,
@@ -2284,8 +2333,9 @@ def _paged_decode(run, tok, pos, table, pools, steps, extras=False):
     each step's float32 logits [B, steps, V], its routed picks [B, steps,
     routed layers, K] and the dispatch's Stats."""
     pos = pos.astype(jnp.int32)
-    in_place = decode_in_place(run.kinds.attention, run.kinds.attn_kinds,
-                               [pl.shape for pl in pools])
+    kinds, shapes = run.kinds, [pl.shape for pl in pools]
+    in_place = kinds.attn_kinds is None \
+        and decode_in_place(kinds.attention, None, shapes)
     if in_place:
         # in-place form: the steps carry the pools themselves, each layer
         # writes its entry into its page and attends the pages where they
@@ -2297,7 +2347,12 @@ def _paged_decode(run, tok, pos, table, pools, steps, extras=False):
     else:
         # dense form: pool -> dense gather once, ``steps`` steps that
         # carry the dense caches in place, their entries written back
-        # (_PagedRunner)
+        # (_PagedRunner); a model that mixes kinds of layer has a form a
+        # kind, and a kind in place has no view: its pools are carried
+        run.table = table
+        run.in_place = frozenset(
+            k for k in range(len(kinds.attn_kinds or ()))
+            if decode_in_place(kinds.attention, kinds.attn_kinds, shapes, k))
         views = [run.pool_view(i, table) for i in range(len(pools))]
         cache = []
         for pl, (tb, (gather, _), scope) in zip(pools, views):

@@ -4,11 +4,15 @@ programs a change must leave alone (test_paged_programs_pinned.py).
 A bundle of ``build_paged_programs`` is lowered as the engine dispatches
 it (core/executor.py ``Executor._jit``: the pools a fifth, donated
 argument) from the shapes its program declares, no weight made."""
+import base64
 import hashlib
 import re
 
 import jax
 import jax.numpy as jnp
+from jax._src.interpreters import mlir
+from jax._src.lib import tpu
+from jax._src.lib.mlir import ir
 
 from paddle_tpu.core.executor import make_stepped
 from paddle_tpu.core.lowering import lower_program
@@ -40,6 +44,32 @@ def lower_bundle(bundle, n_pools, sharding=None):
         {}, ro, feeds,
         jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=sharding),
         [abstract(n) for n in pools])
+
+
+def without_kernel_locations(text):
+    """StableHLO ``text`` with the serialized body of every Mosaic kernel
+    (a Pallas call lowered for the chip) replaced by the kernel printed
+    WITHOUT its debug locations. A body names the files, the functions
+    and the LINES it was traced through, the checkout's path among them:
+    two checkouts of one tree differ there, and so does a program whose
+    kernel a change left alone but moved down its file. What is left is
+    what the chip is asked to run."""
+    def printed(match):
+        context = mlir.make_ir_context()
+        tpu.register_dialect(context)
+        context.allow_unregistered_dialects = True
+        with context:
+            module = ir.Module.parse(base64.b64decode(match.group(1)))
+            return module.operation.get_asm(enable_debug_info=False)
+
+    return re.sub(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', printed, text)
+
+
+def chip_fingerprint(lowered):
+    """sha256 of the StableHLO text of a program lowered for a described
+    chip, its kernels free of their debug locations."""
+    return hashlib.sha256(without_kernel_locations(
+        lowered.as_text()).encode()).hexdigest()[:16]
 
 
 def fingerprint(lowered):

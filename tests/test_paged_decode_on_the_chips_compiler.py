@@ -1,14 +1,17 @@
-"""The in-place decode program as the chip's compiler leaves it.
+"""The in-place decode programs as the chip's compiler leaves them.
 
 Compiled here for a described TPU v5e, with no chip attached (the
-``on-chip-measurement`` guide, section 2), at the benchmark's Mistral
-shapes: what the CPU backend and the Pallas interpreter cannot show.
+``on-chip-measurement`` guide, section 2), at the benchmark's shapes
+(Mistral's plain GQA pools; the sequence kind of MiMo-V2-Flash's share
+and of Jamba2, entries flat in their pages, beside their rings and their
+states): what the CPU backend and the Pallas interpreter cannot show.
 The pools are donated and carried through two nested loops in which a
 scatter writes them and a custom call reads them; that is where XLA has
 twice decided to copy 0.82 GB a layer (PERF.md section 6, PR 25 and
 PR 34). The describing call is made inside a fixture and in this file
 alone: one process at a time may load the TPU's library.
 """
+import importlib
 import json
 import math
 import os
@@ -90,31 +93,137 @@ def test_the_decode_program_copies_no_pool(one_chip, mistral, monkeypatch):
     assert memory.temp_size_in_bytes < pool_bytes
 
 
-# -- the state cache kind's programs (models/hybrid_ssm.py) ---------------
+# program_text.chip_fingerprint of Mistral's three programs at GEOMETRY,
+# taken on PR 40's tree (the parent of PR 42, which gave the kernel's
+# schedule to a second kernel and a mixed model its own form a kind): the
+# batch and chat cells are the control of every change to the paged
+# programs. A PR that means to change one of these takes the new value
+# from this test's failure message.
+MISTRAL_PINNED = {"prefill_128": "6aa7644d2bc2aab5",
+                  "prefill_512": "2412bb7a5021351b",
+                  "decode": "78e8a6fabc7b841a"}
 
-@pytest.fixture(scope="module")
-def jamba():
-    """The programs of benchmark/configs/ai21-jamba2-3b.json at its
-    ``builder.engine`` (128 slots of 5,120 + 2,048 positions in pages of
-    64, chunks of 2,048, 4 steps), the pages sized as DecodeEngine sizes
-    them."""
-    from benchmark.builders.serve_ssm import model_config
+
+@pytest.mark.parametrize("label", sorted(MISTRAL_PINNED))
+def test_mistrals_programs_are_what_the_chip_was_asked_before(
+        one_chip, mistral, label, monkeypatch):
+    """The text each program lowers to for the chip, its kernel printed
+    without the lines it was traced through."""
+    monkeypatch.setattr(pa, "_use_pallas", lambda: True)
+    bundle = program_text.bundles_of(
+        mistral.build_paged_programs(**GEOMETRY))[label]
+    got = program_text.chip_fingerprint(
+        program_text.lower_bundle(bundle, 2, sharding=one_chip))
+    assert got == MISTRAL_PINNED[label], (label, got)
+
+
+# -- models that mix kinds of layer (models/hybrid_moe.py, hybrid_ssm.py) --
+
+# model -> (its file under benchmark/configs, its builder)
+MIXED = {"mimo": ("mimo-v2-flash-ep16.json", "serve_hybrid"),
+         "jamba": ("ai21-jamba2-3b.json", "serve_ssm")}
+
+
+def _programs_of(config_file, builder):
+    """The programs of a configuration under benchmark/configs at its own
+    ``builder.engine``, the pages sized as DecodeEngine sizes them: (the
+    file's ``model_config``, the programs)."""
     with open(os.path.join(HERE, os.pardir, "benchmark", "configs",
-                           "ai21-jamba2-3b.json")) as f:
+                           config_file)) as f:
         config = json.load(f)
     e = config["builder"]["engine"]
     per_seq = -(-(e["prompt_buckets"][-1] + e["max_new_tokens"]
                   + e["decode_block"]) // e["page_size"])
-    return model_config(config).build_paged_programs(
+    cfg = importlib.import_module(
+        "benchmark.builders." + builder).model_config(config)
+    return cfg, cfg.build_paged_programs(
         max_batch=e["max_batch"], page_size=e["page_size"],
         n_pages=e["max_batch"] * per_seq + 1, pages_per_seq=per_seq,
         prompt_buckets=tuple(e["prompt_buckets"]),
         decode_block=e["decode_block"], chunk_size=e["chunk_size"])
 
 
+@pytest.fixture(scope="module")
+def jamba():
+    """benchmark/configs/ai21-jamba2-3b.json: 128 slots of 5,120 + 2,048
+    positions in pages of 64, chunks of 2,048, 4 steps."""
+    return _programs_of(*MIXED["jamba"])[1]
+
+
+@pytest.fixture(params=sorted(MIXED))
+def mixed(request, monkeypatch):
+    """(a mixed model's configuration, its programs), with the gate as the
+    chip passes it while the test runs: the kernel is lowered, not
+    interpreted."""
+    monkeypatch.setattr(pa, "_use_pallas", lambda: True)
+    return _programs_of(*MIXED[request.param])
+
+
 def _hlo_type(shape, dtype):
     return {"float32": "f32", "bfloat16": "bf16"}[dtype] \
         + "[" + ",".join(map(str, shape)) + "]"
+
+
+def _assert_held_uncopied(text, pool_specs):
+    """Every pool is in the module as it is stored, and never copied."""
+    for shape, dtype in pool_specs:
+        pool = _hlo_type(shape, dtype)
+        assert pool in text
+        assert not re.findall(re.escape(pool) + r"\S* copy\(", text), pool
+
+
+def test_the_flat_kernel_compiles_at_the_mixed_models_shapes(
+        one_chip, mixed):
+    """Mosaic takes the sequence kind's pools as they are stored, keys 768
+    wide beside values 512 (four heads of 192 | 128) and one head of 128:
+    no pool is re-laid on its way into the call."""
+    cfg, programs = mixed
+
+    def abstract(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    (k_shape, _), (v_shape, _) = programs.pool_specs[:2]
+    rows = programs.max_batch
+    text = jax.jit(pa.paged_flat_decode).lower(
+        abstract((rows, cfg.n_heads, cfg.head_dim)), abstract(k_shape),
+        abstract(v_shape), abstract((), jnp.int32),
+        abstract((rows, programs.pages_per_seq), jnp.int32),
+        abstract((rows,), jnp.int32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    _assert_held_uncopied(text, programs.pool_specs[:2])
+
+
+def test_a_mixed_decode_program_holds_no_view_of_its_sequence_kind(
+        one_chip, mixed):
+    """A kernel instance a layer of the kind (those layers are taken by
+    number), every pool of every kind aliased from the donated inputs to
+    the outputs and none copied, and no buffer of the view's shape
+    ([rows, kv heads, kmax, width] a layer, or as gathered) anywhere in
+    the module: 2.15 GB of MiMo's 3.56 GB of temporaries, 0.95 of Jamba's
+    1.07 (PERF.md section 6, PR 42)."""
+    _, programs = mixed
+    assert programs.decode["in_place"]
+    compiled = program_text.lower_bundle(
+        programs.decode, len(programs.pool_specs),
+        sharding=one_chip).compile()
+    text = compiled.as_text()
+    (k_shape, _), (v_shape, _) = programs.pool_specs[:2]
+    assert len(re.findall(r"tpu_custom_call.*paged_flat_decode", text)) \
+        == k_shape[0] == 2
+    _assert_held_uncopied(text, programs.pool_specs)
+    rows = programs.max_batch
+    kmax = programs.pages_per_seq * programs.page_size
+    assert (rows, kmax) in ((24, 17472), (128, 7232))
+    views = re.findall(rf"\w+\[(?:\d+,)*{rows},(?:\d+,)?(?:{kmax}|"
+                       rf"{programs.pages_per_seq},{programs.page_size})"
+                       r"(?:,\d+)*\]", text)
+    assert not views, sorted(set(views))
+    memory = compiled.memory_analysis()
+    pools = sum(math.prod(s) * (4 if dt == "float32" else 2)
+                for s, dt in programs.pool_specs)
+    assert memory.alias_size_in_bytes >= pools
+    view = rows * kmax * (k_shape[3] + v_shape[3]) * 2 * k_shape[0]
+    assert memory.temp_size_in_bytes < view / 2
 
 
 @pytest.mark.parametrize("label", ["decode", "chunk", "prefill_512"])
@@ -132,16 +241,14 @@ def test_no_program_re_lays_or_copies_a_state_pool(one_chip, jamba, label):
         program_text.bundles_of(jamba)[label], 4,
         sharding=one_chip).compile()
     text = compiled.as_text()
-    for shape, dtype in jamba.pool_specs:
-        pool = _hlo_type(shape, dtype)
-        assert pool in text
-        assert not re.findall(re.escape(pool) + r"\S* copy\(", text), pool
+    _assert_held_uncopied(text, jamba.pool_specs)
     pools = sum(math.prod(s) * (4 if dt == "float32" else 2)
                 for s, dt in jamba.pool_specs)
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= pools
     if label == "decode":
-        # the attention layers' dense view (0.95 GB) and no state view
+        # the attention layers' dense view (0.95 GB: the gate is not
+        # passed here; in place: above) and no state view
         rows = _hlo_type([s_shape[0], jamba.max_batch] + s_shape[2:],
                          "float32")
         assert rows not in text
