@@ -304,7 +304,18 @@ class BlockKinds:
     entries, N, C]`` float32 and the convolution's tail ``[layers,
     entries, (k - 1) * C]``, reached through a third table, [rows, 1]
     (``StateTable``). ``rotary_dim`` 0: the ``gqa`` kinds rotate nothing
-    (the state layers carry the order)."""
+    (the state layers carry the order).
+
+    A stack may be RUN SEVERAL TIMES A TOKEN (a looped language model,
+    arXiv:2510.25741): ``passes`` > 1 runs the same stacked layers that
+    many times, every pass with keys and values of its own, so the cache
+    is ``passes`` times as deep as the weights (layer ``j`` of pass ``s``
+    at cache layer ``s * layers + j``: ``_PagedRunner._stack_forward``),
+    and the model's final norm closes every pass. Such a stack is one
+    kind of plain, dense layer. The ``plain`` residual has a second form,
+    read off the PARAMETERS: where a layer holds ``AttnPostNorm`` /
+    ``MlpPostNorm`` the sublayer's output is normed too, ``x +
+    norm(f(norm(x)))`` (a norm on each side: sandwich normalisation)."""
 
     def __init__(self, *, n_heads, n_kv=None, base=10000.0, eps=1e-6,
                  attention="gqa", ffn="swiglu", residual="plain",
@@ -314,7 +325,7 @@ class BlockKinds:
                  rope_inv_freq=None, softmax_scale=None, n_streams=1,
                  sinkhorn_iters=0, hc_eps=1e-6, hc_clamp=(-30.0, 30.0),
                  key_dim=None, rotary_dim=None, value_scale=1.0,
-                 attn_kinds=None, layer_kinds=None):
+                 attn_kinds=None, layer_kinds=None, passes=1):
         for kind, table in ((attention, _ATTENTION), (ffn, _FFN),
                             (residual, _RESIDUAL)):
             if kind not in table:
@@ -340,6 +351,15 @@ class BlockKinds:
             else tuple(dict(k) for k in attn_kinds)
         self.layer_kinds = None if layer_kinds is None \
             else tuple(int(k) for k in layer_kinds)
+        self.passes = int(passes)
+        if self.passes < 1 or (self.passes > 1 and (
+                ffn == "routed" or residual != "plain"
+                or layer_kinds is not None)):
+            raise ValueError(
+                f"{passes} passes over a stack of {ffn} / {residual} "
+                "layers" + (" of several kinds" if layer_kinds else "")
+                + ": a stack run more than once is one kind of plain, "
+                "dense layer")
 
     def of(self, k):
         """These kinds with attention kind ``k``'s own values in place."""
@@ -463,7 +483,11 @@ def _routed_ffn(kinds, p, u, valid):
 
 
 def _plain_residual(kinds, p, which, x, sublayer):
-    return x + sublayer(rms_normalize(x, p[which + "Norm"], kinds.eps))
+    y = sublayer(rms_normalize(x, p[which + "Norm"], kinds.eps))
+    post = p.get(which + "PostNorm")
+    if post is not None:        # a norm on each side of the sublayer
+        y = rms_normalize(y, post, kinds.eps)
+    return x + y
 
 
 def sinkhorn_knopp(m, iters, eps):
@@ -1258,6 +1282,13 @@ HYBRID_STATS = PAGED_STATS + ("attn_full_positions_total",
 # positions its prefill windows scanned (layers x positions)
 SSM_STATS = HYBRID_STATS + ("ssm_state_updates_total",
                             "ssm_prefill_positions_total")
+# and a model whose stack is run several times a token, over decode steps
+# alone: the layer passes its active rows went through (passes x layers a
+# row a step, counted by the loop that ran them) and the cache positions
+# they attended, summed over those layer passes (a layer's K and V entry
+# bytes x these is what the steps had to read of the cache)
+LOOP_STATS = PAGED_STATS + ("loop_layer_passes_total",
+                            "loop_positions_attended_total")
 
 # keys a prefill window expands at a time (latent attention): scores of
 # [heads, window, keys] float32, never of the whole cache. At most
@@ -1407,7 +1438,23 @@ class _PagedRunner:
     and writes the live rows' entries where they lie, in the entries'
     order (``_state_step``: the dense form has no view of this kind), and
     a row that is not live writes nothing at all. A run of such layers is
-    one scan."""
+    one scan.
+
+    A STACK RUN SEVERAL TIMES A TOKEN (``BlockKinds.passes`` > 1): the
+    number of layers is the WEIGHTS', not the pools': the pools are
+    ``passes`` times as deep, and ``_stack_forward`` wraps the layer scan
+    in a scan over the passes that carries ``(h, pools)`` as the layer
+    scan does, so the program holds ONE copy of the layer body and the
+    pools alias through both loops. Pass ``s`` hands layer ``j`` cache
+    layer ``s * layers + j`` in every form; the final norm and the exit
+    gate close EVERY pass (``_close_pass``: scopes ``loop/pass``,
+    ``loop/norm``, ``loop/gate``) and ``logits_of`` takes the last pass's
+    output as it is. Prefill writes all ``passes * layers`` cache layers.
+    Such a model's cache is too deep for a dense view on the chip (192
+    layers of 16 rows of 1,040 positions: 26 GB), so there it decodes in
+    place or not at all; the dense form serves it on the CPU at a tiny
+    size. Its programs count the layer passes that ran and the positions
+    they attended (LOOP_STATS)."""
 
     def __init__(self, params, emb_w, fnorm, head, *, n_heads, n_kv,
                  base, eps, page_size, head_scale=None, moe_top_k=2,
@@ -1444,6 +1491,10 @@ class _PagedRunner:
                                 # reads no page beyond them
         self._loads = []        # the last forward's routed loads [n, E]
         self.picks = None       # and its picks at pick_at, [n, B, K]
+        self.exit_gate = None   # (w [D], b [1]) of a looped model's gate
+        self.gates = None       # and its values, [passes, B, T] float32
+        self.layer_passes = None  # the layer passes its last forward ran,
+                                # counted where they ran
         if self.kinds.attention == "gqa":
             self.hd = self.kinds.key_dim \
                 or params["Wq"].shape[-1] // n_heads
@@ -1820,20 +1871,45 @@ class _PagedRunner:
 
         if lk is None:
             held, sliced = split(self.params)
+            # the layers are the weights', not the pools': a stack that is
+            # run ``passes`` times has ``passes`` cache layers a layer
+            n = next(iter(self.params.values())).shape[0]
 
-            def scanned(carry, xs):
-                p = dict(xs[0], **held)
-                if held:
-                    p["ExpertsOf"] = xs[1]
-                h, pools, routing = layer(*carry, p, xs[1] + n_lead)
-                return (h, pools), routing
+            def stack(h, pools, first):
+                """The stacked layers once, layer j on cache layer
+                ``first + j``."""
+                def scanned(carry, xs):
+                    p = dict(xs[0], **held)
+                    if held:
+                        p["ExpertsOf"] = xs[1]
+                    h, pools, routing = layer(*carry, p, xs[1] + first)
+                    return (h, pools), routing
 
-            n = pools[0].shape[0] - n_lead
-            (h, pools), routing = jax.lax.scan(
-                scanned, (h, pools),
-                (sliced, jnp.arange(n, dtype=jnp.int32)))
-            if routing is not None:
-                self._loads, self.picks = routing
+                return jax.lax.scan(
+                    scanned, (h, pools),
+                    (sliced, jnp.arange(n, dtype=jnp.int32)))
+
+            if self.kinds.passes == 1:
+                (h, pools), routing = stack(h, pools, n_lead)
+                if routing is not None:
+                    self._loads, self.picks = routing
+                return h, pools
+            if n_lead:
+                raise ValueError("leading layers before a stack that is "
+                                 f"run {self.kinds.passes} times")
+
+            def one_pass(carry, s):
+                """Pass ``s`` of the same weights over its own ``n`` cache
+                layers, then what closes a pass (_close_pass)."""
+                with jax.named_scope("loop/pass"):
+                    (h, pools), _ = stack(*carry, s * n)
+                h, gate = self._close_pass(h)
+                return (h, pools), (gate, jnp.int32(n))
+
+            (h, pools), (self.gates, layers) = jax.lax.scan(
+                one_pass, (h, pools),
+                jnp.arange(self.kinds.passes, dtype=jnp.int32))
+            self.layer_passes = jnp.sum(layers)
             return h, pools
 
         # runs of same-kind layers: (kind, first layer of the run in its
@@ -2182,10 +2258,41 @@ class _PagedRunner:
                                        attend_write)
         return (h,) + tuple(pools)
 
+    def _close_pass(self, h):
+        """(h, gate): what closes EVERY pass of a stack that is run
+        several times: the model's one final norm, whose output the next
+        pass (or the head: logits_of) takes, and the exit gate on it."""
+        with jax.named_scope("loop/norm"):
+            h = rms_normalize(h, self.fnorm, self.eps)
+        with jax.named_scope("loop/gate"):
+            return self._exit_gate(h)
+
+    def _exit_gate(self, h):
+        """(h, gate [B, T] float32): a looped model's exit gate ``w . h +
+        b`` on a pass's normed output. The published forward stops a token
+        at the first pass where the exit probabilities built from
+        ``sigmoid(gate)`` reach its threshold; at the published threshold
+        of 1 that is the last pass, so every pass runs, the head reads the
+        last, and NO LOGIT DEPENDS ON THE GATE. It is the model's and is
+        computed all the same (ISSUE 43): the barrier ties it to ``h``,
+        or the compiler would drop a value nothing reads. NOTHING served
+        reads ``self.gates`` today: an exit below threshold 1 would, and
+        is not built. A model without the parameters has no gate."""
+        if self.exit_gate is None:
+            return h, None
+        w, b = self.exit_gate
+        gate = jnp.einsum("btd,d->bt", h, w,
+                          preferred_element_type=jnp.float32) \
+            + b.astype(jnp.float32)
+        return jax.lax.optimization_barrier((h, gate))
+
     def logits_of(self, hl):
         if self.kinds.residual == "mhc":      # the streams leave summed
             hl = jnp.sum(hl.astype(jnp.float32), axis=-2).astype(hl.dtype)
-        hn = rms_normalize(hl, self.fnorm, self.eps)
+        # a stack run several times ends EVERY pass in the final norm
+        # (_stack_forward): what comes here is normed already
+        hn = hl if self.kinds.passes > 1 \
+            else rms_normalize(hl, self.fnorm, self.eps)
         if self.head_scale is None:
             return (hn @ self.head).astype(jnp.float32)
         return qmat(hn, {"W": self.head, "WScale": self.head_scale},
@@ -2232,13 +2339,20 @@ class _PagedRunner:
                 out[3] = jnp.sum(loads > 0)
         if decode and self.kinds.attention == "latent":
             out[4] = jnp.sum(jnp.where(self.valid[:, 0], positions + 1, 0))
+        if decode and self.kinds.passes > 1:
+            n = jnp.where(self.valid[:, 0], positions + 1, 0)
+            out[6] = self.layer_passes * jnp.sum(self.valid)
+            out[7] = self.layer_passes * jnp.sum(n)
         return jnp.stack([jnp.asarray(x, jnp.int32) for x in out])
 
 
 def stats_names(kinds):
     """The counters a program of a model of these kinds returns, in its
     order: PAGED_STATS, HYBRID_STATS where it mixes kinds of layer,
-    SSM_STATS where some of those are state-space mixers."""
+    SSM_STATS where some of those are state-space mixers, LOOP_STATS
+    where its stack is run several times a token."""
+    if kinds.passes > 1:
+        return LOOP_STATS
     if kinds.layer_kinds is None:
         return PAGED_STATS
     if any(_is_ssm(k) for k in kinds.attn_kinds):
@@ -2477,7 +2591,8 @@ _BLOCK_SLOTS = (
     "MoeWUp", "MoeWDown", "ShWGate", "ShWUp", "ShWDown", "HcAttnPhi",
     "HcAttnAlpha", "HcAttnBias", "HcMlpPhi", "HcMlpAlpha", "HcMlpBias",
     "Wq", "Wk", "Wv", "Sink", "WIn", "ConvW", "ConvB", "WX", "DtNorm",
-    "BNorm", "CNorm", "WDt", "DtBias", "ALog", "D", "WOut")
+    "BNorm", "CNorm", "WDt", "DtBias", "ALog", "D", "WOut",
+    "AttnPostNorm", "MlpPostNorm")
 
 
 def _block_runner(ins, attrs):
@@ -2485,9 +2600,11 @@ def _block_runner(ins, attrs):
     by slot, the leading dense layers' under ``Lead<Slot>``, and the
     kinds from the attributes; where these mix attention kinds
     (``attn_kinds``), each kind's stacked layers under ``<its
-    stack><Slot>`` and the window kinds' table under ``RingTable``."""
+    stack><Slot>`` and the window kinds' table under ``RingTable``; a
+    looped model's exit gate under ``ExitW`` / ``ExitB``."""
     kinds = BlockKinds(
-        n_heads=attrs["n_heads"], eps=attrs["epsilon"],
+        n_heads=attrs["n_heads"], n_kv=attrs.get("n_kv"),
+        base=attrs.get("rope_base", 10000.0), eps=attrs["epsilon"],
         attention=attrs["attention"], ffn=attrs["ffn"],
         residual=attrs["residual"], moe_top_k=attrs["moe_top_k"],
         scoring=attrs["scoring"], route_scale=attrs["route_scale"],
@@ -2502,7 +2619,8 @@ def _block_runner(ins, attrs):
         rotary_dim=attrs.get("rotary_dim"),
         value_scale=attrs.get("value_scale", 1.0),
         attn_kinds=attrs.get("attn_kinds"),
-        layer_kinds=attrs.get("layer_kinds"))
+        layer_kinds=attrs.get("layer_kinds"),
+        passes=attrs.get("passes", 1))
     params = {s: ins[s][0] for s in _BLOCK_SLOTS if s in ins}
     lead = {s: ins["Lead" + s][0] for s in _BLOCK_SLOTS
             if "Lead" + s in ins}
@@ -2519,6 +2637,8 @@ def _block_runner(ins, attrs):
         run.ring_table = ins["RingTable"][0]
     if "StateTable" in ins:
         run.state_table = ins["StateTable"][0]
+    if "ExitW" in ins:
+        run.exit_gate = (ins["ExitW"][0], ins["ExitB"][0])
     return run
 
 

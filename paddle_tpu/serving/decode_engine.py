@@ -156,7 +156,19 @@ _DECODE_COUNTERS = (
     # cache_bytes_held_total (which counts them too), the bytes of the
     # state entries the active slots held. 0 for a model without the kind.
     "ssm_state_updates_total", "ssm_prefill_positions_total",
-    "state_resets_total", "state_bytes_held_total")
+    "state_resets_total", "state_bytes_held_total",
+    # a model whose stack is run several times a token (PR 43) keeps a
+    # cache layer a pass a layer, and counts on the device, over decode
+    # steps, the layer passes its active rows went through and the
+    # positions they attended (LOOP_STATS). Such a cache is the first so
+    # large that the POOL and not the slots bounds the batch; the engine's
+    # own, for every model: the decode dispatches that ran while a request
+    # was queued, a slot stood free and the queue's head was refused its
+    # pages (page_wait_total counts the refusals, one an admission pass,
+    # not the time): beside decode_batches_total it says which of the two,
+    # slots or pages, set the batch.
+    "loop_layer_passes_total", "loop_positions_attended_total",
+    "decode_page_bound_total")
 
 # how long after a program's end the worker keeps polling before it reads
 # the tokens and counters whose host copies set out with the program: the
@@ -535,6 +547,9 @@ class DecodeEngine:
         # slot idx -> _ChunkJob: chunked prefills in flight (the slot
         # itself stays None until the final chunk installs it)
         self._chunk_jobs = {}
+        # whether the last admission pass left the queue's head waiting
+        # for pages with a slot free (_page_wait)
+        self._page_bound = False
         # guards slots + chunk jobs + allocator against the
         # close()/watchdog vs worker race (drain-timeout expiry,
         # worker death)
@@ -1382,6 +1397,7 @@ class DecodeEngine:
         frees pages and wakes admission); a terminal prefill failure
         fails only that dispatch's request."""
         admitted = False
+        self._page_bound = False
         while True:
             with self._slots_lock:
                 free = [i for i, sl in enumerate(self.slots)
@@ -1441,7 +1457,7 @@ class DecodeEngine:
                             self._pages_needed(r.prompt.size,
                                                r.max_new))
                 except PagesExhaustedError:
-                    self.metrics.incr("page_wait_total")
+                    self._page_wait()
                     starved.append(r)
                     continue
                 granted.append((r, pages))
@@ -1465,6 +1481,13 @@ class DecodeEngine:
                 admitted |= self._prefill_request(policy, bucket, r,
                                                   pages, idx)
         return admitted
+
+    def _page_wait(self):
+        """The head of the queue found a free slot and not its pages: it
+        goes back to the queue's front and waits for a retirement. Until
+        the next admission pass the batch is bound by pages."""
+        self.metrics.incr("page_wait_total")
+        self._page_bound = True
 
     def _prefill_request(self, policy, bucket, r, held, idx):
         """One whole-prompt request's own dispatch of its bucket's
@@ -1646,7 +1669,7 @@ class DecodeEngine:
                 held = self._alloc(
                     self._pages_needed(r.prompt.size, r.max_new), grant)
         except PagesExhaustedError:
-            self.metrics.incr("page_wait_total")
+            self._page_wait()
             with self._qlock:
                 self._queue.insert(0, r)
             return False
@@ -1685,7 +1708,7 @@ class DecodeEngine:
                 held = self._alloc(
                     self._pages_needed(r.prompt.size, r.max_new))
         except PagesExhaustedError:
-            self.metrics.incr("page_wait_total")
+            self._page_wait()
             with self._qlock:
                 self._queue.insert(0, r)
             return False
@@ -1882,6 +1905,7 @@ class DecodeEngine:
         # what the active slots held through this dispatch: pages of
         # every kind, whole, and the positions resident in them
         self._tick(decode_batches_total=1,
+                   decode_page_bound_total=int(self._page_bound),
                    decode_in_place_total=int(
                        not use_spec
                        and self.programs.decode.get("in_place", False)),

@@ -255,3 +255,76 @@ def test_no_program_re_lays_or_copies_a_state_pool(one_chip, jamba, label):
         assert memory.temp_size_in_bytes < 1.3e9
     else:
         assert memory.temp_size_in_bytes < 0.5e9 < 2048 * 16 * 5120 * 4
+
+
+# -- a model whose stack is run several times a token (models/looped.py) ---
+
+@pytest.fixture(scope="module")
+def looped():
+    """benchmark/configs/ouro-2.6b.json: 16 slots of 512 + 512 positions in
+    pages of 16 over a pool of 300 pages, 192 cache layers deep, 4 steps."""
+    with open(os.path.join(HERE, os.pardir, "benchmark", "configs",
+                           "ouro-2.6b.json")) as f:
+        config = json.load(f)
+    e = config["builder"]["engine"]
+    per_seq = -(-(e["prompt_buckets"][-1] + e["max_new_tokens"]
+                  + e["decode_block"]) // e["page_size"])
+    cfg = importlib.import_module(
+        "benchmark.builders.serve_loop").model_config(config)
+    return cfg, dict(
+        max_batch=e["max_batch"], page_size=e["page_size"],
+        n_pages=e["n_pages"], pages_per_seq=per_seq,
+        prompt_buckets=tuple(e["prompt_buckets"]),
+        decode_block=e["decode_block"])
+
+
+def test_the_kernel_compiles_at_a_key_value_head_a_query_head(one_chip):
+    """16 key/value heads of 128 with ONE query head each and a layer
+    number up to 191: Mosaic takes the pools as they are stored."""
+    def abstract(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = abstract((192, 300, 16, 16, 128))
+    text = jax.jit(pa.paged_gqa_decode).lower(
+        abstract((16, 16, 128)), pool, pool, abstract((), jnp.int32),
+        abstract((16, 65), jnp.int32),
+        abstract((16,), jnp.int32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert not re.findall(r"bf16\[192,300,16,16,128\]\S* copy\(", text)
+
+
+@pytest.mark.parametrize("label", ["decode", "prefill_512"])
+def test_a_looped_program_holds_one_layer_body_and_copies_no_pool(
+        one_chip, looped, label, monkeypatch):
+    """The passes are a loop around the layers' loop: ONE kernel instance
+    in the decode program for 192 calls a step, both pools (3.77 GB each)
+    aliased from the donated inputs to the outputs through both loops and
+    never copied, no buffer of the dense view's shape (26 GB: the form
+    that cannot exist for this model), and beside its arguments the
+    program holds less than a third of one pool: the compiler's own
+    re-laid copy of the q, k and v matrices (1.2 GB: PERF.md section 6,
+    PR 43), not a pass's copy of the layers."""
+    monkeypatch.setattr(pa, "_use_pallas", lambda: True)
+    cfg, geometry = looped
+    programs = cfg.build_paged_programs(**geometry)
+    assert programs.decode["in_place"]
+    assert programs.pool_specs == [
+        ([192, 300, 16, 16, 128], "bfloat16")] * 2
+    compiled = program_text.lower_bundle(
+        program_text.bundles_of(programs)[label], 2,
+        sharding=one_chip).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == (label == "decode")
+    _assert_held_uncopied(text, programs.pool_specs)
+    for view in ("192,16,1040,16,128", "16,1040,16,128",
+                 "192,16,65,16,16,128"):
+        assert f"bf16[{view}]" not in text, view
+    pool_bytes = 192 * 300 * 16 * 16 * 128 * 2
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * pool_bytes
+    assert memory.temp_size_in_bytes < pool_bytes / 3
+    # the layers' matrices are read where they lie, four times: no copy of
+    # a SwiGLU matrix (1.1 GB each) anywhere
+    assert not re.findall(r"bf16\[48,(?:2048,5632|5632,2048)\]\S* copy\(",
+                          text)

@@ -72,6 +72,9 @@ def kernel_on(monkeypatch):
 # flat, [PS, g * dk] beside [PS, g * dv]
 LAYOUTS = {
     "heads": (pa.paged_gqa_decode, NKV, HD, HD, NH),
+    # a key/value head a query head (a looped model's 16 of 16: PR 43):
+    # the same product under a mask that keeps one head in g, not rep in g
+    "heads_one_each": (pa.paged_gqa_decode, NH, HD, HD, NH),
     "flat_4x192_128": (pa.paged_flat_decode, 4, 192, 128, 8),
     "flat_1x128": (pa.paged_flat_decode, 1, 128, 128, 5),
 }
@@ -80,7 +83,7 @@ LAYOUTS = {
 def _pools(dtype, seed=0, layout="heads"):
     _, g, dk, dv, _ = LAYOUTS[layout]
     kk, kv = jax.random.split(jax.random.PRNGKey(seed))
-    if layout == "heads":
+    if layout.startswith("heads"):
         return tuple(jax.random.normal(k, (L, NP, PS, g, dk)).astype(dtype)
                      for k in (kk, kv))
     return (jax.random.normal(kk, (L, NP, PS, g * dk)).astype(dtype),
