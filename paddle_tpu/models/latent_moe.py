@@ -31,7 +31,8 @@ from dataclasses import dataclass
 from .. import layers
 from ..layers import transformer as tfl
 from ..ops.transformer_ops import (PAGED_STATS, decode_in_place,
-                                   whole_tiles, yarn_inv_freq, yarn_mscale)
+                                   prefill_in_kernel, whole_tiles,
+                                   yarn_inv_freq, yarn_mscale)
 from .llama import (PagedDecodePrograms, cache_pool_specs,
                     prefill_buckets_reached)
 
@@ -279,10 +280,22 @@ def build_block_programs(cfg, *, pool_specs, common, max_batch, page_size,
             (*spec["table"], [b, spec["pages_per_seq"]], "int32")
             for spec in (kinds or {}).values()]
 
+    attrs = common["attrs"]
+    shapes = [shape for shape, _ in pool_specs]
+
+    def attn_in_kernel(t_len, seen):
+        """Whether a prefill program over a ``t_len``-token window attends
+        through the kernel: asked as its op asks where it lowers."""
+        return prefill_in_kernel(
+            attrs["attention"], attrs.get("attn_kinds"),
+            (attrs["nope_dim"], attrs["v_dim"]), shapes, t_len,
+            pages_per_seq, seen)
+
     prefill = {
-        bucket: bundle("prefill", "pp", [
+        bucket: dict(bundle("prefill", "pp", [
             ("Tokens", "tokens", [1, bucket], "int64"),
-            ("Lens", "lens", [1], "int32"), *tables(1)])
+            ("Lens", "lens", [1], "int32"), *tables(1)]),
+            attn_in_kernel=attn_in_kernel(bucket, bucket))
         for bucket in prefill_buckets_reached(prompt_buckets,
                                               chunk_size)}
     decode = bundle("decode", "dc", [
@@ -290,8 +303,7 @@ def build_block_programs(cfg, *, pool_specs, common, max_batch, page_size,
         ("Positions", "positions", [max_batch], "int32"),
         *tables(max_batch)], steps=decode_block)
     decode["in_place"] = decode_in_place(
-        common["attrs"]["attention"], common["attrs"].get("attn_kinds"),
-        [shape for shape, _ in pool_specs])
+        attrs["attention"], attrs.get("attn_kinds"), shapes)
     chunk = None
     if chunk_size is not None:
         cs = int(chunk_size)
@@ -301,6 +313,7 @@ def build_block_programs(cfg, *, pool_specs, common, max_batch, page_size,
             ("Tokens", "tokens", [1, cs], "int64"),
             ("Lens", "lens", [1], "int32"),
             ("Offsets", "offsets", [1], "int32"), *tables(1)])
+        chunk["attn_in_kernel"] = attn_in_kernel(cs, None)
     return PagedDecodePrograms(
         cfg, None, page_size, pages_per_seq, n_pages, max_batch,
         prefill, decode, None, list(pool_specs), None,

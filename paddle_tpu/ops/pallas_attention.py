@@ -555,6 +555,174 @@ def paged_flat_decode(q, k_pool, v_pool, layer, table, lengths):
 
 
 # ---------------------------------------------------------------------------
+# prefill fold: a window of queries against one block of a row's keys, the
+# scores never leaving VMEM
+# ---------------------------------------------------------------------------
+
+# queries and keys a tile (one grid step's [queries, keys] float32 scores
+# live in VMEM and nowhere else), and the keys one call folds (what its
+# caller gathers, or expands, at a time), by the chip: a grid step costs
+# some 2 us beside its products, so 32 heads of 128 | 64 | 128 over 2,048
+# queries and 2,048 keys all seen take 3.14 ms in tiles of 256 x 256 (14%
+# of 197 TFLOP/s), 1.48 at 512 x 512 (29%), 0.906 at 512 x 1,024 and
+# 0.785 at 1,024 x 1,024 (56%; MiMo's 64 heads over 4: 1.53 ms, 57%); 1,024
+# x 2,048 reads 0.702 and needs a raised VMEM limit, and folds a diagonal
+# block at full price where these tiles skip a quarter of it. Per key the
+# call costs 0.402 us at 1,024 keys a visit, 0.385 at 2,048, 0.364 at
+# 4,096 (the carry's trip through HBM, 0.115 ms a visit) (PERF.md section
+# 6, PR 44)
+PREFILL_BLOCK_Q = 1024
+PREFILL_BLOCK_KEYS = 1024
+PREFILL_VISIT_KEYS = 2048
+
+
+def _tile(n, block):
+    """The tile ``n`` positions are cut in: all of them where they are at
+    most ``block``, else the largest of block, block / 2, ... (no smaller
+    than a lane tile, or ``block`` itself where that is smaller) that
+    divides them; None where none does."""
+    if n <= block:
+        return n
+    d = block
+    while d >= min(block, 128):
+        if n % d == 0:
+            return d
+        d //= 2
+    return None
+
+
+def prefill_fold_usable(t, kb, *widths):
+    """The gate of ``prefill_fold``: the backend runs Pallas kernels, every
+    width (keys, values, a shared key part) is whole lane tiles, and the
+    window's ``t`` queries and a call's ``kb`` keys cut into tiles."""
+    return bool(_use_pallas() and all(w % 128 == 0 for w in widths)
+                and _tile(t, PREFILL_BLOCK_Q)
+                and _tile(kb, PREFILL_BLOCK_KEYS))
+
+
+def _prefill_fold_kernel(pos0_ref, k0_ref, *refs, scale, bq, bk, shared):
+    """One (row, head, query tile) of ``prefill_fold`` against key tile
+    ``program_id(3)``: the running softmax lives in the OUTPUT blocks,
+    which stay in VMEM over the key tiles (taken from the carry at the
+    first, written back after the last). A tile no query of which sees a
+    key is skipped; one every query sees whole is not masked."""
+    if shared:
+        q_ref, k_ref, v_ref, q2_ref, k2_ref, acc_in, ml_in, acc_ref, \
+            ml_ref = refs
+    else:
+        q_ref, k_ref, v_ref, acc_in, ml_in, acc_ref, ml_ref = refs
+    ki = pl.program_id(3)
+    q_lo = pos0_ref[pl.program_id(0)] + pl.program_id(2) * bq
+    k_lo = k0_ref[0] + ki * bk
+
+    @pl.when(ki == 0)
+    def _():
+        acc_ref[...] = acc_in[...]
+        ml_ref[...] = ml_in[...]
+
+    def fold(masked):
+        s = lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+        if shared:
+            s = s + lax.dot_general(
+                q2_ref[0], k2_ref[0], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        s = s * scale
+        if masked:      # key k_lo + c at or before query q_lo + r
+            ahead = lax.broadcasted_iota(jnp.int32, (bq, bk), 1) \
+                - lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+            s = jnp.where(ahead <= q_lo - k_lo, s, NEG_INF)
+        ml = ml_ref[0, 0]
+        m, l = ml[:, :1], ml[:, 1:2]
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        w = jnp.exp(s - m_new)
+        a = jnp.exp(m - m_new)
+        acc_ref[0, 0] = acc_ref[0, 0] * a + lax.dot_general(
+            w.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        lane = lax.broadcasted_iota(jnp.int32, ml.shape, 1)
+        ml_ref[0, 0] = jnp.where(
+            lane == 0, m_new, l * a + jnp.sum(w, axis=1, keepdims=True))
+
+    whole = k_lo + bk - 1 <= q_lo       # the tile's last key, its first query
+    pl.when(whole)(lambda: fold(False))
+    pl.when(jnp.logical_and(jnp.logical_not(whole),
+                            k_lo <= q_lo + bq - 1))(lambda: fold(True))
+
+
+def prefill_fold(q, k, v, pos0, k0, acc, ml, *, scale, shared=None):
+    """One block of keys folded into the running softmax of a prefill
+    window's attention, the [queries, keys] scores, their exponents and
+    the weights in VMEM alone (``_PagedRunner._gqa_blocked`` and
+    ``_latent_expanded`` call it once a block of a row's pages; they are
+    its reference where it is not usable). q [B, T, heads * dk], a head's
+    width whole lane tiles; k [B, kb, g * dk] and v [B, kb, g * dv], ``g``
+    key/value heads each shared by heads / g query heads; ``shared``: (q2
+    [B, T, heads * d2], k2 [B, kb, d2]), a second part of every head's key
+    that all heads share (latent attention's rotated part), its product
+    added to the scores. Query i of row b stands at ``pos0[b] + i`` and
+    key j at ``k0 + j`` (both traced); a query sees the keys at or before
+    it. The carry: acc [B, heads, T, dv] float32 and ml [B, heads, T, 128]
+    float32 (lane 0 the running maximum, lane 1 the denominator), aliased
+    to the results. Operands in their own type, scores, maximum, sum and
+    accumulator float32, the weights in v's type for their product."""
+    b, t, _ = q.shape
+    kb = k.shape[1]
+    n_heads, dv = acc.shape[1], acc.shape[3]
+    dk = q.shape[2] // n_heads
+    # query heads a key head, and a value head (the same, but where a
+    # caller hands the keys of all its groups as one head's)
+    rep_k, rep_v = (n_heads * w // x.shape[2] for w, x in ((dk, k), (dv, v)))
+    bq, bk = _tile(t, PREFILL_BLOCK_Q), _tile(kb, PREFILL_BLOCK_KEYS)
+    nk = kb // bk
+
+    def last_seen(bi, qi, pos0_ref, k0_ref):
+        # the last key tile a query of this tile sees: the tiles behind it
+        # are not fetched (the same block index asks for no new copy)
+        return jnp.clip((pos0_ref[bi] + (qi + 1) * bq - 1 - k0_ref[0])
+                        // bk, 0, nk - 1)
+
+    def q_spec(width):
+        return pl.BlockSpec((1, bq, width),
+                            lambda bi, h, qi, ki, *_: (bi, qi, h))
+
+    def k_spec(width, col):
+        return pl.BlockSpec(
+            (1, bk, width), lambda bi, h, qi, ki, *s: (
+                bi, jnp.minimum(ki, last_seen(bi, qi, *s)), col(h)))
+
+    def carry_spec(width):
+        return pl.BlockSpec((1, 1, bq, width),
+                            lambda bi, h, qi, ki, *_: (bi, h, qi, 0))
+
+    operands = [q, k, v]
+    in_specs = [q_spec(dk), k_spec(dk, lambda h: h // rep_k),
+                k_spec(dv, lambda h: h // rep_v)]
+    if shared is not None:
+        d2 = shared[1].shape[2]
+        operands += list(shared)
+        in_specs += [q_spec(d2), k_spec(d2, lambda h: 0)]
+    n_in = 2 + len(operands)
+    return _pcall(
+        functools.partial(_prefill_fold_kernel, scale=scale, bq=bq, bk=bk,
+                          shared=shared is not None),
+        name="prefill_fold",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, n_heads, t // bq, nk),
+            in_specs=in_specs + [carry_spec(dv), carry_spec(128)],
+            out_specs=[carry_spec(dv), carry_spec(128)]),
+        out_shape=[jax.ShapeDtypeStruct(acc.shape, acc.dtype),
+                   jax.ShapeDtypeStruct(ml.shape, ml.dtype)],
+        input_output_aliases={n_in: 0, n_in + 1: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
+    )(pos0.astype(jnp.int32), jnp.reshape(k0, (1,)).astype(jnp.int32),
+      *operands, acc, ml)
+
+
+# ---------------------------------------------------------------------------
 # jax reference path (CPU tests, backward, and lse building block)
 # ---------------------------------------------------------------------------
 

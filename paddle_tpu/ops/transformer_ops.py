@@ -20,9 +20,10 @@ import jax.numpy as jnp
 
 from ..core.registry import register_op
 from . import ssm
+from . import pallas_attention as _pa
 from .pallas_attention import (flash_attention, paged_flat_decode,
                                paged_flat_usable, paged_gqa_decode,
-                               paged_gqa_usable)
+                               paged_gqa_usable, prefill_fold)
 
 
 def rms_normalize(x, scale=None, eps=1e-6):
@@ -1290,11 +1291,14 @@ SSM_STATS = HYBRID_STATS + ("ssm_state_updates_total",
 LOOP_STATS = PAGED_STATS + ("loop_layer_passes_total",
                             "loop_positions_attended_total")
 
-# keys a prefill window expands at a time (latent attention): scores of
-# [heads, window, keys] float32, never of the whole cache. At most
-# _KEY_BLOCK keys, and fewer where heads x window is so large that one
-# score pass would pass _SCORE_BYTES (128 heads over a 1,024-token
-# window: 1,024 keys; 32 heads over 2,048: all 2,048)
+# keys a prefill window folds at a time where its fold is plain jax.numpy
+# (every backend but the chip, a width the kernel does not admit): scores
+# of [heads, window, keys] float32 in HBM, never of the whole cache. At
+# most _KEY_BLOCK keys, and fewer where heads x window is so large that
+# one score pass would pass _SCORE_BYTES (128 heads over a 1,024-token
+# window: 1,024 keys; 32 heads over 2,048: all 2,048). Where the fold is
+# the kernel ``prefill_fold`` the scores never reach HBM and a block is
+# pallas_attention.PREFILL_VISIT_KEYS positions, whatever the heads
 _KEY_BLOCK = 2048
 _SCORE_BYTES = 2 ** 29
 
@@ -1311,13 +1315,37 @@ def whole_tiles(width):
     return -(-int(width) // _LANE_TILE) * _LANE_TILE
 
 
+def _padded(x, width):
+    """``x`` [..., w] at ``width``: zeros behind it where that is wider."""
+    pad = width - x.shape[-1]
+    if not pad:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
 def _as_stored(entry, pool):
     """``entry`` [..., width] as ``pool`` [..., stored width] keeps it:
     zeros behind it where the pool is wider."""
-    pad = pool.shape[-1] - entry.shape[-1]
-    if not pad:
-        return entry
-    return jnp.pad(entry, [(0, 0)] * (entry.ndim - 1) + [(0, pad)])
+    return _padded(entry, pool.shape[-1])
+
+
+def _fold_carry(b, n_heads, t, vd):
+    """``prefill_fold``'s carry before the first block: (acc [B, heads,
+    T, vd], ml [B, heads, T, 128]: lane 0 the running maximum, lane 1 the
+    denominator), float32. The lanes are told apart by an iota: a
+    constant scattered into zeros is folded into a literal of the carry's
+    size (33-67 MB a program held on the chip and read back from the
+    compile cache at every set-up: PERF.md section 6, PR 44)."""
+    lane = jax.lax.broadcasted_iota(
+        jnp.int32, (b, n_heads, t, _LANE_TILE), 3)
+    return (jnp.zeros((b, n_heads, t, vd), jnp.float32),
+            jnp.where(lane == 0, jnp.float32(-1e30), jnp.float32(0)))
+
+
+def _folded(carry):
+    """(m, l, acc) of ``prefill_fold``'s carry after the last block."""
+    acc, ml = carry
+    return ml[..., 0], ml[..., 1], acc
 
 
 class _PagedRunner:
@@ -1391,7 +1419,18 @@ class _PagedRunner:
     values, a block of keys at a time; a decode step ABSORBS the
     expansion into its query and its output and reads the latents as
     they lie. The dense view holds bitwise the same values the pools
-    do, so both forms see identical caches. int8 ``<Slot>Scale``
+    do, so both forms see identical caches. A PREFILL WINDOW'S FOLD over
+    a block of keys (``_latent_expanded``; ``_gqa_blocked`` of a mixed
+    model's layers that keep the whole sequence) has two forms as a
+    decode op has, read off what the op is given (``prefill_in_kernel``):
+    where the backend runs the Pallas kernels and the heads are whole
+    lane tiles (a 192-wide key is padded to them), a block of
+    PREFILL_VISIT_KEYS positions is one call of ``prefill_fold``, which
+    keeps the [queries, keys] scores in VMEM and carries the running
+    maximum, sum and accumulator through HBM (a sixteenth of one score
+    block); everywhere else it is plain jax.numpy with its float32 scores
+    in HBM, and that form is the kernel's reference (PERF.md section 6,
+    PR 44). int8 ``<Slot>Scale``
     companions ride along in ``params`` exactly as in the contiguous
     runner (qmat).
 
@@ -1566,21 +1605,26 @@ class _PagedRunner:
                          preferred_element_type=f32)
         return out.astype(q.dtype).reshape(b, t, -1)
 
-    def _gqa_blocked(self, q, read_block, n_blocks, kb, q_pos, sink=None):
+    def _gqa_blocked(self, q, read_block, n_blocks, kb, q_pos, sink=None,
+                     in_kernel=False):
         """GQA attention of a prefill window over a whole-sequence cache,
         a block of ``kb`` positions at a time (``read_block(i) -> (keys
         [B, kb, g, kd], values [B, kb, g, vd])``) under a running softmax:
         _latent_expanded's fold without the expansion. Only the blocks
-        that hold a position some query may see are visited."""
+        that hold a position some query may see are visited.
+        ``in_kernel``: a visit is one call of ``prefill_fold`` (queries
+        and keys zero-padded to whole lane tiles a head, the product's
+        extra columns zeros) and this fold its reference."""
         b, t = q_pos.shape
         f32 = jnp.float32
         scale = q.shape[-1] ** -0.5
+        k0, v0 = jax.eval_shape(read_block, 0)
+        g, r, vd = k0.shape[2], self.n_heads // k0.shape[2], v0.shape[-1]
 
         def fold(i, carry):
             m, l, acc = carry
             kblk, vblk = read_block(i)
-            g = kblk.shape[2]
-            qg = q.reshape(b, t, g, self.n_heads // g, q.shape[-1])
+            qg = q.reshape(b, t, g, r, q.shape[-1])
             s = jnp.einsum("bqgrd,bkgd->bgrqk", qg, kblk,
                            preferred_element_type=f32) * scale
             k_pos = i * kb + jnp.arange(kb, dtype=jnp.int32)
@@ -1594,13 +1638,26 @@ class _PagedRunner:
                 preferred_element_type=f32)
             return m2, l * a + jnp.sum(w, axis=-1), acc
 
-        k0, v0 = jax.eval_shape(read_block, 0)
-        g, r = k0.shape[2], self.n_heads // k0.shape[2]
-        init = (jnp.full((b, g, r, t), -1e30, f32),
-                jnp.zeros((b, g, r, t), f32),
-                jnp.zeros((b, g, r, t, v0.shape[-1]), f32))
+        if in_kernel:
+            wide = whole_tiles(q.shape[-1])
+            qf = _padded(q, wide).reshape(b, t, -1)
+
+            def visit(i, carry):
+                kblk, vblk = read_block(i)
+                return prefill_fold(
+                    qf, _padded(kblk, wide).reshape(b, kb, -1),
+                    vblk.reshape(b, kb, -1), q_pos[:, 0], i * kb, *carry,
+                    scale=scale)
+
+            step, init = visit, _fold_carry(b, self.n_heads, t, vd)
+        else:
+            step, init = fold, (jnp.full((b, g, r, t), -1e30, f32),
+                                jnp.zeros((b, g, r, t), f32),
+                                jnp.zeros((b, g, r, t, vd), f32))
         seen = jnp.minimum(jnp.max(q_pos) // kb + 1, n_blocks)
-        m, l, acc = jax.lax.fori_loop(0, seen, fold, init)
+        carry = jax.lax.fori_loop(0, seen, step, init)
+        m, l, acc = carry if not in_kernel else (
+            x.reshape((b, g, r) + x.shape[2:]) for x in _folded(carry))
         if sink is not None:
             sk = sink.astype(f32).reshape(1, g, r, 1)
             m2 = jnp.maximum(m, sk)
@@ -1736,14 +1793,19 @@ class _PagedRunner:
         return p["Wkvb"].reshape(k.kv_rank, k.n_heads,
                                  k.nope_dim + k.v_dim)
 
-    def _latent_expanded(self, p, q, read_block, n_blocks, kb, q_pos):
+    def _latent_expanded(self, p, q, read_block, n_blocks, kb, q_pos,
+                         in_kernel=False):
         """Latent attention of a prefill window, expanded: each block of
         ``kb`` cache positions (``read_block(i) -> [B, kb, entry]``) is
         expanded to per-head keys and values and folded into a running
         softmax, so no [heads, window, kmax] array is ever live. Only
         the blocks that hold a position some query may see are visited.
         Block 0 holds position 0, which every query sees, so the running
-        maximum is real before any wholly masked block meets it."""
+        maximum is real before any wholly masked block meets it.
+        ``in_kernel``: a visit expands its block as here and folds it in
+        one call of ``prefill_fold`` (a head's own key part in one
+        product, the rotated part all heads share, zero-padded to whole
+        lane tiles, in a second); this fold is its reference."""
         k = self.kinds
         q_nope, q_pe = q
         b, t = q_pos.shape
@@ -1773,11 +1835,35 @@ class _PagedRunner:
             return m2, l * a + jnp.sum(w, axis=-1), acc
 
         with jax.named_scope("mla/expand"):
-            init = (jnp.full((b, k.n_heads, t), -1e30, f32),
+            if in_kernel:
+                wide = whole_tiles(k.rope_dim)
+                qn = q_nope.reshape(b, t, -1)
+                qp = _padded(q_pe, wide).reshape(b, t, -1)
+                w_k, w_v = w_up[..., :k.nope_dim], w_up[..., k.nope_dim:]
+
+                def visit(i, carry):
+                    blk = read_block(i)
+                    lat = blk[..., :k.kv_rank]
+                    return prefill_fold(
+                        qn,
+                        jnp.einsum("bkr,rhd->bkhd", lat, w_k).reshape(
+                            b, kb, -1),
+                        jnp.einsum("bkr,rhd->bkhd", lat, w_v).reshape(
+                            b, kb, -1),
+                        q_pos[:, 0], i * kb, *carry, scale=k.softmax_scale,
+                        shared=(qp, _padded(
+                            blk[..., k.kv_rank:k.kv_rank + k.rope_dim],
+                            wide)))
+
+                step, init = visit, _fold_carry(b, k.n_heads, t, k.v_dim)
+            else:
+                step, init = fold, (
+                    jnp.full((b, k.n_heads, t), -1e30, f32),
                     jnp.zeros((b, k.n_heads, t), f32),
                     jnp.zeros((b, k.n_heads, t, k.v_dim), f32))
             seen = jnp.minimum(jnp.max(q_pos) // kb + 1, n_blocks)
-            _, l, acc = jax.lax.fori_loop(0, seen, fold, init)
+            carry = jax.lax.fori_loop(0, seen, step, init)
+            _, l, acc = _folded(carry) if in_kernel else carry
             out = jnp.moveaxis(acc / l[..., None], 1, 2)
         return out.astype(q_nope.dtype).reshape(b, t,
                                                 k.n_heads * k.v_dim)
@@ -1965,15 +2051,28 @@ class _PagedRunner:
         ps = self.page_size
         kmax = table.shape[1] * ps
         q_pos = pos0[:, None] + jnp.arange(t_len, dtype=jnp.int32)[None]
-        # latent attention reads its pages a block of keys at a time
-        n_read = table.shape[1] if self.seen is None \
-            else min(table.shape[1], -(-self.seen // ps))
-        keys = min(_KEY_BLOCK,
-                   _SCORE_BYTES // (4 * self.n_heads * b * t_len))
-        ppb = max(1, min(keys // ps, n_read))
-        n_blocks = -(-n_read // ppb)
-        blocks = jnp.pad(table[:, :n_read],
-                         ((0, 0), (0, n_blocks * ppb - n_read)))
+        k = self.kinds
+        shapes = [pl.shape for pl in pools]
+
+        def key_blocks(kind=None):
+            """How a layer that keeps the whole sequence reads its row's
+            pages, a block of keys at a time: (its fold is the kernel,
+            pages a block, blocks, the table padded to whole blocks)."""
+            in_kernel = prefill_in_kernel(
+                k.attention, k.attn_kinds, (k.nope_dim, k.v_dim), shapes,
+                t_len, table.shape[1], self.seen, kind)
+            n_read = _pages_seen(table.shape[1], self.seen, ps)
+            ppb = _pages_a_block(
+                in_kernel, ps, n_read, self.n_heads * b * t_len)
+            n_blocks = -(-n_read // ppb)
+            return in_kernel, ppb, n_blocks, jnp.pad(
+                table[:, :n_read], ((0, 0), (0, n_blocks * ppb - n_read)))
+
+        # once a program, before its layers (a scanned layer reads it)
+        blocks_of = {kind: key_blocks(kind) for kind in (
+            [None] if k.attn_kinds is None else
+            [i for i, spec in enumerate(k.attn_kinds)
+             if spec["window"] is None and not _is_ssm(spec)])}
 
         def attend_kind(p, q, entries, pools, lyr, kind):
             """A layer of one of several attention kinds: its own pools."""
@@ -1993,6 +2092,7 @@ class _PagedRunner:
                     mine = [pl.at[lyr, pg, q_pos % ps].set(
                         e.reshape(b, t_len, -1))
                         for pl, e in zip(mine, entries)]
+                    in_kernel, ppb, n_blocks, blocks = blocks_of[kind]
 
                     def read_block(i):
                         tb = jax.lax.dynamic_slice_in_dim(
@@ -2003,7 +2103,8 @@ class _PagedRunner:
                             for pl, e in zip(mine, entries))
 
                     out = self._gqa_blocked(q, read_block, n_blocks,
-                                            ppb * ps, q_pos, sink)
+                                            ppb * ps, q_pos, sink,
+                                            in_kernel)
             pools = list(pools)
             for i, pl in zip(spec["pools"], mine):
                 pools[i] = pl
@@ -2016,13 +2117,16 @@ class _PagedRunner:
             pools = tuple(pl.at[lyr, pg, q_pos % ps].set(_as_stored(e, pl))
                           for pl, e in zip(pools, entries))
             if self.kinds.attention == "latent":
+                in_kernel, ppb, n_blocks, blocks = blocks_of[None]
+
                 def read_block(i):
                     tb = jax.lax.dynamic_slice_in_dim(blocks, i * ppb,
                                                       ppb, axis=1)
                     return pools[0][lyr, tb].reshape(b, ppb * ps, -1)
 
-                return (self._latent_expanded(p, q, read_block, n_blocks,
-                                              ppb * ps, q_pos), pools)
+                return (self._latent_expanded(
+                    p, q, read_block, n_blocks, ppb * ps, q_pos,
+                    in_kernel), pools)
             views = [pl[lyr, table].reshape((b, kmax) + pl.shape[3:])
                      for pl in pools]
             return self._attend_math(q, *views, q_pos, t_len), pools
@@ -2385,6 +2489,61 @@ def decode_in_place(attention, attn_kinds, pool_shapes, kind=None):
             and not spec["sink"] and len(spec["pools"]) == 2
             and paged_flat_usable(*(pool_shapes[i] for i in spec["pools"]),
                                   spec["n_kv"]))
+
+
+def _pages_seen(n_pages, seen, page_size):
+    """The pages of a row's ``n_pages`` that hold a position a prefill
+    window may see (``seen`` positions at most; None: not known)."""
+    return n_pages if seen is None else min(n_pages, -(-seen // page_size))
+
+
+def _pages_a_block(in_kernel, page_size, n_read, score_rows):
+    """The pages a prefill window folds at a time: a block of
+    PREFILL_VISIT_KEYS positions where its fold is the kernel; else of
+    _KEY_BLOCK, or as many as keep [score_rows, keys] float32 scores
+    under _SCORE_BYTES."""
+    keys = _pa.PREFILL_VISIT_KEYS if in_kernel \
+        else min(_KEY_BLOCK, _SCORE_BYTES // (4 * score_rows))
+    return max(1, min(keys // page_size, n_read))
+
+
+def prefill_in_kernel(attention, attn_kinds, latent_widths, pool_shapes,
+                      t_len, n_pages, seen, kind=None):
+    """Whether a prefill op of a model with these block kinds, over pools
+    of these shapes, a window of ``t_len`` tokens against a table of
+    ``n_pages`` pages (``seen``: the positions it can see at most, where
+    known), folds its attention over the whole sequence through the
+    kernel ``prefill_fold`` and not in plain jax.numpy: read off what the
+    op is given, as ``decode_in_place`` is, by ``_PagedRunner.forward``
+    where it lowers and by whoever builds its program. Latent attention
+    (``latent_widths``: its heads' own key and value widths): both whole
+    lane tiles. A model that mixes kinds of layer is asked KIND BY KIND
+    (``kind`` None: whether any is): the kind that keeps the whole
+    sequence and attends, its entries flat in their pages and a value
+    head whole lane tiles (a key head is padded to them). And, for both,
+    a backend that runs the kernel and a window and a block of keys that
+    cut into its tiles. A model with one kind of plain GQA layer attends
+    a dense view (``_attend_math``) and is not asked."""
+    if attn_kinds is None:
+        if attention != "latent":
+            return False
+        widths, page_size = latent_widths, pool_shapes[0][2]
+    elif attention != "gqa":
+        return False
+    elif kind is None:
+        return any(prefill_in_kernel(attention, attn_kinds, latent_widths,
+                                     pool_shapes, t_len, n_pages, seen, i)
+                   for i in range(len(attn_kinds)))
+    else:
+        spec = attn_kinds[kind]
+        mine = [pool_shapes[i] for i in spec["pools"]]
+        if (spec["window"] is not None or _is_ssm(spec) or len(mine) != 2
+                or any(len(shape) != 4 for shape in mine)):
+            return False
+        widths, page_size = (mine[1][3] // spec["n_kv"],), mine[1][2]
+    kb = page_size * _pages_a_block(
+        True, page_size, _pages_seen(n_pages, seen, page_size), 1)
+    return _pa.prefill_fold_usable(t_len, kb, *widths)
 
 
 def _make_paged_runner(params, emb_w, fnorm, head, *, n_heads, n_kv,

@@ -131,6 +131,13 @@ _DECODE_COUNTERS = (
     # backend with the paged kernel); its share of decode_batches_total
     # is how often that form engages
     "decode_in_place_total",
+    # and its sibling for prefill: ticked beside prefill_dispatch_total
+    # and chunk_prefill_total for every whole-prompt or chunk dispatch
+    # whose program folds its attention through the kernel prefill_fold
+    # (the bundle's ``attn_in_kernel``: a block-kind model's layers that
+    # keep the whole sequence, on a backend with the kernel), to be read
+    # against the sum of those two
+    "prefill_attn_in_kernel_total",
     # a model with window attention layers (PR 33) has caches of two
     # kinds, and counts on the device, over decode steps, the positions
     # its active rows attended in the layers of each (HYBRID_STATS:
@@ -1534,6 +1541,9 @@ class DecodeEngine:
             return False
         self.breaker.record_success()
         self._tick(prefill_dispatch_total=1,
+                   prefill_attn_in_kernel_total=int(
+                       self.programs.prefill[bucket].get(
+                           "attn_in_kernel", False)),
                    prefill_dispatch_s_total=dispatch.seconds,
                    prefill_tokens_total=int(r.prompt.size),
                    prefill_padded_tokens_total=bucket,
@@ -1800,6 +1810,9 @@ class DecodeEngine:
                 continue
             self.breaker.record_success()
             self._tick(chunk_prefill_total=1,
+                       prefill_attn_in_kernel_total=int(
+                           self.programs.chunk.get("attn_in_kernel",
+                                                   False)),
                        chunk_dispatch_s_total=dispatch.seconds,
                        prefill_tokens_total=int(sl.size),
                        prefill_padded_tokens_total=cs,

@@ -504,3 +504,13 @@ def test_a_window_in_two_calls_is_the_window_in_one(cut):
     ys, ss, ts = ssm.step(p, z[:, 9], s9, t9, 1e-6)
     assert np.allclose(ys, y[:, 9], atol=1e-6)
     assert np.allclose(ss, state, atol=1e-6) and np.array_equal(ts, tail)
+
+
+def test_the_engines_dispatches_with_the_fold_in_the_kernel(monkeypatch):
+    """This model at Jamba's head (20 query heads over ONE key/value head
+    of 128): the chip comparison's probe, whole and chunked, with its two
+    attention layers' ``_gqa_blocked`` in jax.numpy and through the
+    kernel, the state layers beside them in both."""
+    import prefill_forms
+    prefill_forms.check_both_forms(
+        prefill_forms.SSM_WIDE, serve_ssm.engine_logits, monkeypatch)
