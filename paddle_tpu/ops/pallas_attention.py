@@ -322,7 +322,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, scale, causal,
 
 
 # ---------------------------------------------------------------------------
-# paged GQA decode: one query a row against the row's pages, where they lie
+# paged decode: one query a row against the row's pages, where they lie
 # ---------------------------------------------------------------------------
 
 # positions a block of keys holds (whole pages: 8 of Mistral's 16). Fixed
@@ -335,6 +335,16 @@ PAGED_BLOCK_KEYS = 128
 # read 495 GB/s at 128 positions a block (0.33 MB), 692 at 256, 735 at 512
 # and no more at 1,024 or 2,048 (PERF.md section 6, PR 42)
 PAGED_FLAT_BLOCK_KEYS = 512
+# and of latent entries (8 pages of 64, 640 wide: 0.66 MB), by the chip:
+# xing4's 16 rows x 32 heads of 1,100-8,400 positions (58 operations a
+# byte: the copies bound it) read 294 GB/s at 128 positions a block, 434
+# at 256, 562 at 512 and 618 at 1,024; DeepSeek-V3's 64 rows x 128 heads
+# of 320-2,050 (230 operations a byte, the chip's ridge: the fold bounds
+# it, its two products one after the other with the float32 passes over
+# the scores between them) 210, 302, 340 and 347 GB/s (48 to 80 of 197
+# TFLOP/s), and 64 rows of 320 positions 205 at 512 against 135 at 1,024:
+# one size for both, the longer rows' tenth left (PERF.md section 6, PR 45)
+PAGED_LATENT_BLOCK_KEYS = 512
 
 
 def paged_gqa_usable(k_shape, v_shape):
@@ -346,17 +356,21 @@ def paged_gqa_usable(k_shape, v_shape):
             and tuple(k_shape) == tuple(v_shape) and k_shape[-1] % 128 == 0)
 
 
-def _paged_rows_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, v_hbm,
-                       o_ref, k_buf, v_buf, sems, *, fold):
-    """The schedule of the paged decode kernels. Every row of the batch,
-    one after the other: a row's pages are copied to VMEM a block of
-    ``ppb`` at a time, only those that hold a position the row attends,
-    the next block (the next row's first, at a row's end) in flight while
-    ``fold(q, k_buf, v_buf, slot, length, blk, (m, l, acc)) -> (m, l,
-    acc)`` takes this one into the row's running softmax (float32
-    maximum, denominator and accumulator)."""
+def _paged_rows_kernel(layer_ref, table_ref, len_ref, q_ref, *refs, fold):
+    """The schedule of the paged decode kernels, over one pool or two
+    (``refs``: the pools in HBM, the output, a VMEM buffer a pool, the
+    copies' semaphores; the LAST pool's buffer is the one a fold takes its
+    values from). Every row of the batch, one after the other: a row's
+    pages are copied to VMEM a block of ``ppb`` at a time, only those that
+    hold a position the row attends, the next block (the next row's first,
+    at a row's end) in flight while ``fold(q, bufs, slot, length, blk, (m,
+    l, acc)) -> (m, l, acc)`` takes this one into the row's running
+    softmax (float32 maximum, denominator and accumulator)."""
+    n_pools = (len(refs) - 2) // 2
+    pools, o_ref = refs[:n_pools], refs[n_pools]
+    bufs, sems = refs[n_pools + 1:-1], refs[-1]
     n_rows, n_heads, _ = q_ref.shape
-    ppb, ps = k_buf.shape[1:3]
+    ppb, ps = bufs[0].shape[1:3]
     pps = table_ref.shape[1]
     bk = ppb * ps
     lyr = layer_ref[0]
@@ -370,15 +384,14 @@ def _paged_rows_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, v_hbm,
             @pl.when(page_no * ps < len_ref[row])
             def _():
                 page = table_ref[row, jnp.minimum(page_no, pps - 1)]
-                for i, (hbm, buf) in enumerate(((k_hbm, k_buf),
-                                                (v_hbm, v_buf))):
+                for i, (hbm, buf) in enumerate(zip(pools, bufs)):
                     act(pltpu.make_async_copy(
                         hbm.at[lyr, page], buf.at[slot, p],
                         sems.at[i, slot]))
 
     # a page that is not copied leaves what its place in the buffer held,
     # under a weight of exactly 0.0: that has to be a number
-    v_buf[...] = jnp.zeros_like(v_buf)
+    bufs[-1][...] = jnp.zeros_like(bufs[-1])
     block_copies(0, 0, 0, lambda c: c.start())
 
     def row_body(row, slot):
@@ -398,8 +411,7 @@ def _paged_rows_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, v_hbm,
                              1 - slot, lambda c: c.start())
 
             block_copies(row, blk, slot, lambda c: c.wait())
-            return (*fold(q, k_buf, v_buf, slot, length, blk, folded),
-                    1 - slot)
+            return (*fold(q, bufs, slot, length, blk, folded), 1 - slot)
 
         m, l, acc, slot = lax.fori_loop(
             0, n_blocks, block_body,
@@ -412,7 +424,18 @@ def _paged_rows_kernel(layer_ref, table_ref, len_ref, q_ref, k_hbm, v_hbm,
     lax.fori_loop(0, n_rows, row_body, 0)
 
 
-def _fold_heads(q, k_buf, v_buf, slot, length, blk, carry, *, scale, rep):
+def _running_softmax(s, m, l):
+    """A block's masked scores ``s`` [heads, columns] into a row's running
+    maximum ``m`` and denominator ``l``: (the new maximum, the new
+    denominator, the block's weights, what the accumulator so far is
+    scaled by)."""
+    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+    e = jnp.exp(s - m_new)
+    corr = jnp.exp(m - m_new)
+    return m_new, corr * l + jnp.sum(e, axis=1, keepdims=True), e, corr
+
+
+def _fold_heads(q, bufs, slot, length, blk, carry, *, scale, rep):
     """A block of pages that lie ``[page_size, g, hd]``, heads inside
     positions, read FLAT, ``[positions x g, hd]``: every query head meets
     every kv head's keys in one product and the columns of the other
@@ -420,6 +443,7 @@ def _fold_heads(q, k_buf, v_buf, slot, length, blk, carry, *, scale, rep):
     product is the price of leaving the pools as they are stored; it is
     the MXU's, which a decode step leaves idle."""
     m, l, acc = carry
+    k_buf, v_buf = bufs
     n_heads, hd = q.shape
     _, ppb, ps, g, _ = k_buf.shape
     bk = ppb * ps
@@ -433,45 +457,60 @@ def _fold_heads(q, k_buf, v_buf, slot, length, blk, carry, *, scale, rep):
     seen = (lax.rem(cols, g) == lax.div(heads, rep)) \
         & (lax.div(cols, g) < length - blk * bk)
     s = jnp.where(seen, s, NEG_INF)
-    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-    e = jnp.exp(s - m_new)
-    corr = jnp.exp(m - m_new)
-    l = corr * l + jnp.sum(e, axis=1, keepdims=True)
+    m_new, l, e, corr = _running_softmax(s, m, l)
     acc = corr * acc + lax.dot_general(
         e.astype(v.dtype), v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     return m_new, l, acc
 
 
-def _paged_decode_call(name, fold, q, k_pool, v_pool, layer, table,
-                       lengths, out_width, block_keys):
+# what a kernel may hold in VMEM where nothing is asked of the compiler:
+# v5e's scoped default, 16 MiB of the core's 128
+_VMEM_DEFAULT = 16 * 2 ** 20
+
+
+def _paged_decode_call(name, fold, q, pools, layer, table, lengths,
+                       out_width, block_keys):
     """One program over the batch's rows (``_paged_rows_kernel`` with
     ``fold``): layer, table and lengths are scalar-prefetch operands, the
     pools stay in HBM in the layout they are stored in, a block of
     ``block_keys`` positions of each in VMEM twice. ``lengths`` is
-    held to 1 and to the table's positions."""
+    held to 1 and to the table's positions. The queries and the results
+    are whole in VMEM. Where those and the blocks pass three quarters of
+    the default limit the call asks for what it holds and 8 MiB:
+    DeepSeek-V3's 64 rows x 128 heads x 640 are 10.5 MB of queries and 8.4
+    MB of results, 19.25 MiB held, which Mosaic refuses under the default
+    16 (Mistral's queries are 0.13 MB, xing4's 0.66: nothing is asked, and
+    their programs' text stays what it was)."""
     n_rows, n_heads, _ = q.shape
-    ps = k_pool.shape[2]
+    ps = pools[0].shape[2]
     lengths = jnp.clip(lengths, 1, table.shape[1] * ps)
     ppb = max(1, block_keys // ps)
     out = (n_rows, n_heads, out_width)
+    buffers = [(2, ppb) + pool.shape[2:] for pool in pools]
+    held = (q.size + int(np.prod(out))) * q.dtype.itemsize + sum(
+        int(np.prod(shape)) * pool.dtype.itemsize
+        for shape, pool in zip(buffers, pools))
+    asked = {} if held <= 3 * _VMEM_DEFAULT // 4 else {
+        "compiler_params": pltpu.CompilerParams(
+            vmem_limit_bytes=held + 8 * 2 ** 20)}
     return _pcall(
         functools.partial(_paged_rows_kernel, fold=fold),
         name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(1,),
-            in_specs=[pl.BlockSpec(q.shape, lambda i, *_: (0, 0, 0)),
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
+            in_specs=[pl.BlockSpec(q.shape, lambda i, *_: (0, 0, 0))]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
             out_specs=pl.BlockSpec(out, lambda i, *_: (0, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((2, ppb) + pool.shape[2:], pool.dtype)
-                for pool in (k_pool, v_pool)]
-            + [pltpu.SemaphoreType.DMA((2, 2))]),
+                pltpu.VMEM(shape, pool.dtype)
+                for shape, pool in zip(buffers, pools)]
+            + [pltpu.SemaphoreType.DMA((len(pools), 2))]),
         out_shape=jax.ShapeDtypeStruct(out, q.dtype),
+        **asked,
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), table.astype(jnp.int32),
-      lengths.astype(jnp.int32), q, k_pool, v_pool)
+      lengths.astype(jnp.int32), q, *pools)
 
 
 def paged_gqa_decode(q, k_pool, v_pool, layer, table, lengths):
@@ -486,7 +525,7 @@ def paged_gqa_decode(q, k_pool, v_pool, layer, table, lengths):
     _, n_heads, hd = q.shape
     fold = functools.partial(_fold_heads, scale=hd ** -0.5,
                              rep=n_heads // k_pool.shape[3])
-    return _paged_decode_call("paged_gqa_decode", fold, q, k_pool, v_pool,
+    return _paged_decode_call("paged_gqa_decode", fold, q, (k_pool, v_pool),
                               layer, table, lengths, hd, PAGED_BLOCK_KEYS)
 
 
@@ -501,7 +540,7 @@ def paged_flat_usable(k_shape, v_shape, n_kv):
             and v_shape[3] % (128 * n_kv) == 0)
 
 
-def _fold_flat(q, k_buf, v_buf, slot, length, blk, carry, *, scale, g):
+def _fold_flat(q, bufs, slot, length, blk, carry, *, scale, g):
     """A block of pages whose entries lie FLAT, ``[page_size, g * dk]``
     keys and ``[page_size, g * dv]`` values, against a ZERO-EXPANDED
     query, [heads, g * dk]: a head's ``dk`` values in its own group's
@@ -512,6 +551,7 @@ def _fold_flat(q, k_buf, v_buf, slot, length, blk, carry, *, scale, g):
     MXU's. A group's heads then meet their own ``dv`` columns of the
     values, a whole-tile slice."""
     m, l, acc = carry
+    k_buf, v_buf = bufs
     rep = q.shape[0] // g
     _, ppb, ps, kw = k_buf.shape
     bk, dv = ppb * ps, v_buf.shape[3] // g
@@ -520,10 +560,7 @@ def _fold_flat(q, k_buf, v_buf, slot, length, blk, carry, *, scale, g):
                         preferred_element_type=jnp.float32) * scale
     cols = lax.broadcasted_iota(jnp.int32, (1, bk), 1)
     s = jnp.where(cols < length - blk * bk, s, NEG_INF)
-    m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-    e = jnp.exp(s - m_new)
-    corr = jnp.exp(m - m_new)
-    l = corr * l + jnp.sum(e, axis=1, keepdims=True)
+    m_new, l, e, corr = _running_softmax(s, m, l)
     acc = corr * acc + jnp.concatenate([
         lax.dot_general(
             e[i * rep:(i + 1) * rep].astype(v_buf.dtype),
@@ -550,8 +587,55 @@ def paged_flat_decode(q, k_pool, v_pool, layer, table, lengths):
     return _paged_decode_call(
         "paged_flat_decode", functools.partial(
             _fold_flat, scale=dk ** -0.5, g=g),
-        expanded, k_pool, v_pool, layer, table, lengths,
+        expanded, (k_pool, v_pool), layer, table, lengths,
         v_pool.shape[3] // g, PAGED_FLAT_BLOCK_KEYS)
+
+
+def paged_latent_usable(pool_shapes):
+    """The gate of ``paged_latent_decode``: the backend runs Pallas
+    kernels, and the cache is ONE pool ``[L, pages, page_size, entry]``
+    whose entry is whole lane tiles (latent attention's 512 + 64 stored
+    640 wide: _PagedRunner)."""
+    return (_use_pallas() and len(pool_shapes) == 1
+            and len(pool_shapes[0]) == 4 and pool_shapes[0][3] % 128 == 0)
+
+
+def _fold_latent(q, bufs, slot, length, blk, carry, *, scale, width):
+    """A block of a latent model's pages, ``[page_size, entry]``, copied
+    ONCE and met twice: whole, as the keys of the absorbed query (``q_abs
+    | q_pe`` and zeros against the entry's padding: one product with the
+    block as it lies), and at its leading ``width`` columns (the latent's,
+    whole lane tiles) as the values the weights attend."""
+    m, l, acc = carry
+    (buf,) = bufs
+    _, ppb, ps, entry = buf.shape
+    bk = ppb * ps
+    s = lax.dot_general(q, buf[slot].reshape(bk, entry),
+                        (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * scale
+    cols = lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+    s = jnp.where(cols < length - blk * bk, s, NEG_INF)
+    m_new, l, e, corr = _running_softmax(s, m, l)
+    acc = corr * acc + lax.dot_general(
+        e.astype(buf.dtype), buf[slot, :, :, :width].reshape(bk, width),
+        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    return m_new, l, acc
+
+
+def paged_latent_decode(q, pool, layer, table, lengths, *, scale, width):
+    """``paged_gqa_decode`` for a latent model's ONE pool, [L, pages,
+    page_size, entry], the absorbed form: q [B, heads, entry] is ``q_abs |
+    q_pe`` with zeros against the entry's padding, and a row's result is
+    the latent its weights attend, [B, heads, ``width``] in q's type:
+    the entry's leading ``width`` columns, the whole lane tiles that hold
+    the latent (the cut to its rank and the value half of the expansion
+    are the caller's). A block of pages is copied to VMEM once and serves
+    as keys and as values. The same schedule and the same promise: a
+    row's result depends on its pages and its length alone."""
+    return _paged_decode_call(
+        "paged_latent_decode", functools.partial(
+            _fold_latent, scale=scale, width=width),
+        q, (pool,), layer, table, lengths, width, PAGED_LATENT_BLOCK_KEYS)
 
 
 # ---------------------------------------------------------------------------

@@ -23,6 +23,7 @@ from . import ssm
 from . import pallas_attention as _pa
 from .pallas_attention import (flash_attention, paged_flat_decode,
                                paged_flat_usable, paged_gqa_decode,
+                               paged_latent_decode, paged_latent_usable,
                                paged_gqa_usable, prefill_fold)
 
 
@@ -1379,13 +1380,17 @@ class _PagedRunner:
       against the pools themselves: each layer writes the step's entry
       into its page and a Pallas kernel attends the row's pages where
       they lie, to the row's own length (``_in_place_step``;
-      pallas_attention.py ``paged_gqa_decode``). No view, no gather, no
-      write-back: the view cost Mistral's decode program 26.6 of its
-      71.68 ms (PERF.md section 6, PR 37). WHICH FORM A DECODE OP TAKES
-      is read off what it is given (``decode_in_place``): this one where
-      the model has one kind of plain GQA layer, its K and V pools are of
-      one shape with heads of whole lane tiles, and the backend runs the
-      kernel (the chip; the tests' interpreter hook). A model that MIXES
+      pallas_attention.py ``paged_gqa_decode``, ``paged_latent_decode``).
+      No view, no gather, no write-back: the view cost Mistral's decode
+      program 26.6 of its 71.68 ms (PERF.md section 6, PR 37). WHICH FORM
+      A DECODE OP TAKES is read off what it is given (``decode_in_place``):
+      this one where the model has one kind of layer and the backend runs
+      the kernel (the chip; the tests' interpreter hook): plain GQA whose
+      K and V pools are of one shape with heads of whole lane tiles, or
+      LATENT attention over its one pool of whole-tile entries, absorbed
+      (the key half of the expansion on the query, a block of pages copied
+      once and met as keys and as values, the value half on the attended
+      latent: PERF.md section 6, PR 45). A model that MIXES
       KINDS OF LAYER is asked KIND BY KIND and runs ``forward_dense``
       with a form a kind: its kind that keeps the whole sequence, where it
       has no sink and its entries lie flat at whole lane tiles, the same
@@ -1393,10 +1398,11 @@ class _PagedRunner:
       the step scan as the state kind's do, nothing gathered or written
       back: half of MiMo-V2-Flash's decode program, PERF.md section 6,
       PR 42), beside a window kind's view of its rings and a state kind's
-      entries. The dense form everywhere else: latent attention, a kind
-      with a sink or narrow entries, the speculative step, every backend
-      that is not the chip. Latent attention wants a third kernel; the
-      dense form goes when its last caller has one (ROADMAP.md, Speed 1).
+      entries. The dense form everywhere else: a kind with a sink or
+      narrow entries, the speculative step, every backend that is not the
+      chip (the kernels' reference in the tests). Every cache form the
+      benchmark serves has its kernel now; the dense form's deletion is a
+      ``simplicity`` issue (ROADMAP.md, Speed 1 and Design 2).
 
     Page 0 is the null page: the writes of inactive slots and of
     unallocated tails land there, in no defined order (in place, two
@@ -2322,10 +2328,11 @@ class _PagedRunner:
     def _in_place_step(self, kernel, table, pos, n_pages):
         """``attend(q, entries, pools, layer) -> (out [B, 1, heads * dv],
         the pools written)``: a decode step at positions ``pos`` [B] of
-        one layer against its K and V pools themselves. The step's entry
-        goes to ``[layer, table[row, pos // page_size], pos % page_size]``
-        (the addressing ``forward`` and ``write_back`` use; a position at
-        or beyond ``kmax`` is dropped, a null table entry lands on page 0)
+        one layer against its pools themselves (K and V; a latent model's
+        one). The step's entry goes to ``[layer, table[row, pos //
+        page_size], pos % page_size]`` (the addressing ``forward`` and
+        ``write_back`` use; a position at or beyond ``kmax`` is dropped, a
+        null table entry lands on page 0)
         and ``kernel`` (pallas_attention.py) attends the row's pages where
         they lie, to the row's own length, ``pos + 1`` and ``kmax`` at
         most."""
@@ -2348,15 +2355,38 @@ class _PagedRunner:
         return attend
 
     def forward_in_place(self, h, *pools_table_pos):
-        """One decode step of a model with one kind of plain GQA layer
-        against its pools themselves (_in_place_step with
-        pallas_attention.paged_gqa_decode)."""
+        """One decode step of a model with one kind of layer against its
+        pools themselves (_in_place_step): plain GQA through
+        pallas_attention.paged_gqa_decode; latent attention, absorbed,
+        through paged_latent_decode over its one pool."""
         *pools, table, pos = pools_table_pos
-        attend = self._in_place_step(paged_gqa_decode, table, pos,
-                                     pools[0].shape[1])
+        k = self.kinds
+        latent = k.attention == "latent"
+        attend = self._in_place_step(
+            functools.partial(paged_latent_decode, scale=k.softmax_scale,
+                              width=whole_tiles(k.kv_rank))
+            if latent else paged_gqa_decode, table, pos, pools[0].shape[1])
 
         def attend_write(p, q, entries, pools, lyr, kind=None):
-            return attend(q, entries, pools, lyr)
+            if not latent:
+                return attend(q, entries, pools, lyr)
+            # _latent_absorbed's mathematics with the view's two products
+            # and its softmax in the kernel: the key half of the expansion
+            # on the query, the value half on the attended latent
+            q_nope, q_pe = q
+            w_up = self._kv_up(p)
+            with jax.named_scope("mla/absorb"):
+                q_abs = jnp.einsum("bqhd,rhd->bqhr", q_nope,
+                                   w_up[..., :k.nope_dim])
+                o_lat, pools = attend(
+                    _as_stored(jnp.concatenate([q_abs, q_pe], axis=-1),
+                               pools[0]),
+                    [_as_stored(e, pools[0]) for e in entries], pools, lyr)
+                out = jnp.einsum(
+                    "bqhr,rhd->bqhd",
+                    o_lat.reshape(q_abs.shape[:3] + (-1,))[..., :k.kv_rank],
+                    w_up[..., k.nope_dim:])
+            return out.reshape(out.shape[:2] + (-1,)), pools
 
         h, pools = self._stack_forward(h, tuple(pools), pos[:, None],
                                        attend_write)
@@ -2469,14 +2499,17 @@ def decode_in_place(attention, attn_kinds, pool_shapes, kind=None):
     of these shapes, runs its steps against the pools themselves and not
     against a dense view: read off what the op is given, by
     ``_paged_decode`` where it lowers and by whoever builds its program
-    and wants to know which form that is. A model with one kind of plain
-    GQA layer (``_PagedRunner.forward_in_place``): K and V pools of one
-    shape with whole-tile heads, and a backend that runs the Pallas
-    kernel. A model that mixes kinds of layer is asked KIND BY KIND
+    and wants to know which form that is. A model with one kind of layer
+    (``_PagedRunner.forward_in_place``), on a backend that runs the Pallas
+    kernels: plain GQA with K and V pools of one shape and whole-tile
+    heads; latent attention with ONE pool whose entries lie flat at whole
+    lane tiles. A model that mixes kinds of layer is asked KIND BY KIND
     (``kind`` None: whether any is): in place where the kind keeps the
     whole sequence, has attention for its mixer and no sink, and its two
     pools hold their entries flat at whole lane tiles (paged_flat_usable);
     its other kinds keep their form."""
+    if attn_kinds is None and attention == "latent":
+        return paged_latent_usable(pool_shapes)
     if attention != "gqa":
         return False
     if attn_kinds is None:

@@ -127,8 +127,9 @@ _DECODE_COUNTERS = (
     "pools_consumed_total", "pools_lost_total",
     # ticked beside decode_batches_total for every decode dispatch whose
     # program runs its steps against the pools themselves and not a dense
-    # view of them (the decode bundle's ``in_place``: plain GQA pools on a
-    # backend with the paged kernel); its share of decode_batches_total
+    # view of them (the decode bundle's ``in_place``: plain GQA pools, a
+    # mixed model's flat sequence kind or a latent model's one pool, on a
+    # backend with the paged kernels); its share of decode_batches_total
     # is how often that form engages
     "decode_in_place_total",
     # and its sibling for prefill: ticked beside prefill_dispatch_total

@@ -535,3 +535,30 @@ def test_the_engines_dispatches_with_the_fold_in_the_kernel(monkeypatch):
     from benchmark.builders import serve_blocks
     prefill_forms.check_both_forms(
         prefill_forms.SHARE_WIDE, serve_blocks.engine_logits, monkeypatch)
+
+
+# -- the decode program in place (decode_forms.py; PERF.md section 6, PR 45) --
+
+def test_a_decode_dispatch_in_both_forms(monkeypatch):
+    """``block_paged_decode`` over the pool as the engine stores it (the
+    entry at a whole lane tile), dense and then in place."""
+    import decode_forms
+    decode_forms.check_a_dispatch_in_both_forms(
+        run_op, CFG, PROMPTS, LENS, TABLE,
+        [jnp.zeros((CFG.n_layers, 40, PS, CFG.stored_dim), jnp.float32)],
+        monkeypatch, REL_L2_F32)
+
+
+def test_the_in_place_decode_program_holds_no_view(monkeypatch):
+    import decode_forms
+    decode_forms.check_the_program_holds_no_view(
+        CFG, dict(max_batch=3, page_size=PS, n_pages=40, pages_per_seq=MP,
+                  prompt_buckets=(8,), decode_block=2), monkeypatch)
+
+
+@pytest.mark.parametrize("hook", [False, True], ids=["off", "on"])
+def test_an_engine_decodes_in_place_where_the_kernel_runs(hook,
+                                                          monkeypatch):
+    import decode_forms
+    decode_forms.check_an_engines_tokens_and_its_counter(
+        make_engine, CFG, reference_logits, monkeypatch, hook, PS)

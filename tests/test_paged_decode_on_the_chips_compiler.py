@@ -4,13 +4,16 @@ Compiled here for a described TPU v5e, with no chip attached (the
 ``on-chip-measurement`` guide, section 2), at the benchmark's shapes
 (Mistral's plain GQA pools; the sequence kind of MiMo-V2-Flash's share
 and of Jamba2, entries flat in their pages, beside their rings and their
-states): what the CPU backend and the Pallas interpreter cannot show.
+states; the one pool of xing4's and of DeepSeek-V3's share's latent
+entries, 640 wide): what the CPU backend and the Pallas interpreter cannot
+show.
 The pools are donated and carried through two nested loops in which a
 scatter writes them and a custom call reads them; that is where XLA has
 twice decided to copy 0.82 GB a layer (PERF.md section 6, PR 25 and
 PR 34). The describing call is made inside a fixture and in this file
 alone: one process at a time may load the TPU's library.
 """
+import functools
 import importlib
 import json
 import math
@@ -142,7 +145,7 @@ def _programs_of(config_file, builder):
         max_batch=e["max_batch"], page_size=e["page_size"],
         n_pages=e["max_batch"] * per_seq + 1, pages_per_seq=per_seq,
         prompt_buckets=tuple(e["prompt_buckets"]),
-        decode_block=block, chunk_size=e["chunk_size"])
+        decode_block=block, chunk_size=e.get("chunk_size"))
 
 
 @pytest.fixture(scope="module")
@@ -385,3 +388,93 @@ def test_a_chunk_program_holds_no_score_block(one_chip, cell, monkeypatch):
         temporaries[in_kernel] = \
             compiled.memory_analysis().temp_size_in_bytes
     assert temporaries[True] < 0.65 * temporaries[False]
+
+
+# -- latent attention in place (paged_latent_decode; PR 45) -----------------
+
+# cell -> (its file under benchmark/configs, its builder, rows, query
+# heads, positions a row): docs' 16 rows of 133 pages of 64, reason's 64
+# rows x 128 heads, whose queries and results (18.9 MB) pass the default
+# VMEM limit
+LATENT = {"docs": ("xing4.0-29b-a4b.json", "serve_blocks", 16, 32, 8512),
+          "reason": ("deepseek-v3-ep16.json", "serve_share", 64, 128, 2112)}
+
+
+@pytest.fixture(params=sorted(LATENT))
+def latent(request, monkeypatch):
+    """(a latent cell's rows, heads and ``kmax``, its programs), with the
+    gate as the chip passes it while the test runs."""
+    monkeypatch.setattr(pa, "_use_pallas", lambda: True)
+    config_file, builder, *shape = LATENT[request.param]
+    return shape, _programs_of(config_file, builder)[1]
+
+
+def test_the_latent_kernel_compiles_at_the_cells_shapes(one_chip, latent):
+    """Mosaic takes the one pool as it is stored, 640 wide, a block of it
+    in VMEM twice beside the whole queries and results: no pool is re-laid
+    or copied on its way into the call."""
+    (rows, heads, kmax), programs = latent
+
+    def abstract(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    (shape, _), = programs.pool_specs
+    assert shape[2:] == [64, 640] and kmax == programs.pages_per_seq * 64
+    text = jax.jit(functools.partial(
+        pa.paged_latent_decode, scale=0.1, width=512)).lower(
+        abstract((rows, heads, 640)), abstract(shape),
+        abstract((), jnp.int32),
+        abstract((rows, programs.pages_per_seq), jnp.int32),
+        abstract((rows,), jnp.int32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert f"bf16[{rows},{heads},512]" in text
+    _assert_held_uncopied(text, programs.pool_specs)
+
+
+def test_a_latent_decode_program_holds_no_view(one_chip, latent):
+    """One kernel instance a layer body (the leading dense layer's and the
+    layer scan's), the pool aliased from the donated input to the output
+    and never copied, and no array with the view's ``kmax`` axis anywhere
+    in the module: not the gathered view ``bf16[.., kmax, 640]`` (1.04 GB
+    docs, 0.87 GB reason), not the float32 scores over every position."""
+    (rows, heads, kmax), programs = latent
+    assert programs.decode["in_place"]
+    assert (programs.max_batch, programs.pages_per_seq * programs.page_size) \
+        == (rows, kmax)
+    compiled = program_text.lower_bundle(programs.decode, 1,
+                                         sharding=one_chip).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"tpu_custom_call.*paged_latent_decode", text)) == 2
+    _assert_held_uncopied(text, programs.pool_specs)
+    views = re.findall(rf"\w+\[(?:\d+,)*(?:{kmax}|"
+                       rf"{programs.pages_per_seq},{programs.page_size})"
+                       r"(?:,\d+)*\]", text)
+    assert not views, sorted(set(views))
+    (shape, _), = programs.pool_specs
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= math.prod(shape) * 2
+    # beside its arguments the program holds less than one view (the
+    # dense form: 1.73 GB reason, 2.10 GB docs; in place 0.59 and 0.14)
+    assert memory.temp_size_in_bytes < shape[0] * rows * kmax * 640 * 2
+
+
+# program_text.chip_fingerprint of the other in-place decode programs at
+# their configurations' own engines, as PR 44 recorded them (PERF.md
+# section 6) and PR 45, which gave the schedule a third fold and one pool
+# or two, left them
+OTHERS_PINNED = {"mimo": "599ecd3567fa65c8", "jamba": "9e718d24c1afa2e7",
+                 "ouro": "4862221ab083276b"}
+
+
+@pytest.mark.parametrize("model", sorted(OTHERS_PINNED))
+def test_the_other_decode_programs_are_what_the_chip_was_asked_before(
+        one_chip, model, looped, monkeypatch):
+    monkeypatch.setattr(pa, "_use_pallas", lambda: True)
+    if model == "ouro":
+        cfg, geometry = looped
+        programs = cfg.build_paged_programs(**geometry)
+    else:
+        programs = _programs_of(*MIXED[model])[1]
+    got = program_text.chip_fingerprint(program_text.lower_bundle(
+        programs.decode, len(programs.pool_specs), sharding=one_chip))
+    assert got == OTHERS_PINNED[model], (model, got)

@@ -17,8 +17,16 @@ runs onto a null table entry) at a head 128 wide:
   and the same pools on every page but the null one;
 - structure: no array of the dense view's shape, no pool as a scan's
   ``xs`` / ``ys``;
-- the gate: latent and narrow-head models build the dense form, and the
-  engine's ``decode_in_place_total`` says which it dispatched.
+- the gate: narrow-head models build the dense form, and the engine's
+  ``decode_in_place_total`` says which it dispatched.
+
+A LATENT model's one pool (PERF.md section 6, PR 45) is attended in place
+by ``paged_latent_decode``, a third fold under the same schedule: a block
+of pages copied once, keys whole and values at its leading lane tiles.
+Held here on the same cases against the absorbed form's own two products
+over the gathered view, at an entry padded past its latent and rotated
+columns; the decode form itself in tests/test_latent_moe.py and
+tests/test_latent_share.py.
 
 A model that MIXES KINDS OF LAYER is asked kind by kind (PERF.md section
 6, PR 42): its sequence kind, whose entries lie FLAT in their pages (keys
@@ -31,6 +39,7 @@ HYBRID_MOE_TINY and of HYBRID_SSM_TINY, widened to whole lane tiles, in
 both forms; the gate, one reason to refuse at a time.
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -65,12 +74,22 @@ def kernel_on(monkeypatch):
     so that rows of these lengths fold several blocks."""
     monkeypatch.setattr(pa, "_FORCE_INTERPRET", True)
     monkeypatch.setattr(pa, "PAGED_BLOCK_KEYS", 2 * PS)
+    monkeypatch.setattr(pa, "PAGED_LATENT_BLOCK_KEYS", 2 * PS)
 
+
+# a latent entry as stored: 100 latent columns and 40 rotated ones, padded
+# to two lane tiles; the weights attend its first whole tile, which holds
+# the latent (the caller cuts it to 100)
+KV_RANK, ROPE, ENTRY, LATENT_SCALE = 100, 40, 256, 0.11
 
 # how a page holds its entries -> (kernel, kv heads, key width, value
-# width, query heads): heads inside positions, [PS, g, hd]; or the entry
-# flat, [PS, g * dk] beside [PS, g * dv]
+# width, query heads): heads inside positions, [PS, g, hd]; the entry
+# flat, [PS, g * dk] beside [PS, g * dv]; or ONE pool of latent entries,
+# [PS, entry], keys and values both
 LAYOUTS = {
+    "latent": (functools.partial(pa.paged_latent_decode, scale=LATENT_SCALE,
+                                 width=T.whole_tiles(KV_RANK)),
+               1, ENTRY, 128, 6),
     "heads": (pa.paged_gqa_decode, NKV, HD, HD, NH),
     # a key/value head a query head (a looped model's 16 of 16: PR 43):
     # the same product under a mask that keeps one head in g, not rep in g
@@ -83,6 +102,9 @@ LAYOUTS = {
 def _pools(dtype, seed=0, layout="heads"):
     _, g, dk, dv, _ = LAYOUTS[layout]
     kk, kv = jax.random.split(jax.random.PRNGKey(seed))
+    if layout == "latent":      # numbers in the padding too: the query's
+        # zeros meet them
+        return (jax.random.normal(kk, (L, NP, PS, dk)).astype(dtype),)
     if layout.startswith("heads"):
         return tuple(jax.random.normal(k, (L, NP, PS, g, dk)).astype(dtype)
                      for k in (kk, kv))
@@ -92,15 +114,34 @@ def _pools(dtype, seed=0, layout="heads"):
 
 def _query(dtype, seed, layout="heads"):
     _, _, dk, _, heads = LAYOUTS[layout]
-    return jax.random.normal(jax.random.PRNGKey(seed),
-                             (B, heads, dk)).astype(dtype)
+    q = jax.random.normal(jax.random.PRNGKey(seed),
+                          (B, heads, dk)).astype(dtype)
+    if layout == "latent":      # ``q_abs | q_pe`` and zeros behind them
+        q = jnp.where(jnp.arange(dk) < KV_RANK + ROPE, q, 0)
+    return q
 
 
-def _view_attention(q, k_pool, v_pool, layer, table, lengths):
+def _view_attention(q, *pools_layer_table_lengths):
     """The dense form's own attention of one query a row at position
     ``length - 1`` over the gathered view: ``_attend_math`` for pools with
-    heads inside positions, ``_attend_masked`` for flat entries."""
+    heads inside positions, ``_attend_masked`` for flat entries, and for
+    ONE pool of latent entries what ``_latent_absorbed`` does between its
+    two halves of the expansion."""
+    *pools, layer, table, lengths = pools_layer_table_lengths
     heads, dk = q.shape[1:]
+    if len(pools) == 1:
+        view = T._PagedRunner({"Wq": jnp.zeros((L, D, heads * dk))}, None,
+                              None, None, n_heads=heads, n_kv=1, base=1e4,
+                              eps=1e-5, page_size=PS).gather(
+            pools[0], table)[layer]                     # [B, kmax, entry]
+        s = jnp.einsum("bhc,bkc->bhk", q, view,
+                       preferred_element_type=jnp.float32) * LATENT_SCALE
+        seen = jnp.arange(view.shape[1])[None] < lengths[:, None]
+        w = jax.nn.softmax(jnp.where(seen[:, None], s, -1e30), axis=-1)
+        return jnp.einsum("bhk,bkc->bhc", w.astype(view.dtype),
+                          view[..., :128],
+                          preferred_element_type=jnp.float32)
+    k_pool, v_pool = pools
     flat = k_pool.ndim == 4
     g = k_pool.shape[3] // dk if flat else k_pool.shape[3]
     run = T._PagedRunner({"Wq": jnp.zeros((L, D, heads * dk))}, None, None,
@@ -141,11 +182,10 @@ def test_kernel_against_the_gathered_view(layout, case, dtype, kernel_on):
     weights are rounded to the cache's type before they meet the
     values."""
     row, length = CASES[case]
-    k_pool, v_pool = _pools(dtype, layout=layout)
     lengths = np.array([5, 12, 2, 7], np.int32)
     lengths[row] = length
-    args = (_query(dtype, 5, layout), k_pool, v_pool, jnp.int32(1),
-            jnp.asarray(TABLE), jnp.asarray(lengths))
+    args = (_query(dtype, 5, layout), *_pools(dtype, layout=layout),
+            jnp.int32(1), jnp.asarray(TABLE), jnp.asarray(lengths))
     got = np.asarray(jax.jit(LAYOUTS[layout][0])(*args), np.float32)
     want = np.asarray(_view_attention(*args), np.float32)
     tol = 2e-2 if dtype == "bfloat16" else 2e-6
@@ -158,14 +198,14 @@ def test_a_row_alone_is_the_row_among_peers(layout, kernel_on):
     are fixed by the program, so neither the batch's longest row nor what
     another row left in the buffers reaches its result."""
     kernel = jax.jit(LAYOUTS[layout][0])
-    k_pool, v_pool = _pools("bfloat16", layout=layout)
+    pools = _pools("bfloat16", layout=layout)
     q = _query(jnp.bfloat16, 6, layout)
     lengths = np.array([9, 17, 2, KMAX], np.int32)
-    among = np.asarray(kernel(q, k_pool, v_pool, jnp.int32(2),
+    among = np.asarray(kernel(q, *pools, jnp.int32(2),
                               jnp.asarray(TABLE), jnp.asarray(lengths)))
     for row in (0, 1, 3):
         alone = np.asarray(kernel(
-            q[row:row + 1], k_pool, v_pool, jnp.int32(2),
+            q[row:row + 1], *pools, jnp.int32(2),
             jnp.asarray(TABLE[row:row + 1]),
             jnp.asarray(lengths[row:row + 1])))
         assert np.array_equal(alone[0].view(np.uint16),
@@ -290,9 +330,10 @@ SSM_WIDE = dataclasses.replace(HYBRID_SSM_TINY, name="hybrid-ssm-wide",
 
 @pytest.mark.parametrize("hook", [False, True], ids=["off", "on"])
 def test_the_gate_reads_the_model_and_the_backend(hook, monkeypatch):
-    """Plain GQA pools with whole-tile heads, or a mixed model's sequence
-    kind with flat whole-tile entries, on a backend that runs the kernel:
-    everything else builds the dense form."""
+    """Plain GQA pools with whole-tile heads, a mixed model's sequence
+    kind with flat whole-tile entries, or a latent model's ONE pool of
+    whole-tile entries (PERF.md section 6, PR 45), on a backend that runs
+    the kernel: everything else builds the dense form."""
     monkeypatch.setattr(pa, "_FORCE_INTERPRET", hook)
     built = {name: cfg.build_paged_programs(**GEOMETRY).decode["in_place"]
              for name, cfg in (("wide", WIDE), ("narrow", LLAMA_TINY),
@@ -301,13 +342,16 @@ def test_the_gate_reads_the_model_and_the_backend(hook, monkeypatch):
                                ("hybrid_ssm", HYBRID_SSM_TINY),
                                ("hybrid_wide", MOE_WIDE),
                                ("hybrid_ssm_wide", SSM_WIDE))}
-    assert built == {"wide": hook, "narrow": False, "latent": False,
+    assert built == {"wide": hook, "narrow": False, "latent": hook,
                      "hybrid": False, "hybrid_ssm": False,
                      "hybrid_wide": hook, "hybrid_ssm_wide": hook}
     pool = (2, 40, 4, 1, 128)
     assert T.decode_in_place("gqa", None, [pool, pool]) is hook
+    assert T.decode_in_place("latent", None, [(2, 40, 4, 640)]) is hook
     for attention, kinds, pools in (
-            ("latent", None, [(2, 40, 4, 640)]),
+            ("latent", None, [(2, 40, 4, 576)]),            # 4.5 tiles
+            ("latent", None, [(2, 40, 4, 640)] * 2),        # two pools
+            ("latent", None, [(2, 40, 4, 5, 128)]),         # no flat entry
             ("gqa", None, [pool, (2, 40, 4, 1, 256)]),      # key != value
             ("gqa", None, [(2, 40, 4, 2, 64)] * 2),         # half a tile
             ("gqa", None, [(2, 40, 4, 128)] * 2)):          # flat entries
