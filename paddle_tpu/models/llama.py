@@ -367,8 +367,9 @@ class PagedDecodePrograms:
     fetches are (token outputs, the pools, then what ``extras`` names:
     ``logits``, ``picks``, ``stats``); ``pools`` says which pools those
     are where not the target's (``draft``, ``both``); the decode
-    bundle's ``in_place`` says which form its steps were built on
-    (ops/transformer_ops.py decode_in_place). ``pool_specs`` (and ``draft_pool_specs``
+    bundle's ``in_place`` says whether its steps attend their pages through
+    a Pallas kernel (ops/transformer_ops.py decode_in_place: a report; the
+    steps run against the pools either way). ``pool_specs`` (and ``draft_pool_specs``
     when spec) are the (shape, dtype) of each pool the engine allocates
     and round-trips through every dispatch: ``[L, n_pages, page_size]``
     followed by one entry of the model's ``cache_spec()``. ``stats``

@@ -14,8 +14,8 @@ own, so a position leaves ``passes x n_layers`` entries and the pools are
 ``[passes * n_layers, pages, page_size, n_kv, head_dim]``, layer ``j`` of
 pass ``s`` at ``s * n_layers + j``. At Ouro-2.6B's sizes that is 192
 layer-caches, 1.5 MB a position: the pool, not the slots, bounds the
-batch, and a dense view of it cannot exist on the chip (the decode steps
-attend the pages where they lie: ``decode_in_place``).
+batch, and no program holds a view of it: the decode steps attend the
+pages where they lie (``_PagedRunner.decode_step``).
 
 The exit gate (``exit_gate.w`` [dim], ``exit_gate.b`` [1]) is computed on
 every pass's normed output and moves no logit at the published exit

@@ -322,7 +322,10 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, scale, causal,
 
 
 # ---------------------------------------------------------------------------
-# paged decode: one query a row against the row's pages, where they lie
+# paged decode: one query a row against the row's pages, where they lie.
+# Each of the three calls asks its own gate, as ``flash_attention`` does:
+# the Pallas kernel where it passes (the chip; the tests' interpreter
+# hook), ``_ref_paged_attention`` everywhere else, under one promise
 # ---------------------------------------------------------------------------
 
 # positions a block of keys holds (whole pages: 8 of Mistral's 16). Fixed
@@ -523,6 +526,9 @@ def paged_gqa_decode(q, k_pool, v_pool, layer, table, lengths):
     pages and that length alone (float32 scores and accumulator, products
     in the cache's type). Returns [B, heads, hd] in q's type."""
     _, n_heads, hd = q.shape
+    if not paged_gqa_usable(k_pool.shape, v_pool.shape):
+        return _ref_paged_attention(q, k_pool, v_pool, layer, table,
+                                    lengths, k_pool.shape[3], hd ** -0.5)
     fold = functools.partial(_fold_heads, scale=hd ** -0.5,
                              rep=n_heads // k_pool.shape[3])
     return _paged_decode_call("paged_gqa_decode", fold, q, (k_pool, v_pool),
@@ -570,16 +576,21 @@ def _fold_flat(q, bufs, slot, length, blk, carry, *, scale, g):
     return m_new, l, acc
 
 
-def paged_flat_decode(q, k_pool, v_pool, layer, table, lengths):
+def paged_flat_decode(q, k_pool, v_pool, layer, table, lengths, sink=None):
     """``paged_gqa_decode`` for pools whose entries lie flat in their
     pages, keys and values of unequal widths: q [B, heads, dk]; k_pool [L,
     pages, page_size, g * dk]; v_pool [.., g * dv] (a model that mixes
     kinds of layer stores its sequence kind so: _PagedRunner). The same
     schedule and the same promise: a row's result depends on its pages
-    and its length alone. Scores are scaled by ``dk ** -0.5``. Returns [B,
-    heads, dv] in q's type."""
+    and its length alone. Scores are scaled by ``dk ** -0.5``. ``sink``
+    [heads]: one more column of each head's denominator, which no kernel
+    takes. Returns [B, heads, dv] in q's type."""
     n_rows, n_heads, dk = q.shape
     g = k_pool.shape[3] // dk
+    if sink is not None or not paged_flat_usable(k_pool.shape, v_pool.shape,
+                                                 g):
+        return _ref_paged_attention(q, k_pool, v_pool, layer, table,
+                                    lengths, g, dk ** -0.5, sink)
     own = (jnp.arange(n_heads)[:, None] // (n_heads // g)
            == jnp.arange(g)[None])                          # [heads, g]
     expanded = jnp.where(own[None, :, :, None], q[:, :, None], 0).reshape(
@@ -632,6 +643,9 @@ def paged_latent_decode(q, pool, layer, table, lengths, *, scale, width):
     are the caller's). A block of pages is copied to VMEM once and serves
     as keys and as values. The same schedule and the same promise: a
     row's result depends on its pages and its length alone."""
+    if not paged_latent_usable([pool.shape]):
+        return _ref_paged_attention(q, pool, pool, layer, table, lengths,
+                                    1, scale)[..., :width]
     return _paged_decode_call(
         "paged_latent_decode", functools.partial(
             _fold_latent, scale=scale, width=width),
@@ -827,6 +841,66 @@ def _ref_attention_lse(q, k, v, scale, causal, bias=None):
     o = jnp.einsum("...qk,...kd->...qd", (p / l).astype(v.dtype), v)
     lse = (m + jnp.log(l))[..., 0]
     return o, lse
+
+
+def masked_attention(q, k_all, v_all, q_pos, k_pos=None, window=None,
+                     sink=None, scale=None):
+    """GQA attention of queries q [B, T, heads, kd] at ``q_pos`` [B, T]
+    over keys [B, K, g, kd] and values [B, K, g, vd] in the cache's type,
+    accumulated in float32: float32 scores and softmax, the weights
+    rounded to the cache's type before they meet the values. Key j of row
+    b is position ``k_pos[b, j]`` (None: j; negative: no key there); a
+    query sees a key at or before itself and, with ``window``, fewer than
+    ``window`` positions back. ``sink`` [heads]: one more column of the
+    softmax's denominator a head, that adds to nothing else. Scores are
+    scaled by ``scale`` (None: ``kd ** -0.5``). Returns [B, T, heads *
+    vd] in q's type."""
+    b, t = q_pos.shape
+    g, n_keys = k_all.shape[2], k_all.shape[1]
+    f32 = jnp.float32
+    qg = q.reshape(b, t, g, q.shape[2] // g, q.shape[-1])
+    kp = jnp.arange(n_keys, dtype=jnp.int32)[None] \
+        if k_pos is None else k_pos
+    back = q_pos[:, :, None] - kp[:, None, :]            # [B, T, K]
+    mask = (back >= 0) & (kp[:, None, :] >= 0)
+    if window is not None:
+        mask = mask & (back < window)
+    s = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k_all,
+                   preferred_element_type=f32) \
+        * (q.shape[-1] ** -0.5 if scale is None else scale)
+    s = jnp.where(mask[:, None, None], s, NEG_INF)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    if sink is not None:
+        sk = sink.astype(f32).reshape(1, g, -1, 1, 1)
+        m = jnp.maximum(m, sk)
+    e = jnp.exp(s - m)
+    l = jnp.sum(e, axis=-1, keepdims=True)
+    if sink is not None:
+        l = l + jnp.exp(sk - m)
+    out = jnp.einsum("bgrqk,bkgd->bqgrd", (e / l).astype(v_all.dtype),
+                     v_all, preferred_element_type=f32)
+    return out.astype(q.dtype).reshape(b, t, -1)
+
+
+def _ref_paged_attention(q, k_pool, v_pool, layer, table, lengths, g,
+                         scale, sink=None):
+    """What the three paged decode kernels promise, in plain jax.numpy,
+    for pools of any width: q [B, heads, dk] over layer ``layer`` of
+    ``k_pool`` and ``v_pool`` [L, pages, page_size, ...], an entry ``g``
+    key/value heads of ``dk`` | ``dv`` (inside positions or flat; a latent
+    model's one pool is both, ``g`` 1). Each row's pages are gathered in
+    its table's order (``pool[layer, table]``: a layer's own [B, kmax,
+    ...], never the layers') and attended to the row's ``lengths`` (held
+    to 1 and to the table's positions) by ``masked_attention``, a sink
+    too, which no kernel takes. Returns [B, heads, dv] in q's type."""
+    b, n_heads, _ = q.shape
+    kmax = table.shape[1] * k_pool.shape[2]
+    keys = k_pool[layer, table].reshape(b, kmax, g, -1)
+    values = keys if v_pool is k_pool \
+        else v_pool[layer, table].reshape(b, kmax, g, -1)
+    last = jnp.clip(lengths, 1, kmax)[:, None] - 1
+    return masked_attention(q[:, None], keys, values, last, sink=sink,
+                            scale=scale).reshape(b, n_heads, -1)
 
 
 def attention_with_lse(q, k, v, scale=None, causal=False):

@@ -7,7 +7,6 @@ fusion_lstm_op.cc etc. are the CUDA-era analogues): the hot path is one
 op the compiler can schedule as a unit, instead of a softmax/matmul
 chain.
 """
-import contextlib
 import copy
 import functools
 import itertools
@@ -21,10 +20,11 @@ import jax.numpy as jnp
 from ..core.registry import register_op
 from . import ssm
 from . import pallas_attention as _pa
-from .pallas_attention import (flash_attention, paged_flat_decode,
-                               paged_flat_usable, paged_gqa_decode,
-                               paged_latent_decode, paged_latent_usable,
-                               paged_gqa_usable, prefill_fold)
+from .pallas_attention import (flash_attention, masked_attention,
+                               paged_flat_decode, paged_flat_usable,
+                               paged_gqa_decode, paged_latent_decode,
+                               paged_latent_usable, paged_gqa_usable,
+                               prefill_fold)
 
 
 def rms_normalize(x, scale=None, eps=1e-6):
@@ -1351,155 +1351,64 @@ def _folded(carry):
 
 class _PagedRunner:
     """Paged twin of _make_cached_runner, closed over one model's
-    stacked weights and its ``BlockKinds``. A model's cache is a tuple
-    of pools ``[L, n_pages, page_size, *entry]``, one per entry a token
-    leaves in a layer (GQA: K and V ``[g, hd]``; latent attention: one
-    ``[kv_rank + rope_dim]``). AN ENTRY IS STORED AT WHOLE LANE TILES: a
-    pool whose minor width is not a multiple of 128 is handed to every
-    program with its page axis innermost and re-laid, whole, for the
-    gather, the scatter and the output, so latent attention's 576 values
-    are stored 640 wide, zeros behind them (the model's ``cache_spec()``
-    says so; both forms write an entry at the width of the pool they are
-    given and attention multiplies the pad by zeros), as the hybrid
-    model's heads lie flat in their page, below: one rule, PERF.md
-    section 6, PR 33 and PR 34. Three execution forms over the SAME math:
+    stacked weights and its ``BlockKinds``. A model's cache is a tuple of
+    pools ``[L, n_pages, page_size, *entry]``, one per entry a token leaves
+    in a layer (GQA: K and V ``[g, hd]``; latent attention: one ``[kv_rank
+    + rope_dim]``), AN ENTRY STORED AT WHOLE LANE TILES (latent attention's
+    576 values 640 wide, zeros behind them, or the chip re-lays the whole
+    pool on its way into every program: ``cache_spec()``; PERF.md section
+    6, PR 33 and 34). Page 0 is the null page: the writes of inactive slots
+    and of unallocated tails land there, in no defined order (the engine
+    lets no live row read them). TWO ENTRIES, both against the pools:
 
-    - ``forward(h, *pools, table, pos0, t_len)`` — operate directly on
-      the page pools through ``table`` [B, max_pages]: each layer
-      writes the window's entries at ``[layer, page, offset]`` and
-      attends over the row's pages of that layer (both prefill ops).
-    - ``gather``/``forward_dense``/``write_back`` — gather each row's
-      pages to a dense [L, B, kmax, *entry] cache once a dispatch, run
-      every step against it (each layer writes ``[layer, row, q_pos]``
-      and attends over ``dense[layer]``), and at the end write back to
-      the pools the entries the steps wrote, a few positions a row, and
-      nothing else: the rest of the view is what the pools already
-      hold. The speculative step op uses this, and the decode ops of
-      every model the next form does not take.
-    - ``forward_in_place(h, *pools, table, pos)`` — a decode step
-      against the pools themselves: each layer writes the step's entry
-      into its page and a Pallas kernel attends the row's pages where
-      they lie, to the row's own length (``_in_place_step``;
-      pallas_attention.py ``paged_gqa_decode``, ``paged_latent_decode``).
-      No view, no gather, no write-back: the view cost Mistral's decode
-      program 26.6 of its 71.68 ms (PERF.md section 6, PR 37). WHICH FORM
-      A DECODE OP TAKES is read off what it is given (``decode_in_place``):
-      this one where the model has one kind of layer and the backend runs
-      the kernel (the chip; the tests' interpreter hook): plain GQA whose
-      K and V pools are of one shape with heads of whole lane tiles, or
-      LATENT attention over its one pool of whole-tile entries, absorbed
-      (the key half of the expansion on the query, a block of pages copied
-      once and met as keys and as values, the value half on the attended
-      latent: PERF.md section 6, PR 45). A model that MIXES
-      KINDS OF LAYER is asked KIND BY KIND and runs ``forward_dense``
-      with a form a kind: its kind that keeps the whole sequence, where it
-      has no sink and its entries lie flat at whole lane tiles, the same
-      step against its own pools (``paged_flat_decode``; the pools ride
-      the step scan as the state kind's do, nothing gathered or written
-      back: half of MiMo-V2-Flash's decode program, PERF.md section 6,
-      PR 42), beside a window kind's view of its rings and a state kind's
-      entries. The dense form everywhere else: a kind with a sink or
-      narrow entries, the speculative step, every backend that is not the
-      chip (the kernels' reference in the tests). Every cache form the
-      benchmark serves has its kernel now; the dense form's deletion is a
-      ``simplicity`` issue (ROADMAP.md, Speed 1 and Design 2).
+    - ``forward(h, *pools, table, pos0, t_len)`` — a WINDOW of tokens
+      (the prefill ops, the speculative round's windows): each layer
+      writes the window's entries at ``[layer, table[row, p // page_size],
+      p % page_size]`` and attends the row's pages of that layer. Plain
+      GQA attends the layer's gathered rows (``_attend_math``); a layer
+      that keeps the whole sequence beside other kinds, and latent
+      attention, which EXPANDS the latents it sees into per-head keys and
+      values, fold the row's pages a block of keys at a time under a
+      running softmax (``_gqa_blocked``, ``_latent_expanded``: jax.numpy,
+      or a block a call of the kernel ``prefill_fold`` where
+      ``prefill_in_kernel`` says so: PERF.md section 6, PR 44).
+    - ``decode_step(h, *cache, table, pos)`` — ONE token a row (the step
+      scan of ``_paged_decode``; the draft's steps): a layer writes its
+      entry into its page and calls its paged attention function
+      (``_paged_step``;
+      pallas_attention.py ``paged_gqa_decode``, ``paged_flat_decode``,
+      ``paged_latent_decode``: the Pallas kernel where its gate passes, the
+      jax.numpy reference where not: the CALL decides, no program's shape
+      does). Latent attention ABSORBS the expansion into its query and its
+      output and reads the latents as they lie.
 
-    Page 0 is the null page: the writes of inactive slots and of
-    unallocated tails land there, in no defined order (in place, two
-    rows that both run onto null entries also READ each other's writes
-    there; the engine lets no live row do that).
+    The layer scan CARRIES the whole [L, ...] pools beside ``h`` and scans
+    over (weights, layer index): as a scan's ``xs`` / ``ys`` they would be
+    rebuilt whole on every call; carried, they alias through every loop of
+    a program (tests/test_paged_cache_inplace.py). ``lead``: the leading
+    layers whose feed-forward is dense, before the scan, on the same pools'
+    first layers. int8 ``<Slot>Scale`` ride in ``params``.
 
-    In all three, the layer scan CARRIES the whole [L, ...] caches beside
-    ``h`` and scans over (weights, layer index): a scan's ``ys`` is a
-    fresh buffer that cannot alias its ``xs``, so caches passed that
-    way are rebuilt whole on every call — on every token, inside the
-    decode op's step loop. Carried, they alias from the dispatch's
-    gather to its write-back, and a step touches the rows it writes and
-    the bytes attention reads (tests/test_paged_cache_inplace.py holds
-    both to it). ``lead`` are the parameters of the model's leading
-    layers whose feed-forward is dense (``lead_ffn``): they run before
-    the scan, on the first layers of the same caches.
-
-    Latent attention attends two ways over its one cache: a prefill
-    window EXPANDS the latents it can see into per-head keys and
-    values, a block of keys at a time; a decode step ABSORBS the
-    expansion into its query and its output and reads the latents as
-    they lie. The dense view holds bitwise the same values the pools
-    do, so both forms see identical caches. A PREFILL WINDOW'S FOLD over
-    a block of keys (``_latent_expanded``; ``_gqa_blocked`` of a mixed
-    model's layers that keep the whole sequence) has two forms as a
-    decode op has, read off what the op is given (``prefill_in_kernel``):
-    where the backend runs the Pallas kernels and the heads are whole
-    lane tiles (a 192-wide key is padded to them), a block of
-    PREFILL_VISIT_KEYS positions is one call of ``prefill_fold``, which
-    keeps the [queries, keys] scores in VMEM and carries the running
-    maximum, sum and accumulator through HBM (a sixteenth of one score
-    block); everywhere else it is plain jax.numpy with its float32 scores
-    in HBM, and that form is the kernel's reference (PERF.md section 6,
-    PR 44). int8 ``<Slot>Scale``
-    companions ride along in ``params`` exactly as in the contiguous
-    runner (qmat).
-
-    A CACHE KIND is how long a layer's entries live. ``sequence``: as
-    long as the request, a page for every ``page_size`` positions,
-    reached through ``table`` [B, pages_per_seq]. ``window`` (a layer
-    that attends the query and the ``w - 1`` positions before it): a RING
-    of ``ring_table.shape[1]`` pages a row, position ``p`` at page ``(p //
-    page_size) % ring pages``, offset ``p % page_size``, so a row holds
-    its last ring's worth of positions and no more. A model whose
-    ``BlockKinds`` mix attention kinds (``attn_kinds`` / ``layer_kinds``)
-    has, for each kind, its own pools ``[layers of the kind, pages,
-    page_size, heads * width]`` (the kind's own head count; an entry lies
-    FLAT in its page: 4 heads of 192 are 6 lane tiles, where ``[4, 192]``
-    had the chip re-lay the whole pool on its way into and out of every
-    program, 11 of a decode program's 83 ms: PERF.md section 6, PR 33),
-    its own parameter stack (``stacks[prefix]``, shapes differ between
-    kinds) and its table; ``_stack_forward`` walks the pattern a RUN of
-    same-kind layers at a time: one scan a run of window layers, and the
-    layers that keep the whole sequence by their own number. A
-    prefill window through a window layer attends its own keys and the
-    ``w - 1`` before them (read from the ring BEFORE it is written), in
-    bands of two w-blocks a query block, and leaves its last real
-    positions in the ring; through a sequence layer it folds the row's
-    pages a block of keys at a time under a running softmax, so neither
-    ever holds [heads, window, kmax] scores. The dense form gathers the
-    window kinds' rings stacked, [layers, B, ring, ...], and of the layers
-    that keep the whole sequence, where they are not attended in place, a
-    view EACH, [B, g, kmax, d] with heads before positions
-    (``gather_layers``): a step reads its layer's view where it lies, and
-    those layers are taken by number, not by a scan.
-
-    ``state`` (a layer whose mixer is the selective state-space one,
-    ops/ssm.py): ONE entry a row for the row's life, reached through
-    ``state_table`` [B, 1]; the kind's two pools are the recurrent state
-    ``[layers, entries, N, C]`` float32 and the convolution's tail
-    ``[layers, entries, (k - 1) * C]``, the minor axis whole lane tiles as
-    everywhere. Entry 0 is the null entry. UNLIKE A PAGE, AN ENTRY IS NOT
-    PROTECTED BY THE LENGTH MASK: whatever it holds is the row's past. So
-    a prefill window that starts a row (``fresh``: the whole-prompt op; a
-    chunk whose offset is 0) starts from zeros and does not look at the
-    entry, scans the row's real positions alone (ssm.window) and writes
-    the state after position ``lens - 1``; a decode step reads, updates
-    and writes the live rows' entries where they lie, in the entries'
-    order (``_state_step``: the dense form has no view of this kind), and
-    a row that is not live writes nothing at all. A run of such layers is
-    one scan.
-
-    A STACK RUN SEVERAL TIMES A TOKEN (``BlockKinds.passes`` > 1): the
-    number of layers is the WEIGHTS', not the pools': the pools are
-    ``passes`` times as deep, and ``_stack_forward`` wraps the layer scan
-    in a scan over the passes that carries ``(h, pools)`` as the layer
-    scan does, so the program holds ONE copy of the layer body and the
-    pools alias through both loops. Pass ``s`` hands layer ``j`` cache
-    layer ``s * layers + j`` in every form; the final norm and the exit
-    gate close EVERY pass (``_close_pass``: scopes ``loop/pass``,
-    ``loop/norm``, ``loop/gate``) and ``logits_of`` takes the last pass's
-    output as it is. Prefill writes all ``passes * layers`` cache layers.
-    Such a model's cache is too deep for a dense view on the chip (192
-    layers of 16 rows of 1,040 positions: 26 GB), so there it decodes in
-    place or not at all; the dense form serves it on the CPU at a tiny
-    size. Its programs count the layer passes that ran and the positions
-    they attended (LOOP_STATS)."""
+    A CACHE KIND is how long a layer's entries live; a model whose
+    ``BlockKinds`` mix kinds (``attn_kinds`` / ``layer_kinds``) has for
+    each its own pools (an entry FLAT in its page, ``[.., heads *
+    width]``), parameter stack (``stacks[prefix]``) and table, and
+    ``_stack_forward`` walks the pattern a run of same-kind layers at a
+    time. ``sequence``: as long as the request, through ``table`` [B,
+    pages_per_seq]. ``window`` (a layer that attends the ``w - 1``
+    positions before the query): a RING of ``ring_table.shape[1]`` pages a
+    row, position ``p`` at page ``(p // page_size) % ring pages``. THE ONE
+    VIEW LEFT is this kind's: it has no kernel (a window and a learned
+    sink), so a decode dispatch gathers the rows' rings once
+    (``open_rings``), its steps write and attend that, and ``close_rings``
+    copies their entries back (0.08 GB in MiMo's share; ROADMAP.md Design
+    2). ``state`` (the state-space mixer, ops/ssm.py): ONE entry a row for
+    the row's life through ``state_table`` [B, 1], NOT protected by the
+    length mask: a window that starts a row starts from zeros (``fresh``),
+    a decode step updates the live rows' entries where they lie. A STACK
+    RUN SEVERAL TIMES A TOKEN (``BlockKinds.passes``): the pools are
+    ``passes`` times as deep as the weights, layer ``j`` of pass ``s`` at
+    ``s * layers + j``; the final norm and the exit gate close every pass."""
 
     def __init__(self, params, emb_w, fnorm, head, *, n_heads, n_kv,
                  base, eps, page_size, head_scale=None, moe_top_k=2,
@@ -1520,10 +1429,6 @@ class _PagedRunner:
             moe_top_k=moe_top_k)
         self.lead = lead
         self.stacks = stacks    # attention kind's prefix -> its layers
-        self.table = None       # [B, pages]: the sequence kinds' table,
-        self.in_place = frozenset()  # and the kinds (of a model that
-                                # mixes them) whose decode steps attend
-                                # their pages in place (_paged_decode)
         self.ring_table = None  # [B, ring pages]: the window kinds' table
         self.state_table = None  # [B, 1]: the state kinds' entry a row
         self.fresh = False      # a prefill window from position 0: the
@@ -1531,6 +1436,10 @@ class _PagedRunner:
         self.lens = None        # [B]: a prefill window's real tokens
         self.valid = None       # [B, T] bool: the tokens Stats counts
         self.pick_at = None     # [B]: the window position Picks reports
+        self.leaves_table = False  # a window whose positions may run to
+                                # ``kmax`` and beyond, where they are
+                                # dropped (the speculative round's; a
+                                # prefill window never leaves its table)
         self.seen = None        # positions a prefill window can see at
                                 # most, where known: latent attention
                                 # reads no page beyond them
@@ -1572,44 +1481,6 @@ class _PagedRunner:
                          v_all.astype(jnp.float32))
         return out.astype(q.dtype).reshape(
             b, t_len, self.n_heads * self.hd)
-
-    def _attend_masked(self, q, k_all, v_all, q_pos, k_pos=None,
-                       window=None, sink=None, head_major=False):
-        """GQA attention of queries q [B, T, heads, kd] at ``q_pos``
-        [B, T] over keys [B, K, g, kd] and values [B, K, g, vd]
-        (``head_major``: [B, g, K, *]) in the cache's type, accumulated
-        in float32. Key j of row b is position ``k_pos[b, j]`` (None: j;
-        negative: no key there); a query sees a key at or before itself
-        and, with ``window``, fewer than ``window`` positions back.
-        ``sink`` [heads]: one more column of the softmax's denominator a
-        head, that adds to nothing else."""
-        b, t = q_pos.shape
-        g, n_keys = (k_all.shape[1], k_all.shape[2]) if head_major \
-            else (k_all.shape[2], k_all.shape[1])
-        keys = "bgkd" if head_major else "bkgd"
-        f32 = jnp.float32
-        qg = q.reshape(b, t, g, self.n_heads // g, q.shape[-1])
-        kp = jnp.arange(n_keys, dtype=jnp.int32)[None] \
-            if k_pos is None else k_pos
-        back = q_pos[:, :, None] - kp[:, None, :]            # [B, T, K]
-        mask = (back >= 0) & (kp[:, None, :] >= 0)
-        if window is not None:
-            mask = mask & (back < window)
-        s = jnp.einsum(f"bqgrd,{keys}->bgrqk", qg, k_all,
-                       preferred_element_type=f32) * q.shape[-1] ** -0.5
-        s = jnp.where(mask[:, None, None], s, -1e30)
-        m = jnp.max(s, axis=-1, keepdims=True)
-        if sink is not None:
-            sk = sink.astype(f32).reshape(1, g, -1, 1, 1)
-            m = jnp.maximum(m, sk)
-        e = jnp.exp(s - m)
-        l = jnp.sum(e, axis=-1, keepdims=True)
-        if sink is not None:
-            l = l + jnp.exp(sk - m)
-        out = jnp.einsum(f"bgrqk,{keys}->bqgrd",
-                         (e / l).astype(v_all.dtype), v_all,
-                         preferred_element_type=f32)
-        return out.astype(q.dtype).reshape(b, t, -1)
 
     def _gqa_blocked(self, q, read_block, n_blocks, kb, q_pos, sink=None,
                      in_kernel=False):
@@ -1712,7 +1583,7 @@ class _PagedRunner:
         keys, values = (
             banded(e, ring[lyr, pg, off].reshape((b, w) + e.shape[2:]))
             for e, ring in zip(entries, rings))
-        out = self._attend_masked(
+        out = masked_attention(
             banded(q), keys, values, at.reshape(b * nb, w),
             k_pos=banded(at, prev), window=w, sink=sink)
         out = out.reshape(b, nb * w, -1)[:, :t]
@@ -1874,39 +1745,8 @@ class _PagedRunner:
         return out.astype(q_nope.dtype).reshape(b, t,
                                                 k.n_heads * k.v_dim)
 
-    def _latent_absorbed(self, p, q, view, q_pos):
-        """Latent attention of decode steps, absorbed: the key half of
-        the expansion moves into the query (``q_nope Wk^T``, then one
-        [kv_rank + rope_dim]-wide product with the cache as it lies),
-        the value half onto the attended latent. Same mathematics as
-        _latent_expanded; the cache is read once and never expanded.
-        Where the view is stored wider than the entry (whole lane tiles)
-        the query gets zeros against the view's zeros."""
-        k = self.kinds
-        q_nope, q_pe = q
-        b, t = q_pos.shape
-        w_up = self._kv_up(p)
-        f32 = jnp.float32
-        with jax.named_scope("mla/absorb"):
-            q_abs = jnp.einsum("bqhd,rhd->bqhr", q_nope,
-                               w_up[..., :k.nope_dim])
-            s = jnp.einsum("bqhc,bkc->bhqk", _as_stored(
-                jnp.concatenate([q_abs, q_pe], axis=-1), view), view,
-                preferred_element_type=f32) * k.softmax_scale
-            mask = (jnp.arange(view.shape[1], dtype=jnp.int32)[None, None]
-                    <= q_pos[:, :, None])
-            w = jax.nn.softmax(jnp.where(mask[:, None], s, -1e30), axis=-1)
-            # over the whole stored entry: slicing the latent out of the
-            # view would copy it; the other columns are dropped after
-            o_lat = jnp.einsum("bhqk,bkc->bqhc", w.astype(view.dtype),
-                               view, preferred_element_type=f32)
-            out = jnp.einsum("bqhr,rhd->bqhd",
-                             o_lat[..., :k.kv_rank].astype(view.dtype),
-                             w_up[..., k.nope_dim:])
-        return out.reshape(b, t, k.n_heads * k.v_dim)
-
     def _stack_forward(self, h, pools, q_pos, attend_write):
-        """The layers, shared by both forms: the leading layers one by
+        """The layers, shared by both entries: the leading layers one by
         one, then the scan over the stacked ones (where the model mixes
         attention kinds: a scan over each run of same-kind layers, in
         the pattern's order). The whole [L, ...] caches ride in the carry
@@ -2016,13 +1856,10 @@ class _PagedRunner:
             held, sliced = split(self.stacks[spec["stack"]])
             if spec["window"] is None and not _is_ssm(spec):
                 # a layer that keeps the whole sequence is taken by its
-                # own number, not a scan's: the dense form then holds a
-                # view a layer and reads it where it lies, where a
-                # traced index would copy 17,472 positions x 24 rows of
-                # keys and values out of a stacked view on every step
-                # (9 of a 31.6 ms step: PERF.md section 6, PR 33). Such
-                # layers are one in six of the pattern, so this is no
-                # loop over the stack
+                # own number, not a scan's (the chip's programs hold a
+                # kernel instance a layer so: PERF.md section 6, PR 33 and
+                # 42). Such layers are one in six of the pattern, so this
+                # is no loop over the stack
                 for j in range(start, start + count):
                     p = dict({s: w[j] for s, w in sliced.items()}, **held)
                     if held:
@@ -2119,7 +1956,14 @@ class _PagedRunner:
         def attend_write(p, q, entries, pools, lyr, kind=None):
             if kind is not None:
                 return attend_kind(p, q, entries, pools, lyr, kind)
-            pg = jnp.take_along_axis(table, q_pos // ps, axis=1)
+            if self.leaves_table:
+                # beyond kmax: a page past the pool's last, which the set
+                # drops
+                pg = jnp.where(q_pos < kmax, jnp.take_along_axis(
+                    table, jnp.minimum(q_pos, kmax - 1) // ps, axis=1),
+                    pools[0].shape[1])
+            else:
+                pg = jnp.take_along_axis(table, q_pos // ps, axis=1)
             pools = tuple(pl.at[lyr, pg, q_pos % ps].set(_as_stored(e, pl))
                           for pl, e in zip(pools, entries))
             if self.kinds.attention == "latent":
@@ -2141,201 +1985,70 @@ class _PagedRunner:
                                        attend_write)
         return (h,) + tuple(pools)
 
-    # -- dense form (decode / spec loops) --------------------------------
-    def pool_view(self, i, table):
-        """How the dense form sees pool ``i``: (its table, the pair of
-        methods that gather its view and write it back, a named scope
-        for the two, ``cache/<the kind's name>``, where the model has
-        several kinds, so that a trace tells them apart). A model with one
-        kind of layer: one stacked view [L, B, kmax, ...]; a window
-        kind: the rows' rings, stacked; the sequence kind of a model
-        that has both: a view a layer, heads before positions."""
-        for k, spec in enumerate(self.kinds.attn_kinds or ()):
-            if i in spec["pools"]:
-                scope = jax.named_scope("cache/" + spec["name"])
-                if _is_ssm(spec) or k in self.in_place:
-                    # no view: the steps run against the pool
-                    # (_state_step, _in_place_step)
-                    return (None, (lambda pool, _: pool,
-                                   lambda pool, seen, *_: seen), scope)
-                if spec["window"] is not None:
-                    return (self.ring_table,
-                            (self.gather, self.write_back_ring), scope)
-                return (table, (functools.partial(
-                    self.gather_layers, heads=spec["n_kv"]),
-                    self.write_back_layers), scope)
-        return (table, (self.gather, self.write_back),
-                contextlib.nullcontext())
+    # -- the decode step --------------------------------------------------
+    def _ring_pools(self):
+        """(kind's name, pool index) of every pool of a window kind."""
+        return [(spec["name"], i) for spec in self.kinds.attn_kinds or ()
+                if spec["window"] is not None and not _is_ssm(spec)
+                for i in spec["pools"]]
 
-    def gather(self, pages, table):
-        """[L, P, ps, *entry] pool -> dense [L, B, kmax, *entry] view of
-        each row's pages, in table order."""
-        lyr, b = pages.shape[0], table.shape[0]
-        return pages[:, table].reshape(
-            (lyr, b, table.shape[1] * self.page_size) + pages.shape[3:])
+    def open_rings(self, pools):
+        """What the steps of a dispatch carry: the pools, and in a window
+        kind's place the ONE view left, its rows' rings gathered through
+        ``ring_table`` in table order, [layers, B, ring, ...] (position p
+        at ``p % ring``): the kind has no kernel (a window and a learned
+        sink), so its steps attend the view, and ``close_rings`` writes
+        their entries back."""
+        cache, table = list(pools), self.ring_table
+        for name, i in self._ring_pools():
+            with jax.named_scope("cache/" + name):
+                lyr, b = pools[i].shape[0], table.shape[0]
+                cache[i] = pools[i][:, table].reshape(
+                    (lyr, b, table.shape[1] * self.page_size)
+                    + pools[i].shape[3:])
+        return tuple(cache)
 
-    def write_back(self, pages, dense, table, pos0, n):
-        """The pool with the ``n`` positions from ``pos0`` [B] of every
-        row copied out of the dense view: all that the dispatch's steps
-        wrote, so all in which the view differs from the pool it was
-        gathered from. The [L, B, n, *entry] entries go to ``[:,
-        table[row, p // page_size], p % page_size]``, the addressing
-        ``forward`` writes with. A position at or beyond ``kmax`` is
-        dropped, as ``forward_dense`` dropped its write. Rows' real
-        pages are disjoint by construction; every null-table entry
-        (inactive slots, unallocated tails) collides harmlessly on page
-        0, the null page: it holds garbage, and nothing reads it
-        unmasked."""
-        ps = self.page_size
-        kmax = table.shape[1] * ps
-        q_pos = pos0[:, None] + jnp.arange(n, dtype=jnp.int32)[None]
-        at = jnp.minimum(q_pos, kmax - 1)
-        # one index an entry, the layer's too: a slice across the layers
-        # has XLA re-lay the whole view with its layers innermost
-        lyr = jnp.arange(pages.shape[0])[:, None, None]
-        rows = jnp.arange(table.shape[0])[None, :, None]
-        entries = dense[lyr, rows, at[None]]
-        # the pool is not written (nor, where the chip keeps it in
-        # another layout, re-laid) before the entries are out and the
-        # view is dead, or both are live: with the pools donated the
-        # latent decode program's footprint read 12.69 GB with this
-        # barrier and 14.78 without while its pool was 576 wide (PERF.md
-        # section 6, PR 32); at whole lane tiles XLA's memory analysis
-        # reads 12.77 GB either way (PR 34); it stays for any pool the
-        # chip does re-lay
-        pages, entries = jax.lax.optimization_barrier((pages, entries))
-        # beyond kmax: a page past the pool's last, which the set drops
-        pg = jnp.where(q_pos < kmax,
-                       jnp.take_along_axis(table, at // ps, axis=1),
-                       pages.shape[1])
-        return pages.at[lyr, pg[None], (q_pos % ps)[None]].set(
-            entries, mode="drop")
+    def close_rings(self, pools, cache, pos0, n):
+        """The pools after ``n`` steps from ``pos0`` [B]: what the steps
+        carried, and a window kind's pool with those ``n`` positions of
+        every row copied out of the view of its rings (``open_rings``):
+        all that the steps wrote, so all in which the view differs from
+        the pool it was gathered from. Position p lies at ``p % ring`` of
+        the view and in page ``(p // page_size) % ring pages`` of the
+        row's ring, and no position is beyond it."""
+        back, table, ps = list(cache), self.ring_table, self.page_size
+        for name, i in self._ring_pools():
+            with jax.named_scope("cache/" + name):
+                pages = pools[i]
+                q_pos = pos0[:, None] + jnp.arange(n, dtype=jnp.int32)[None]
+                # one index an entry, the layer's too: a slice across the
+                # layers has XLA re-lay the whole view, layers innermost
+                lyr = jnp.arange(pages.shape[0])[:, None, None]
+                rows = jnp.arange(table.shape[0])[None, :, None]
+                entries = cache[i][
+                    lyr, rows, (q_pos % (table.shape[1] * ps))[None]]
+                # the pool is not written before the entries are out and
+                # the view is dead, or both are live (PERF.md section 6,
+                # PR 32)
+                pages, entries = jax.lax.optimization_barrier(
+                    (pages, entries))
+                pg = jnp.take_along_axis(
+                    table, (q_pos // ps) % table.shape[1], axis=1)
+                back[i] = pages.at[
+                    lyr, pg[None], (q_pos % ps)[None]].set(entries)
+        return back
 
-    def gather_layers(self, pages, table, heads):
-        """[L, P, ps, g * d] pool -> a view a layer, each [B, g, kmax, d]:
-        every row's pages in table order, heads before positions, which
-        is how a step's products want them, so that a step reads its
-        layer's view where it lies: no slice out of a stacked view, no
-        re-layout (the transposition is made here, once a dispatch)."""
-        b = table.shape[0]
-        return tuple(
-            jnp.moveaxis(pages[lyr, table].reshape(
-                b, table.shape[1] * self.page_size, heads, -1), 1, 2)
-            for lyr in range(pages.shape[0]))
-
-    def write_back_layers(self, pages, dense, table, pos0, n):
-        """write_back for the views ``gather_layers`` made."""
-        ps = self.page_size
-        kmax = table.shape[1] * ps
-        q_pos = pos0[:, None] + jnp.arange(n, dtype=jnp.int32)[None]
-        at = jnp.minimum(q_pos, kmax - 1)
-        rows = jnp.arange(table.shape[0])[:, None]
-        entries = jnp.stack([d[rows, :, at].reshape(at.shape + (-1,))
-                             for d in dense])
-        pages, entries = jax.lax.optimization_barrier((pages, entries))
-        pg = jnp.where(q_pos < kmax,
-                       jnp.take_along_axis(table, at // ps, axis=1),
-                       pages.shape[1])
-        lyr = jnp.arange(pages.shape[0])[:, None, None]
-        return pages.at[lyr, pg[None], (q_pos % ps)[None]].set(
-            entries, mode="drop")
-
-    def write_back_ring(self, pages, dense, table, pos0, n):
-        """write_back for a window kind's pool, view and ring ``table``:
-        position p lies at ``p % ring`` of the view and in page ``(p //
-        page_size) % table.shape[1]`` of the row's ring, and no position
-        is beyond it."""
-        ps = self.page_size
-        q_pos = pos0[:, None] + jnp.arange(n, dtype=jnp.int32)[None]
-        lyr = jnp.arange(pages.shape[0])[:, None, None]
-        rows = jnp.arange(table.shape[0])[None, :, None]
-        entries = dense[lyr, rows, (q_pos % (table.shape[1] * ps))[None]]
-        pages, entries = jax.lax.optimization_barrier((pages, entries))
-        pg = jnp.take_along_axis(table, (q_pos // ps) % table.shape[1],
-                                 axis=1)
-        return pages.at[lyr, pg[None], (q_pos % ps)[None]].set(entries)
-
-    def forward_dense(self, h, *dense_pos0_len):
-        *dense, pos0, t_len = dense_pos0_len
-        rows = jnp.arange(h.shape[0])
-        q_pos = pos0[:, None] + jnp.arange(t_len, dtype=jnp.int32)[None]
-
-        def attend_kind(p, q, entries, dense, lyr, kind):
-            """A layer of one of several attention kinds: its own views.
-            A window kind's view is the row's ring, position p at ``p %
-            ring``; index i then holds the last position at or before
-            the window's last query that falls there."""
-            spec = self.kinds.attn_kinds[kind]
-            sink = p["Sink"] if spec["sink"] else None
-            mine = [dense[i] for i in spec["pools"]]
-            if _is_ssm(spec):
-                out, mine = self._state_step(p, q, mine, lyr, spec)
-            elif kind in self.in_place:
-                with jax.named_scope("attn/" + spec["name"]):
-                    out, mine = self._in_place_step(
-                        paged_flat_decode, self.table, pos0,
-                        mine[0].shape[1])(q, entries, mine, lyr)
-            elif spec["window"] is None:
-                with jax.named_scope("attn/" + spec["name"]):
-                    # a view a layer [B, g, kmax, d] (gather_layers);
-                    # ``lyr`` is the layer's own number (_stack_forward)
-                    mine = [d[:lyr] + (d[lyr].at[
-                        rows[:, None], :, q_pos].set(e, mode="drop"),)
-                        + d[lyr + 1:] for d, e in zip(mine, entries)]
-                    out = self._attend_masked(
-                        q, mine[0][lyr], mine[1][lyr], q_pos, sink=sink,
-                        head_major=True)
-            else:
-                with jax.named_scope("attn/" + spec["name"]):
-                    ring = mine[0].shape[2]
-                    if ring < spec["window"] + t_len - 1:
-                        raise ValueError(
-                            f"a ring of {ring} positions cannot hold a "
-                            f"window of {spec['window']} behind {t_len} "
-                            "queries")
-                    mine = [d.at[lyr, rows[:, None], q_pos % ring].set(
-                        e.reshape(e.shape[:2] + (-1,)))
-                        for d, e in zip(mine, entries)]
-                    last = q_pos[:, -1:]
-                    k_pos = last - (last - jnp.arange(
-                        ring, dtype=jnp.int32)[None]) % ring
-                    out = self._attend_masked(
-                        q, *(d[lyr].reshape(d.shape[1:3]
-                                            + (spec["n_kv"], -1))
-                             for d in mine), q_pos, k_pos=k_pos,
-                        window=spec["window"], sink=sink)
-            dense = list(dense)
-            for i, d in zip(spec["pools"], mine):
-                dense[i] = d
-            return out, tuple(dense)
-
-        def attend_write(p, q, entries, dense, lyr, kind=None):
-            if kind is not None:
-                return attend_kind(p, q, entries, dense, lyr, kind)
-            dense = tuple(d.at[lyr, rows[:, None], q_pos].set(
-                _as_stored(e, d)) for d, e in zip(dense, entries))
-            if self.kinds.attention == "latent":
-                return (self._latent_absorbed(p, q, dense[0][lyr], q_pos),
-                        dense)
-            return (self._attend_math(q, *(d[lyr] for d in dense), q_pos,
-                                      t_len), dense)
-
-        h, dense = self._stack_forward(h, tuple(dense), q_pos,
-                                       attend_write)
-        return (h,) + tuple(dense)
-
-    # -- in-place form (a decode step against the pools, on the chip) ----
-    def _in_place_step(self, kernel, table, pos, n_pages):
+    def _paged_step(self, attention, table, pos, n_pages):
         """``attend(q, entries, pools, layer) -> (out [B, 1, heads * dv],
         the pools written)``: a decode step at positions ``pos`` [B] of
-        one layer against its pools themselves (K and V; a latent model's
-        one). The step's entry goes to ``[layer, table[row, pos //
-        page_size], pos % page_size]`` (the addressing ``forward`` and
-        ``write_back`` use; a position at or beyond ``kmax`` is dropped, a
-        null table entry lands on page 0)
-        and ``kernel`` (pallas_attention.py) attends the row's pages where
-        they lie, to the row's own length, ``pos + 1`` and ``kmax`` at
-        most."""
+        one layer of a sequence kind against its pools themselves (K and
+        V; a latent model's one). The step's entry goes to ``[layer,
+        table[row, pos // page_size], pos % page_size]`` (the addressing
+        ``forward`` writes with; a position at or beyond ``kmax`` is
+        dropped, a null table entry lands on page 0) and ``attention``
+        (pallas_attention.py: the kernel where its gate passes, the
+        reference where not) attends the row's pages where they lie, to
+        the row's own length, ``pos + 1`` and ``kmax`` at most."""
         ps = self.page_size
         kmax = table.shape[1] * ps
         at = jnp.minimum(pos, kmax - 1)
@@ -2349,30 +2062,86 @@ class _PagedRunner:
             pools = tuple(pl.at[lyr, pg, pos % ps].set(
                 e[:, 0].reshape((-1,) + pl.shape[3:]), mode="drop")
                 for pl, e in zip(pools, entries))
-            out = kernel(q[:, 0], *pools, lyr, table, lens)
+            out = attention(q[:, 0], *pools, lyr, table, lens)
             return out.reshape(out.shape[0], 1, -1), pools
 
         return attend
 
-    def forward_in_place(self, h, *pools_table_pos):
-        """One decode step of a model with one kind of layer against its
-        pools themselves (_in_place_step): plain GQA through
-        pallas_attention.paged_gqa_decode; latent attention, absorbed,
-        through paged_latent_decode over its one pool."""
-        *pools, table, pos = pools_table_pos
+    def decode_step(self, h, *cache_table_pos):
+        """One decode step of every row at positions ``pos`` [B] against
+        what ``open_rings`` gave, through ``table`` [B, pages_per_seq], the
+        sequence kinds': (h, *the same, written). A layer of a SEQUENCE
+        kind (plain GQA, a mixed model's layers that keep the whole
+        sequence, the latent pool) writes its entry into its page and calls
+        its paged attention function (_paged_step); a state kind steps its
+        entries where they lie (_state_step); a window kind writes and
+        attends the view of its rings. Latent attention is ABSORBED: the
+        key half of the expansion moves onto the query (``q_nope Wk^T``,
+        then one product with the entries as they lie, zeros against their
+        padding), the value half onto the attended latent: the mathematics
+        of _latent_expanded, the cache read once and never expanded."""
+        *cache, table, pos = cache_table_pos
         k = self.kinds
-        latent = k.attention == "latent"
-        attend = self._in_place_step(
-            functools.partial(paged_latent_decode, scale=k.softmax_scale,
-                              width=whole_tiles(k.kv_rank))
-            if latent else paged_gqa_decode, table, pos, pools[0].shape[1])
+        if k.attn_kinds is None:
+            attend = self._paged_step(
+                functools.partial(paged_latent_decode,
+                                  scale=k.softmax_scale,
+                                  width=whole_tiles(k.kv_rank))
+                if k.attention == "latent" else paged_gqa_decode,
+                table, pos, cache[0].shape[1])
+            q_pos = pos[:, None]
+        else:
+            # a window of one position, and a kind's addressing taken at
+            # its layers: as the mixed models' pinned programs hold them
+            rows = jnp.arange(h.shape[0])
+            q_pos = pos[:, None] + jnp.arange(1, dtype=jnp.int32)[None]
+
+        def attend_kind(p, q, entries, cache, lyr, kind):
+            """A layer of one of several attention kinds: its own pools,
+            or the view of its rings."""
+            spec = k.attn_kinds[kind]
+            sink = p["Sink"] if spec["sink"] else None
+            mine = [cache[i] for i in spec["pools"]]
+            if _is_ssm(spec):
+                out, mine = self._state_step(p, q, mine, lyr, spec)
+            elif spec["window"] is None:
+                with jax.named_scope("attn/" + spec["name"]):
+                    out, mine = self._paged_step(
+                        paged_flat_decode if sink is None else
+                        functools.partial(paged_flat_decode, sink=sink),
+                        table, pos, mine[0].shape[1])(
+                            q, entries, mine, lyr)
+            else:
+                # the row's ring, position p at ``p % ring``; index i then
+                # holds the last position at or before the query that
+                # falls there
+                with jax.named_scope("attn/" + spec["name"]):
+                    ring = mine[0].shape[2]
+                    if ring < spec["window"]:
+                        raise ValueError(
+                            f"a ring of {ring} positions cannot hold a "
+                            f"window of {spec['window']}")
+                    mine = [d.at[lyr, rows[:, None], q_pos % ring].set(
+                        e.reshape(e.shape[:2] + (-1,)))
+                        for d, e in zip(mine, entries)]
+                    last = q_pos[:, -1:]
+                    k_pos = last - (last - jnp.arange(
+                        ring, dtype=jnp.int32)[None]) % ring
+                    out = masked_attention(
+                        q, *(d[lyr].reshape(d.shape[1:3]
+                                            + (spec["n_kv"], -1))
+                             for d in mine), q_pos, k_pos=k_pos,
+                        window=spec["window"], sink=sink)
+            cache = list(cache)
+            for i, d in zip(spec["pools"], mine):
+                cache[i] = d
+            return out, tuple(cache)
 
         def attend_write(p, q, entries, pools, lyr, kind=None):
-            if not latent:
+            if kind is not None:
+                return attend_kind(p, q, entries, pools, lyr, kind)
+            if k.attention != "latent":
                 return attend(q, entries, pools, lyr)
-            # _latent_absorbed's mathematics with the view's two products
-            # and its softmax in the kernel: the key half of the expansion
-            # on the query, the value half on the attended latent
             q_nope, q_pe = q
             w_up = self._kv_up(p)
             with jax.named_scope("mla/absorb"):
@@ -2388,9 +2157,9 @@ class _PagedRunner:
                     w_up[..., k.nope_dim:])
             return out.reshape(out.shape[:2] + (-1,)), pools
 
-        h, pools = self._stack_forward(h, tuple(pools), pos[:, None],
+        h, cache = self._stack_forward(h, tuple(cache), q_pos,
                                        attend_write)
-        return (h,) + tuple(pools)
+        return (h,) + tuple(cache)
 
     def _close_pass(self, h):
         """(h, gate): what closes EVERY pass of a stack that is run
@@ -2495,19 +2264,20 @@ def stats_names(kinds):
 
 
 def decode_in_place(attention, attn_kinds, pool_shapes, kind=None):
-    """Whether a decode op of a model with these block kinds, over pools
-    of these shapes, runs its steps against the pools themselves and not
-    against a dense view: read off what the op is given, by
-    ``_paged_decode`` where it lowers and by whoever builds its program
-    and wants to know which form that is. A model with one kind of layer
-    (``_PagedRunner.forward_in_place``), on a backend that runs the Pallas
-    kernels: plain GQA with K and V pools of one shape and whole-tile
-    heads; latent attention with ONE pool whose entries lie flat at whole
-    lane tiles. A model that mixes kinds of layer is asked KIND BY KIND
-    (``kind`` None: whether any is): in place where the kind keeps the
-    whole sequence, has attention for its mixer and no sink, and its two
-    pools hold their entries flat at whole lane tiles (paged_flat_usable);
-    its other kinds keep their form."""
+    """Whether the decode program of a model with these block kinds, over
+    pools of these shapes, attends through a Pallas kernel: a REPORT, for
+    whoever builds the program and wants to say so (a decode bundle's
+    ``in_place``, the engine's ``decode_in_place_total``). It chooses
+    nothing: every decode step runs against the pools, and each paged
+    attention call asks the same gate itself (pallas_attention.py). A
+    model with one kind of layer, on a backend that runs the kernels:
+    plain GQA with K and V pools of one shape and whole-tile heads
+    (paged_gqa_usable); latent attention with ONE pool whose entries lie
+    flat at whole lane tiles (paged_latent_usable). A model that mixes
+    kinds of layer is asked KIND BY KIND (``kind`` None: whether any
+    does): the kind that keeps the whole sequence, has attention for its
+    mixer and no sink, its two pools' entries flat at whole lane tiles
+    (paged_flat_usable); a window kind and a state kind have no kernel."""
     if attn_kinds is None and attention == "latent":
         return paged_latent_usable(pool_shapes)
     if attention != "gqa":
@@ -2547,8 +2317,8 @@ def prefill_in_kernel(attention, attn_kinds, latent_widths, pool_shapes,
     ``n_pages`` pages (``seen``: the positions it can see at most, where
     known), folds its attention over the whole sequence through the
     kernel ``prefill_fold`` and not in plain jax.numpy: read off what the
-    op is given, as ``decode_in_place`` is, by ``_PagedRunner.forward``
-    where it lowers and by whoever builds its program. Latent attention
+    op is given, by ``_PagedRunner.forward`` where it lowers and by
+    whoever builds its program. Latent attention
     (``latent_widths``: its heads' own key and value widths): both whole
     lane tiles. A model that mixes kinds of layer is asked KIND BY KIND
     (``kind`` None: whether any is): the kind that keeps the whole
@@ -2556,7 +2326,7 @@ def prefill_in_kernel(attention, attn_kinds, latent_widths, pool_shapes,
     head whole lane tiles (a key head is padded to them). And, for both,
     a backend that runs the kernel and a window and a block of keys that
     cut into its tiles. A model with one kind of plain GQA layer attends
-    a dense view (``_attend_math``) and is not asked."""
+    a layer's gathered rows (``_attend_math``) and is not asked."""
     if attn_kinds is None:
         if attention != "latent":
             return False
@@ -2635,45 +2405,20 @@ def _paged_prefill(run, tokens, lens, offsets, table, pools):
 
 def _paged_decode(run, tok, pos, table, pools, steps, extras=False):
     """``steps`` greedy steps of every slot: the body of every paged
-    decode op. Returns (tokens [B, steps], pools) and, with ``extras``,
-    each step's float32 logits [B, steps, V], its routed picks [B, steps,
-    routed layers, K] and the dispatch's Stats."""
+    decode op. The steps carry the pools themselves (a window kind's: the
+    view of its rings, ``open_rings`` / ``close_rings``) and each layer
+    writes its entry where it lies; what comes back is what was carried.
+    Returns (tokens [B, steps], pools) and, with ``extras``, each step's
+    float32 logits [B, steps, V], its routed picks [B, steps, routed
+    layers, K] and the dispatch's Stats."""
     pos = pos.astype(jnp.int32)
-    kinds, shapes = run.kinds, [pl.shape for pl in pools]
-    in_place = kinds.attn_kinds is None \
-        and decode_in_place(kinds.attention, None, shapes)
-    if in_place:
-        # in-place form: the steps carry the pools themselves, each layer
-        # writes its entry into its page and attends the pages where they
-        # lie; what comes back is what was carried
-        cache = tuple(pools)
-
-        def forward(h, cache, pos):
-            return run.forward_in_place(h, *cache, table, pos)
-    else:
-        # dense form: pool -> dense gather once, ``steps`` steps that
-        # carry the dense caches in place, their entries written back
-        # (_PagedRunner); a model that mixes kinds of layer has a form a
-        # kind, and a kind in place has no view: its pools are carried
-        run.table = table
-        run.in_place = frozenset(
-            k for k in range(len(kinds.attn_kinds or ()))
-            if decode_in_place(kinds.attention, kinds.attn_kinds, shapes, k))
-        views = [run.pool_view(i, table) for i in range(len(pools))]
-        cache = []
-        for pl, (tb, (gather, _), scope) in zip(pools, views):
-            with scope:
-                cache.append(gather(pl, tb))
-        cache = tuple(cache)
-
-        def forward(h, cache, pos):
-            return run.forward_dense(h, *cache, pos, 1)
-
+    cache = run.open_rings(pools)
     run.valid = table[:, :1] > 0        # a live row owns a real first page
 
     def step(carry, _):
         tok, pos, cache, stats = carry
-        h, *cache = forward(run.embed(tok[:, None]), cache, pos)
+        h, *cache = run.decode_step(run.embed(tok[:, None]), *cache, table,
+                                    pos)
         logits = run.logits_of(h[:, 0])
         nxt = jnp.argmax(logits, axis=-1).astype(tok.dtype)
         if not extras:
@@ -2685,14 +2430,7 @@ def _paged_decode(run, tok, pos, table, pools, steps, extras=False):
     stats0 = jnp.zeros_like(run.stats(False)) if extras else None
     (_, _, cache, stats), ys = jax.lax.scan(
         step, (tok, pos, cache, stats0), None, length=steps)
-    if in_place:
-        pools = list(cache)
-    else:
-        back = []
-        for pl, d, (tb, (_, write_back), scope) in zip(pools, cache, views):
-            with scope:
-                back.append(write_back(pl, d, tb, pos, steps))
-        pools = back
+    pools = run.close_rings(pools, cache, pos, steps)
     if not extras:
         return jnp.moveaxis(ys, 0, 1), pools
     return (jnp.moveaxis(ys[0], 0, 1), pools, jnp.moveaxis(ys[1], 0, 1),
@@ -2929,21 +2667,23 @@ def _llama_paged_spec_step(ctx, ins, attrs):
         eps=attrs.get("draft_epsilon", attrs.get("epsilon", 1e-6)),
         page_size=page_size, head_scale=d_hscale)
 
-    # dense form for the whole round (one gather and one write-back of
-    # the round's entries per pool)
-    dkd, dvd = d_run.gather(dkp, table), d_run.gather(dvp, table)
-    tkd, tvd = t_run.gather(tkp, table), t_run.gather(tvp, table)
+    # the round runs against the pools: its windows of several tokens
+    # through ``forward`` (a window's entries into its pages, each layer's
+    # pages of the row attended), the draft's single steps through the
+    # decode step. A row near the end of its table runs past ``kmax``:
+    # those positions are dropped
+    d_run.leaves_table = t_run.leaves_table = True
 
     # 1. draft proposes gamma tokens autoregressively per row
-    dh, dkd, dvd = d_run.forward_dense(
-        demb[jnp.stack([prev, cur], axis=1)], dkd, dvd, pos - 1, 2)
+    dh, dkp, dvp = d_run.forward(
+        demb[jnp.stack([prev, cur], axis=1)], dkp, dvp, table, pos - 1, 2)
     dl = d_run.logits_of(dh[:, 1])
     drafts = []
     d_tok = None
     for i in range(gamma):
         if i > 0:
-            dh, dkd, dvd = d_run.forward_dense(
-                demb[d_tok][:, None], dkd, dvd, pos + i, 1)
+            dh, dkp, dvp = d_run.decode_step(
+                demb[d_tok][:, None], dkp, dvp, table, pos + i)
             dl = d_run.logits_of(dh[:, 0])
         d_tok = jnp.argmax(dl, axis=-1).astype(cur.dtype)
         drafts.append(d_tok)
@@ -2951,8 +2691,8 @@ def _llama_paged_spec_step(ctx, ins, attrs):
 
     # 2. target scores cur + all gamma proposals in ONE forward
     cand = jnp.concatenate([cur[:, None], D], axis=1)    # [B, gamma+1]
-    th, tkd, tvd = t_run.forward_dense(emb_w[cand], tkd, tvd, pos,
-                                       gamma + 1)
+    th, tkp, tvp = t_run.forward(emb_w[cand], tkp, tvp, table, pos,
+                                 gamma + 1)
     G = jnp.argmax(t_run.logits_of(th), axis=-1).astype(cur.dtype)
 
     # 3. per-row longest accepted prefix; row b's emission is
@@ -2960,14 +2700,8 @@ def _llama_paged_spec_step(ctx, ins, attrs):
     match = (D == G[:, :gamma]).astype(jnp.int32)
     m = jnp.sum(jnp.cumprod(match, axis=1), axis=1)
     return {"Emitted": [G], "Accepted": [(m + 1).astype(jnp.int32)],
-            "KPagesOut": [t_run.write_back(tkp, tkd, table, pos,
-                                           gamma + 1)],
-            "VPagesOut": [t_run.write_back(tvp, tvd, table, pos,
-                                           gamma + 1)],
-            "DraftKPagesOut": [d_run.write_back(dkp, dkd, table, pos - 1,
-                                                gamma + 1)],
-            "DraftVPagesOut": [d_run.write_back(dvp, dvd, table, pos - 1,
-                                                gamma + 1)]}
+            "KPagesOut": [tkp], "VPagesOut": [tvp],
+            "DraftKPagesOut": [dkp], "DraftVPagesOut": [dvp]}
 
 
 @register_op("llama_decoder_stack")

@@ -126,11 +126,11 @@ _DECODE_COUNTERS = (
     # own deleted and replaced them all with zeroed ones
     "pools_consumed_total", "pools_lost_total",
     # ticked beside decode_batches_total for every decode dispatch whose
-    # program runs its steps against the pools themselves and not a dense
-    # view of them (the decode bundle's ``in_place``: plain GQA pools, a
-    # mixed model's flat sequence kind or a latent model's one pool, on a
-    # backend with the paged kernels); its share of decode_batches_total
-    # is how often that form engages
+    # program attends its pages through a Pallas kernel (the decode
+    # bundle's ``in_place``: plain GQA pools, a mixed model's flat
+    # sequence kind or a latent model's one pool, on a backend with the
+    # paged kernels; everywhere else the same step calls their jax.numpy
+    # reference): equal to decode_batches_total on the chip, 0 on a CPU
     "decode_in_place_total",
     # and its sibling for prefill: ticked beside prefill_dispatch_total
     # and chunk_prefill_total for every whole-prompt or chunk dispatch

@@ -1,13 +1,13 @@
-"""A latent model's decode program in both of its forms.
+"""A latent model's decode program, the reference or the kernel behind it.
 
-On a backend that runs the Pallas kernels (the chip; here the hook
-``pallas_attention._FORCE_INTERPRET``) a decode op over a latent model's
-ONE pool, its entry stored at whole lane tiles, takes the in-place form
-(PERF.md section 6, PR 45): the steps carry the pool, each layer writes
-its entry into its page and ``paged_latent_decode`` attends the row's pages
-to the row's own length, where the dense form gathers a view of ``kmax``
-once a dispatch, attends all of it every step and writes the steps'
-entries back. tests/test_latent_moe.py (under hyper-connections) and
+A decode op over a latent model's ONE pool carries the pool: each layer
+writes its entry into its page and ``paged_latent_decode`` attends the
+row's pages to the row's own length (PERF.md section 6, PR 45 and 46). On
+a backend that runs the Pallas kernels (the chip; here the hook
+``pallas_attention._FORCE_INTERPRET``), over an entry stored at whole lane
+tiles, that call is the kernel; everywhere else the jax.numpy reference of
+the same promise, which gathers a layer's rows where it attends them.
+tests/test_latent_moe.py (under hyper-connections) and
 tests/test_latent_share.py (the plain residual path, a share of the
 router) run these checks on their own model, weights and reference; the
 kernel itself is held in tests/test_paged_gqa_decode.py.
@@ -39,11 +39,12 @@ def check_a_dispatch_in_both_forms(run_op, cfg, prompts, lens, table,
                                    empty_pool, monkeypatch, limit,
                                    steps=4):
     """Rows of unequal length prefilled, then ``steps`` steps of every
-    slot in the dense form and in place (float32: the same sums in
-    another order): the same tokens and picks, logits within the
-    comparison's limit, and THE SAME POOL: the same positions written (the
-    live rows' steps, nothing else off the null page) with the same
-    entries, zeros behind ``entry_dim`` in both."""
+    slot behind the reference (``dense``) and behind the kernel
+    (``in_place``) (float32: the same sums in another order): the same
+    tokens and picks, logits within the comparison's limit, and THE SAME
+    POOL: the same positions written (the live rows' steps, nothing else
+    off the null page) with the same entries, zeros behind ``entry_dim``
+    in both."""
     page_size = empty_pool[0].shape[2]
     pre = run_op(T._block_paged_prefill, Tokens=prompts, Lens=lens,
                  Table=table, Pools=empty_pool)
@@ -83,27 +84,33 @@ def check_a_dispatch_in_both_forms(run_op, cfg, prompts, lens, table,
 
 
 def check_the_program_holds_no_view(cfg, geometry, monkeypatch):
-    """The decode program's text in both forms: dense, the stacked view
-    ``[layers, rows, kmax, entry]`` and the scores over every position,
-    ``[rows, heads, 1, kmax]``; in place, neither, no array with a
-    ``kmax`` axis of the pool's entries at all, and ONE instance of the
+    """The decode program's text, reference or kernel behind the call: no
+    view of the layers, ``[layers, rows, kmax, entry]``, in either.
+    Behind the reference a layer's rows as they are gathered and the
+    scores over every position; behind the kernel neither, no array with
+    a ``kmax`` axis of the pool's entries at all, and ONE instance of the
     kernel a layer body (the leading layer's and the scan's) under the
     absorbed form's scope."""
     rows = geometry["max_batch"]
     kmax = geometry["pages_per_seq"] * geometry["page_size"]
-    view = f"tensor<{cfg.n_layers}x{rows}x{kmax}x{cfg.stored_dim}xf32>"
-    scores = f"tensor<{rows}x{cfg.n_heads}x1x{kmax}xf32>"
+    views = [f"tensor<{n}x{rows}x{kmax}x{cfg.stored_dim}xf32>"
+             for n in (1, cfg.n_layers)]
+    gathered = f"tensor<{rows}x{kmax}x1x{cfg.stored_dim}xf32>"
+    scores = f"tensor<{rows}x1x{cfg.n_heads}x1x{kmax}xf32>"
     for hook in (False, True):
         with monkeypatch.context() as m:
-            m.setattr(pa, "_FORCE_INTERPRET", hook)
+            if hook:
+                kernel_on(m, geometry["page_size"])
             programs = cfg.build_paged_programs(**geometry)
             assert programs.decode["in_place"] is hook
             lowered = program_text.lower_bundle(programs.decode, 1)
         text = lowered.as_text()
-        assert (view in text, scores in text) == (not hook,) * 2
+        assert not [v for v in views if v in text]
+        assert (gathered in text, scores in text) == (not hook,) * 2
         if hook:
             wide = re.findall(
-                rf"tensor<(?:\d+x)*{kmax}x{cfg.stored_dim}xf32>", text)
+                rf"tensor<(?:\d+x)*{kmax}x(?:\d+x)*{cfg.stored_dim}xf32>",
+                text)
             assert not wide, sorted(set(wide))
         # the interpreter leaves the kernel's name in its scopes alone
         assert ("mla/absorb/paged_latent_decode"
@@ -113,11 +120,11 @@ def check_the_program_holds_no_view(cfg, geometry, monkeypatch):
 def check_an_engines_tokens_and_its_counter(make_engine, cfg,
                                             reference_logits, monkeypatch,
                                             hook, page_size):
-    """An engine built where the kernel runs (``hook``) decodes every
-    dispatch in place, ``decode_in_place_total == decode_batches_total``,
-    and one built where it does not runs the dense form and counts 0; in
-    both its tokens are the reference's, a request alone and the same
-    request co-scheduled, and no pool is lost."""
+    """An engine built where the kernel runs (``hook``) attends every
+    dispatch through it, ``decode_in_place_total == decode_batches_total``,
+    and one built where it does not counts 0; in both its tokens are the
+    reference's, a request alone and the same request co-scheduled, and no
+    pool is lost."""
     with monkeypatch.context() as m:
         if hook:
             kernel_on(m, page_size)

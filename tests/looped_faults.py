@@ -33,7 +33,7 @@ def _cache_layer_is(of):
 
 def _reads_the_pass_before(mp, cfg):
     """A decode step of pass ``s`` writes its own cache layers and ATTENDS
-    pass ``s - 1``'s (the in-place form's kernel is handed ``layer -
+    pass ``s - 1``'s (the step's paged attention call is handed ``layer -
     layers``); prefill is left alone."""
     kernel, n = T.paged_gqa_decode, cfg.n_layers
 
@@ -61,7 +61,7 @@ def _norm_after_the_last_pass_alone(mp, cfg):
 
 
 FAULTS = {
-    # needs the in-place form (the chip, or the interpreter hook)
+    # in the decode step's paged attention call, kernel or reference
     "a pass reads the pass before's cache": _reads_the_pass_before,
     "one cache shared by all passes": _cache_layer_is(lambda lyr, n: lyr % n),
     "the final norm after the last pass alone":

@@ -343,12 +343,9 @@ def test_the_shares_add_up_to_the_uncut_layer():
             v = w_all[f"window.{suffix}"][0]
             p[slot] = v[4 * share:4 * share + 4] \
                 if slot in T._EXPERT_SLOTS else v
-        run = T._PagedRunner({}, None, None, None, n_heads=CFG.n_heads,
-                             n_kv=4, base=0, eps=CFG.norm_eps,
-                             page_size=PS, kinds=kinds)
 
         def attend(q, kv):       # over this window alone, no cache
-            return run._attend_masked(q, *kv, pos, window=CFG.window,
+            return T.masked_attention(q, *kv, pos, window=CFG.window,
                                       sink=p["Sink"])
 
         y, (load, idx) = T.block_forward(kinds, p, x, pos, attend)

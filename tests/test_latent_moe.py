@@ -199,7 +199,9 @@ def test_prefill_in_chunks_writes_what_the_whole_prompt_does():
 
 
 def test_absorbed_attention_is_expanded_attention():
-    """One window through both forms of the runner over the same cache."""
+    """One window of three tokens through ``forward``, expanded, and the
+    same tokens through three decode steps, absorbed, over the same
+    cache."""
     run = T._block_runner(op_inputs(), CFG.block_attrs(PS))
     pool = jax.random.normal(jax.random.PRNGKey(3),
                              (CFG.n_layers, 12, PS, CFG.entry_dim)) * 0.5
@@ -208,12 +210,17 @@ def test_absorbed_attention_is_expanded_attention():
     pos0 = jnp.asarray([6, 11], jnp.int32)
     h = run.embed(jnp.asarray(RNG.randint(0, CFG.vocab_size, (2, 3))))
     paged, pool2 = run.forward(h, pool, table, pos0, 3)
-    dense, dense2 = run.forward_dense(h, run.gather(pool, table), pos0, 3)
-    np.testing.assert_allclose(np.asarray(paged), np.asarray(dense),
+    stepped, pool3 = [], pool
+    for i in range(3):
+        out, pool3 = run.decode_step(h[:, i:i + 1], pool3, table, pos0 + i)
+        stepped.append(out)
+    np.testing.assert_allclose(np.asarray(paged),
+                               np.asarray(jnp.concatenate(stepped, axis=1)),
                                rtol=2e-5, atol=2e-5)
-    np.testing.assert_allclose(
-        np.asarray(run.gather(pool2, table))[:, :, :14],
-        np.asarray(dense2)[:, :, :14], rtol=0, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(pool2)[:, 1:],
+                               np.asarray(pool3)[:, 1:], rtol=0, atol=1e-5)
+    assert not np.array_equal(np.asarray(pool3)[:, 1:],
+                              np.asarray(pool)[:, 1:])
 
 
 def test_expanded_attention_walks_its_key_blocks(monkeypatch):

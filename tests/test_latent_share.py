@@ -351,7 +351,8 @@ def test_the_shares_add_up_to_the_uncut_layer():
             run = T._PagedRunner(p, None, None, None, n_heads=CFG.n_heads,
                                  n_kv=CFG.n_heads, base=0, eps=CFG.norm_eps,
                                  page_size=PS, kinds=kinds)
-            return run._latent_absorbed(p, q, entries[0], pos)
+            return run._latent_expanded(p, q, lambda i: entries[0], 1, 9,
+                                        pos)
         return fn
 
     total, picks = jnp.zeros_like(common), []

@@ -2,7 +2,8 @@
 pass with keys and values of its own (models/looped.py), through
 DecodeEngine at a tiny size on the CPU: the engine's own logits against
 the plain reference (benchmark/reference/looped.py) in float32 and bf16,
-in the dense form and in the in-place form (the interpreter hook), the
+behind the paged attention call's reference and behind its kernel (the
+interpreter hook), the
 depth and the order of its cache, each way the loop could be wrong and
 still look right (looped_faults.py) failing the builder's comparison, and
 an engine whose POOL, not its slots, bounds the batch."""
@@ -116,7 +117,7 @@ def test_the_pools_are_passes_times_as_deep_as_the_weights(engine):
     assert programs.stats == LOOP_STATS == stats_names(BlockKinds(
         n_heads=4, passes=3))
     assert LOOP_STATS[:len(PAGED_STATS)] == PAGED_STATS
-    assert not programs.decode["in_place"]         # the CPU: dense form
+    assert not programs.decode["in_place"]         # the CPU: no kernel
     gb = programs.decode["program"].global_block()
     assert tuple(gb.vars["blocks.wq"].shape) == (2, 32, 32)
 
@@ -297,7 +298,7 @@ def test_a_fault_in_the_engine_fails_the_builders_comparison(
     the cell's ``correct``, on an engine built WITH the fault against the
     clean reference: it returns findings (float32 here, so the limit is
     float32's; the chip's is set under the same plants' readings at the
-    published sizes, PERF.md section 4). In the in-place form, as on the
+    published sizes, PERF.md section 4). Behind the kernel, as on the
     chip. The pages held a request before the probes, as after a
     window."""
     monkeypatch.setattr(pa, "_FORCE_INTERPRET", True)

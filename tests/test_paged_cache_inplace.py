@@ -4,19 +4,15 @@
 and updates them where they lie. The form it replaced passed them as the
 scan's ``xs`` and rebuilt them as its ``ys``: a fresh buffer a call, so
 the decode op copied its whole dense cache on every token (PERF.md
-section 6, PR 25). Two guards:
+section 6, PR 25). Held by structure: no cache- or pool-shaped array is an
+``xs`` or ``ys`` of a scan in any of the ops' jaxprs, and the compiled
+module holds no whole-cache ``copy`` inside a loop.
 
-- structure: no cache- or pool-shaped array is an ``xs`` or ``ys`` of a
-  scan in any of the four ops' jaxprs, and the compiled module holds no
-  whole-cache ``copy`` inside a loop;
-- bit parity: the replaced form, frozen below as ``_XsYsRunner``, gives
-  the same tokens and the same pools, bit for bit.
-
-A dense-form dispatch writes back the entries it wrote, not its whole
-dense view (PERF.md section 6, PR 28). The same two guards: no write
-into a pool outside the step loop has an update of the view's size, and
-the whole-view scatter, frozen below as ``_WholeViewRunner``, gives the
-same tokens and the same pools on every page but the null one.
+A decode or speculative dispatch runs against the pools themselves (PR
+46: no view of a sequence kind, nothing gathered, nothing written back).
+Held by value: the pools that come back differ from the pools given at
+exactly the live rows' step positions under ``kmax``, the null page
+aside.
 """
 import re
 
@@ -47,72 +43,6 @@ PREV = np.array([9, 2, 0, 41], np.int32)
 # past KMAX (18, 19, then 20 and up, which are dropped). Row 1 runs off
 # its allocated pages onto a null entry of its table (position 16).
 POS_END = np.array([7, 13, 1, 18], np.int32)
-
-
-class _XsYsRunner(T._PagedRunner):
-    """The form this repo ran until PR 25, kept as the plain reference:
-    the layer scan takes each layer's cache as ``xs`` and stacks the
-    updated caches as ``ys``."""
-
-    def _stack_forward(self, h, k_caches, v_caches, q_pos, t_len,
-                       attend_write):
-        def layer(h, xs):
-            p, kc, vc = xs
-            caches = {}
-
-            def attend(q, k, v):
-                out, caches["k"], caches["v"] = attend_write(
-                    q, k, v, kc, vc)
-                return out
-
-            h = T.decoder_block(p, h, n_heads=self.n_heads,
-                                n_kv=self.n_kv, base=self.base,
-                                eps=self.eps, pos=q_pos, attend_fn=attend,
-                                moe_top_k=self.moe_top_k)
-            return h, (caches["k"], caches["v"])
-
-        h, (k_caches, v_caches) = jax.lax.scan(
-            layer, h, (self.params, k_caches, v_caches))
-        return h, k_caches, v_caches
-
-    def forward(self, h, k_pages, v_pages, table, pos0, t_len):
-        b, kmax = h.shape[0], table.shape[1] * self.page_size
-        q_pos = pos0[:, None] + jnp.arange(t_len, dtype=jnp.int32)[None]
-
-        def attend_write(q, k, v, kp, vp):
-            pg = jnp.take_along_axis(table, q_pos // self.page_size, axis=1)
-            kp2 = kp.at[pg, q_pos % self.page_size].set(k)
-            vp2 = vp.at[pg, q_pos % self.page_size].set(v)
-            k_all = kp2[table].reshape(b, kmax, self.n_kv, self.hd)
-            v_all = vp2[table].reshape(b, kmax, self.n_kv, self.hd)
-            return self._attend_math(q, k_all, v_all, q_pos, t_len), kp2, vp2
-
-        return self._stack_forward(h, k_pages, v_pages, q_pos, t_len,
-                                   attend_write)
-
-    def forward_dense(self, h, k_dense, v_dense, pos0, t_len):
-        rows = jnp.arange(h.shape[0])
-        q_pos = pos0[:, None] + jnp.arange(t_len, dtype=jnp.int32)[None]
-
-        def attend_write(q, k, v, kd, vd):
-            kd2 = kd.at[rows[:, None], q_pos].set(k)
-            vd2 = vd.at[rows[:, None], q_pos].set(v)
-            return self._attend_math(q, kd2, vd2, q_pos, t_len), kd2, vd2
-
-        return self._stack_forward(h, k_dense, v_dense, q_pos, t_len,
-                                   attend_write)
-
-
-class _WholeViewRunner(T._PagedRunner):
-    """The write-back this repo ran until PR 28, kept as the plain
-    reference: the whole dense view goes back through the table, every
-    null-table entry colliding on page 0."""
-
-    def write_back(self, pages, dense, table, pos0, n):
-        lyr, b = dense.shape[0], dense.shape[1]
-        return pages.at[:, table].set(
-            dense.reshape((lyr, b, table.shape[1], self.page_size)
-                          + dense.shape[3:]))
 
 
 def _model(key, n_layers, prefix="", quant=False):
@@ -184,7 +114,7 @@ PAGED_OPS = ("llama_paged_decode", "llama_paged_prefill",
 
 
 def _cache_shapes(ins):
-    """Every pool shape among the inputs, and its dense view's."""
+    """Every pool shape among the inputs, and a view of its layers'."""
     shapes = set()
     for name, x in ins.items():
         if name.endswith("Pages"):
@@ -321,179 +251,41 @@ def test_latent_cache_is_carried_not_streamed(op_name):
     assert not _scan_cache_use(jax.make_jaxpr(fn)(ins), expert_stacks)[0]
 
 
-def test_structure_check_sees_the_replaced_form(monkeypatch):
-    """The detector is not vacuous: the frozen xs/ys form trips both
-    halves of it, on this backend too."""
-    monkeypatch.setattr(T, "_PagedRunner", _XsYsRunner)
-    streamed, _, copies = _structure(*_case("llama_paged_decode"))
-    assert streamed and copies
-
-
-@pytest.mark.parametrize("op_name,steps,quant", [
-    ("llama_paged_decode", 1, False), ("llama_paged_decode", 4, False),
-    ("llama_paged_decode", 1, True), ("llama_paged_decode", 4, True),
-    ("llama_paged_prefill", 1, False), ("llama_paged_prefill", 1, True),
-    ("llama_paged_prefill_chunk", 1, False),
-    ("llama_paged_spec_step", 1, False)])
-def test_bit_parity_with_the_replaced_form(op_name, steps, quant,
-                                           monkeypatch):
-    op, ins, attrs = _case(op_name, steps=steps, quant=quant)
-    new = _jit(op, attrs)(ins)
-    # from here ``_make_paged_runner`` builds the frozen reference
-    monkeypatch.setattr(T, "_PagedRunner", _XsYsRunner)
-    old = _jit(op, attrs)(ins)
-    assert sorted(new) == sorted(old)
-    for name in sorted(new):
-        a, b = np.asarray(new[name]), np.asarray(old[name])
-        assert a.dtype == b.dtype and a.shape == b.shape, name
-        assert np.array_equal(a.view(np.uint8), b.view(np.uint8)), (
-            f"{op_name} steps={steps} quant={quant}: {name} differs")
-    if op_name == "llama_paged_decode":
-        assert new["OutTokens"].shape == (B, steps)
-        # the dispatch wrote: the pools are not what went in
-        assert not np.array_equal(np.asarray(new["KPagesOut"], np.float32),
-                                  np.asarray(ins["KPages"], np.float32))
-
-
-# ---------------------------------------------------------------------
-# The write-back of a dense-form dispatch (PR 28)
-# ---------------------------------------------------------------------
-
-def _pool_writes(jaxpr, pools):
-    """The update shape of every scatter or dynamic-update-slice into a
-    pool-shaped array outside any loop: ``jaxpr`` is searched through
-    its calls, not into a scan or a while."""
-    found = []
-    for eqn in jaxpr.eqns:
-        name = eqn.primitive.name
-        if name in ("scan", "while"):
-            continue
-        if (name.startswith("scatter") or name == "dynamic_update_slice") \
-                and tuple(eqn.invars[0].aval.shape) in pools:
-            update = eqn.invars[2 if name.startswith("scatter") else 1]
-            found.append(tuple(update.aval.shape))
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            found += _pool_writes(sub, pools)
-    return found
-
-
-def _n_written(op_name, steps=4):
-    """Positions a row a dense-form dispatch writes."""
-    return ATTRS["gamma"] + 1 if op_name == "llama_paged_spec_step" \
-        else steps
-
-
-def _pool_inputs(ins):
-    return [k for k in ins if k.endswith("Pages") or k == "Pools"]
-
-
-def _dense_op_pools(op_name, ins):
-    """{pool shape: (entries a dispatch writes, entries of its view)}
-    of a decode or speculative op's inputs."""
-    n = _n_written(op_name)
-    out = {}
-    for x in (ins[name] for name in _pool_inputs(ins)):
-        entry = int(np.prod(x.shape[3:]))
-        out[tuple(x.shape)] = (x.shape[0] * B * n * entry,
-                               x.shape[0] * B * KMAX * entry)
-    return out
-
-
-def _write_back_sizes(op, ins, attrs, op_name):
-    """(pool writes of the size a dispatch writes, of any other size,
-    the pools there are): the sizes are element counts of the update."""
-    pools = _dense_op_pools(op_name, ins)
-    jaxpr = jax.make_jaxpr(_jit(op, attrs))(ins).jaxpr
-    written, other = [], []
-    for shape in _pool_writes(jaxpr, set(pools)):
-        size = int(np.prod(shape))
-        (written if size in {w for w, _ in pools.values()}
-         else other).append(shape)
-    return written, other, pools
-
-
-def _dense_case(op_name, **kw):
-    if op_name == "block_paged_decode":
-        kw.pop("quant", None)
-        return _latent_case(op_name, **kw)
-    return _case(op_name, **kw)
-
-
-@pytest.mark.parametrize("op_name", [
-    "llama_paged_decode", "llama_paged_spec_step", "block_paged_decode"])
-def test_write_back_holds_the_entries_written(op_name):
-    """Outside the step loop every write into a pool has an update of
-    [L, B, n, *entry], the entries the dispatch wrote: none of the dense
-    view's size, and one for each pool."""
-    op, ins, attrs = _dense_case(op_name)
-    written, other, _ = _write_back_sizes(op, ins, attrs, op_name)
-    assert not other, f"{op_name}: a pool write of another size: {other}"
-    assert len(written) == len(_pool_inputs(ins)), (op_name, written)
-
-
-def test_write_back_check_sees_the_whole_view_form(monkeypatch):
-    """The detector is not vacuous: the frozen whole-view scatter trips
-    it with an update as large as the dense view."""
-    monkeypatch.setattr(T, "_PagedRunner", _WholeViewRunner)
-    op, ins, attrs = _case("llama_paged_decode")
-    written, other, pools = _write_back_sizes(op, ins, attrs,
-                                              "llama_paged_decode")
-    assert not written
-    assert len(other) == 2          # the K pool's and the V pool's
-    assert {int(np.prod(s)) for s in other} == {
-        view for _, view in pools.values()}
-
-
-@pytest.mark.parametrize("op_name,steps,quant", [
-    ("llama_paged_decode", 1, False), ("llama_paged_decode", 4, False),
-    ("llama_paged_decode", 1, True), ("llama_paged_decode", 4, True),
-    ("llama_paged_spec_step", 1, False),
-    ("llama_paged_spec_step", 1, True),
-    ("block_paged_decode", 1, False), ("block_paged_decode", 4, False)])
-def test_write_back_parity_with_the_whole_view_scatter(op_name, steps,
-                                                       quant, monkeypatch):
-    """Tokens (and whatever else the op returns) bit for bit, and every
-    pool bit for bit on pages 1 and up. Inside the case: rows of unequal
-    length; row 0 crossing a page boundary within the dispatch; row 1
-    running onto a null entry of its table; row 2 an inactive slot on
-    the all-null table; row 3 running past KMAX, where its writes are
-    dropped and do not come back into its last page."""
-    op, ins, attrs = _dense_case(op_name, steps=steps, quant=quant,
-                                 pos=POS_END)
-    new = _jit(op, attrs)(ins)
-    monkeypatch.setattr(T, "_PagedRunner", _WholeViewRunner)
-    old = _jit(op, attrs)(ins)
-    assert sorted(new) == sorted(old)
-    for name in sorted(new):
-        a, b = np.asarray(new[name]), np.asarray(old[name])
-        assert a.dtype == b.dtype and a.shape == b.shape, name
-        if name[:-3] in _pool_inputs(ins):      # <pool>Out: pages 1 and up
-            a, b = a[:, 1:], b[:, 1:]
-        assert np.array_equal(a.view(np.uint8), b.view(np.uint8)), (
-            f"{op_name} steps={steps} quant={quant}: {name} differs")
-    n = _n_written(op_name, steps)
-    for src in _pool_inputs(ins):
-        name = src + "Out"
-        got = np.asarray(new[name]).astype(np.float32)
-        was = np.asarray(ins[src]).astype(np.float32)
-        first = POS_END - src.startswith("Draft")       # draft: pos - 1
-        # row 3's last page: the positions inside KMAX are written, the
-        # head of the page (where a clamped table lookup would put
-        # positions KMAX and up) is as it was
-        last = TABLE[3, -1]
-        head = first[3] % PS
-        assert np.array_equal(got[:, last, :head], was[:, last, :head]), name
-        if first[3] + n > KMAX:
-            assert not np.array_equal(got[:, last, head:],
-                                      was[:, last, head:]), name
-        # row 0 wrote on both sides of its page boundary when it crossed
-        if first[0] + n > 8:
-            for page, off in ((TABLE[0, 1], 3), (TABLE[0, 2], 0)):
-                assert not np.array_equal(got[:, page, off],
-                                          was[:, page, off]), name
-        # pages 1 and up that no live position of the dispatch reaches
-        # are as they went in: the inactive slot touched none of them
-        touched = {TABLE[r, p // PS] for r in (0, 1, 3)
-                   for p in range(first[r], min(first[r] + n, KMAX))}
-        for page in set(range(1, NP)) - touched:
-            assert np.array_equal(got[:, page], was[:, page]), (name, page)
+@pytest.mark.parametrize("pos", [POS, POS_END], ids=["pos", "pos_end"])
+@pytest.mark.parametrize("op_name,quant", [
+    ("llama_paged_decode", False), ("llama_paged_decode", True),
+    ("llama_paged_spec_step", False), ("llama_paged_spec_step", True),
+    ("block_paged_decode", False)])
+def test_a_dispatch_writes_its_steps_entries_and_nothing_else(op_name,
+                                                              quant, pos):
+    """Every pool that comes back differs from the pool given at the
+    positions the live rows' steps wrote and nowhere else off the null
+    page: 4 steps from ``pos``; a speculative round's ``gamma + 1`` from
+    ``pos`` (the target's) and from ``pos - 1`` (the draft's). Inside the
+    case: row 0 crossing a page boundary within the dispatch; row 1 running
+    onto a null entry of its table (position 16: page 0, not held); row 2
+    an inactive slot on the all-null table, which touches no page of
+    another row; under POS_END row 3 running past KMAX, where its writes
+    are dropped and do not come back into the head of its last page."""
+    op, ins, attrs = _latent_case(op_name, pos=pos) \
+        if op_name == "block_paged_decode" \
+        else _case(op_name, quant=quant, pos=pos)
+    out = _jit(op, attrs)(ins)
+    n = ATTRS["gamma"] + 1 if op_name == "llama_paged_spec_step" else 4
+    pools = [k for k in ins if k.endswith("Pages") or k == "Pools"]
+    assert pools
+    for src in pools:
+        first = pos - src.startswith("Draft")
+        got = np.asarray(out[src + "Out"]).astype(np.float32)[:, 1:]
+        was = np.asarray(ins[src]).astype(np.float32)[:, 1:]
+        # [page, offset] that any layer changed, over the entry's axes
+        wrote = {(int(pg) + 1, int(off)) for pg, off in np.argwhere(
+            (got != was).any(axis=tuple(range(3, got.ndim))).any(axis=0))}
+        want = {(int(TABLE[r, p // PS]), int(p % PS)) for r in (0, 1, 3)
+                for p in range(first[r], min(first[r] + n, KMAX))
+                if TABLE[r, p // PS] > 0}
+        assert wrote == want, (src, sorted(wrote ^ want))
+        # every layer wrote each of them
+        for pg, off in want:
+            assert (got[:, pg - 1, off] != was[:, pg - 1, off]).any(
+                axis=tuple(range(1, got.ndim - 2))).all(), (src, pg, off)

@@ -58,9 +58,10 @@ def mistral():
         return llama_config(json.load(f))
 
 
-def test_the_kernel_compiles_at_mistrals_shapes(one_chip):
+def test_the_kernel_compiles_at_mistrals_shapes(one_chip, monkeypatch):
     """Mosaic takes the pools as they are stored: no operand is re-laid
     on its way into the call."""
+    monkeypatch.setattr(pa, "_use_pallas", lambda: True)
     def abstract(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
@@ -252,8 +253,9 @@ def test_no_program_re_lays_or_copies_a_state_pool(one_chip, jamba, label):
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= pools
     if label == "decode":
-        # the attention layers' dense view (0.95 GB: the gate is not
-        # passed here; in place: above) and no state view
+        # the gate is not passed here (behind the kernel: above): ONE
+        # attention layer's rows as the reference gathers them (0.47 GB)
+        # and no state view
         rows = _hlo_type([s_shape[0], jamba.max_batch] + s_shape[2:],
                          "float32")
         assert rows not in text
@@ -283,9 +285,11 @@ def looped():
         decode_block=e["decode_block"])
 
 
-def test_the_kernel_compiles_at_a_key_value_head_a_query_head(one_chip):
+def test_the_kernel_compiles_at_a_key_value_head_a_query_head(one_chip,
+                                                              monkeypatch):
     """16 key/value heads of 128 with ONE query head each and a layer
     number up to 191: Mosaic takes the pools as they are stored."""
+    monkeypatch.setattr(pa, "_use_pallas", lambda: True)
     def abstract(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
@@ -304,8 +308,8 @@ def test_a_looped_program_holds_one_layer_body_and_copies_no_pool(
     """The passes are a loop around the layers' loop: ONE kernel instance
     in the decode program for 192 calls a step, both pools (3.77 GB each)
     aliased from the donated inputs to the outputs through both loops and
-    never copied, no buffer of the dense view's shape (26 GB: the form
-    that cannot exist for this model), and beside its arguments the
+    never copied, no buffer of a view of the layers' shape (26 GB: it
+    could not exist for this model), and beside its arguments the
     program holds less than a third of one pool: the compiler's own
     re-laid copy of the q, k and v matrices (1.2 GB: PERF.md section 6,
     PR 43), not a pass's copy of the layers."""
@@ -454,16 +458,19 @@ def test_a_latent_decode_program_holds_no_view(one_chip, latent):
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= math.prod(shape) * 2
     # beside its arguments the program holds less than one view (the
-    # dense form: 1.73 GB reason, 2.10 GB docs; in place 0.59 and 0.14)
+    # dense form PR 45 replaced: 1.73 GB reason, 2.10 GB docs; 0.59 and
+    # 0.14 since)
     assert memory.temp_size_in_bytes < shape[0] * rows * kmax * 640 * 2
 
 
-# program_text.chip_fingerprint of the other in-place decode programs at
-# their configurations' own engines, as PR 44 recorded them (PERF.md
-# section 6) and PR 45, which gave the schedule a third fold and one pool
-# or two, left them
+# program_text.chip_fingerprint of the other decode programs at their
+# configurations' own engines, as PR 44 recorded them (PERF.md section 6)
+# and PR 45, which gave the schedule a third fold and one pool or two,
+# left them; the two latent models' as PR 45 made them, taken on its tree
+# before PR 46 merged the decode forms into one step
 OTHERS_PINNED = {"mimo": "599ecd3567fa65c8", "jamba": "9e718d24c1afa2e7",
-                 "ouro": "4862221ab083276b"}
+                 "ouro": "4862221ab083276b", "xing4": "f9c8c1dbb68a814f",
+                 "deepseek": "ea419789abe205cd"}
 
 
 @pytest.mark.parametrize("model", sorted(OTHERS_PINNED))
@@ -474,7 +481,9 @@ def test_the_other_decode_programs_are_what_the_chip_was_asked_before(
         cfg, geometry = looped
         programs = cfg.build_paged_programs(**geometry)
     else:
-        programs = _programs_of(*MIXED[model])[1]
+        programs = _programs_of(*dict(
+            MIXED, xing4=LATENT["docs"][:2],
+            deepseek=LATENT["reason"][:2])[model])[1]
     got = program_text.chip_fingerprint(program_text.lower_bundle(
         programs.decode, len(programs.pool_specs), sharding=one_chip))
     assert got == OTHERS_PINNED[model], (model, got)

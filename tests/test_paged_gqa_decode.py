@@ -1,45 +1,41 @@
-"""A GQA decode step attends its pages where they lie.
+"""A decode step attends its pages where they lie.
 
-On a backend that runs the Pallas kernels (the chip; here the hook
-``pallas_attention._FORCE_INTERPRET``) a decode op over plain GQA pools
-takes the in-place form (PERF.md section 6, PR 37): the steps carry the
-pools themselves, each layer writes its entry into its page and
-``paged_gqa_decode`` folds the row's pages, to the row's own length,
-under a running softmax. Held here, on test_paged_cache_inplace.py's
-rows (unequal lengths, a row that crosses a page inside a 4-step
-dispatch, the inactive slot, a row that runs past ``kmax``, a row that
-runs onto a null table entry) at a head 128 wide:
+A decode op runs its steps against the pools themselves: each layer of a
+sequence kind writes its entry into its page and calls its paged
+attention function (ops/pallas_attention.py ``paged_gqa_decode``,
+``paged_flat_decode``, ``paged_latent_decode``), which asks its own gate:
+the Pallas kernel on a backend that runs it (the chip; here the hook
+``pallas_attention._FORCE_INTERPRET``) over pools it takes, the plain
+jax.numpy reference of the same signature and the same promise everywhere
+else (PERF.md section 6, PR 37, 42, 45 and 46). Held here, on
+test_paged_cache_inplace.py's rows (unequal lengths, a row that crosses a
+page inside a 4-step dispatch, the inactive slot, a row that runs past
+``kmax``, a row that runs onto a null table entry):
 
-- the kernel against ``_attend_math`` over the gathered view, a case a
-  row;
+- the kernel AND the reference against a float64 numpy gather-and-softmax
+  of each row's pages, a case a row, in every layout: Mistral's heads
+  inside positions at a head 128 wide, a key/value head a query head, the
+  flat entries of a mixed model's sequence kind at MiMo's head shape (4
+  key/value heads, keys 192 beside values 128) and Jamba's (one head of
+  128), a latent model's one pool (an entry padded past its latent and
+  rotated columns); the reference alone where no kernel goes: a sink, a
+  narrow entry (576 wide, four and a half lane tiles), heads 8 wide;
 - a row alone against the same row among peers, bit for bit;
-- a whole ``llama_paged_decode`` dispatch in both forms: the same tokens
-  and the same pools on every page but the null one;
-- structure: no array of the dense view's shape, no pool as a scan's
-  ``xs`` / ``ys``;
-- the gate: narrow-head models build the dense form, and the engine's
-  ``decode_in_place_total`` says which it dispatched.
-
-A LATENT model's one pool (PERF.md section 6, PR 45) is attended in place
-by ``paged_latent_decode``, a third fold under the same schedule: a block
-of pages copied once, keys whole and values at its leading lane tiles.
-Held here on the same cases against the absorbed form's own two products
-over the gathered view, at an entry padded past its latent and rotated
-columns; the decode form itself in tests/test_latent_moe.py and
-tests/test_latent_share.py.
-
-A model that MIXES KINDS OF LAYER is asked kind by kind (PERF.md section
-6, PR 42): its sequence kind, whose entries lie FLAT in their pages (keys
-``g * dk`` wide beside values ``g * dv``), is attended in place by
-``paged_flat_decode`` while its window rings and its states keep their
-form. Held at MiMo's head shape (4 key/value heads, keys 192 beside values
-128) and Jamba's (one head of 128): the kernel against ``_attend_masked``
-over the gathered view, the same cases; a dispatch of the engine of
-HYBRID_MOE_TINY and of HYBRID_SSM_TINY, widened to whole lane tiles, in
-both forms; the gate, one reason to refuse at a time.
+- a whole ``llama_paged_decode`` dispatch behind the reference and behind
+  the kernel: the same tokens and the same pools on every page but the
+  null one; a dispatch of the engine of HYBRID_MOE_TINY and of
+  HYBRID_SSM_TINY, widened to whole lane tiles, the same way;
+- structure: no program holds a view of a sequence kind's layers
+  ([layers, rows, kmax, ...]), kernel or not; behind the kernel no array
+  with the rows' ``kmax`` positions at all, and no pool as a scan's ``xs``
+  / ``ys``;
+- the report: ``decode_in_place`` (a program's ``in_place``, the engine's
+  ``decode_in_place_total``) says whether a decode program attends through
+  a kernel, one reason to refuse at a time, and chooses nothing.
 """
 import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -75,6 +71,7 @@ def kernel_on(monkeypatch):
     monkeypatch.setattr(pa, "_FORCE_INTERPRET", True)
     monkeypatch.setattr(pa, "PAGED_BLOCK_KEYS", 2 * PS)
     monkeypatch.setattr(pa, "PAGED_LATENT_BLOCK_KEYS", 2 * PS)
+    monkeypatch.setattr(pa, "PAGED_FLAT_BLOCK_KEYS", 2 * PS)
 
 
 # a latent entry as stored: 100 latent columns and 40 rotated ones, padded
@@ -102,8 +99,8 @@ LAYOUTS = {
 def _pools(dtype, seed=0, layout="heads"):
     _, g, dk, dv, _ = LAYOUTS[layout]
     kk, kv = jax.random.split(jax.random.PRNGKey(seed))
-    if layout == "latent":      # numbers in the padding too: the query's
-        # zeros meet them
+    if layout.startswith("latent"):     # numbers in the padding too: the
+        # query's zeros meet them
         return (jax.random.normal(kk, (L, NP, PS, dk)).astype(dtype),)
     if layout.startswith("heads"):
         return tuple(jax.random.normal(k, (L, NP, PS, g, dk)).astype(dtype)
@@ -121,44 +118,54 @@ def _query(dtype, seed, layout="heads"):
     return q
 
 
-def _view_attention(q, *pools_layer_table_lengths):
-    """The dense form's own attention of one query a row at position
-    ``length - 1`` over the gathered view: ``_attend_math`` for pools with
-    heads inside positions, ``_attend_masked`` for flat entries, and for
-    ONE pool of latent entries what ``_latent_absorbed`` does between its
-    two halves of the expansion."""
-    *pools, layer, table, lengths = pools_layer_table_lengths
-    heads, dk = q.shape[1:]
-    if len(pools) == 1:
-        view = T._PagedRunner({"Wq": jnp.zeros((L, D, heads * dk))}, None,
-                              None, None, n_heads=heads, n_kv=1, base=1e4,
-                              eps=1e-5, page_size=PS).gather(
-            pools[0], table)[layer]                     # [B, kmax, entry]
-        s = jnp.einsum("bhc,bkc->bhk", q, view,
-                       preferred_element_type=jnp.float32) * LATENT_SCALE
-        seen = jnp.arange(view.shape[1])[None] < lengths[:, None]
-        w = jax.nn.softmax(jnp.where(seen[:, None], s, -1e30), axis=-1)
-        return jnp.einsum("bhk,bkc->bhc", w.astype(view.dtype),
-                          view[..., :128],
-                          preferred_element_type=jnp.float32)
-    k_pool, v_pool = pools
-    flat = k_pool.ndim == 4
-    g = k_pool.shape[3] // dk if flat else k_pool.shape[3]
-    run = T._PagedRunner({"Wq": jnp.zeros((L, D, heads * dk))}, None, None,
-                         None, n_heads=heads, n_kv=g, base=1e4, eps=1e-5,
-                         page_size=PS)
-    views = [run.gather(pool, table)[layer] for pool in (k_pool, v_pool)]
-    at = lengths[:, None] - 1
-    if not flat:
-        return run._attend_math(q[:, None], *views, at, 1)[:, 0].reshape(
-            q.shape)
-    return run._attend_masked(
-        q[:, None], *(v.reshape(v.shape[:2] + (g, -1)) for v in views),
-        at)[:, 0].reshape(q.shape[:2] + (-1,))
+# where no kernel goes, whatever the backend: layout -> (call, kv heads,
+# key width, value width, query heads). A sink (one more column of each
+# head's denominator); an entry that is not whole lane tiles (latent
+# attention's 512 + 64 as published, 576 wide: the weights attend its
+# first 512); heads narrower than a lane tile
+SINK = np.linspace(-1.0, 2.0, 8).astype(np.float32)
+NARROW = {
+    "flat_4x192_128_sink": (functools.partial(pa.paged_flat_decode,
+                                              sink=jnp.asarray(SINK)),
+                            4, 192, 128, 8),
+    "latent_576": (functools.partial(pa.paged_latent_decode,
+                                     scale=LATENT_SCALE, width=512),
+                   1, 576, 512, 6),
+    "heads_8": (pa.paged_gqa_decode, NKV, 8, 8, NH),
+}
+LAYOUTS.update(NARROW)
+
+
+def _gathered_attention(layout, q, *pools_layer_table_lengths):
+    """One query a row over the first ``lengths[row]`` positions of its
+    pages, gathered in its table's order: float64 numpy, a row and a head
+    at a time, nothing of the code under test."""
+    *pools, layer, table, lengths = (
+        np.asarray(x, np.float64 if np.ndim(x) > 2 else None)
+        for x in pools_layer_table_lengths)
+    _, g, dk, dv, heads = LAYOUTS[layout]
+    q = np.asarray(q, np.float64)
+    latent = len(pools) == 1
+    scale = LATENT_SCALE if latent else dk ** -0.5
+    sink = SINK if layout.endswith("sink") else None
+    out = np.zeros((q.shape[0], heads, dv))
+    for row in range(q.shape[0]):
+        n = int(np.clip(lengths[row], 1, KMAX))
+        keys = pools[0][layer][table[row]].reshape(KMAX, g, dk)[:n]
+        values = keys[..., :dv] if latent \
+            else pools[1][layer][table[row]].reshape(KMAX, g, dv)[:n]
+        for h in range(heads):
+            kv = h // (heads // g)
+            scores = keys[:, kv] @ q[row, h] * scale
+            m = scores.max() if sink is None else max(scores.max(), sink[h])
+            e = np.exp(scores - m)
+            total = e.sum() + (0 if sink is None else np.exp(sink[h] - m))
+            out[row, h] = (e / total) @ values[:, kv]
+    return out
 
 
 # (table row, length attended): the rows of TABLE at the lengths a 4-step
-# dispatch from POS / POS_END gives them, as ``forward_in_place`` hands
+# dispatch from POS / POS_END gives them, as ``decode_step`` hands
 # them over (position + 1, ``kmax`` at most)
 CASES = {
     "before_its_page_boundary": (0, 8),      # ends exactly on a page
@@ -172,32 +179,63 @@ CASES = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(CASES))
-@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
-@pytest.mark.parametrize("layout", sorted(LAYOUTS))
-def test_kernel_against_the_gathered_view(layout, case, dtype, kernel_on):
+@functools.lru_cache(maxsize=None)
+def _jitted(layout, kernel):
+    """The layout's call jitted, one function for the kernel and one for
+    the reference: jit's cache goes by the function and knows nothing of
+    the hook a trace was made under."""
+    call = LAYOUTS[layout][0]
+    return jax.jit(lambda *args: call(*args))
+
+
+def _against_the_gathered_view(layout, case, dtype, kernel):
     """Every row of a batch at once, this case's row among them (peers of
-    other lengths before and behind it), against the dense form's
-    attention. float32 pools: the same math in another order; bf16: the
-    weights are rounded to the cache's type before they meet the
-    values."""
+    other lengths before and behind it). float32 pools: the same math in
+    another order; bf16: the weights are rounded to the cache's type
+    before they meet the values."""
     row, length = CASES[case]
     lengths = np.array([5, 12, 2, 7], np.int32)
     lengths[row] = length
     args = (_query(dtype, 5, layout), *_pools(dtype, layout=layout),
             jnp.int32(1), jnp.asarray(TABLE), jnp.asarray(lengths))
-    got = np.asarray(jax.jit(LAYOUTS[layout][0])(*args), np.float32)
-    want = np.asarray(_view_attention(*args), np.float32)
-    tol = 2e-2 if dtype == "bfloat16" else 2e-6
+    call = _jitted(layout, kernel)
+    assert ("pallas_call" in str(jax.make_jaxpr(call)(*args))) is kernel
+    got = np.asarray(call(*args), np.float64)
+    want = _gathered_attention(layout, *args)
+    tol = 2e-2 if dtype == "bfloat16" else 4e-6
     np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
 
 
-@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+@pytest.mark.parametrize("case", sorted(CASES))
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("layout", sorted(set(LAYOUTS) - set(NARROW)))
+@pytest.mark.parametrize("impl", ["kernel", "reference"])
+def test_kernel_against_the_gathered_view(impl, layout, case, dtype,
+                                          request):
+    """The Pallas kernel through the interpreter, and the jax.numpy
+    reference the same call is where its gate does not pass (here: the
+    CPU), each against the float64 gather-and-softmax."""
+    if impl == "kernel":
+        request.getfixturevalue("kernel_on")
+    _against_the_gathered_view(layout, case, dtype, impl == "kernel")
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("layout", sorted(NARROW))
+def test_reference_where_no_kernel_goes(layout, case, dtype, kernel_on):
+    """A sink, an entry of no whole lane tiles, a head narrower than one:
+    the gate refuses them on a backend that runs the kernels too, and the
+    call is the reference."""
+    _against_the_gathered_view(layout, case, dtype, False)
+
+
+@pytest.mark.parametrize("layout", sorted(set(LAYOUTS) - set(NARROW)))
 def test_a_row_alone_is_the_row_among_peers(layout, kernel_on):
     """Bit for bit: a row's blocks and the order its softmax is folded in
     are fixed by the program, so neither the batch's longest row nor what
     another row left in the buffers reaches its result."""
-    kernel = jax.jit(LAYOUTS[layout][0])
+    kernel = _jitted(layout, True)
     pools = _pools("bfloat16", layout=layout)
     q = _query(jnp.bfloat16, 6, layout)
     lengths = np.array([9, 17, 2, KMAX], np.int32)
@@ -234,15 +272,13 @@ def _decode_case(dtype, pos, steps=4):
 
 @pytest.mark.parametrize("pos", [POS, POS_END], ids=["pos", "pos_end"])
 def test_a_dispatch_in_both_forms(pos, monkeypatch):
-    """Four steps of every row, dense form then in-place form, float32
-    (the two forms are then the same sums in another order; at bf16 the
-    kernel rounds its weights to the cache's type where ``_attend_math``
-    does not, and this toy's argmax does not survive that). The same
-    tokens, and the same pools on pages 1 and up. Outside the comparison,
-    as in the dense form "in no defined order": the null page, and the
-    last token of the two rows whose fourth position is on it (row 1 at
-    16, the inactive row 2 at 4: in place they meet on one offset of one
-    page, in a view each had a copy)."""
+    """Four steps of every row, the reference behind the call then the
+    kernel, float32 (the same sums in another order). The same tokens, and
+    the same pools on pages 1 and up. Outside the comparison, "in no
+    defined order": the null page, and the last token of the two rows
+    whose fourth position is on it (row 1 at 16, the inactive row 2 at 4:
+    they meet on one offset of one page, and the kernel reads the page
+    after every row of the step has written it)."""
     op, ins, attrs = _decode_case("float32", pos)
     assert not T.decode_in_place("gqa", None, [ins["KPages"].shape] * 2)
     dense = rows._jit(op, attrs)(ins)
@@ -285,31 +321,53 @@ def _all_shapes(jaxpr, found=None):
     return found
 
 
-# the dense view of a [L, NP, PS, NKV, HD] pool: stacked, a layer's, as
-# gathered, and heads before positions
-VIEW_SHAPES = {(L, B, KMAX, NKV, HD), (B, KMAX, NKV, HD),
-               (1, B, KMAX, NKV, HD), (L, B, MP, PS, NKV, HD),
-               (B, NKV, KMAX, HD)}
+def _views(pool, layers=None):
+    """(the shapes of a view of the layers of ``pool`` [L, NP, PS, ...],
+    as gathered and as reshaped; the shapes of ONE layer's rows)."""
+    entry = tuple(pool.shape[3:])
+    a_layers = {(B, KMAX) + entry, (B, MP, PS) + entry,
+                (B,) + entry[:-1] + (KMAX,) + entry[-1:]}
+    return ({(n,) + shape for shape in a_layers
+             for n in (1, pool.shape[0])}, a_layers)
 
 
-def test_the_in_place_op_holds_no_view(monkeypatch):
-    """No array of the dense view's shape anywhere in the op, the pools
-    carried by its scans and never their ``xs`` or ``ys``; and the
-    detector sees the dense form, which has both a view and a carry."""
+@pytest.mark.parametrize("hook", [False, True], ids=["off", "on"])
+def test_the_decode_op_holds_no_view(hook, monkeypatch):
+    """No array [layers, rows, kmax, ...] anywhere in the op, whatever is
+    behind the call. Behind the reference a layer's own rows, gathered
+    where they are attended; behind the kernel not those either, the
+    pools carried by its scans and never their ``xs`` or ``ys``, and one
+    kernel instance a program: inside the layer scan."""
     op, ins, attrs = _decode_case("bfloat16", POS)
-    pool = {tuple(ins["KPages"].shape)}
-    dense = jax.make_jaxpr(rows._jit(op, attrs))(ins)
-    assert _all_shapes(dense.jaxpr) & VIEW_SHAPES
-    monkeypatch.setattr(pa, "_FORCE_INTERPRET", True)
-    in_place = jax.make_jaxpr(rows._jit(op, attrs))(ins)
-    assert not _all_shapes(in_place.jaxpr) & VIEW_SHAPES
-    streamed, carried = rows._scan_cache_use(in_place, pool)
+    stacked, a_layers = _views(ins["KPages"])
+    monkeypatch.setattr(pa, "_FORCE_INTERPRET", hook)
+    jaxpr = jax.make_jaxpr(rows._jit(op, attrs))(ins)
+    shapes = _all_shapes(jaxpr.jaxpr)
+    assert not shapes & stacked, shapes & stacked
+    assert bool(shapes & a_layers) is not hook
+    streamed, carried = rows._scan_cache_use(
+        jaxpr, {tuple(ins["KPages"].shape)})
     assert not streamed, streamed
     assert carried == 2         # the step scan and the layer scan
-    # one kernel instance a program: inside the layer scan
-    text = str(in_place)
-    assert text.count("name=paged_gqa_decode") == 1, text.count(
-        "paged_gqa_decode")
+    assert str(jaxpr).count("name=paged_gqa_decode") == hook
+
+
+def test_the_speculative_op_holds_no_view():
+    """Nor does a speculative round: its windows and the draft's steps
+    run against the pools, a layer's rows gathered where it attends
+    them, and every pool is carried by the layer scans."""
+    op, ins, attrs = rows._case("llama_paged_spec_step")
+    jaxpr = jax.make_jaxpr(rows._jit(op, attrs))(ins)
+    shapes = _all_shapes(jaxpr.jaxpr)
+    for name in ("KPages", "DraftKPages"):
+        stacked, a_layers = _views(ins[name])
+        assert not shapes & stacked, (name, shapes & stacked)
+        assert shapes & a_layers
+    streamed, carried = rows._scan_cache_use(
+        jaxpr, {tuple(ins[n].shape) for n in ("KPages", "DraftKPages")})
+    assert not streamed, streamed
+    # the draft's window and its two steps, the target's window
+    assert carried == 2 + (rows.ATTRS["gamma"] - 1)
 
 
 WIDE = LlamaConfig(vocab_size=64, dim=256, n_layers=2, n_heads=2,
@@ -333,18 +391,30 @@ def test_the_gate_reads_the_model_and_the_backend(hook, monkeypatch):
     """Plain GQA pools with whole-tile heads, a mixed model's sequence
     kind with flat whole-tile entries, or a latent model's ONE pool of
     whole-tile entries (PERF.md section 6, PR 45), on a backend that runs
-    the kernel: everything else builds the dense form."""
+    the kernel: everywhere else the same step calls the reference, and
+    the program says so."""
     monkeypatch.setattr(pa, "_FORCE_INTERPRET", hook)
-    built = {name: cfg.build_paged_programs(**GEOMETRY).decode["in_place"]
-             for name, cfg in (("wide", WIDE), ("narrow", LLAMA_TINY),
-                               ("latent", LATENT_MOE_TINY),
-                               ("hybrid", HYBRID_MOE_TINY),
-                               ("hybrid_ssm", HYBRID_SSM_TINY),
-                               ("hybrid_wide", MOE_WIDE),
-                               ("hybrid_ssm_wide", SSM_WIDE))}
+    for block in ("PAGED_BLOCK_KEYS", "PAGED_FLAT_BLOCK_KEYS",
+                  "PAGED_LATENT_BLOCK_KEYS"):
+        monkeypatch.setattr(pa, block, 8)           # two pages
+    programs = {name: cfg.build_paged_programs(**GEOMETRY)
+                for name, cfg in (("wide", WIDE), ("narrow", LLAMA_TINY),
+                                  ("latent", LATENT_MOE_TINY),
+                                  ("hybrid", HYBRID_MOE_TINY),
+                                  ("hybrid_ssm", HYBRID_SSM_TINY),
+                                  ("hybrid_wide", MOE_WIDE),
+                                  ("hybrid_ssm_wide", SSM_WIDE))}
+    built = {name: p.decode["in_place"] for name, p in programs.items()}
     assert built == {"wide": hook, "narrow": False, "latent": hook,
                      "hybrid": False, "hybrid_ssm": False,
                      "hybrid_wide": hook, "hybrid_ssm_wide": hook}
+    # the report is the calls' own answer: a kernel's scope is in the
+    # program's text where it says so and nowhere else
+    for name, p in programs.items():
+        text = program_text.lower_bundle(
+            p.decode, len(p.pool_specs)).as_text(debug_info=True)
+        assert bool(re.search(r"/paged_(gqa|flat|latent)_decode", text)) \
+            is built[name], name
     pool = (2, 40, 4, 1, 128)
     assert T.decode_in_place("gqa", None, [pool, pool]) is hook
     assert T.decode_in_place("latent", None, [(2, 40, 4, 640)]) is hook
@@ -378,7 +448,8 @@ def test_the_gate_answers_a_mixed_model_kind_by_kind(why, monkeypatch):
     """In place: the kind that keeps the whole sequence, attends, has no
     sink and stores flat whole-tile entries, where the backend runs the
     kernel; each other kind of the same model, and the same kind for any
-    one reason, keeps the dense form."""
+    one reason, has no kernel (the reference, the view of its rings, its
+    state entries)."""
     window = dict(FULL_KIND, name="window", window=4, sink=True, n_kv=4,
                   stack="Window", pools=[2, 3])
     rings = [(3, 7, 4, 768), (3, 7, 4, 512)]
@@ -464,20 +535,28 @@ def test_a_mixed_models_dispatches_in_both_forms(cfg, monkeypatch):
 
 
 def test_the_mixed_program_holds_no_view_of_its_sequence_kind(monkeypatch):
-    """The decode program's text in both forms: in place, no array of the
-    sequence kind's views ([rows, kv heads, kmax, width], a layer each)
-    and the kernel under the kind's scope; the window kind keeps the view
-    of its rings in both."""
+    """The decode program's text, reference or kernel behind the call: no
+    view of the sequence kind's layers, stacked ([layers, rows, kmax,
+    width]) or a layer each with heads before positions ([rows, kv heads,
+    kmax, width]: the form PR 46 took away); behind the reference a
+    layer's rows as they are gathered, behind the kernel nothing with the
+    rows' ``kmax`` positions and the kernel under the kind's scope; the
+    window kind keeps the view of its rings in both."""
     b, kmax = GEOMETRY["max_batch"], 8 * GEOMETRY["page_size"]
-    views = [f"tensor<{b}x2x{kmax}x{w}xf32>" for w in (192, 128)]
+    gone = [f"tensor<{b}x2x{kmax}x{w}xf32>" for w in (192, 128)] \
+        + [f"tensor<{n}x{b}x{kmax}x{2 * w}xf32>" for w in (192, 128)
+           for n in (1, 2)]
+    gathered = [f"tensor<{b}x{kmax}x2x{w}xf32>" for w in (192, 128)]
     rings = f"tensor<3x{b}x4x{4 * 192}xf32>"
+    monkeypatch.setattr(pa, "PAGED_FLAT_BLOCK_KEYS", 8)  # two pages
     for hook in (False, True):
         monkeypatch.setattr(pa, "_FORCE_INTERPRET", hook)
         progs = MOE_WIDE.build_paged_programs(**GEOMETRY)
         lowered = program_text.lower_bundle(progs.decode,
                                             len(progs.pool_specs))
         text = lowered.as_text()
-        assert [v in text for v in views] == [not hook] * 2
+        assert not [v for v in gone if v in text]
+        assert [v in text for v in gathered] == [not hook] * 2
         assert rings in text
         # the interpreter leaves the kernel's name in its scopes alone
         assert ("attn/full/paged_flat_decode"
@@ -493,6 +572,7 @@ def test_the_engine_counts_its_in_place_dispatches(hook, model, monkeypatch):
     model of plain GQA layers, and one that mixes kinds of layer whose
     sequence kind is attended in place."""
     monkeypatch.setattr(pa, "_FORCE_INTERPRET", hook)
+    monkeypatch.setattr(pa, "PAGED_FLAT_BLOCK_KEYS", 8)  # two pages
     cfg, scope = (WIDE, _wide_scope()) if model == "wide" \
         else (SSM_WIDE, _mixed_scope(SSM_WIDE))
     engine = DecodeEngine(

@@ -184,7 +184,7 @@ def test_the_gate_answers_kind_by_kind(why, monkeypatch):
     assert asked("latent", None, latent)
     assert not asked("latent", None, latent, widths=(8, 8))
     assert not asked("latent", None, latent, widths=(128, 192))
-    assert not asked("gqa", None, [(2, 40, 4, 2, 128)] * 2)   # a dense view
+    assert not asked("gqa", None, [(2, 40, 4, 2, 128)] * 2)   # plain GQA
     assert asked("gqa", kinds, pools)
     assert [asked("gqa", kinds, pools, kind=i) for i in (0, 1)] \
         == [True, False]
