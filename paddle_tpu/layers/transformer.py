@@ -762,7 +762,8 @@ def block_paged_op(kind, feeds, pools, *, params, lead_params, attrs,
 
     tables = [("", name, params), ("Lead", lead_name, lead_params)] \
         + list(stacks)
-    dim = next(t["AttnNorm"][1][-1] for _, _, t in tables if t)
+    dim = next((t.get("AttnNorm") or t["AttnPostNorm"])[1][-1]
+               for _, _, t in tables if t)
     n_routed = sum(t["MoeRouter"][1][0] for _, _, t in tables
                    if "MoeRouter" in t)
     inputs = {"Emb": [make(emb_name, [vocab_size, dim], dtype).name],

@@ -1,5 +1,8 @@
 """Model zoo — parity with the reference's benchmark/fluid/models and
-book examples, plus the Llama flagship."""
+book examples, plus the Llama flagship. The serving-only block-kind models
+beside ``latent_moe`` (``hybrid_moe``, ``hybrid_ssm``, ``hybrid_delta``,
+``looped``) are imported by whoever serves them, not here: nothing of
+theirs runs at ``import paddle_tpu``."""
 from . import mnist           # noqa: F401
 from . import vgg             # noqa: F401
 from . import resnet          # noqa: F401

@@ -52,6 +52,8 @@ class HybridSSMConfig:
     norm_eps: float = 1e-6
     dtype: str = "bfloat16"
 
+    stats = SSM_STATS       # what its programs count on the device
+
     def __post_init__(self):
         kinds = set(self.layer_kinds)
         if kinds != {FULL, SSM}:
@@ -165,7 +167,7 @@ class HybridSSMConfig:
         ``sequence`` kind, and the state layers' states and tails,
         ``max_batch`` entries of the ``state`` kind and the null entry.
         Every program takes the rows' state table behind their page
-        table, and returns SSM_STATS. The scope must already hold
+        table, and returns ``stats``. The scope must already hold
         ``param_shapes()``."""
         if draft_cfg is not None or quantize:
             raise NotImplementedError(
@@ -190,7 +192,7 @@ class HybridSSMConfig:
             max_batch=max_batch, page_size=page_size, n_pages=n_pages,
             pages_per_seq=pages_per_seq, prompt_buckets=prompt_buckets,
             decode_block=decode_block, chunk_size=chunk_size,
-            stats=SSM_STATS, kinds={"state": state})
+            stats=self.stats, kinds={"state": state})
 
 
 # both kinds over two periods (attention at layers 1 and 4), 4 query heads

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.registry import register_op
-from . import ssm
+from . import delta_rule, ssm
 from . import pallas_attention as _pa
 from .pallas_attention import (flash_attention, masked_attention,
                                paged_flat_decode, paged_flat_usable,
@@ -306,7 +306,13 @@ class BlockKinds:
     entries, N, C]`` float32 and the convolution's tail ``[layers,
     entries, (k - 1) * C]``, reached through a third table, [rows, 1]
     (``StateTable``). ``rotary_dim`` 0: the ``gqa`` kinds rotate nothing
-    (the state layers carry the order).
+    (the state layers carry the order). ``"mixer": "delta"`` is the third
+    mixer, the gated delta rule (ops/delta_rule.py), of the same cache
+    kind: its state a MATRIX a head, ``[layers, entries, heads, dk, dv]``
+    float32, a prompt computed ``delta_rule.CHUNK`` positions at a time as
+    matrix products; its sizes are read off its parameters. ``gqa`` norms
+    its WHOLE query and key projection where the layer holds ``QNorm`` /
+    ``KNorm``.
 
     A stack may be RUN SEVERAL TIMES A TOKEN (a looped language model,
     arXiv:2510.25741): ``passes`` > 1 runs the same stacked layers that
@@ -317,7 +323,10 @@ class BlockKinds:
     kind of plain, dense layer. The ``plain`` residual has a second form,
     read off the PARAMETERS: where a layer holds ``AttnPostNorm`` /
     ``MlpPostNorm`` the sublayer's output is normed too, ``x +
-    norm(f(norm(x)))`` (a norm on each side: sandwich normalisation)."""
+    norm(f(norm(x)))`` (a norm on each side: sandwich normalisation), and
+    where it holds the post-norm and NO ``AttnNorm`` / ``MlpNorm`` the
+    sublayer reads the stream as it is, ``x + norm(f(x))`` (the reordered
+    norm, OLMo 2, arXiv:2501.00656)."""
 
     def __init__(self, *, n_heads, n_kv=None, base=10000.0, eps=1e-6,
                  attention="gqa", ffn="swiglu", residual="plain",
@@ -391,8 +400,14 @@ def _gqa_attention(kinds, p, u, pos, attend_fn):
             [apply_rope_at(x[..., :rd], pos, kinds.base), x[..., rd:]],
             axis=-1)
 
-    q = rotate(qmat(u, p, "Wq").reshape(b, t, kinds.n_heads, hd))
-    k = rotate(qmat(u, p, "Wk").reshape(b, t, kinds.n_kv, hd))
+    def heads(slot, norm, n):
+        x = qmat(u, p, slot)
+        if p.get(norm) is not None:     # over the whole projection
+            x = rms_normalize(x, p[norm], kinds.eps)
+        return rotate(x.reshape(b, t, n, hd))
+
+    q = heads("Wq", "QNorm", kinds.n_heads)
+    k = heads("Wk", "KNorm", kinds.n_kv)
     v = qmat(u, p, "Wv").reshape(b, t, kinds.n_kv, -1)
     if kinds.value_scale != 1.0:
         v = v * jnp.asarray(kinds.value_scale, v.dtype)
@@ -422,10 +437,24 @@ def _latent_attention(kinds, p, u, pos, attend_fn):
     return attend_fn((q_nope, rotate(q_pe)), (entry,)) @ p["Wo"]
 
 
-def _is_ssm(spec):
-    """Whether a kind of layer (an ``attn_kinds`` dict) has the selective
-    state-space mixer, and so a cache of the ``state`` kind."""
-    return spec.get("mixer") == "ssm"
+# the mixers whose cache is the ``state`` kind, each a module of ``window(p,
+# z, state0, tail0, lens, eps)`` and ``step(p, z, state0, tail0, eps)``
+_STATE_MIXERS = {"ssm": ssm, "delta": delta_rule}
+
+
+def _state_stats(mixer):
+    """The device counters a model with layers of this state mixer adds,
+    under the mixer's name: (states its active rows updated over decode
+    steps, layers x rows; real positions its prefill windows computed,
+    layers x positions)."""
+    return (f"{mixer}_state_updates_total",
+            f"{mixer}_prefill_positions_total")
+
+
+def _keeps_state(spec):
+    """Whether a kind of layer (an ``attn_kinds`` dict) has a cache of the
+    ``state`` kind: ONE entry a sequence, which its mixer carries."""
+    return spec.get("mixer") in _STATE_MIXERS
 
 
 def _ssm_mixer(kinds, p, u, pos, attend_fn):
@@ -436,6 +465,21 @@ def _ssm_mixer(kinds, p, u, pos, attend_fn):
     zg = qmat(u, p, "WIn")
     z, g = jnp.split(zg, 2, axis=-1)
     return qmat(attend_fn(z, ()) * jax.nn.silu(g), p, "WOut")
+
+
+def _delta_mixer(kinds, p, u, pos, attend_fn):
+    """The gated delta rule's projections: queries, keys and values (to
+    be convolved) and the decay's and the write strength's a head (not to
+    be), one ``z``; out ``= W_o (norm(o) * silu(W_z u))``, the norm an
+    RMSNorm over each head's values. ``attend_fn(z, ())`` owns what lies
+    between (ops/delta_rule.py) and the state a sequence carries."""
+    z = jnp.concatenate([qmat(u, p, s) for s in
+                         ("Wq", "Wk", "Wv", "Wa", "Wb")], axis=-1)
+    o = attend_fn(z, ())
+    dv = p["GNorm"].shape[-1]
+    o = rms_normalize(o.reshape(o.shape[:-1] + (-1, dv)), p["GNorm"],
+                      kinds.eps).reshape(o.shape)
+    return qmat(o * jax.nn.silu(qmat(u, p, "Wz")), p, "Wo")
 
 
 def _swiglu(p, x, gate="WGate", up="WUp", down="WDown"):
@@ -485,8 +529,9 @@ def _routed_ffn(kinds, p, u, valid):
 
 
 def _plain_residual(kinds, p, which, x, sublayer):
-    y = sublayer(rms_normalize(x, p[which + "Norm"], kinds.eps))
-    post = p.get(which + "PostNorm")
+    pre, post = p.get(which + "Norm"), p.get(which + "PostNorm")
+    y = sublayer(x if pre is None     # the reordered norm: behind alone
+                 else rms_normalize(x, pre, kinds.eps))
     if post is not None:        # a norm on each side of the sublayer
         y = rms_normalize(y, post, kinds.eps)
     return x + y
@@ -530,7 +575,7 @@ def _mhc_residual(kinds, p, which, x, sublayer):
 
 
 _ATTENTION = {"gqa": _gqa_attention, "latent": _latent_attention,
-              "ssm": _ssm_mixer}
+              "ssm": _ssm_mixer, "delta": _delta_mixer}
 _FFN = {"swiglu": _swiglu_ffn, "routed": _routed_ffn}
 _RESIDUAL = {"plain": _plain_residual, "mhc": _mhc_residual}
 
@@ -1282,8 +1327,9 @@ HYBRID_STATS = PAGED_STATS + ("attn_full_positions_total",
 # and a model some of whose layers are state-space mixers: the states its
 # active rows updated over decode steps (layers x rows), and the real
 # positions its prefill windows scanned (layers x positions)
-SSM_STATS = HYBRID_STATS + ("ssm_state_updates_total",
-                            "ssm_prefill_positions_total")
+SSM_STATS = HYBRID_STATS + _state_stats("ssm")
+# or gated delta-rule layers: the same two, under the mixer's name
+DELTA_STATS = HYBRID_STATS + _state_stats("delta")
 # and a model whose stack is run several times a token, over decode steps
 # alone: the layer passes its active rows went through (passes x layers a
 # row a step, counted by the loop that ran them) and the cache positions
@@ -1328,6 +1374,11 @@ def _as_stored(entry, pool):
     """``entry`` [..., width] as ``pool`` [..., stored width] keeps it:
     zeros behind it where the pool is wider."""
     return _padded(entry, pool.shape[-1])
+
+
+def _over(rows, ndim):
+    """``rows`` [n] against an ``ndim``-dimensional array of n rows."""
+    return rows[(slice(None),) + (None,) * (ndim - 1)]
 
 
 def _fold_carry(b, n_heads, t, vd):
@@ -1402,7 +1453,7 @@ class _PagedRunner:
     sink), so a decode dispatch gathers the rows' rings once
     (``open_rings``), its steps write and attend that, and ``close_rings``
     copies their entries back (0.08 GB in MiMo's share; ROADMAP.md Design
-    2). ``state`` (the state-space mixer, ops/ssm.py): ONE entry a row for
+    2). ``state`` (the mixers of _STATE_MIXERS): ONE entry a row for
     the row's life through ``state_table`` [B, 1], NOT protected by the
     length mask: a window that starts a row starts from zeros (``fresh``),
     a decode step updates the live rows' entries where they lie. A STACK
@@ -1601,7 +1652,8 @@ class _PagedRunner:
         return out, rings
 
     def _state_prefill(self, p, z, mine, lyr, pos0, spec):
-        """A prefill window through a state-space layer: (y, the kind's
+        """A prefill window through a layer that keeps a state (the
+        kind's ``mixer``: ops/ssm.py, ops/delta_rule.py): (y, the kind's
         pools written). The rows' state and tail come from their entries
         where the window continues a row (``pos0 > 0``) and are zeros
         where it starts one; ``fresh`` (every row starts): the entries are
@@ -1615,12 +1667,12 @@ class _PagedRunner:
                 tail0 = jnp.zeros((b, t_pool.shape[2]), t_pool.dtype)
             else:
                 goes_on = pos0 > 0
-                state0 = jnp.where(goes_on[:, None, None],
+                state0 = jnp.where(_over(goes_on, s_pool.ndim - 1),
                                    s_pool[lyr, entry], 0)
                 tail0 = jnp.where(goes_on[:, None], t_pool[lyr, entry], 0)
-        y, state, tail = ssm.window(
-            p, z, state0, tail0.reshape(b, -1, z.shape[-1]), self.lens,
-            self.kinds.eps)
+        y, state, tail = _STATE_MIXERS[spec["mixer"]].window(
+            p, z, state0, tail0.reshape(b, -1, p["ConvW"].shape[-1]),
+            self.lens, self.kinds.eps)
         with jax.named_scope("cache/" + spec["name"]):
             s_pool = s_pool.at[lyr, entry].set(state.astype(s_pool.dtype))
             t_pool = t_pool.at[lyr, entry].set(
@@ -1628,8 +1680,8 @@ class _PagedRunner:
         return y, [s_pool, t_pool]
 
     def _state_step(self, p, z, mine, lyr, spec):
-        """A decode step through a state-space layer, run IN THE ENTRIES'
-        ORDER against the kind's pools themselves: (y [B, 1, C], the
+        """A decode step through a layer that keeps a state, run IN THE
+        ENTRIES' ORDER against the kind's pools themselves: (y [B, 1, C], the
         pools written). The step's inputs, a row each and small, are
         scattered to their rows' entries, every entry of the layer is
         stepped where it lies, and the live rows' outputs are gathered
@@ -1649,12 +1701,13 @@ class _PagedRunner:
                 z[:, 0], mode="drop")
             held = jnp.zeros((n,), bool).at[at].set(True, mode="drop")
             state0, tail0 = s_pool[lyr], t_pool[lyr]
-        y_e, state, tail = ssm.step(
-            p, z_e, state0, tail0.reshape(n, -1, z.shape[-1]),
+        y_e, state, tail = _STATE_MIXERS[spec["mixer"]].step(
+            p, z_e, state0, tail0.reshape(n, -1, p["ConvW"].shape[-1]),
             self.kinds.eps)
         with jax.named_scope("cache/" + spec["name"]):
             s_pool = s_pool.at[lyr].set(jnp.where(
-                held[:, None, None], state.astype(s_pool.dtype), state0))
+                _over(held, s_pool.ndim - 1), state.astype(s_pool.dtype),
+                state0))
             t_pool = t_pool.at[lyr].set(jnp.where(
                 held[:, None], tail.reshape(n, -1).astype(t_pool.dtype),
                 tail0))
@@ -1854,7 +1907,7 @@ class _PagedRunner:
             start, nxt[kind] = nxt[kind], nxt[kind] + count
             spec = self.kinds.attn_kinds[kind]
             held, sliced = split(self.stacks[spec["stack"]])
-            if spec["window"] is None and not _is_ssm(spec):
+            if spec["window"] is None and not _keeps_state(spec):
                 # a layer that keeps the whole sequence is taken by its
                 # own number, not a scan's (the chip's programs hold a
                 # kernel instance a layer so: PERF.md section 6, PR 33 and
@@ -1915,14 +1968,14 @@ class _PagedRunner:
         blocks_of = {kind: key_blocks(kind) for kind in (
             [None] if k.attn_kinds is None else
             [i for i, spec in enumerate(k.attn_kinds)
-             if spec["window"] is None and not _is_ssm(spec)])}
+             if spec["window"] is None and not _keeps_state(spec)])}
 
         def attend_kind(p, q, entries, pools, lyr, kind):
             """A layer of one of several attention kinds: its own pools."""
             spec = self.kinds.attn_kinds[kind]
             mine = [pools[i] for i in spec["pools"]]
             sink = p["Sink"] if spec["sink"] else None
-            if _is_ssm(spec):
+            if _keeps_state(spec):
                 out, mine = self._state_prefill(p, q, mine, lyr, pos0,
                                                 spec)
             elif spec["window"] is not None:
@@ -1989,7 +2042,7 @@ class _PagedRunner:
     def _ring_pools(self):
         """(kind's name, pool index) of every pool of a window kind."""
         return [(spec["name"], i) for spec in self.kinds.attn_kinds or ()
-                if spec["window"] is not None and not _is_ssm(spec)
+                if spec["window"] is not None and not _keeps_state(spec)
                 for i in spec["pools"]]
 
     def open_rings(self, pools):
@@ -2102,7 +2155,7 @@ class _PagedRunner:
             spec = k.attn_kinds[kind]
             sink = p["Sink"] if spec["sink"] else None
             mine = [cache[i] for i in spec["pools"]]
-            if _is_ssm(spec):
+            if _keeps_state(spec):
                 out, mine = self._state_step(p, q, mine, lyr, spec)
             elif spec["window"] is None:
                 with jax.named_scope("attn/" + spec["name"]):
@@ -2208,23 +2261,23 @@ class _PagedRunner:
         each summed over its routed layers; for a decode step also its
         expert-layer calls x experts held, the experts among them that a
         token reached, and the cache positions its active rows
-        attended (HYBRID_STATS: by cache kind); a model with state-space
-        layers (SSM_STATS) also the states a decode step updated and the
-        real positions a prefill window scanned."""
+        attended (HYBRID_STATS: by cache kind); a model with layers that
+        keep a state (SSM_STATS, DELTA_STATS) also the states a decode
+        step updated and the real positions a prefill window computed."""
         hybrid = self.kinds.layer_kinds is not None
         names = stats_names(self.kinds)
         out = [jnp.int32(0)] * len(names)
         for k, spec in enumerate(self.kinds.attn_kinds or ()):
-            if _is_ssm(spec):
-                at = names.index("ssm_state_updates_total" if decode
-                                 else "ssm_prefill_positions_total")
+            if _keeps_state(spec):
+                at = names.index(
+                    _state_stats(spec["mixer"])[0 if decode else 1])
                 out[at] = out[at] + self.kinds.layer_kinds.count(k) \
                     * jnp.sum(self.valid)
         if decode and hybrid:
             n = jnp.where(self.valid[:, 0], positions + 1, 0)
             for k, spec in enumerate(self.kinds.attn_kinds):
                 layers = self.kinds.layer_kinds.count(k)
-                if _is_ssm(spec):
+                if _keeps_state(spec):
                     continue
                 if spec["window"] is None:
                     out[6] = out[6] + layers * jnp.sum(n)
@@ -2252,15 +2305,17 @@ class _PagedRunner:
 def stats_names(kinds):
     """The counters a program of a model of these kinds returns, in its
     order: PAGED_STATS, HYBRID_STATS where it mixes kinds of layer,
-    SSM_STATS where some of those are state-space mixers, LOOP_STATS
-    where its stack is run several times a token."""
+    and behind them a state mixer's two where some of those layers keep
+    a state (SSM_STATS, DELTA_STATS), LOOP_STATS where its stack is run
+    several times a token."""
     if kinds.passes > 1:
         return LOOP_STATS
     if kinds.layer_kinds is None:
         return PAGED_STATS
-    if any(_is_ssm(k) for k in kinds.attn_kinds):
-        return SSM_STATS
-    return HYBRID_STATS
+    return HYBRID_STATS + tuple(
+        name for mixer in _STATE_MIXERS
+        if any(k.get("mixer") == mixer for k in kinds.attn_kinds)
+        for name in _state_stats(mixer))
 
 
 def decode_in_place(attention, attn_kinds, pool_shapes, kind=None):
@@ -2288,7 +2343,7 @@ def decode_in_place(attention, attn_kinds, pool_shapes, kind=None):
         return any(decode_in_place(attention, attn_kinds, pool_shapes, k)
                    for k in range(len(attn_kinds)))
     spec = attn_kinds[kind]
-    return (spec["window"] is None and not _is_ssm(spec)
+    return (spec["window"] is None and not _keeps_state(spec)
             and not spec["sink"] and len(spec["pools"]) == 2
             and paged_flat_usable(*(pool_shapes[i] for i in spec["pools"]),
                                   spec["n_kv"]))
@@ -2340,7 +2395,8 @@ def prefill_in_kernel(attention, attn_kinds, latent_widths, pool_shapes,
     else:
         spec = attn_kinds[kind]
         mine = [pool_shapes[i] for i in spec["pools"]]
-        if (spec["window"] is not None or _is_ssm(spec) or len(mine) != 2
+        if (spec["window"] is not None or _keeps_state(spec)
+                or len(mine) != 2
                 or any(len(shape) != 4 for shape in mine)):
             return False
         widths, page_size = (mine[1][3] // spec["n_kv"],), mine[1][2]
@@ -2522,7 +2578,7 @@ _BLOCK_SLOTS = (
     "HcAttnAlpha", "HcAttnBias", "HcMlpPhi", "HcMlpAlpha", "HcMlpBias",
     "Wq", "Wk", "Wv", "Sink", "WIn", "ConvW", "ConvB", "WX", "DtNorm",
     "BNorm", "CNorm", "WDt", "DtBias", "ALog", "D", "WOut",
-    "AttnPostNorm", "MlpPostNorm")
+    "AttnPostNorm", "MlpPostNorm", "KNorm", "Wz", "Wa", "Wb", "GNorm")
 
 
 def _block_runner(ins, attrs):

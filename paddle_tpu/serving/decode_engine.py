@@ -165,6 +165,11 @@ _DECODE_COUNTERS = (
     # state entries the active slots held. 0 for a model without the kind.
     "ssm_state_updates_total", "ssm_prefill_positions_total",
     "state_resets_total", "state_bytes_held_total",
+    # the same two for a model whose state layers are gated delta-rule
+    # linear attention (PR 47; DELTA_STATS): a matrix state a head, so an
+    # entry is megabytes a layer and a long prompt is its chunk job's to
+    # carry through many dispatches
+    "delta_state_updates_total", "delta_prefill_positions_total",
     # a model whose stack is run several times a token (PR 43) keeps a
     # cache layer a pass a layer, and counts on the device, over decode
     # steps, the layer passes its active rows went through and the
