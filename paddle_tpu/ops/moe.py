@@ -20,6 +20,11 @@ from ..core.registry import register_op
 __all__ = ["top_k_gating", "moe_apply", "moe_route", "moe_load",
            "moe_apply_sorted", "moe_apply_no_drop", "moe_apply_no_drop_q"]
 
+# Rows of a share's sorted pairs that one trip of the un-sort's loop sums
+# back to their tokens (moe_apply_sorted): swept on the chip at 256 / 512 /
+# 1,024 (PERF.md section 6, PR 48).
+UNSORT_BLOCK = 256
+
 
 def _ep_constraint(x, spec):
     """Pin ``x``'s sharding when the active mesh has a real 'ep' axis, so
@@ -175,7 +180,10 @@ def moe_apply_sorted(xt, idx, gates, w_gate, w_up, w_down, layer=None,
     rows of the sorted order go through the matmuls: four times an even
     router's share of the pairs, and where the router sends this chip
     more than that, all of them, so no pair to a held expert is ever
-    dropped."""
+    dropped. The sum back to the tokens walks those rows in blocks of
+    ``UNSORT_BLOCK`` and stops behind the last pair held, so its cost
+    follows the pairs this chip holds and not the rows set aside for
+    them."""
     t, k = idx.shape
     e = w_gate.shape[-3]
     local = idx
@@ -208,19 +216,57 @@ def moe_apply_sorted(xt, idx, gates, w_gate, w_up, w_down, layer=None,
         return jnp.sum(pairs * gates[..., None], axis=1).astype(cdt)
 
     def leading(rows):
-        """The first ``rows`` sorted pairs, gated and summed by token; a
-        row behind the last held group counts for nothing (the kernel
-        leaves it unwritten). The sum is one [T, rows] x [rows, D]
-        product: a scatter-add goes row by row on the chip, half a
-        microsecond each (PERF.md section 6, PR 31)."""
+        """The first ``rows`` sorted pairs, gated and summed by token, in
+        float32; a row behind the last held group counts for nothing (the
+        kernel leaves it unwritten).
+
+        Still a product and not a scatter-add, which goes row by row on
+        the chip, half a microsecond each (PERF.md section 6, PR 31): the
+        gate goes onto the rows first, so the matrix on the left is 0/1
+        (row r is token ``pairs[r] // k``'s), and the gated rows are split
+        into their three bfloat16 parts (3 x 8 bits: the whole float32
+        mantissa), laid end to end along the contraction of ONE
+        single-pass product. Every term of it is 1 x a bfloat16 number,
+        exact, and the accumulation is float32 over at most ``k`` live
+        pairs a token: the float32 sum at half the passes
+        ``Precision.HIGHEST`` takes. The rows are walked a block at a
+        time by a loop of ``ceil(n_held / block)`` trips, read on the
+        device; where they are one block or less (every decode step) the
+        product stands alone, with no loop."""
         pairs = order[:rows]
-        live = jnp.arange(rows) < n_held
-        ys = jnp.where(live[:, None], grouped(pairs), 0.0)
-        g = jnp.where(live, gates.reshape(t * k)[pairs], 0.0)
-        to_token = jnp.where(
-            (pairs // k)[None] == jnp.arange(t)[:, None], g[None], 0.0)
-        return jnp.dot(to_token, ys,
-                       precision=jax.lax.Precision.HIGHEST).astype(cdt)
+        ys = grouped(pairs)
+        with jax.named_scope("moe/unsort"):
+            tokens = pairs // k
+            g = gates.reshape(t * k)[pairs]
+            size = min(rows, UNSORT_BLOCK)
+
+            def block(i):
+                """[T, D]: what rows ``i * size`` on add to their tokens;
+                the last block is drawn back inside the rows, and the
+                rows it shares with the one before count there alone."""
+                start = jnp.minimum(i * size, rows - size)
+                row = start + jnp.arange(size)
+                zs = jnp.where(
+                    ((row >= i * size) & (row < n_held))[:, None],
+                    jax.lax.dynamic_slice_in_dim(g, start, size)[:, None]
+                    * jax.lax.dynamic_slice_in_dim(ys, start, size), 0.0)
+                # reduce_precision and not a cast there and back, which
+                # XLA may take for the identity (excess precision allowed)
+                hi = jax.lax.reduce_precision(zs, 8, 7)
+                rest = zs - hi
+                mid = jax.lax.reduce_precision(rest, 8, 7)
+                parts = jnp.concatenate([rest - mid, mid, hi])
+                of_token = jnp.tile(jax.lax.dynamic_slice_in_dim(
+                    tokens, start, size), 3)[None] == jnp.arange(t)[:, None]
+                return jnp.dot(of_token.astype(jnp.bfloat16),
+                               parts.astype(jnp.bfloat16),
+                               preferred_element_type=jnp.float32)
+
+            if rows == size:
+                return block(0).astype(cdt)
+            return jax.lax.fori_loop(
+                0, -(-n_held // size), lambda i, acc: acc + block(i),
+                jnp.zeros((t, ys.shape[-1]), jnp.float32)).astype(cdt)
 
     few = -(-4 * t * k * e // width // 8) * 8
     if few >= t * k:

@@ -330,6 +330,164 @@ def test_the_short_form_and_the_whole_length_form_agree():
                                    rtol=1e-4, atol=1e-5)
 
 
+def _dense_unsort(x, idx, gates, w, first, n):
+    """The un-sort as it stood before PR 48: every sorted pair through its
+    expert, then ONE float32 [T, T x K] x [T x K, D] product at
+    ``Precision.HIGHEST`` whose left operand is the gate where the pair
+    is the token's and its expert is held."""
+    t, k = idx.shape
+    local = jnp.where((idx >= first) & (idx < first + n), idx - first, n)
+    order = jnp.argsort(local.reshape(t * k), stable=True)
+    sizes = moe.moe_load(local, n)
+    live = jnp.arange(t * k) < jnp.sum(sizes)
+    xs = x[order // k]
+    gate_h = jax.lax.ragged_dot(xs, w[0][first:first + n], sizes)
+    up_h = jax.lax.ragged_dot(xs, w[1][first:first + n], sizes)
+    ys = jax.lax.ragged_dot((gate_h * jax.nn.sigmoid(gate_h)) * up_h,
+                            w[2][first:first + n], sizes)
+    ys = jnp.where(live[:, None], ys, 0.0)
+    g = jnp.where(live, gates.reshape(t * k)[order], 0.0)
+    to_token = jnp.where((order // k)[None] == jnp.arange(t)[:, None],
+                         g[None], 0.0)
+    return jnp.dot(to_token, ys, precision=jax.lax.Precision.HIGHEST)
+
+
+def _held_pairs_case(t, first, n, held, fine=False, seed=5):
+    """A layer case whose router sends exactly ``held`` pairs to experts
+    ``first .. first + n - 1`` of 16 (None: as the router fell).
+    ``fine``: gates and expert outputs of the form 1 + j x 2^-20, which
+    no two bfloat16 parts hold."""
+    x, idx, gates, w = _layer_case(t=t, seed=seed)
+    if held is not None:
+        mine = (idx >= first) & (idx < first + n)
+        away = jnp.where(mine, (idx - first + n) % (16 - n) + first + n, idx)
+        away = jnp.where(away >= 16, away - 16, away)
+        assert not bool(((away >= first) & (away < first + n)).any())
+        flat = away.reshape(-1)
+        # a token's k picks stay distinct: one pair a token goes to expert
+        # ``first``, a second round to ``first + 1``, and so on
+        at = np.arange(held)
+        flat = flat.at[(at % t) * idx.shape[1] + at // t].set(
+            first + at // t)
+        idx = flat.reshape(idx.shape)
+        assert int(((idx >= first) & (idx < first + n)).sum()) == held
+    if fine:
+        j = np.arange(gates.size, dtype=np.float32).reshape(gates.shape)
+        gates = jnp.asarray(1.0 + (j % 61 + 1) * 2.0 ** -20)
+        w = [w[0], w[1], w[2] * (1.0 + 2.0 ** -20)]
+    return x, idx, gates, w
+
+
+UNSORT_CASES = {
+    # name: tokens, block, first held, experts held, pairs held, fine
+    "rows_under_one_block": (24, 512, 4, 4, None, False),
+    "rows_of_one_block_exactly": (32, 24, 4, 4, None, False),
+    "held_on_a_blocks_edge": (96, 32, 4, 2, 64, False),
+    "held_one_past_the_edge": (96, 32, 4, 2, 65, False),
+    "held_in_the_drawn_back_last_block": (96, 40, 4, 2, 140, False),
+    "nothing_held": (96, 32, 4, 2, 0, False),
+    "more_held_than_the_leading_rows": (96, 32, 4, 2, 180, False),
+    "every_pair_held_is_the_whole_length": (32, 40, 0, 16, None, False),
+    "all_three_bfloat16_parts_small": (24, 512, 4, 4, None, True),
+    "all_three_bfloat16_parts_looped": (96, 32, 4, 2, 100, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(UNSORT_CASES))
+def test_the_unsort_is_the_float32_sum_whatever_the_blocks(case,
+                                                           monkeypatch):
+    """The blocked three-pass un-sort against the loop over the held
+    experts at the file's tolerances, and against the dense ``HIGHEST``
+    product it replaced at float32 rounding."""
+    t, block, first, n, held, fine = UNSORT_CASES[case]
+    monkeypatch.setattr(moe, "UNSORT_BLOCK", block)
+    x, idx, gates, w = _held_pairs_case(t, first, n, held, fine)
+    got = np.asarray(moe.moe_apply_sorted(
+        x, idx, gates, *(m[first:first + n] for m in w), held=(first, 16)))
+    np.testing.assert_allclose(got, _by_loop(x, idx, gates, w, first, n),
+                               rtol=1e-4, atol=1e-5)
+    want = np.asarray(_dense_unsort(x, idx, gates, w, first, n))
+    np.testing.assert_allclose(got, want, rtol=1e-6,
+                               atol=1e-6 * np.abs(want).max())
+    assert got.any() == (held != 0)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub)
+
+
+def _primitives(jaxpr):
+    return [eqn.primitive.name for eqn in _equations(jaxpr)]
+
+
+@pytest.mark.parametrize("held", [0, 1, 64, 65, 140, 180])
+def test_the_unsorts_loop_takes_as_many_trips_as_the_pairs_held_fill(
+        held, monkeypatch):
+    """A probe build counts the trips of the un-sort's loop as they run:
+    ``ceil(n_held / block)``, in the short form and the whole-length one
+    (144 leading rows of 288 here)."""
+    block = 32
+    monkeypatch.setattr(moe, "UNSORT_BLOCK", block)
+    x, idx, gates, w = _held_pairs_case(96, 4, 2, held)
+    trips = []
+    loop = jax.lax.fori_loop
+
+    def probe(lower, upper, body, init):
+        def counted(i, carry):
+            jax.debug.callback(lambda: trips.append(1))
+            return body(i, carry)
+        return loop(lower, upper, counted, init)
+
+    monkeypatch.setattr(jax.lax, "fori_loop", probe)
+    got = jax.jit(lambda idx: moe.moe_apply_sorted(
+        x, idx, gates, *(m[4:6] for m in w), held=(4, 16)))(idx)
+    jax.block_until_ready(got)
+    jax.effects_barrier()
+    assert len(trips) == -(-held // block)
+    np.testing.assert_allclose(np.asarray(got),
+                               _by_loop(x, idx, gates, w, 4, 2),
+                               rtol=1e-4, atol=1e-5)
+
+
+def test_rows_of_one_block_or_less_hold_no_loop(monkeypatch):
+    """The traced text: 96 tokens' 144 leading rows of 288 over a block of
+    32 hold a ``while`` in each form of the ``cond``; rows of one block or
+    less (every decode step) hold the product alone. Neither holds a constant
+    of an operand's size, and each un-sort is three bfloat16 parts in
+    ONE product with float32 out."""
+    def text(t, block):
+        monkeypatch.setattr(moe, "UNSORT_BLOCK", block)
+        x, idx, gates, w = _layer_case(t=t)
+        return jax.make_jaxpr(lambda idx, gates: moe.moe_apply_sorted(
+            x, idx, gates, *(m[4:6] for m in w), held=(4, 16)))(idx, gates)
+
+    looped = _primitives(text(96, 32).jaxpr)
+    assert looped.count("cond") == 1 and looped.count("while") == 2
+    # the short form one block exactly: the whole-length one alone loops
+    assert _primitives(text(96, 144).jaxpr).count("while") == 1
+    for t, block in ((24, 512), (96, 288), (24, 72)):
+        single = text(t, block)
+        names = _primitives(single.jaxpr)
+        assert "while" not in names and "scan" not in names
+        # two forms under the cond, the gated rows split twice in each
+        assert names.count("reduce_precision") == 4
+        assert all(np.size(c) <= 16 * 32 * 16 for c in single.consts)
+    # 24 tokens x 3 picks: 40 leading rows or all 72, a product each
+    dots = [e for e in _equations(text(24, 512).jaxpr)
+            if e.primitive.name == "dot_general"]
+    assert sorted([(str(v.aval.dtype),) + v.aval.shape for v in e.invars]
+                  for e in dots) == [
+        [("bfloat16", 24, 3 * rows), ("bfloat16", 3 * rows, 32)]
+        for rows in (40, 72)]
+    for e in dots:
+        assert e.outvars[0].aval.dtype == jnp.float32
+        assert e.params["precision"] is None
+
+
 def test_the_shares_add_up_to_the_uncut_layer():
     """Four chips, four experts each: what the shares' routed parts give,
     with the attention, the residual and the shared expert counted once,
