@@ -31,8 +31,8 @@ from dataclasses import dataclass
 from .. import layers
 from ..layers import transformer as tfl
 from ..ops.transformer_ops import (PAGED_STATS, decode_in_place,
-                                   prefill_in_kernel, whole_tiles,
-                                   yarn_inv_freq, yarn_mscale)
+                                   prefill_in_kernel, state_step_in_kernel,
+                                   whole_tiles, yarn_inv_freq, yarn_mscale)
 from .llama import (PagedDecodePrograms, cache_pool_specs,
                     prefill_buckets_reached)
 
@@ -304,6 +304,8 @@ def build_block_programs(cfg, *, pool_specs, common, max_batch, page_size,
         *tables(max_batch)], steps=decode_block)
     decode["in_place"] = decode_in_place(
         attrs["attention"], attrs.get("attn_kinds"), shapes)
+    decode["state_in_kernel"] = state_step_in_kernel(
+        attrs.get("attn_kinds"), pool_specs)
     chunk = None
     if chunk_size is not None:
         cs = int(chunk_size)

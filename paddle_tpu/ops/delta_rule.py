@@ -46,8 +46,8 @@ import jax.numpy as jnp
 
 from . import ssm
 
-__all__ = ["window", "step", "gates", "chunk_rule", "rule_step", "CHUNK",
-           "BETA_MAX"]
+__all__ = ["window", "step", "step_in_kernel", "gates", "chunk_rule",
+           "rule_step", "CHUNK", "BETA_MAX"]
 
 _F32 = jnp.float32
 _HI = jax.lax.Precision.HIGHEST
@@ -196,14 +196,31 @@ def window(p, z, state0, tail0, lens, eps):
     return o.reshape(o.shape[:2] + (-1,)).astype(z.dtype), state, tail
 
 
-def step(p, z, state0, tail0, eps):
-    """``window`` for one position a row: z [B, C + 2H] -> (o [B, H * dv]
-    in z's type, state [B, H, dk, dv] float32, tail [B, k - 1, C])."""
+def step_in_kernel(pool_shape, pool_dtype):
+    """Whether ``step`` runs a Pallas kernel over a pool of this shape and
+    type (ops/ssm.py's has one): never, it is jax.numpy everywhere."""
+    return False
+
+
+def step(p, z, s_pool, layer, held, tail0, eps):
+    """A decode step of one layer IN THE ENTRIES' ORDER against the state
+    pool itself, as ops/ssm.py's: z [n, C + 2H], an entry's input; s_pool
+    [L, n, H, dk, dv]; held [n] bool; tail0 [n, (k - 1) * C], flat as the
+    tail pool stores it -> (o [n, H * dv] in z's type, the pool with layer
+    ``layer`` written, tail [n, (k - 1) * C]). The layer's entries are
+    sliced out of the pool, stepped (``rule_step``: ``window`` for one
+    position) and set back, an entry that is not ``held`` as it was."""
+    state0 = s_pool[layer]
     x, ab = _split(p, z)
+    n = z.shape[0]
     with jax.named_scope("delta/conv"):
         c, tail = ssm.conv_step(
-            x, tail0, p["ConvW"], jnp.zeros((x.shape[-1],), _F32))
+            x, tail0.reshape(n, -1, x.shape[-1]), p["ConvW"],
+            jnp.zeros((x.shape[-1],), _F32))
     with jax.named_scope("delta/step"):
         o, state = rule_step(*_heads(p, c), *gates(p, ab),
                              state0.astype(_F32))
-    return o.reshape(o.shape[0], -1).astype(z.dtype), state, tail
+        o = o.reshape(n, -1).astype(z.dtype)
+        s_pool = s_pool.at[layer].set(jnp.where(
+            held[:, None, None, None], state.astype(s_pool.dtype), state0))
+    return o, s_pool, tail.reshape(n, -1)

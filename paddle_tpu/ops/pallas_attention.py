@@ -472,6 +472,16 @@ def _fold_heads(q, bufs, slot, length, blk, carry, *, scale, rep):
 _VMEM_DEFAULT = 16 * 2 ** 20
 
 
+def _vmem_asked(held):
+    """The ``pallas_call`` arguments of a kernel whose blocks hold ``held``
+    bytes of VMEM: nothing under three quarters of the default limit, else
+    what it holds and 8 MiB."""
+    if held <= 3 * _VMEM_DEFAULT // 4:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=held + 8 * 2 ** 20)}
+
+
 def _paged_decode_call(name, fold, q, pools, layer, table, lengths,
                        out_width, block_keys):
     """One program over the batch's rows (``_paged_rows_kernel`` with
@@ -494,9 +504,6 @@ def _paged_decode_call(name, fold, q, pools, layer, table, lengths,
     held = (q.size + int(np.prod(out))) * q.dtype.itemsize + sum(
         int(np.prod(shape)) * pool.dtype.itemsize
         for shape, pool in zip(buffers, pools))
-    asked = {} if held <= 3 * _VMEM_DEFAULT // 4 else {
-        "compiler_params": pltpu.CompilerParams(
-            vmem_limit_bytes=held + 8 * 2 ** 20)}
     return _pcall(
         functools.partial(_paged_rows_kernel, fold=fold),
         name=name,
@@ -511,7 +518,7 @@ def _paged_decode_call(name, fold, q, pools, layer, table, lengths,
                 for shape, pool in zip(buffers, pools)]
             + [pltpu.SemaphoreType.DMA((len(pools), 2))]),
         out_shape=jax.ShapeDtypeStruct(out, q.dtype),
-        **asked,
+        **_vmem_asked(held),
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), table.astype(jnp.int32),
       lengths.astype(jnp.int32), q, *pools)
 

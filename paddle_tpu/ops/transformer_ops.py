@@ -438,7 +438,8 @@ def _latent_attention(kinds, p, u, pos, attend_fn):
 
 
 # the mixers whose cache is the ``state`` kind, each a module of ``window(p,
-# z, state0, tail0, lens, eps)`` and ``step(p, z, state0, tail0, eps)``
+# z, state0, tail0, lens, eps)``, ``step(p, z, s_pool, layer, held, tail0,
+# eps)`` and ``step_in_kernel(pool_shape, pool_dtype)``
 _STATE_MIXERS = {"ssm": ssm, "delta": delta_rule}
 
 
@@ -1683,14 +1684,17 @@ class _PagedRunner:
         """A decode step through a layer that keeps a state, run IN THE
         ENTRIES' ORDER against the kind's pools themselves: (y [B, 1, C], the
         pools written). The step's inputs, a row each and small, are
-        scattered to their rows' entries, every entry of the layer is
-        stepped where it lies, and the live rows' outputs are gathered
-        back: the states, which are most of what a step moves, are
-        neither gathered to the rows nor scattered back (a view of them
-        cost 6.5 GB of copies a dispatch at 128 rows: PERF.md section 6,
-        PR 39). An entry that no live row holds (the null entry, a free
-        one, a chunk job's between its chunks) keeps what it held, and a
-        row that is not live gets zeros."""
+        scattered to their rows' entries, the kind's mixer steps every
+        entry of the layer where it lies (its ``step`` takes the state pool
+        and hands it back written, each mixer owns how its slab is
+        updated, and the entries' tails flat as their pool stores them),
+        and the live rows' outputs are gathered back: the
+        states, which are most of what a step moves, are neither gathered
+        to the rows nor scattered back (a view of them cost 6.5 GB of
+        copies a dispatch at 128 rows: PERF.md section 6, PR 39). An entry
+        that no live row holds (the null entry, a free one, a chunk job's
+        between its chunks) keeps what it held, and a row that is not
+        live gets zeros."""
         s_pool, t_pool = mine
         n = s_pool.shape[1]
         live = self.valid[:, 0]
@@ -1700,17 +1704,12 @@ class _PagedRunner:
             z_e = jnp.zeros((n, z.shape[-1]), z.dtype).at[at].set(
                 z[:, 0], mode="drop")
             held = jnp.zeros((n,), bool).at[at].set(True, mode="drop")
-            state0, tail0 = s_pool[lyr], t_pool[lyr]
-        y_e, state, tail = _STATE_MIXERS[spec["mixer"]].step(
-            p, z_e, state0, tail0.reshape(n, -1, p["ConvW"].shape[-1]),
-            self.kinds.eps)
+            tail0 = t_pool[lyr]
+        y_e, s_pool, tail = _STATE_MIXERS[spec["mixer"]].step(
+            p, z_e, s_pool, lyr, held, tail0, self.kinds.eps)
         with jax.named_scope("cache/" + spec["name"]):
-            s_pool = s_pool.at[lyr].set(jnp.where(
-                _over(held, s_pool.ndim - 1), state.astype(s_pool.dtype),
-                state0))
             t_pool = t_pool.at[lyr].set(jnp.where(
-                held[:, None], tail.reshape(n, -1).astype(t_pool.dtype),
-                tail0))
+                held[:, None], tail.astype(t_pool.dtype), tail0))
             # a row that is not live reads no entry either: whatever the
             # null entry holds cannot reach the null PAGE through it
             y = jnp.where(live[:, None], y_e[entry], 0)
@@ -2347,6 +2346,21 @@ def decode_in_place(attention, attn_kinds, pool_shapes, kind=None):
             and not spec["sink"] and len(spec["pools"]) == 2
             and paged_flat_usable(*(pool_shapes[i] for i in spec["pools"]),
                                   spec["n_kv"]))
+
+
+def state_step_in_kernel(attn_kinds, pool_specs):
+    """Whether the decode program of a model with these block kinds, over
+    the pools ``pool_specs`` [(shape, dtype)], steps the entries of its
+    state layers through a Pallas kernel: a REPORT, as ``decode_in_place``
+    is (a decode bundle's ``state_in_kernel``, the engine's
+    ``state_step_in_kernel_total``), which chooses nothing: every state
+    layer's ``step`` asks its mixer's own gate (ops/ssm.py
+    ``step_in_kernel``: the backend, and the state pool's shape and type;
+    the delta rule has no kernel)."""
+    return any(
+        _keeps_state(spec) and _STATE_MIXERS[spec["mixer"]].step_in_kernel(
+            *pool_specs[spec["pools"][0]])
+        for spec in attn_kinds or ())
 
 
 def _pages_seen(n_pages, seen, page_size):

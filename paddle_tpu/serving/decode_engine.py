@@ -132,8 +132,17 @@ _DECODE_COUNTERS = (
     # paged kernels; everywhere else the same step calls their jax.numpy
     # reference): equal to decode_batches_total on the chip, 0 on a CPU
     "decode_in_place_total",
-    # and its sibling for prefill: ticked beside prefill_dispatch_total
-    # and chunk_prefill_total for every whole-prompt or chunk dispatch
+    # ticked beside decode_batches_total for every decode dispatch whose
+    # program steps its state layers' entries through the Pallas kernel
+    # (the decode bundle's ``state_in_kernel``: the selective state-space
+    # mixer over a float32 pool of whole tiles, on a backend with the
+    # kernel; everywhere else, and for the delta rule, the jax.numpy
+    # step): equal to decode_batches_total on the chip for Jamba2, 0 on a
+    # CPU and for a model without such layers
+    "state_step_in_kernel_total",
+    # and decode_in_place_total's sibling for prefill: ticked beside
+    # prefill_dispatch_total and chunk_prefill_total for every whole-prompt
+    # or chunk dispatch
     # whose program folds its attention through the kernel prefill_fold
     # (the bundle's ``attn_in_kernel``: a block-kind model's layers that
     # keep the whole sequence, on a backend with the kernel), to be read
@@ -1928,6 +1937,9 @@ class DecodeEngine:
                    decode_in_place_total=int(
                        not use_spec
                        and self.programs.decode.get("in_place", False)),
+                   state_step_in_kernel_total=int(
+                       not use_spec and self.programs.decode.get(
+                           "state_in_kernel", False)),
                    decode_dispatch_s_total=dispatch.seconds,
                    cache_bytes_held_total=sum(
                        self._held_bytes(s) for _, s in active),

@@ -72,6 +72,23 @@ def chip_fingerprint(lowered):
         lowered.as_text()).encode()).hexdigest()[:16]
 
 
+def compiled_fingerprint(lowered):
+    """sha256 of what the chip's compiler LEAVES of a program lowered for a
+    described chip: the optimized module, every instruction with its name,
+    shape, layout and operands, in its schedule, without what names the
+    source it was traced from (an instruction's ``metadata``: scopes,
+    frames; the tables of files, functions and lines before the module; a
+    kernel's serialized body, which ``chip_fingerprint`` holds free of its
+    locations). Two programs whose StableHLO differs in the order of
+    independent operations and nothing else compile to the same module."""
+    text = lowered.compile().as_text()
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    text = re.sub(r'"body":"[A-Za-z0-9+/=]+"', "", text)
+    kept = [line for line in text.splitlines()
+            if not re.match(r'\d+ ["{]', line)]
+    return hashlib.sha256("\n".join(kept).encode()).hexdigest()[:16]
+
+
 def fingerprint(lowered):
     """(sha256 of the StableHLO text the program lowers to, instructions
     of the optimized module): the first is what the program asks for,

@@ -193,10 +193,13 @@ def test_a_step_is_a_window_of_one():
                                1e-6)
     o_w, s_w, t_w = dr.window(p, z[:, 8:], state, tail, jnp.asarray([1, 1]),
                               1e-6)
-    o_s, s_s, t_s = dr.step(p, z[:, 8], state, tail, 1e-6)
+    # the rows' states as the entries of a one-layer pool, all held
+    # and their tails flat, as the tail pool stores an entry
+    o_s, s_s, t_s = dr.step(p, z[:, 8], state[None], 0, jnp.ones((2,), bool),
+                            tail.reshape(2, -1), 1e-6)
     assert np.allclose(o_s, o_w[:, 0], rtol=1e-5, atol=1e-7)
-    assert np.allclose(s_s, s_w, rtol=1e-5, atol=1e-7)
-    assert np.allclose(t_s, t_w)
+    assert np.allclose(s_s[0], s_w, rtol=1e-5, atol=1e-7)
+    assert np.allclose(t_s.reshape(t_w.shape), t_w)
 
 
 def test_the_state_is_float32_whatever_the_models_type():
@@ -208,8 +211,9 @@ def test_the_state_is_float32_whatever_the_models_type():
                                jnp.asarray([5]), 1e-6)
     assert (o.dtype, state.dtype, tail.dtype) == (
         jnp.bfloat16, jnp.float32, jnp.bfloat16)
-    o, state, tail = dr.step(p, z[:, 0], state0,
-                             tail0.astype(jnp.bfloat16), 1e-6)
+    o, state, tail = dr.step(p, z[:, 0], state0[None], 0,
+                             jnp.ones((1,), bool),
+                             tail0.astype(jnp.bfloat16).reshape(1, -1), 1e-6)
     assert (o.dtype, state.dtype, tail.dtype) == (
         jnp.bfloat16, jnp.float32, jnp.bfloat16)
 
@@ -219,6 +223,8 @@ def test_the_scopes_a_trace_names():
     text = jax.jit(dr.window, static_argnums=5).lower(
         p, z, *zeros(1), jnp.asarray([5]), 1e-6).as_text(debug_info=True)
     assert "delta/conv" in text and "delta/chunk" in text
-    text = jax.jit(dr.step, static_argnums=4).lower(
-        p, z[:, 0], *zeros(1), 1e-6).as_text(debug_info=True)
+    state0, tail0 = zeros(1)
+    text = jax.jit(dr.step, static_argnums=6).lower(
+        p, z[:, 0], state0[None], 0, jnp.ones((1,), bool),
+        tail0.reshape(1, -1), 1e-6).as_text(debug_info=True)
     assert "delta/conv" in text and "delta/step" in text

@@ -5,6 +5,8 @@ hybrid_ssm.py) along every path a request takes, and each way a cache
 entry that NO POSITION INDEXES could make a result depend on a slot's
 history (ISSUE 39's hazards): a reused entry, a bucket's padding, rows
 that are not live, a chunk boundary, a handoff."""
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -14,9 +16,11 @@ import jax.numpy as jnp
 import paddle_tpu as fluid
 from paddle_tpu.models.hybrid_ssm import (FULL, HYBRID_SSM_TINY, SSM,
                                           HybridSSMConfig)
+from paddle_tpu.ops import pallas_attention as pa
 from paddle_tpu.ops import ssm
 from paddle_tpu.ops.transformer_ops import (SSM_STATS, _PagedRunner,
-                                            decode_in_place)
+                                            decode_in_place,
+                                            state_step_in_kernel)
 from paddle_tpu.serving.decode_engine import DecodeConfig, DecodeEngine
 from paddle_tpu.serving.kv_pages import PageAllocator, PagesExhaustedError
 
@@ -25,43 +29,59 @@ from benchmark.builders.serve_blocks import make_weights
 from benchmark.reference import hybrid_ssm as ref
 
 CFG = HYBRID_SSM_TINY
-MODEL = dict(
-    name="tiny-ssm", model_type="jamba", vocab_size=CFG.vocab_size,
-    hidden_size=CFG.dim, num_hidden_layers=CFG.n_layers,
-    attn_layer_period=CFG.attn_period, attn_layer_offset=CFG.attn_offset,
-    num_attention_heads=CFG.n_heads, num_key_value_heads=CFG.n_kv,
-    intermediate_size=CFG.ffn_hidden, mamba_d_state=CFG.d_state,
-    mamba_d_conv=CFG.d_conv, mamba_dt_rank=CFG.dt_rank,
-    mamba_expand=CFG.expand, rms_norm_eps=CFG.norm_eps,
-    torch_dtype="float32")
+# the state widened to whole tiles, 8 states a channel over 128 channels:
+# where the interpreter hook admits the decode step's kernel
+# (ops/ssm.py step_in_kernel; CFG's 4 x 48 keeps the jax.numpy step)
+WIDE = dataclasses.replace(CFG, name="hybrid-ssm-wide-state", dim=64,
+                           head_dim=16, d_state=8)
+
+
+def model_of(cfg):
+    return dict(
+        name="tiny-ssm", model_type="jamba", vocab_size=cfg.vocab_size,
+        hidden_size=cfg.dim, num_hidden_layers=cfg.n_layers,
+        attn_layer_period=cfg.attn_period,
+        attn_layer_offset=cfg.attn_offset,
+        num_attention_heads=cfg.n_heads, num_key_value_heads=cfg.n_kv,
+        intermediate_size=cfg.ffn_hidden, mamba_d_state=cfg.d_state,
+        mamba_d_conv=cfg.d_conv, mamba_dt_rank=cfg.dt_rank,
+        mamba_expand=cfg.expand, rms_norm_eps=cfg.norm_eps,
+        torch_dtype="float32")
+
+
+MODEL = model_of(CFG)
 ENGINE = dict(max_batch=3, prompt_buckets=(8, 16, 48), max_new_tokens=8,
               page_size=4, decode_block=2, chunk_size=16, prefill_batch=1,
               default_timeout_s=120.0)
 STEPS = 6
 
 
-def weights(seed=3):
+def weights(seed=3, cfg=CFG):
     """The builder's weights, every matrix ten times as large (so that a
     layer moves the residual stream and a fault in one shows)."""
-    w = make_weights(CFG, seed)
+    w = make_weights(cfg, seed)
     w = {k: v if k.endswith("norm") else v * 10 for k, v in w.items()}
-    w.update(serve_ssm.stand_ins(CFG, w))
+    w.update(serve_ssm.stand_ins(cfg, w))
     return w
+
+
+def scope_of(w):
+    scope = fluid.Scope()
+    for name, value in w.items():
+        scope.set(name, value)
+    return scope
 
 
 @pytest.fixture(scope="module")
 def served():
     w = weights()
-    scope = fluid.Scope()
-    for name, value in w.items():
-        scope.set(name, value)
-    return w, scope
+    return w, scope_of(w)
 
 
-def engine_of(scope, **over):
-    return DecodeEngine(CFG, scope=scope,
+def engine_of(scope, cfg=CFG, auto_start=False, **over):
+    return DecodeEngine(cfg, scope=scope,
                         config=DecodeConfig(**dict(ENGINE, **over)),
-                        auto_start=False)
+                        auto_start=auto_start)
 
 
 @pytest.fixture(scope="module")
@@ -72,14 +92,15 @@ def engine(served):
 
 
 class _System:
-    def __init__(self, w):
-        self.weights, self.config = w, MODEL
+    def __init__(self, w, model=MODEL):
+        self.weights, self.config = w, model
 
 
-def reference_at(w, prompt, decoded, **kw):
+def reference_at(w, prompt, decoded, model=MODEL, **kw):
     sequence = np.concatenate([prompt, decoded[:-1]])
     positions = prompt.size - 1 + np.arange(decoded.size)
-    return serve_ssm.reference_logits(_System(w), sequence, positions, **kw)
+    return serve_ssm.reference_logits(_System(w, model), sequence, positions,
+                                      **kw)
 
 
 def prompt_of(n, seed=0):
@@ -257,12 +278,37 @@ def poison(engine, keep_pages, keep_entries):
     engine._pools = pools
 
 
-def test_rows_that_are_not_live_and_entries_not_held_touch_nothing(
-        served, engine):
+@pytest.fixture(scope="module")
+def wide():
+    """(WIDE's weights, its engine built and warmed with the interpreter
+    hook on): its decode program steps its state layers' entries through
+    the kernel, and stays that program with the hook off again (jit's
+    cache goes by the function)."""
+    w = weights(cfg=WIDE)
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(pa, "_FORCE_INTERPRET", True)
+        eng = engine_of(scope_of(w), cfg=WIDE)
+        assert eng.programs.decode["state_in_kernel"]
+        eng.warmup()
+    return w, eng
+
+
+@pytest.fixture(params=["jnp", "kernel"])
+def either(request):
+    """(weights, engine, the reference's model) with the decode step's
+    states in jax.numpy (CFG) and through the kernel (WIDE)."""
+    if request.param == "jnp":
+        w, _ = request.getfixturevalue("served")
+        return w, request.getfixturevalue("engine"), MODEL
+    return request.getfixturevalue("wide") + (model_of(WIDE),)
+
+
+def test_rows_that_are_not_live_and_entries_not_held_touch_nothing(either):
     """A live row between two that are not, its entry the last, every
     other entry NaN and every other page garbage: its logits are the
     reference's, and the entries it does not hold come back as they
     were."""
+    w, engine, model = either
     prompt = prompt_of(7, seed=21)
     c = engine.config
     need = engine.allocator.pages_for(prompt.size + STEPS + c.decode_block)
@@ -290,12 +336,64 @@ def test_rows_that_are_not_live_and_entries_not_held_touch_nothing(
     got = np.concatenate(logits)[:1 + STEPS]
     decoded = np.asarray(decoded[:1 + STEPS], np.int64)
     assert np.isfinite(got).all()
-    want = reference_at(served[0], prompt, decoded)
+    want = reference_at(w, prompt, decoded, model=model)
     assert serve_ssm.rel_l2(got, want).max() < 2e-5
     state = np.asarray(engine._pools[2])
     assert np.isnan(state[:, [0, 1, 2]]).all() \
         and np.isfinite(state[:, 3]).all()
     engine._pools, _ = engine._zeroed_pools()
+
+
+@pytest.mark.parametrize("n", [5, 16, 37])
+def test_engine_logits_through_the_kernel_are_the_references(wide, n):
+    """``test_engine_logits_are_the_references``' probes (a bucket with
+    padding, a full one, three chunks) at WIDE, the decode steps' states
+    through the interpreted kernel: the same tolerances."""
+    w, engine = wide
+    prompt = prompt_of(n, seed=n)
+    got, decoded, state = serve_ssm.engine_logits(engine, prompt, STEPS)
+    want, want_state = reference_at(w, prompt, decoded, model=model_of(WIDE),
+                                    with_state=True)
+    assert serve_ssm.rel_l2(got, want).max() < 2e-5
+    assert (np.argmax(got, -1) == np.argmax(want, -1)).all()
+    assert np.allclose(state, want_state, rtol=1e-4, atol=1e-7)
+
+
+@pytest.mark.serving
+@pytest.mark.parametrize("hook", [False, True], ids=["off", "on"])
+def test_the_engine_counts_its_state_steps_through_the_kernel(
+        hook, monkeypatch):
+    """``state_step_in_kernel_total`` ticks with ``decode_batches_total``
+    for an engine built where the kernel runs and stays 0 where it does
+    not (the bundle's ``state_in_kernel``: a report, which chooses
+    nothing); CFG's narrow state is never admitted, hook or not. The
+    tokens are the reference's both ways."""
+    monkeypatch.setattr(pa, "_FORCE_INTERPRET", hook)
+    attrs = CFG.block_attrs(4)["attn_kinds"]
+    assert not state_step_in_kernel(
+        attrs, [([2, 40, 4, 6], "float32")] * 2
+        + [([4, 4, 4, 48], "float32"), ([4, 4, 144], "float32")])
+    assert not state_step_in_kernel(None, [])
+    w = weights(cfg=WIDE)
+    engine = engine_of(scope_of(w), cfg=WIDE, auto_start=True)
+    try:
+        assert engine.programs.decode["state_in_kernel"] is hook
+        assert not engine.programs.decode["in_place"]   # heads of 16
+        engine.warmup()
+        requests = [engine.submit(prompt_of(n, seed=n), max_new=6,
+                                  timeout=120) for n in (3, 7, 12, 5)]
+        tokens = [r.result(120) for r in requests]
+        stats = engine.stats()
+    finally:
+        engine.close()
+    assert stats["decode_batches_total"] > 0
+    assert stats["state_step_in_kernel_total"] == (
+        stats["decode_batches_total"] if hook else 0)
+    assert stats["pools_lost_total"] == 0
+    for n, t in zip((3, 7, 12, 5), tokens):
+        want = reference_at(w, prompt_of(n, seed=n), np.asarray(t, np.int64),
+                            model=model_of(WIDE))
+        assert np.array_equal(np.argmax(want, -1), t)
 
 
 def test_a_request_on_a_reused_slot_is_the_request_on_a_fresh_engine(
@@ -501,9 +599,33 @@ def test_a_window_in_two_calls_is_the_window_in_one(cut):
     assert np.allclose(jnp.concatenate([y1, y2], 1), y, atol=1e-6)
     assert np.allclose(s2, state, atol=1e-6) and np.array_equal(t2, tail)
     y9, s9, t9 = ssm.window(p, z[:, :9], *zeros, n(9), 1e-6)
-    ys, ss, ts = ssm.step(p, z[:, 9], s9, t9, 1e-6)
+    # a step: the rows' states as the entries of a one-layer pool, all held
+    # and its tail flat, as the tail pool stores an entry
+    ys, ss, ts = ssm.step(p, z[:, 9], s9[None], 0, jnp.ones((2,), bool),
+                          t9.reshape(2, -1), 1e-6)
     assert np.allclose(ys, y[:, 9], atol=1e-6)
-    assert np.allclose(ss, state, atol=1e-6) and np.array_equal(ts, tail)
+    assert np.allclose(ss[0], state, atol=1e-6)
+    assert np.array_equal(ts.reshape(tail.shape), tail)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_a_step_over_the_flat_tail_is_the_step_over_its_rows(dtype):
+    """``conv_step_flat`` on an entry as the tail pool stores it, against
+    ``conv_step`` on the [k - 1, C] view of it: the same tail, and the same
+    output to the order of a sum over 4 taps."""
+    r = np.random.RandomState(2)
+    b, k, c = 5, 4, 256
+    z = jnp.asarray(r.randn(b, c), dtype)
+    tail0 = jnp.asarray(r.randn(b, k - 1, c), dtype)
+    w = jnp.asarray(r.randn(k, c), dtype)
+    bias = jnp.asarray(r.randn(c), dtype)
+    want, want_tail = ssm.conv_step(z, tail0, w, bias)
+    got, tail = ssm.conv_step_flat(z, tail0.reshape(b, -1), w, bias)
+    assert got.dtype == want.dtype and tail.dtype == tail0.dtype
+    assert np.array_equal(tail.reshape(want_tail.shape), want_tail)
+    tol = 1e-6 if dtype == "float32" else 2e-2
+    assert np.allclose(np.asarray(got, np.float32),
+                       np.asarray(want, np.float32), rtol=tol, atol=tol)
 
 
 def test_the_engines_dispatches_with_the_fold_in_the_kernel(monkeypatch):

@@ -27,6 +27,7 @@ from jax.sharding import SingleDeviceSharding
 
 from benchmark.builders.serve import llama_config
 from paddle_tpu.ops import pallas_attention as pa
+from paddle_tpu.ops import ssm
 from paddle_tpu.serving import DecodeConfig
 
 import program_text
@@ -144,8 +145,8 @@ def _programs_of(config_file, builder):
         "benchmark.builders." + builder).model_config(config)
     return cfg, cfg.build_paged_programs(
         max_batch=e["max_batch"], page_size=e["page_size"],
-        n_pages=e["max_batch"] * per_seq + 1, pages_per_seq=per_seq,
-        prompt_buckets=tuple(e["prompt_buckets"]),
+        n_pages=e.get("n_pages", e["max_batch"] * per_seq + 1),
+        pages_per_seq=per_seq, prompt_buckets=tuple(e["prompt_buckets"]),
         decode_block=block, chunk_size=e.get("chunk_size"))
 
 
@@ -262,6 +263,103 @@ def test_no_program_re_lays_or_copies_a_state_pool(one_chip, jamba, label):
         assert memory.temp_size_in_bytes < 1.3e9
     else:
         assert memory.temp_size_in_bytes < 0.5e9 < 2048 * 16 * 5120 * 4
+
+
+def test_the_state_step_kernel_compiles_at_jambas_slab(one_chip,
+                                                       monkeypatch):
+    """Mosaic takes the state pool as it is stored and writes it where it
+    lies: ONE custom call, the pool aliased from the donated argument to
+    the result, nothing of the slab's size beside it (the input and output
+    maps ride with the entries on lanes, [3, 16, 64]: no [129, 16] stored
+    128 wide)."""
+    monkeypatch.setattr(pa, "_use_pallas", lambda: True)
+
+    def abstract(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    n, states, width = 129, 16, 5120
+    pool = (26, n, states, width)
+    assert ssm.step_in_kernel(pool, "float32")
+    compiled = jax.jit(ssm.step_entries, donate_argnums=(7,)).lower(
+        abstract((n, width)), abstract((n, width), jnp.bfloat16),
+        abstract((n, states)), abstract((n, states)),
+        abstract((n,), jnp.bool_), abstract((states, width)),
+        abstract((width,)), abstract(pool),
+        abstract((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"tpu_custom_call.*ssm_state_step", text)) == 1
+    _assert_held_uncopied(text, [(pool, "float32")])
+    assert _hlo_type(pool[1:], "float32") not in text
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= math.prod(pool) * 4
+    assert memory.temp_size_in_bytes < 1e6
+
+
+def test_jambas_decode_program_steps_its_states_in_one_kernel_a_run(
+        one_chip, monkeypatch):
+    """The decode program at the cell's geometry with the gate as the chip
+    passes it: the kernel ONCE A RUN of Mamba layers (the layer scans of
+    13, 7 and 6 layers between and around the two attention layers), all
+    four pools aliased from the donated inputs to the outputs, and nothing
+    that MAKES an array of the pool's or of a layer's slab's shape: no
+    copy, no fusion, no update-slice, no select; the pool is a parameter,
+    a loop's carry and the kernel's own result, and nothing else (PERF.md
+    section 6, PRs 25 and 34: XLA has twice answered an aliased custom
+    call in these two nested loops with a copy of the pool)."""
+    monkeypatch.setattr(pa, "_use_pallas", lambda: True)
+    programs = _programs_of(*MIXED["jamba"])[1]
+    assert programs.decode["state_in_kernel"] and programs.decode["in_place"]
+    compiled = program_text.lower_bundle(
+        programs.decode, len(programs.pool_specs),
+        sharding=one_chip).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"tpu_custom_call.*ssm_state_step", text)) == 3
+    _assert_held_uncopied(text, programs.pool_specs)
+    s_shape, _ = programs.pool_specs[2]
+    assert s_shape == [26, 129, 16, 5120]
+    pool, slab = (_hlo_type(shape, "float32")
+                  for shape in (s_shape, s_shape[1:]))
+    made_by = set(re.findall(
+        r" = \(?(?:[^()]*, )?" + re.escape(pool) + r"[^ ]* ([\w\-]+)\(",
+        text))
+    assert made_by <= {"parameter", "get-tuple-element", "custom-call",
+                       "while", "tuple", "conditional"}, made_by
+    assert slab not in text
+    # the tails' taps are whole-tile slices of an entry as its pool stores
+    # it: no [entries, 3, channels] view, which the chip stores in tiles of
+    # 4 rows and copied an entry into and out of, a layer a step
+    assert not re.findall(r"bf16\[(?:1,)?129,3,5120\]", text)
+    memory = compiled.memory_analysis()
+    pools = sum(math.prod(s) * (4 if dt == "float32" else 2)
+                for s, dt in programs.pool_specs)
+    assert memory.alias_size_in_bytes >= pools
+    # beside its arguments: the 134 MB of float32 logits it returns and the
+    # steps' rows; no slab (42 MB a layer was the jax.numpy step's)
+    assert memory.temp_size_in_bytes < 0.2e9
+
+
+# tests/program_text.py ``compiled_fingerprint`` of the delta cell's decode
+# program (benchmark/configs/olmo-hybrid-7b.json at its own geometry), taken
+# on PR 48's tree, the parent of PR 49, which moved the update of a layer's
+# slab out of ``_state_step`` into each mixer's own ``step``
+# (ops/delta_rule.py: the same slice, step, ``where`` and set; the StableHLO
+# differs in the ORDER of the state's slice and the tail's slice and
+# reshape, independent reads, and in nothing else: ``chip_fingerprint``
+# 087b7b82ca75ef6f before, b803baf44dde286d after): what the chip's
+# compiler leaves is the same module, instruction for instruction
+DELTA_COMPILED = "e7fcd32965a238a4"
+
+
+def test_the_delta_cells_decode_program_compiles_to_what_it_did(
+        one_chip, monkeypatch):
+    monkeypatch.setattr(pa, "_use_pallas", lambda: True)
+    programs = _programs_of("olmo-hybrid-7b.json", "serve_delta")[1]
+    assert programs.decode["in_place"]
+    assert not programs.decode["state_in_kernel"]
+    assert programs.pool_specs[2] == ([12, 9, 30, 96, 192], "float32")
+    got = program_text.compiled_fingerprint(program_text.lower_bundle(
+        programs.decode, len(programs.pool_specs), sharding=one_chip))
+    assert got == DELTA_COMPILED, got
 
 
 # -- a model whose stack is run several times a token (models/looped.py) ---
@@ -471,10 +569,13 @@ def test_a_latent_decode_program_holds_no_view(one_chip, latent):
 # expert-parallel layer (mimo, deepseek) as PR 48 made them: their held
 # pairs are summed back to their tokens in three exact bfloat16 passes
 # (ops/moe.py; 599ecd3567fa65c8 and ea419789abe205cd before); xing4, which
-# holds every expert, kept its text
-OTHERS_PINNED = {"mimo": "e3ec26ac94aea7da", "jamba": "9e718d24c1afa2e7",
+# holds every expert, kept its text. Jamba2's as PR 49 made it: its 26 state
+# layers step their entries through the kernel ``ssm_state_step``
+# (9e718d24c1afa2e7 before); Olmo-Hybrid's taken on PR 49's tree, whose
+# compiled module is PR 48's (DELTA_COMPILED, above)
+OTHERS_PINNED = {"mimo": "e3ec26ac94aea7da", "jamba": "2e58799dbdc7d219",
                  "ouro": "4862221ab083276b", "xing4": "f9c8c1dbb68a814f",
-                 "deepseek": "7f3ae63f3374ef41"}
+                 "deepseek": "7f3ae63f3374ef41", "olmo": "b803baf44dde286d"}
 
 
 @pytest.mark.parametrize("model", sorted(OTHERS_PINNED))
@@ -486,8 +587,8 @@ def test_the_other_decode_programs_are_what_the_chip_was_asked_before(
         programs = cfg.build_paged_programs(**geometry)
     else:
         programs = _programs_of(*dict(
-            MIXED, xing4=LATENT["docs"][:2],
-            deepseek=LATENT["reason"][:2])[model])[1]
+            MIXED, xing4=LATENT["docs"][:2], deepseek=LATENT["reason"][:2],
+            olmo=("olmo-hybrid-7b.json", "serve_delta"))[model])[1]
     got = program_text.chip_fingerprint(program_text.lower_bundle(
         programs.decode, len(programs.pool_specs), sharding=one_chip))
     assert got == OTHERS_PINNED[model], (model, got)
