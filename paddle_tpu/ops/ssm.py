@@ -24,10 +24,16 @@ whatever the model's type: a decay of 0.999 a token is lost in bfloat16.
 
 ``window`` scans a row's REAL positions: a position at or past ``lens``
 gets ``dt = 0``, which leaves S as it was (exp(0) = 1, no input), and the
-tail is taken from the real inputs alone. The scan is CHUNKED
-(``scan_window``): blocks of ``SCAN_BLOCK`` positions, the state carried
-from position to position; nothing of ``[T, N, C]`` is ever materialised
-(671 MB a layer for a 2,048-token window at C = 5,120).
+tail is taken from the real inputs alone. The scan (``scan_window``) walks
+the positions in their order, in float32, the state carried from position
+to position; nothing of ``[T, N, C]`` is ever materialised (671 MB a layer
+for a 2,048-token window at C = 5,120). What runs it is the call's to
+decide, as ``flash_attention`` decides it: on the chip at whole-tile
+widths ONE Pallas kernel a call, ``ssm_state_scan``, the running state in
+VMEM from a window's first position to its last (``state0`` read once and
+the last state written once a window); everywhere else (every CPU, a
+narrow model) one carried ``lax.scan`` of ``SCAN_BLOCK`` positions a loop
+iteration, under the same promise.
 
 Parameters ``p`` of one layer, by slot: ``ConvW`` [k, C], ``ConvB`` [C],
 ``WX`` [C, R + 2N], ``DtNorm`` [R], ``BNorm`` [N], ``CNorm`` [N], ``WDt``
@@ -41,15 +47,17 @@ from jax.experimental.pallas import tpu as pltpu
 from . import pallas_attention as pa
 
 __all__ = ["window", "step", "conv_window", "conv_step", "conv_step_flat",
-           "dt_b_c", "scan_window", "scan_step", "step_entries",
-           "step_in_kernel"]
+           "dt_b_c", "scan_window", "scan_in_kernel", "scan_step",
+           "step_entries", "step_in_kernel"]
 
 _F32 = jnp.float32
 
-# positions a loop iteration of the prefill scan takes (scan_window). One
-# 2,048-token row at C = 5,120 alone on the chip: 3.16 us a position at 1,
-# 1.10 at 4, 1.16 at 8, 1.21 at 16, 1.52 at 64; the results bit for bit the
-# same (my chip run, PR 39)
+# positions a loop iteration of the carried scan takes (scan_window, where
+# its kernel's gate fails: no cell). The chip ran this loop until PR 52;
+# one 2,048-token row at C = 5,120 alone on the chip: 3.16 us a position at
+# 1, 1.10 at 4, 1.16 at 8, 1.21 at 16, 1.52 at 64; the results bit for bit
+# the same (my chip run, PR 39); inside Jamba2's prefill programs 0.72 at 4,
+# by what the kernel took out of them (PR 52)
 SCAN_BLOCK = 4
 
 
@@ -129,24 +137,143 @@ def scan_step(dt, c, bm, cm, a, d, state):
     return y, state
 
 
-def scan_window(dt, c, bm, cm, a, d, state0, block=SCAN_BLOCK):
-    """The recurrence over a window, position after position with the
-    state carried: one ``lax.scan`` over T whose loop takes ``block``
-    positions an iteration (any ``block`` gives the same positions in the
-    same order; one that does not divide T leaves a shorter last run).
-    Only the running state [B, N, C] lives between positions: nothing of
-    ``[T, N, C]`` is materialised.
+# positions a block and channels a tile of the prefill scan's kernel
+# (``scan_window``), by the chip: one 2,048-token row at C = 5,120, 26 layer
+# calls one after the other, us a position a layer at 128 / 256 / 512
+# positions a block: 0.323 / 0.318 / 0.315 at 256 channels a tile, 0.235 /
+# 0.233 / 0.232 at 512, 0.2135 / 0.2132 / 0.2134 at 1,280, 0.232 / 0.233 /
+# 0.238 at 2,560, 0.302 / 0.303 / 0.308 at 5,120 (its first call 0.9-1.2 s
+# where 1,280's took 0.35), 0.218 at 1,024 x 256 and 0.573 at 640 x 256 (one
+# reading, not looked into); the carried scan beside them 0.43, the results
+# the carried scan's bit for bit at every pair; in the cell's programs 0.2105
+# at every bucket. The positions hardly matter, the channels do. At 1,280 x
+# 256 a body that only copies its blocks takes 0.046, one that never reads
+# the state out 0.140, one without the sum over N 0.162, one without the
+# ``exp`` 0.193: the vector unit's passes bound it (143 operations a
+# position a tile in 79 cycles), not the memory and not the ``exp``; the
+# sums of 8 positions as one butterfly of selects and rotations read 0.192-
+# 0.195 and were left out, 10% of a twentieth of the chip (my chip runs, PR
+# 52; PERF.md section 6)
+SCAN_KERNEL_POSITIONS = 256
+SCAN_KERNEL_CHANNELS = 1280
+# positions a pass of the kernel's loop takes, unrolled in its body: the
+# sublane tile of a 16-bit ``c`` (and two of a float32 ``dt``), so a pass
+# reads and writes whole tiles
+_SCAN_GROUP = 16
 
-    dt, c [B, T, C]; bm, cm [B, T, N]; state0 [B, N, C] float32 -> (y
-    [B, T, C] float32, the state after the last position)."""
+
+def scan_in_kernel(width, n_state, state_dtype):
+    """The gate of ``scan_window``: the backend runs Pallas kernels
+    (``flash_attention``'s own gate), the state is float32, ``C`` whole
+    lane tiles and ``N`` whole sublane tiles."""
+    return (pa._use_pallas() and jnp.dtype(state_dtype) == _F32
+            and n_state % 8 == 0 and width % 128 == 0)
+
+
+def _scan_kernel(dt_ref, c_ref, maps_ref, a_ref, d_ref, s0_ref, y_ref, s_ref,
+                 acc_ref):
+    """A block of ``tb`` positions x ``tc`` channels of one row, the
+    positions one after the other: ``s_ref`` [N, tc], the block of the
+    state handed back, stays in VMEM from a tile's first block of
+    positions (where it is ``s0_ref``) to its last, and is the running
+    state between blocks; inside a block the state is the loop's carry.
+    ``maps_ref`` [tb / G, 2N, G]: a pass's input maps over its output maps
+    with N down the sublanes, as the state's rows lie, so a position's
+    column is spread over the channels' lanes by a broadcast; ``dt`` and
+    ``c`` are rows that broadcast over the N sublanes. ``acc_ref`` [G,
+    tc]: a pass's reductions over N, a row a position."""
+    n_state = a_ref.shape[0]
+    g = _SCAN_GROUP
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_ref[...] = s0_ref[...]
+
+    def group(i, s):
+        rows = pl.ds(pl.multiple_of(i * g, g), g)
+        dt = dt_ref[rows, :]
+        x = c_ref[rows, :].astype(_F32)
+        dtx = dt * x
+        a = a_ref[...]
+        for k in range(g):
+            s = jnp.exp(dt[k:k + 1] * a) * s \
+                + dtx[k:k + 1] * maps_ref[i, :n_state, k:k + 1]
+            acc_ref[k:k + 1, :] = jnp.sum(
+                s * maps_ref[i, n_state:, k:k + 1], axis=0, keepdims=True)
+        y_ref[rows, :] = (acc_ref[...] + d_ref[...] * x).astype(y_ref.dtype)
+        return s
+
+    s_ref[...] = jax.lax.fori_loop(0, dt_ref.shape[0] // g, group,
+                                   s_ref[...])
+
+
+def _scan_in_kernel(dt, c, bm, cm, a, d, state0):
+    """``scan_window`` as ONE kernel: a grid over (row, channel tile, block
+    of positions), the positions innermost and in order, the channel
+    tiles independent (a channel's recurrence touches no other channel).
+    ``state0`` is read once and the last state written once a row and
+    tile; a position brings its ``dt``, ``c`` and maps and takes its ``y``
+    away, and nothing else crosses HBM."""
+    b, t, width = dt.shape
+    n_state, g = a.shape[0], _SCAN_GROUP
+    tb = min(SCAN_KERNEL_POSITIONS, -(-t // g) * g)
+    nb = -(-t // tb)
+    tc = max(w for w in range(128, min(width, SCAN_KERNEL_CHANNELS) + 1, 128)
+             if width % w == 0)
+    maps = jnp.concatenate([bm, cm], axis=2).astype(_F32)
+    if nb * tb > t:
+        # positions past the window: no step (dt = 0), no input
+        dt, c, maps = (jnp.pad(x, ((0, 0), (0, nb * tb - t), (0, 0)))
+                       for x in (dt, c, maps))
+    # [B, T, 2N] -> [B, T / G, 2N, G]: a pass's maps, N on sublanes
+    maps = jnp.swapaxes(maps.reshape(b, nb * tb // g, g, 2 * n_state), 2, 3)
+    rows = pl.BlockSpec((None, tb, tc), lambda i, j, k: (i, k, j))
+    state = pl.BlockSpec((None, n_state, tc), lambda i, j, k: (i, 0, j))
+    y, state = pa._pcall(
+        _scan_kernel, name="ssm_state_scan",
+        grid=(b, width // tc, nb),
+        in_specs=[rows, rows,
+                  pl.BlockSpec((None, tb // g, 2 * n_state, g),
+                               lambda i, j, k: (i, k, 0, 0)),
+                  pl.BlockSpec((n_state, tc), lambda i, j, k: (0, j)),
+                  pl.BlockSpec((1, tc), lambda i, j, k: (0, j)),
+                  state],
+        out_specs=[rows, state],
+        out_shape=[jax.ShapeDtypeStruct((b, nb * tb, width), c.dtype),
+                   jax.ShapeDtypeStruct(state0.shape, _F32)],
+        scratch_shapes=[pltpu.VMEM((g, tc), _F32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+    )(dt, c, maps, a, d.astype(_F32)[None], state0)
+    return y[:, :t], state
+
+
+def scan_window(dt, c, bm, cm, a, d, state0):
+    """The recurrence over a window, position after position in float32
+    with the state carried: ``scan_step``'s equations at every position,
+    in the positions' order. Only the running state [B, N, C] lives
+    between positions: nothing of ``[T, N, C]`` is materialised. Where
+    the gate passes (``scan_in_kernel``: the chip at whole-tile widths)
+    ONE Pallas kernel walks the window with the state in VMEM
+    (``_scan_in_kernel``); everywhere else one ``lax.scan`` over T, whose
+    loop takes ``SCAN_BLOCK`` positions an iteration (any number gives the
+    same positions in the same order; one that does not divide T leaves a
+    shorter last run).
+
+    dt [B, T, C] float32; c [B, T, C]; bm, cm [B, T, N]; state0 [B, N, C]
+    float32 -> (y [B, T, C] in ``c``'s type, the state after the last
+    position)."""
+    if scan_in_kernel(dt.shape[2], a.shape[0], state0.dtype):
+        return _scan_in_kernel(dt, c, bm, cm, a, d, state0)
+
     def body(state, xs):
         y, state = scan_step(*xs, a, d, state)
-        return state, y
+        return state, y.astype(c.dtype)
 
     state, ys = jax.lax.scan(
         body, state0.astype(_F32),
         tuple(jnp.moveaxis(x, 1, 0) for x in (dt, c, bm, cm)),
-        unroll=max(1, min(int(block), dt.shape[1])))
+        unroll=max(1, min(SCAN_BLOCK, dt.shape[1])))
     return jnp.moveaxis(ys, 0, 1), state
 
 

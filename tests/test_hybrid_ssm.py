@@ -547,11 +547,15 @@ def _scan_by_hand(dt, c, bm, cm, a, d, state):
 @pytest.mark.parametrize("t, block", [
     (12, 1), (12, 2), (12, 3), (12, 4), (12, 5), (12, 12), (12, 16),
     (7, 2), (7, 3), (7, 8), (1, 1), (1, 4), (40, 8)])
-def test_the_chunked_scan_is_the_sequential_one(t, block):
-    """Blocks that do and do not divide the length, a block longer than
-    the window, a block of one (the scan a position a loop iteration)."""
+def test_the_chunked_scan_is_the_sequential_one(t, block, monkeypatch):
+    """The carried ``lax.scan`` (what runs wherever the kernel's gate
+    fails: here, a CPU at 5 channels) at ``block`` positions a loop
+    iteration: blocks that do and do not divide the length, a block
+    longer than the window, a block of one. The kernel's own cases are
+    tests/test_ssm_prefill_scan.py's."""
+    monkeypatch.setattr(ssm, "SCAN_BLOCK", block)
     args = _scan_inputs(t)
-    y, state = ssm.scan_window(*args, block=block)
+    y, state = ssm.scan_window(*args)
     want_y, want_state = _scan_by_hand(*args)
     assert np.allclose(y, want_y, atol=2e-5)
     assert np.allclose(state, want_state, atol=2e-5)

@@ -295,6 +295,66 @@ def test_the_state_step_kernel_compiles_at_jambas_slab(one_chip,
     assert memory.temp_size_in_bytes < 1e6
 
 
+def test_the_state_scan_kernel_compiles_at_jambas_window(one_chip,
+                                                       monkeypatch):
+    """Mosaic takes a 2,048-token window of Jamba2's widths as its inputs
+    are stored: ONE custom call, no operand copied on its way in (the
+    maps alone are made for it, [1, 128, 32, 16]: N down the sublanes), no
+    loop over positions beside it, and nothing of [T, N, C] anywhere."""
+    monkeypatch.setattr(pa, "_use_pallas", lambda: True)
+
+    def abstract(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    t, states, width = 2048, 16, 5120
+    assert ssm.scan_in_kernel(width, states, "float32")
+    compiled = jax.jit(ssm.scan_window).lower(
+        abstract((1, t, width)), abstract((1, t, width), jnp.bfloat16),
+        abstract((1, t, states)), abstract((1, t, states)),
+        abstract((states, width)), abstract((width,)),
+        abstract((1, states, width))).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"tpu_custom_call.*ssm_state_scan", text)) == 1
+    assert not re.findall(r" while\(", text)
+    for shape, dtype in (([1, t, width], "float32"),
+                         ([1, t, width], "bfloat16"),
+                         ([1, states, width], "float32")):
+        assert not re.findall(re.escape(_hlo_type(shape, dtype))
+                              + r"\S* copy\(", text), (shape, dtype)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e6
+
+
+@pytest.mark.parametrize("label", ["chunk", "prefill_512"])
+def test_jambas_prefill_programs_scan_in_one_kernel_a_run(
+        one_chip, label, monkeypatch):
+    """A whole-prompt program and the chunk program at the cell's
+    geometry with the gate as the chip passes it: the kernel ONCE A RUN of
+    Mamba layers (the layer scans of 13, 7 and 6 layers), and no loop
+    over the window's positions: the loops left are the three runs of
+    layers and, in the chunk program, the two attention layers' visits of
+    their pages (the carried scan left three more, each a quarter of the
+    window's positions long, a handful of small fusions in its body)."""
+    monkeypatch.setattr(pa, "_use_pallas", lambda: True)
+    programs = _programs_of(*MIXED["jamba"])[1]
+    compiled = program_text.lower_bundle(
+        program_text.bundles_of(programs)[label],
+        len(programs.pool_specs), sharding=one_chip).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"tpu_custom_call.*ssm_state_scan", text)) == 3
+    s_shape, _ = programs.pool_specs[2]
+    pool = _hlo_type(s_shape, "float32")
+    running = _hlo_type([1] + s_shape[2:], "float32")
+    loops = [line for line in text.splitlines() if " while(" in line]
+    # the three runs of layers carry the pool; no loop carries ONE state
+    # from position to position (the carried scan's three did)
+    assert sum(pool in line for line in loops) == 3
+    assert not [line for line in loops if running in line]
+    assert len(loops) == (5 if label == "chunk" else 3)
+    _assert_held_uncopied(text, programs.pool_specs)
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 0.5e9
+
+
 def test_jambas_decode_program_steps_its_states_in_one_kernel_a_run(
         one_chip, monkeypatch):
     """The decode program at the cell's geometry with the gate as the chip
