@@ -92,22 +92,15 @@ CHIP = SmokeConfig(
 # that fell back to the unoptimized program
 _DEGRADED = ("transient device error on dispatch", "rewrite failed")
 
-_compile_seconds = []
-
 
 def _compile_clock():
     """Seconds JAX has spent in backend compilation (or loading from the
-    persistent cache) so far in this process, from its own monitoring
-    events. Tracing and lowering are not in it: no cache saves those."""
-    if not _compile_seconds:
-        _compile_seconds.append(0.0)
-
-        def listen(name, seconds, **_):
-            if name.endswith("backend_compile_duration"):
-                _compile_seconds[0] += seconds
-
-        jax.monitoring.register_event_duration_secs_listener(listen)
-    return _compile_seconds[0]
+    persistent cache) so far in this process, by the program's compile
+    log (paddle_tpu/profiler.py): the executors' entries and the stray
+    compiles beside them. Tracing and lowering are not in it: no cache
+    saves those."""
+    totals = fluid.profiler.compile_totals()
+    return totals["compile_s"] + totals["stray"]["compile_s"]
 
 
 @contextlib.contextmanager

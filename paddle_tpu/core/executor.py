@@ -8,6 +8,7 @@ fetch-set) — JAX itself re-specializes on feed shapes — and donates the
 read-write state so parameter updates are in-place in HBM.
 """
 import os
+import time
 import warnings
 
 import numpy as np
@@ -17,6 +18,7 @@ import jax.numpy as jnp
 
 from . import framework
 from .lowering import lower_program, written_names
+from .. import profiler
 from ..profiler import record_event
 from ..resilience import faultinject as _faultinject
 from ..resilience.retry import (TransientDeviceError, default_policy,
@@ -185,7 +187,7 @@ def check_nan_guard(new_state, fn):
             f"outputs: {bad}")
 
 
-def make_stepped(step_fn, repeats=1):
+def make_stepped(step_fn, repeats=1, on_trace=None):
     """Wrap a lowered step function so the per-step rng derives INSIDE
     the executable from a tiny [step, seed] uint32 argument: a host-side
     fold_in would be a second device dispatch per step (its cost is not
@@ -197,8 +199,14 @@ def make_stepped(step_fn, repeats=1):
     ``repeats`` > 1 unrolls that many optimizer steps into ONE
     executable (same feed, rng advancing per sub-step exactly as
     separate runs would) — one dispatch instead of k. What a launch
-    costs is not re-measured on this installation."""
+    costs is not re-measured on this installation.
+
+    ``on_trace(feed)`` is called where the step is TRACED, which a
+    dispatch of an executable jax.jit already holds never does: the
+    executors' hook for the compile log (profiler.compile_traced)."""
     def stepped(rw, ro, feed, step_seed):
+        if on_trace is not None:
+            on_trace(feed)
         fetches = None
         for i in range(repeats):
             rng = jax.random.fold_in(jax.random.PRNGKey(step_seed[1]),
@@ -265,13 +273,13 @@ class Executor:
         diagnostic; "0"/False disables."""
         program = program or framework.default_main_program()
         with record_event("pt:executor/run", program=program.uid,
-                          step=self._step + 1, repeats=repeats):
+                          step=self._step + 1, repeats=repeats) as span:
             return self._run(program, feed, fetch_list, scope,
                              return_numpy, mode, repeats, validate,
-                             tuple(donate_feeds))
+                             tuple(donate_feeds), span.t0)
 
     def _run(self, program, feed, fetch_list, scope, return_numpy, mode,
-             repeats, validate, donate_feeds):
+             repeats, validate, donate_feeds, t0):
         if not 1 <= repeats <= 32:
             # an unroll, deliberately: a lax.scan over sub-steps would
             # keep the executable O(1) in k at the price of a while-loop
@@ -292,7 +300,7 @@ class Executor:
                     feed.setdefault(k, v)   # explicit feed keys win
         # static verification BEFORE anything is prepared or lowered,
         # once per (program version, fetch set, validate mode)
-        self._validate(program, fetch_list, feed, validate)
+        verify_s = self._validate(program, fetch_list, feed, validate)
         # opt-in graph rewrites (PADDLE_TPU_OPTIMIZE): lower a DCE/CSE'd
         # clone instead of the caller's program — numerics-preserving by
         # construction (analysis/optimize.py), cached per fetch set
@@ -303,6 +311,7 @@ class Executor:
         key = (program.uid, program.version, mode, tuple(fetch_names),
                repeats, donate_feeds)
         fn = self._cache.get(key)
+        compiling = None
         if fn is None:
             # evict executables for older versions of this program so a
             # mutate-and-run loop doesn't leak compiled programs
@@ -313,6 +322,10 @@ class Executor:
             fn = self._jit(program, fetch_names, mode, repeats,
                            donate_feeds, self._donate_state)
             self._cache[key] = fn
+            # the compile log's bracket (profiler.py): from the top of
+            # this run to the return of fn's first call
+            compiling = profiler.open_compile(
+                "Executor", program, feed_vals, t0, verify_s)
 
         self._step += 1
         first_step = self._step
@@ -340,12 +353,19 @@ class Executor:
         policy = self._retry_policy or default_policy()
         # async: the span is host-side enqueue time; the device's time
         # is on the trace's own device lines, on the same clock
-        with record_event("pt:executor/dispatch"):
-            new_state, fetches = with_retries(
-                _dispatch, policy=policy,
-                on_retry=lambda exc, n, delay: warnings.warn(
-                    f"transient device error on dispatch (failure {n}): "
-                    f"{exc}; retrying in {delay:.3g}s", stacklevel=3))
+        try:
+            with record_event("pt:executor/dispatch"):
+                new_state, fetches = with_retries(
+                    _dispatch, policy=policy,
+                    on_retry=lambda exc, n, delay: warnings.warn(
+                        f"transient device error on dispatch (failure "
+                        f"{n}): {exc}; retrying in {delay:.3g}s",
+                        stacklevel=3))
+        except BaseException:
+            profiler.drop_compile()     # nothing compiled: no entry
+            raise
+        if compiling is not None:
+            compiling.close()
 
         # write the scope FIRST: state_rw was donated (its old buffers
         # are already deleted), so if the guard raises and the scope
@@ -371,7 +391,9 @@ class Executor:
         fifth argument that holds those alone, in the order named, so
         that they are donated and the feeds beside them are not."""
         step_fn = lower_program(program, fetch_names, mode)
-        stepped = make_stepped(step_fn, repeats)
+        stepped = make_stepped(
+            step_fn, repeats,
+            lambda feed: profiler.compile_traced("Executor", program, feed))
         donate = (0,) if donate_state else ()
         if donate_feeds:
             kept_feeds = stepped
@@ -448,18 +470,21 @@ class Executor:
         same cadence as compilation, never per step. Cheap mode must
         never block a run: any error-level finding (or a verifier
         crash) degrades to a VerifyWarning. Strict mode runs the full
-        pipeline and raises VerifyError before anything is lowered."""
+        pipeline and raises VerifyError before anything is lowered.
+        Returns the seconds the verifier took (0.0 where it did not
+        run), for the compile log's ``verify_s``."""
         mode = validate
         if mode is None:
             mode = os.environ.get("PADDLE_TPU_VALIDATE", "1")
         if mode in (False, "0", "off", "none"):
-            return
+            return 0.0
         fetch_names = tuple(
             v.name if isinstance(v, framework.Variable) else v
             for v in (fetch_list or []))
         vkey = (program.uid, program.version, fetch_names, str(mode))
         if vkey in self._validated:
-            return
+            return 0.0
+        t0 = time.monotonic()
         from ..analysis import VerifyError, VerifyWarning, errors, \
             verify_program
         feed_names = sorted(feed) if feed else []
@@ -481,6 +506,7 @@ class Executor:
                               "set PADDLE_TPU_VALIDATE=0 to silence",
                               VerifyWarning, stacklevel=3)
         self._validated.add(vkey)
+        return time.monotonic() - t0
 
     # ------------------------------------------------------------------
     def _prepare(self, program, feed, fetch_list, scope, mode,

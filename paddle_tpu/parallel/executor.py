@@ -23,6 +23,7 @@ from ..core import framework
 from ..core.executor import (Executor, global_scope, make_stepped,
                              step_arg, check_nan_guard)
 from ..core.lowering import lower_program, written_names
+from .. import profiler
 from ..profiler import record_event
 from .mesh import make_mesh, DeviceMesh, mesh_scope
 
@@ -192,7 +193,9 @@ class ParallelExecutor:
         if getattr(program, "_nan_guard", False):
             rw_sh_out["__nan_guard__"] = rep
         fn = jax.jit(
-            make_stepped(step_fn),
+            make_stepped(
+                step_fn, on_trace=lambda feed: profiler.compile_traced(
+                    "ParallelExecutor", program, feed)),
             in_shardings=(rw_sh, ro_sh, fd_sh, rep),
             out_shardings=(rw_sh_out, None),
             donate_argnums=(0,))
@@ -203,10 +206,10 @@ class ParallelExecutor:
     def run(self, fetch_list, feed=None, feed_dict=None, return_numpy=True):
         feed = feed if feed is not None else (feed_dict or {})
         with record_event("pt:pexecutor/run", program=self.program.uid,
-                          step=self._step + 1):
-            return self._run(fetch_list, feed, return_numpy)
+                          step=self._step + 1) as span:
+            return self._run(fetch_list, feed, return_numpy, span.t0)
 
-    def _run(self, fetch_list, feed, return_numpy):
+    def _run(self, fetch_list, feed, return_numpy, t0):
         program = self.program
         # its own span: _prepare pulls a device-resident feed to the
         # host and back (jnp.asarray(np.asarray(v))) on every step
@@ -216,17 +219,28 @@ class ParallelExecutor:
 
         key = (program.uid, program.version, tuple(fetch_names))
         fn = self._cache.get(key)
+        compiling = None
         if fn is None:
             fn = self._build_fn(fetch_names, state_rw, state_ro,
                                 feed_vals)
             self._cache[key] = fn
+            # the compile log's bracket, as core Executor.run opens it
+            compiling = profiler.open_compile(
+                "ParallelExecutor", program, feed_vals, t0)
 
         self._step += 1
 
-        with mesh_scope(self.mesh), record_event("pt:pexecutor/dispatch"):
-            new_state, fetches = fn(state_rw, state_ro, feed_vals,
-                                    step_arg(self._step,
-                                             program.random_seed))
+        try:
+            with mesh_scope(self.mesh), \
+                    record_event("pt:pexecutor/dispatch"):
+                new_state, fetches = fn(state_rw, state_ro, feed_vals,
+                                        step_arg(self._step,
+                                                 program.random_seed))
+        except BaseException:
+            profiler.drop_compile()     # nothing compiled: no entry
+            raise
+        if compiling is not None:
+            compiling.close()
 
         # scope first: state_rw was donated, so a guard raise before
         # this write would leave the scope aimed at deleted buffers

@@ -48,6 +48,7 @@ import time
 import numpy as np
 
 from ..core.executor import Executor, global_scope
+from .. import profiler
 from ..profiler import record_event
 from ..resilience import faultinject as _faultinject
 from ..resilience.retry import RetryPolicy, default_policy, with_retries
@@ -102,6 +103,10 @@ _DECODE_COUNTERS = (
     # enqueued_at, summed over the requests prefill_total counts. The
     # pt:engine/* spans share these boundaries.
     "loop_busy_s_total", "loop_idle_s_total",
+    # a set-up's two parts inside the engine, each with its span's
+    # boundaries: the constructor's program building and pool
+    # allocation (pt:engine/build) and warmup() (pt:engine/warmup)
+    "engine_build_s_total", "warmup_s_total",
     "decode_dispatch_s_total", "chunk_dispatch_s_total",
     "prefill_dispatch_s_total", "prefill_dispatch_total",
     "prefill_tokens_total", "prefill_padded_tokens_total",
@@ -508,46 +513,53 @@ class DecodeEngine:
             self._bo_max_new_cap = int(
                 bo_kw.pop("max_new_cap", self._bo_max_new_cap))
             self.brownout = BrownoutController(**bo_kw)
-        self.programs = cfg.build_paged_programs(
-            max_batch=c.max_batch, page_size=c.page_size,
-            n_pages=n_pages, pages_per_seq=self.pages_per_seq,
-            prompt_buckets=c.prompt_buckets,
-            decode_block=c.decode_block, quantize=c.quantize,
-            draft_cfg=draft_cfg, gamma=c.gamma,
-            chunk_size=c.chunk_size)
-        # further cache kinds, where the model has them (window layers: a
-        # ring of pages a request; state-space layers: an entry a
-        # request), each with its own pool of page ids
-        self.kinds = dict(self.programs.kinds)
-        # the ``window`` kind's spec by a name of its own, beside
-        # ``_ring_rows``: what benchmark/builders/serve_hybrid.py reads
-        self.ring = self.kinds.get(self.RING)
-        for kind, spec in self.kinds.items():
-            self.allocator.add_kind(kind, spec["n_pages"])
-        # which kind each pool is of, and the bytes a page of each kind
-        # holds, over all the kind's pools
-        self._pool_kind = [
-            next((k for k, spec in self.kinds.items()
-                  if i in spec["pools"]), PageAllocator.SEQUENCE)
-            for i in range(len(self.programs.pool_specs))]
-        self._page_bytes = dict.fromkeys(self.allocator.kinds, 0)
-        for kind, (shape, dtype) in zip(self._pool_kind,
-                                        self.programs.pool_specs):
-            self._page_bytes[kind] += int(
-                np.prod([shape[0]] + list(shape[2:]))
-                * np.dtype(dtype).itemsize)
-        # graph rewrites on every step program (analysis/optimize.py,
-        # proven bit-exact by optcheck): the bundles are private
-        # clones, so optimizing in place is safe, and each program's
-        # version bump lands BEFORE warmup so the no-recompile pin
-        # covers the optimized executables. Failure degrades to the
-        # unoptimized bundle.
-        self.optimize_reports = {}
-        if optimize:
-            self._optimize_programs()
-        # the cache pools, as the model's programs specify them: donated
-        # to every dispatch, and rebound to what it hands back
-        self._pools, self._draft_pools = self._zeroed_pools()
+        # the engine's build, one span and one counter of the same
+        # boundaries (engine_build_s_total): the model's programs made,
+        # rewritten, and the pools allocated
+        with record_event("pt:engine/build") as build:
+            self.programs = cfg.build_paged_programs(
+                max_batch=c.max_batch, page_size=c.page_size,
+                n_pages=n_pages, pages_per_seq=self.pages_per_seq,
+                prompt_buckets=c.prompt_buckets,
+                decode_block=c.decode_block, quantize=c.quantize,
+                draft_cfg=draft_cfg, gamma=c.gamma,
+                chunk_size=c.chunk_size)
+            # further cache kinds, where the model has them (window
+            # layers: a ring of pages a request; state-space layers: an
+            # entry a request), each with its own pool of page ids
+            self.kinds = dict(self.programs.kinds)
+            # the ``window`` kind's spec by a name of its own, beside
+            # ``_ring_rows``: what benchmark/builders/serve_hybrid.py
+            # reads
+            self.ring = self.kinds.get(self.RING)
+            for kind, spec in self.kinds.items():
+                self.allocator.add_kind(kind, spec["n_pages"])
+            # which kind each pool is of, and the bytes a page of each
+            # kind holds, over all the kind's pools
+            self._pool_kind = [
+                next((k for k, spec in self.kinds.items()
+                      if i in spec["pools"]), PageAllocator.SEQUENCE)
+                for i in range(len(self.programs.pool_specs))]
+            self._page_bytes = dict.fromkeys(self.allocator.kinds, 0)
+            for kind, (shape, dtype) in zip(self._pool_kind,
+                                            self.programs.pool_specs):
+                self._page_bytes[kind] += int(
+                    np.prod([shape[0]] + list(shape[2:]))
+                    * np.dtype(dtype).itemsize)
+            # graph rewrites on every step program (analysis/optimize.py,
+            # proven bit-exact by optcheck): the bundles are private
+            # clones, so optimizing in place is safe, and each program's
+            # version bump lands BEFORE warmup so the no-recompile pin
+            # covers the optimized executables. Failure degrades to the
+            # unoptimized bundle.
+            self.optimize_reports = {}
+            if optimize:
+                self._optimize_programs()
+            # the cache pools, as the model's programs specify them:
+            # donated to every dispatch, and rebound to what it hands back
+            self._pools, self._draft_pools = self._zeroed_pools()
+            build.note(programs=len(self._bundles()), pool_bytes=sum(
+                p.nbytes for p in (*self._pools, *self._draft_pools)))
         # program label -> what its last dispatch returned beside tokens,
         # pools and stats, by name (``logits``, ``picks``), where the
         # model's programs return such: left on the device, for whoever
@@ -561,6 +573,8 @@ class DecodeEngine:
         self.exe = Executor(place,
                             retry_policy=RetryPolicy(max_attempts=1))
         self.metrics = ServingMetrics(extra_counters=_DECODE_COUNTERS)
+        self.metrics.incr("engine_build_s_total", build.seconds)
+        self._built_at = build.t0 + build.seconds
         self.health = HealthMonitor()
         self.breaker = CircuitBreaker(
             failure_threshold=c.breaker_threshold,
@@ -580,7 +594,7 @@ class DecodeEngine:
         self._qlock = threading.Lock()
         self._cv = threading.Condition(self._qlock)
         self._closed = False          # no new admissions (drain)
-        self._warmed = None
+        self._warmed = self._warmed_at = None
         self._worker = None
         self._watchdog = None
         self._worker_death_seen = False
@@ -665,59 +679,93 @@ class DecodeEngine:
         single-row program, the decode step, the spec step) with
         null-page dummy dispatches, then snapshot compile counts for
         assert_no_recompiles(). The steady state after this never
-        compiles, no matter how requests churn."""
-        n = 0
+        compiles, no matter how requests churn. Returns the count of
+        programs and of executables, the ``seconds`` it took
+        (``warmup_s_total``, the ``pt:engine/warmup`` span) and
+        ``by_label``, a program: the seconds of its dispatch (its
+        ``pt:engine/warm`` span, to the tokens' landing) and the phases
+        the compile log holds of it (profiler.compile_log)."""
+        seconds = {}
+
+        def warm(label, run, *args):
+            with record_event("pt:engine/warm", label=label) as span:
+                run(*args)
+            seconds[label] = span.seconds
+
+        max_batch = self.config.max_batch
         row = (np.ones((1,), np.int32),
                np.zeros((1, self.pages_per_seq), np.int32),
                *self._kind_tables([None]))
-        for bucket in sorted(self.programs.prefill):
-            self._run_prefill_program(
-                bucket, np.zeros((1, bucket), np.int64), *row)
-            n += 1
+        with record_event("pt:engine/warmup") as whole:
+            for bucket in sorted(self.programs.prefill):
+                warm(f"prefill_{bucket}", self._run_prefill_program,
+                     bucket, np.zeros((1, bucket), np.int64), *row)
+                if self.draft_cfg is not None:
+                    warm(f"draft_prefill_{bucket}",
+                         self._run_draft_prefill_program, bucket,
+                         np.zeros((1, bucket), np.int64), *row[:2])
+            if self.programs.chunk is not None:
+                cs = self.programs.chunk_size
+                warm("chunk", self._run_chunk_program,
+                     np.zeros((1, cs), np.int64), np.ones((1,), np.int32),
+                     np.zeros((1,), np.int32),
+                     np.zeros((1, self.pages_per_seq), np.int32),
+                     *self._kind_tables([None]))
+            # the PLAIN decode program warms even for speculative
+            # engines: brownout level 2 (spec_off) switches a live engine
+            # to it, and the no-recompile pin must survive that switch
+            warm("decode", self._run_decode_program,
+                 np.zeros((max_batch,), np.int64),
+                 np.ones((max_batch,), np.int32),
+                 np.zeros((max_batch, self.pages_per_seq), np.int32),
+                 *self._kind_tables([None] * max_batch))
             if self.draft_cfg is not None:
-                self._run_draft_prefill_program(
-                    bucket, np.zeros((1, bucket), np.int64), *row[:2])
-                n += 1
-        if self.programs.chunk is not None:
-            cs = self.programs.chunk_size
-            self._run_chunk_program(
-                np.zeros((1, cs), np.int64), np.ones((1,), np.int32),
-                np.zeros((1,), np.int32),
-                np.zeros((1, self.pages_per_seq), np.int32),
-                *self._kind_tables([None]))
-            n += 1
-        # the PLAIN decode program warms even for speculative engines:
-        # brownout level 2 (spec_off) switches a live engine to it,
-        # and the no-recompile pin must survive that switch
-        self._run_decode_program(
-            np.zeros((self.config.max_batch,), np.int64),
-            np.ones((self.config.max_batch,), np.int32),
-            np.zeros((self.config.max_batch, self.pages_per_seq),
-                     np.int32),
-            *self._kind_tables([None] * self.config.max_batch))
-        n += 1
-        if self.draft_cfg is not None:
-            self._run_spec_program(
-                np.zeros((self.config.max_batch,), np.int64),
-                np.zeros((self.config.max_batch,), np.int64),
-                np.ones((self.config.max_batch,), np.int32),
-                np.zeros((self.config.max_batch, self.pages_per_seq),
-                         np.int32))
-            n += 1
+                warm("spec", self._run_spec_program,
+                     np.zeros((max_batch,), np.int64),
+                     np.zeros((max_batch,), np.int64),
+                     np.ones((max_batch,), np.int32),
+                     np.zeros((max_batch, self.pages_per_seq), np.int32))
+        self.metrics.incr("warmup_s_total", whole.seconds)
         self._warmed = self.exe.compile_counts()
+        self._warmed_at = whole.t0 + whole.seconds
         compiles = self.exe.total_compiles()
         self.metrics.incr("warmup_compiles", compiles)
-        return {"programs": n, "compiles": compiles}
+        by_label = {label: {"seconds": round(s, 3)}
+                    for label, s in seconds.items()}
+        for label, e in self._compiled_since(whole.t0):
+            by_label[label].update(
+                {k: round(e[k], 3) for k in profiler.COMPILE_PHASES},
+                cache_hit=e["cache_hit"])
+        return {"programs": len(seconds), "compiles": compiles,
+                "seconds": round(whole.seconds, 3), "by_label": by_label}
+
+    def _compiled_since(self, t):
+        """(label, entry) for the compile log's entries of this engine's
+        programs that closed at or after ``t``: the engine knows a
+        program's label, the executor only its uid."""
+        labels = {b["program"].uid: label
+                  for label, b in self._bundles().items()}
+        return [(labels[e["program"]], e)
+                for e in profiler.compile_log(since=t)
+                if e["program"] in labels]
 
     def assert_no_recompiles(self):
         """AssertionError if any XLA compile happened after warmup —
-        the churn-proof contract. No-op before warmup."""
+        the churn-proof contract — naming what compiled from the
+        compile log: label, feed shapes, seconds, ``cache_hit``. No-op
+        before warmup."""
         if self._warmed is None:
             return
         now = self.exe.compile_counts()
         if now != self._warmed:
+            what = "; ".join(
+                f"{label} in {e['t1'] - e['t0']:.3f} s (cache_hit "
+                f"{e['cache_hit']}) for the feeds "
+                + " ".join(f"{k}:{v}" for k, v in e["shapes"].items())
+                for label, e in self._compiled_since(self._warmed_at))
             raise AssertionError(
-                f"decode executables changed after warmup: "
+                f"decode executables changed after warmup: compiled "
+                f"{what or 'nothing the compile log holds'}; "
                 f"{self._warmed} -> {now} — a traced shape escaped the "
                 "paged-buffer discipline")
 
@@ -975,6 +1023,9 @@ class DecodeEngine:
     def stats(self):
         snap = self.metrics.stats()
         snap["compiles_now"] = self.exe.total_compiles()
+        # the time.monotonic() the build ended at: with
+        # engine_build_s_total, where it lies beside the compile log
+        snap["engine_built_at"] = self._built_at
         with self._qlock:
             snap["queue_depth"] = len(self._queue)
         snap["active_slots"] = sum(s is not None for s in self.slots)

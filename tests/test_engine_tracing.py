@@ -352,13 +352,16 @@ def test_a_session_still_prints_its_spans(tmp_path, capsys):
 
 def test_the_profiler_keeps_one_timeline_and_one_list():
     """The public names, and the module's state: the session's summary
-    rows and nothing else (no second timeline beside the trace)."""
+    rows, and since PR 53 the compile log with its one running entry for
+    stray compiles; nothing else (no second timeline beside the trace)."""
     assert sorted(profiler.__all__) == sorted(
         ["cuda_profiler", "reset_profiler", "start_profiler",
-         "stop_profiler", "profiler", "record_event"])
+         "stop_profiler", "profiler", "record_event", "compile_log",
+         "compile_totals"])
     state = {k for k, v in vars(profiler).items()
-             if isinstance(v, (list, dict)) and not k.startswith("__")}
-    assert state == {"_records"}
+             if isinstance(v, (list, dict)) and not k.startswith("__")
+             and k not in ("_PHASES", "_COMPILE_SPANS")}     # constants
+    assert state == {"_records", "_log", "_stray"}
 
 
 # ---------------------------------------------------------------------
