@@ -195,7 +195,16 @@ _DECODE_COUNTERS = (
     # not the time): beside decode_batches_total it says which of the two,
     # slots or pages, set the batch.
     "loop_layer_passes_total", "loop_positions_attended_total",
-    "decode_page_bound_total")
+    "decode_page_bound_total",
+    # a whole-prompt request takes its ``sequence`` pages as it writes
+    # them (PR 54), and admission is by the residents' projected peak
+    # (``_admit``): the pages rows were granted after their admission,
+    # before the decode dispatch that writes them; the admission passes
+    # in which the queue's head had its first pages free and the
+    # projected peak refused it (a part of page_wait_total); and the
+    # growths that found no page, which the rule excludes: MUST read 0
+    "pages_grown_total", "admit_projection_refusals_total",
+    "page_stall_total")
 
 # how long after a program's end the worker keeps polling before it reads
 # the tokens and counters whose host copies set out with the program: the
@@ -229,7 +238,8 @@ class DecodeConfig:
     ``max_new_tokens`` the per-request generation cap; ``page_size``
     positions per KV page; ``n_pages`` pool size (None → full
     residency: every slot can hold its longest sequence — smaller
-    values overcommit and admission waits for pages);
+    values overcommit and admission waits until the residents'
+    projected peak leaves room, ``DecodeEngine._admit``);
     ``decode_block`` tokens generated per decode dispatch (the
     dispatch-overhead amortizer; admission/retirement happen at block
     boundaries); ``prefill_batch`` the most same-bucket requests one
@@ -388,23 +398,52 @@ class DecodeRequest:
         return self._result
 
 
+def projected_peak(pos, left, total, grows, page_size, block):
+    """The most ``sequence`` pages the rows hold together at any decode
+    dispatch to come, in pure integers. Row ``i`` has ``pos[i]`` positions
+    cached and ``left[i]`` tokens still to emit, and every decode dispatch
+    advances every row ``block`` positions, so it runs ``d = ceil(left /
+    block)`` more dispatches. Where ``grows[i]`` it takes its pages as it
+    writes them: during dispatch ``j < d`` (the next is 0) it holds
+    ``min(total[i], pages for pos[i] + (j + 1) x block positions)`` and
+    from ``j = d`` on nothing; where not, it holds ``total[i]`` at every
+    ``j`` (nothing is assumed of when it leaves). The sum only rises
+    between two retirements, so it is evaluated at each growing row's
+    LAST dispatch and nowhere else."""
+    pos, left, total = (np.asarray(x, np.int64).reshape(-1, 1)
+                        for x in (pos, left, total))
+    grows = np.asarray(grows, bool).reshape(-1, 1)
+    if not pos.size:
+        return 0
+    last = -(-left // block)
+    at = np.unique(np.where(grows, np.maximum(last, 1), 1))[None] - 1
+    need = np.minimum(total, -(-(pos + (at + 1) * block) // page_size))
+    held = np.where(grows, np.where(at < last, need, 0), total)
+    return int(held.sum(0).max())
+
+
 class _Slot:
     """One active decode slot: the request, its page set / table row,
     and the per-sequence scheduler state."""
 
     __slots__ = ("req", "held", "table", "pos", "cur", "prev",
-                 "emitted", "first_token_at")
+                 "emitted", "first_token_at", "grows_to")
 
     def __init__(self, req, held, table, pos, cur, prev, emitted,
-                 first_token_at):
+                 first_token_at, grows_to=None):
         self.req = req
-        self.held = held              # cache kind -> its pages (_alloc)
+        # cache kind -> its pages (_alloc); the ``sequence`` kind's list
+        # is appended to where the row grows (_grow)
+        self.held = held
         self.table = table            # np int32 [pages_per_seq]
         self.pos = pos                # cache length (cur not cached yet)
         self.cur = cur                # last emitted token
         self.prev = prev              # token at pos - 1
         self.emitted = emitted        # generated tokens so far
         self.first_token_at = first_token_at
+        # the ``sequence`` pages it may come to hold (_pages_needed) where
+        # it takes them as it writes them; None: it holds them all
+        self.grows_to = grows_to
 
 
 class _ChunkJob:
@@ -439,7 +478,9 @@ class DecodeEngine:
     ``state`` kind ONE entry a request, which no position indexes
     (kv_pages.py). One ``PageAllocator`` serves them all; a slot or chunk
     job holds a MAPPING, cache kind -> its pages of that kind (``held``),
-    granted together and freed together: admission, retirement, shedding
+    granted together (but for the ``sequence`` pages a whole-prompt
+    request takes as it writes them: ``_admit``) and freed together:
+    admission, retirement, shedding
     and the handoff blob cover every kind the model has, and every
     program takes the rows' table of each kind, in ``programs.kinds``'
     order behind the page table. A model with one kind has one table and
@@ -1316,6 +1357,92 @@ class DecodeEngine:
         for kind, pages in held.items():
             self.allocator.free(pages, kind)
 
+    def _grows(self, r):
+        """Whether request ``r`` takes its ``sequence`` pages as it writes
+        them. Not where the engine cannot tell when its rows will ask: a
+        speculative round advances rows unequally, a chunk job's decode
+        starts an unknown number of dispatches later; and not a handoff
+        import or a ``prefill_only`` request, whose pages travel whole.
+        Those keep their whole reservation from admission on."""
+        return (self.draft_cfg is None and r.handoff_state is None
+                and not r.prefill_only and not self._is_chunk_path(r))
+
+    def _grant(self, r, granted=(), grant=None):
+        """What ``r`` is admitted with, or None where it has to wait
+        (``_admit`` has the rule): ``(held, row)``, its pages by cache
+        kind and its row ``(pos, left, total, grows)`` of the projection.
+        Its pages of every kind must be free now, and the residents'
+        projected peak, with ``r`` and the rows ``granted`` in this pass
+        before it counted, inside the pool. ``grant``: as ``_alloc``'s."""
+        total = self._pages_needed(r.prompt.size, r.max_new)
+        if self._grows(r):
+            first = min(total, self.allocator.pages_for(
+                r.prompt.size + self.config.decode_block))
+            row = (r.prompt.size, r.max_new - 1, total, True)
+        else:
+            first, row = total, (0, 0, total, False)
+        # a prefill_only request's pages are exported and freed as its
+        # first token lands, before any decode dispatch: no row at all
+        transient = r.prefill_only and not self._is_chunk_path(r)
+        with self._slots_lock:
+            try:
+                held = self._alloc(first, grant)
+            except PagesExhaustedError:
+                return None
+            if not transient and not self._peak_fits([*granted, row]):
+                self._free(held)
+                self.metrics.incr("admit_projection_refusals_total")
+                return None
+        return held, row
+
+    def _peak_fits(self, newcomers):
+        """Whether the ``sequence`` pool holds the projected peak
+        (``projected_peak``) of the residents, slots and chunk jobs, and
+        ``newcomers``, rows ``(pos, left, total, grows)``. Not computed
+        where everybody's whole reservation fits anyway: always, in a pool
+        sized for ``max_batch`` longest requests. Under ``_slots_lock``."""
+        usable = self.allocator.usable_pages
+        if usable >= self.config.max_batch * self.pages_per_seq:
+            return True
+        seq = PageAllocator.SEQUENCE
+        rows = list(newcomers)
+        for slot in self.slots:
+            if slot is not None:
+                rows.append((slot.pos, slot.req.max_new - len(slot.emitted),
+                             slot.grows_to or len(slot.held[seq]),
+                             slot.grows_to is not None))
+        rows += [(0, 0, len(job.held[seq]), False)
+                 for job in self._chunk_jobs.values()]
+        pos, left, total, grows = zip(*rows)
+        return sum(total) <= usable or projected_peak(
+            pos, left, total, grows, self.config.page_size,
+            self.config.decode_block) <= usable
+
+    def _grow(self, idx, slot):
+        """Slot ``idx`` takes the pages its next decode dispatch writes and
+        it does not hold yet: appended to what it holds, written into its
+        table. False where the row sits the dispatch out: close() or the
+        watchdog took it meanwhile, or the pool had no page, which the
+        admission rule excludes (``page_stall_total``: a fault, not
+        traffic)."""
+        seq = slot.held[PageAllocator.SEQUENCE]
+        short = min(slot.grows_to, self.allocator.pages_for(
+            slot.pos + self.config.decode_block)) - len(seq)
+        if short < 1:
+            return True
+        with self._slots_lock:
+            if self.slots[idx] is not slot:
+                return False
+            try:
+                pages = self.allocator.alloc(short)
+            except PagesExhaustedError:
+                self.metrics.incr("page_stall_total")
+                return False
+            slot.table[len(seq):len(seq) + short] = pages
+            seq.extend(pages)
+        self.metrics.incr("pages_grown_total", short)
+        return True
+
     def _bucket_for(self, prompt_len):
         for b in self.config.prompt_buckets:
             if b >= prompt_len:
@@ -1466,9 +1593,28 @@ class DecodeEngine:
         (_prefill_request). Each first token is installed as its own
         dispatch returns, nothing waits to fill a dispatch, and a
         request runs the same executable alone or in company.
-        Transient page exhaustion leaves requests queued (retirement
-        frees pages and wakes admission); a terminal prefill failure
-        fails only that dispatch's request."""
+
+        **What a request holds, and who comes in** (``_grant``). A
+        whole-prompt request takes its ``sequence`` pages AS IT WRITES
+        THEM: at admission those its prefill writes and its first decode
+        dispatch needs, before every later dispatch the difference
+        (``_step``, ``_grow``); of every other kind, and where the engine
+        cannot tell when a row will ask (``_grows``), the whole
+        reservation of ``_pages_needed`` at once. The engine knows every
+        resident's ``max_new`` and every decode dispatch advances every
+        row ``decode_block`` positions, so the pool's use at every
+        dispatch to come is known now (``projected_peak``). The queue's
+        head comes in iff its admission pages are free now AND the
+        residents' projected peak, with it counted, is at most the pool.
+        THE INVARIANT: the projected peak is at most the pool after every
+        admission, and a retirement, an early end (``eos_id``, a deadline,
+        an error) or a close only lowers the sum at every dispatch to
+        come; so every growth finds its page, no row ever stalls and none
+        is preempted or recomputed. A growth that finds none is a fault
+        of this rule, counted in ``page_stall_total``. A request refused
+        either way goes back to the queue's front and waits for a
+        retirement (``_page_wait``); a terminal prefill failure fails
+        only that dispatch's request."""
         admitted = False
         self._page_bound = False
         while True:
@@ -1518,22 +1664,17 @@ class DecodeEngine:
                 admitted = True
                 continue
             bucket, group = plan[1], plan[2]
-            granted = []       # (req, held) actually prefilling now
+            granted = []       # (req, held, row) actually prefilling now
             starved = []
-            for j, r in enumerate(group):
-                if starved:
+            for r in group:
+                got = None if starved else self._grant(
+                    r, [row for _, _, row in granted])
+                if got is None:
+                    if not starved:
+                        self._page_wait()
                     starved.append(r)
                     continue
-                try:
-                    with self._slots_lock:
-                        pages = self._alloc(
-                            self._pages_needed(r.prompt.size,
-                                               r.max_new))
-                except PagesExhaustedError:
-                    self._page_wait()
-                    starved.append(r)
-                    continue
-                granted.append((r, pages))
+                granted.append((r, *got))
             if starved:        # put them back at the front, in order
                 with self._qlock:
                     self._queue[0:0] = starved
@@ -1542,17 +1683,18 @@ class DecodeEngine:
             self.metrics.set_queue_depth(len(self._queue))
             if not self.breaker.allow():
                 with self._slots_lock:
-                    for _, held in granted:
+                    for _, held, _ in granted:
                         self._free(held)
                 self.metrics.incr("breaker_shed_total", len(granted))
-                for r, _ in granted:
+                for r, _, _ in granted:
                     r.set_error(ServiceUnavailableError(
                         "circuit breaker open — prefill shed; back "
                         f"off {self.config.breaker_cooldown_s}s"))
                 continue
-            for (r, pages), idx in zip(granted, free):
-                admitted |= self._prefill_request(policy, bucket, r,
-                                                  pages, idx)
+            for (r, held, (_, _, total, grows)), idx in zip(granted, free):
+                admitted |= self._prefill_request(
+                    policy, bucket, r, held, idx,
+                    grows_to=total if grows else None)
         return admitted
 
     def _page_wait(self):
@@ -1562,12 +1704,16 @@ class DecodeEngine:
         self.metrics.incr("page_wait_total")
         self._page_bound = True
 
-    def _prefill_request(self, policy, bucket, r, held, idx):
+    def _prefill_request(self, policy, bucket, r, held, idx,
+                         grows_to=None):
         """One whole-prompt request's own dispatch of its bucket's
         single-row program (the draft's behind it), and its first token
         installed as that dispatch returns: True. A terminal failure
         frees the pages and fails this request alone: False. ``held``:
-        the request's pages by cache kind, as ``_alloc`` gave them."""
+        the request's pages by cache kind, as ``_grant`` gave them: where
+        the row grows (``grows_to``, ``_Slot``'s) they end short of the
+        bucket, and the padding's entries land on the null page, where an
+        inactive row's do."""
         pages = held[PageAllocator.SEQUENCE]
         kind_tables = self._kind_tables([held])
         tokens = np.zeros((1, bucket), np.int64)
@@ -1615,7 +1761,8 @@ class DecodeEngine:
                    prefill_padded_tokens_total=bucket,
                    state_resets_total=int(self.STATE in held),
                    queue_wait_s_total=admitted_at - r.enqueued_at)
-        self._install_first_token(r, held, table[0], int(nxt[0]), idx)
+        self._install_first_token(r, held, table[0], int(nxt[0]), idx,
+                                  grows_to)
         return True
 
     def _score_ttft(self, r):
@@ -1631,12 +1778,14 @@ class DecodeEngine:
                               else "slo_ttft_violated")
         self.metrics.observe_window(f"{slo.name}.ttft_s", r.ttft_s)
 
-    def _install_first_token(self, r, held, table, first, idx):
+    def _install_first_token(self, r, held, table, first, idx,
+                             grows_to=None):
         """Post-prefill bookkeeping shared by whole-prompt admission
         and the final chunk of a chunked prefill: TTFT accounting,
         then either a decode slot install or — for ``prefill_only``
         requests — a KV handoff export (the request resolves with the
-        handoff blob instead of occupying a slot)."""
+        handoff blob instead of occupying a slot). ``grows_to``: the
+        slot's (``_Slot``)."""
         now = time.monotonic()
         r.ttft_s = now - r.enqueued_at
         self.metrics.observe_window("ttft_s", r.ttft_s)
@@ -1652,7 +1801,7 @@ class DecodeEngine:
             self.slots[idx] = _Slot(
                 r, held, table, pos=r.prompt.size, cur=first,
                 prev=int(r.prompt[-1]), emitted=[first],
-                first_token_at=now)
+                first_token_at=now, grows_to=grows_to)
         eos = self.config.eos_id
         if (eos is not None and first == eos) or r.max_new == 1:
             self._retire(idx, draining=self._closed
@@ -1740,15 +1889,13 @@ class DecodeEngine:
                 {"pages": pages, "page_size": state["page_size"]},
                 total=n, kind=kind)
 
-        try:
-            with self._slots_lock:
-                held = self._alloc(
-                    self._pages_needed(r.prompt.size, r.max_new), grant)
-        except PagesExhaustedError:
+        got = self._grant(r, grant=grant)
+        if got is None:
             self._page_wait()
             with self._qlock:
                 self._queue.insert(0, r)
             return False
+        held = got[0]
         import jax.numpy as jnp
         pages = held[PageAllocator.SEQUENCE]
         rows = {kind: np.asarray(
@@ -1779,15 +1926,13 @@ class DecodeEngine:
         one per engine iteration in _step_chunks, interleaved with the
         decode batch. Returns False (request requeued at the front) on
         page exhaustion."""
-        try:
-            with self._slots_lock:
-                held = self._alloc(
-                    self._pages_needed(r.prompt.size, r.max_new))
-        except PagesExhaustedError:
+        got = self._grant(r)
+        if got is None:
             self._page_wait()
             with self._qlock:
                 self._queue.insert(0, r)
             return False
+        held = got[0]
         pages = held[PageAllocator.SEQUENCE]
         table = np.zeros((self.pages_per_seq,), np.int32)
         table[:len(pages)] = pages
@@ -1920,18 +2065,30 @@ class DecodeEngine:
         if not active:
             return True
         c = self.config
+        # a row that takes its pages as it writes them is granted, before
+        # the dispatch, what it writes in it (_grow)
+        seq = PageAllocator.SEQUENCE
+        sits_out = [i for i, slot in active if slot.grows_to is not None
+                    and slot.pos + c.decode_block
+                    > len(slot.held[seq]) * c.page_size
+                    and not self._grow(i, slot)]
+        if sits_out:
+            active = [(i, slot) for i, slot in active if i not in sits_out]
+            if not active:
+                return False
         B = c.max_batch
         toks = np.zeros((B,), np.int64)
         prev = np.zeros((B,), np.int64)
         pos = np.ones((B,), np.int32)
         table = np.zeros((B, self.pages_per_seq), np.int32)
+        helds = [None] * B
         for i, slot in active:
             toks[i] = slot.cur
             prev[i] = slot.prev
             pos[i] = slot.pos
             table[i] = slot.table
-        kind_tables = self._kind_tables(
-            [None if s is None else s.held for s in self.slots])
+            helds[i] = slot.held
+        kind_tables = self._kind_tables(helds)
         deadlines = [s.req.deadline for _, s in active
                      if s.req.deadline is not None]
         batch_deadline = min(deadlines) if deadlines else None

@@ -45,6 +45,8 @@ a model with one kind never names it).
 
 Pure host-side integers: no jax, no numpy, trivially unit-testable.
 """
+import heapq
+
 from .batching import QueueFullError
 
 __all__ = ["PagesExhaustedError", "PageAllocator"]
@@ -75,7 +77,10 @@ class PageAllocator:
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
         self.page_size = int(page_size)
-        self._n_pages, self._free_of = {}, {}
+        # a kind's free pages twice: the set answers "is it free", the
+        # heap hands out the lowest without sorting the pool (a row that
+        # takes its pages as it writes them asks every few dispatches)
+        self._n_pages, self._free_of, self._heap_of = {}, {}, {}
         self.add_kind(self.SEQUENCE, n_pages)
 
     def add_kind(self, kind, n_pages):
@@ -89,6 +94,7 @@ class PageAllocator:
                 f"page), got {n_pages}")
         self._n_pages[kind] = int(n_pages)
         self._free_of[kind] = set(range(1, int(n_pages)))
+        self._heap_of[kind] = list(range(1, int(n_pages)))
 
     @property
     def kinds(self):
@@ -146,7 +152,8 @@ class PageAllocator:
                 f"KV page pool exhausted: need {n} {kind} pages, "
                 f"{len(free)}/{self.usable_of(kind)} free — load "
                 "shed, retry with backoff (or grow n_pages)")
-        got = sorted(free)[:n]
+        heap = self._heap_of[kind]
+        got = [heapq.heappop(heap) for _ in range(n)]
         free.difference_update(got)
         return got
 
@@ -162,7 +169,11 @@ class PageAllocator:
                     f"[1, {self._n_pages[kind]}) of {kind} pages")
             if p in free:
                 raise ValueError(f"double free of {kind} page {p}")
+        if len(set(pages)) != len(pages):
+            raise ValueError(f"double free among {kind} pages {pages}")
         free.update(pages)
+        for p in pages:
+            heapq.heappush(self._heap_of[kind], p)
 
     # -- KV handoff hooks ------------------------------------------------
     def export_state(self, pages, kind=SEQUENCE):

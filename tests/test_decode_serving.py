@@ -27,6 +27,7 @@ from paddle_tpu.models.llama import (LlamaConfig, build_llama_generator,
                                      quantize_generator_weights)
 from paddle_tpu.resilience import faultinject
 from paddle_tpu.resilience.retry import RetryPolicy, TransientDeviceError
+from paddle_tpu.serving.decode_engine import projected_peak
 from paddle_tpu.serving import (BucketError, DecodeConfig, DecodeEngine,
                                 PageAllocator, PagesExhaustedError,
                                 PoolsLostError, QueueFullError,
@@ -449,7 +450,9 @@ def test_transient_exhaustion_queues_and_reuses_pages(tight_engine):
     retired request's pages produces its run-alone tokens exactly
     (stale page contents are unobservable behind the length mask)."""
     rng = np.random.RandomState(2)
-    prompts = _prompts(3, rng, lo=4, hi=8)
+    # prompts that write a second page in their first decode dispatch:
+    # two of them are four pages, over the pool from admission on
+    prompts = _prompts(3, rng, lo=6, hi=8)
     reqs = [tight_engine.submit(p, max_new=4, timeout=120)
             for p in prompts]
     together = [r.result(120) for r in reqs]
@@ -921,6 +924,260 @@ def test_drain_completes_admitted_requests(served_scope):
     for r in reqs:
         assert len(r.result(1.0)) == 6    # all admitted work finished
     assert eng.stats()["drained_total"] >= 1
+
+
+# ---------------------------------------------------------------------
+# a row takes its pages as it writes them; admission by the residents'
+# projected peak (DecodeEngine._admit)
+# ---------------------------------------------------------------------
+
+def _walk(pos, left, total, grows, page_size, block):
+    """projected_peak by brute force: every dispatch walked, one by one,
+    until no growing row is left."""
+    peak, j = 0, 0
+    while True:
+        held, live = 0, False
+        for p, n, t, g in zip(pos, left, total, grows):
+            if not g:
+                held += t
+            elif j < -(-n // block):
+                held += min(t, -(-(p + (j + 1) * block) // page_size))
+                live = True
+        peak = max(peak, held)
+        if not live:
+            return peak
+        j += 1
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_projected_peak_is_the_walk_of_every_dispatch(seed):
+    """The projection, evaluated at the rows' last dispatches alone,
+    against a walk of every dispatch: random prompts, answers and
+    progress, rows that grow and rows that keep a whole reservation, a
+    row with nothing left to emit, and no row at all."""
+    rng = np.random.RandomState(100 + seed)
+    assert projected_peak([], [], [], [], 16, 4) == 0
+    for _ in range(50):
+        n = int(rng.randint(1, 13))
+        page_size = int(rng.choice([1, 4, 8, 16]))
+        block = int(rng.choice([1, 2, 3, 4]))
+        prompt = rng.randint(1, 41, n)
+        max_new = rng.randint(1, 41, n)
+        emitted = np.asarray([rng.randint(1, m + 1) for m in max_new])
+        pos, left = prompt + emitted - 1, max_new - emitted
+        total = -(-(prompt + max_new + block) // page_size)
+        grows = rng.rand(n) < 0.7
+        assert projected_peak(pos, left, total, grows, page_size, block) \
+            == _walk(pos, left, total, grows, page_size, block)
+    # three rows of four pages each, one dispatch from their ends, and a
+    # newcomer that will not reach its fourth page before they are gone
+    assert projected_peak([14, 14, 14, 4], [1, 1, 1, 12], [4] * 4,
+                          [True] * 4, 4, 2) == 4 * 3 + 2
+
+
+def _tight(scope, **over):
+    """Eight slots over a pool of three longest requests (11 pages of 4
+    each), no worker yet: what is queued before ``start()`` meets one
+    admission pass."""
+    conf = dict(max_batch=8, prompt_buckets=(8, 16), max_new_tokens=24,
+                page_size=4, decode_block=2, prefill_batch=2,
+                n_pages=3 * 11 + 1, max_queue=32)
+    return _group_engine(scope, **dict(conf, **over))
+
+
+def _watch(eng, at=None):
+    """Records, inside every decode (or speculative) dispatch of ``eng``,
+    (rows live, requests queued, [(sequence pages held, positions they
+    must cover, grows_to, request) a live row]); ``at(n, slots)`` is
+    called in the n-th dispatch, on the worker's thread."""
+    seen = []
+
+    def watched(run):
+        def dispatch(*args):
+            slots = [s for s in eng.slots if s is not None]
+            seen.append((len(slots), len(eng._queue), [
+                (len(s.held["sequence"]),
+                 s.pos + eng.config.decode_block, s.grows_to, s.req)
+                for s in slots]))
+            if at is not None:
+                at(len(seen), slots)
+            return run(*args)
+        return dispatch
+
+    eng._run_decode_program = watched(eng._run_decode_program)
+    eng._run_spec_program = watched(eng._run_spec_program)
+    return seen
+
+
+def _books_balance(eng, seen):
+    """After a run: no growth ever found the pool empty, every page is
+    back, and in every dispatch a growing row held the pages that
+    dispatch wrote and not one more."""
+    st = eng.stats()
+    assert st["page_stall_total"] == 0
+    assert st["pages_in_use"] == 0 and eng._active() == []
+    for _, _, rows in seen:
+        for held, reach, grows_to, _ in rows:
+            if grows_to is not None:
+                assert held == min(grows_to, eng.allocator.pages_for(reach))
+    return st
+
+
+@pytest.fixture(scope="module")
+def unequal(served_scope):
+    """Fourteen requests of unequal lengths, and each one's tokens
+    alone (a pool that holds every slot's longest request)."""
+    rng = np.random.RandomState(21)
+    prompts = _prompts(14, rng, lo=6, hi=16)
+    news = [int(n) for n in rng.randint(10, 25, len(prompts))]
+    roomy = _tight(served_scope[0], n_pages=None)
+    try:
+        roomy.start()
+        alone = [roomy.generate(p, max_new=n, timeout=120)
+                 for p, n in zip(prompts, news)]
+        st = roomy.stats()
+    finally:
+        roomy.close()
+    # a roomy pool never computes a projection, and never waits
+    assert st["page_wait_total"] == 0 == st["page_stall_total"]
+    assert st["admit_projection_refusals_total"] == 0
+    assert st["pages_grown_total"] > 0
+    return prompts, news, alone
+
+
+def test_rows_that_take_pages_as_they_write_them_hold_more_rows(
+        served_scope, unequal):
+    """A pool of three whole reservations under eight slots: every
+    request returns its tokens alone, no growth ever stalls, every page
+    comes back, and a decode dispatch holds more rows than the same
+    requests do where every row keeps its whole reservation (the rule
+    before: ``_grows`` false for all)."""
+    prompts, news, alone = unequal
+    mean_rows, stats = {}, {}
+    for rule in ("grows", "whole"):
+        eng = _tight(served_scope[0])
+        if rule == "whole":
+            eng._grows = lambda r: False
+        seen = _watch(eng)
+        try:
+            reqs = [eng.submit(p, max_new=n, timeout=120)
+                    for p, n in zip(prompts, news)]
+            eng.start()
+            got = [r.result(120) for r in reqs]
+            eng.close(drain=True)
+            stats[rule] = _books_balance(eng, seen)
+        finally:
+            eng.close()
+        for a, b in zip(got, alone):
+            np.testing.assert_array_equal(a, b)
+        # while a request was waiting, so the drain's tail is left out
+        bound = [rows for rows, queued, _ in seen if queued]
+        mean_rows[rule] = sum(bound) / len(bound)
+        assert all(held <= 33 for held in (
+            sum(h for h, _, _, _ in rows) for _, _, rows in seen))
+    new, old = stats["grows"], stats["whole"]
+    assert new["pages_grown_total"] > 0 == old["pages_grown_total"]
+    assert new["admit_projection_refusals_total"] > 0
+    assert new["page_wait_total"] > 0 and old["page_wait_total"] > 0
+    assert old["admit_projection_refusals_total"] == 0
+    assert mean_rows["grows"] > mean_rows["whole"] + 0.5
+    assert new["decode_batches_total"] < old["decode_batches_total"]
+    assert new["shed_total"] == 0 == new["errors_total"]
+
+
+@pytest.mark.parametrize("how", ["eos", "deadline", "drain"])
+def test_an_answer_that_ends_early_only_frees_pages_sooner(
+        served_scope, unequal, how):
+    """``eos_id`` ending answers early, a deadline expiring mid-answer
+    and ``close(drain=True)`` all keep the invariant (no growth stalls)
+    and free every page; whoever finishes returns its tokens alone."""
+    prompts, news, alone = unequal
+    want, over, late = list(alone), {}, []
+    if how == "eos":
+        # a token inside the longest answer, not its last: that answer
+        # ends at it, and so does every other answer that emits it
+        eos = int(max(alone, key=len)[3])
+        over["eos_id"] = eos
+        want = [a[:list(a).index(eos) + 1] if eos in a else a
+                for a in alone]
+        assert any(len(w) < len(a) for w, a in zip(want, alone))
+
+    def expire(n, slots):
+        if n == 3:                        # mid-answer, on the worker
+            late.append(max(slots, key=lambda s: s.req.max_new).req)
+            late[0].deadline = time.monotonic() - 1.0
+
+    eng = _tight(served_scope[0], **over)
+    seen = _watch(eng, expire if how == "deadline" else None)
+    try:
+        reqs = [eng.submit(p, max_new=n, timeout=120)
+                for p, n in zip(prompts, news)]
+        eng.start()
+        if how == "drain":
+            eng.close(drain=True)         # everything queued is finished
+            assert all(r.done() for r in reqs)
+        assert all(r.wait(120) for r in reqs)
+        for r, w in zip(reqs, want):
+            if r in late:
+                with pytest.raises(RequestTimeoutError):
+                    r.result(120)
+            else:
+                np.testing.assert_array_equal(r.result(120), w)
+        eng.close(drain=True)
+        st = _books_balance(eng, seen)
+    finally:
+        eng.close()
+    assert st["pages_grown_total"] > 0 and st["page_wait_total"] > 0
+    assert st["timeouts_total"] == len(late) == (how == "deadline")
+
+
+@pytest.mark.parametrize("who", ["draft", "chunk", "handoff",
+                                 "prefill_only"])
+def test_who_keeps_a_whole_reservation(served_scope, who):
+    """An engine with a draft (a speculative round advances rows
+    unequally), a chunk-path request (its decode starts an unknown
+    number of dispatches later), a handoff import and a ``prefill_only``
+    request (their pages travel whole) hold from admission what
+    ``_pages_needed`` says, as before, and never grow."""
+    scope = served_scope[0]
+    over = {}
+    if who == "draft":
+        with fluid.scope_guard(scope):
+            copy_weights_as_draft(scope)
+        over = dict(draft_cfg=CFG, gamma=3)
+    elif who == "chunk":
+        over = dict(chunk_size=4)
+    rng = np.random.RandomState(31)
+    prompt = rng.randint(0, CFG.vocab_size, (9,)).astype(np.int64)
+    plain = _tight(scope, n_pages=None)
+    eng = _tight(scope, **over)
+    seen = _watch(eng)
+    try:
+        plain.start(), eng.start()
+        alone = plain.generate(prompt, max_new=12, timeout=120)
+        need = eng._pages_needed(prompt.size, 12)
+        if who == "prefill_only":
+            blob = eng.submit(prompt, max_new=12,
+                              prefill_only=True).result(120)
+            assert len(blob["pages"]) == need and not seen
+            got = plain.import_handoff(blob).result(120)
+        elif who == "handoff":
+            got = eng.import_handoff(plain.submit(
+                prompt, max_new=12, prefill_only=True).result(120)
+            ).result(120)
+        else:
+            got = eng.generate(prompt, max_new=12, timeout=120)
+        np.testing.assert_array_equal(got, alone)
+        assert eng.stats()["chunk_prefill_total"] == 3 * (who == "chunk")
+        assert eng.stats()["spec_rounds_total"] > 0 or who != "draft"
+        st = _books_balance(eng, seen)
+    finally:
+        plain.close(), eng.close()
+    assert seen or who == "prefill_only"
+    for _, _, rows in seen:
+        assert [(held, grows_to) for held, _, grows_to, _ in rows] \
+            == [(need, None)]
+    assert st["pages_grown_total"] == 0
 
 
 # ---------------------------------------------------------------------

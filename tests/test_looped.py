@@ -377,3 +377,49 @@ def test_a_pool_of_three_requests_serves_eight_slots_requests(served):
     assert s["retired_total"] == 8 and s["pages_in_use"] == 0
     assert s["shed_total"] == 0 and s.get("preempted_total", 0) == 0
     assert s["pools_lost_total"] == 0
+
+
+@pytest.mark.serving
+def test_that_pool_holds_more_rows_than_three_whole_reservations(served):
+    """The same pool under requests of unequal answers: a row takes its
+    pages as it writes them through all 6 cache layers' pools, so more
+    than three rows are live in a decode dispatch at times; no growth
+    ever finds the pool empty, every request returns its tokens alone
+    and every page comes back."""
+    prompts = [prompt_of(5 + n % 4, seed=80 + n) for n in range(10)]
+    news = [8, 3, 6, 2, 8, 4, 7, 3, 5, 8]
+    roomy = engine_of(CFG, served[1], max_batch=8)
+    try:
+        roomy.start()
+        alone = [np.asarray(roomy.generate(p, max_new=n))
+                 for p, n in zip(prompts, news)]
+    finally:
+        roomy.close()
+    eng = engine_of(CFG, served[1], max_batch=8, n_pages=3 * 5 + 1,
+                    max_queue=16)
+    rows, run = [], eng._run_decode_program
+
+    def watched(*args):
+        live = [s for s in eng.slots if s is not None]
+        rows.append(len(live))
+        assert sum(len(s.held["sequence"]) for s in live) <= 15
+        assert all(s.grows_to == eng._pages_needed(s.req.prompt.size,
+                                                   s.req.max_new)
+                   for s in live)
+        return run(*args)
+
+    eng._run_decode_program = watched
+    try:
+        reqs = [eng.submit(p, max_new=n) for p, n in zip(prompts, news)]
+        eng.start()
+        tight = [np.asarray(r.result(120)) for r in reqs]
+        s = eng.stats()
+    finally:
+        eng.close()
+    for a, t in zip(alone, tight):
+        assert np.array_equal(a, t)
+    assert max(rows) > 3
+    assert s["page_stall_total"] == 0 and s["pages_grown_total"] > 0
+    assert s["page_wait_total"] > 0
+    assert s["retired_total"] == 10 and s["pages_in_use"] == 0
+    assert s["shed_total"] == 0 and s["pools_lost_total"] == 0
