@@ -6,7 +6,13 @@ softmax's denominator), each kind with its own key/value head count and
 rotary base; keys wider than values, a part of each head rotated, the
 values scaled; sigmoid-routed experts (ops/moe.py) on the plain residual
 path behind leading dense layers. MiMo-V2-Flash's block is a value of
-``HybridMoEConfig``.
+``HybridMoEConfig``, and Laguna-XS.2's is another: the window layers with
+their OWN query-head count and rotation (``n_heads_window``,
+``rotary_dim_window``; YaRN's frequencies and factor in the full layers,
+``yarn_full``), a sigmoid gate on every head's result (``head_gate``), a
+softmax router (``scoring``) and a shared expert beside the routed ones
+(``shared_hidden``), every expert held. A configuration that names none
+of these builds the parameters and the programs it built before them.
 
 The block is ops/transformer_ops.py ``block_forward`` at these kinds
 (``gqa`` + ``routed`` + ``plain``), the kinds of attention layer being
@@ -25,10 +31,10 @@ from ``experts_first`` on), and it is serving only.
 """
 from dataclasses import dataclass
 
-from ..ops.transformer_ops import HYBRID_STATS
+from ..ops.transformer_ops import HYBRID_STATS, yarn_inv_freq
 from .latent_moe import build_block_programs
 
-__all__ = ["HybridMoEConfig", "HYBRID_MOE_TINY"]
+__all__ = ["HybridMoEConfig", "HYBRID_MOE_TINY", "HYBRID_GATED_TINY"]
 
 FULL, WINDOW = 0, 1         # a layer's kind, as ``layer_pattern`` has it
 
@@ -61,6 +67,15 @@ class HybridMoEConfig:
     route_scale: float = 1.0
     norm_eps: float = 1e-5
     dtype: str = "bfloat16"
+    n_heads_window: int = None       # query heads of a window layer
+    rotary_dim_window: int = None    # and the widths it rotates
+    # the full layers' YaRN: factor, original_max, beta_fast, beta_slow
+    # (ops/transformer_ops.py yarn_inv_freq) and attention_factor, which
+    # multiplies their cosines and sines
+    yarn_full: dict = None
+    head_gate: bool = False          # sigmoid(u Wg) a head, before Wo
+    scoring: str = "sigmoid"         # the router's (ops/moe.py moe_route)
+    shared_hidden: int = 0           # a shared expert's SwiGLU; 0: none
 
     def __post_init__(self):
         self.layer_pattern = tuple(int(k) for k in self.layer_pattern)
@@ -80,9 +95,15 @@ class HybridMoEConfig:
                 "hold 0 (full) and 1 (window) alone, its leading dense "
                 "layers must be of one kind, and a routed layer must "
                 "follow them")
-        if self.rotary_dim % 2 or self.rotary_dim > self.head_dim:
-            raise ValueError(f"{self.name}: cannot rotate "
-                             f"{self.rotary_dim} of {self.head_dim} widths")
+        for rd in (self.rotary_dim, self.rotary(WINDOW)):
+            if rd % 2 or rd > self.head_dim:
+                raise ValueError(f"{self.name}: cannot rotate {rd} of "
+                                 f"{self.head_dim} widths")
+        for kind in (FULL, WINDOW):
+            if self.heads(kind) % self.n_kv(kind):
+                raise ValueError(
+                    f"{self.name}: {self.heads(kind)} query heads over "
+                    f"{self.n_kv(kind)} key/value heads")
 
     @property
     def n_layers(self):
@@ -100,6 +121,16 @@ class HybridMoEConfig:
     def n_kv(self, kind):
         return self.n_kv_window if kind == WINDOW else self.n_kv_full
 
+    def heads(self, kind):
+        """Query heads of a layer of attention kind ``kind``."""
+        return self.n_heads_window if kind == WINDOW \
+            and self.n_heads_window else self.n_heads
+
+    def rotary(self, kind):
+        """Leading widths a head of kind ``kind`` rotates."""
+        return self.rotary_dim if kind == FULL \
+            or self.rotary_dim_window is None else self.rotary_dim_window
+
     def ring_pages(self, page_size):
         """Pages of a row's ring: the window, in whole pages."""
         return -(-self.window // page_size)
@@ -115,10 +146,23 @@ class HybridMoEConfig:
              "base": self.rope_base_window, "window": self.window,
              "sink": self.sink_window, "stack": stack[WINDOW],
              "pools": [2, 3]}]
+        # a kind's own heads and rotation, named only where the model has
+        # them: a configuration without them keeps its attributes
+        for kind, spec in zip((FULL, WINDOW), attn_kinds):
+            if self.n_heads_window:
+                spec["n_heads"] = self.heads(kind)
+            if self.rotary_dim_window is not None:
+                spec["rotary_dim"] = self.rotary(kind)
+        if self.yarn_full:
+            y = self.yarn_full
+            attn_kinds[FULL]["inv_freq"] = [float(f) for f in yarn_inv_freq(
+                self.rotary_dim, self.rope_base_full, y["factor"],
+                y["original_max"], y["beta_fast"], y["beta_slow"])]
+            attn_kinds[FULL]["rope_factor"] = float(y["attention_factor"])
         return {
             "n_heads": self.n_heads, "epsilon": self.norm_eps,
             "attention": "gqa", "ffn": "routed", "residual": "plain",
-            "moe_top_k": self.moe_top_k, "scoring": "sigmoid",
+            "moe_top_k": self.moe_top_k, "scoring": self.scoring,
             "route_scale": self.route_scale, "n_group": 1,
             "topk_group": 1, "experts_first": self.experts_first,
             "kv_rank": 0, "rope_dim": 0, "nope_dim": 0,
@@ -134,9 +178,9 @@ class HybridMoEConfig:
         """slot -> (suffix, shape, dtype) of ``n_layers`` stacked layers
         of attention kind ``kind`` with a routed (else dense)
         feed-forward. The router (``router_width`` wide beside
-        ``n_experts`` held experts), its bias and the sinks are float32
-        whatever ``dtype`` is."""
-        L, D, H, G = n_layers, self.dim, self.n_heads, self.n_kv(kind)
+        ``n_experts`` held experts), its bias (a sigmoid router's) and
+        the sinks are float32 whatever ``dtype`` is."""
+        L, D, H, G = n_layers, self.dim, self.heads(kind), self.n_kv(kind)
         dt = self.dtype
         out = {
             "AttnNorm": ("attn_norm", [L, D], dt),
@@ -147,6 +191,8 @@ class HybridMoEConfig:
             "Wo": ("wo", [L, H * self.v_head_dim, D], dt)}
         if self.sink_window if kind == WINDOW else self.sink_full:
             out["Sink"] = ("sink", [L, H], "float32")
+        if self.head_gate:
+            out["Wg"] = ("wg", [L, D, H], dt)
         if not routed:
             F = self.ffn_hidden
             out.update(WGate=("w_gate", [L, D, F], dt),
@@ -154,11 +200,17 @@ class HybridMoEConfig:
                        WDown=("w_down", [L, F, D], dt))
             return out
         E, F, R = self.n_experts, self.expert_hidden, self.router_width
-        out.update(MoeRouter=("moe_router", [L, D, R], "float32"),
-                   MoeBias=("moe_bias", [L, R], "float32"),
-                   MoeWGate=("moe_w_gate", [L, E, D, F], dt),
+        out["MoeRouter"] = ("moe_router", [L, D, R], "float32")
+        if self.scoring == "sigmoid":
+            out["MoeBias"] = ("moe_bias", [L, R], "float32")
+        out.update(MoeWGate=("moe_w_gate", [L, E, D, F], dt),
                    MoeWUp=("moe_w_up", [L, E, D, F], dt),
                    MoeWDown=("moe_w_down", [L, E, F, D], dt))
+        if self.shared_hidden:
+            S = self.shared_hidden
+            out.update(ShWGate=("sh_w_gate", [L, D, S], dt),
+                       ShWUp=("sh_w_up", [L, D, S], dt),
+                       ShWDown=("sh_w_down", [L, S, D], dt))
         return out
 
     def stacks(self):
@@ -238,3 +290,21 @@ HYBRID_MOE_TINY = HybridMoEConfig(
     value_scale=0.707, window=4, ffn_hidden=64, n_experts=4,
     router_width=16, experts_first=4, moe_top_k=3, expert_hidden=16,
     dtype="float32")
+
+# Laguna-XS.2's mechanisms small: 6 query heads over 2 key/value heads in
+# full layers (3 a group: not a sublane tile) and 8 in window layers, 4 of
+# 8 widths rotated with YaRN's frequencies and factor in full layers and
+# all 8 plainly in window layers, a gate on every head, a softmax router
+# over 8 experts ALL held (3 a token, the routed sum scaled 2.5) beside a
+# shared expert, no sinks, no value scale, a window of 4
+HYBRID_GATED_TINY = HybridMoEConfig(
+    name="hybrid-gated-tiny", vocab_size=96, dim=32,
+    layer_pattern=(0, 1, 1, 1, 0), n_dense_layers=1, n_heads=6,
+    n_heads_window=8, head_dim=8, v_head_dim=8, n_kv_full=2, n_kv_window=2,
+    rope_base_full=5e2, rope_base_window=1e2, rotary_dim=4,
+    rotary_dim_window=8, yarn_full=dict(
+        factor=8.0, original_max=8, beta_fast=4.0, beta_slow=1.0,
+        attention_factor=1.2), value_scale=1.0, window=4, sink_window=False,
+    head_gate=True, scoring="softmax", shared_hidden=16, ffn_hidden=64,
+    n_experts=8, moe_top_k=3, expert_hidden=16, route_scale=2.5,
+    norm_eps=1e-6, dtype="float32")

@@ -11,14 +11,22 @@ constraints on the [experts, capacity, dim] intermediates.
 Everything is one fused XLA program: no per-expert Python loops, no
 dynamic shapes, no host round-trips.
 """
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..core.registry import register_op
+from . import pallas_attention as _pa
 
 __all__ = ["top_k_gating", "moe_apply", "moe_route", "moe_load",
-           "moe_apply_sorted", "moe_apply_no_drop", "moe_apply_no_drop_q"]
+           "moe_apply_sorted", "moe_apply_no_drop", "moe_apply_no_drop_q",
+           "few_rows_usable", "moe_apply_few_rows"]
+
+# rows the few-rows kernel takes: one pass of the MXU's 128-row tile
+FEW_ROWS = 128
 
 # Rows of a share's sorted pairs that one trip of the un-sort's loop sums
 # back to their tokens (moe_apply_sorted): swept on the chip at 256 / 512 /
@@ -154,6 +162,100 @@ def moe_load(idx, n_experts, valid=None, first=0):
     return hits.sum(axis=0)
 
 
+def few_rows_usable(t, w_gate, w_down, held=None):
+    """The gate of ``moe_apply_few_rows``: the backend runs Pallas kernels,
+    every expert of the layer is held, the rows are at most one MXU tile
+    (a decode step's: a prefill window sorts its pairs), the widths whole
+    lane tiles, and ONE expert's three matrices fit VMEM's default limit
+    twice over, one in flight while one is multiplied (512-wide experts of
+    a 2,048-wide model: 12 MB; an expert of 1,024 x 3,584 would want its
+    hidden width cut in tiles, which nobody has measured)."""
+    d, f = w_gate.shape[-2:]
+    return (_pa._use_pallas() and held is None and t <= FEW_ROWS
+            and d % 128 == 0 and f % 128 == 0
+            and w_gate.dtype == w_down.dtype
+            and 6 * d * f * w_gate.dtype.itemsize <= _pa._VMEM_DEFAULT)
+
+
+def _few_rows_kernel(layer_ref, touched_ref, n_ref, x_ref, c_ref, wg_ref,
+                     wu_ref, wd_ref, o_ref):
+    """Grid step ``i``: the ``i``-th expert that a row reached, its three
+    matrices in VMEM (the next one's in flight), on ALL the rows; a row
+    that did not pick it has weight 0.0 there and is left as it was."""
+    i = pl.program_id(0)
+
+    @pl.when(i == 0)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(i < n_ref[0])
+    def _():
+        x = x_ref[...]
+        g = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
+        h = ((g * jax.nn.sigmoid(g)) * u).astype(x.dtype)
+        o_ref[...] += c_ref[...] * jnp.dot(
+            h, wd_ref[...], preferred_element_type=jnp.float32)
+
+
+def moe_apply_few_rows(xt, idx, gates, w_gate, w_up, w_down, layer=None):
+    """``moe_apply_sorted`` for A FEW ROWS and every expert held (a decode
+    step: 64 rows x 8 picks over 256 experts are 2 rows an expert, and a
+    grouped matmul over 2-row groups reads their weights at a third of
+    the chip's bandwidth: PERF.md section 6, PR 55). No sort and no
+    gather: the experts that a row reached are visited in ascending
+    order, one a grid step, each multiplied with ALL the rows (at most
+    one MXU tile of them: the products cost what the copy of the expert's
+    6 MB costs) and added under the rows' own weights for it, 0.0 for a
+    row that did not pick it. An expert nobody picked is never copied. A
+    row's result is the float32 sum of its own experts' outputs in
+    ascending order of expert: it depends on no other row. Returns [T, D]
+    in xt's dtype."""
+    t, d = xt.shape
+    e, f = w_gate.shape[-3], w_gate.shape[-1]
+    if layer is None:
+        w_gate, w_up, w_down = (w[None] for w in (w_gate, w_up, w_down))
+        layer = 0
+    # [T, E]: a row's weight for every expert
+    weights = jnp.sum(jax.nn.one_hot(idx, e, dtype=jnp.float32)
+                      * gates[..., None].astype(jnp.float32), axis=1)
+    reached = moe_load(idx, e) > 0
+    n = jnp.sum(reached.astype(jnp.int32))
+    touched = jnp.argsort(~reached, stable=True).astype(jnp.int32)
+    # behind the last expert reached: itself again, so no copy is asked
+    touched = jnp.where(jnp.arange(e) < n, touched,
+                        touched[jnp.maximum(n - 1, 0)])
+    rows = -(-t // 16) * 16             # whole tiles of the rows' type
+    if rows != t:
+        xt = jnp.pad(xt, ((0, rows - t), (0, 0)))
+        weights = jnp.pad(weights, ((0, rows - t), (0, 0)))
+    by_expert = weights.T[touched][..., None]               # [E, rows, 1]
+    held = 6 * d * f * w_gate.dtype.itemsize \
+        + rows * d * (xt.dtype.itemsize + 8)
+
+    def expert(i, lyr, tch, n):
+        return lyr[0], tch[i], 0, 0
+
+    out = _pa._pcall(
+        _few_rows_kernel, name="moe_few_rows",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(e,),
+            in_specs=[
+                pl.BlockSpec((rows, d), lambda i, *_: (0, 0)),
+                pl.BlockSpec((None, rows, 1), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec((None, None, d, f), expert),
+                pl.BlockSpec((None, None, d, f), expert),
+                pl.BlockSpec((None, None, f, d), expert)],
+            out_specs=pl.BlockSpec((rows, d), lambda i, *_: (0, 0))),
+        out_shape=jax.ShapeDtypeStruct((rows, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=held + 8 * 2 ** 20),
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), touched,
+      jnp.reshape(n, (1,)), xt, by_expert, w_gate, w_up, w_down)
+    return out[:t].astype(xt.dtype)
+
+
 def moe_apply_sorted(xt, idx, gates, w_gate, w_up, w_down, layer=None,
                      held=None):
     """The drop-free expert layer: the T x K token-expert pairs sorted by
@@ -183,9 +285,17 @@ def moe_apply_sorted(xt, idx, gates, w_gate, w_up, w_down, layer=None,
     dropped. The sum back to the tokens walks those rows in blocks of
     ``UNSORT_BLOCK`` and stops behind the last pair held, so its cost
     follows the pairs this chip holds and not the rows set aside for
-    them."""
+    them.
+
+    The CALL decides its form, as the paged attention calls do: a few
+    rows over experts all held and small enough go through the kernel
+    ``moe_apply_few_rows`` where ``few_rows_usable`` says so (the chip;
+    the tests' interpreter hook), and this function is its reference."""
     t, k = idx.shape
     e = w_gate.shape[-3]
+    if few_rows_usable(t, w_gate, w_down, held):
+        return moe_apply_few_rows(xt, idx, gates, w_gate, w_up, w_down,
+                                  layer)
     local = idx
     if held is not None:
         first, width = held

@@ -338,6 +338,8 @@ PAGED_BLOCK_KEYS = 128
 # read 495 GB/s at 128 positions a block (0.33 MB), 692 at 256, 735 at 512
 # and no more at 1,024 or 2,048 (PERF.md section 6, PR 42)
 PAGED_FLAT_BLOCK_KEYS = 512
+# rows of a float32 tile: the kernel slices [heads, keys] weights a group
+_SUBLANES = 8
 # and of latent entries (8 pages of 64, 640 wide: 0.66 MB), by the chip:
 # xing4's 16 rows x 32 heads of 1,100-8,400 positions (58 operations a
 # byte: the copies bound it) read 294 GB/s at 128 positions a block, 434
@@ -598,15 +600,33 @@ def paged_flat_decode(q, k_pool, v_pool, layer, table, lengths, sink=None):
                                                  g):
         return _ref_paged_attention(q, k_pool, v_pool, layer, table,
                                     lengths, g, dk ** -0.5, sink)
-    own = (jnp.arange(n_heads)[:, None] // (n_heads // g)
+    # the kernel slices the [heads, keys] weights a group, so several
+    # heads a group go in as whole sublane tiles: 6 a group (48 over 8) as
+    # 8, the two behind them zero queries whose results are cut off again
+    # (their weights are uniform over the row's own positions: a number,
+    # and nobody's). 16 and 8 a group, one group, and one head a group
+    # (a slice of one row) go in as they are
+    rep = n_heads // g
+    padded = rep if g == 1 or rep == 1 \
+        else -(-rep // _SUBLANES) * _SUBLANES
+    if padded != rep:
+        q = jnp.pad(q.reshape(n_rows, g, rep, dk),
+                    ((0, 0), (0, 0), (0, padded - rep), (0, 0))).reshape(
+                        n_rows, g * padded, dk)
+    own = (jnp.arange(g * padded)[:, None] // padded
            == jnp.arange(g)[None])                          # [heads, g]
     expanded = jnp.where(own[None, :, :, None], q[:, :, None], 0).reshape(
-        n_rows, n_heads, g * dk)
-    return _paged_decode_call(
+        n_rows, g * padded, g * dk)
+    dv = v_pool.shape[3] // g
+    out = _paged_decode_call(
         "paged_flat_decode", functools.partial(
             _fold_flat, scale=dk ** -0.5, g=g),
-        expanded, (k_pool, v_pool), layer, table, lengths,
-        v_pool.shape[3] // g, PAGED_FLAT_BLOCK_KEYS)
+        expanded, (k_pool, v_pool), layer, table, lengths, dv,
+        PAGED_FLAT_BLOCK_KEYS)
+    if padded != rep:
+        out = out.reshape(n_rows, g, padded, dv)[:, :, :rep].reshape(
+            n_rows, n_heads, dv)
+    return out
 
 
 def paged_latent_usable(pool_shapes):

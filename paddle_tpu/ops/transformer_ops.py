@@ -46,13 +46,15 @@ def _rms_norm(ctx, ins, attrs):
                                 attrs.get("epsilon", 1e-6))]}
 
 
-def apply_rope_at(x, positions, base=10000.0, inv_freq=None):
+def apply_rope_at(x, positions, base=10000.0, inv_freq=None, factor=1.0):
     """x: [B, T, H, D]; positions: [T] absolute positions shared by the
     batch, or [B, T] per-row positions (the continuous-batching decode
     engine schedules rows at unrelated sequence offsets). Positions may
     be traced values — unlike apply_rope's table slicing, nothing here
     depends on them being static. ``inv_freq`` [D/2] replaces the plain
-    ``base ** (-2i / D)`` (yarn_inv_freq)."""
+    ``base ** (-2i / D)`` (yarn_inv_freq); ``factor`` multiplies the
+    cosines and sines (YaRN's attention factor, where a model carries it
+    on the rotation and not on the softmax's scale)."""
     b, t, h, d = x.shape
     inv = (1.0 / (base ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
            if inv_freq is None else jnp.asarray(inv_freq, jnp.float32))
@@ -63,6 +65,8 @@ def apply_rope_at(x, positions, base=10000.0, inv_freq=None):
     else:
         cos = jnp.cos(freqs)[:, :, None, :]
         sin = jnp.sin(freqs)[:, :, None, :]
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
                           axis=-1)
@@ -297,7 +301,14 @@ class BlockKinds:
     pools are this kind's: a kind with a window keeps a RING of the last
     positions, a kind without keeps the whole sequence), and
     ``layer_kinds`` names each layer's kind by its index there.
-    ``of(k)`` is this object at kind ``k``.
+    ``of(k)`` is this object at kind ``k``. A kind's dict may also carry
+    its OWN QUERY HEADS and rotation (absent: the model's): ``n_heads``
+    (its ``Wq`` / ``Wo`` are then another width than the other kinds'),
+    ``rotary_dim``, ``inv_freq`` (the rotated part's inverse frequencies,
+    ``yarn_inv_freq``; absent: ``base ** (-2i / rotary_dim)``) and
+    ``rope_factor`` (on the cosines and sines). ``gqa`` GATES each head's
+    result before ``Wo`` where the layer holds ``Wg`` [D, heads]:
+    ``sigmoid(u Wg)`` of the layer's normed input (arXiv:2505.06708).
 
     A kind of layer may have ANOTHER MIXER than attention: ``"mixer":
     "ssm"`` in its dict is the selective state-space mixer (ops/ssm.py),
@@ -358,6 +369,8 @@ class BlockKinds:
         self.key_dim, self.rotary_dim = key_dim, rotary_dim
         self.value_scale = value_scale
         self.window, self.sink = None, False
+        self.name = None            # of(k): the kind's, for its scopes
+        self.rotary_inv_freq, self.rotary_factor = None, 1.0
         self.attn_kinds = None if attn_kinds is None \
             else tuple(dict(k) for k in attn_kinds)
         self.layer_kinds = None if layer_kinds is None \
@@ -379,6 +392,11 @@ class BlockKinds:
         out.n_kv, out.base = kind["n_kv"], kind["base"]
         out.window, out.sink = kind["window"], kind["sink"]
         out.attention = kind.get("mixer", self.attention)
+        out.name = kind["name"]
+        out.n_heads = kind.get("n_heads", self.n_heads)
+        out.rotary_dim = kind.get("rotary_dim", self.rotary_dim)
+        out.rotary_inv_freq = kind.get("inv_freq")
+        out.rotary_factor = kind.get("rope_factor", 1.0)
         return out
 
 
@@ -386,19 +404,21 @@ def _gqa_attention(kinds, p, u, pos, attend_fn):
     """Grouped-query projections, the first ``rotary_dim`` widths of every
     query and key head rotated (all of them where None), the values times
     ``value_scale``; ``attend_fn(q, (k, v))`` owns the attention and any
-    cache."""
+    cache. Where the layer holds ``Wg`` each head's result is gated by
+    ``sigmoid(u Wg)`` [.., heads] before ``Wo``."""
     b, t, _ = u.shape
     hd = kinds.key_dim or p["Wq"].shape[-1] // kinds.n_heads
     rd = kinds.rotary_dim
+    rope = functools.partial(apply_rope_at, positions=pos, base=kinds.base,
+                             inv_freq=kinds.rotary_inv_freq,
+                             factor=kinds.rotary_factor)
 
     def rotate(x):
         if rd == 0:
             return x
         if rd is None or rd == hd:
-            return apply_rope_at(x, pos, kinds.base)
-        return jnp.concatenate(
-            [apply_rope_at(x[..., :rd], pos, kinds.base), x[..., rd:]],
-            axis=-1)
+            return rope(x)
+        return jnp.concatenate([rope(x[..., :rd]), x[..., rd:]], axis=-1)
 
     def heads(slot, norm, n):
         x = qmat(u, p, slot)
@@ -411,7 +431,14 @@ def _gqa_attention(kinds, p, u, pos, attend_fn):
     v = qmat(u, p, "Wv").reshape(b, t, kinds.n_kv, -1)
     if kinds.value_scale != 1.0:
         v = v * jnp.asarray(kinds.value_scale, v.dtype)
-    return qmat(attend_fn(q, (k, v)), p, "Wo")
+    a = attend_fn(q, (k, v))
+    if p.get("Wg") is not None:
+        with jax.named_scope("/".join(
+                x for x in ("attn", kinds.name, "gate") if x)):
+            g = jax.nn.sigmoid(qmat(u, p, "Wg").astype(jnp.float32))
+            a = (a.reshape(b, t, kinds.n_heads, -1).astype(jnp.float32)
+                 * g[..., None]).astype(a.dtype).reshape(a.shape)
+    return qmat(a, p, "Wo")
 
 
 def _latent_attention(kinds, p, u, pos, attend_fn):
@@ -1548,7 +1575,8 @@ class _PagedRunner:
         f32 = jnp.float32
         scale = q.shape[-1] ** -0.5
         k0, v0 = jax.eval_shape(read_block, 0)
-        g, r, vd = k0.shape[2], self.n_heads // k0.shape[2], v0.shape[-1]
+        n_heads = q.shape[2]                # the layer's kind's own
+        g, r, vd = k0.shape[2], n_heads // k0.shape[2], v0.shape[-1]
 
         def fold(i, carry):
             m, l, acc = carry
@@ -1578,7 +1606,7 @@ class _PagedRunner:
                     vblk.reshape(b, kb, -1), q_pos[:, 0], i * kb, *carry,
                     scale=scale)
 
-            step, init = visit, _fold_carry(b, self.n_heads, t, vd)
+            step, init = visit, _fold_carry(b, n_heads, t, vd)
         else:
             step, init = fold, (jnp.full((b, g, r, t), -1e30, f32),
                                 jnp.zeros((b, g, r, t), f32),
@@ -1957,8 +1985,8 @@ class _PagedRunner:
                 k.attention, k.attn_kinds, (k.nope_dim, k.v_dim), shapes,
                 t_len, table.shape[1], self.seen, kind)
             n_read = _pages_seen(table.shape[1], self.seen, ps)
-            ppb = _pages_a_block(
-                in_kernel, ps, n_read, self.n_heads * b * t_len)
+            heads = (k if kind is None else k.of(kind)).n_heads
+            ppb = _pages_a_block(in_kernel, ps, n_read, heads * b * t_len)
             n_blocks = -(-n_read // ppb)
             return in_kernel, ppb, n_blocks, jnp.pad(
                 table[:, :n_read], ((0, 0), (0, n_blocks * ppb - n_read)))
@@ -2592,7 +2620,8 @@ _BLOCK_SLOTS = (
     "HcAttnAlpha", "HcAttnBias", "HcMlpPhi", "HcMlpAlpha", "HcMlpBias",
     "Wq", "Wk", "Wv", "Sink", "WIn", "ConvW", "ConvB", "WX", "DtNorm",
     "BNorm", "CNorm", "WDt", "DtBias", "ALog", "D", "WOut",
-    "AttnPostNorm", "MlpPostNorm", "KNorm", "Wz", "Wa", "Wb", "GNorm")
+    "AttnPostNorm", "MlpPostNorm", "KNorm", "Wz", "Wa", "Wb", "GNorm",
+    "Wg")
 
 
 def _block_runner(ins, attrs):

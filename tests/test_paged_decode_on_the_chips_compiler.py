@@ -127,7 +127,8 @@ def test_mistrals_programs_are_what_the_chip_was_asked_before(
 
 # model -> (its file under benchmark/configs, its builder)
 MIXED = {"mimo": ("mimo-v2-flash-ep16.json", "serve_hybrid"),
-         "jamba": ("ai21-jamba2-3b.json", "serve_ssm")}
+         "jamba": ("ai21-jamba2-3b.json", "serve_ssm"),
+         "laguna": ("laguna-xs.2.json", "serve_hybrid_gated")}
 
 
 def _programs_of(config_file, builder):
@@ -182,8 +183,9 @@ def _assert_held_uncopied(text, pool_specs):
 def test_the_flat_kernel_compiles_at_the_mixed_models_shapes(
         one_chip, mixed):
     """Mosaic takes the sequence kind's pools as they are stored, keys 768
-    wide beside values 512 (four heads of 192 | 128) and one head of 128:
-    no pool is re-laid on its way into the call."""
+    wide beside values 512 (four heads of 192 | 128), one head of 128, and
+    eight heads of 128 | 128 under 48 query heads (6 a group, which go in
+    as 8): no pool is re-laid on its way into the call."""
     cfg, programs = mixed
 
     def abstract(shape, dtype=jnp.bfloat16):
@@ -220,7 +222,7 @@ def test_a_mixed_decode_program_holds_no_view_of_its_sequence_kind(
     _assert_held_uncopied(text, programs.pool_specs)
     rows = programs.max_batch
     kmax = programs.pages_per_seq * programs.page_size
-    assert (rows, kmax) in ((24, 17472), (128, 7232))
+    assert (rows, kmax) in ((24, 17472), (128, 7232), (64, 13376))
     views = re.findall(rf"\w+\[(?:\d+,)*{rows},(?:\d+,)?(?:{kmax}|"
                        rf"{programs.pages_per_seq},{programs.page_size})"
                        r"(?:,\d+)*\]", text)
@@ -652,3 +654,36 @@ def test_the_other_decode_programs_are_what_the_chip_was_asked_before(
     got = program_text.chip_fingerprint(program_text.lower_bundle(
         programs.decode, len(programs.pool_specs), sharding=one_chip))
     assert got == OTHERS_PINNED[model], (model, got)
+
+
+def test_the_few_rows_kernel_compiles_at_lagunas_experts(one_chip,
+                                                         monkeypatch):
+    """A decode step's experts at Laguna-XS.2's window stack (3 layers x
+    256 experts of 2,048 x 512, 64 rows x 8 picks): ONE custom call, the
+    expert stacks taken as they are stored (a block is one expert's
+    matrix), nothing of a stack's size beside them. xing4's experts
+    (3,584 x 1,024: 44 MB twice over) and a share's are not the kernel's."""
+    from paddle_tpu.ops import moe
+    monkeypatch.setattr(pa, "_use_pallas", lambda: True)
+
+    def abstract(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    up, down = abstract((3, 256, 2048, 512)), abstract((3, 256, 512, 2048))
+    assert moe.few_rows_usable(64, up, down)
+    assert not moe.few_rows_usable(2048, up, down)
+    assert not moe.few_rows_usable(64, up, down, held=(0, 256))
+    assert not moe.few_rows_usable(16, abstract((5, 64, 3584, 1024)),
+                                   abstract((5, 64, 1024, 3584)))
+    compiled = jax.jit(
+        lambda x, idx, gates, wg, wu, wd, layer: moe.moe_apply_sorted(
+            x, idx, gates, wg, wu, wd, layer=layer)).lower(
+        abstract((64, 2048)), abstract((64, 8), jnp.int32),
+        abstract((64, 8), jnp.float32), up, up, down,
+        abstract((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"tpu_custom_call.*moe_few_rows", text)) == 1
+    assert "ragged" not in text
+    _assert_held_uncopied(text, [([3, 256, 2048, 512], "bfloat16"),
+                                 ([3, 256, 512, 2048], "bfloat16")])
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e6
