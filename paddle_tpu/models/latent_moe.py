@@ -31,6 +31,7 @@ from dataclasses import dataclass
 from .. import layers
 from ..layers import transformer as tfl
 from ..ops.transformer_ops import (PAGED_STATS, decode_in_place,
+                                   prefill_experts_in_kernel,
                                    prefill_in_kernel, state_step_in_kernel,
                                    whole_tiles, yarn_inv_freq, yarn_mscale)
 from .llama import (PagedDecodePrograms, cache_pool_specs,
@@ -291,11 +292,19 @@ def build_block_programs(cfg, *, pool_specs, common, max_batch, page_size,
             (attrs["nope_dim"], attrs["v_dim"]), shapes, t_len,
             pages_per_seq, seen)
 
+    def experts_in_kernel(t_len):
+        """Whether its routed layers' pairs go through the grouped kernel:
+        asked as ``moe_apply_sorted`` asks where it lowers."""
+        return prefill_experts_in_kernel(
+            [common["params"], common["lead_params"]]
+            + [table for _, _, table in common.get("stacks", ())], t_len)
+
     prefill = {
         bucket: dict(bundle("prefill", "pp", [
             ("Tokens", "tokens", [1, bucket], "int64"),
             ("Lens", "lens", [1], "int32"), *tables(1)]),
-            attn_in_kernel=attn_in_kernel(bucket, bucket))
+            attn_in_kernel=attn_in_kernel(bucket, bucket),
+            experts_in_kernel=experts_in_kernel(bucket))
         for bucket in prefill_buckets_reached(prompt_buckets,
                                               chunk_size)}
     decode = bundle("decode", "dc", [
@@ -316,6 +325,7 @@ def build_block_programs(cfg, *, pool_specs, common, max_batch, page_size,
             ("Lens", "lens", [1], "int32"),
             ("Offsets", "offsets", [1], "int32"), *tables(1)])
         chunk["attn_in_kernel"] = attn_in_kernel(cs, None)
+        chunk["experts_in_kernel"] = experts_in_kernel(cs)
     return PagedDecodePrograms(
         cfg, None, page_size, pages_per_seq, n_pages, max_batch,
         prefill, decode, None, list(pool_specs), None,

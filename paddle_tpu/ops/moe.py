@@ -23,10 +23,22 @@ from . import pallas_attention as _pa
 
 __all__ = ["top_k_gating", "moe_apply", "moe_route", "moe_load",
            "moe_apply_sorted", "moe_apply_no_drop", "moe_apply_no_drop_q",
-           "few_rows_usable", "moe_apply_few_rows"]
+           "few_rows_usable", "moe_apply_few_rows",
+           "grouped_rows_usable", "moe_grouped_rows"]
 
 # rows the few-rows kernel takes: one pass of the MXU's 128-row tile
 FEW_ROWS = 128
+
+# Rows of the sorted pairs that one grid step of the grouped-rows kernel
+# multiplies with an expert: the MXU's tile (a taller one holds an expert's
+# matrices longer for every group edge inside it).
+GROUPED_ROW_TILE = 128
+
+# What two experts' matrices may take of VMEM in that kernel, of the 128 MiB
+# a v5e core has (the rows' tiles and the products' results come on top):
+# xing4's experts, 3,584 x 1,024, are 44 MB twice over and go through it
+# whole at half the three ragged_dot's time (PERF.md section 6, PR 56).
+GROUPED_VMEM = 48 * 2 ** 20
 
 # Rows of a share's sorted pairs that one trip of the un-sort's loop sums
 # back to their tokens (moe_apply_sorted): swept on the chip at 256 / 512 /
@@ -168,8 +180,11 @@ def few_rows_usable(t, w_gate, w_down, held=None):
     (a decode step's: a prefill window sorts its pairs), the widths whole
     lane tiles, and ONE expert's three matrices fit VMEM's default limit
     twice over, one in flight while one is multiplied (512-wide experts of
-    a 2,048-wide model: 12 MB; an expert of 1,024 x 3,584 would want its
-    hidden width cut in tiles, which nobody has measured)."""
+    a 2,048-wide model: 12 MB; an expert of 3,584 x 1,024 is 44 MB twice
+    over, and its decode step's grouped products stand at their roofline
+    (PERF.md section 5, docs); cutting the hidden width in tiles copies an
+    expert once a row tile and read 0.56 of the uncut form's bandwidth in
+    the grouped kernel: PERF.md section 6, PR 56)."""
     d, f = w_gate.shape[-2:]
     return (_pa._use_pallas() and held is None and t <= FEW_ROWS
             and d % 128 == 0 and f % 128 == 0
@@ -256,6 +271,152 @@ def moe_apply_few_rows(xt, idx, gates, w_gate, w_up, w_down, layer=None):
     return out[:t].astype(xt.dtype)
 
 
+def grouped_rows_usable(t, w_gate, w_down, held=None):
+    """The gate of ``moe_grouped_rows``, beside ``few_rows_usable`` and in
+    its form: the backend runs Pallas kernels, every expert of the layer
+    is held, the rows are MORE than one MXU tile (a prefill window: a
+    decode step keeps the few-rows kernel), the widths whole lane tiles,
+    one type for the three matrices, and an expert's three matrices WHOLE
+    (their hidden width uncut) twice over within ``GROUPED_VMEM``, one in
+    flight while one is multiplied."""
+    d, f = w_gate.shape[-2:]
+    return (_pa._use_pallas() and held is None and t > FEW_ROWS
+            and d % 128 == 0 and f % 128 == 0
+            and w_gate.dtype == w_down.dtype
+            and 6 * d * f * w_gate.dtype.itemsize <= GROUPED_VMEM)
+
+
+def _grouped_rows_kernel(layer_ref, expert_ref, tile_ref, starts_ref, n_ref,
+                         x_ref, wg_ref, wu_ref, wd_ref, o_ref, *acc):
+    """Grid step ``(i, j)``: work item ``i`` is one expert and one row tile
+    that holds rows of its group; ``j`` a tile of the hidden width. The
+    tile's rows through the expert's three blocks, and the rows that are
+    the expert's own written over what the tile held: the others are their
+    own experts' to write, in the steps before and behind this one."""
+    i, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(i < n_ref[0])
+    def _():
+        x = x_ref[...]
+        g = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
+        h = ((g * jax.nn.sigmoid(g)) * u).astype(x.dtype)
+        y = jnp.dot(h, wd_ref[...], preferred_element_type=jnp.float32)
+        e = expert_ref[i]
+        row = tile_ref[i] * x.shape[0] + jax.lax.broadcasted_iota(
+            jnp.int32, (x.shape[0], 1), 0)
+        mine = (row >= starts_ref[e]) & (row < starts_ref[e + 1])
+        if not acc:                     # the hidden width in one tile
+            o_ref[...] = jnp.where(mine, y, o_ref[...])
+            return
+        acc_ref, = acc
+
+        @pl.when(j == 0)
+        def _():
+            acc_ref[...] = y
+
+        @pl.when(j > 0)
+        def _():
+            acc_ref[...] += y
+
+        @pl.when(j == pl.num_programs(1) - 1)
+        def _():
+            o_ref[...] = jnp.where(mine, acc_ref[...], o_ref[...])
+
+
+def _work_items(sizes, tile, n_tiles):
+    """The grouped-rows kernel's work list for groups of ``sizes`` [E] rows
+    laid end to end over ``n_tiles`` row tiles of ``tile``: (expert [N],
+    row tile [N], the groups' first rows and the last one's end [E + 1],
+    how many of the N = ``n_tiles + E - 1`` items are work). An item is an
+    expert and a row tile that holds rows of its group, in ascending order
+    of both; an empty group has none; behind the last one the list repeats
+    it, so that nothing more is copied."""
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    first = starts // tile
+    spans = jnp.where(sizes > 0, (ends - 1) // tile - first + 1, 0)
+    upto = jnp.cumsum(spans)
+    i = jnp.minimum(jnp.arange(n_tiles + sizes.shape[0] - 1), upto[-1] - 1)
+    expert = jnp.searchsorted(upto, i, side="right",
+                              method="compare_all").astype(jnp.int32)
+    row_tile = first[expert] + i - (upto - spans)[expert]
+    return (expert, row_tile.astype(jnp.int32),
+            jnp.concatenate([starts, ends[-1:]]).astype(jnp.int32),
+            upto[-1:].astype(jnp.int32))
+
+
+def moe_grouped_rows(xs, sizes, w_gate, w_up, w_down, layer=None,
+                     hidden_tile=None):
+    """The SORTED pairs' rows ``xs`` [P, D] (group ``e`` is the ``sizes[e]``
+    rows behind the groups before it; ``sizes`` [E] sums to P) through
+    their experts' SwiGLU as ONE Pallas kernel, ``moe_grouped_rows`` in a
+    trace: what three ``jax.lax.ragged_dot`` and the SwiGLU between them
+    compute, float32 [P, D].
+
+    The experts that a pair reached are visited in ascending order, each
+    multiplied with ITS OWN rows only, a row tile of ``GROUPED_ROW_TILE``
+    at a time, the three products fused (``h`` never leaves VMEM; operands
+    in the weights' type, float32 accumulation, ``h`` cast to the rows'
+    type before the down product). A group starts wherever the sort put
+    it: a tile that two groups share is multiplied once with each and
+    each writes its own rows of the result (``_work_items``). An expert's
+    matrices are blocks of the stack as it is stored (``[L, E, ...]``
+    with ``layer`` a traced index: no slice of a layer's size), copied
+    when the list moves on to it, the next one's in flight while this one
+    is multiplied, and NOT again for its second row tile; an expert
+    nobody reached is never copied. A row's result depends on no other
+    row. ``hidden_tile`` cuts the hidden width (an inner grid axis, the
+    down products summed in VMEM): then an expert's blocks are copied
+    once a ROW TILE of its group, and the chip read that form a fifth to
+    a third slower than the uncut one at both widths it was tried at,
+    which is why the gate asks for the width uncut (PERF.md section 6,
+    PR 56)."""
+    p, d = xs.shape
+    f = w_gate.shape[-1]
+    if layer is None:
+        w_gate, w_up, w_down = (w[None] for w in (w_gate, w_up, w_down))
+        layer = 0
+    tile = GROUPED_ROW_TILE
+    tf = f if hidden_tile is None else hidden_tile
+    n_tiles = -(-p // tile)
+    if n_tiles * tile != p:     # rows of no group: written by nobody
+        xs = jnp.pad(xs, ((0, n_tiles * tile - p), (0, 0)))
+    expert, row_tile, starts, n = _work_items(sizes, tile, n_tiles)
+    # two experts' blocks, two tiles of rows in and out, the products
+    held = 6 * d * tf * w_gate.dtype.itemsize \
+        + 2 * tile * d * (xs.dtype.itemsize + 4) + tile * (d + 3 * tf) * 4
+
+    def rows(i, j, lyr, exp, til, *_):
+        return til[i], 0
+
+    def gate_up(i, j, lyr, exp, *_):
+        return lyr[0], exp[i], 0, j
+
+    def down(i, j, lyr, exp, *_):
+        return lyr[0], exp[i], j, 0
+
+    out = _pa._pcall(
+        _grouped_rows_kernel, name="moe_grouped_rows",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5, grid=(expert.shape[0], f // tf),
+            in_specs=[
+                pl.BlockSpec((tile, d), rows),
+                pl.BlockSpec((None, None, d, tf), gate_up),
+                pl.BlockSpec((None, None, d, tf), gate_up),
+                pl.BlockSpec((None, None, tf, d), down)],
+            out_specs=pl.BlockSpec((tile, d), rows),
+            scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32)]
+            if tf != f else []),
+        out_shape=jax.ShapeDtypeStruct((n_tiles * tile, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=held + 8 * 2 ** 20),
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), expert, row_tile, starts,
+      n, xs, w_gate, w_up, w_down)
+    return out[:p]
+
+
 def moe_apply_sorted(xt, idx, gates, w_gate, w_up, w_down, layer=None,
                      held=None):
     """The drop-free expert layer: the T x K token-expert pairs sorted by
@@ -287,10 +448,19 @@ def moe_apply_sorted(xt, idx, gates, w_gate, w_up, w_down, layer=None,
     follows the pairs this chip holds and not the rows set aside for
     them.
 
-    The CALL decides its form, as the paged attention calls do: a few
-    rows over experts all held and small enough go through the kernel
-    ``moe_apply_few_rows`` where ``few_rows_usable`` says so (the chip;
-    the tests' interpreter hook), and this function is its reference."""
+    The CALL decides its form, as the paged attention calls do, from
+    what it is given (the backend or the tests' interpreter hook, the
+    rows, ``held``, the experts' widths and type): a few rows over
+    experts all held and small enough go through the kernel
+    ``moe_apply_few_rows`` where ``few_rows_usable`` says so (a decode
+    step); more rows than that over such experts keep the sort, the
+    gather and the un-sort and put the sorted rows through the kernel
+    ``moe_grouped_rows`` in place of the three ``ragged_dot`` where
+    ``grouped_rows_usable`` says so (a prefill window); and this function
+    is the reference of both, on every CPU, for a share and for experts
+    too wide. No training path differentiates through it (a Pallas call
+    has no gradient here): ``moe_ffn`` trains through ``moe_apply``, and
+    the paged programs and ``llama_generate`` only infer."""
     t, k = idx.shape
     e = w_gate.shape[-3]
     if few_rows_usable(t, w_gate, w_down, held):
@@ -304,17 +474,20 @@ def moe_apply_sorted(xt, idx, gates, w_gate, w_up, w_down, layer=None,
     order = jnp.argsort(local.reshape(t * k), stable=True)
     sizes = moe_load(local, e)
     n_held = jnp.sum(sizes)
-    if layer is not None:
+    cdt = xt.dtype
+    in_kernel = grouped_rows_usable(t, w_gate, w_down, held)
+    if layer is not None and not in_kernel:
         n_layers = w_gate.shape[0]
         sizes = jax.lax.dynamic_update_slice(
             jnp.zeros((n_layers * e,), jnp.int32), sizes, (layer * e,))
         w_gate, w_up, w_down = (w.reshape((n_layers * e,) + w.shape[2:])
                                 for w in (w_gate, w_up, w_down))
-    cdt = xt.dtype
 
     def grouped(pairs):
         """The sorted pairs ``pairs`` through their experts, float32."""
         xs = xt[pairs // k]
+        if in_kernel:
+            return moe_grouped_rows(xs, sizes, w_gate, w_up, w_down, layer)
         gate_h = jax.lax.ragged_dot(xs, w_gate, sizes)
         up_h = jax.lax.ragged_dot(xs, w_up, sizes)
         h = ((gate_h * jax.nn.sigmoid(gate_h)) * up_h).astype(cdt)

@@ -2391,6 +2391,32 @@ def state_step_in_kernel(attn_kinds, pool_specs):
         for spec in attn_kinds or ())
 
 
+def prefill_experts_in_kernel(param_tables, t_len):
+    """Whether a prefill program over a window of ``t_len`` tokens puts its
+    routed layers' sorted pairs through the Pallas kernel
+    ``moe_grouped_rows``: a REPORT, as ``decode_in_place`` is (a prefill or
+    chunk bundle's ``experts_in_kernel``, the engine's
+    ``prefill_experts_in_kernel_total``), which chooses nothing: every
+    routed layer's ``moe_apply_sorted`` asks the gate itself (ops/moe.py
+    ``grouped_rows_usable``: the backend, the rows, whether every expert is
+    held, the widths). ``param_tables``: the programs' layer parameters,
+    slot -> (suffix, shape, dtype) a stack; whether any stack's does."""
+    from . import moe
+
+    def asks(table):
+        gate, down, router = (table.get(slot) for slot in (
+            "MoeWGate", "MoeWDown", "MoeRouter"))
+        if gate is None:
+            return False
+        w_gate, w_down = (jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dt))
+                          for _, shape, dt in (gate, down))
+        whole = w_gate.shape[-3] == router[1][-1]
+        return moe.grouped_rows_usable(
+            t_len, w_gate, w_down, None if whole else (0, router[1][-1]))
+
+    return any(asks(table) for table in param_tables)
+
+
 def _pages_seen(n_pages, seen, page_size):
     """The pages of a row's ``n_pages`` that hold a position a prefill
     window may see (``seen`` positions at most; None: not known)."""

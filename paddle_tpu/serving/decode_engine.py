@@ -153,6 +153,13 @@ _DECODE_COUNTERS = (
     # keep the whole sequence, on a backend with the kernel), to be read
     # against the sum of those two
     "prefill_attn_in_kernel_total",
+    # and its sibling for the routed experts: ticked at the same two places
+    # for every whole-prompt or chunk dispatch whose program puts its routed
+    # layers' sorted pairs through the kernel moe_grouped_rows (the bundle's
+    # ``experts_in_kernel``: every expert of the layer held, a window of
+    # more rows than the few-rows kernel takes, on a backend with the
+    # kernel), to be read against the same sum
+    "prefill_experts_in_kernel_total",
     # a model with window attention layers (PR 33) has caches of two
     # kinds, and counts on the device, over decode steps, the positions
     # its active rows attended in the layers of each (HYBRID_STATS:
@@ -1756,6 +1763,9 @@ class DecodeEngine:
                    prefill_attn_in_kernel_total=int(
                        self.programs.prefill[bucket].get(
                            "attn_in_kernel", False)),
+                   prefill_experts_in_kernel_total=int(
+                       self.programs.prefill[bucket].get(
+                           "experts_in_kernel", False)),
                    prefill_dispatch_s_total=dispatch.seconds,
                    prefill_tokens_total=int(r.prompt.size),
                    prefill_padded_tokens_total=bucket,
@@ -2023,6 +2033,9 @@ class DecodeEngine:
             self._tick(chunk_prefill_total=1,
                        prefill_attn_in_kernel_total=int(
                            self.programs.chunk.get("attn_in_kernel",
+                                                   False)),
+                       prefill_experts_in_kernel_total=int(
+                           self.programs.chunk.get("experts_in_kernel",
                                                    False)),
                        chunk_dispatch_s_total=dispatch.seconds,
                        prefill_tokens_total=int(sl.size),

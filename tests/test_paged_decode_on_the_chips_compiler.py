@@ -687,3 +687,67 @@ def test_the_few_rows_kernel_compiles_at_lagunas_experts(one_chip,
     _assert_held_uncopied(text, [([3, 256, 2048, 512], "bfloat16"),
                                  ([3, 256, 512, 2048], "bfloat16")])
     assert compiled.memory_analysis().temp_size_in_bytes < 4e6
+
+
+def test_the_grouped_rows_kernel_compiles_at_lagunas_window(one_chip,
+                                                            monkeypatch):
+    """A prefill window's experts at Laguna-XS.2's window stack (2,048
+    tokens x 8 picks over 3 layers x 256 experts of 2,048 x 512): ONE
+    custom call, ``moe_grouped_rows``, in place of three ``ragged_dot``,
+    the expert stacks taken as they are stored, no more held beside the
+    arguments than the sorted form holds (both peak at the float32
+    ``[16384, 2048]`` result and its un-sorted copy, 268 MB). A decode
+    step's 64 rows still compile to
+    ``moe_few_rows`` and a share to ``ragged_dot``; xing4's window (2,048
+    tokens x 4 picks over 64 experts of 3,584 x 1,024, 44 MB twice over)
+    compiles to the kernel too, whole experts under a raised VMEM limit:
+    the gate's budget admits it since the probe read it at half the three
+    ``ragged_dot``'s time (PERF.md section 6, PR 56), and its decode step
+    (16 rows) keeps ``ragged_dot``."""
+    from paddle_tpu.ops import moe
+    monkeypatch.setattr(pa, "_use_pallas", lambda: True)
+
+    def abstract(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def compiled(tokens, picks, up, down, held=None):
+        return jax.jit(
+            lambda x, idx, gates, wg, wu, wd, layer: moe.moe_apply_sorted(
+                x, idx, gates, wg, wu, wd, layer=layer, held=held)).lower(
+            abstract((tokens, up.shape[2])),
+            abstract((tokens, picks), jnp.int32),
+            abstract((tokens, picks), jnp.float32), up, up, down,
+            abstract((), jnp.int32)).compile()
+
+    up, down = abstract((3, 256, 2048, 512)), abstract((3, 256, 512, 2048))
+    stacks = [([3, 256, 2048, 512], "bfloat16"),
+              ([3, 256, 512, 2048], "bfloat16")]
+    assert moe.grouped_rows_usable(2048, up, down)
+    window = compiled(2048, 8, up, down)
+    text = window.as_text()
+    grouped = r"tpu_custom_call.*moe_grouped_rows"
+    assert len(re.findall(grouped, text)) == 1
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "ragged" not in text and "moe_few_rows" not in text
+    _assert_held_uncopied(text, stacks)
+    with monkeypatch.context() as m:
+        m.setattr(moe, "grouped_rows_usable", lambda *a, **k: False)
+        parent = compiled(2048, 8, up, down)
+    assert "ragged" in parent.as_text()
+    assert window.memory_analysis().temp_size_in_bytes \
+        <= parent.memory_analysis().temp_size_in_bytes
+
+    step = compiled(64, 8, up, down).as_text()
+    assert len(re.findall(r"tpu_custom_call.*moe_few_rows", step)) == 1
+    assert not re.findall(grouped, step)
+    share = compiled(2048, 8, up, down, held=(0, 256)).as_text()
+    assert "ragged" in share and not re.findall(grouped, share)
+
+    xing4 = (abstract((5, 64, 3584, 1024)), abstract((5, 64, 1024, 3584)))
+    assert moe.grouped_rows_usable(2048, *xing4)
+    docs = compiled(2048, 4, *xing4).as_text()
+    assert len(re.findall(grouped, docs)) == 1 and "ragged" not in docs
+    _assert_held_uncopied(docs, [([5, 64, 3584, 1024], "bfloat16"),
+                                 ([5, 64, 1024, 3584], "bfloat16")])
+    docs_step = compiled(16, 4, *xing4).as_text()
+    assert "ragged" in docs_step and not re.findall(grouped, docs_step)
