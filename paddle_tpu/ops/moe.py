@@ -123,7 +123,7 @@ def moe_apply(xt, wg, w_gate, w_up, w_down, top_k, cap_factor):
 
 
 def moe_route(xt, wg, top_k, scoring="softmax", bias=None, scale=1.0,
-              n_group=1, topk_group=1):
+              n_group=1, topk_group=1, eps=1e-20):
     """Exact top-k routing of flat tokens xt [T, D] over the router's
     whole width E (``wg`` [D, E], however many of those experts are held
     here), in float32 whatever the model's dtype (the products at
@@ -133,7 +133,9 @@ def moe_route(xt, wg, top_k, scoring="softmax", bias=None, scale=1.0,
     ``softmax``: the K largest probabilities, renormalised.
     ``sigmoid``: scores ``sigmoid(x Wg)``; the K largest of
     ``scores + bias`` are picked (``bias`` steers selection only), their
-    own scores are renormalised and multiplied by ``scale``. With
+    own scores are renormalised (divided by their sum + ``eps``, the
+    model's own constant: 1e-20 in DeepSeek-V3's rule, 1e-6 in the LFM2
+    family's) and multiplied by ``scale``. With
     ``n_group`` > 1 the selection is group-limited (DeepSeek-V3,
     arXiv:2412.19437): the experts are ``n_group`` equal runs, a group
     scores the sum of its two largest ``scores + bias``, the
@@ -159,7 +161,7 @@ def moe_route(xt, wg, top_k, scoring="softmax", bias=None, scale=1.0,
     idx = jax.lax.top_k(picked, top_k)[1]
     gates = jnp.take_along_axis(scores, idx, axis=-1)
     return idx, scale * gates / (
-        jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+        jnp.sum(gates, axis=-1, keepdims=True) + eps)
 
 
 def moe_load(idx, n_experts, valid=None, first=0):

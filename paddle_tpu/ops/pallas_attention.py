@@ -555,6 +555,20 @@ def paged_flat_usable(k_shape, v_shape, n_kv):
             and v_shape[3] % (128 * n_kv) == 0)
 
 
+def paged_packed_usable(k_shape, v_shape, n_kv):
+    """The gate of ``paged_flat_decode``'s PACKED form, beside
+    ``paged_flat_usable`` and in its form: the same flat pools, the keys'
+    width whole lane tiles, and a value head NARROWER than a lane tile that
+    divides it (64: two heads a tile), the key/value heads filling whole
+    tiles (8 heads of 64: four)."""
+    dv = v_shape[3] // n_kv if len(v_shape) == 4 and n_kv else 0
+    return (_use_pallas() and len(k_shape) == len(v_shape) == 4
+            and tuple(k_shape[:3]) == tuple(v_shape[:3])
+            and k_shape[3] % 128 == 0 and k_shape[3] % n_kv == 0
+            and v_shape[3] % n_kv == 0 and 0 < dv < 128 and 128 % dv == 0
+            and v_shape[3] % 128 == 0)
+
+
 def _fold_flat(q, bufs, slot, length, blk, carry, *, scale, g):
     """A block of pages whose entries lie FLAT, ``[page_size, g * dk]``
     keys and ``[page_size, g * dv]`` values, against a ZERO-EXPANDED
@@ -593,22 +607,38 @@ def paged_flat_decode(q, k_pool, v_pool, layer, table, lengths, sink=None):
     schedule and the same promise: a row's result depends on its pages
     and its length alone. Scores are scaled by ``dk ** -0.5``. ``sink``
     [heads]: one more column of each head's denominator, which no kernel
-    takes. Returns [B, heads, dv] in q's type."""
+    takes. Returns [B, heads, dv] in q's type.
+
+    Where a value head is a PART of a lane tile (``paged_packed_usable``:
+    64, two heads a tile) the kernel is the PACKED form,
+    ``paged_flat_packed_decode`` in a trace: the scores as ever, one
+    product of the zero-expanded query with the block of keys as it lies,
+    at any key width; the values met a LANE TILE at a time, the ``pack =
+    128 // dv`` key/value heads whose values share a tile and all their
+    query heads in one product with that tile as it lies, so that no
+    half-tile slice of a page is re-laid (``_fold_flat`` over ``g / pack``
+    groups of 128-wide values: pack-fold products of columns nobody
+    reads, the MXU's); a head's row then holds its own ``dv`` columns
+    beside its tile-mates' and is cut to them here, outside the kernel."""
     n_rows, n_heads, dk = q.shape
     g = k_pool.shape[3] // dk
-    if sink is not None or not paged_flat_usable(k_pool.shape, v_pool.shape,
-                                                 g):
+    packed = paged_packed_usable(k_pool.shape, v_pool.shape, g)
+    if sink is not None or not (packed or paged_flat_usable(
+            k_pool.shape, v_pool.shape, g)):
         return _ref_paged_attention(q, k_pool, v_pool, layer, table,
                                     lengths, g, dk ** -0.5, sink)
-    # the kernel slices the [heads, keys] weights a group, so several
-    # heads a group go in as whole sublane tiles: 6 a group (48 over 8) as
-    # 8, the two behind them zero queries whose results are cut off again
-    # (their weights are uniform over the row's own positions: a number,
-    # and nobody's). 16 and 8 a group, one group, and one head a group
-    # (a slice of one row) go in as they are
-    rep = n_heads // g
-    padded = rep if g == 1 or rep == 1 \
-        else -(-rep // _SUBLANES) * _SUBLANES
+    dv = v_pool.shape[3] // g
+    pack = 128 // dv if packed else 1       # value heads a lane tile
+    tiles, rep = g // pack, n_heads // g
+    # the kernel slices the [heads, keys] weights a tile of values, so the
+    # heads of one (pack groups of rep) go in as whole sublane tiles: 6 a
+    # group (48 over 8) as 8, the two behind them zero queries whose
+    # results are cut off again (their weights are uniform over the row's
+    # own positions: a number, and nobody's). 16 and 8 a group, one tile,
+    # and one head a tile (a slice of one row) go in as they are
+    padded = rep if tiles == 1 or pack * rep == 1 else next(
+        r for r in range(rep, rep + _SUBLANES + 1)
+        if (pack * r) % _SUBLANES == 0)
     if padded != rep:
         q = jnp.pad(q.reshape(n_rows, g, rep, dk),
                     ((0, 0), (0, 0), (0, padded - rep), (0, 0))).reshape(
@@ -617,16 +647,17 @@ def paged_flat_decode(q, k_pool, v_pool, layer, table, lengths, sink=None):
            == jnp.arange(g)[None])                          # [heads, g]
     expanded = jnp.where(own[None, :, :, None], q[:, :, None], 0).reshape(
         n_rows, g * padded, g * dk)
-    dv = v_pool.shape[3] // g
     out = _paged_decode_call(
-        "paged_flat_decode", functools.partial(
-            _fold_flat, scale=dk ** -0.5, g=g),
-        expanded, (k_pool, v_pool), layer, table, lengths, dv,
+        "paged_flat_packed_decode" if packed else "paged_flat_decode",
+        functools.partial(_fold_flat, scale=dk ** -0.5, g=tiles),
+        expanded, (k_pool, v_pool), layer, table, lengths, pack * dv,
         PAGED_FLAT_BLOCK_KEYS)
+    if packed:      # [.., head in the tile, rep, ITS part of the tile's]
+        out = out.reshape(n_rows, tiles, pack, padded, pack, dv)
+        out = jnp.stack([out[:, :, j, :, j] for j in range(pack)], axis=2)
     if padded != rep:
-        out = out.reshape(n_rows, g, padded, dv)[:, :, :rep].reshape(
-            n_rows, n_heads, dv)
-    return out
+        out = out.reshape(n_rows, g, padded, dv)[:, :, :rep]
+    return out.reshape(n_rows, n_heads, dv)
 
 
 def paged_latent_usable(pool_shapes):

@@ -18,10 +18,11 @@ import jax
 import jax.numpy as jnp
 
 from ..core.registry import register_op
-from . import delta_rule, ssm
+from . import delta_rule, short_conv, ssm
 from . import pallas_attention as _pa
 from .pallas_attention import (flash_attention, masked_attention,
                                paged_flat_decode, paged_flat_usable,
+                               paged_packed_usable,
                                paged_gqa_decode, paged_latent_decode,
                                paged_latent_usable, paged_gqa_usable,
                                prefill_fold)
@@ -321,9 +322,15 @@ class BlockKinds:
     mixer, the gated delta rule (ops/delta_rule.py), of the same cache
     kind: its state a MATRIX a head, ``[layers, entries, heads, dk, dv]``
     float32, a prompt computed ``delta_rule.CHUNK`` positions at a time as
-    matrix products; its sizes are read off its parameters. ``gqa`` norms
-    its WHOLE query and key projection where the layer holds ``QNorm`` /
-    ``KNorm``.
+    matrix products; its sizes are read off its parameters. ``"mixer":
+    "conv"`` is the fourth, the gated short convolution (ops/short_conv.py):
+    a tail and NO state, so its kind has ONE pool, ``[layers, entries, (k -
+    1) * C]`` (``pools`` names one). ``gqa`` norms its query and key
+    projection where the layer holds ``QNorm`` / ``KNorm``: the WHOLE
+    projection where the weight is as wide as it, EACH HEAD with the one
+    weight where the weight is a head wide (read off the parameter).
+    ``route_eps`` is what a sigmoid router adds to the sum of the picked
+    scores it divides them by (ops/moe.py ``moe_route``).
 
     A stack may be RUN SEVERAL TIMES A TOKEN (a looped language model,
     arXiv:2510.25741): ``passes`` > 1 runs the same stacked layers that
@@ -347,7 +354,8 @@ class BlockKinds:
                  rope_inv_freq=None, softmax_scale=None, n_streams=1,
                  sinkhorn_iters=0, hc_eps=1e-6, hc_clamp=(-30.0, 30.0),
                  key_dim=None, rotary_dim=None, value_scale=1.0,
-                 attn_kinds=None, layer_kinds=None, passes=1):
+                 attn_kinds=None, layer_kinds=None, passes=1,
+                 route_eps=1e-20):
         for kind, table in ((attention, _ATTENTION), (ffn, _FFN),
                             (residual, _RESIDUAL)):
             if kind not in table:
@@ -357,7 +365,7 @@ class BlockKinds:
         self.base, self.eps = base, eps
         self.attention, self.ffn, self.residual = attention, ffn, residual
         self.moe_top_k, self.scoring = moe_top_k, scoring
-        self.route_scale = route_scale
+        self.route_scale, self.route_eps = route_scale, route_eps
         self.n_group, self.topk_group = n_group, topk_group
         self.experts_first = experts_first
         self.kv_rank, self.rope_dim = kv_rank, rope_dim
@@ -404,8 +412,10 @@ def _gqa_attention(kinds, p, u, pos, attend_fn):
     """Grouped-query projections, the first ``rotary_dim`` widths of every
     query and key head rotated (all of them where None), the values times
     ``value_scale``; ``attend_fn(q, (k, v))`` owns the attention and any
-    cache. Where the layer holds ``Wg`` each head's result is gated by
-    ``sigmoid(u Wg)`` [.., heads] before ``Wo``."""
+    cache. ``QNorm`` / ``KNorm`` norm the projection before the rotation,
+    whole or a head at a time as the weight's width says. Where the layer
+    holds ``Wg`` each head's result is gated by ``sigmoid(u Wg)`` [..,
+    heads] before ``Wo``."""
     b, t, _ = u.shape
     hd = kinds.key_dim or p["Wq"].shape[-1] // kinds.n_heads
     rd = kinds.rotary_dim
@@ -422,9 +432,16 @@ def _gqa_attention(kinds, p, u, pos, attend_fn):
 
     def heads(slot, norm, n):
         x = qmat(u, p, slot)
-        if p.get(norm) is not None:     # over the whole projection
-            x = rms_normalize(x, p[norm], kinds.eps)
-        return rotate(x.reshape(b, t, n, hd))
+        w = p.get(norm)
+        whole = w is not None and w.shape[-1] == x.shape[-1]
+        if whole:                       # over the whole projection
+            x = rms_normalize(x, w, kinds.eps)
+        x = x.reshape(b, t, n, hd)
+        if w is not None and not whole:     # a head at a time, one weight
+            with jax.named_scope("/".join(
+                    s for s in ("attn", kinds.name, "head_norm") if s)):
+                x = rms_normalize(x, w, kinds.eps)
+        return rotate(x)
 
     q = heads("Wq", "QNorm", kinds.n_heads)
     k = heads("Wk", "KNorm", kinds.n_kv)
@@ -466,8 +483,9 @@ def _latent_attention(kinds, p, u, pos, attend_fn):
 
 # the mixers whose cache is the ``state`` kind, each a module of ``window(p,
 # z, state0, tail0, lens, eps)``, ``step(p, z, s_pool, layer, held, tail0,
-# eps)`` and ``step_in_kernel(pool_shape, pool_dtype)``
-_STATE_MIXERS = {"ssm": ssm, "delta": delta_rule}
+# eps)`` and ``step_in_kernel(pool_shape, pool_dtype)``; a mixer that keeps
+# a tail and no state (``conv``) is handed None for the state and its pool
+_STATE_MIXERS = {"ssm": ssm, "delta": delta_rule, "conv": short_conv}
 
 
 def _state_stats(mixer):
@@ -477,6 +495,12 @@ def _state_stats(mixer):
     layers x positions)."""
     return (f"{mixer}_state_updates_total",
             f"{mixer}_prefill_positions_total")
+
+
+def _state_pools(mine):
+    """(state pool, tail pool) of a state kind's pools: a mixer with a
+    tail and no state (``conv``) has the tail's alone, and None."""
+    return (None, mine[0]) if len(mine) == 1 else tuple(mine)
 
 
 def _keeps_state(spec):
@@ -510,6 +534,20 @@ def _delta_mixer(kinds, p, u, pos, attend_fn):
     return qmat(o * jax.nn.silu(qmat(u, p, "Wz")), p, "Wo")
 
 
+def _conv_mixer(kinds, p, u, pos, attend_fn):
+    """The gated short convolution's projections: ``[B | C | z] = W_in u``,
+    the input gated BEFORE the taps (``g = B * z``) and their result
+    after (out ``= W_out (C * c)``). ``attend_fn(g, ())`` owns what lies
+    between, the taps over g (ops/short_conv.py), and the tail a sequence
+    carries from one call to the next."""
+    with jax.named_scope("mixer/conv/in"):
+        gate_in, gate_out, z = jnp.split(qmat(u, p, "WIn"), 3, axis=-1)
+        g = gate_in * z
+    c = attend_fn(g, ())
+    with jax.named_scope("mixer/conv/out"):
+        return qmat(gate_out * c, p, "WOut")
+
+
 def _swiglu(p, x, gate="WGate", up="WUp", down="WDown"):
     g = qmat(x, p, gate)
     return qmat((g * jax.nn.sigmoid(g)) * qmat(x, p, up), p, down)
@@ -540,7 +578,7 @@ def _routed_ffn(kinds, p, u, valid):
         idx, gates = moe.moe_route(
             xt, p["MoeRouter"], kinds.moe_top_k, kinds.scoring,
             p.get("MoeBias"), kinds.route_scale, kinds.n_group,
-            kinds.topk_group)
+            kinds.topk_group, kinds.route_eps)
         load = moe.moe_load(idx, n_held,
                             None if valid is None else valid.reshape(-1),
                             kinds.experts_first)
@@ -603,7 +641,8 @@ def _mhc_residual(kinds, p, which, x, sublayer):
 
 
 _ATTENTION = {"gqa": _gqa_attention, "latent": _latent_attention,
-              "ssm": _ssm_mixer, "delta": _delta_mixer}
+              "ssm": _ssm_mixer, "delta": _delta_mixer,
+              "conv": _conv_mixer}
 _FFN = {"swiglu": _swiglu_ffn, "routed": _routed_ffn}
 _RESIDUAL = {"plain": _plain_residual, "mhc": _mhc_residual}
 
@@ -1358,6 +1397,8 @@ HYBRID_STATS = PAGED_STATS + ("attn_full_positions_total",
 SSM_STATS = HYBRID_STATS + _state_stats("ssm")
 # or gated delta-rule layers: the same two, under the mixer's name
 DELTA_STATS = HYBRID_STATS + _state_stats("delta")
+# or gated short convolutions: the tails updated and the positions tapped
+CONV_STATS = HYBRID_STATS + _state_stats("conv")
 # and a model whose stack is run several times a token, over decode steps
 # alone: the layer passes its active rows went through (passes x layers a
 # row a step, counted by the loop that ran them) and the cache positions
@@ -1688,25 +1729,31 @@ class _PagedRunner:
         where it starts one; ``fresh`` (every row starts): the entries are
         not read at all."""
         b = z.shape[0]
-        s_pool, t_pool = mine
+        s_pool, t_pool = _state_pools(mine)
         entry = self.state_table[:, 0]
         with jax.named_scope("cache/" + spec["name"]):
+            state0 = None
             if self.fresh:
-                state0 = jnp.zeros((b,) + s_pool.shape[2:], s_pool.dtype)
+                if s_pool is not None:
+                    state0 = jnp.zeros((b,) + s_pool.shape[2:],
+                                       s_pool.dtype)
                 tail0 = jnp.zeros((b, t_pool.shape[2]), t_pool.dtype)
             else:
                 goes_on = pos0 > 0
-                state0 = jnp.where(_over(goes_on, s_pool.ndim - 1),
-                                   s_pool[lyr, entry], 0)
+                if s_pool is not None:
+                    state0 = jnp.where(_over(goes_on, s_pool.ndim - 1),
+                                       s_pool[lyr, entry], 0)
                 tail0 = jnp.where(goes_on[:, None], t_pool[lyr, entry], 0)
         y, state, tail = _STATE_MIXERS[spec["mixer"]].window(
             p, z, state0, tail0.reshape(b, -1, p["ConvW"].shape[-1]),
             self.lens, self.kinds.eps)
         with jax.named_scope("cache/" + spec["name"]):
-            s_pool = s_pool.at[lyr, entry].set(state.astype(s_pool.dtype))
+            if s_pool is not None:
+                s_pool = s_pool.at[lyr, entry].set(
+                    state.astype(s_pool.dtype))
             t_pool = t_pool.at[lyr, entry].set(
                 tail.reshape(b, -1).astype(t_pool.dtype))
-        return y, [s_pool, t_pool]
+        return y, [t_pool] if s_pool is None else [s_pool, t_pool]
 
     def _state_step(self, p, z, mine, lyr, spec):
         """A decode step through a layer that keeps a state, run IN THE
@@ -1723,8 +1770,8 @@ class _PagedRunner:
         that no live row holds (the null entry, a free one, a chunk job's
         between its chunks) keeps what it held, and a row that is not
         live gets zeros."""
-        s_pool, t_pool = mine
-        n = s_pool.shape[1]
+        s_pool, t_pool = _state_pools(mine)
+        n = t_pool.shape[1]
         live = self.valid[:, 0]
         entry = self.state_table[:, 0]
         with jax.named_scope("cache/" + spec["name"]):
@@ -1741,7 +1788,7 @@ class _PagedRunner:
             # a row that is not live reads no entry either: whatever the
             # null entry holds cannot reach the null PAGE through it
             y = jnp.where(live[:, None], y_e[entry], 0)
-        return y[:, None], [s_pool, t_pool]
+        return y[:, None], [t_pool] if s_pool is None else [s_pool, t_pool]
 
     def _kv_up(self, p):
         """A layer's latent -> per-head [key | value] expansion,
@@ -2359,7 +2406,8 @@ def decode_in_place(attention, attn_kinds, pool_shapes, kind=None):
     kinds of layer is asked KIND BY KIND (``kind`` None: whether any
     does): the kind that keeps the whole sequence, has attention for its
     mixer and no sink, its two pools' entries flat at whole lane tiles
-    (paged_flat_usable); a window kind and a state kind have no kernel."""
+    (paged_flat_usable; a value head that is a part of a tile:
+    paged_packed_usable); a window kind and a state kind have no kernel."""
     if attn_kinds is None and attention == "latent":
         return paged_latent_usable(pool_shapes)
     if attention != "gqa":
@@ -2370,10 +2418,11 @@ def decode_in_place(attention, attn_kinds, pool_shapes, kind=None):
         return any(decode_in_place(attention, attn_kinds, pool_shapes, k)
                    for k in range(len(attn_kinds)))
     spec = attn_kinds[kind]
+    mine = [pool_shapes[i] for i in spec["pools"]]
     return (spec["window"] is None and not _keeps_state(spec)
-            and not spec["sink"] and len(spec["pools"]) == 2
-            and paged_flat_usable(*(pool_shapes[i] for i in spec["pools"]),
-                                  spec["n_kv"]))
+            and not spec["sink"] and len(mine) == 2
+            and (paged_flat_usable(*mine, spec["n_kv"])
+                 or paged_packed_usable(*mine, spec["n_kv"])))
 
 
 def state_step_in_kernel(attn_kinds, pool_specs):
@@ -2675,7 +2724,8 @@ def _block_runner(ins, attrs):
         value_scale=attrs.get("value_scale", 1.0),
         attn_kinds=attrs.get("attn_kinds"),
         layer_kinds=attrs.get("layer_kinds"),
-        passes=attrs.get("passes", 1))
+        passes=attrs.get("passes", 1),
+        route_eps=attrs.get("route_eps", 1e-20))
     params = {s: ins[s][0] for s in _BLOCK_SLOTS if s in ins}
     lead = {s: ins["Lead" + s][0] for s in _BLOCK_SLOTS
             if "Lead" + s in ins}

@@ -191,6 +191,9 @@ _DECODE_COUNTERS = (
     # entry is megabytes a layer and a long prompt is its chunk job's to
     # carry through many dispatches
     "delta_state_updates_total", "delta_prefill_positions_total",
+    # and for a model whose state layers are gated short convolutions
+    # (CONV_STATS): the kind with ONE pool, a tail of two inputs a layer
+    "conv_state_updates_total", "conv_prefill_positions_total",
     # a model whose stack is run several times a token (PR 43) keeps a
     # cache layer a pass a layer, and counts on the device, over decode
     # steps, the layer passes its active rows went through and the

@@ -751,3 +751,79 @@ def test_the_grouped_rows_kernel_compiles_at_lagunas_window(one_chip,
                                  ([5, 64, 1024, 3584], "bfloat16")])
     docs_step = compiled(16, 4, *xing4).as_text()
     assert "ragged" in docs_step and not re.findall(grouped, docs_step)
+
+
+# -- a model of gated short convolutions (models/hybrid_conv_moe.py) -------
+
+@pytest.fixture(scope="module")
+def lfm2():
+    """benchmark/configs/lfm2-24b-a2b.json: 256 slots of 4,096 + 1,536
+    positions in pages of 64, heads of 64, 64 experts of 2,048 x 1,536 a
+    routed layer, chunks of 2,048, 4 steps: (its configuration, the
+    programs)."""
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(pa, "_use_pallas", lambda: True)
+        return _programs_of("lfm2-24b-a2b.json", "serve_hybrid_conv")
+
+
+def test_the_packed_kernel_compiles_at_lfm2s_shapes(one_chip, lfm2,
+                                                    monkeypatch):
+    """Mosaic takes the pools as they are stored, keys and values 512 wide
+    (eight heads of 64: HALF a lane tile a value head, which the flat
+    form's gate refuses), 32 query heads over 256 rows: ONE custom call,
+    ``paged_flat_packed_decode``, no pool re-laid on its way into it."""
+    monkeypatch.setattr(pa, "_use_pallas", lambda: True)
+    cfg, programs = lfm2
+
+    def abstract(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    (k_shape, _), (v_shape, _) = programs.pool_specs[:2]
+    assert k_shape == v_shape == [1, 22785, 64, 512]
+    assert pa.paged_packed_usable(k_shape, v_shape, cfg.n_kv)
+    assert not pa.paged_flat_usable(k_shape, v_shape, cfg.n_kv)
+    rows = programs.max_batch
+    text = jax.jit(pa.paged_flat_decode).lower(
+        abstract((rows, cfg.n_heads, cfg.head_dim)), abstract(k_shape),
+        abstract(v_shape), abstract((), jnp.int32),
+        abstract((rows, programs.pages_per_seq), jnp.int32),
+        abstract((rows,), jnp.int32)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert len(re.findall(r"tpu_custom_call.*paged_flat_packed_decode",
+                          text)) == 1
+    _assert_held_uncopied(text, programs.pool_specs[:2])
+
+
+@pytest.mark.parametrize("label", ["decode", "prefill_512", "chunk"])
+def test_lfm2s_programs_hold_their_kernels_and_copy_no_page(
+        one_chip, lfm2, label, monkeypatch):
+    """The decode program at 256 rows: ONE instance of the packed paged
+    kernel (one attention layer of five) and the routed layers' sorted
+    pairs through ``moe_grouped_rows`` (the attention layer's by its own
+    number, the conv layers' inside their scan: two instances), no
+    ``ragged_dot`` and no few-rows kernel (two experts are 37.7 MB); the
+    prefill programs the same two instances of the grouped kernel and
+    their attention in plain XLA. The pages are aliased from the donated
+    inputs and never copied; the experts' stacks are taken as stored."""
+    monkeypatch.setattr(pa, "_use_pallas", lambda: True)
+    _, programs = lfm2
+    assert programs.decode["in_place"]
+    assert not programs.decode["state_in_kernel"]
+    assert programs.chunk["experts_in_kernel"]
+    assert not programs.chunk["attn_in_kernel"]
+    compiled = program_text.lower_bundle(
+        program_text.bundles_of(programs)[label],
+        len(programs.pool_specs), sharding=one_chip).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"tpu_custom_call.*paged_flat_packed_decode",
+                          text)) == (label == "decode")
+    assert len(re.findall(r"tpu_custom_call.*moe_grouped_rows", text)) == 2
+    assert "ragged" not in text and "moe_few_rows" not in text
+    assert "prefill_fold" not in text
+    _assert_held_uncopied(text, programs.pool_specs[:2] + [
+        ([3, 64, 2048, 1536], "bfloat16"), ([3, 64, 1536, 2048], "bfloat16"),
+        ([1, 64, 2048, 1536], "bfloat16")])
+    memory = compiled.memory_analysis()
+    pages = 2 * math.prod(programs.pool_specs[0][0]) * 2
+    assert memory.alias_size_in_bytes >= pages
+    assert memory.temp_size_in_bytes < 0.7e9

@@ -436,8 +436,8 @@ REFUSED = {
     "a_window": (dict(FULL_KIND, window=4), FLAT_POOLS),
     "a_state_space_mixer": (dict(FULL_KIND, mixer="ssm"), FLAT_POOLS),
     "keys_of_no_whole_tiles": (FULL_KIND, [(2, 40, 4, 24), (2, 40, 4, 256)]),
-    "value_heads_of_half_a_tile": (FULL_KIND,
-                                   [(2, 40, 4, 384), (2, 40, 4, 128)]),
+    "value_heads_that_divide_no_tile": (FULL_KIND,
+                                        [(2, 40, 4, 384), (2, 40, 4, 96)]),
     "heads_inside_positions": (FULL_KIND, [(2, 40, 4, 2, 128)] * 2),
     "pools_of_other_pages": (FULL_KIND, [(2, 40, 4, 384), (2, 48, 4, 256)]),
 }
@@ -462,6 +462,9 @@ def test_the_gate_answers_a_mixed_model_kind_by_kind(why, monkeypatch):
     assert not T.decode_in_place("latent", kinds, pools)
     spec, mine = REFUSED[why]
     assert not T.decode_in_place("gqa", (spec, window), mine + rings)
+    # value heads of HALF a tile have the packed form of the kernel (PR 58)
+    assert T.decode_in_place("gqa", (FULL_KIND, window), [
+        (2, 40, 4, 384), (2, 40, 4, 128)] + rings, 0)
     assert not T.decode_in_place("gqa", (spec, window), mine + rings, 0)
 
 
