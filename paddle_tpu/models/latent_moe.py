@@ -251,12 +251,16 @@ def build_block_programs(cfg, *, pool_specs, common, max_batch, page_size,
     models/hybrid_moe.py; ``state``: models/hybrid_ssm.py); every program
     then takes a table a kind, [rows, spec["pages_per_seq"]], behind the
     page table, under the op's slot and the feed name ``spec["table"]``
-    gives."""
+    gives. A bundle's ``fetch`` is what the engine's loop dispatches and
+    ``extras`` names what it fetches behind tokens and pools; the decode
+    bundle also has ``probe``, its whole fetch set under the same two
+    keys, for a caller outside the loop."""
     from ..core import framework
 
     def bundle(kind, prefix, feeds, steps=1):
         """One program: ``feeds`` are (slot, feed name, shape, dtype)
-        of its data inputs, in feed order; the pools follow."""
+        of its data inputs, in feed order; the pools follow. What it
+        fetches beside tokens and pools: logits, picks and stats."""
         main = framework.Program()
         with framework.program_guard(main, framework.Program()), \
                 framework.unique_name.guard():
@@ -311,6 +315,18 @@ def build_block_programs(cfg, *, pool_specs, common, max_batch, page_size,
         ("Tokens", "tokens", [max_batch], "int64"),
         ("Positions", "positions", [max_batch], "int32"),
         *tables(max_batch)], steps=decode_block)
+    # two fetch sets over the one Program, an executable each (the
+    # executor keys them by the fetch names). ``probe``: everything, for a
+    # caller that compares the logits [max_batch, decode_block, vocab] of
+    # every step with a reference. The serving loop's own fetches tokens,
+    # pools and stats: no request receives those logits, and stacked as a
+    # scan's output they cost a pass over the whole float32 buffer a step
+    # (PERF.md section 6, PR 59); the compiler drops an output nobody
+    # fetches, and the picks go with them
+    *head, _, _, st = decode["fetch"]
+    decode.update(probe={"fetch": decode["fetch"],
+                         "extras": decode["extras"]},
+                  fetch=head + [st], extras=("stats",))
     decode["in_place"] = decode_in_place(
         attrs["attention"], attrs.get("attn_kinds"), shapes)
     decode["state_in_kernel"] = state_step_in_kernel(

@@ -2593,6 +2593,15 @@ def _paged_decode(run, tok, pos, table, pools, steps, extras=False):
         h, *cache = run.decode_step(run.embed(tok[:, None]), *cache, table,
                                     pos)
         logits = run.logits_of(h[:, 0])
+        if extras and run.head.dtype == jnp.bfloat16:
+            # the logits handed back are the ones compared, whether a
+            # caller fetches them or not: the head's product rounded to
+            # its own type, SAID to the compiler. Where nobody fetches
+            # them it fuses the argmax into the head and would keep the
+            # product's excess precision, and ties between rounded
+            # logits then fall another way than in the form that stores
+            # them (PERF.md section 6, PR 59)
+            logits = jax.lax.reduce_precision(logits, 8, 7)
         nxt = jnp.argmax(logits, axis=-1).astype(tok.dtype)
         if not extras:
             return (nxt, pos + 1, tuple(cache), stats), nxt
@@ -2790,7 +2799,10 @@ def _block_paged_prefill_chunk(ctx, ins, attrs):
 def _block_paged_decode(ctx, ins, attrs):
     """llama_paged_decode for a model of any block kinds: also Logits
     [B, steps, V] float32, Picks [B, steps, routed layers, K] and Stats
-    summed over the steps."""
+    summed over the steps. Logits and Picks are for a caller that
+    compares them with a reference: the serving loop fetches neither
+    (models/latent_moe.py build_block_programs), and the compiler then
+    builds neither."""
     toks, pools, logits, picks, stats = _paged_decode(
         _block_runner(ins, attrs), ins["Tokens"][0], ins["Positions"][0],
         ins["Table"][0], ins["Pools"],

@@ -145,6 +145,11 @@ _DECODE_COUNTERS = (
     # step): equal to decode_batches_total on the chip for Jamba2, 0 on a
     # CPU and for a model without such layers
     "state_step_in_kernel_total",
+    # ticked once a decode dispatch made in the bundle's ``probe`` form
+    # (``_run_decode_program`` called from outside the loop: every step's
+    # float32 logits and picks fetched and left under ``kept["decode"]``):
+    # how often the costly form ran, 0 over any window of serving
+    "decode_probe_dispatches_total",
     # and decode_in_place_total's sibling for prefill: ticked beside
     # prefill_dispatch_total and chunk_prefill_total for every whole-prompt
     # or chunk dispatch
@@ -765,7 +770,8 @@ class DecodeEngine:
             # the PLAIN decode program warms even for speculative
             # engines: brownout level 2 (spec_off) switches a live engine
             # to it, and the no-recompile pin must survive that switch
-            warm("decode", self._run_decode_program,
+            warm("decode",
+                 lambda *a: self._run_decode_program(*a, loop=True),
                  np.zeros((max_batch,), np.int64),
                  np.ones((max_batch,), np.int32),
                  np.zeros((max_batch, self.pages_per_seq), np.int32),
@@ -777,7 +783,7 @@ class DecodeEngine:
                      np.ones((max_batch,), np.int32),
                      np.zeros((max_batch, self.pages_per_seq), np.int32))
         self.metrics.incr("warmup_s_total", whole.seconds)
-        self._warmed = self.exe.compile_counts()
+        self._warmed = self._loop_compiles()
         self._warmed_at = whole.t0 + whole.seconds
         compiles = self.exe.total_compiles()
         self.metrics.incr("warmup_compiles", compiles)
@@ -800,14 +806,26 @@ class DecodeEngine:
                 for e in profiler.compile_log(since=t)
                 if e["program"] in labels]
 
+    def _loop_compiles(self):
+        """``exe.compile_counts()`` of the executables the loop dispatches:
+        all but the decode bundle's ``probe`` form, which an outside
+        caller compiles at its first use and ``warmup`` does not."""
+        probe = self.programs.decode.get("probe")
+        names = probe and tuple(getattr(v, "name", v)
+                                for v in probe["fetch"])
+        uid = self.programs.decode["program"].uid
+        return {k: n for k, n in self.exe.compile_counts().items()
+                if (k[0], k[3]) != (uid, names)}
+
     def assert_no_recompiles(self):
         """AssertionError if any XLA compile happened after warmup —
         the churn-proof contract — naming what compiled from the
         compile log: label, feed shapes, seconds, ``cache_hit``. No-op
-        before warmup."""
+        before warmup. The probe form of the decode program is no part
+        of the contract (``_loop_compiles``)."""
         if self._warmed is None:
             return
-        now = self.exe.compile_counts()
+        now = self._loop_compiles()
         if now != self._warmed:
             what = "; ".join(
                 f"{label} in {e['t1'] - e['t0']:.3f} s (cache_hit "
@@ -1127,7 +1145,7 @@ class DecodeEngine:
             try:
                 report = b["program"].optimize(
                     fetch_list=[v.name if hasattr(v, "name") else v
-                                for v in b["fetch"]])
+                                for v in b.get("probe", b)["fetch"]])
                 if report:
                     self.optimize_reports[label] = report.to_dict()
             except Exception as e:  # pragma: no cover - safety net
@@ -1268,6 +1286,8 @@ class DecodeEngine:
                 (int(x) for x in np.asarray(kept.pop("stats"))))))
         if kept:
             self.kept[label] = kept
+        else:                   # nor does an earlier dispatch's stay
+            self.kept.pop(label, None)
         # ticked as the dispatch returns, beside its caller's own count:
         # a snapshot finds the two equal, not one dispatch apart
         if consumed:
@@ -1326,10 +1346,21 @@ class DecodeEngine:
             "chunk", self.programs.chunk,
             (tokens, lens, offsets, table) + kinds)[0]
 
-    def _run_decode_program(self, tokens, positions, table, *kinds):
+    def _run_decode_program(self, tokens, positions, table, *kinds,
+                            loop=False):
+        """One decode dispatch: the ``[rows, decode_block]`` tokens.
+        ``loop``: what the worker's loop and ``warmup`` pass, and get the
+        bundle's serving form. Any other caller gets its ``probe`` form
+        where it has one (a block-kind model's: the same Program with its
+        whole fetch set, compiled at the first such call): ``kept["decode"]``
+        then holds that dispatch's ``logits`` ``[rows, decode_block,
+        vocab]`` float32 and ``picks``, on the device."""
+        b = self.programs.decode
+        if not loop and "probe" in b:
+            b = {**b, **b["probe"]}
+            self.metrics.incr("decode_probe_dispatches_total")
         return self._run_program(
-            "decode", self.programs.decode,
-            (tokens, positions, table) + kinds)[0]
+            "decode", b, (tokens, positions, table) + kinds)[0]
 
     def _run_spec_program(self, tokens, prev, positions, table):
         emitted, accepted = self._run_program(
@@ -2124,7 +2155,7 @@ class DecodeEngine:
             self._maybe_inject_fault()
             if not use_spec:
                 return self._run_decode_program(toks, pos, table,
-                                                *kind_tables)
+                                                *kind_tables, loop=True)
             return self._run_spec_program(toks, prev, pos, table)
 
         dispatch = record_event("pt:engine/decode_dispatch",

@@ -101,6 +101,13 @@ def fingerprint(lowered):
     return hashlib.sha256(text.encode()).hexdigest()[:16], count
 
 
+def probe_form(bundle):
+    """A decode bundle with its ``probe`` fetch set in place of the one
+    the loop dispatches (models/latent_moe.py ``build_block_programs``):
+    the same Program, every step's logits and picks among its results."""
+    return {**bundle, **bundle["probe"]}
+
+
 def bundles_of(programs):
     """label -> bundle of every target-model program of a
     PagedDecodePrograms."""

@@ -993,7 +993,7 @@ def _watch(eng, at=None):
     seen = []
 
     def watched(run):
-        def dispatch(*args):
+        def dispatch(*args, **kw):
             slots = [s for s in eng.slots if s is not None]
             seen.append((len(slots), len(eng._queue), [
                 (len(s.held["sequence"]),
@@ -1001,7 +1001,7 @@ def _watch(eng, at=None):
                 for s in slots]))
             if at is not None:
                 at(len(seen), slots)
-            return run(*args)
+            return run(*args, **kw)
         return dispatch
 
     eng._run_decode_program = watched(eng._run_decode_program)

@@ -335,14 +335,19 @@ def _float8_weights(mp, w):
 
 def _gate_dropped(which):
     """The engine's mixer without one of its two gates: of the three
-    parts ``W_in``'s result is split into, the gate's reads 1."""
+    parts ``W_in``'s result is split into, the gate's reads 1. ``T.jnp``
+    is jax.numpy itself, which the reference splits with too: only a call
+    from the engine's module loses the gate, whichever of the two is
+    traced first in this process."""
     def plant(mp, w):
+        import sys
         from paddle_tpu.ops import transformer_ops as T
         split = jnp.split
 
         def gates_of(x, n, axis=-1):
             parts = split(x, n, axis=axis)
-            if n == 3 and axis == -1:
+            if n == 3 and axis == -1 \
+                    and sys._getframe(1).f_globals is vars(T):
                 parts[which] = jnp.ones_like(parts[which])
             return parts
         mp.setattr(T.jnp, "split", gates_of)

@@ -336,13 +336,16 @@ def test_a_request_on_a_reused_slot_is_the_request_on_a_fresh_engine(
     """One slot, so the second and third requests take the entry and the
     pages the first left full: bit for bit the tokens and the logits of
     the same requests on an engine nothing has used; whole-prompt and
-    chunked."""
+    chunked. The loop's own dispatches fetch no logits: here they are
+    made in the probe form, as a caller outside the loop makes them."""
     first, short, long_ = (prompt_of(n, seed=s)
                            for n, s in ((14, 1), (6, 2), (29, 3)))
 
     def serve(prompts):
         out = []
         with engine_of(served[1], max_batch=1) as eng:
+            probe = eng._run_decode_program
+            eng._run_decode_program = lambda *a, loop: probe(*a)
             eng.start()
             for p in prompts:
                 toks = eng.generate(p, max_new=5)

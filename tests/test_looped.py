@@ -399,14 +399,14 @@ def test_that_pool_holds_more_rows_than_three_whole_reservations(served):
                     max_queue=16)
     rows, run = [], eng._run_decode_program
 
-    def watched(*args):
+    def watched(*args, **kw):
         live = [s for s in eng.slots if s is not None]
         rows.append(len(live))
         assert sum(len(s.held["sequence"]) for s in live) <= 15
         assert all(s.grows_to == eng._pages_needed(s.req.prompt.size,
                                                    s.req.max_new)
                    for s in live)
-        return run(*args)
+        return run(*args, **kw)
 
     eng._run_decode_program = watched
     try:

@@ -151,6 +151,13 @@ def _programs_of(config_file, builder):
         decode_block=block, chunk_size=e.get("chunk_size"))
 
 
+def _decode_form(programs, form):
+    """The decode bundle as the loop dispatches it (``serving``) or with
+    its whole fetch set (``probe``)."""
+    return programs.decode if form == "serving" \
+        else program_text.probe_form(programs.decode)
+
+
 @pytest.fixture(scope="module")
 def jamba():
     """benchmark/configs/ai21-jamba2-3b.json: 128 slots of 5,120 + 2,048
@@ -408,20 +415,29 @@ def test_jambas_decode_program_steps_its_states_in_one_kernel_a_run(
 # differs in the ORDER of the state's slice and the tail's slice and
 # reshape, independent reads, and in nothing else: ``chip_fingerprint``
 # 087b7b82ca75ef6f before, b803baf44dde286d after): what the chip's
-# compiler leaves is the same module, instruction for instruction
-DELTA_COMPILED = "e7fcd32965a238a4"
+# compiler leaves is the same module, instruction for instruction. PR 59
+# gave the decode bundle two fetch sets over the one Program. With nothing
+# else changed the PROBE form, the whole set, still compiled to that value
+# (e7fcd32965a238a4) and the form the loop dispatches, which fetches no
+# ``Logits`` and no ``Picks``, to 2b3021d94c20afa4: those two results went
+# and nothing else. The values below were taken after the same PR's second
+# change, one ``reduce_precision`` of a step's logits to bfloat16 before
+# their argmax (``_paged_decode``), which is in both forms
+DELTA_COMPILED = {"probe": "1580d25c5d040dc5", "serving": "969ce41a30244cbf"}
 
 
+@pytest.mark.parametrize("form", sorted(DELTA_COMPILED))
 def test_the_delta_cells_decode_program_compiles_to_what_it_did(
-        one_chip, monkeypatch):
+        one_chip, form, monkeypatch):
     monkeypatch.setattr(pa, "_use_pallas", lambda: True)
     programs = _programs_of("olmo-hybrid-7b.json", "serve_delta")[1]
     assert programs.decode["in_place"]
     assert not programs.decode["state_in_kernel"]
     assert programs.pool_specs[2] == ([12, 9, 30, 96, 192], "float32")
     got = program_text.compiled_fingerprint(program_text.lower_bundle(
-        programs.decode, len(programs.pool_specs), sharding=one_chip))
-    assert got == DELTA_COMPILED, got
+        _decode_form(programs, form), len(programs.pool_specs),
+        sharding=one_chip))
+    assert got == DELTA_COMPILED[form], got
 
 
 # -- a model whose stack is run several times a token (models/looped.py) ---
@@ -634,15 +650,30 @@ def test_a_latent_decode_program_holds_no_view(one_chip, latent):
 # holds every expert, kept its text. Jamba2's as PR 49 made it: its 26 state
 # layers step their entries through the kernel ``ssm_state_step``
 # (9e718d24c1afa2e7 before); Olmo-Hybrid's taken on PR 49's tree, whose
-# compiled module is PR 48's (DELTA_COMPILED, above)
-OTHERS_PINNED = {"mimo": "e3ec26ac94aea7da", "jamba": "2e58799dbdc7d219",
-                 "ouro": "4862221ab083276b", "xing4": "f9c8c1dbb68a814f",
-                 "deepseek": "7f3ae63f3374ef41", "olmo": "b803baf44dde286d"}
+# compiled module is PR 48's (DELTA_COMPILED, above). PR 59 made two
+# changes to every one of them, taken one after the other on its tree. A
+# decode bundle has two fetch sets over its one Program: the PROBE form (the
+# whole set, the first of each pair below) kept the text it had (mimo
+# e3ec26ac94aea7da, jamba 2e58799dbdc7d219, ouro 4862221ab083276b, xing4
+# f9c8c1dbb68a814f, deepseek 7f3ae63f3374ef41, olmo b803baf44dde286d) and the
+# form the loop dispatches (the second) lost its ``Logits`` and ``Picks``
+# results and nothing else (66f567f60fbba837, 8c6bf1ef9f1acf13,
+# f5b2bb1bc7704ea6, 983ff2884a43e182, e1c6f34ad4c863c6, da2370972a93574b).
+# Then a step's bfloat16 logits are rounded in so many words before their
+# argmax (one ``reduce_precision`` in ``_paged_decode``, in both forms, so
+# that both break ties alike on the chip: PERF.md section 6): the values below
+OTHERS_PINNED = {"mimo": ("709778c50a5d8e3e", "cd3c6d2fbdbf9b5c"),
+                 "jamba": ("f93ae67235d28a87", "62afaf6af85d580f"),
+                 "ouro": ("bec3361482f58ada", "ce139c92c00c35fb"),
+                 "xing4": ("5eba9bd50298ccc5", "d847159f6ff1abbf"),
+                 "deepseek": ("1fce1d82787bf467", "bc5a4bc7c4822632"),
+                 "olmo": ("ec1662f42fb73b82", "7602b73ab9aba4af")}
 
 
+@pytest.mark.parametrize("form", ["probe", "serving"])
 @pytest.mark.parametrize("model", sorted(OTHERS_PINNED))
 def test_the_other_decode_programs_are_what_the_chip_was_asked_before(
-        one_chip, model, looped, monkeypatch):
+        one_chip, model, form, looped, monkeypatch):
     monkeypatch.setattr(pa, "_use_pallas", lambda: True)
     if model == "ouro":
         cfg, geometry = looped
@@ -652,8 +683,9 @@ def test_the_other_decode_programs_are_what_the_chip_was_asked_before(
             MIXED, xing4=LATENT["docs"][:2], deepseek=LATENT["reason"][:2],
             olmo=("olmo-hybrid-7b.json", "serve_delta"))[model])[1]
     got = program_text.chip_fingerprint(program_text.lower_bundle(
-        programs.decode, len(programs.pool_specs), sharding=one_chip))
-    assert got == OTHERS_PINNED[model], (model, got)
+        _decode_form(programs, form), len(programs.pool_specs),
+        sharding=one_chip))
+    assert got == OTHERS_PINNED[model][form == "serving"], (model, got)
 
 
 def test_the_few_rows_kernel_compiles_at_lagunas_experts(one_chip,
@@ -827,3 +859,55 @@ def test_lfm2s_programs_hold_their_kernels_and_copy_no_page(
     pages = 2 * math.prod(programs.pool_specs[0][0]) * 2
     assert memory.alias_size_in_bytes >= pages
     assert memory.temp_size_in_bytes < 0.7e9
+
+
+# -- the two forms of a block-kind model's decode program (PR 59) ----------
+
+def _step_logits(text, steps, rows, vocab):
+    """(the float32 arrays of ``text`` whose dimensions are steps, rows
+    and vocabulary in any order, its dynamic-update-slices of vocabulary
+    width)."""
+    stacked = [m.group(0) for m in re.finditer(r"f32\[([\d,]+)\]", text)
+               if sorted(map(int, m.group(1).split(",")))
+               == sorted((steps, rows, vocab))]
+    updates = [m.group(0) for m in re.finditer(
+        r"= \w+\[([\d,]*)\][^ ]* dynamic-update-slice\(", text)
+        if str(vocab) in m.group(1).split(",")]
+    return stacked, updates
+
+
+@pytest.mark.parametrize("model", ["jamba", "lfm2"])
+def test_the_serving_decode_program_holds_no_step_logits(one_chip, model,
+                                                         request):
+    """The decode program the loop dispatches, at the cell's shapes (LFM2:
+    4 steps of 256 rows over 65,536 words; Jamba2: of 128 rows): the
+    chip's compiler leaves no float32 array of steps x rows x vocabulary
+    and no dynamic-update-slice of vocabulary width, because nothing
+    fetches the scan's stacked logits (as a scan output their layout puts
+    the steps in every tile's sublanes, and a step's write goes through
+    the whole 268 MB: PERF.md section 6, PR 59). The probe form, the same
+    Program with its whole fetch set, holds both."""
+    programs = request.getfixturevalue(model)
+    programs = programs[1] if model == "lfm2" else programs
+    steps, rows, vocab = 4, programs.max_batch, 65536
+    assert rows == {"jamba": 128, "lfm2": 256}[model]
+    decode = programs.decode
+    whole = [v.name for v in decode["probe"]["fetch"]]
+    assert [v.name for v in decode["fetch"]] == whole[:-3] + whole[-1:]
+
+    def compiled(bundle):
+        return program_text.lower_bundle(
+            bundle, len(programs.pool_specs), sharding=one_chip).compile()
+
+    probe = compiled(program_text.probe_form(decode))
+    stacked, updates = _step_logits(probe.as_text(), steps, rows, vocab)
+    assert stacked and updates
+    serving = compiled(decode)
+    assert _step_logits(serving.as_text(), steps, rows, vocab) == ([], [])
+    # where the argmax is fused into the head the product's rounding to
+    # bfloat16 is the program's own word, not the compiler's choice
+    assert re.search(rf"f32\[{rows},{vocab}\][^ ]* reduce-precision\(.*"
+                     r"exponent_bits=8, mantissa_bits=7", serving.as_text())
+    assert probe.memory_analysis().output_size_in_bytes \
+        - serving.memory_analysis().output_size_in_bytes \
+        >= steps * rows * vocab * 4
