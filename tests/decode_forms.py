@@ -23,10 +23,15 @@ import program_text
 
 
 def kernel_on(monkeypatch, page_size):
-    """The Pallas kernels through the interpreter, a block two pages long
-    so that a row of a few pages folds several blocks."""
+    """The Pallas kernels through the interpreter, a block of any of the
+    paged kernels two pages long: a row of a few pages folds several
+    blocks, and the interpreter unrolls two page copies a block where the
+    chip's 512 positions over pages of 4 would be 128 (a minute of
+    lowering a decode program)."""
     monkeypatch.setattr(pa, "_FORCE_INTERPRET", True)
-    monkeypatch.setattr(pa, "PAGED_LATENT_BLOCK_KEYS", 2 * page_size)
+    for block in ("PAGED_BLOCK_KEYS", "PAGED_FLAT_BLOCK_KEYS",
+                  "PAGED_LATENT_BLOCK_KEYS"):
+        monkeypatch.setattr(pa, block, 2 * page_size)
 
 
 def rel_l2(got, want):

@@ -5,13 +5,13 @@ time (latent attention under hyper-connections and as a share, full and
 window layers, attention beside state-space layers) at their tiny sizes
 with the heads WIDENED TO WHOLE LANE TILES, which is where the kernel
 ``prefill_fold`` is admitted (the tiny configurations themselves are
-narrower: there the gate keeps the jax.numpy fold, on the chip too). Each
-model's test file runs its builder's probe (``engine_logits``: a prompt
-through the whole-prompt program, one through three chunks, then decoded
-positions, as the chip comparison drives the engine's own programs) with
-the fold in jax.numpy and then through the kernel in the Pallas
-interpreter: the same tokens and picks, the same logits and pools to the
-order of the sums.
+narrower: there the gate keeps the jax.numpy fold, on the chip too).
+test_prefill_forms.py runs each model's builder's probe (``engine_logits``:
+a prompt through the whole-prompt program, one through three chunks, then
+decoded positions, as the chip comparison drives the engine's own
+programs) with the fold in jax.numpy and then through the kernel in the
+Pallas interpreter: the same tokens and picks, the same logits and pools
+to the order of the sums.
 """
 import dataclasses
 
@@ -26,6 +26,8 @@ from paddle_tpu.models.latent_moe import (LATENT_MOE_TINY,
                                           LATENT_SHARE_TINY)
 from paddle_tpu.ops import pallas_attention as pa
 from paddle_tpu.serving import DecodeConfig, DecodeEngine
+
+import decode_forms
 
 # xing4's and DeepSeek-V3's head (128 | 64 rotated | 128), MiMo's (keys 192
 # beside values 128, 64 of them rotated; two key/value heads here) and
@@ -47,8 +49,10 @@ ENGINE = dict(max_batch=3, prompt_buckets=(8, 16, 48), max_new_tokens=8,
 def kernel_on(monkeypatch):
     """The Pallas kernels through the interpreter, ``prefill_fold`` in
     tiles of 8 queries by 8 keys and 16 keys a visit: a window of 16 is two
-    query tiles, a row of 48 positions three visits of two key tiles."""
-    monkeypatch.setattr(pa, "_FORCE_INTERPRET", True)
+    query tiles, a row of 48 positions three visits of two key tiles. The
+    hook takes the decode steps through their paged kernels too: blocks of
+    two of ``ENGINE``'s pages, as decode_forms.py has them."""
+    decode_forms.kernel_on(monkeypatch, ENGINE["page_size"])
     monkeypatch.setattr(pa, "PREFILL_BLOCK_Q", 8)
     monkeypatch.setattr(pa, "PREFILL_BLOCK_KEYS", 8)
     monkeypatch.setattr(pa, "PREFILL_VISIT_KEYS", 16)

@@ -316,6 +316,13 @@ class TestExecutorValidation:
 # lowering error context (satellite)
 # ---------------------------------------------------------------------------
 
+def said(e):
+    """An exception's message and its notes: ``core/lowering.py`` attaches
+    the op's context with ``add_note`` (every Python since 3.11), which a
+    traceback prints and ``str(e)`` does not."""
+    return "\n".join([str(e), *getattr(e, "__notes__", ())])
+
+
 class TestLoweringErrorContext:
     def test_failure_names_op_and_wiring(self):
         a = fluid.layers.data(name="a", shape=[4, 6], dtype="float32",
@@ -325,7 +332,7 @@ class TestLoweringErrorContext:
         with pytest.raises(Exception) as ei:
             exe.run(feed={"a": np.zeros((4, 6), np.float32)},
                     fetch_list=[r], validate="0")
-        msg = str(ei.value)
+        msg = said(ei.value)
         assert "while lowering op 'reshape'" in msg
         assert "block 0" in msg and a.name in msg
 
@@ -342,7 +349,7 @@ class TestLoweringErrorContext:
             exe.run(feed={"x": np.zeros((2, 3), np.float32)},
                     fetch_list=[out.name], validate="0")
         assert not isinstance(ei.value, (SystemExit, KeyboardInterrupt))
-        assert "while lowering op 'transpose'" in str(ei.value)
+        assert "while lowering op 'transpose'" in said(ei.value)
 
 
 # ---------------------------------------------------------------------------

@@ -598,10 +598,15 @@ def test_replica_crash_chaos_zero_loss_and_revival():
         finally:
             faultinject.disarm("serving_replica_crash")
         assert outs[0][0].shape == (1, 10)
-        # the monitor revives the crashed worker
+        # the monitor revives the crashed worker. Waited for by the
+        # revival's own counter: ``ready_count()`` alone still reads 2
+        # until the monitor's next sweep has SEEN the crash, and a loop
+        # that left on it then read ``revives_total`` 0 (met under six
+        # workers, where the answer came back before the sweep)
         deadline = time.monotonic() + 10.0
-        while time.monotonic() < deadline \
-                and router.pool.ready_count() < 2:
+        while time.monotonic() < deadline and not (
+                router.pool.ready_count() == 2
+                and router.stats()["revives_total"] >= 1):
             time.sleep(0.01)
         snap = router.stats()
         assert snap["ready_replicas"] == 2

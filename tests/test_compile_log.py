@@ -3,6 +3,7 @@
 by phase, written where the compile happens; nothing on a cached dispatch;
 the engine's two set-up counters and its warm-up by label; the spans.
 """
+import re
 import threading
 import time
 
@@ -211,7 +212,7 @@ def test_totals_sum_the_entries_up_to_an_instant():
 # ---------------------------------------------------------------------
 
 def test_cache_hit_is_false_then_true_across_clear_caches_and_null_when_off(
-        tmp_path):
+        tmp_path, no_compile_cache):
     from jax.experimental.compilation_cache import compilation_cache
     exe, main, run = regression(width=24)
     keep = {k: getattr(jax.config, k) for k in (
@@ -342,7 +343,7 @@ def test_two_engines_warming_on_two_threads_keep_their_entries_apart(scope):
 # (e) the engine's counters, its warm-up by label, what recompiled
 # ---------------------------------------------------------------------
 
-def test_the_engine_counts_its_build_and_its_warmup(scope):
+def test_the_engine_counts_its_build_and_its_warmup(scope, no_compile_cache):
     t = time.monotonic()
     eng = make_engine(scope)
     built = time.monotonic()
@@ -375,7 +376,7 @@ def test_the_engine_counts_its_build_and_its_warmup(scope):
         eng.close()
 
 
-def test_assert_no_recompiles_names_what_compiled(scope):
+def test_assert_no_recompiles_names_what_compiled(scope, no_compile_cache):
     eng = make_engine(scope)
     try:
         eng.warmup()
@@ -388,7 +389,9 @@ def test_assert_no_recompiles_names_what_compiled(scope):
     finally:
         eng.close()
     said = str(err.value)
-    assert "compiled decode in 0." in said[:70]
+    # the message's words: how long the compile took is the machine's
+    # affair (1.055 s once, under six workers, where this read "in 0.")
+    assert re.search(r"compiled decode in \d+\.\d{3} s ", said[:70])
     assert "(cache_hit None)" in said[:200]       # what a builder prints
     assert "dc_tokens:int32[2]" in said and "prefill" not in said[:200]
 

@@ -45,7 +45,14 @@ _CHILD = textwrap.dedent("""
     def step(_):
         i = jax.lax.axis_index("dp")            # 0..7 across the pod
         x = (jnp.arange(4, dtype=jnp.float32) + 4.0 * i) / 100.0
-        w = jnp.full((4,), 0.5, jnp.float32)
+        # each shard's OWN copy of the weights: differentiating by a value
+        # that is the same on every shard gives, under this JAX's
+        # shard_map, the gradient already SUMMED over "dp" (the transpose
+        # of the broadcast), and the pmean below would then average eight
+        # copies of the sum: a step eight times the reference's (0.41580
+        # where it has 0.49677). The step moved, not the pin.
+        w = jax.lax.pcast(jnp.full((4,), 0.5, jnp.float32), "dp",
+                          to="varying")
 
         def loss_fn(w):
             return (jnp.dot(x, w) - 1.0) ** 2
@@ -179,7 +186,10 @@ _RESUME_CHILD = textwrap.dedent("""
         def loss_fn(w):
             return (jnp.dot(x, w) - 1.0) ** 2
 
-        loss, g = jax.value_and_grad(loss_fn)(w)
+        # by each shard's own copy of the weights, as in the child above:
+        # by the shared ``w`` the gradient comes summed over "dp" already
+        loss, g = jax.value_and_grad(loss_fn)(
+            jax.lax.pcast(w, "dp", to="varying"))
         # the satellite under test: whole-pytree dp grad sync
         synced = collectives.grad_tree_sync({"w": g}, "dp")
         w2 = w - 0.1 * synced["w"]

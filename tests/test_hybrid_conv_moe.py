@@ -531,6 +531,9 @@ def test_the_packed_paged_kernel_is_the_reference(monkeypatch, g, rep, dv):
     want = pa._ref_paged_attention(q, k_pool, v_pool, 1, table, lengths, g,
                                    dk ** -0.5)
     monkeypatch.setattr(pa, "_FORCE_INTERPRET", True)
+    # two pages a block: the row of 40 positions folds three, where the
+    # chip's 512 would be 64 copies a block for the interpreter to unroll
+    monkeypatch.setattr(pa, "PAGED_FLAT_BLOCK_KEYS", 2 * ps)
     assert pa.paged_packed_usable(k_pool.shape, v_pool.shape, g)
     assert not pa.paged_flat_usable(k_pool.shape, v_pool.shape, g)
     got = jax.jit(lambda *a: pa.paged_flat_decode(*a))(
@@ -633,6 +636,9 @@ def test_the_wide_model_decodes_through_both_kernels(monkeypatch):
     grouped kernel; logits are the reference's."""
     w = weights(cfg=WIDE)
     monkeypatch.setattr(pa, "_FORCE_INTERPRET", True)
+    # two of the engine's pages a block: the chip's 512 positions would be
+    # 128 copies a block for the interpreter to unroll
+    monkeypatch.setattr(pa, "PAGED_FLAT_BLOCK_KEYS", 2 * ENGINE["page_size"])
     monkeypatch.setattr(moe, "FEW_ROWS", 2)
     eng = engine_of(scope_of(w), cfg=WIDE, prompt_buckets=(8, 16))
     assert eng.programs.decode["in_place"]

@@ -295,6 +295,9 @@ def test_paged_flat_decode_pads_a_groups_heads_to_whole_sublane_tiles(
     want = PA._ref_paged_attention(q, k_pool, v_pool, 1, table, lens, 2,
                                    128 ** -0.5)
     monkeypatch.setattr(PA, "_FORCE_INTERPRET", True)
+    # two pages a block (the rows fold 2, 1 and 2): the chip's 512
+    # positions would be 64 copies a block for the interpreter to unroll
+    monkeypatch.setattr(PA, "PAGED_FLAT_BLOCK_KEYS", 16)
     got = jax.jit(lambda *a: PA.paged_flat_decode(*a))(
         q, k_pool, v_pool, 1, table, lens)
     assert got.shape == (3, 6, 128)

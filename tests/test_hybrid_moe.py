@@ -536,14 +536,3 @@ def test_the_handoff_blob_round_trips_both_kinds(engine, scope):
                 other.allocator.in_use_of("window")) == (3, 1)
     finally:
         other.close()
-
-
-def test_the_engines_dispatches_with_the_fold_in_the_kernel(monkeypatch):
-    """This model at MiMo's head (keys 192 beside values 128), its full
-    layers' entries flat at whole lane tiles: the chip comparison's probe,
-    whole and chunked, with ``_gqa_blocked`` in jax.numpy and through the
-    kernel (the hook also takes the full layers' decode steps in place:
-    test_paged_gqa_decode.py holds that form alone)."""
-    import prefill_forms
-    prefill_forms.check_both_forms(prefill_forms.MOE_WIDE, engine_logits,
-                                   monkeypatch)

@@ -633,13 +633,3 @@ def test_a_step_over_the_flat_tail_is_the_step_over_its_rows(dtype):
     tol = 1e-6 if dtype == "float32" else 2e-2
     assert np.allclose(np.asarray(got, np.float32),
                        np.asarray(want, np.float32), rtol=tol, atol=tol)
-
-
-def test_the_engines_dispatches_with_the_fold_in_the_kernel(monkeypatch):
-    """This model at Jamba's head (20 query heads over ONE key/value head
-    of 128): the chip comparison's probe, whole and chunked, with its two
-    attention layers' ``_gqa_blocked`` in jax.numpy and through the
-    kernel, the state layers beside them in both."""
-    import prefill_forms
-    prefill_forms.check_both_forms(
-        prefill_forms.SSM_WIDE, serve_ssm.engine_logits, monkeypatch)

@@ -588,16 +588,6 @@ def test_a_fault_the_comparison_must_catch_fails_it(probe, case):
     assert rel_l2(got, want).max() > REL_L2_F32, case
 
 
-def test_the_engines_dispatches_with_the_fold_in_the_kernel(monkeypatch):
-    """This model at heads of whole lane tiles (128 | 64 rotated | 128:
-    xing4's), where ``_latent_expanded`` hands its visits to the kernel:
-    the chip comparison's probe, whole and chunked, in both forms."""
-    import prefill_forms
-    from benchmark.builders import serve_blocks
-    prefill_forms.check_both_forms(
-        prefill_forms.LATENT_WIDE, serve_blocks.engine_logits, monkeypatch)
-
-
 # -- the decode program in place (decode_forms.py; PERF.md section 6, PR 45) --
 
 def test_a_decode_dispatch_in_both_forms(monkeypatch):

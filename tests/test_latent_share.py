@@ -685,17 +685,6 @@ def test_the_engines_logits_are_the_references_and_a_fault_is_not(engine):
     assert rel_l2(got, other).max() > REL_L2_F32
 
 
-def test_the_engines_dispatches_with_the_fold_in_the_kernel(monkeypatch):
-    """The share at heads of whole lane tiles (DeepSeek-V3's 128 | 64
-    rotated | 128), under the plain residual path: the chip comparison's
-    probe, whole and chunked, with ``_latent_expanded`` in jax.numpy and
-    through the kernel."""
-    import prefill_forms
-    from benchmark.builders import serve_blocks
-    prefill_forms.check_both_forms(
-        prefill_forms.SHARE_WIDE, serve_blocks.engine_logits, monkeypatch)
-
-
 # -- the decode program in place (decode_forms.py; PERF.md section 6, PR 45) --
 
 def test_a_decode_dispatch_in_both_forms(monkeypatch):

@@ -138,8 +138,15 @@ def test_one_f_one_b_dp_and_training():
     want_loss = _direct_loss(p, micro, tgt)
     assert abs(float(loss0) - float(want_loss)) < 1e-5
 
+    # one step in flight at a time. Dispatched without waiting, forty
+    # steps queue on the eight device threads, and once in some eighty
+    # runs under load seven threads meet in a step's collective permute
+    # while the eighth never comes: after 40 s XLA's CPU backend ends the
+    # PROCESS ("Termination timeout ... exceeded", `Fatal Python error:
+    # Aborted`) and the xdist worker's other tests with it. Waiting for
+    # each step, 150 runs under the same load all ended.
     for _ in range(40):
-        loss, grads = step(p, micro, tgt)
+        loss, grads = jax.block_until_ready(step(p, micro, tgt))
         p = jax.tree_util.tree_map(lambda a, g: a - 0.4 * g, p, grads)
     assert float(loss) < float(loss0) * 0.7, (float(loss0), float(loss))
 

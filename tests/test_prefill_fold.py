@@ -18,9 +18,8 @@ here:
   says of itself;
 - the engine's ``prefill_attn_in_kernel_total``.
 
-The engines of the four models that reach the folds run their own probes
-in both forms in their own files (test_latent_moe.py, test_latent_share.py,
-test_hybrid_moe.py, test_hybrid_ssm.py: ``prefill_forms.py``).
+The engines of the four models that reach the folds run their builders'
+probes in both forms in test_prefill_forms.py (``prefill_forms.py``).
 """
 import jax
 import jax.numpy as jnp
