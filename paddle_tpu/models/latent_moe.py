@@ -30,7 +30,8 @@ from dataclasses import dataclass
 
 from .. import layers
 from ..layers import transformer as tfl
-from ..ops.transformer_ops import (PAGED_STATS, decode_in_place,
+from ..ops.transformer_ops import (PAGED_STATS, decode_experts_in_kernel,
+                                   decode_in_place,
                                    prefill_experts_in_kernel,
                                    prefill_in_kernel, state_step_in_kernel,
                                    whole_tiles, yarn_inv_freq, yarn_mscale)
@@ -296,12 +297,13 @@ def build_block_programs(cfg, *, pool_specs, common, max_batch, page_size,
             (attrs["nope_dim"], attrs["v_dim"]), shapes, t_len,
             pages_per_seq, seen)
 
+    param_tables = [common["params"], common["lead_params"]] + [
+        table for _, _, table in common.get("stacks", ())]
+
     def experts_in_kernel(t_len):
         """Whether its routed layers' pairs go through the grouped kernel:
         asked as ``moe_apply_sorted`` asks where it lowers."""
-        return prefill_experts_in_kernel(
-            [common["params"], common["lead_params"]]
-            + [table for _, _, table in common.get("stacks", ())], t_len)
+        return prefill_experts_in_kernel(param_tables, t_len)
 
     prefill = {
         bucket: dict(bundle("prefill", "pp", [
@@ -331,6 +333,8 @@ def build_block_programs(cfg, *, pool_specs, common, max_batch, page_size,
         attrs["attention"], attrs.get("attn_kinds"), shapes)
     decode["state_in_kernel"] = state_step_in_kernel(
         attrs.get("attn_kinds"), pool_specs)
+    decode["experts_in_kernel"] = decode_experts_in_kernel(
+        param_tables, max_batch)
     chunk = None
     if chunk_size is not None:
         cs = int(chunk_size)

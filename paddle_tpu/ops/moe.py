@@ -12,6 +12,8 @@ Everything is one fused XLA program: no per-expert Python loops, no
 dynamic shapes, no host round-trips.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -176,32 +178,54 @@ def moe_load(idx, n_experts, valid=None, first=0):
     return hits.sum(axis=0)
 
 
+def _hidden_tile(d, f, itemsize):
+    """The run of an expert's hidden width ``f`` that one grid step of the
+    few-rows kernel takes: ``f`` itself, the expert uncut, where its three
+    matrices fit VMEM's default limit twice over; else the widest run of
+    whole lane tiles that divides ``f`` and keeps two runs' three blocks
+    (one in flight, one multiplied) within ``GROUPED_VMEM``; None where
+    not even one lane tile does."""
+    if 6 * d * f * itemsize <= _pa._VMEM_DEFAULT:
+        return f
+    return max((tf for tf in range(128, f + 1, 128)
+                if f % tf == 0 and 6 * d * tf * itemsize <= GROUPED_VMEM),
+               default=None)
+
+
 def few_rows_usable(t, w_gate, w_down, held=None):
     """The gate of ``moe_apply_few_rows``: the backend runs Pallas kernels,
-    every expert of the layer is held, the rows are at most one MXU tile
-    (a decode step's: a prefill window sorts its pairs), the widths whole
-    lane tiles, and ONE expert's three matrices fit VMEM's default limit
-    twice over, one in flight while one is multiplied (512-wide experts of
-    a 2,048-wide model: 12 MB; an expert of 3,584 x 1,024 is 44 MB twice
-    over, and its decode step's grouped products stand at their roofline
-    (PERF.md section 5, docs); cutting the hidden width in tiles copies an
-    expert once a row tile and read 0.56 of the uncut form's bandwidth in
-    the grouped kernel: PERF.md section 6, PR 56)."""
+    the rows are at most one MXU tile (a decode step's: a prefill window
+    sorts its pairs), the widths whole lane tiles, one type for the three
+    matrices, and a tile of an expert exists within the kernel's budget
+    (``_hidden_tile``: 512-wide experts of a 2,048-wide model are 12 MB
+    twice over and go uncut; DeepSeek-V3's 7,168 x 2,048 go 512 of their
+    hidden width a grid step, MiMo's 4,096 x 2,048 go 1,024, xing4's
+    3,584 x 1,024 whole under a raised limit). ``held`` refuses nothing:
+    a share only changes which columns of the rows' weights are non-zero.
+    It is taken so that whoever asks hands over what the call is given."""
+    del held
     d, f = w_gate.shape[-2:]
-    return (_pa._use_pallas() and held is None and t <= FEW_ROWS
+    return (_pa._use_pallas() and t <= FEW_ROWS
             and d % 128 == 0 and f % 128 == 0
             and w_gate.dtype == w_down.dtype
-            and 6 * d * f * w_gate.dtype.itemsize <= _pa._VMEM_DEFAULT)
+            and _hidden_tile(d, f, w_gate.dtype.itemsize) is not None)
 
 
 def _few_rows_kernel(layer_ref, touched_ref, n_ref, x_ref, c_ref, wg_ref,
-                     wu_ref, wd_ref, o_ref):
-    """Grid step ``i``: the ``i``-th expert that a row reached, its three
-    matrices in VMEM (the next one's in flight), on ALL the rows; a row
-    that did not pick it has weight 0.0 there and is left as it was."""
+                     wu_ref, wd_ref, o_ref, *, tiled):
+    """Grid step ``i`` (``(i, j)`` where the hidden width is ``tiled``):
+    the ``i``-th expert that a row reached, its three matrices (their
+    ``j``-th runs of the hidden width) in VMEM, the next ones in flight,
+    on ALL the rows; a row that did not pick it has weight 0.0 there and
+    is left as it was. A run's ``h`` needs no other run, and its down
+    product is added to the rows' result, which stays in VMEM from the
+    first step to the last."""
     i = pl.program_id(0)
+    first = i == 0
+    if tiled:
+        first &= pl.program_id(1) == 0
 
-    @pl.when(i == 0)
+    @pl.when(first)
     def _():
         o_ref[...] = jnp.zeros_like(o_ref)
 
@@ -215,25 +239,53 @@ def _few_rows_kernel(layer_ref, touched_ref, n_ref, x_ref, c_ref, wg_ref,
             h, wd_ref[...], preferred_element_type=jnp.float32)
 
 
-def moe_apply_few_rows(xt, idx, gates, w_gate, w_up, w_down, layer=None):
-    """``moe_apply_sorted`` for A FEW ROWS and every expert held (a decode
-    step: 64 rows x 8 picks over 256 experts are 2 rows an expert, and a
-    grouped matmul over 2-row groups reads their weights at a third of
-    the chip's bandwidth: PERF.md section 6, PR 55). No sort and no
-    gather: the experts that a row reached are visited in ascending
-    order, one a grid step, each multiplied with ALL the rows (at most
-    one MXU tile of them: the products cost what the copy of the expert's
-    6 MB costs) and added under the rows' own weights for it, 0.0 for a
-    row that did not pick it. An expert nobody picked is never copied. A
-    row's result is the float32 sum of its own experts' outputs in
-    ascending order of expert: it depends on no other row. Returns [T, D]
-    in xt's dtype."""
+def moe_apply_few_rows(xt, idx, gates, w_gate, w_up, w_down, layer=None,
+                       held=None):
+    """``moe_apply_sorted`` for A FEW ROWS (a decode step: 64 rows x 8
+    picks over 256 experts are 2 rows an expert, and a grouped matmul
+    over 2-row groups reads their weights at a third of the chip's
+    bandwidth: PERF.md section 6, PR 55). No sort and no gather: the
+    experts that a row reached are visited in ascending order, one a grid
+    step, each multiplied with ALL the rows (at most one MXU tile of
+    them: the products cost what the copy of the expert costs) and added
+    under the rows' own weights for it, 0.0 for a row that did not pick
+    it. An expert nobody picked is never copied. A row's result is the
+    float32 sum of its own experts' outputs in ascending order of expert:
+    it depends on no other row. Returns [T, D] in xt's dtype.
+
+    With ``held`` = (first, width) the weights are one chip's share of an
+    expert-parallel layer (``moe_apply_sorted``): the columns of the
+    rows' weights are the E experts from ``first`` on, a pick outside
+    them is no column, and what the absent experts would add is left out.
+    A row none of whose picks is held comes back as zeros; so does every
+    row of a step that reached no held expert, at the cost of the one
+    block the pipeline fetches before it looks.
+
+    An expert too wide for VMEM twice over goes ``_hidden_tile`` of its
+    hidden width a grid step, an inner axis of the grid: a decode step
+    has ONE row tile, so every run of every touched expert is copied
+    exactly once either way, and a run's gate and up blocks are rows of
+    ``tile * itemsize`` bytes of the stored matrices. Where the expert
+    fits uncut the grid is ``(E,)`` and the blocks whole, the kernel PR
+    55 measured. On the chip (PERF.md section 6, PR 61: 64 rows over 16
+    experts of 7,168 x 2,048, all reached, 1.72 ms at 819 GB/s): runs of
+    512 read 1.90 ms a call, 0.91 of the bandwidth, where the sort, the
+    three ``ragged_dot`` and the un-sort read 2.36, 0.73; runs of 256 and
+    128 read 1.97 and 1.90; the other cut, the CONTRACTION of gate and up
+    in contiguous ``[1024, f]`` blocks into float32 scratch and then the
+    down product in ``[512, d]`` blocks, 1.96 (1.93 at half those
+    blocks): strided rows of 1 KB cost the copy nothing, and this form
+    needs no scratch and no second phase. xing4's 3,584 x 1,024 whole
+    under a raised limit read 1.11 ms for 37 experts against the sorted
+    form's 1.29, and 1.20 cut in two."""
     t, d = xt.shape
     e, f = w_gate.shape[-3], w_gate.shape[-1]
     if layer is None:
         w_gate, w_up, w_down = (w[None] for w in (w_gate, w_up, w_down))
         layer = 0
-    # [T, E]: a row's weight for every expert
+    if held is not None:        # a pick of another chip's: no column
+        idx = idx - held[0]
+    # [T, E]: a row's weight for every expert held
     weights = jnp.sum(jax.nn.one_hot(idx, e, dtype=jnp.float32)
                       * gates[..., None].astype(jnp.float32), axis=1)
     reached = moe_load(idx, e) > 0
@@ -247,27 +299,45 @@ def moe_apply_few_rows(xt, idx, gates, w_gate, w_up, w_down, layer=None):
         xt = jnp.pad(xt, ((0, rows - t), (0, 0)))
         weights = jnp.pad(weights, ((0, rows - t), (0, 0)))
     by_expert = weights.T[touched][..., None]               # [E, rows, 1]
-    held = 6 * d * f * w_gate.dtype.itemsize \
+    tf = _hidden_tile(d, f, w_gate.dtype.itemsize)
+    tiled = tf != f
+    in_vmem = 6 * d * tf * w_gate.dtype.itemsize \
         + rows * d * (xt.dtype.itemsize + 8)
+    if tiled:
+        # behind the last expert reached: its last run again
+        last = f // tf - 1
 
-    def expert(i, lyr, tch, n):
-        return lyr[0], tch[i], 0, 0
+        def run(i, j, n):
+            return jnp.where(i < n[0], j, last)
+
+        def gate_up(i, j, lyr, tch, n):
+            return lyr[0], tch[i], 0, run(i, j, n)
+
+        def down(i, j, lyr, tch, n):
+            return lyr[0], tch[i], run(i, j, n), 0
+    else:
+        def gate_up(i, lyr, tch, n):
+            return lyr[0], tch[i], 0, 0
+
+        down = gate_up
 
     out = _pa._pcall(
-        _few_rows_kernel, name="moe_few_rows",
+        functools.partial(_few_rows_kernel, tiled=tiled),
+        name="moe_few_rows",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(e,),
+            num_scalar_prefetch=3,
+            grid=(e, f // tf) if tiled else (e,),
             in_specs=[
-                pl.BlockSpec((rows, d), lambda i, *_: (0, 0)),
+                pl.BlockSpec((rows, d), lambda *_: (0, 0)),
                 pl.BlockSpec((None, rows, 1), lambda i, *_: (i, 0, 0)),
-                pl.BlockSpec((None, None, d, f), expert),
-                pl.BlockSpec((None, None, d, f), expert),
-                pl.BlockSpec((None, None, f, d), expert)],
-            out_specs=pl.BlockSpec((rows, d), lambda i, *_: (0, 0))),
+                pl.BlockSpec((None, None, d, tf), gate_up),
+                pl.BlockSpec((None, None, d, tf), gate_up),
+                pl.BlockSpec((None, None, tf, d), down)],
+            out_specs=pl.BlockSpec((rows, d), lambda *_: (0, 0))),
         out_shape=jax.ShapeDtypeStruct((rows, d), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=held + 8 * 2 ** 20),
+            dimension_semantics=("arbitrary",) * (1 + tiled),
+            vmem_limit_bytes=in_vmem + 8 * 2 ** 20),
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), touched,
       jnp.reshape(n, (1,)), xt, by_expert, w_gate, w_up, w_down)
     return out[:t].astype(xt.dtype)
@@ -452,22 +522,25 @@ def moe_apply_sorted(xt, idx, gates, w_gate, w_up, w_down, layer=None,
 
     The CALL decides its form, as the paged attention calls do, from
     what it is given (the backend or the tests' interpreter hook, the
-    rows, ``held``, the experts' widths and type): a few rows over
-    experts all held and small enough go through the kernel
-    ``moe_apply_few_rows`` where ``few_rows_usable`` says so (a decode
-    step); more rows than that over such experts keep the sort, the
-    gather and the un-sort and put the sorted rows through the kernel
-    ``moe_grouped_rows`` in place of the three ``ragged_dot`` where
-    ``grouped_rows_usable`` says so (a prefill window); and this function
-    is the reference of both, on every CPU, for a share and for experts
-    too wide. No training path differentiates through it (a Pallas call
-    has no gradient here): ``moe_ffn`` trains through ``moe_apply``, and
-    the paged programs and ``llama_generate`` only infer."""
+    rows, ``held``, the experts' widths and type): a few rows go through
+    the kernel ``moe_apply_few_rows`` where ``few_rows_usable`` says so
+    (a decode step: a whole layer or a share of one, an expert taken in
+    runs of its hidden width where two of it pass the kernel's budget:
+    no sort, no gather and no un-sort there); more rows than that over
+    experts all held keep the sort, the gather and the un-sort and put
+    the sorted rows through the kernel ``moe_grouped_rows`` in place of
+    the three ``ragged_dot`` where ``grouped_rows_usable`` says so (a
+    prefill window); and this function is the reference of both, on
+    every CPU, and the form of a share's prefill window and of a window
+    over experts too wide. No training path differentiates through it (a
+    Pallas call has no gradient here): ``moe_ffn`` trains through
+    ``moe_apply``, and the paged programs and ``llama_generate`` only
+    infer."""
     t, k = idx.shape
     e = w_gate.shape[-3]
     if few_rows_usable(t, w_gate, w_down, held):
         return moe_apply_few_rows(xt, idx, gates, w_gate, w_up, w_down,
-                                  layer)
+                                  layer, held)
     local = idx
     if held is not None:
         first, width = held

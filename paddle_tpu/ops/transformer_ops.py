@@ -2440,6 +2440,26 @@ def state_step_in_kernel(attn_kinds, pool_specs):
         for spec in attn_kinds or ())
 
 
+def _experts_gate(param_tables, gate, rows):
+    """Whether any of the stacks ``param_tables`` (the programs' layer
+    parameters, slot -> (suffix, shape, dtype) a stack) holds routed
+    experts that ``gate`` (one of ops/moe.py's) admits at ``rows`` rows,
+    asked with what the call will be given: the stacks' shapes and types,
+    and ``held`` where the experts are a share of the router's width."""
+    def asks(table):
+        w_gate, w_down, router = (table.get(slot) for slot in (
+            "MoeWGate", "MoeWDown", "MoeRouter"))
+        if w_gate is None:
+            return False
+        w_gate, w_down = (jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dt))
+                          for _, shape, dt in (w_gate, w_down))
+        whole = w_gate.shape[-3] == router[1][-1]
+        return gate(rows, w_gate, w_down,
+                    None if whole else (0, router[1][-1]))
+
+    return any(asks(table) for table in param_tables)
+
+
 def prefill_experts_in_kernel(param_tables, t_len):
     """Whether a prefill program over a window of ``t_len`` tokens puts its
     routed layers' sorted pairs through the Pallas kernel
@@ -2451,19 +2471,18 @@ def prefill_experts_in_kernel(param_tables, t_len):
     held, the widths). ``param_tables``: the programs' layer parameters,
     slot -> (suffix, shape, dtype) a stack; whether any stack's does."""
     from . import moe
+    return _experts_gate(param_tables, moe.grouped_rows_usable, t_len)
 
-    def asks(table):
-        gate, down, router = (table.get(slot) for slot in (
-            "MoeWGate", "MoeWDown", "MoeRouter"))
-        if gate is None:
-            return False
-        w_gate, w_down = (jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dt))
-                          for _, shape, dt in (gate, down))
-        whole = w_gate.shape[-3] == router[1][-1]
-        return moe.grouped_rows_usable(
-            t_len, w_gate, w_down, None if whole else (0, router[1][-1]))
 
-    return any(asks(table) for table in param_tables)
+def decode_experts_in_kernel(param_tables, rows):
+    """Whether a decode program of ``rows`` rows puts its routed layers
+    through the Pallas kernel ``moe_few_rows``: the same REPORT for a
+    decode step (the decode bundle's ``experts_in_kernel``, the engine's
+    ``decode_experts_in_kernel_total``), asked of ``few_rows_usable`` as
+    ``moe_apply_sorted`` asks where it lowers (the backend, the rows, the
+    widths; a share is admitted as a whole layer is)."""
+    from . import moe
+    return _experts_gate(param_tables, moe.few_rows_usable, rows)
 
 
 def _pages_seen(n_pages, seen, page_size):

@@ -165,6 +165,14 @@ _DECODE_COUNTERS = (
     # more rows than the few-rows kernel takes, on a backend with the
     # kernel), to be read against the same sum
     "prefill_experts_in_kernel_total",
+    # and the decode step's: ticked beside decode_batches_total for every
+    # decode dispatch whose program puts its routed layers through the
+    # kernel moe_few_rows (the decode bundle's ``experts_in_kernel``: at
+    # most one MXU tile of rows over experts a tile of which fits the
+    # kernel's budget, a share or a whole layer, on a backend with the
+    # kernel): equal to decode_batches_total on the chip for agent, reason,
+    # mixed and docs, 0 on a CPU, without routed experts and at 256 rows
+    "decode_experts_in_kernel_total",
     # a model with window attention layers (PR 33) has caches of two
     # kinds, and counts on the device, over decode steps, the positions
     # its active rows attended in the layers of each (HYBRID_STATS:
@@ -2195,6 +2203,9 @@ class DecodeEngine:
                    state_step_in_kernel_total=int(
                        not use_spec and self.programs.decode.get(
                            "state_in_kernel", False)),
+                   decode_experts_in_kernel_total=int(
+                       not use_spec and self.programs.decode.get(
+                           "experts_in_kernel", False)),
                    decode_dispatch_s_total=dispatch.seconds,
                    cache_bytes_held_total=sum(
                        self._held_bytes(s) for _, s in active),

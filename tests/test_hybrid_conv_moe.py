@@ -595,8 +595,7 @@ def test_moe_route_with_the_models_divisor_is_the_references_rule():
 @pytest.mark.parametrize("layer", [None, 1])
 def test_a_wide_decode_steps_experts_go_through_the_grouped_kernel(
         monkeypatch, layer):
-    """More than ``FEW_ROWS`` rows over experts all held and too large for
-    the few-rows kernel (two of them over VMEM's default): the call puts
+    """More than ``FEW_ROWS`` rows over experts all held: the call puts
     the sorted pairs through ``moe_grouped_rows``, here in the interpreter,
     and gives what the three ``ragged_dot`` give."""
     rng = np.random.RandomState(4)
@@ -616,11 +615,13 @@ def test_a_wide_decode_steps_experts_go_through_the_grouped_kernel(
     monkeypatch.setattr(pa, "_FORCE_INTERPRET", True)
     assert moe.grouped_rows_usable(t, wg, wd)
     assert not moe.grouped_rows_usable(moe.FEW_ROWS, wg, wd)
-    # LFM2's expert: two of them are 37.7 MB, over the few-rows kernel's
-    # 16 and under the grouped kernel's 48
+    # LFM2's expert: two of them are 37.7 MB, under the grouped kernel's 48
+    # and, since PR 61, the few-rows kernel's at a step of 128 rows or
+    # fewer; the cell's 256 rows are the grouped kernel's
     big = jax.ShapeDtypeStruct((4, 64, 2048, 1536), jnp.bfloat16)
     down = jax.ShapeDtypeStruct((4, 64, 1536, 2048), jnp.bfloat16)
-    assert not moe.few_rows_usable(64, big, down)
+    assert moe.few_rows_usable(64, big, down)
+    assert not moe.few_rows_usable(256, big, down)
     assert moe.grouped_rows_usable(256, big, down)
     got = jax.jit(lambda *a: moe.moe_apply_sorted(*a, layer=layer))(
         x, idx, gates, wg, wu, wd)

@@ -427,7 +427,7 @@ def test_the_few_rows_kernel_is_the_sorted_form(t, layer, monkeypatch):
     want = moe.moe_apply_sorted(x, idx, gates, *w, layer=layer)
     monkeypatch.setattr(PA, "_FORCE_INTERPRET", True)
     assert moe.few_rows_usable(t, w[0], w[2])
-    assert not moe.few_rows_usable(t, w[0], w[2], held=(0, 8))
+    assert moe.few_rows_usable(t, w[0], w[2], held=(0, 8))
     assert not moe.few_rows_usable(moe.FEW_ROWS + 1, w[0], w[2])
     got = jax.jit(lambda *a: moe.moe_apply_sorted(*a, layer=layer))(
         x, idx, gates, *w)
@@ -437,3 +437,175 @@ def test_the_few_rows_kernel_is_the_sorted_form(t, layer, monkeypatch):
     alone = jax.jit(lambda *a: moe.moe_apply_sorted(*a, layer=layer))(
         x[:1], idx[:1], gates[:1], *w)
     assert np.array_equal(np.asarray(alone[0]), np.asarray(got[0]))
+
+
+# -- the same kernel for one chip's SHARE of a layer, and for an expert
+# -- taken a run of its hidden width at a time (PR 61) -----------------------
+
+# 8 experts held of a router 32 wide: (the first held, the rows, the
+# stack's layer, what the picks are drawn from: None is the whole width)
+SHARE_CASES = {
+    "first_0": dict(first=0, t=24),
+    "first_not_0": dict(first=16, t=24),
+    "the_last_run_of_the_router": dict(first=24, t=13),
+    "a_row_with_no_held_pick": dict(first=8, t=9, absent_rows=(0, 4),
+                                    picks=[8, 9, 11, 14]),
+    "an_expert_nobody_reached": dict(
+        first=8, t=40, picks=[3, 8, 9, 10, 12, 13, 14, 15, 20, 31]),
+    "no_held_expert_reached": dict(first=8, t=6, picks=[0, 1, 2, 16, 30]),
+    "stacked_with_a_traced_layer": dict(first=16, t=24, layer=2),
+}
+
+
+def _share_case(first, t, layer=None, picks=None, absent_rows=(), f=128):
+    from paddle_tpu.ops import moe
+    rng = np.random.RandomState(11)
+    e, width, d, k = 8, 32, 128, 3
+    x = jnp.asarray(rng.randn(t, d), jnp.float32)
+    shape = (3,) if layer is not None else ()
+    w = [jnp.asarray(0.1 * rng.randn(*shape, e, *dims), jnp.float32)
+         for dims in ((d, f), (d, f), (f, d))]
+    idx = np.stack([rng.choice(np.arange(width) if picks is None else picks,
+                               k, replace=False) for _ in range(t)])
+    for r in absent_rows:          # every pick of the row is another chip's
+        idx[r] = [(first + e + j) % width for j in range(k)]
+    gates = jnp.asarray(rng.rand(t, k), jnp.float32)
+    return moe, x, jnp.asarray(idx, jnp.int32), gates, w, (first, width)
+
+
+def _rows_agree(got, want, idx, held, e):
+    """The rows with a held pick agree to the order of the float32 sums,
+    the others are zeros in both; which rows have one."""
+    first = 0 if held is None else held[0]
+    live = np.asarray(((idx >= first) & (idx < first + e)).any(axis=1))
+    got, want = np.asarray(got), np.asarray(want)
+    if live.any():
+        assert rel_l2(got[live], want[live]).max() < 1e-5
+    assert not got[~live].any() and not want[~live].any()
+    return live
+
+
+@pytest.mark.parametrize("case", sorted(SHARE_CASES))
+def test_the_few_rows_kernel_for_a_share_is_the_sorted_form(case,
+                                                            monkeypatch):
+    """``held=(first, width)``: the columns of the rows' weights are the
+    experts held, a pick outside them is no column, and what the absent
+    experts would add is left out as the sorted form leaves it out."""
+    spec = dict(SHARE_CASES[case])
+    layer = spec.get("layer")
+    moe, x, idx, gates, w, held = _share_case(**spec)
+    e = w[0].shape[-3]
+    want = moe.moe_apply_sorted(x, idx, gates, *w, layer=layer, held=held)
+    load = np.asarray(moe.moe_load(idx, e, first=held[0]))
+    monkeypatch.setattr(PA, "_FORCE_INTERPRET", True)
+    assert moe.few_rows_usable(x.shape[0], w[0], w[2], held)
+
+    def through(*a):
+        return moe.moe_apply_sorted(*a[:-1], layer=a[-1], held=held)
+
+    args = (x, idx, gates, *w, None if layer is None else jnp.int32(layer))
+    jaxpr = str(jax.make_jaxpr(through)(*args))
+    # the one sort left is over the E experts held: which were reached
+    assert "moe_few_rows" in jaxpr and "ragged_dot" not in jaxpr
+    assert f"[{idx.size}]" not in jaxpr
+    got = jax.jit(through)(*args)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    live = _rows_agree(got, want, idx, held, e)
+    if case == "a_row_with_no_held_pick":
+        assert not live[0] and not live[4] and live.sum() == len(live) - 2
+    if case == "an_expert_nobody_reached":
+        assert (load == 0).sum() == 1 and load.sum() > 0
+    if case == "no_held_expert_reached":
+        assert load.sum() == 0 and not live.any()
+
+
+@pytest.mark.parametrize("t", [5, 21])
+@pytest.mark.parametrize("tiles", [2, 4])
+@pytest.mark.parametrize("share", [False, True], ids=["whole", "share"])
+def test_the_tiled_kernel_is_the_uncut_one(share, tiles, t, monkeypatch):
+    """An expert too wide for VMEM twice over goes a run of its hidden
+    width a grid step (``_hidden_tile``, read off the shapes against the
+    two budgets, which are shrunk here to cut a 512-wide expert in 2 and
+    in 4): a run's ``h`` needs no other run, so the result is the uncut
+    kernel's to the order of the float32 sums; rows not a multiple of 16,
+    a stacked layer, an expert nobody reached."""
+    f, d = 512, 128
+    moe, x, idx, gates, w, held = _share_case(
+        8, t, layer=1, f=f, picks=None if share else [8, 9, 10, 12, 13, 15])
+    if not share:
+        idx, held = idx - 8, None
+    monkeypatch.setattr(PA, "_FORCE_INTERPRET", True)
+
+    def through():
+        return jax.jit(lambda *a: moe.moe_apply_sorted(
+            *a, layer=1, held=held))(x, idx, gates, *w)
+
+    assert moe._hidden_tile(d, f, 4) == f
+    want = through()
+    monkeypatch.setattr(PA, "_VMEM_DEFAULT", 2 ** 20)
+    monkeypatch.setattr(moe, "GROUPED_VMEM", 6 * d * (f // tiles) * 4)
+    assert moe._hidden_tile(d, f, 4) == f // tiles
+    assert moe.few_rows_usable(t, w[0], w[2], held)
+    got = through()
+    assert _rows_agree(got, want, idx, held, 8).any()
+    monkeypatch.setattr(PA, "_FORCE_INTERPRET", False)
+    _rows_agree(got, moe.moe_apply_sorted(x, idx, gates, *w, layer=1,
+                                          held=held), idx, held, 8)
+
+
+def _abstract(shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _experts(e, d, f, dtype=jnp.bfloat16, down=None):
+    return (_abstract((e, d, f), dtype),
+            _abstract((e, f, d), dtype if down is None else down))
+
+
+# what the gate says: (rows, (w_gate, w_down), held, the hook, the run of
+# the hidden width a grid step or None where it refuses)
+GATE_CASES = {
+    "lagunas_experts_uncut": (64, _experts(256, 2048, 512), None, True,
+                              512),
+    "a_share_of_lagunas": (64, _experts(16, 2048, 512), (32, 256), True,
+                           512),
+    "deepseeks_share_512_a_step": (64, _experts(16, 7168, 2048), (0, 256),
+                                   True, 512),
+    "mimos_share_1024_a_step": (24, _experts(16, 4096, 2048), (16, 256),
+                                True, 1024),
+    "xing4s_experts_whole_under_a_raised_limit": (
+        16, _experts(64, 3584, 1024), None, True, 1024),
+    "exactly_128_rows_over_wide_experts": (
+        128, _experts(64, 2048, 1536), None, True, 1536),
+    "float32_experts": (8, _experts(8, 128, 128, jnp.float32), (0, 32),
+                        True, 128),
+    "one_row_more_than_a_tile": (129, _experts(256, 2048, 512), None, True,
+                                 None),
+    "a_cpu_without_the_hook": (64, _experts(256, 2048, 512), None, False,
+                               None),
+    "a_model_width_off_the_lane_tile": (64, _experts(8, 2000, 512), None,
+                                        True, None),
+    "a_hidden_width_off_the_lane_tile": (64, _experts(8, 2048, 500),
+                                         (0, 32), True, None),
+    "two_types": (64, _experts(8, 2048, 512, down=jnp.float32), None, True,
+                  None),
+    # 6 x 32,896 x 128 x 2 B: one lane tile of the hidden width is over
+    "no_tile_within_the_budget": (64, _experts(8, 32896, 512), None, True,
+                                  None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GATE_CASES))
+def test_the_few_rows_gate(case, monkeypatch):
+    """``few_rows_usable``: the backend, the rows, whole lane tiles, one
+    type, and a tile of an expert within the budget; ``held`` refuses
+    nothing."""
+    from paddle_tpu.ops import moe
+    assert moe.FEW_ROWS == 128
+    t, (w_gate, w_down), held, hook, tile = GATE_CASES[case]
+    monkeypatch.setattr(PA, "_FORCE_INTERPRET", hook)
+    assert moe.few_rows_usable(t, w_gate, w_down, held) is (tile is not None)
+    if tile is not None:
+        d, f = w_gate.shape[-2:]
+        assert moe._hidden_tile(d, f, w_gate.dtype.itemsize) == tile
+        assert not moe.grouped_rows_usable(t, w_gate, w_down, held)

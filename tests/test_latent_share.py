@@ -710,3 +710,78 @@ def test_an_engine_decodes_in_place_where_the_kernel_runs(hook,
     import decode_forms
     decode_forms.check_an_engines_tokens_and_its_counter(
         make_engine, CFG, reference_logits, monkeypatch, hook, PS)
+
+
+# -- a share's decode step through the few-rows kernel (PR 61) ---------------
+
+# the share's mechanisms at a model width and a hidden width of whole lane
+# tiles: where ``few_rows_usable`` admits the kernel
+WIDE = replace(CFG, name="latent-share-wide-experts", dim=128,
+               expert_hidden=128)
+
+
+def _decoded_by_a_wide_share(monkeypatch, hook, sorted_experts=False):
+    """Four requests through an engine of ``WIDE`` (one alone, three
+    co-scheduled), built with the Pallas interpreter behind the calls or
+    not (``hook``) and the experts' gate as it is or refusing
+    (``sorted_experts``): (their tokens, the pool, the counters, what the
+    decode bundle says of its experts)."""
+    import decode_forms
+    scope = fluid.Scope()
+    for name, value in make_weights(WIDE, 1).items():
+        scope.set(name, value)
+    with monkeypatch.context() as m:
+        if hook:
+            decode_forms.kernel_on(m, PS)
+        if sorted_experts:
+            m.setattr(moe, "few_rows_usable", lambda *a, **k: False)
+        engine = DecodeEngine(WIDE, scope=scope, config=DecodeConfig(
+            max_batch=3, prompt_buckets=(8, 32), max_new_tokens=8,
+            page_size=PS, decode_block=2, prefill_batch=1))
+        try:
+            said = engine.programs.decode["experts_in_kernel"]
+            engine.warmup()
+            rng = np.random.RandomState(23)
+            prompts = [rng.randint(0, WIDE.vocab_size, n)
+                       for n in (5, 21, 13, 8)]
+            tokens = [engine.generate(prompts[0], max_new=6)] + [
+                h.result(120) for h in [engine.submit(p, max_new=6)
+                                        for p in prompts[1:]]]
+            engine.assert_no_recompiles()
+            stats = engine.stats()
+        finally:
+            engine.close()
+        pool, = engine._pools
+        return tokens, np.asarray(pool), stats, said
+
+
+@pytest.mark.parametrize("hook", [False, True], ids=["off", "on"])
+def test_a_shares_engine_decodes_through_the_few_rows_kernel(hook,
+                                                             monkeypatch):
+    """Where the kernel runs, every decode dispatch of a share's engine
+    puts its routed layers through ``moe_few_rows``
+    (``decode_experts_in_kernel_total == decode_batches_total``) and
+    decodes the tokens and leaves the pool that the sorted form does
+    (float32: the same sums in another order); on a CPU without the hook
+    the counter stays 0."""
+    tokens, pool, stats, said = _decoded_by_a_wide_share(monkeypatch, hook)
+    assert said is hook
+    assert stats["decode_batches_total"] > 0
+    assert stats["decode_experts_in_kernel_total"] == (
+        stats["decode_batches_total"] if hook else 0)
+    assert stats["pools_lost_total"] == 0
+    assert 0 < stats["moe_held_assignments_total"] \
+        < stats["moe_assignments_total"]
+    if not hook:
+        return
+    want, want_pool, sorted_stats, sorted_said = _decoded_by_a_wide_share(
+        monkeypatch, True, sorted_experts=True)
+    assert not sorted_said
+    assert sorted_stats["decode_experts_in_kernel_total"] == 0
+    assert sorted_stats["decode_batches_total"] \
+        == stats["decode_batches_total"]
+    for a, b in zip(tokens, want):
+        assert np.array_equal(a, b)
+    np.testing.assert_allclose(pool[:, 1:], want_pool[:, 1:],
+                               rtol=REL_L2_F32, atol=REL_L2_F32)
+    assert np.abs(want_pool[:, 1:]).max() > 0

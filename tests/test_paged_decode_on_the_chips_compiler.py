@@ -217,7 +217,7 @@ def test_a_mixed_decode_program_holds_no_view_of_its_sequence_kind(
     ([rows, kv heads, kmax, width] a layer, or as gathered) anywhere in
     the module: 2.15 GB of MiMo's 3.56 GB of temporaries, 0.95 of Jamba's
     1.07 (PERF.md section 6, PR 42)."""
-    _, programs = mixed
+    cfg, programs = mixed
     assert programs.decode["in_place"]
     compiled = program_text.lower_bundle(
         programs.decode, len(programs.pool_specs),
@@ -226,6 +226,13 @@ def test_a_mixed_decode_program_holds_no_view_of_its_sequence_kind(
     (k_shape, _), (v_shape, _) = programs.pool_specs[:2]
     assert len(re.findall(r"tpu_custom_call.*paged_flat_decode", text)) \
         == k_shape[0] == 2
+    # the routed layers' experts a decode step: ONE few-rows kernel a layer
+    # body and no grouped product, for MiMo's share as for Laguna's whole
+    # layers (PR 61); Jamba has no routed experts
+    calls = len(re.findall(r"tpu_custom_call.*moe_few_rows", text))
+    assert calls == {24: 3, 128: 0, 64: 2}[programs.max_batch]
+    assert programs.decode["experts_in_kernel"] is bool(calls)
+    assert "ragged" not in text
     _assert_held_uncopied(text, programs.pool_specs)
     rows = programs.max_batch
     kmax = programs.pages_per_seq * programs.page_size
@@ -625,6 +632,12 @@ def test_a_latent_decode_program_holds_no_view(one_chip, latent):
                                          sharding=one_chip).compile()
     text = compiled.as_text()
     assert len(re.findall(r"tpu_custom_call.*paged_latent_decode", text)) == 2
+    # and ONE few-rows kernel, the routed layers' scan's: reason's share
+    # (64 rows, 88 MB an expert in runs of 512) and docs' 64 experts whole
+    # (16 rows) leave no grouped product in a decode step (PR 61)
+    assert programs.decode["experts_in_kernel"]
+    assert len(re.findall(r"tpu_custom_call.*moe_few_rows", text)) == 1
+    assert "ragged" not in text
     _assert_held_uncopied(text, programs.pool_specs)
     views = re.findall(rf"\w+\[(?:\d+,)*(?:{kmax}|"
                        rf"{programs.pages_per_seq},{programs.page_size})"
@@ -661,12 +674,18 @@ def test_a_latent_decode_program_holds_no_view(one_chip, latent):
 # f5b2bb1bc7704ea6, 983ff2884a43e182, e1c6f34ad4c863c6, da2370972a93574b).
 # Then a step's bfloat16 logits are rounded in so many words before their
 # argmax (one ``reduce_precision`` in ``_paged_decode``, in both forms, so
-# that both break ties alike on the chip: PERF.md section 6): the values below
-OTHERS_PINNED = {"mimo": ("709778c50a5d8e3e", "cd3c6d2fbdbf9b5c"),
+# that both break ties alike on the chip: PERF.md section 6; mimo
+# 709778c50a5d8e3e | cd3c6d2fbdbf9b5c, xing4 5eba9bd50298ccc5 |
+# d847159f6ff1abbf, deepseek 1fce1d82787bf467 | bc5a4bc7c4822632). PR 61
+# put the decode step's routed experts of those three through the kernel
+# ``moe_few_rows`` (a share's, and experts wider than VMEM's default twice
+# over): their sort, three ``ragged_dot`` and un-sort left the text, the
+# other three kept theirs: the values below
+OTHERS_PINNED = {"mimo": ("08727de06028a35d", "472ac0e9409ef1ab"),
                  "jamba": ("f93ae67235d28a87", "62afaf6af85d580f"),
                  "ouro": ("bec3361482f58ada", "ce139c92c00c35fb"),
-                 "xing4": ("5eba9bd50298ccc5", "d847159f6ff1abbf"),
-                 "deepseek": ("1fce1d82787bf467", "bc5a4bc7c4822632"),
+                 "xing4": ("fe1152bd40131699", "24ba454eed626407"),
+                 "deepseek": ("86cfcb5f7edc1737", "502a46a8d63a9ea6"),
                  "olmo": ("ec1662f42fb73b82", "7602b73ab9aba4af")}
 
 
@@ -688,13 +707,26 @@ def test_the_other_decode_programs_are_what_the_chip_was_asked_before(
     assert got == OTHERS_PINNED[model][form == "serving"], (model, got)
 
 
+def _few_rows_call(jaxpr):
+    """(the grid, each operand's block sizes) of the ONE ``moe_few_rows``
+    call in ``jaxpr``'s text."""
+    assert len(re.findall(r"name=moe_few_rows", jaxpr)) == 1
+    grid, = re.findall(r"GridMapping\(grid=\(([\d, ]*)\)", jaxpr)
+    blocks = [tuple(int(n) for n in re.findall(r"block_size=(\d+)", b))
+              for b in re.findall(r"BlockMapping\(block_shape=\((.*?)\)\)",
+                                  jaxpr)]
+    return tuple(int(n) for n in re.findall(r"\d+", grid)), blocks
+
+
 def test_the_few_rows_kernel_compiles_at_lagunas_experts(one_chip,
                                                          monkeypatch):
     """A decode step's experts at Laguna-XS.2's window stack (3 layers x
     256 experts of 2,048 x 512, 64 rows x 8 picks): ONE custom call, the
     expert stacks taken as they are stored (a block is one expert's
-    matrix), nothing of a stack's size beside them. xing4's experts
-    (3,584 x 1,024: 44 MB twice over) and a share's are not the kernel's."""
+    matrix), nothing of a stack's size beside them, and the call PR 55
+    measured: a grid of the 256 experts, whole ``[2048, 512]`` blocks. A
+    share of such a layer and xing4's experts (3,584 x 1,024: 44 MB twice
+    over, whole under a raised limit) are the kernel's too since PR 61."""
     from paddle_tpu.ops import moe
     monkeypatch.setattr(pa, "_use_pallas", lambda: True)
 
@@ -704,20 +736,82 @@ def test_the_few_rows_kernel_compiles_at_lagunas_experts(one_chip,
     up, down = abstract((3, 256, 2048, 512)), abstract((3, 256, 512, 2048))
     assert moe.few_rows_usable(64, up, down)
     assert not moe.few_rows_usable(2048, up, down)
-    assert not moe.few_rows_usable(64, up, down, held=(0, 256))
-    assert not moe.few_rows_usable(16, abstract((5, 64, 3584, 1024)),
-                                   abstract((5, 64, 1024, 3584)))
-    compiled = jax.jit(
-        lambda x, idx, gates, wg, wu, wd, layer: moe.moe_apply_sorted(
-            x, idx, gates, wg, wu, wd, layer=layer)).lower(
-        abstract((64, 2048)), abstract((64, 8), jnp.int32),
-        abstract((64, 8), jnp.float32), up, up, down,
-        abstract((), jnp.int32)).compile()
+    assert moe.few_rows_usable(64, up, down, held=(0, 256))
+    assert moe.few_rows_usable(16, abstract((5, 64, 3584, 1024)),
+                               abstract((5, 64, 1024, 3584)))
+
+    def step(x, idx, gates, wg, wu, wd, layer):
+        return moe.moe_apply_sorted(x, idx, gates, wg, wu, wd, layer=layer)
+
+    args = (abstract((64, 2048)), abstract((64, 8), jnp.int32),
+            abstract((64, 8), jnp.float32), up, up, down,
+            abstract((), jnp.int32))
+    assert _few_rows_call(str(jax.make_jaxpr(step)(*args))) == (
+        (256,), [(64, 2048), (64, 1), (2048, 512), (2048, 512),
+                 (512, 2048), (64, 2048)])
+    compiled = jax.jit(step).lower(*args).compile()
     text = compiled.as_text()
     assert len(re.findall(r"tpu_custom_call.*moe_few_rows", text)) == 1
     assert "ragged" not in text
     _assert_held_uncopied(text, [([3, 256, 2048, 512], "bfloat16"),
                                  ([3, 256, 512, 2048], "bfloat16")])
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e6
+
+
+# a decode step's held experts at the three cells PR 61 admitted: (rows,
+# picks, the stack [layers, experts held, model width, hidden width],
+# ``held``, the grid, the gate and up block, the down block)
+FEW_ROWS_CELLS = {
+    # DeepSeek-V3's share: 88 MB an expert, 512 of its hidden width a step
+    "reason": (64, 8, (4, 16, 7168, 2048), (0, 256), (16, 4),
+               (7168, 512), (512, 7168)),
+    # MiMo-V2-Flash's share: 50 MB an expert, halves of 1,024
+    "mixed": (24, 8, (4, 16, 4096, 2048), (16, 256), (16, 2),
+              (4096, 1024), (1024, 4096)),
+    # xing4's, all held: 22 MB an expert, whole under a raised limit
+    "docs": (16, 4, (5, 64, 3584, 1024), None, (64,),
+             (3584, 1024), (1024, 3584)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(FEW_ROWS_CELLS))
+def test_the_few_rows_kernel_compiles_at_a_share_and_at_wide_experts(
+        one_chip, cell, monkeypatch):
+    """Mosaic takes the kernel at the published widths: ONE custom call, no
+    ``ragged_dot``, the stacks as they are stored, an expert cut where two
+    of it pass ``GROUPED_VMEM`` (an inner grid axis over runs of the hidden
+    width: the tile is read off the shapes), and nothing of a stack's size
+    beside the arguments."""
+    from paddle_tpu.ops import moe
+    monkeypatch.setattr(pa, "_use_pallas", lambda: True)
+    rows, picks, (layers, e, d, f), held, grid, gate_up, down_block = \
+        FEW_ROWS_CELLS[cell]
+
+    def abstract(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    up, down = abstract((layers, e, d, f)), abstract((layers, e, f, d))
+    assert moe.few_rows_usable(rows, up, down, held)
+    assert not moe.few_rows_usable(moe.FEW_ROWS + 1, up, down, held)
+
+    def step(x, idx, gates, wg, wu, wd, layer):
+        return moe.moe_apply_sorted(x, idx, gates, wg, wu, wd, layer=layer,
+                                    held=held)
+
+    args = (abstract((rows, d)), abstract((rows, picks), jnp.int32),
+            abstract((rows, picks), jnp.float32), up, up, down,
+            abstract((), jnp.int32))
+    padded = -(-rows // 16) * 16
+    assert _few_rows_call(str(jax.make_jaxpr(step)(*args))) == (
+        grid, [(padded, d), (padded, 1), gate_up, gate_up, down_block,
+               (padded, d)])
+    compiled = jax.jit(step).lower(*args).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"tpu_custom_call.*moe_few_rows", text)) == 1
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "ragged" not in text
+    _assert_held_uncopied(text, [([layers, e, d, f], "bfloat16"),
+                                 ([layers, e, f, d], "bfloat16")])
     assert compiled.memory_analysis().temp_size_in_bytes < 4e6
 
 
@@ -730,12 +824,12 @@ def test_the_grouped_rows_kernel_compiles_at_lagunas_window(one_chip,
     arguments than the sorted form holds (both peak at the float32
     ``[16384, 2048]`` result and its un-sorted copy, 268 MB). A decode
     step's 64 rows still compile to
-    ``moe_few_rows`` and a share to ``ragged_dot``; xing4's window (2,048
-    tokens x 4 picks over 64 experts of 3,584 x 1,024, 44 MB twice over)
-    compiles to the kernel too, whole experts under a raised VMEM limit:
-    the gate's budget admits it since the probe read it at half the three
-    ``ragged_dot``'s time (PERF.md section 6, PR 56), and its decode step
-    (16 rows) keeps ``ragged_dot``."""
+    ``moe_few_rows`` and a share's WINDOW to ``ragged_dot``; xing4's
+    window (2,048 tokens x 4 picks over 64 experts of 3,584 x 1,024, 44 MB
+    twice over) compiles to the kernel too, whole experts under a raised
+    VMEM limit: the gate's budget admits it since the probe read it at
+    half the three ``ragged_dot``'s time (PERF.md section 6, PR 56), and
+    its decode step (16 rows) is the few-rows kernel's since PR 61."""
     from paddle_tpu.ops import moe
     monkeypatch.setattr(pa, "_use_pallas", lambda: True)
 
@@ -782,7 +876,8 @@ def test_the_grouped_rows_kernel_compiles_at_lagunas_window(one_chip,
     _assert_held_uncopied(docs, [([5, 64, 3584, 1024], "bfloat16"),
                                  ([5, 64, 1024, 3584], "bfloat16")])
     docs_step = compiled(16, 4, *xing4).as_text()
-    assert "ragged" in docs_step and not re.findall(grouped, docs_step)
+    assert "ragged" not in docs_step and not re.findall(grouped, docs_step)
+    assert len(re.findall(r"tpu_custom_call.*moe_few_rows", docs_step)) == 1
 
 
 # -- a model of gated short convolutions (models/hybrid_conv_moe.py) -------
@@ -833,7 +928,7 @@ def test_lfm2s_programs_hold_their_kernels_and_copy_no_page(
     kernel (one attention layer of five) and the routed layers' sorted
     pairs through ``moe_grouped_rows`` (the attention layer's by its own
     number, the conv layers' inside their scan: two instances), no
-    ``ragged_dot`` and no few-rows kernel (two experts are 37.7 MB); the
+    ``ragged_dot`` and no few-rows kernel (256 rows are two MXU tiles); the
     prefill programs the same two instances of the grouped kernel and
     their attention in plain XLA. The pages are aliased from the donated
     inputs and never copied; the experts' stacks are taken as stored."""
@@ -862,6 +957,25 @@ def test_lfm2s_programs_hold_their_kernels_and_copy_no_page(
 
 
 # -- the two forms of a block-kind model's decode program (PR 59) ----------
+
+def test_lfm2s_128_token_bucket_goes_through_the_few_rows_kernel(
+        one_chip, lfm2, monkeypatch):
+    """The one window of 128 rows or fewer that a cell's programs hold:
+    LFM2's smallest whole-prompt bucket. Its 512 pairs over 64 experts of
+    2,048 x 1,536 (37.7 MB twice over: whole under a raised limit) were
+    three ``ragged_dot`` a routed layer until PR 61, the boundary both
+    gates shared; they are the few-rows kernel's now, an instance a layer
+    body, and the grouped kernel keeps every larger bucket."""
+    monkeypatch.setattr(pa, "_use_pallas", lambda: True)
+    _, programs = lfm2
+    assert min(programs.prefill) == 128
+    assert not programs.prefill[128]["experts_in_kernel"]   # the grouped one
+    text = program_text.lower_bundle(
+        program_text.bundles_of(programs)["prefill_128"],
+        len(programs.pool_specs), sharding=one_chip).compile().as_text()
+    assert len(re.findall(r"tpu_custom_call.*moe_few_rows", text)) == 2
+    assert "ragged" not in text and "moe_grouped_rows" not in text
+
 
 def _step_logits(text, steps, rows, vocab):
     """(the float32 arrays of ``text`` whose dimensions are steps, rows
