@@ -6,10 +6,26 @@ selective state-space mixer.
 A head keeps a MATRIX ``S`` [dk, dv]. For a position's query and key
 ``q_t``, ``k_t`` [dk] (L2-normed a head, the query times ``dk^-0.5``), its
 value ``v_t`` [dv], a decay ``alpha_t = exp(g_t)`` in (0, 1) and a write
-strength ``beta_t`` in (0, BETA_MAX), each a scalar a head::
+strength ``beta_t`` in (0, ``beta_max``), each a scalar a head::
 
     S_t = alpha_t S_{t-1} + beta_t k_t (v_t - alpha_t S_{t-1}^T k_t)^T
     o_t = S_t^T q_t
+
+WHAT A KIND OF DELTA-RULE LAYER CARRIES beyond its sizes is three data,
+keywords of ``window`` / ``step`` (a model's ``attn_kinds`` dict hands them
+over as its ``rule``; the defaults are Gated Delta Networks' and give
+Olmo-Hybrid's programs letter for letter): ``beta_max``, the write
+strength's ceiling (2 lets ``1 - beta`` reach (-1, 1), a negative eigenvalue
+of the state's transition, ``linear_allow_neg_eigval``; 1 is a plain
+sigmoid); ``floor``, the gate's form (None: ``g = -exp(A_log) softplus(a +
+dt_bias)``, unbounded below; a number < 0: the lower-bound gate ``g = floor
+* sigmoid(exp(A_log) (a + dt_bias))`` in (floor, 0), Kimi Delta Attention's
+safe gate, arXiv:2510.26692); ``scope``, the name its spans go under. And
+the decay is A HEAD's or A CHANNEL's, read off the width of what the mixer
+is given: with ``a`` [H dk] wide ``alpha_t`` is a vector over the key
+channels and scales the state's ROWS, ``S_t = (I - beta k k^T) diag(alpha_t)
+S_{t-1} + beta k v^T`` (the same update with ``alpha_t S`` read as
+``diag(alpha_t) S``).
 
 The update is NOT diagonal: the correction term READS the state (``S^T
 k``), so a prompt cannot be scanned channel by channel. ``chunk_rule``
@@ -28,18 +44,31 @@ before the loop that carries ``S``, which has one step a chunk and holds
 four products. Every exponent is <= 0. A position at or past ``lens`` gets
 ``beta = 0``, ``g = 0``: it writes nothing and decays nothing.
 
-What the mixer is given is ``z`` [..., C + 2H]: the C = H (2 dk + dv)
+With a decay a CHANNEL the mask sits inside the contraction, ``sum_c x_i[c]
+exp(gamma_i[c] - gamma_j[c]) k_j[c]``, and the factored form ``(x_i
+exp(gamma_i)) . (k_j exp(-gamma_j))`` raises ``exp`` to a positive power
+(64 positions at -5 each: ``exp(320)``). ``_channel_terms`` cuts a chunk
+into RUNS of ``RUN`` positions and takes the decays relative to the runs'
+ends: a pair inside one run elementwise over the channels (``exp(gamma_i -
+gamma_j)``, j <= i), a pair of two runs I > J as ``(x_i exp(gamma_i -
+start_I)) . (k_j exp(end_J - gamma_j)) exp(start_I - end_J)``, three
+factors with exponents <= 0 BY CONSTRUCTION, whatever the gate's floor: no
+``exp`` of a positive number is taken anywhere in this module. The same
+``(I + A)^-1``, loop and four products follow.
+
+What the mixer is given is ``z`` [..., C + A + H]: the C = H (2 dk + dv)
 channels ``[q | k | v]`` before their convolution (ops/ssm.py's, causal,
-depthwise, without a bias, then SiLU) and behind them the H decay and the H
-write projections ``[a | b]``, which are not convolved. What a sequence
-carries from one call to the next is ``S`` [H, dk, dv] float32 (whatever the
-model's type) and the TAIL, the convolution's last ``k - 1`` inputs [k - 1,
-C]: ONE entry a sequence, as ops/ssm.py's, and as little protected by any
-length mask.
+depthwise, without a bias, then SiLU) and behind them the decay's (A = H
+wide, or H dk: a channel's) and the H write projections ``[a | b]``, which
+are not convolved. What a sequence carries from one call to the next is
+``S`` [H, dk, dv] float32 (whatever the model's type) and the TAIL, the
+convolution's last ``k - 1`` inputs [k - 1, C]: ONE entry a sequence, as
+ops/ssm.py's, and as little protected by any length mask.
 
 Parameters ``p`` of one layer, by slot: ``ConvW`` [k, C], ``ALog`` [H] and
-``DtBias`` [H] float32, ``GNorm`` [dv] (the mixer's output norm: the sizes
-are read off these: H from ``ALog``, dv from ``GNorm``, dk from C).
+``DtBias`` [H] (a decay a channel: [H dk]) float32, ``GNorm`` [dv] (the
+mixer's output norm: the sizes are read off these: H from ``ALog``, dv
+from ``GNorm``, dk from C).
 """
 import jax
 import jax.numpy as jnp
@@ -47,15 +76,17 @@ import jax.numpy as jnp
 from . import ssm
 
 __all__ = ["window", "step", "step_in_kernel", "gates", "chunk_rule",
-           "rule_step", "CHUNK", "BETA_MAX"]
+           "rule_step", "CHUNK", "RUN", "BETA_MAX"]
 
 _F32 = jnp.float32
 _HI = jax.lax.Precision.HIGHEST
 
 CHUNK = 64          # positions a chunk of the rule: one triangular system
-# the write strength is ``BETA_MAX * sigmoid(.)``: 2 lets ``1 - beta`` reach
-# (-1, 1), a negative eigenvalue of the state's transition
-# (``linear_allow_neg_eigval``); a model published without it is not built
+RUN = 16            # positions a run of a chunk whose decay is a channel's:
+                    # pairs inside one are taken channel by channel
+# the write strength is ``beta_max * sigmoid(.)``, and this the ceiling of a
+# kind that names none: 2 lets ``1 - beta`` reach (-1, 1), a negative
+# eigenvalue of the state's transition (``linear_allow_neg_eigval``)
 BETA_MAX = 2.0
 L2_EPS = 1e-6
 
@@ -71,14 +102,30 @@ def _l2(x):
                              + L2_EPS)
 
 
-def gates(p, ab):
+def gates(p, ab, beta_max=None, floor=None):
     """The log decay and the write strength of every position and head,
-    float32: ab [..., 2H] (``[a | b]``) -> (g [..., H] <= 0, beta [...,
-    H])."""
-    a, b = jnp.split(ab.astype(_F32), 2, axis=-1)
-    g = -jnp.exp(p["ALog"].astype(_F32)) \
-        * jax.nn.softplus(a + p["DtBias"].astype(_F32))
-    return g, BETA_MAX * jax.nn.sigmoid(b)
+    float32: ab [..., A + H] (``[a | b]``) -> (g <= 0, beta [..., H] in (0,
+    ``beta_max``)). A = H: a decay a head, g [..., H]; A = H dk: a decay a
+    channel, g [..., H, dk]. ``floor`` None: ``g = -exp(A_log) softplus(a +
+    dt_bias)``; a number: the lower-bound gate, ``g = floor * sigmoid(exp(
+    A_log) (a + dt_bias))`` in (floor, 0). ``beta_max`` None: BETA_MAX."""
+    h = p["ALog"].shape[-1]
+    ab = ab.astype(_F32)
+    rate, bias = (lambda: jnp.exp(p["ALog"].astype(_F32))), \
+        (lambda: p["DtBias"].astype(_F32))
+    if ab.shape[-1] == 2 * h:
+        a, b = jnp.split(ab, 2, axis=-1)
+        x = lambda: a + bias()
+    else:
+        a, b, head = ab[..., :-h], ab[..., -h:], rate
+        rate = lambda: head()[..., None]
+        x = lambda: (a + bias()).reshape(a.shape[:-1] + (h, -1))
+    # taken where the expression reads them: a decay a head with no floor is
+    # the program it was, operation for operation
+    g = -rate() * jax.nn.softplus(x()) if floor is None \
+        else floor * jax.nn.sigmoid(rate() * x())
+    return g, (BETA_MAX if beta_max is None else beta_max) \
+        * jax.nn.sigmoid(b)
 
 
 def _heads(p, c):
@@ -118,22 +165,12 @@ def _unit_lower_inverse(a):
     return inv[..., 0, :, :]
 
 
-def chunk_rule(q, k, v, g, beta, state0, chunk=CHUNK):
-    """The rule over a window, ``chunk`` positions at a time (a power of
-    two; a window it does not divide is padded with positions that move
-    nothing). q, k [B, T, H, dk]; v [B, T, H, dv]; g, beta [B, T, H]
-    (``beta = 0, g = 0`` at padding); state0 [B, H, dk, dv]; all float32
-    -> (o [B, T, H, dv], the state after the last position)."""
-    b, t, h, dk = q.shape
-    n = -(-t // chunk)
-
-    def chunks(x):      # [B, T, H, ...] -> [n, B, H, chunk, ...]
-        x = jnp.pad(x, [(0, 0), (0, n * chunk - t)]
-                    + [(0, 0)] * (x.ndim - 2))
-        x = x.reshape((b, n, chunk) + x.shape[2:])
-        return jnp.moveaxis(jnp.moveaxis(x, 3, 1), 2, 0)
-
-    q, k, v, g, beta = (chunks(x) for x in (q, k, v, g, beta))
+def _head_terms(q, k, g, beta):
+    """What a chunk's loop is given, with a decay a head: q, k [..., C,
+    dk]; g, beta [..., C] -> (k_in, q_in [..., C, dk], ``(I + A)^-1
+    diag(beta)`` and the masked ``Q K^T`` [..., C, C], k_out [..., C, dk],
+    the chunk's whole decay [..., 1, 1])."""
+    chunk = g.shape[-1]
     gamma = jnp.cumsum(g, axis=-1)                       # [n, B, H, C]
     diff = gamma[..., :, None] - gamma[..., None, :]     # gamma_i - gamma_j
     i, j = jnp.arange(chunk)[:, None], jnp.arange(chunk)[None]
@@ -147,6 +184,77 @@ def chunk_rule(q, k, v, g, beta, state0, chunk=CHUNK):
     k_in, q_in = decay * k, decay * q
     k_out = jnp.exp(gamma[..., -1:] - gamma)[..., None] * k
     last = jnp.exp(gamma[..., -1])[..., None, None]
+    return k_in, q_in, solve, qk, k_out, last
+
+
+def _channel_terms(q, k, g, beta, run=RUN):
+    """The same with a decay a CHANNEL, g [..., C, dk] (the chunk's whole
+    decay [..., dk, 1]: the state's rows): the chunk in runs of ``run``
+    positions, every exponent <= 0 by construction (the module's
+    docstring)."""
+    lead, (c, dk) = g.shape[:-2], g.shape[-2:]
+    m = c // run
+    runs = lambda x: x.reshape(lead + (m, run, dk))
+    # a difference of two sums of gates is <= 0 but for their rounding:
+    # held to it, so that no exponent is positive whatever the sums' order
+    decay = lambda x: jnp.exp(jnp.minimum(x, 0.0))
+    gl = jnp.cumsum(runs(g), axis=-2)       # from its run's start, <= 0
+    end = jnp.cumsum(gl[..., -1, :], axis=-2)       # [.., m, dk] a run's end
+    start = end - gl[..., -1, :]                    # and its start
+    i, j = jnp.arange(run)[:, None], jnp.arange(run)[None]
+    inside = decay(jnp.where(
+        (j <= i)[..., None], gl[..., :, None, :] - gl[..., None, :, :],
+        -jnp.inf))                                  # [.., m, run, run, dk]
+    big, small = jnp.arange(m)[:, None], jnp.arange(m)[None]
+    between = decay(jnp.where(
+        (small < big)[..., None],
+        start[..., :, None, :] - end[..., None, :, :], -jnp.inf))
+    kr = runs(k)
+    k_end = kr * decay(gl[..., -1:, :] - gl)        # at its run's end
+
+    def pairs(x):
+        """sum_c x_i[c] exp(gamma_i[c] - gamma_j[c]) k_j[c], j <= i."""
+        xr = runs(x)
+        same = jnp.sum(xr[..., :, None, :] * kr[..., None, :, :] * inside,
+                       axis=-1)                     # [.., m, run, run]
+        x_in = (xr * decay(gl))[..., :, :, None, :] \
+            * between[..., :, None, :, :]           # [.., m, run, m, dk]
+        cross = jnp.einsum("...IiJc,...Jjc->...IiJj", x_in, k_end,
+                           preferred_element_type=_F32)
+        on = (big == small)[:, None, :, None]
+        return (cross + jnp.where(on, same[..., :, :, None, :], 0.0)
+                ).reshape(lead + (c, c))
+
+    i, j = jnp.arange(c)[:, None], jnp.arange(c)[None]
+    a = jnp.where(j < i, pairs(k), 0.0) * beta[..., None]
+    solve = _unit_lower_inverse(a) * beta[..., None, :]
+    gamma = (gl + start[..., None, :]).reshape(g.shape)
+    since = decay(gamma)                    # from the chunk's start
+    return (since * k, since * q, solve, pairs(q),
+            decay(gamma[..., -1:, :] - gamma) * k,
+            decay(gamma[..., -1, :])[..., None])
+
+
+def chunk_rule(q, k, v, g, beta, state0, chunk=CHUNK):
+    """The rule over a window, ``chunk`` positions at a time (a power of
+    two; a window it does not divide is padded with positions that move
+    nothing). q, k [B, T, H, dk]; v [B, T, H, dv]; beta [B, T, H] and g
+    [B, T, H] (a decay a head) or [B, T, H, dk] (a channel) (``beta = 0, g =
+    0`` at padding); state0 [B, H, dk, dv]; all float32 -> (o [B, T, H,
+    dv], the state after the last position)."""
+    b, t, h, dk = q.shape
+    n = -(-t // chunk)
+
+    def chunks(x):      # [B, T, H, ...] -> [n, B, H, chunk, ...]
+        x = jnp.pad(x, [(0, 0), (0, n * chunk - t)]
+                    + [(0, 0)] * (x.ndim - 2))
+        x = x.reshape((b, n, chunk) + x.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(x, 3, 1), 2, 0)
+
+    q, k, v, g, beta = (chunks(x) for x in (q, k, v, g, beta))
+    k_in, q_in, solve, qk, k_out, last = (
+        _head_terms if g.ndim == beta.ndim else _channel_terms)(
+            q, k, g, beta)
 
     def body(state, xs):
         k_in, q_in, v, solve, qk, k_out, last = xs
@@ -161,10 +269,11 @@ def chunk_rule(q, k, v, g, beta, state0, chunk=CHUNK):
 
 
 def rule_step(q, k, v, g, beta, state):
-    """One position of every row: q, k [B, H, dk]; v [B, H, dv]; g, beta
-    [B, H]; state [B, H, dk, dv] float32 -> (o [B, H, dv], the state after
-    it)."""
-    state = jnp.exp(g)[..., None, None] * state
+    """One position of every row: q, k [B, H, dk]; v [B, H, dv]; beta [B,
+    H]; g [B, H], or [B, H, dk] where the decay is a channel's and scales
+    the state's ROWS; state [B, H, dk, dv] float32 -> (o [B, H, dv], the
+    state after it)."""
+    state = jnp.exp(g)[(...,) + (None,) * (state.ndim - g.ndim)] * state
     u = beta[..., None] * (v - jnp.sum(state * k[..., None], axis=-2))
     state = state + k[..., None] * u[..., None, :]
     return jnp.sum(state * q[..., None], axis=-2), state
@@ -175,24 +284,29 @@ def _split(p, z):
     return z[..., :c], z[..., c:]
 
 
-def window(p, z, state0, tail0, lens, eps):
+def window(p, z, state0, tail0, lens, eps, scope="delta", **gate):
     """A window of positions through the mixer's recurrent part. z [B, T,
-    C + 2H]; state0 [B, H, dk, dv] float32 and tail0 [B, k - 1, C]: what
-    the rows carried in (zeros for a row that starts here); lens [B]: the
-    rows' real positions, the rest of T is padding and moves nothing.
+    C + A + H] (A = H, or H dk where the decay is a channel's); state0 [B,
+    H, dk, dv] float32 and tail0 [B, k - 1, C]: what the rows carried in
+    (zeros for a row that starts here); lens [B]: the rows' real
+    positions, the rest of T is padding and moves nothing.
     Returns (o [B, T, H * dv] in z's type, state after position ``lens -
     1``, tail [B, k - 1, C]). ``eps`` is the block's, for its norms: the
-    rule has its own (L2_EPS)."""
+    rule has its own (L2_EPS). ``scope`` and ``gate`` (``beta_max``,
+    ``floor``: ``gates``' keywords): the kind's data (the module's
+    docstring)."""
     x, ab = _split(p, z)
-    with jax.named_scope("delta/conv"):
+    with jax.named_scope(scope + "/conv"):
         c, tail = ssm.conv_window(
             x, tail0, p["ConvW"], jnp.zeros((x.shape[-1],), _F32), lens)
-    with jax.named_scope("delta/chunk"):
-        g, beta = gates(p, ab)
+    with jax.named_scope(scope + "/chunk"):
+        g, beta = gates(p, ab, **gate)
         real = (jnp.arange(z.shape[1], dtype=jnp.int32)[None]
                 < lens[:, None])[:, :, None]
-        o, state = chunk_rule(*_heads(p, c), jnp.where(real, g, 0.0),
-                              jnp.where(real, beta, 0.0), state0)
+        o, state = chunk_rule(
+            *_heads(p, c),
+            jnp.where(real if g.ndim == 3 else real[..., None], g, 0.0),
+            jnp.where(real, beta, 0.0), state0)
     return o.reshape(o.shape[:2] + (-1,)).astype(z.dtype), state, tail
 
 
@@ -202,9 +316,9 @@ def step_in_kernel(pool_shape, pool_dtype):
     return False
 
 
-def step(p, z, s_pool, layer, held, tail0, eps):
+def step(p, z, s_pool, layer, held, tail0, eps, scope="delta", **gate):
     """A decode step of one layer IN THE ENTRIES' ORDER against the state
-    pool itself, as ops/ssm.py's: z [n, C + 2H], an entry's input; s_pool
+    pool itself, as ops/ssm.py's: z [n, C + A + H], an entry's input; s_pool
     [L, n, H, dk, dv]; held [n] bool; tail0 [n, (k - 1) * C], flat as the
     tail pool stores it -> (o [n, H * dv] in z's type, the pool with layer
     ``layer`` written, tail [n, (k - 1) * C]). The layer's entries are
@@ -213,12 +327,12 @@ def step(p, z, s_pool, layer, held, tail0, eps):
     state0 = s_pool[layer]
     x, ab = _split(p, z)
     n = z.shape[0]
-    with jax.named_scope("delta/conv"):
+    with jax.named_scope(scope + "/conv"):
         c, tail = ssm.conv_step(
             x, tail0.reshape(n, -1, x.shape[-1]), p["ConvW"],
             jnp.zeros((x.shape[-1],), _F32))
-    with jax.named_scope("delta/step"):
-        o, state = rule_step(*_heads(p, c), *gates(p, ab),
+    with jax.named_scope(scope + "/step"):
+        o, state = rule_step(*_heads(p, c), *gates(p, ab, **gate),
                              state0.astype(_F32))
         o = o.reshape(n, -1).astype(z.dtype)
         s_pool = s_pool.at[layer].set(jnp.where(

@@ -518,7 +518,10 @@ def moe_apply_sorted(xt, idx, gates, w_gate, w_up, w_down, layer=None,
     dropped. The sum back to the tokens walks those rows in blocks of
     ``UNSORT_BLOCK`` and stops behind the last pair held, so its cost
     follows the pairs this chip holds and not the rows set aside for
-    them.
+    them. A share of a QUARTER of the router or more sets no row aside:
+    where the walk would take more than one block its rows go back to
+    token order as the whole layer's do, and a token's few held pairs
+    are summed in the token's own order.
 
     The CALL decides its form, as the paged attention calls do, from
     what it is given (the backend or the tests' interpreter hook, the
@@ -627,6 +630,19 @@ def moe_apply_sorted(xt, idx, gates, w_gate, w_up, w_down, layer=None,
                 jnp.zeros((t, ys.shape[-1]), jnp.float32)).astype(cdt)
 
     few = -(-4 * t * k * e // width // 8) * 8
+    if few >= t * k > UNSORT_BLOCK:
+        # a share of a quarter of the router or more, over more rows than
+        # one block: no row is set aside, so every sorted row is computed
+        # and each goes back to its place in token order, as the whole
+        # layer's do; a pair not held counts for nothing. A token's pairs
+        # are then summed in ITS order, a few of them held: walked in
+        # blocks, the order of a token's sum followed where its pairs fell
+        # among the other rows', and one request's tokens moved with its
+        # company (PERF.md section 6, PR 62)
+        back = jnp.argsort(order)
+        pairs = jnp.where((back < n_held)[:, None], grouped(order)[back],
+                          0.0).reshape(t, k, -1)
+        return jnp.sum(pairs * gates[..., None], axis=1).astype(cdt)
     if few >= t * k:
         return leading(t * k)
     return jax.lax.cond(n_held <= few, lambda: leading(few),

@@ -7,6 +7,7 @@ fusion_lstm_op.cc etc. are the CUDA-era analogues): the hot path is one
 op the compiler can schedule as a unit, instead of a softmax/matmul
 chain.
 """
+import contextlib
 import copy
 import functools
 import itertools
@@ -330,7 +331,24 @@ class BlockKinds:
     projection where the weight is as wide as it, EACH HEAD with the one
     weight where the weight is a head wide (read off the parameter).
     ``route_eps`` is what a sigmoid router adds to the sum of the picked
-    scores it divides them by (ops/moe.py ``moe_route``).
+    scores it divides them by (ops/moe.py ``moe_route``). ``"mixer": "kda"``
+    is the fifth, Kimi Delta Attention (arXiv:2510.26692): the delta rule
+    again (the same module, cache kind and pools) with a decay A CHANNEL
+    (the layer's ``Wa`` is [D, heads * dk], read off its width), a sigmoid
+    output gate, and the kind's own ``rule``: a dict of ops/delta_rule.py's
+    three data (``scope`` of its spans, ``beta_max`` the write strength's
+    ceiling, ``floor`` the lower-bound gate's bound), absent for ``delta``,
+    whose values are that module's defaults.
+
+    LATENT ATTENTION MAY BE A KIND AMONG OTHERS: ``"mixer": "latent"`` in a
+    kind's dict (its widths the model's ``kv_rank`` / ``rope_dim`` /
+    ``nope_dim`` / ``v_dim`` / ``softmax_scale``) with ONE ``sequence`` pool
+    ``[its layers, pages, page_size, stored entry]``: its layers fold
+    their prefill expanded and decode absorbed through the paths a
+    whole-stack latent model has. A layer WITHOUT the query's low-rank
+    pair holds ``Wq`` [D, heads * (nope_dim + rope_dim)] in place of
+    ``Wqa`` / ``QNorm`` / ``Wqb``, and one that holds ``Wg`` gates each
+    head's result as ``gqa`` does.
 
     A stack may be RUN SEVERAL TIMES A TOKEN (a looped language model,
     arXiv:2510.25741): ``passes`` > 1 runs the same stacked layers that
@@ -408,6 +426,17 @@ class BlockKinds:
         return out
 
 
+def _head_gate(kinds, p, u, a):
+    """a [b, t, heads * dv], each head's part times ``sigmoid(u Wg)`` [..,
+    heads] of the layer's normed input (arXiv:2505.06708)."""
+    b, t, _ = u.shape
+    with jax.named_scope("/".join(
+            x for x in ("attn", kinds.name, "gate") if x)):
+        g = jax.nn.sigmoid(qmat(u, p, "Wg").astype(jnp.float32))
+        return (a.reshape(b, t, kinds.n_heads, -1).astype(jnp.float32)
+                * g[..., None]).astype(a.dtype).reshape(a.shape)
+
+
 def _gqa_attention(kinds, p, u, pos, attend_fn):
     """Grouped-query projections, the first ``rotary_dim`` widths of every
     query and key head rotated (all of them where None), the values times
@@ -450,11 +479,7 @@ def _gqa_attention(kinds, p, u, pos, attend_fn):
         v = v * jnp.asarray(kinds.value_scale, v.dtype)
     a = attend_fn(q, (k, v))
     if p.get("Wg") is not None:
-        with jax.named_scope("/".join(
-                x for x in ("attn", kinds.name, "gate") if x)):
-            g = jax.nn.sigmoid(qmat(u, p, "Wg").astype(jnp.float32))
-            a = (a.reshape(b, t, kinds.n_heads, -1).astype(jnp.float32)
-                 * g[..., None]).astype(a.dtype).reshape(a.shape)
+        a = _head_gate(kinds, p, u, a)
     return qmat(a, p, "Wo")
 
 
@@ -466,8 +491,11 @@ def _latent_attention(kinds, p, u, pos, attend_fn):
     expanded or absorbed, and returns [b, t, heads * v_dim]."""
     b, t, _ = u.shape
     r = kinds.kv_rank
-    q = (rms_normalize(u @ p["Wqa"], p["QNorm"], kinds.eps)
-         @ p["Wqb"]).reshape(b, t, kinds.n_heads, -1)
+    if p.get("Wqa") is None:    # no low-rank pair on the query's side
+        q = (u @ p["Wq"]).reshape(b, t, kinds.n_heads, -1)
+    else:
+        q = (rms_normalize(u @ p["Wqa"], p["QNorm"], kinds.eps)
+             @ p["Wqb"]).reshape(b, t, kinds.n_heads, -1)
     q_nope, q_pe = q[..., :kinds.nope_dim], q[..., kinds.nope_dim:]
     ckv = u @ p["Wkva"]
     c = rms_normalize(ckv[..., :r], p["KvNorm"], kinds.eps)
@@ -478,14 +506,20 @@ def _latent_attention(kinds, p, u, pos, attend_fn):
 
     k_pe = rotate(ckv[..., None, r:])[:, :, 0]
     entry = jnp.concatenate([c, k_pe], axis=-1)
-    return attend_fn((q_nope, rotate(q_pe)), (entry,)) @ p["Wo"]
+    a = attend_fn((q_nope, rotate(q_pe)), (entry,))
+    if p.get("Wg") is not None:
+        a = _head_gate(kinds, p, u, a)
+    return a @ p["Wo"]
 
 
 # the mixers whose cache is the ``state`` kind, each a module of ``window(p,
 # z, state0, tail0, lens, eps)``, ``step(p, z, s_pool, layer, held, tail0,
 # eps)`` and ``step_in_kernel(pool_shape, pool_dtype)``; a mixer that keeps
-# a tail and no state (``conv``) is handed None for the state and its pool
-_STATE_MIXERS = {"ssm": ssm, "delta": delta_rule, "conv": short_conv}
+# a tail and no state (``conv``) is handed None for the state and its pool;
+# ``window`` and ``step`` also take the keywords a kind's dict names under
+# ``rule`` (ops/delta_rule.py's: ``kda`` is that module with a kind's own)
+_STATE_MIXERS = {"ssm": ssm, "delta": delta_rule, "conv": short_conv,
+                 "kda": delta_rule}
 
 
 def _state_stats(mixer):
@@ -501,6 +535,13 @@ def _state_pools(mine):
     """(state pool, tail pool) of a state kind's pools: a mixer with a
     tail and no state (``conv``) has the tail's alone, and None."""
     return (None, mine[0]) if len(mine) == 1 else tuple(mine)
+
+
+def _is_latent(spec):
+    """Whether a kind of layer (an ``attn_kinds`` dict) is latent
+    attention: ONE ``sequence`` pool of ``[latent | rotated key]``
+    entries."""
+    return spec.get("mixer") == "latent"
 
 
 def _keeps_state(spec):
@@ -519,19 +560,21 @@ def _ssm_mixer(kinds, p, u, pos, attend_fn):
     return qmat(attend_fn(z, ()) * jax.nn.silu(g), p, "WOut")
 
 
-def _delta_mixer(kinds, p, u, pos, attend_fn):
+def _delta_mixer(kinds, p, u, pos, attend_fn, gate=jax.nn.silu):
     """The gated delta rule's projections: queries, keys and values (to
-    be convolved) and the decay's and the write strength's a head (not to
-    be), one ``z``; out ``= W_o (norm(o) * silu(W_z u))``, the norm an
-    RMSNorm over each head's values. ``attend_fn(z, ())`` owns what lies
-    between (ops/delta_rule.py) and the state a sequence carries."""
+    be convolved) and the decay's (a head's, or a channel's where ``Wa`` is
+    that wide) and the write strength's a head (not to be), one ``z``; out
+    ``= W_o (norm(o) * gate(W_z u))``, the norm an RMSNorm over each head's
+    values, ``gate`` SiLU (``delta``) or a sigmoid (``kda``).
+    ``attend_fn(z, ())`` owns what lies between (ops/delta_rule.py) and
+    the state a sequence carries."""
     z = jnp.concatenate([qmat(u, p, s) for s in
                          ("Wq", "Wk", "Wv", "Wa", "Wb")], axis=-1)
     o = attend_fn(z, ())
     dv = p["GNorm"].shape[-1]
     o = rms_normalize(o.reshape(o.shape[:-1] + (-1, dv)), p["GNorm"],
                       kinds.eps).reshape(o.shape)
-    return qmat(o * jax.nn.silu(qmat(u, p, "Wz")), p, "Wo")
+    return qmat(o * gate(qmat(u, p, "Wz")), p, "Wo")
 
 
 def _conv_mixer(kinds, p, u, pos, attend_fn):
@@ -642,7 +685,8 @@ def _mhc_residual(kinds, p, which, x, sublayer):
 
 _ATTENTION = {"gqa": _gqa_attention, "latent": _latent_attention,
               "ssm": _ssm_mixer, "delta": _delta_mixer,
-              "conv": _conv_mixer}
+              "conv": _conv_mixer,
+              "kda": functools.partial(_delta_mixer, gate=jax.nn.sigmoid)}
 _FFN = {"swiglu": _swiglu_ffn, "routed": _routed_ffn}
 _RESIDUAL = {"plain": _plain_residual, "mhc": _mhc_residual}
 
@@ -1399,6 +1443,12 @@ SSM_STATS = HYBRID_STATS + _state_stats("ssm")
 DELTA_STATS = HYBRID_STATS + _state_stats("delta")
 # or gated short convolutions: the tails updated and the positions tapped
 CONV_STATS = HYBRID_STATS + _state_stats("conv")
+# or Kimi-delta-attention layers beside a LATENT layer: the rule's two under
+# this mixer's name, and the cache positions the active rows attended in
+# the latent layers over decode steps (layers x rows x positions: the
+# stored entry's bytes x this is what the steps read of the latent pool)
+KDA_LATENT_STATS = HYBRID_STATS + _state_stats("kda") + (
+    "attn_latent_positions_total",)
 # and a model whose stack is run several times a token, over decode steps
 # alone: the layer passes its active rows went through (passes x layers a
 # row a step, counted by the loop that ran them) and the cache positions
@@ -1746,7 +1796,7 @@ class _PagedRunner:
                 tail0 = jnp.where(goes_on[:, None], t_pool[lyr, entry], 0)
         y, state, tail = _STATE_MIXERS[spec["mixer"]].window(
             p, z, state0, tail0.reshape(b, -1, p["ConvW"].shape[-1]),
-            self.lens, self.kinds.eps)
+            self.lens, self.kinds.eps, **spec.get("rule", {}))
         with jax.named_scope("cache/" + spec["name"]):
             if s_pool is not None:
                 s_pool = s_pool.at[lyr, entry].set(
@@ -1781,7 +1831,8 @@ class _PagedRunner:
             held = jnp.zeros((n,), bool).at[at].set(True, mode="drop")
             tail0 = t_pool[lyr]
         y_e, s_pool, tail = _STATE_MIXERS[spec["mixer"]].step(
-            p, z_e, s_pool, lyr, held, tail0, self.kinds.eps)
+            p, z_e, s_pool, lyr, held, tail0, self.kinds.eps,
+            **spec.get("rule", {}))
         with jax.named_scope("cache/" + spec["name"]):
             t_pool = t_pool.at[lyr].set(jnp.where(
                 held[:, None], tail.astype(t_pool.dtype), tail0))
@@ -2044,6 +2095,19 @@ class _PagedRunner:
             [i for i, spec in enumerate(k.attn_kinds)
              if spec["window"] is None and not _keeps_state(spec)])}
 
+        def latent_fold(p, q, pool, lyr, kind=None):
+            """A latent layer's window against its rows' pages of ``pool``,
+            written already: expanded, a block of keys at a time."""
+            in_kernel, ppb, n_blocks, blocks = blocks_of[kind]
+
+            def read_block(i):
+                tb = jax.lax.dynamic_slice_in_dim(blocks, i * ppb, ppb,
+                                                  axis=1)
+                return pool[lyr, tb].reshape(b, ppb * ps, -1)
+
+            return self._latent_expanded(p, q, read_block, n_blocks,
+                                         ppb * ps, q_pos, in_kernel)
+
         def attend_kind(p, q, entries, pools, lyr, kind):
             """A layer of one of several attention kinds: its own pools."""
             spec = self.kinds.attn_kinds[kind]
@@ -2052,6 +2116,14 @@ class _PagedRunner:
             if _keeps_state(spec):
                 out, mine = self._state_prefill(p, q, mine, lyr, pos0,
                                                 spec)
+            elif _is_latent(spec):
+                with jax.named_scope("attn/" + spec["name"]):
+                    with jax.named_scope("cache/" + spec["name"]):
+                        pg = jnp.take_along_axis(table, q_pos // ps, axis=1)
+                        mine = [pl.at[lyr, pg, q_pos % ps].set(
+                            _as_stored(e, pl))
+                            for pl, e in zip(mine, entries)]
+                    out = latent_fold(p, q, mine[0], lyr, kind)
             elif spec["window"] is not None:
                 with jax.named_scope("attn/" + spec["name"]):
                     out, mine = self._window_prefill(
@@ -2094,16 +2166,7 @@ class _PagedRunner:
             pools = tuple(pl.at[lyr, pg, q_pos % ps].set(_as_stored(e, pl))
                           for pl, e in zip(pools, entries))
             if self.kinds.attention == "latent":
-                in_kernel, ppb, n_blocks, blocks = blocks_of[None]
-
-                def read_block(i):
-                    tb = jax.lax.dynamic_slice_in_dim(blocks, i * ppb,
-                                                      ppb, axis=1)
-                    return pools[0][lyr, tb].reshape(b, ppb * ps, -1)
-
-                return (self._latent_expanded(
-                    p, q, read_block, n_blocks, ppb * ps, q_pos,
-                    in_kernel), pools)
+                return latent_fold(p, q, pools[0], lyr), pools
             views = [pl[lyr, table].reshape((b, kmax) + pl.shape[3:])
                      for pl in pools]
             return self._attend_math(q, *views, q_pos, t_len), pools
@@ -2165,7 +2228,7 @@ class _PagedRunner:
                     lyr, pg[None], (q_pos % ps)[None]].set(entries)
         return back
 
-    def _paged_step(self, attention, table, pos, n_pages):
+    def _paged_step(self, attention, table, pos, n_pages, scope=None):
         """``attend(q, entries, pools, layer) -> (out [B, 1, heads * dv],
         the pools written)``: a decode step at positions ``pos`` [B] of
         one layer of a sequence kind against its pools themselves (K and
@@ -2175,7 +2238,9 @@ class _PagedRunner:
         dropped, a null table entry lands on page 0) and ``attention``
         (pallas_attention.py: the kernel where its gate passes, the
         reference where not) attends the row's pages where they lie, to
-        the row's own length, ``pos + 1`` and ``kmax`` at most."""
+        the row's own length, ``pos + 1`` and ``kmax`` at most. ``scope``:
+        the named scope the entry's write goes under, where it has one of
+        its own."""
         ps = self.page_size
         kmax = table.shape[1] * ps
         at = jnp.minimum(pos, kmax - 1)
@@ -2186,9 +2251,11 @@ class _PagedRunner:
         lens = jnp.minimum(pos + 1, kmax)
 
         def attend(q, entries, pools, lyr):
-            pools = tuple(pl.at[lyr, pg, pos % ps].set(
-                e[:, 0].reshape((-1,) + pl.shape[3:]), mode="drop")
-                for pl, e in zip(pools, entries))
+            with contextlib.nullcontext() if scope is None \
+                    else jax.named_scope(scope):
+                pools = tuple(pl.at[lyr, pg, pos % ps].set(
+                    e[:, 0].reshape((-1,) + pl.shape[3:]), mode="drop")
+                    for pl, e in zip(pools, entries))
             out = attention(q[:, 0], *pools, lyr, table, lens)
             return out.reshape(out.shape[0], 1, -1), pools
 
@@ -2209,13 +2276,13 @@ class _PagedRunner:
         of _latent_expanded, the cache read once and never expanded."""
         *cache, table, pos = cache_table_pos
         k = self.kinds
+        latent_decode = functools.partial(
+            paged_latent_decode, scale=k.softmax_scale,
+            width=whole_tiles(k.kv_rank))
         if k.attn_kinds is None:
             attend = self._paged_step(
-                functools.partial(paged_latent_decode,
-                                  scale=k.softmax_scale,
-                                  width=whole_tiles(k.kv_rank))
-                if k.attention == "latent" else paged_gqa_decode,
-                table, pos, cache[0].shape[1])
+                latent_decode if k.attention == "latent"
+                else paged_gqa_decode, table, pos, cache[0].shape[1])
             q_pos = pos[:, None]
         else:
             # a window of one position, and a kind's addressing taken at
@@ -2231,6 +2298,13 @@ class _PagedRunner:
             mine = [cache[i] for i in spec["pools"]]
             if _keeps_state(spec):
                 out, mine = self._state_step(p, q, mine, lyr, spec)
+            elif _is_latent(spec):
+                with jax.named_scope("attn/" + spec["name"]):
+                    out, mine = absorbed(p, q, entries, mine, lyr,
+                                         self._paged_step(
+                                             latent_decode, table, pos,
+                                             mine[0].shape[1],
+                                             "cache/" + spec["name"]))
             elif spec["window"] is None:
                 with jax.named_scope("attn/" + spec["name"]):
                     out, mine = self._paged_step(
@@ -2264,11 +2338,10 @@ class _PagedRunner:
                 cache[i] = d
             return out, tuple(cache)
 
-        def attend_write(p, q, entries, pools, lyr, kind=None):
-            if kind is not None:
-                return attend_kind(p, q, entries, pools, lyr, kind)
-            if k.attention != "latent":
-                return attend(q, entries, pools, lyr)
+        def absorbed(p, q, entries, pools, lyr, attend):
+            """A latent layer's step against its ONE pool: the key half of
+            the expansion on the query, the value half on what ``attend``
+            (a _paged_step) gives back."""
             q_nope, q_pe = q
             w_up = self._kv_up(p)
             with jax.named_scope("mla/absorb"):
@@ -2283,6 +2356,13 @@ class _PagedRunner:
                     o_lat.reshape(q_abs.shape[:3] + (-1,))[..., :k.kv_rank],
                     w_up[..., k.nope_dim:])
             return out.reshape(out.shape[:2] + (-1,)), pools
+
+        def attend_write(p, q, entries, pools, lyr, kind=None):
+            if kind is not None:
+                return attend_kind(p, q, entries, pools, lyr, kind)
+            if k.attention != "latent":
+                return attend(q, entries, pools, lyr)
+            return absorbed(p, q, entries, pools, lyr, attend)
 
         h, cache = self._stack_forward(h, tuple(cache), q_pos,
                                        attend_write)
@@ -2353,7 +2433,10 @@ class _PagedRunner:
                 layers = self.kinds.layer_kinds.count(k)
                 if _keeps_state(spec):
                     continue
-                if spec["window"] is None:
+                if _is_latent(spec):
+                    at = names.index("attn_latent_positions_total")
+                    out[at] = out[at] + layers * jnp.sum(n)
+                elif spec["window"] is None:
                     out[6] = out[6] + layers * jnp.sum(n)
                 else:
                     out[7] = out[7] + layers * jnp.sum(
@@ -2367,7 +2450,7 @@ class _PagedRunner:
             if decode:
                 out[2] = jnp.int32(loads.shape[0] * loads.shape[1])
                 out[3] = jnp.sum(loads > 0)
-        if decode and self.kinds.attention == "latent":
+        if decode and self.kinds.attention == "latent" and not hybrid:
             out[4] = jnp.sum(jnp.where(self.valid[:, 0], positions + 1, 0))
         if decode and self.kinds.passes > 1:
             n = jnp.where(self.valid[:, 0], positions + 1, 0)
@@ -2380,8 +2463,9 @@ def stats_names(kinds):
     """The counters a program of a model of these kinds returns, in its
     order: PAGED_STATS, HYBRID_STATS where it mixes kinds of layer,
     and behind them a state mixer's two where some of those layers keep
-    a state (SSM_STATS, DELTA_STATS), LOOP_STATS where its stack is run
-    several times a token."""
+    a state (SSM_STATS, DELTA_STATS), the latent layers' positions where
+    one of its kinds is latent (KDA_LATENT_STATS), LOOP_STATS where its
+    stack is run several times a token."""
     if kinds.passes > 1:
         return LOOP_STATS
     if kinds.layer_kinds is None:
@@ -2389,7 +2473,9 @@ def stats_names(kinds):
     return HYBRID_STATS + tuple(
         name for mixer in _STATE_MIXERS
         if any(k.get("mixer") == mixer for k in kinds.attn_kinds)
-        for name in _state_stats(mixer))
+        for name in _state_stats(mixer)) + (
+            ("attn_latent_positions_total",)
+            if any(_is_latent(k) for k in kinds.attn_kinds) else ())
 
 
 def decode_in_place(attention, attn_kinds, pool_shapes, kind=None):
@@ -2407,19 +2493,22 @@ def decode_in_place(attention, attn_kinds, pool_shapes, kind=None):
     does): the kind that keeps the whole sequence, has attention for its
     mixer and no sink, its two pools' entries flat at whole lane tiles
     (paged_flat_usable; a value head that is a part of a tile:
-    paged_packed_usable); a window kind and a state kind have no kernel."""
+    paged_packed_usable); a LATENT kind, its one pool as a latent model's;
+    a window kind and a state kind have no kernel."""
     if attn_kinds is None and attention == "latent":
         return paged_latent_usable(pool_shapes)
-    if attention != "gqa":
-        return False
     if attn_kinds is None:
-        return len(pool_shapes) == 2 and paged_gqa_usable(*pool_shapes)
+        return (attention == "gqa" and len(pool_shapes) == 2
+                and paged_gqa_usable(*pool_shapes))
     if kind is None:
         return any(decode_in_place(attention, attn_kinds, pool_shapes, k)
                    for k in range(len(attn_kinds)))
     spec = attn_kinds[kind]
     mine = [pool_shapes[i] for i in spec["pools"]]
-    return (spec["window"] is None and not _keeps_state(spec)
+    if _is_latent(spec):
+        return paged_latent_usable(mine)
+    return (spec.get("mixer", attention) == "gqa"
+            and spec["window"] is None and not _keeps_state(spec)
             and not spec["sink"] and len(mine) == 2
             and (paged_flat_usable(*mine, spec["n_kv"])
                  or paged_packed_usable(*mine, spec["n_kv"])))
@@ -2516,22 +2605,25 @@ def prefill_in_kernel(attention, attn_kinds, latent_widths, pool_shapes,
     sequence and attends, its entries flat in their pages and a value
     head whole lane tiles (a key head is padded to them). And, for both,
     a backend that runs the kernel and a window and a block of keys that
-    cut into its tiles. A model with one kind of plain GQA layer attends
+    cut into its tiles. A LATENT kind among others is asked as a latent
+    model is. A model with one kind of plain GQA layer attends
     a layer's gathered rows (``_attend_math``) and is not asked."""
     if attn_kinds is None:
         if attention != "latent":
             return False
         widths, page_size = latent_widths, pool_shapes[0][2]
-    elif attention != "gqa":
-        return False
     elif kind is None:
         return any(prefill_in_kernel(attention, attn_kinds, latent_widths,
                                      pool_shapes, t_len, n_pages, seen, i)
                    for i in range(len(attn_kinds)))
+    elif _is_latent(attn_kinds[kind]):
+        widths = latent_widths
+        page_size = pool_shapes[attn_kinds[kind]["pools"][0]][2]
     else:
         spec = attn_kinds[kind]
         mine = [pool_shapes[i] for i in spec["pools"]]
-        if (spec["window"] is not None or _keeps_state(spec)
+        if (spec.get("mixer", attention) != "gqa"
+                or spec["window"] is not None or _keeps_state(spec)
                 or len(mine) != 2
                 or any(len(shape) != 4 for shape in mine)):
             return False

@@ -207,6 +207,12 @@ _DECODE_COUNTERS = (
     # and for a model whose state layers are gated short convolutions
     # (CONV_STATS): the kind with ONE pool, a tail of two inputs a layer
     "conv_state_updates_total", "conv_prefill_positions_total",
+    # and for a model whose state layers are Kimi delta attention (a decay
+    # a channel) beside LATENT layers in one stack (KDA_LATENT_STATS): the
+    # rule's two under this mixer's name, and the positions the active
+    # rows attended in the latent layers' pool over decode steps
+    "kda_state_updates_total", "kda_prefill_positions_total",
+    "attn_latent_positions_total",
     # a model whose stack is run several times a token (PR 43) keeps a
     # cache layer a pass a layer, and counts on the device, over decode
     # steps, the layer passes its active rows went through and the

@@ -1025,3 +1025,56 @@ def test_the_serving_decode_program_holds_no_step_logits(one_chip, model,
     assert probe.memory_analysis().output_size_in_bytes \
         - serving.memory_analysis().output_size_in_bytes \
         >= steps * rows * vocab * 4
+
+
+# -- a latent kind beside a state kind in one stack (PR 62) ----------------
+
+@pytest.fixture(scope="module")
+def ling():
+    """benchmark/configs/ling-3.0-flash-ep4.json: 256 slots, ONE latent
+    layer's 16,384 pages of 64 positions stored 640 wide beside five kda
+    layers' float32 states [257, 32, 128, 128] and tails, 128 held experts
+    of 2,560 x 768 a routed layer, chunks of 2,048, 4 steps: (its
+    configuration, the programs)."""
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(pa, "_use_pallas", lambda: True)
+        return _programs_of("ling-3.0-flash-ep4.json", "serve_kda_latent")
+
+
+@pytest.mark.parametrize("label", ["decode", "chunk"])
+def test_lings_programs_hold_the_latent_kernels_and_copy_no_pool(
+        one_chip, ling, label, monkeypatch):
+    """The latent KIND's one layer among five kda layers goes through the
+    kernels a whole-stack latent model has, ONE instance each: the decode
+    program ``paged_latent_decode`` against the pool itself, the chunk
+    program ``prefill_fold``. The state pool, 2.7 GB of float32 that every
+    layer's step (or window) writes where it lies, is aliased from the
+    donated input and never copied, nor are the latent pages and the
+    tails; the held experts go through ``ragged_dot`` (no kernel takes a
+    share at 256 rows, nor a share's prefill window)."""
+    monkeypatch.setattr(pa, "_use_pallas", lambda: True)
+    _, programs = ling
+    assert programs.pool_specs == [
+        ([1, 16384, 64, 640], "bfloat16"),
+        ([5, 257, 32, 128, 128], "float32"), ([5, 257, 36864], "bfloat16")]
+    assert programs.decode["in_place"] and programs.chunk["attn_in_kernel"]
+    assert not programs.decode["state_in_kernel"]
+    assert not programs.decode["experts_in_kernel"]
+    assert not programs.chunk["experts_in_kernel"]
+    compiled = program_text.lower_bundle(
+        program_text.bundles_of(programs)[label],
+        len(programs.pool_specs), sharding=one_chip).compile()
+    text = compiled.as_text()
+    kernel = {"decode": "paged_latent_decode", "chunk": "prefill_fold"}
+    for name in kernel.values():
+        assert len(re.findall(rf"tpu_custom_call.*{name}", text)) \
+            == (name == kernel[label]), name
+    assert "ragged" in text
+    for name in ("moe_few_rows", "moe_grouped_rows"):
+        assert not re.findall(rf"tpu_custom_call.*{name}", text), name
+    _assert_held_uncopied(text, programs.pool_specs)
+    memory = compiled.memory_analysis()
+    pools = sum(math.prod(shape) * jnp.dtype(dt).itemsize
+                for shape, dt in programs.pool_specs)
+    assert memory.alias_size_in_bytes >= pools
+    assert memory.temp_size_in_bytes < {"decode": 0.3e9, "chunk": 3e9}[label]
