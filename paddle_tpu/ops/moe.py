@@ -200,9 +200,10 @@ def few_rows_usable(t, w_gate, w_down, held=None):
     (``_hidden_tile``: 512-wide experts of a 2,048-wide model are 12 MB
     twice over and go uncut; DeepSeek-V3's 7,168 x 2,048 go 512 of their
     hidden width a grid step, MiMo's 4,096 x 2,048 go 1,024, xing4's
-    3,584 x 1,024 whole under a raised limit). ``held`` refuses nothing:
-    a share only changes which columns of the rows' weights are non-zero.
-    It is taken so that whoever asks hands over what the call is given."""
+    3,584 x 1,024 whole under a raised limit). More rows than that are
+    ``grouped_rows_usable``'s to admit. ``held`` refuses nothing: a share
+    only changes which columns of the rows' weights are non-zero. It is
+    taken so that whoever asks hands over what the call is given."""
     del held
     d, f = w_gate.shape[-2:]
     return (_pa._use_pallas() and t <= FEW_ROWS
@@ -345,14 +346,21 @@ def moe_apply_few_rows(xt, idx, gates, w_gate, w_up, w_down, layer=None,
 
 def grouped_rows_usable(t, w_gate, w_down, held=None):
     """The gate of ``moe_grouped_rows``, beside ``few_rows_usable`` and in
-    its form: the backend runs Pallas kernels, every expert of the layer
-    is held, the rows are MORE than one MXU tile (a prefill window: a
-    decode step keeps the few-rows kernel), the widths whole lane tiles,
-    one type for the three matrices, and an expert's three matrices WHOLE
-    (their hidden width uncut) twice over within ``GROUPED_VMEM``, one in
-    flight while one is multiplied."""
+    its form: the backend runs Pallas kernels, the rows are MORE than one
+    MXU tile (a prefill window, or a decode step of more slots than that:
+    128 rows or fewer keep the few-rows kernel), the widths whole lane
+    tiles, one type for the three matrices, and an expert's three matrices
+    WHOLE (their hidden width uncut) twice over within ``GROUPED_VMEM``,
+    one in flight while one is multiplied (Ling's 2,560 x 768 are 23.6 MB
+    twice over, xing4's 3,584 x 1,024 44 MB; DeepSeek-V3's 7,168 x 2,048
+    and MiMo's 4,096 x 2,048 are 176 and 100 MB and keep ``ragged_dot``).
+    ``held`` refuses nothing, as in ``few_rows_usable``: a share's pairs
+    to absent experts sort behind the last held group, where no work item
+    names them (``_work_items``). It is taken so that whoever asks hands
+    over what the call is given."""
+    del held
     d, f = w_gate.shape[-2:]
-    return (_pa._use_pallas() and held is None and t > FEW_ROWS
+    return (_pa._use_pallas() and t > FEW_ROWS
             and d % 128 == 0 and f % 128 == 0
             and w_gate.dtype == w_down.dtype
             and 6 * d * f * w_gate.dtype.itemsize <= GROUPED_VMEM)
@@ -398,20 +406,26 @@ def _grouped_rows_kernel(layer_ref, expert_ref, tile_ref, starts_ref, n_ref,
 
 def _work_items(sizes, tile, n_tiles):
     """The grouped-rows kernel's work list for groups of ``sizes`` [E] rows
-    laid end to end over ``n_tiles`` row tiles of ``tile``: (expert [N],
-    row tile [N], the groups' first rows and the last one's end [E + 1],
-    how many of the N = ``n_tiles + E - 1`` items are work). An item is an
-    expert and a row tile that holds rows of its group, in ascending order
-    of both; an empty group has none; behind the last one the list repeats
-    it, so that nothing more is copied."""
+    laid end to end from row 0 of ``n_tiles`` row tiles of ``tile``:
+    (expert [N], row tile [N], the groups' first rows and the last one's
+    end [E + 1], how many of the N = ``n_tiles + E - 1`` items are work).
+    An item is an expert and a row tile that holds rows of its group, in
+    ascending order of both; an empty group has none, and neither has a
+    tile behind the last group's end (a share's pairs to absent experts);
+    behind the last item the list repeats it, so that nothing more is
+    copied. Where every group is empty NO item is work and the list names
+    the last expert and tile 0 throughout: the one block the pipeline
+    fetches before it looks."""
     ends = jnp.cumsum(sizes)
     starts = ends - sizes
     first = starts // tile
     spans = jnp.where(sizes > 0, (ends - 1) // tile - first + 1, 0)
     upto = jnp.cumsum(spans)
-    i = jnp.minimum(jnp.arange(n_tiles + sizes.shape[0] - 1), upto[-1] - 1)
-    expert = jnp.searchsorted(upto, i, side="right",
-                              method="compare_all").astype(jnp.int32)
+    i = jnp.minimum(jnp.arange(n_tiles + sizes.shape[0] - 1),
+                    jnp.maximum(upto[-1] - 1, 0))
+    expert = jnp.minimum(
+        jnp.searchsorted(upto, i, side="right", method="compare_all"),
+        sizes.shape[0] - 1).astype(jnp.int32)
     row_tile = first[expert] + i - (upto - spans)[expert]
     return (expert, row_tile.astype(jnp.int32),
             jnp.concatenate([starts, ends[-1:]]).astype(jnp.int32),
@@ -421,10 +435,14 @@ def _work_items(sizes, tile, n_tiles):
 def moe_grouped_rows(xs, sizes, w_gate, w_up, w_down, layer=None,
                      hidden_tile=None):
     """The SORTED pairs' rows ``xs`` [P, D] (group ``e`` is the ``sizes[e]``
-    rows behind the groups before it; ``sizes`` [E] sums to P) through
-    their experts' SwiGLU as ONE Pallas kernel, ``moe_grouped_rows`` in a
-    trace: what three ``jax.lax.ragged_dot`` and the SwiGLU between them
-    compute, float32 [P, D].
+    rows behind the groups before it; ``sizes`` [E] sums to P, or to FEWER
+    where the experts are a share and the pairs of absent ones lie behind
+    the last group) through their experts' SwiGLU as ONE Pallas kernel,
+    ``moe_grouped_rows`` in a trace: what three ``jax.lax.ragged_dot`` and
+    the SwiGLU between them compute, float32 [P, D]. A row of no group is
+    written by nobody and holds whatever the buffer held, not 0.0 as
+    ``ragged_dot`` leaves it: the caller masks it (``moe_apply_sorted``
+    does, by ``where`` and not by a product, which a NaN would pass).
 
     The experts that a pair reached are visited in ascending order, each
     multiplied with ITS OWN rows only, a row tile of ``GROUPED_ROW_TILE``
@@ -527,15 +545,19 @@ def moe_apply_sorted(xt, idx, gates, w_gate, w_up, w_down, layer=None,
     what it is given (the backend or the tests' interpreter hook, the
     rows, ``held``, the experts' widths and type): a few rows go through
     the kernel ``moe_apply_few_rows`` where ``few_rows_usable`` says so
-    (a decode step: a whole layer or a share of one, an expert taken in
-    runs of its hidden width where two of it pass the kernel's budget:
-    no sort, no gather and no un-sort there); more rows than that over
-    experts all held keep the sort, the gather and the un-sort and put
-    the sorted rows through the kernel ``moe_grouped_rows`` in place of
-    the three ``ragged_dot`` where ``grouped_rows_usable`` says so (a
-    prefill window); and this function is the reference of both, on
-    every CPU, and the form of a share's prefill window and of a window
-    over experts too wide. No training path differentiates through it (a
+    (a decode step of 128 rows or fewer: a whole layer or a share of
+    one, an expert taken in runs of its hidden width where two of it
+    pass the kernel's budget: no sort, no gather and no un-sort there);
+    more rows than that keep the sort, the gather and the un-sort and
+    put the sorted rows through the kernel ``moe_grouped_rows`` in place
+    of the three ``ragged_dot`` where ``grouped_rows_usable`` says so (a
+    prefill window or a decode step of more than 128 slots, over a whole
+    layer or a share of one, whichever of the share's branches below
+    takes it: Laguna's, xing4's and LFM2's experts all held, Ling's 128
+    of 512); and this function is the reference of both, on every CPU,
+    and the form of more than 128 rows over experts two of which are
+    over the grouped kernel's budget (DeepSeek-V3's and MiMo's prefill
+    windows). No training path differentiates through it (a
     Pallas call has no gradient here): ``moe_ffn`` trains through
     ``moe_apply``, and the paged programs and ``llama_generate`` only
     infer."""

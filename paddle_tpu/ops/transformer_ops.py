@@ -2556,22 +2556,25 @@ def prefill_experts_in_kernel(param_tables, t_len):
     chunk bundle's ``experts_in_kernel``, the engine's
     ``prefill_experts_in_kernel_total``), which chooses nothing: every
     routed layer's ``moe_apply_sorted`` asks the gate itself (ops/moe.py
-    ``grouped_rows_usable``: the backend, the rows, whether every expert is
-    held, the widths). ``param_tables``: the programs' layer parameters,
-    slot -> (suffix, shape, dtype) a stack; whether any stack's does."""
+    ``grouped_rows_usable``: the backend, the rows, the widths; a share is
+    admitted as a whole layer is). ``param_tables``: the programs' layer
+    parameters, slot -> (suffix, shape, dtype) a stack; whether any
+    stack's does."""
     from . import moe
     return _experts_gate(param_tables, moe.grouped_rows_usable, t_len)
 
 
 def decode_experts_in_kernel(param_tables, rows):
     """Whether a decode program of ``rows`` rows puts its routed layers
-    through the Pallas kernel ``moe_few_rows``: the same REPORT for a
+    through a Pallas kernel, ``moe_few_rows`` at 128 rows or fewer and
+    ``moe_grouped_rows`` (behind the sort) at more: the same REPORT for a
     decode step (the decode bundle's ``experts_in_kernel``, the engine's
-    ``decode_experts_in_kernel_total``), asked of ``few_rows_usable`` as
-    ``moe_apply_sorted`` asks where it lowers (the backend, the rows, the
-    widths; a share is admitted as a whole layer is)."""
+    ``decode_experts_in_kernel_total``), asked of both gates as
+    ``moe_apply_sorted`` asks them where it lowers (the backend, the rows,
+    the widths; a share is admitted as a whole layer is)."""
     from . import moe
-    return _experts_gate(param_tables, moe.few_rows_usable, rows)
+    return any(_experts_gate(param_tables, gate, rows) for gate in (
+        moe.few_rows_usable, moe.grouped_rows_usable))
 
 
 def _pages_seen(n_pages, seen, page_size):

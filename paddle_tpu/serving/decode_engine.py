@@ -161,17 +161,20 @@ _DECODE_COUNTERS = (
     # and its sibling for the routed experts: ticked at the same two places
     # for every whole-prompt or chunk dispatch whose program puts its routed
     # layers' sorted pairs through the kernel moe_grouped_rows (the bundle's
-    # ``experts_in_kernel``: every expert of the layer held, a window of
-    # more rows than the few-rows kernel takes, on a backend with the
-    # kernel), to be read against the same sum
+    # ``experts_in_kernel``: a window of more rows than the few-rows kernel
+    # takes over experts two of which fit the kernel's budget, a share or
+    # a whole layer, on a backend with the kernel), to be read against the
+    # same sum
     "prefill_experts_in_kernel_total",
     # and the decode step's: ticked beside decode_batches_total for every
-    # decode dispatch whose program puts its routed layers through the
-    # kernel moe_few_rows (the decode bundle's ``experts_in_kernel``: at
+    # decode dispatch whose program puts its routed layers through a
+    # kernel (the decode bundle's ``experts_in_kernel``: moe_few_rows at
     # most one MXU tile of rows over experts a tile of which fits the
-    # kernel's budget, a share or a whole layer, on a backend with the
-    # kernel): equal to decode_batches_total on the chip for agent, reason,
-    # mixed and docs, 0 on a CPU, without routed experts and at 256 rows
+    # kernel's budget, moe_grouped_rows behind the sort at more rows over
+    # experts that fit its budget uncut; a share or a whole layer, on a
+    # backend with the kernel): equal to decode_batches_total on the chip
+    # for agent, reason, mixed, docs, wide and longanswers, 0 on a CPU and
+    # without routed experts
     "decode_experts_in_kernel_total",
     # a model with window attention layers (PR 33) has caches of two
     # kinds, and counts on the device, over decode steps, the positions

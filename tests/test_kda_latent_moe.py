@@ -33,6 +33,7 @@ from benchmark.builders.serve_blocks import make_weights
 from benchmark.reference import kda_latent_moe_share as ref
 
 import program_text
+from decode_forms import kernel_on
 
 CFG = KDA_LATENT_TINY
 FLOOR = CFG.gate_floor
@@ -448,24 +449,49 @@ def test_the_gates_forms_are_the_kinds_data():
 
 # -- the share -------------------------------------------------------------
 
-def test_the_four_shares_add_up_to_the_uncut_layer():
+# the tiny model at widths that are whole lane tiles: where the gates of
+# ops/moe.py admit the kernels under the interpreter hook
+WIDE = dataclasses.replace(CFG, name="kda-latent-wide", dim=128,
+                           expert_hidden=128)
+
+
+@pytest.mark.parametrize("through, rows", [
+    ("the_reference", 9), ("the_few_rows_kernel", 9),
+    ("the_grouped_rows_kernel", 150)])
+def test_the_four_shares_add_up_to_the_uncut_layer(through, rows,
+                                                   monkeypatch):
     """Four chips, four experts each: what the shares' routed parts give,
     with the shared expert counted once, is the feed-forward of the uncut
-    reference; and every share picks the same experts for every token."""
-    whole = dataclasses.replace(CFG, n_experts=16, experts_first=0)
+    reference; and every share picks the same experts for every token.
+    Through the kernels too (the interpreter hook at widths the gates
+    admit): a share of a quarter at 9 rows, and at 150, more than the
+    few-rows kernel takes, whose sorted rows go through
+    ``moe_grouped_rows`` since PR 63."""
+    from paddle_tpu.ops import moe
+    cfg, model = (CFG, MODEL) if through == "the_reference" else (
+        WIDE, model_of(WIDE))
+    if through != "the_reference":
+        monkeypatch.setattr(pa, "_FORCE_INTERPRET", True)
+    shape = jax.ShapeDtypeStruct((4, cfg.dim, cfg.expert_hidden),
+                                 jnp.float32)
+    assert moe.few_rows_usable(rows, shape, shape, (0, 16)) is (
+        through == "the_few_rows_kernel")
+    assert moe.grouped_rows_usable(rows, shape, shape, (0, 16)) is (
+        through == "the_grouped_rows_kernel")
+    whole = dataclasses.replace(cfg, n_experts=16, experts_first=0)
     w_all = weights(7, whole)
-    layer = CFG.n_dense_layers          # the first routed one: kda.* 0
-    u = jax.random.normal(jax.random.PRNGKey(4), (1, 9, CFG.dim))
-    uncut = dict(MODEL, experts_held=dict(first=0, count=16, of=16))
+    layer = cfg.n_dense_layers          # the first routed one: kda.* 0
+    u = jax.random.normal(jax.random.PRNGKey(4), (1, rows, cfg.dim))
+    uncut = dict(model, experts_held=dict(first=0, count=16, of=16))
     ref_all = ref.from_stacked(w_all, uncut)
     with jax.default_matmul_precision("highest"):
         want, _, _, own = ref.experts(ref_all, layer, u[0], uncut)
         shared, _, _, _ = ref.experts(ref_all, layer, u[0], dict(
-            MODEL, experts_held=dict(first=0, count=0, of=16)))
+            model, experts_held=dict(first=0, count=0, of=16)))
     total, picks = jnp.zeros_like(shared), []
     for share in range(4):
-        cfg = dataclasses.replace(CFG, experts_first=4 * share)
-        attrs = {k: v for k, v in cfg.block_attrs(4).items()
+        held = dataclasses.replace(cfg, experts_first=4 * share)
+        attrs = {k: v for k, v in held.block_attrs(4).items()
                  if k != "page_size"}
         kinds = T.BlockKinds(
             eps=attrs.pop("epsilon"), **{k: attrs[k] for k in (
@@ -473,7 +499,7 @@ def test_the_four_shares_add_up_to_the_uncut_layer():
                 "scoring", "route_scale", "route_eps", "n_group",
                 "topk_group", "experts_first")})
         p = {}
-        for slot, (suffix, _, _) in cfg.layer_params(4, KDA, True).items():
+        for slot, (suffix, _, _) in held.layer_params(4, KDA, True).items():
             v = w_all[f"kda.{suffix}"][0]
             p[slot] = v[4 * share:4 * share + 4] \
                 if slot in T._EXPERT_SLOTS else v
@@ -583,3 +609,72 @@ def test_a_large_shares_sum_is_taken_in_each_tokens_own_order():
                 want[i] += float(gates[i, j]) * (
                     (g / (1 + np.exp(-g)) * (xn[i] @ wu[ex])) @ wd[ex])
     np.testing.assert_allclose(out, want, rtol=2e-4, atol=2e-5)
+
+
+# -- more than 128 rows of a share through the grouped kernel (PR 63) --------
+
+# 130 slots: a decode step's 130 rows x 3 picks and a window's 144 x 3 are
+# more rows than the few-rows kernel takes, over a quarter of the router
+WIDE_ENGINE = dict(ENGINE, max_batch=130, prompt_buckets=(144, 288),
+                   chunk_size=144)
+
+
+@pytest.fixture(scope="module")
+def wide_served():
+    w = weights(cfg=WIDE)
+    return w, scope_of(w)
+
+
+def _wide_run(scope, hook):
+    """Three requests over the 130-slot engine, a whole prompt, one through
+    two chunks and a short one: (the engine's own logits and tokens of each
+    ALONE through the path a request of its length takes, the tokens each
+    gives alone on a live engine, those it gives among the other two, that
+    engine's totals)."""
+    prompts = [np.random.RandomState(n).randint(
+        0, WIDE.vocab_size, n).astype(np.int64) for n in (140, 200, 9)]
+    with pytest.MonkeyPatch.context() as m:
+        if hook:
+            kernel_on(m, ENGINE["page_size"])
+        eng = engine_of(scope, cfg=WIDE, **WIDE_ENGINE)
+        bundles = [eng.programs.decode, eng.programs.chunk,
+                   *eng.programs.prefill.values()]
+        assert [b["experts_in_kernel"] for b in bundles] \
+            == [hook] * len(bundles)
+        probes = [builder.engine_logits(eng, p, 3, 0) for p in prompts]
+        eng.close()
+        eng = engine_of(scope, cfg=WIDE, auto_start=True, **WIDE_ENGINE)
+        try:
+            alone = [np.asarray(eng.generate(p, max_new=4)) for p in prompts]
+            together = [np.asarray(h.result(300)) for h in [
+                eng.submit(p, max_new=4) for p in prompts]]
+            stats = eng.stats()
+        finally:
+            eng.close()
+    return probes, alone, together, stats
+
+
+def test_the_wide_engine_is_the_same_through_the_grouped_kernel(
+        wide_served):
+    """The tiny model at whole lane tiles with 130 slots, the hook off and
+    on: the same tokens, logits within the file's limit, a request in the
+    mix the request alone on BOTH sides (a row's result in the kernel
+    depends on no other row), and the two counters tick with the hook (the
+    decode step's for a step of more than 128 rows, the window's for a
+    share) and not without."""
+    off, on = (_wide_run(wide_served[1], hook) for hook in (False, True))
+    for (probes, alone, together, stats), hook in ((off, False), (on, True)):
+        for (_, _, decoded), one, mixed in zip(probes, alone, together):
+            assert np.array_equal(one, mixed)
+            assert np.array_equal(np.asarray(decoded)[:4], one)
+        windows = stats["prefill_dispatch_total"] \
+            + stats["chunk_prefill_total"]
+        assert windows == 8 and stats["decode_batches_total"] > 0
+        assert stats["prefill_experts_in_kernel_total"] == (
+            windows if hook else 0)
+        assert stats["decode_experts_in_kernel_total"] == (
+            stats["decode_batches_total"] if hook else 0)
+        assert stats["pools_lost_total"] == 0
+    for (want, _, tokens), (got, _, again) in zip(off[0], on[0]):
+        assert np.array_equal(np.asarray(tokens), np.asarray(again))
+        assert builder.rel_l2(got, want).max() < REL_L2_F32

@@ -824,7 +824,8 @@ def test_the_grouped_rows_kernel_compiles_at_lagunas_window(one_chip,
     arguments than the sorted form holds (both peak at the float32
     ``[16384, 2048]`` result and its un-sorted copy, 268 MB). A decode
     step's 64 rows still compile to
-    ``moe_few_rows`` and a share's WINDOW to ``ragged_dot``; xing4's
+    ``moe_few_rows``, a share's WINDOW to the kernel as a whole layer's
+    does, and a share of experts over the budget to ``ragged_dot``; xing4's
     window (2,048 tokens x 4 picks over 64 experts of 3,584 x 1,024, 44 MB
     twice over) compiles to the kernel too, whole experts under a raised
     VMEM limit: the gate's budget admits it since the probe read it at
@@ -866,7 +867,18 @@ def test_the_grouped_rows_kernel_compiles_at_lagunas_window(one_chip,
     step = compiled(64, 8, up, down).as_text()
     assert len(re.findall(r"tpu_custom_call.*moe_few_rows", step)) == 1
     assert not re.findall(grouped, step)
-    share = compiled(2048, 8, up, down, held=(0, 256)).as_text()
+    # a SHARE of such experts is the kernel's too (PR 63): a quarter of
+    # the router, every sorted row back in token order, ONE call; a
+    # sixteenth, the leading rows or all of them, a call each side of the
+    # cond; a share of experts over the budget (DeepSeek-V3's) keeps
+    # ``ragged_dot``
+    quarter = compiled(2048, 8, up, down, held=(0, 1024)).as_text()
+    assert len(re.findall(grouped, quarter)) == 1 and "ragged" not in quarter
+    sixteenth = compiled(2048, 8, up, down, held=(256, 4096)).as_text()
+    assert len(re.findall(grouped, sixteenth)) == 2
+    assert "ragged" not in sixteenth
+    share = compiled(2048, 8, abstract((4, 16, 7168, 2048)),
+                     abstract((4, 16, 2048, 7168)), held=(0, 256)).as_text()
     assert "ragged" in share and not re.findall(grouped, share)
 
     xing4 = (abstract((5, 64, 3584, 1024)), abstract((5, 64, 1024, 3584)))
@@ -1050,8 +1062,10 @@ def test_lings_programs_hold_the_latent_kernels_and_copy_no_pool(
     program ``prefill_fold``. The state pool, 2.7 GB of float32 that every
     layer's step (or window) writes where it lies, is aliased from the
     donated input and never copied, nor are the latent pages and the
-    tails; the held experts go through ``ragged_dot`` (no kernel takes a
-    share at 256 rows, nor a share's prefill window)."""
+    tails; the held experts' sorted pairs go through ``moe_grouped_rows``,
+    ONE call a body of routed layers, at the step's 256 rows as in the
+    chunk's 2,048 (a share of experts that fit the kernel's budget is
+    admitted as a whole layer is, PR 63), and no ``ragged_dot`` is left."""
     monkeypatch.setattr(pa, "_use_pallas", lambda: True)
     _, programs = ling
     assert programs.pool_specs == [
@@ -1059,8 +1073,8 @@ def test_lings_programs_hold_the_latent_kernels_and_copy_no_pool(
         ([5, 257, 32, 128, 128], "float32"), ([5, 257, 36864], "bfloat16")]
     assert programs.decode["in_place"] and programs.chunk["attn_in_kernel"]
     assert not programs.decode["state_in_kernel"]
-    assert not programs.decode["experts_in_kernel"]
-    assert not programs.chunk["experts_in_kernel"]
+    assert programs.decode["experts_in_kernel"]
+    assert programs.chunk["experts_in_kernel"]
     compiled = program_text.lower_bundle(
         program_text.bundles_of(programs)[label],
         len(programs.pool_specs), sharding=one_chip).compile()
@@ -1069,9 +1083,11 @@ def test_lings_programs_hold_the_latent_kernels_and_copy_no_pool(
     for name in kernel.values():
         assert len(re.findall(rf"tpu_custom_call.*{name}", text)) \
             == (name == kernel[label]), name
-    assert "ragged" in text
-    for name in ("moe_few_rows", "moe_grouped_rows"):
-        assert not re.findall(rf"tpu_custom_call.*{name}", text), name
+    assert "ragged" not in text
+    assert not re.findall(r"tpu_custom_call.*moe_few_rows", text)
+    # the routed layers are three runs of the stack, a body each: the kda
+    # layers 2-4, the latent layer 5, the kda layer 6
+    assert len(re.findall(r"tpu_custom_call.*moe_grouped_rows", text)) == 3
     _assert_held_uncopied(text, programs.pool_specs)
     memory = compiled.memory_analysis()
     pools = sum(math.prod(shape) * jnp.dtype(dt).itemsize
