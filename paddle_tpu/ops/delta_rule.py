@@ -65,18 +65,35 @@ are not convolved. What a sequence carries from one call to the next is
 convolution's last ``k - 1`` inputs [k - 1, C]: ONE entry a sequence, as
 ops/ssm.py's, and as little protected by any length mask.
 
+A DECODE STEP runs against the state pool itself (``step``): a layer's
+entries are stepped where they lie, in the entries' order. Where the gate
+passes (``step_in_kernel``: a backend that runs Pallas kernels and a
+float32 pool ``[L, n, H, dk, dv]`` whose states are whole tiles, ``dk % 8
+== 0 and dv % 128 == 0``) ONE kernel, ``delta_state_step``
+(``step_entries``), passes over the layer's slab once, the pool aliased to
+the result: a block of entries x heads is read, decayed, reduced against
+its key and its query, corrected and written back where it was read, a
+decay a head or a channel alike (the broadcast differs). Everywhere else
+the same equations (``rule_step``) in jax.numpy over a slice of the pool,
+which XLA makes three reads and a write of the slab.
+
 Parameters ``p`` of one layer, by slot: ``ConvW`` [k, C], ``ALog`` [H] and
 ``DtBias`` [H] (a decay a channel: [H dk]) float32, ``GNorm`` [dv] (the
 mixer's output norm: the sizes are read off these: H from ``ALog``, dv
 from ``GNorm``, dk from C).
 """
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from . import pallas_attention as pa
 from . import ssm
 
-__all__ = ["window", "step", "step_in_kernel", "gates", "chunk_rule",
-           "rule_step", "CHUNK", "RUN", "BETA_MAX"]
+__all__ = ["window", "step", "step_entries", "step_in_kernel", "gates",
+           "chunk_rule", "rule_step", "CHUNK", "RUN", "BETA_MAX"]
 
 _F32 = jnp.float32
 _HI = jax.lax.Precision.HIGHEST
@@ -310,10 +327,122 @@ def window(p, z, state0, tail0, lens, eps, scope="delta", **gate):
     return o.reshape(o.shape[:2] + (-1,)).astype(z.dtype), state, tail
 
 
+# entries and heads a block of ``delta_state_step`` holds (a block of the pool
+# is entries x heads x dk x dv float32, in VMEM four times: read and written,
+# each twice), by the chip at Ling's slab, 257 entries of [32, 128, 128]
+# float32, 1.08 GB read and written a layer: 633 GB/s at 4 x 8 (2 MB a
+# block), 616 at 1 x 8, 633 at 2 x 8, 628 at 8 x 8, 620 at 16 x 8, 634 at 2 x
+# 16 and 4 x 16, 634-635 at 1 x 32 and 2 x 32; a kernel that only COPIES its
+# blocks reads 631-639 at every one of them: the copies bound it, not the
+# arithmetic (the jax.numpy step: 328) (PERF.md section 6, PR 64). The body
+# LOOPS over the block's entries and is unrolled over its heads alone, which
+# lie on the lanes of the block's columns: 8, a sublane tile of the rows'
+# blocks (32 unrolled cost 0.4-0.6 s more of tracing and lowering an
+# instance, three instances a decode program)
+STEP_BLOCK_ENTRIES = 4
+STEP_BLOCK_HEADS = 8
+
+
 def step_in_kernel(pool_shape, pool_dtype):
-    """Whether ``step`` runs a Pallas kernel over a pool of this shape and
-    type (ops/ssm.py's has one): never, it is jax.numpy everywhere."""
-    return False
+    """The gate of ``step_entries``, as ops/ssm.py's: the backend runs
+    Pallas kernels (``flash_attention``'s own gate), and the pool is float32
+    ``[L, entries, H, dk, dv]`` with ``dk`` whole sublane tiles and ``dv``
+    whole lane tiles."""
+    return (pa._use_pallas() and len(pool_shape) == 5
+            and jnp.dtype(pool_dtype) == _F32
+            and pool_shape[3] % 8 == 0 and pool_shape[4] % 128 == 0)
+
+
+def _step_kernel(layer_ref, held_ref, cols_ref, rows_ref, s_ref, y_ref,
+                 o_ref, *, n_heads):
+    """A block of ``be`` entries x ``bh`` heads of one layer's slab, read
+    once: each state's ``rule_step`` in VMEM, written where it was read from
+    (or what it held, where no live row holds its entry), its output reduced
+    over dk beside it. ``cols_ref`` [be, dk, W]: the decay, the key and the
+    query of ALL the entries' heads with the heads on lanes (lane ``c H +
+    head``), so a head's dk values lie down the sublanes as its state's rows
+    do and are spread over the dv lanes by a broadcast (``n_heads``: H);
+    the block's heads are rolled to lanes ``c H + 0 .. bh``. ``rows_ref`` [be, 3, bh, dv]: the
+    value, and the write strength and ``k . q`` spread over dv. Both
+    reductions are taken off the decayed state: ``S''^T q = S'^T q + (k . q)
+    u``."""
+    be, bh = s_ref.shape[:2]
+    width = cols_ref.shape[-1]
+    first = pl.program_id(0) * be
+    last = held_ref.shape[0] - 1
+    shift = (width - pl.program_id(1) * bh) % width
+
+    def entry(e, carry):
+        # a ragged last block's entries past the pool are never written
+        kept = held_ref[jnp.minimum(first + e, last)] != 0
+        cols = pltpu.roll(cols_ref[e], shift, 1)
+        for h in range(bh):
+            s = s_ref[e, h]
+            k = cols[:, n_heads + h:n_heads + h + 1]
+            decayed = cols[:, h:h + 1] * s
+            u = rows_ref[e, 1, h:h + 1] * (rows_ref[e, 0, h:h + 1] - jnp.sum(
+                decayed * k, axis=0, keepdims=True))
+            y_ref[e, h:h + 1] = jnp.sum(
+                decayed * cols[:, 2 * n_heads + h:2 * n_heads + h + 1],
+                axis=0, keepdims=True) + rows_ref[e, 2, h:h + 1] * u
+            o_ref[e, h] = jnp.where(kept, decayed + k * u, s)
+        return carry
+
+    jax.lax.fori_loop(0, be, entry, 0)
+
+
+def step_entries(q, k, v, g, beta, held, s_pool, layer):
+    """``rule_step`` of EVERY entry of layer ``layer`` of the state pool
+    ``s_pool`` [L, n, H, dk, dv], in the entries' order and where they lie,
+    through ONE kernel, ``delta_state_step`` (the caller has asked
+    ``step_in_kernel``): q, k [n, H, dk]; v [n, H, dv]; beta [n, H]; g [n,
+    H] (a decay a head) or [n, H, dk] (a channel); held [n] bool; all
+    float32 -> (o [n, H, dv] float32, the pool written). An entry that is
+    ``held`` is stepped by ``rule_step``'s equations in float32; one that
+    is not keeps what it held bit for bit, a NaN too (its ``o`` is whatever
+    its inputs give: the caller gathers the held entries'). An entry's
+    result depends on its own state and inputs alone, whatever else the
+    layer holds. The kernel passes over the slab once: a block is read,
+    stepped, reduced to its output and written back to the same place of
+    the same buffer (the pool is aliased to the result: no temporary of the
+    slab's size, no update-slice over it, no second and third read for the
+    two reductions)."""
+    _, n, h, dk, dv = s_pool.shape
+    be = min(STEP_BLOCK_ENTRIES, n)
+    # the rows' blocks: whole sublane tiles of heads, or every head
+    bh = STEP_BLOCK_HEADS if h % STEP_BLOCK_HEADS == 0 else h
+    # a decay a head scales every row of its state alike
+    decay = jnp.broadcast_to(jnp.exp(g).reshape(n, h, -1), (n, h, dk))
+    # [3, n, H, dk] -> [n, dk, 3 H] in whole lane tiles: heads on lanes
+    cols = jnp.transpose(jnp.stack([decay, k, q]), (1, 3, 0, 2)).reshape(
+        n, dk, 3 * h)
+    cols = jnp.pad(cols, ((0, 0), (0, 0), (0, -3 * h % 128)))
+    rows = jnp.stack([v] + [jnp.broadcast_to(x[..., None], v.shape) for x in (
+        beta, jnp.sum(k * q, axis=-1))], axis=1)
+    slab = pl.BlockSpec((None, be, bh, dk, dv),
+                        lambda i, j, lyr, _: (lyr[0], i, j, 0, 0))
+    # what the blocks hold in VMEM, each twice
+    blocks = 2 * 4 * be * (2 * bh * dk * dv + dk * cols.shape[-1]
+                           + 4 * bh * dv)
+    return pa._pcall(
+        functools.partial(_step_kernel, n_heads=h), name="delta_state_step",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            # heads innermost: an entry's columns are fetched once
+            grid=(-(-n // be), h // bh),
+            in_specs=[pl.BlockSpec((be, dk, cols.shape[-1]),
+                                   lambda i, j, *_: (i, 0, 0)),
+                      pl.BlockSpec((be, 3, bh, dv),
+                                   lambda i, j, *_: (i, 0, j, 0)),
+                      slab],
+            out_specs=[pl.BlockSpec((be, bh, dv),
+                                    lambda i, j, *_: (i, j, 0)), slab]),
+        out_shape=[jax.ShapeDtypeStruct((n, h, dv), _F32),
+                   jax.ShapeDtypeStruct(s_pool.shape, s_pool.dtype)],
+        input_output_aliases={4: 1},
+        **pa._vmem_asked(blocks),
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), held.astype(jnp.int32),
+      cols, rows, s_pool)
 
 
 def step(p, z, s_pool, layer, held, tail0, eps, scope="delta", **gate):
@@ -321,10 +450,16 @@ def step(p, z, s_pool, layer, held, tail0, eps, scope="delta", **gate):
     pool itself, as ops/ssm.py's: z [n, C + A + H], an entry's input; s_pool
     [L, n, H, dk, dv]; held [n] bool; tail0 [n, (k - 1) * C], flat as the
     tail pool stores it -> (o [n, H * dv] in z's type, the pool with layer
-    ``layer`` written, tail [n, (k - 1) * C]). The layer's entries are
-    sliced out of the pool, stepped (``rule_step``: ``window`` for one
-    position) and set back, an entry that is not ``held`` as it was."""
-    state0 = s_pool[layer]
+    ``layer`` written, tail [n, (k - 1) * C]). The entries that are ``held``
+    step as ``window`` steps one position (``rule_step``), the others keep
+    their state (their tail is the caller's to keep). Where the gate passes
+    (``step_in_kernel``) the layer's slab is stepped where it lies by ONE
+    kernel (``step_entries``); elsewhere its entries are sliced out of the
+    pool, stepped in jax.numpy and set back."""
+    in_kernel = step_in_kernel(s_pool.shape, s_pool.dtype)
+    # sliced here, ahead of the convolution: outside the gate the program is
+    # the one it was, operation for operation (Olmo-Hybrid's pinned text)
+    state0 = None if in_kernel else s_pool[layer]
     x, ab = _split(p, z)
     n = z.shape[0]
     with jax.named_scope(scope + "/conv"):
@@ -332,9 +467,14 @@ def step(p, z, s_pool, layer, held, tail0, eps, scope="delta", **gate):
             x, tail0.reshape(n, -1, x.shape[-1]), p["ConvW"],
             jnp.zeros((x.shape[-1],), _F32))
     with jax.named_scope(scope + "/step"):
-        o, state = rule_step(*_heads(p, c), *gates(p, ab, **gate),
-                             state0.astype(_F32))
-        o = o.reshape(n, -1).astype(z.dtype)
-        s_pool = s_pool.at[layer].set(jnp.where(
-            held[:, None, None, None], state.astype(s_pool.dtype), state0))
+        inputs = *_heads(p, c), *gates(p, ab, **gate)
+        if in_kernel:
+            o, s_pool = step_entries(*inputs, held, s_pool, layer)
+            o = o.reshape(n, -1).astype(z.dtype)
+        else:
+            o, state = rule_step(*inputs, state0.astype(_F32))
+            o = o.reshape(n, -1).astype(z.dtype)
+            s_pool = s_pool.at[layer].set(jnp.where(
+                held[:, None, None, None], state.astype(s_pool.dtype),
+                state0))
     return o, s_pool, tail.reshape(n, -1)

@@ -2520,9 +2520,9 @@ def state_step_in_kernel(attn_kinds, pool_specs):
     state layers through a Pallas kernel: a REPORT, as ``decode_in_place``
     is (a decode bundle's ``state_in_kernel``, the engine's
     ``state_step_in_kernel_total``), which chooses nothing: every state
-    layer's ``step`` asks its mixer's own gate (ops/ssm.py
-    ``step_in_kernel``: the backend, and the state pool's shape and type;
-    the delta rule has no kernel)."""
+    layer's ``step`` asks its mixer's own gate (``step_in_kernel`` of
+    ops/ssm.py and of ops/delta_rule.py: the backend, and the state pool's
+    shape and type; the gated short convolution keeps no state)."""
     return any(
         _keeps_state(spec) and _STATE_MIXERS[spec["mixer"]].step_in_kernel(
             *pool_specs[spec["pools"][0]])

@@ -140,10 +140,11 @@ _DECODE_COUNTERS = (
     # ticked beside decode_batches_total for every decode dispatch whose
     # program steps its state layers' entries through the Pallas kernel
     # (the decode bundle's ``state_in_kernel``: the selective state-space
-    # mixer over a float32 pool of whole tiles, on a backend with the
-    # kernel; everywhere else, and for the delta rule, the jax.numpy
-    # step): equal to decode_batches_total on the chip for Jamba2, 0 on a
-    # CPU and for a model without such layers
+    # mixer or the delta rule over a float32 pool of whole tiles, on a
+    # backend with the kernel; everywhere else the jax.numpy step): equal
+    # to decode_batches_total on the chip for Jamba2 and for Ling's kda
+    # layers, 0 on a CPU, for Olmo-Hybrid (a state a lane tile and a half
+    # wide) and for a model without such layers
     "state_step_in_kernel_total",
     # ticked once a decode dispatch made in the bundle's ``probe`` form
     # (``_run_decode_program`` called from outside the loop: every step's

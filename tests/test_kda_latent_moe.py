@@ -117,10 +117,11 @@ def prompt_of(n, seed=0):
         0, CFG.vocab_size, n).astype(np.int64)
 
 
-def probe(engine, w, n, model=MODEL, **kw):
+def probe(engine, w, n, model=MODEL, with_logits=False, **kw):
     """(rel_l2 of the engine's logits [1 + STEPS] against the reference's
     at a prompt of ``n`` tokens and STEPS decoded, the first kda layer's
-    state's, the largest forced-pick gap)."""
+    state's, the largest forced-pick gap); ``with_logits``: and the
+    engine's logits and tokens themselves."""
     prompt = prompt_of(n)
     got, picks, decoded = builder.engine_logits(engine, prompt, STEPS, 0)
     state = builder.first_state(engine)
@@ -128,9 +129,10 @@ def probe(engine, w, n, model=MODEL, **kw):
     want, _, gaps, want_state = builder.reference_logits(
         _System(w, model), sequence, n - 1 + np.arange(1 + STEPS), picks,
         **kw)
-    return (builder.rel_l2(got, want),
+    errs = (builder.rel_l2(got, want),
             float(np.linalg.norm(state - want_state)
                   / np.linalg.norm(want_state)), float(gaps.max()))
+    return errs + (got, decoded) if with_logits else errs
 
 
 # -- the model's programs -------------------------------------------------
@@ -181,7 +183,9 @@ def test_programs_carry_two_cache_kinds_and_three_pools(engine):
         ([1, n_pages, 4, 128], "float32"), ([5, 4, 2, 4, 6], "float32"),
         ([5, 4, 3 * 28], "float32")]
     assert engine.allocator.kinds == ("sequence", "state")
+    # the gates' answers: a CPU, and a state of 4 x 6 a head is no tile
     assert not p.decode["in_place"] and not p.decode["state_in_kernel"]
+    assert not dr.step_in_kernel(*p.pool_specs[1])
     attrs = CFG.block_attrs(4)
     assert [k.get("mixer") for k in attrs["attn_kinds"]] == ["latent",
                                                             "kda"]
@@ -210,8 +214,19 @@ def test_the_gates_answer_for_a_latent_kind(monkeypatch):
     # model's
     assert not T.decode_in_place("latent", kinds, [(1, 64, 64, 576)]
                                  + shapes[1:])
-    assert not T.state_step_in_kernel(kinds, [(s, "float32")
-                                              for s in shapes])
+    # the state kind's step asks its mixer's own gate (``delta_rule.
+    # step_in_kernel``): the cell's pool passes where the kernels run, the
+    # tiny model's [5, 4, 2, 4, 6] and a bfloat16 pool nowhere
+    specs = [(s, "float32") for s in shapes]
+    assert T.state_step_in_kernel(kinds, specs)
+    assert T.state_step_in_kernel(
+        kinds, [specs[0], ((5, 257, 32, 128, 128), "float32"), specs[2]])
+    assert not T.state_step_in_kernel(
+        kinds, [specs[0], ((5, 4, 2, 4, 6), "float32"), specs[2]])
+    assert not T.state_step_in_kernel(
+        kinds, [specs[0], (shapes[1], "bfloat16"), specs[2]])
+    monkeypatch.setattr(pa, "_use_pallas", lambda: False)
+    assert not T.state_step_in_kernel(kinds, specs)
 
 
 @pytest.mark.parametrize("label, scopes", [
@@ -678,3 +693,87 @@ def test_the_wide_engine_is_the_same_through_the_grouped_kernel(
     for (want, _, tokens), (got, _, again) in zip(off[0], on[0]):
         assert np.array_equal(np.asarray(tokens), np.asarray(again))
         assert builder.rel_l2(got, want).max() < REL_L2_F32
+
+
+# -- the decode steps' states through the kernel (PR 64) ---------------------
+
+# the tiny model with a state of whole tiles a head (8 x 128): where
+# ``delta_rule.step_in_kernel`` admits ``delta_state_step`` under the hook
+TILES = dataclasses.replace(CFG, name="kda-latent-tiles", kda_key_dim=8,
+                            kda_value_dim=128)
+TILES_PROMPTS = ((5, 0), (17, 1), (30, 2), (9, 3))   # (tokens, seed)
+
+
+@pytest.fixture(scope="module")
+def tiles_runs():
+    """The engine at TILES with the hook off and on: {hook: (the probes of a
+    whole prompt and of one through two chunks against the reference, the
+    tokens four requests give alone, those they give together over three
+    slots, that engine's totals)}."""
+    w = weights(cfg=TILES)
+    scope, model = scope_of(w), model_of(TILES)
+    prompts = [prompt_of(n, seed=s) for n, s in TILES_PROMPTS]
+    runs = {}
+    for hook in (False, True):
+        with pytest.MonkeyPatch.context() as m:
+            if hook:
+                kernel_on(m, ENGINE["page_size"])
+            eng = engine_of(scope, cfg=TILES)
+            assert eng.programs.pool_specs[1] == ([5, 4, 2, 8, 128],
+                                                  "float32")
+            assert eng.programs.decode["state_in_kernel"] is hook
+            probes = [probe(eng, w, n, model=model, with_logits=True)
+                      for n in (5, 17)]
+            eng.close()
+            alone = []
+            for p in prompts:
+                eng = engine_of(scope, cfg=TILES, auto_start=True)
+                alone.append(np.asarray(eng.generate(p, max_new=6)))
+                eng.close()
+            eng = engine_of(scope, cfg=TILES, auto_start=True)
+            try:
+                together = [np.asarray(h.result(300)) for h in [
+                    eng.submit(p, max_new=6) for p in prompts]]
+                stats = eng.stats()
+            finally:
+                eng.close()
+        runs[hook] = probes, alone, together, stats
+    return runs
+
+
+@pytest.mark.parametrize("hook", [False, True], ids=["off", "on"])
+def test_the_tiles_engine_is_the_reference_and_counts_its_state_steps(
+        tiles_runs, hook):
+    """Logits, the first kda layer's state and the picks against the
+    reference within the file's limits, the steps' states through
+    ``delta_state_step`` or through jax.numpy; ``state_step_in_kernel_total``
+    ticks with ``decode_batches_total`` under the hook and stays 0 without."""
+    probes, _, _, stats = tiles_runs[hook]
+    for err, s_err, gap, _, _ in probes:
+        assert err.max() < REL_L2_F32, err
+        assert s_err < REL_L2_F32
+        assert gap < 1e-4
+    assert stats["decode_batches_total"] > 0
+    assert stats["state_step_in_kernel_total"] == (
+        stats["decode_batches_total"] if hook else 0)
+    assert stats["kda_state_updates_total"] > 0
+    assert stats["pools_lost_total"] == 0 and stats["page_stall_total"] == 0
+
+
+@pytest.mark.parametrize("hook", [False, True], ids=["off", "on"])
+def test_a_request_in_the_tiles_mix_is_the_request_alone(tiles_runs, hook):
+    """Four requests over three slots (an entry reused, a free one beside
+    the held): an entry's step depends on no other entry, in the kernel as
+    in jax.numpy."""
+    _, alone, together, _ = tiles_runs[hook]
+    for one, mixed in zip(alone, together):
+        assert np.array_equal(one, mixed), (one, mixed)
+
+
+def test_the_tiles_engine_gives_the_same_tokens_hook_off_and_on(tiles_runs):
+    off, on = tiles_runs[False], tiles_runs[True]
+    for (*_, want, tokens), (*_, got, again) in zip(off[0], on[0]):
+        assert np.array_equal(np.asarray(tokens), np.asarray(again))
+        assert builder.rel_l2(got, want).max() < REL_L2_F32
+    for one, other in zip(off[1] + off[2], on[1] + on[2]):
+        assert np.array_equal(one, other)

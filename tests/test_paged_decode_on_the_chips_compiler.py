@@ -187,6 +187,20 @@ def _assert_held_uncopied(text, pool_specs):
         assert not re.findall(re.escape(pool) + r"\S* copy\(", text), pool
 
 
+def _assert_the_state_pool_is_only_carried(text, s_shape):
+    """Nothing MAKES an array of the float32 state pool's shape or of a
+    layer's slab's: the pool is a parameter, a loop's carry and a kernel's
+    own result, and nothing else (no copy, fusion, update-slice, select)."""
+    pool, slab = (_hlo_type(shape, "float32")
+                  for shape in (s_shape, s_shape[1:]))
+    made_by = set(re.findall(
+        r" = \(?(?:[^()]*, )?" + re.escape(pool) + r"[^ ]* ([\w\-]+)\(",
+        text))
+    assert made_by <= {"parameter", "get-tuple-element", "custom-call",
+                       "while", "tuple", "conditional"}, made_by
+    assert slab not in text
+
+
 def test_the_flat_kernel_compiles_at_the_mixed_models_shapes(
         one_chip, mixed):
     """Mosaic takes the sequence kind's pools as they are stored, keys 768
@@ -393,14 +407,7 @@ def test_jambas_decode_program_steps_its_states_in_one_kernel_a_run(
     _assert_held_uncopied(text, programs.pool_specs)
     s_shape, _ = programs.pool_specs[2]
     assert s_shape == [26, 129, 16, 5120]
-    pool, slab = (_hlo_type(shape, "float32")
-                  for shape in (s_shape, s_shape[1:]))
-    made_by = set(re.findall(
-        r" = \(?(?:[^()]*, )?" + re.escape(pool) + r"[^ ]* ([\w\-]+)\(",
-        text))
-    assert made_by <= {"parameter", "get-tuple-element", "custom-call",
-                       "while", "tuple", "conditional"}, made_by
-    assert slab not in text
+    _assert_the_state_pool_is_only_carried(text, s_shape)
     # the tails' taps are whole-tile slices of an entry as its pool stores
     # it: no [entries, 3, channels] view, which the chip stores in tiles of
     # 4 rows and copied an entry into and out of, a layer a step
@@ -1065,14 +1072,22 @@ def test_lings_programs_hold_the_latent_kernels_and_copy_no_pool(
     tails; the held experts' sorted pairs go through ``moe_grouped_rows``,
     ONE call a body of routed layers, at the step's 256 rows as in the
     chunk's 2,048 (a share of experts that fit the kernel's budget is
-    admitted as a whole layer is, PR 63), and no ``ragged_dot`` is left."""
+    admitted as a whole layer is, PR 63), and no ``ragged_dot`` is left.
+    The decode program steps a kda layer's states through ONE kernel,
+    ``delta_state_step`` (PR 64), once a run of kda layers (the leading
+    dense layer, the scan of layers 2-4, layer 6), the pool aliased into
+    and out of it: nothing MAKES an array of the pool's or of a layer's
+    slab's shape, no copy, no fusion, no update-slice, no select (the
+    jax.numpy step's two reductions and select-update were three reads and
+    a write of 0.54 GB a layer-step, half the program); the chunk program
+    holds no such call (its windows are ``chunk_rule``'s)."""
     monkeypatch.setattr(pa, "_use_pallas", lambda: True)
     _, programs = ling
     assert programs.pool_specs == [
         ([1, 16384, 64, 640], "bfloat16"),
         ([5, 257, 32, 128, 128], "float32"), ([5, 257, 36864], "bfloat16")]
     assert programs.decode["in_place"] and programs.chunk["attn_in_kernel"]
-    assert not programs.decode["state_in_kernel"]
+    assert programs.decode["state_in_kernel"]
     assert programs.decode["experts_in_kernel"]
     assert programs.chunk["experts_in_kernel"]
     compiled = program_text.lower_bundle(
@@ -1088,7 +1103,11 @@ def test_lings_programs_hold_the_latent_kernels_and_copy_no_pool(
     # the routed layers are three runs of the stack, a body each: the kda
     # layers 2-4, the latent layer 5, the kda layer 6
     assert len(re.findall(r"tpu_custom_call.*moe_grouped_rows", text)) == 3
+    assert len(re.findall(r"tpu_custom_call.*delta_state_step", text)) \
+        == (3 if label == "decode" else 0)
     _assert_held_uncopied(text, programs.pool_specs)
+    if label == "decode":
+        _assert_the_state_pool_is_only_carried(text, programs.pool_specs[1][0])
     memory = compiled.memory_analysis()
     pools = sum(math.prod(shape) * jnp.dtype(dt).itemsize
                 for shape, dt in programs.pool_specs)
